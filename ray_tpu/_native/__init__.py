@@ -1,7 +1,7 @@
 """Native (C++) kernels with transparent build + pure-numpy fallback.
 
 hashing.cpp is compiled once per machine with g++ -O3 into a cached .so
-(keyed by source hash under /tmp/ray_tpu/native) and bound via ctypes —
+(keyed by source hash, in the git-ignored ``_build`` directory beside it) and bound via ctypes —
 no pybind11 dependency. If no compiler is available the numpy fallbacks
 keep everything working (slower on string keys).
 
@@ -31,7 +31,7 @@ def _build() -> "ctypes.CDLL | None":
     try:
         with open(_SRC, "rb") as f:
             digest = hashlib.sha256(f.read()).hexdigest()[:16]
-        cache = os.path.join("/tmp", "ray_tpu", "native")
+        cache = os.path.join(_HERE, "_build")
         os.makedirs(cache, exist_ok=True)
         so = os.path.join(cache, f"hashing_{digest}.so")
         if not os.path.exists(so):

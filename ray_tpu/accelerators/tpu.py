@@ -86,6 +86,11 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
+        """Chips attached to this host. A TPU VM exposes one device node
+        per chip: ``/dev/accel<N>`` on older images, ``/dev/vfio/<N>`` on
+        the v5e hosts this repo runs on (seen on the chip machine: one
+        numbered node, ``/dev/vfio/3``, beside the ``/dev/vfio/vfio``
+        control node — the number is the host's, not a chip index)."""
         env = os.environ.get("RT_NUM_TPUS")
         if env is not None:
             return int(env)
@@ -120,7 +125,9 @@ class TPUAcceleratorManager:
     @staticmethod
     def worker_env_for_chips(chip_ids: list[int]) -> dict:
         n = len(chip_ids)
-        env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chip_ids)}
+        # JAX_PLATFORMS=tpu: a worker that was given chips and cannot open
+        # them fails; it never runs on the CPU backend in their place
+        env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chip_ids), "JAX_PLATFORMS": "tpu"}
         if n == 1:
             env["TPU_CHIPS_PER_HOST_BOUNDS"] = "1,1,1"
             env["TPU_HOST_BOUNDS"] = "1,1,1"

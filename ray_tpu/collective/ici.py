@@ -229,7 +229,7 @@ def collective_wire_report(closed_jaxpr, axis_size: int) -> dict:
     "ops": [{prim, dtype, shape, count, wire_bytes}, ...]}."""
     import math as _math
 
-    from jax import core as _core
+    from jax.extend import core as _core
 
     by_dtype: dict[str, float] = {}
     ops: list[dict] = []

@@ -1427,6 +1427,11 @@ class Runtime:
 
             env.update(TPUAcceleratorManager.worker_env_for_chips(chips))
             worker.env_binding = {"TPU_VISIBLE_CHIPS": env["TPU_VISIBLE_CHIPS"]}
+        elif node.total_resources.get("TPU"):
+            # one process per chip: a worker that was given no chip must not
+            # open one behind the scheduler's back (libtpu locks the chip to
+            # the first process that initialises the backend)
+            env["JAX_PLATFORMS"] = "cpu"
         if spec.runtime_env and spec.runtime_env.get("env_vars"):
             env.update(spec.runtime_env["env_vars"])
         renv_key = self._renv_key(spec)
@@ -1939,9 +1944,14 @@ class Runtime:
                 # into the process (jax backend init). Release CPU-side
                 # resources now but hold the chips until the process has
                 # actually exited — a fresh worker must not bind chips the
-                # dying libtpu still holds.
-                self._release_alloc(anode, alloc, [])
-                w.retired_chips = (anode, chips)
+                # dying libtpu still holds. The TPU resource COUNT is held
+                # back with the chip ids: released alone, it let the next
+                # num_tpus task be placed with no chip id to bind (seen on
+                # the v5e: the second task ran chipless on the CPU backend).
+                kind, pg_id, idx, res = alloc
+                held = {k: v for k, v in res.items() if k == "TPU"}
+                self._release_alloc(anode, (kind, pg_id, idx, {k: v for k, v in res.items() if k not in held}), [])
+                w.retired_chips = (anode, (kind, pg_id, idx, held), chips)
                 w.state = "retiring"
                 try:
                     w.send({"type": "shutdown"})
@@ -2048,9 +2058,9 @@ class Runtime:
         """The retired TPU worker's process is gone: chips are safe to reuse."""
         retired = getattr(w, "retired_chips", None)
         if retired is not None:
-            anode, chips = retired
+            anode, tpu_alloc, chips = retired
             w.retired_chips = None
-            anode.return_tpu_chips(chips)
+            self._release_alloc(anode, tpu_alloc, chips)
         w.state = "dead"
         node.remove_worker(w.worker_id)
         self._retire_conn(w.conn)

@@ -178,3 +178,10 @@ class PendingCallsLimitExceeded(RayTpuError):
 
 class OutOfMemoryError(RayTpuError):
     pass
+
+
+class AttnKernelUnavailableError(RayTpuError, ValueError):
+    """An engine was asked for ``attn_kernel="pallas"`` on a backend,
+    shape or mesh the kernel cannot serve. Raised at engine
+    construction: an explicit kernel request is never silently served
+    by another path."""

@@ -144,7 +144,7 @@ def _build(spec, bucket: str) -> tuple[tuple, dict]:
 # jaxpr walking
 # ---------------------------------------------------------------------------
 def _sub_jaxprs(params: dict) -> Iterator[Any]:
-    from jax import core
+    from jax.extend import core
 
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)

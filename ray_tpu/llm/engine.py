@@ -42,7 +42,6 @@ from __future__ import annotations
 import queue
 import threading
 import time
-import warnings
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -324,9 +323,9 @@ class LLMEngine:
         HBM-streaming kernel (llm/pallas/paged_attn.py: page-table
         gather, int8 dequant and flash-style attend in ONE program,
         interpret mode off-TPU). Validated here: an unknown value or
-        "pallas" on the slot layout raises; a config/platform the kernel
-        cannot serve (kernel_supported) degrades to "xla" with a
-        one-time warning, never an error. The resolved choice is
+        "pallas" on the slot layout raises, and so does a config/platform
+        the kernel cannot serve (kernel_supported says why):
+        AttnKernelUnavailableError, never a quiet XLA run. The choice is
         ``engine.attn_kernel`` (bench provenance reads it).
 
         cache_dtype: KV-cache storage dtype, validated against
@@ -385,7 +384,9 @@ class LLMEngine:
         from ray_tpu.llm.model_runner import make_paged_runner_fns, make_runner_fns
         from ray_tpu.llm.sampling import sample
         from ray_tpu.models.llama import init_params
+        from ray_tpu.util.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         self.config = config
         self.mesh = mesh
         if tp_collective not in ("fp", "int8"):
@@ -442,10 +443,9 @@ class LLMEngine:
                 dtype=self.kv_dtype,
             )
             if attn_kernel == "pallas":
-                # engine-validated opt-in with a DEGRADE contract: an
-                # unsupported platform/shape (or the not-yet-kernelized
-                # shard_map tp path) falls back to the XLA oracle with a
-                # one-time warning — serving never errors over a kernel
+                # an explicit request that cannot be served is an error at
+                # construction, never a quiet XLA run under the kernel's name
+                from ray_tpu.exceptions import AttnKernelUnavailableError
                 from ray_tpu.llm.pallas.paged_attn import kernel_supported
                 from ray_tpu.parallel.mesh import axis_size as _tp_axis
 
@@ -455,16 +455,10 @@ class LLMEngine:
                 if ok and mesh is not None and _tp_axis(mesh, "tp") > 1:
                     ok, why = False, "the shard_map tensor-parallel path does not ride the kernel yet"
                 if not ok:
-                    warnings.warn(
-                        f"attn_kernel='pallas' unavailable ({why}); falling back to the "
-                        "XLA paged-attention path",
-                        RuntimeWarning,
-                        stacklevel=2,
-                    )
-                    attn_kernel = "xla"
+                    raise AttnKernelUnavailableError(f"attn_kernel='pallas' cannot be served: {why}")
             self.attn_kernel = attn_kernel
             self._prefill, self._insert, self._decode, self._extend = make_paged_runner_fns(
-                config, attn_impl=attn_kernel
+                config, attn_impl=attn_kernel, mesh=mesh
             )
             self._page_alloc = pkv.PageAllocator(self._pcfg.num_pages)
             self._tables = np.zeros((self.max_num_seqs, max_pg), np.int32)
@@ -473,7 +467,7 @@ class LLMEngine:
             self._admit_counter = 0
         else:
             self.attn_kernel = "xla"  # slot layout: no page gather to fuse
-            self._prefill, self._insert, self._decode, self._extend = make_runner_fns(config)
+            self._prefill, self._insert, self._decode, self._extend = make_runner_fns(config, mesh=mesh)
 
         cache_cfg = (
             None
@@ -784,7 +778,10 @@ class LLMEngine:
         with self._lock:
             arrs = self.pool if self.kv_layout == "paged" else self.cache
             allocated = int(sum(int(a.nbytes) for name, a in arrs.items() if name != "length"))
+            devs = sorted(arrs["k"].devices(), key=lambda d: d.id)
             out = {
+                # the devices that HOLD the cache, as this process sees them
+                "device": {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)},
                 "layout": self.kv_layout,
                 "dtype": self.kv_dtype,
                 "quantized": self.kv_quant,
@@ -858,14 +855,17 @@ class LLMEngine:
         )
         # both layouts put kv_heads at axis 3: slot rows [L,B,S,kv,hd],
         # paged pool [L,P,page,kv,hd]
-        kv_s = NamedSharding(mesh, P(None, None, None, tp, None))
+        # no trailing None: shard_map hands the cache back with the normalised
+        # spec, and a textually different (if equivalent) input sharding on the
+        # second step would compile the fused step a second time
+        kv_s = NamedSharding(mesh, P(None, None, None, tp))
         if getattr(self, "kv_layout", "slots") == "paged":
             cache_sh = {"k": kv_s, "v": kv_s}
         else:
             cache_sh = {"k": kv_s, "v": kv_s, "length": NamedSharding(mesh, P())}
         if getattr(self, "kv_quant", False):
             # scale tensors put kv_heads at axis 2 ([L,B,kv,S] / [L,P,kv,page])
-            sc_s = NamedSharding(mesh, P(None, None, tp, None))
+            sc_s = NamedSharding(mesh, P(None, None, tp))
             cache_sh["k_scale"] = cache_sh["v_scale"] = sc_s
         return param_sh, cache_sh
 

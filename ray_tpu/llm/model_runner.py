@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from ray_tpu.lint import jaxcheck
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
 
 
@@ -96,15 +96,12 @@ def _shard_cfg(cfg: LlamaConfig, tp: int) -> LlamaConfig:
 
 
 def _tp_shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map where available; jax.experimental fallback on 0.4.x
-    (same shim as parallel/pipeline.py). check_rep=False: lane outputs are
+    """jax.shard_map over the tp axis. check_vma=False: lane outputs are
     replicated by construction (every shard computes the full sampler on
     the gathered logits), not by inference."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, axis_names={"tp"})
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, axis_names={"tp"}, check_vma=False
+    )
 
 
 def _param_pspecs(cfg: LlamaConfig, mesh):
@@ -128,10 +125,10 @@ def _cache_pspecs(kv_layout: str, kv_quant: bool):
     engine._mesh_shardings."""
     from jax.sharding import PartitionSpec as P
 
-    kv = P(None, None, None, "tp", None)
+    kv = P(None, None, None, "tp")
     specs = {"k": kv, "v": kv} if kv_layout == "paged" else {"k": kv, "v": kv, "length": P()}
     if kv_quant:
-        specs["k_scale"] = specs["v_scale"] = P(None, None, "tp", None)
+        specs["k_scale"] = specs["v_scale"] = P(None, None, "tp")
     return specs
 
 
@@ -265,12 +262,14 @@ def _mlp(x, layer, cfg: LlamaConfig, tpc: TpSpec | None = None):
     name="llm.prefill",
     shapes={"b8_t128": _bucket_prefill, "b8_t256": lambda: _bucket_prefill(T=256)},
 )
-def prefill(params, tokens, length, cfg: LlamaConfig):
+def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
     """Run the prompt through the model, returning last-token logits + K/V.
 
     tokens: [B, T_pad] int32 (right-padded); length: [B] int32 real lengths.
     Returns (logits [B, vocab] f32, k [L, B, T_pad, kv, hd], v same).
     Padded positions produce garbage K/V that later attention masks out.
+    ``mesh``: the engine's mesh when the program compiles SPMD over one —
+    the flash kernel then runs under shard_map (heads over tp).
     """
     B, T = tokens.shape
     positions = jnp.arange(T, dtype=jnp.int32)
@@ -282,7 +281,7 @@ def prefill(params, tokens, length, cfg: LlamaConfig):
         q, k, v = _qkv(xn, layer, cfg)
         qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
         kh = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
-        o = flash_attention(qh, kh, v.transpose(0, 2, 1, 3), True, None, cfg.attention_impl)
+        o = flash_attention_on_mesh(qh, kh, v.transpose(0, 2, 1, 3), mesh, cfg.attention_impl)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.num_heads * cfg.hd)
         x = x + jnp.dot(o, layer["wo"])
         x = _mlp(x, layer, cfg)
@@ -849,7 +848,7 @@ def make_fused_paged_fns(cfg: LlamaConfig, mesh=None, tp_collective: str = "fp",
     the documented gather/scatter program split is untouched.
     ``attn_impl="pallas"``: the attention half's page loop runs as the
     fused HBM-streaming kernel (single-device path only — the engine
-    degrades to "xla" on tp meshes)."""
+    refuses the kernel on tp meshes)."""
     from ray_tpu.parallel.mesh import axis_size
 
     if mesh is not None and axis_size(mesh, "tp") > 1:
@@ -1000,18 +999,18 @@ def paged_fused_step_tp(
     )
 
 
-def make_runner_fns(cfg: LlamaConfig):
+def make_runner_fns(cfg: LlamaConfig, mesh=None):
     """Jitted (prefill, insert, decode, extend) closures for an engine."""
     from ray_tpu.llm import kv_cache as kvc
 
-    prefill_fn = jax.jit(partial(prefill, cfg=cfg))
+    prefill_fn = jax.jit(partial(prefill, cfg=cfg, mesh=mesh))
     insert_fn = jax.jit(kvc.insert_sequence, donate_argnums=(0,))
     decode_fn = jax.jit(partial(decode_step, cfg=cfg), donate_argnums=(1,))
     extend_fn = jax.jit(partial(extend, cfg=cfg), donate_argnums=(1,))
     return prefill_fn, insert_fn, decode_fn, extend_fn
 
 
-def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla"):
+def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla", mesh=None):
     """Jitted (prefill, insert_pages, decode, extend) for a paged engine.
 
     Decode/extend each compile as TWO programs — read-only attention and
@@ -1021,7 +1020,7 @@ def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla"):
     both read-only halves ("xla" oracle / "pallas" fused kernel)."""
     from ray_tpu.llm import paged_kv as pkv
 
-    prefill_fn = jax.jit(partial(prefill, cfg=cfg))
+    prefill_fn = jax.jit(partial(prefill, cfg=cfg, mesh=mesh))
     insert_fn = jax.jit(pkv.insert_pages, donate_argnums=(0,))
     attn_fn = jax.jit(partial(decode_attn_paged, cfg=cfg, attn_impl=attn_impl))
     append_fn = jax.jit(append_paged, donate_argnums=(0,))

@@ -32,9 +32,9 @@ Scope and contracts:
   Pallas interpreter: slow, but the SAME kernel body TPU compiles, so
   CPU CI exercises the real code path.
 
-The XLA path remains the default and the fallback; engines opt in with
-``attn_kernel="pallas"`` (llm/engine.py validates, and degrades with a
-one-time warning — never an error — when ``kernel_supported`` says no).
+The XLA path remains the default; engines opt in with
+``attn_kernel="pallas"`` (llm/engine.py validates, and refuses with
+AttnKernelUnavailableError when ``kernel_supported`` says no).
 """
 
 from __future__ import annotations
@@ -43,6 +43,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.lint import jaxcheck
 from ray_tpu.llm.paged_kv import _NEG
@@ -54,48 +56,54 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# f32 bytes of one dequantized page block, padded to (8, 128) tiles, that
+# the kernel has been compiled with for a v5e (tests/test_chip_compile.py
+# and PERF.md Findings, PR 21): kv 32 x page 128 x hd 128, kv 8 x page 512
+# x hd 128. The body holds a handful of such blocks in VMEM; larger ones
+# have not been shown to fit, so they are not promised.
+_MAX_BLOCK_F32_BYTES = 2 << 20
+
+
 def kernel_supported(page_size: int, num_kv_heads: int, head_dim: int, quantized: bool = False):
     """(ok, why_not) for this config on this backend. CPU always works
-    (interpret mode); TPU gets a CONSERVATIVE tile gate on the dims
-    Mosaic actually tiles — the trailing two of each block: the K/V
-    block ``(1, page, kvh, hd)`` tiles (kvh, hd), so ``hd`` is the
-    128-lane dim and ``kvh`` the 8-sublane dim; an int8 pool's scale
-    block ``(1, kvh, page)`` additionally puts ``page`` on lanes.
-    Anything else has no lowering. This decision is taken ONCE at engine
-    construction, so it must be strict enough that a promised kernel
-    never fails to compile later — the engine turns a False into a
-    one-time warning + XLA fallback, never an error."""
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    except Exception as e:  # noqa: BLE001 — stubbed/absent pallas degrades
-        return False, f"pallas unavailable: {type(e).__name__}: {e}"
+    (interpret mode). On TPU the K/V block ``(1, page, kvh, hd)`` spans
+    the pool's full trailing dims, so Mosaic accepts any (kvh, hd) tile —
+    compiled for v5e at kv 1-32, hd 64-256, page 16-512, fp and int8 —
+    and the gate is the VMEM the dequantized block takes. This decision
+    is taken ONCE at engine construction and the engine turns a False
+    into AttnKernelUnavailableError, so it must be strict enough that a
+    promised kernel never fails to compile later."""
     backend = jax.default_backend()
     if backend == "cpu":
         return True, ""
     if backend == "tpu":
-        if head_dim % 128:
-            return False, f"head_dim {head_dim} is not a multiple of the 128-lane tile"
-        if num_kv_heads % 8:
-            return False, f"num_kv_heads {num_kv_heads} is not a multiple of the 8-sublane tile (the K/V block's sublane dim)"
-        if quantized and page_size % 128:
-            return False, f"int8 pool: page_size {page_size} is not a multiple of the 128-lane tile (the scale plane's lane dim)"
+        block = page_size * -(-num_kv_heads // 8) * 8 * -(-head_dim // 128) * 128 * 4
+        if block > _MAX_BLOCK_F32_BYTES:
+            return False, (
+                f"a page block of {page_size} x {num_kv_heads} x {head_dim} takes {block} f32 bytes "
+                f"in VMEM, over the {_MAX_BLOCK_F32_BYTES} the kernel has been compiled with"
+            )
         return True, ""
     return False, f"no pallas paged-attention path for backend {backend!r}"
 
 
-try:  # the module must import (for the XLA-only engines) even if pallas can't
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # noqa: BLE001 — kernel_supported reports the real reason
-    pl = pltpu = None
-
-
-def _partials_kernel(tables_ref, bound_ref, q_ref, k_ref, v_ref, *rest, page: int, quant: bool):
+def _partials_kernel(tables_ref, bound_ref, q_ref, k_ref, v_ref, *rest,
+                     page: int, rows: int, quant: bool):
     """One (lane b, page j) grid step: stream page ``tables[b, j]`` from
     HBM, dequantize in registers (int8 pools), fold into the lane's
     online-softmax carry. The carry lives in the output refs — the page
-    grid dim revisits the same output block, the canonical reduction."""
+    grid dim revisits the same output block, the canonical reduction.
+
+    The body keeps the pool's own ``[page, kv, hd]`` tiling — one
+    (kv, hd) vreg tile per position — so nothing is relaid out, in HBM
+    or in VMEM. Per query row the score is a broadcast multiply and a
+    lane reduction that KEEPS its axis (``[page, kv, 1]``); the softmax
+    max/sum and the P·V fold reduce over the leading page axis, which is
+    elementwise across tiles. These are the forms Mosaic lowers; the
+    einsum / squeezed-reduction form this replaces was refused
+    ("Offset change" on the row reduction). A decode query is 1-10 rows,
+    so the MXU would idle anyway; the kernel is bound by the page
+    stream."""
     if quant:
         k_sc_ref, v_sc_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -113,22 +121,31 @@ def _partials_kernel(tables_ref, bound_ref, q_ref, k_ref, v_ref, *rest, page: in
     vp = v_ref[0].astype(jnp.float32)
     if quant:
         # the exact kv_quant dequant the XLA path applies to gathered
-        # pages — here on the in-register block, at the f32 compute
-        # dtype (the convert stays off the flops-dominant dots: JXC003)
-        kp = kp * k_sc_ref[0].transpose(1, 0)[..., None]  # [page, kv, 1]
-        vp = vp * v_sc_ref[0].transpose(1, 0)[..., None]
-    qf = q_ref[0]  # [nkv, rep, T, hd], f32, pre-scaled by the caller
-    s = jnp.einsum("grth,pgh->grtp", qf, kp)  # [nkv, rep, T, page]
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)[:, 0]
+        # pages, at the f32 compute dtype. The scale plane is
+        # position-LAST ([kv, page]); a diagonal select + lane reduction
+        # moves it to [page, kv, 1] exactly (it only ever adds zeros)
+        kvh = kp.shape[1]
+        diag = (jax.lax.broadcasted_iota(jnp.int32, (page, kvh, page), 0)
+                == jax.lax.broadcasted_iota(jnp.int32, (page, kvh, page), 2))
+        kp = kp * jnp.where(diag, k_sc_ref[0][None], 0.0).sum(axis=-1, keepdims=True)
+        vp = vp * jnp.where(diag, v_sc_ref[0][None], 0.0).sum(axis=-1, keepdims=True)
+    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1, 1), 0)
     ok = pos < bound_ref[b]  # strictly pre-existing positions only
-    s = jnp.where(ok[None, None, None, :], s, _NEG)
-    m_prev, l_prev, acc_prev = m_ref[0], l_ref[0], acc_ref[0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.exp(s - m_new[..., None])
-    m_ref[0] = m_new
-    l_ref[0] = l_prev * alpha + pexp.sum(axis=-1)
-    acc_ref[0] = acc_prev * alpha[..., None] + jnp.einsum("grtp,pgh->grth", pexp, vp)
+
+    def row(r, carry):
+        qf = q_ref[0, r]  # [kv, hd], f32, pre-scaled by the caller
+        s = (kp * qf[None]).sum(axis=-1, keepdims=True)  # [page, kv, 1]
+        s = jnp.where(ok, s, _NEG)
+        m_prev = m_ref[0, r]  # [kv, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=0))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.exp(s - m_new[None])
+        m_ref[0, r] = m_new
+        l_ref[0, r] = l_ref[0, r] * alpha + pexp.sum(axis=0)
+        acc_ref[0, r] = acc_ref[0, r] * alpha + (pexp * vp).sum(axis=0)  # [kv, hd]
+        return carry
+
+    jax.lax.fori_loop(0, rows, row, None, unroll=rows <= 16)
 
 
 def paged_attn_partials(qf, pool_k_l, pool_v_l, tables, bound,
@@ -149,26 +166,29 @@ def paged_attn_partials(qf, pool_k_l, pool_v_l, tables, bound,
     the same partials the XLA page scan carries, ready for the shared
     ``_combine`` + normalize tail.
     """
-    if pl is None:  # pragma: no cover — kernel_supported gates real callers
-        raise RuntimeError("pallas is unavailable in this jax build")
     B, nkv, rep, T, hd = qf.shape
     page = pool_k_l.shape[1]
     kvh = pool_k_l.shape[2]
+    R = rep * T
     max_pg = tables.shape[1]
     quant = k_scale_l is not None
     if interpret is None:
         interpret = _interpret_default()
 
-    kernel = functools.partial(_partials_kernel, page=page, quant=quant)
+    kernel = functools.partial(_partials_kernel, page=page, rows=R, quant=quant)
     lane = lambda b, j, tbl, bnd: (b, 0, 0, 0)  # noqa: E731
+    # the fused gather: the index map IS the page-table read, so the
+    # pipeline DMAs exactly one pool page per grid step HBM -> VMEM
+    page_blk = lambda b, j, tbl, bnd: (tbl[b, j], 0, 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, nkv, rep, T, hd), lambda b, j, tbl, bnd: (b, 0, 0, 0, 0)),
-        # the fused gather: the index map IS the page-table read, so the
-        # pipeline DMAs exactly one pool page per grid step HBM -> VMEM
-        pl.BlockSpec((1, page, kvh, hd), lambda b, j, tbl, bnd: (tbl[b, j], 0, 0, 0)),
-        pl.BlockSpec((1, page, kvh, hd), lambda b, j, tbl, bnd: (tbl[b, j], 0, 0, 0)),
+        pl.BlockSpec((1, R, nkv, hd), lane),
+        pl.BlockSpec((1, page, kvh, hd), page_blk),
+        pl.BlockSpec((1, page, kvh, hd), page_blk),
     ]
-    args = [tables, bound, qf, pool_k_l, pool_v_l]
+    # query rows lead so each row is one (kv, hd) tile matching a pool
+    # position's tile (a transpose of the tiny query, never of the pool)
+    rows_first = lambda x: x.reshape(B, nkv, R, -1).transpose(0, 2, 1, 3)  # noqa: E731
+    args = [tables, bound, rows_first(qf), pool_k_l, pool_v_l]
     if quant:
         in_specs += [
             pl.BlockSpec((1, kvh, page), lambda b, j, tbl, bnd: (tbl[b, j], 0, 0)),
@@ -180,27 +200,29 @@ def paged_attn_partials(qf, pool_k_l, pool_v_l, tables, bound,
         grid=(B, max_pg),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, nkv, rep, T), lane),
-            pl.BlockSpec((1, nkv, rep, T), lane),
-            pl.BlockSpec((1, nkv, rep, T, hd), lambda b, j, tbl, bnd: (b, 0, 0, 0, 0)),
+            pl.BlockSpec((1, R, nkv, 1), lane),
+            pl.BlockSpec((1, R, nkv, 1), lane),
+            pl.BlockSpec((1, R, nkv, hd), lane),
         ],
     )
     out_shape = [
-        jax.ShapeDtypeStruct((B, nkv, rep, T), jnp.float32),
-        jax.ShapeDtypeStruct((B, nkv, rep, T), jnp.float32),
-        jax.ShapeDtypeStruct((B, nkv, rep, T, hd), jnp.float32),
+        jax.ShapeDtypeStruct((B, R, nkv, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, R, nkv, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, R, nkv, hd), jnp.float32),
     ]
     kw = {}
     if not interpret:
         # lanes are independent; the page dim carries the m/l/acc
         # reduction and must stay sequential
-        kw["compiler_params"] = pltpu.TPUCompilerParams(
+        kw["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         )
     m, l, acc = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret, **kw
+        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        name="paged_attn_partials", **kw
     )(*args)
-    return m, l, acc
+    heads_first = lambda x: x.transpose(0, 2, 1, 3).reshape(B, nkv, rep, T, -1)  # noqa: E731
+    return heads_first(m)[..., 0], heads_first(l)[..., 0], heads_first(acc)
 
 
 # ---------------------------------------------------------------------------

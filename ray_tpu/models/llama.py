@@ -22,7 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, cross_entropy_loss, rms_norm, rotary_embedding
 
 
@@ -156,7 +156,7 @@ def _attention_block(x, layer, config: LlamaConfig, cos, sin, positions, mesh=No
             v = jnp.repeat(v, rep, axis=1)
         o = sp_attention(q, k, v, mesh, impl="ring", causal=True)
     else:
-        o = flash_attention(q, k, v, True, None, config.attention_impl)
+        o = flash_attention_on_mesh(q, k, v, mesh, config.attention_impl)
     o = o.transpose(0, 2, 1, 3).reshape(B, T, nh * hd)
     return x + jnp.dot(o, layer["wo"])
 

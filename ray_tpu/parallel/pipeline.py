@@ -52,26 +52,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ray_tpu.lint import jaxcheck
 
 
-def _shard_map(f, mesh: Mesh, in_specs, out_specs, axis_names: set[str]):
-    """``jax.shard_map(..., axis_names=...)`` where available; on older
-    JAX (0.4.x) fall back to jax.experimental.shard_map with the
-    complement of ``axis_names`` as ``auto`` axes."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, axis_names=axis_names
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False, auto=auto)
-
-
-def _pvary(t, axis_names: tuple[str, ...]):
-    # lax.pvary is a no-op value-wise; it only exists on newer JAX to mark
-    # varying-manual-axes metadata. Identity is correct where it's absent.
-    return lax.pvary(t, axis_names) if hasattr(lax, "pvary") else t
-
-
 def to_stage_stacked(layer_params, n_stages: int, virtual_stages: int = 1):
     """[L, ...]-stacked layer params -> [n_stages, v, L/(n*v), ...].
 
@@ -204,7 +184,7 @@ def pipeline_apply(
             return (state, outputs), None
 
         init = jax.tree.map(
-            lambda t: _pvary(t, (axis_name,)),
+            lambda t: lax.pcast(t, (axis_name,), to="varying"),
             (jnp.zeros_like(xs[0]), jnp.zeros_like(xs)),
         )
         (_, outputs), _ = lax.scan(tick, init, jnp.arange(M * v + n - 1))
@@ -224,7 +204,9 @@ def pipeline_apply(
         x_spec = P(None, None, sp_axis)
         seq_specs = tuple(P(sp_axis) for _ in seq_inputs)
         manual = {axis_name, sp_axis}
-    fn = _shard_map(
+    # vma checking stays on: the psum'd output is inferred replicated, and
+    # the transpose (grad) relies on that typing
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name), x_spec) + seq_specs,

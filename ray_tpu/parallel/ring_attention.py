@@ -190,8 +190,6 @@ def ulysses_attention_local(q, k, v, axis_name: str = "sp", causal: bool = True,
 def sp_attention(q, k, v, mesh: Mesh, impl: str = "ring", causal: bool = True):
     """Top-level entry: q,k,v globally [B, H, T, D] sharded over sp on T.
     Wraps the local kernels in shard_map over the full mesh."""
-    from jax.experimental.shard_map import shard_map
-
     if "sp" not in mesh.axis_names:
         from ray_tpu.ops.flash_attention import flash_attention
 
@@ -200,11 +198,11 @@ def sp_attention(q, k, v, mesh: Mesh, impl: str = "ring", causal: bool = True):
     spec = P(batch_ax, None, "sp", None)
     local = ring_attention_local if impl == "ring" else ulysses_attention_local
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(local, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -20,6 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.lint import jaxcheck
 from ray_tpu.parallel.mesh import DEFAULT_RULES, ShardingRules, shard_batch_spec
+from ray_tpu.util.compile_cache import enable_compile_cache
 
 
 @dataclass
@@ -89,6 +90,7 @@ def make_train_step(
     - init_fn(rng) -> TrainState, sharded at creation (no host gather)
     - step_fn(state, batch) -> (state, metrics); jitted with donation
     """
+    enable_compile_cache()
     param_shardings = rules.tree_shardings(param_axes, mesh)
     batch_sharding = NamedSharding(mesh, shard_batch_spec(mesh))
     repl = NamedSharding(mesh, P())

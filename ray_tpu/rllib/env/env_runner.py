@@ -41,13 +41,16 @@ class SingleAgentEnvRunner:
         self._env_to_module = env_to_module
         self._module_to_env = module_to_env
         self.params = None
-        # rollouts are latency-bound host loops: pin them to the CPU
-        # backend when one is registered, even if the process default is a
-        # (possibly remote/tunneled) TPU — per-step eager ops on a remote
-        # device would make each env step a network round trip
+        # rollouts are latency-bound host loops: keep them on the CPU
+        # backend even where the process default is the TPU. Asking for
+        # the cpu backend initialises every platform JAX_PLATFORMS allows,
+        # so a remote runner relies on the runtime's pin (a worker that
+        # holds no chip runs with JAX_PLATFORMS=cpu) to stay off the chip;
+        # a local runner shares its learner's process, which may hold it.
         try:
             self._device = jax.local_devices(backend="cpu")[0]
-        except Exception:
+        except RuntimeError:
+            # JAX_PLATFORMS names only the process's chip: no cpu backend
             self._device = None
         self._key = self._put(jax.random.PRNGKey(seed + 10_000 * worker_idx))
         self._fwd = jax.jit(self.module.forward_exploration)

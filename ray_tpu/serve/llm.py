@@ -45,8 +45,10 @@ class LLMConfig:
     model_id: str = "ray_tpu-llama"
     tokenizer: object = None
     num_replicas: int = 1
-    # -1 = auto: tensor_parallel_size chips when tp > 1, else none.
-    # Explicit 0 opts out (CPU-mesh testing).
+    # -1 = auto: max(1, tensor_parallel_size) chips where the cluster
+    # advertises TPU (a replica IS the process that holds its chips); on
+    # a CPU-only cluster tp chips when tp > 1, else none. Explicit 0
+    # opts out (CPU-mesh testing).
     num_tpus_per_replica: float = -1
     autoscaling_config: object = None  # serve.AutoscalingConfig
     max_ongoing_requests: int = 32
@@ -301,6 +303,7 @@ class LLMServer:
             "prompt_token_ids": out.prompt_token_ids,
             "token_ids": out.token_ids,
             "finish_reason": out.finish_reason,
+            "logprobs": out.logprobs,  # None unless sampling_params asked for them
         }
 
     def _await_finished(self, rid: str, timeout_s: float):
@@ -1198,9 +1201,14 @@ def _build_app(llm_config: LLMConfig, cls, name: str):
         opts["num_replicas"] = llm_config.num_replicas
     num_tpus = llm_config.num_tpus_per_replica
     if num_tpus < 0:
-        # auto: a TP replica gang-reserves its chips (reference: vLLM
-        # replicas request tensor_parallel_size accelerators via their PG)
-        num_tpus = float(llm_config.tensor_parallel_size) if llm_config.tensor_parallel_size > 1 else 0.0
+        # auto: a replica reserves the chips it will open, one process per
+        # chip set (reference: vLLM replicas request tensor_parallel_size
+        # accelerators via their PG); a CPU-only cluster has none to hold
+        import ray_tpu
+
+        tp = llm_config.tensor_parallel_size
+        has_tpu = ray_tpu.cluster_resources().get("TPU", 0) > 0
+        num_tpus = float(max(1, tp)) if has_tpu or tp > 1 else 0.0
     if num_tpus:
         opts["num_tpus"] = num_tpus  # ReplicaConfig field
     deployment = serve.deployment(**opts)(cls)

@@ -37,6 +37,11 @@ class ScalingConfig:
     def _worker_resources(self) -> dict:
         res = dict(self.resources_per_worker or {})
         res.setdefault("CPU", 1.0)
+        if self.use_tpu:
+            # a TPU worker is the one process that opens its chips: reserve
+            # them so the scheduler never co-locates a second JAX process
+            # (resources_per_worker={"TPU": n} asks for more than one)
+            res.setdefault("TPU", 1.0)
         return res
 
 

@@ -1,0 +1,172 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached (guide on-chip-measurement, section 2): Mosaic and the TPU
+compiler are installed here, so what they refuse costs no chip time.
+Nothing runs — these say "the chip's compiler accepts this program", never
+a result or a time.
+
+The topology is described inside a module-scoped fixture (never at
+import, in a skipif or in parametrize): only the xdist worker that is
+handed this file loads the TPU library, and every worker collects the same
+tests. All such tests live in this one file for the same reason. The
+persistent compile cache is off around the compiles: an executable built
+for a described chip is written to it but cannot be read back here.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(tree, sharding):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), tree)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+# the training shape of bench.py / chip_smoke.py phase 3. VMEM is checked by
+# the compile itself (Mosaic refuses a kernel that scopes more than the chip
+# allows); memory_analysis() below checks what the program takes in HBM
+_B, _H, _T, _D = 8, 16, 2048, 128
+
+
+def test_flash_fwd_compiles_for_v5e(one_chip):
+    from ray_tpu.ops.flash_attention import _fwd_pallas
+
+    q = jax.ShapeDtypeStruct((_B, _H, _T, _D), jnp.bfloat16, sharding=one_chip)
+    compiled, txt = _compile(lambda q, k, v: _fwd_pallas(q, k, v, True, None), q, q, q)
+    assert "tpu_custom_call" in txt
+    # outputs + arguments only: the kernel must not need an HBM temp the size of the scores
+    assert compiled.memory_analysis().temp_size_in_bytes < _B * _H * _T * _T
+
+
+def test_flash_bwd_compiles_for_v5e(one_chip):
+    from ray_tpu.ops.flash_attention import _bwd_pallas
+
+    q = jax.ShapeDtypeStruct((_B, _H, _T, _D), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((_B, _H, _T), jnp.float32, sharding=one_chip)
+    _, txt = _compile(lambda q, k, v, o, lse, g: _bwd_pallas(q, k, v, o, lse, g, True, None), q, q, q, q, lse, q)
+    assert txt.count("tpu_custom_call") >= 2  # the dq kernel and the dk/dv kernel
+
+
+@pytest.mark.parametrize(
+    "T, page, quant",
+    [(1, 16, False), (5, 16, False), (1, 128, True), (5, 128, True)],
+    ids=["fp_decode_T1", "fp_verify_T5", "int8_decode_T1", "int8_verify_T5"],
+)
+def test_paged_partials_compile_for_v5e(one_chip, T, page, quant):
+    """The paged-attention kernel at nkv/hd/page of the 1B serving shape
+    (16 kv heads x 128, 8 lanes, 64 pages per lane), bf16 and int8 pools."""
+    from ray_tpu.llm.pallas.paged_attn import kernel_supported, paged_attn_partials
+
+    B, nkv, rep, hd, max_pg = 8, 16, 1, 128, 64
+    P = B * max_pg + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = sds((P, page, nkv, hd), jnp.int8 if quant else jnp.bfloat16)
+    args = [sds((B, nkv, rep, T, hd), jnp.float32), pool, pool, sds((B, max_pg), jnp.int32), sds((B,), jnp.int32)]
+    if quant:
+        args += [sds((P, nkv, page), jnp.float32)] * 2
+    compiled, txt = _compile(partial(paged_attn_partials, interpret=False), *args)
+    assert "tpu_custom_call" in txt
+    # the pool streams through the kernel: no relaid-out copy of it in HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < pool.size * pool.dtype.itemsize // 8
+    # and the engine's gate promises exactly this shape on a TPU
+    real = jax.default_backend
+    try:
+        jax.default_backend = lambda: "tpu"
+        assert kernel_supported(page, nkv, hd, quantized=quant) == (True, "")
+        assert not kernel_supported(1024, 64, 128)[0]  # past what has been compiled: refused, with a reason
+    finally:
+        jax.default_backend = real
+
+
+def test_fused_slot_decode_step_compiles_for_v5e(one_chip):
+    """One fused slot decode step (decode -> sample -> append KV) at the
+    real widths of the 1B serving shape, depth cut to 2 layers."""
+    from ray_tpu.llm.model_runner import _sds_cache, _sds_lanes, _sds_params, fused_step
+    from ray_tpu.models.llama import LlamaConfig
+
+    cfg = LlamaConfig(vocab_size=32000, hidden_size=2048, intermediate_size=5632, num_layers=2,
+                      num_heads=16, num_kv_heads=16, max_seq_len=2048, remat=False)
+    B = 8
+    args = _on((_sds_params(cfg), _sds_cache(cfg, B, cfg.max_seq_len)) + _sds_lanes(B), one_chip)
+    compiled, _ = _compile(partial(fused_step, cfg=cfg), *args)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 << 30  # fits the chip's HBM
+
+
+# ---------------------------------------------------------------------------
+# four chips: GSPMD cannot partition a Mosaic kernel on its own, so the flash
+# kernel must sit under shard_map wherever a program spans several devices
+# ---------------------------------------------------------------------------
+def _sharded(tree, mesh, specs):
+    from jax.sharding import NamedSharding
+
+    return jax.tree.map(
+        lambda s, sp: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, sp)), tree, specs
+    )
+
+
+def test_tp4_prefill_with_flash_kernel_compiles_for_v5e(topo):
+    """The engine's prefill, SPMD over a tp=4 mesh, with the Pallas flash
+    kernel selected as it is on a TPU (heads over tp under shard_map)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.llm.model_runner import _param_pspecs, _sds_params, prefill
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.parallel.mesh import create_mesh
+
+    cfg = LlamaConfig(vocab_size=32000, hidden_size=2048, intermediate_size=5632, num_layers=2, num_heads=16,
+                      num_kv_heads=16, max_seq_len=2048, remat=False, attention_impl="pallas")
+    mesh = create_mesh(tp=4, devices=topo.devices)
+    params = _sharded(_sds_params(cfg), mesh, _param_pspecs(cfg, mesh))
+    toks, lens = _sharded((jax.ShapeDtypeStruct((4, 512), jnp.int32), jax.ShapeDtypeStruct((4,), jnp.int32)),
+                          mesh, (P(), P()))
+    _, txt = _compile(partial(prefill, cfg=cfg, mesh=mesh), params, toks, lens)
+    assert "tpu_custom_call" in txt and "all-reduce" in txt
+
+
+def test_fsdp4_loss_and_grad_with_flash_kernel_compile_for_v5e(topo):
+    """Forward and backward of the train step's loss over an fsdp=4 mesh
+    (batch over fsdp under shard_map), widths of the SFT shape, depth 2."""
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn, param_logical_axes
+    from ray_tpu.parallel.mesh import DEFAULT_RULES, create_mesh, shard_batch_spec
+
+    cfg = LlamaConfig(vocab_size=32000, hidden_size=2048, intermediate_size=5632, num_layers=2, num_heads=16,
+                      num_kv_heads=8, max_seq_len=2048, attention_impl="pallas")
+    mesh = create_mesh(fsdp=4, devices=topo.devices)
+    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.PRNGKey(0))
+    shardings = DEFAULT_RULES.tree_shardings(param_logical_axes(cfg), mesh)
+    params = jax.tree.map(lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h), shapes, shardings)
+    tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=NamedSharding(mesh, shard_batch_spec(mesh)))
+    _, txt = _compile(jax.value_and_grad(partial(loss_fn, config=cfg, mesh=mesh)), params, {"tokens": tok, "targets": tok})
+    assert txt.count("tpu_custom_call") >= 3  # forward, dq and dk/dv kernels

@@ -831,10 +831,10 @@ def _jx_psum_dp(x):
 
 
 def _jx_collective_entry(x):
-    from jax.experimental.shard_map import shard_map
+    import jax
     from jax.sharding import PartitionSpec as P
 
-    return shard_map(_jx_psum_dp, mesh=_mesh2(), in_specs=P("dp"), out_specs=P(), check_rep=False)(x)
+    return jax.shard_map(_jx_psum_dp, mesh=_mesh2(), in_specs=P("dp"), out_specs=P(), check_vma=False)(x)
 
 
 def test_jxc005_flags_axis_outside_declared_mesh_and_silent_when_declared():
@@ -850,10 +850,9 @@ def _jx_branchy_psum(x):
     def local(v):
         return jax.lax.cond(v.sum() > 0, lambda u: jax.lax.psum(u, "dp"), lambda u: u * 2.0, v)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    return shard_map(local, mesh=_mesh2(), in_specs=P("dp"), out_specs=P("dp"), check_rep=False)(x)
+    return jax.shard_map(local, mesh=_mesh2(), in_specs=P("dp"), out_specs=P("dp"), check_vma=False)(x)
 
 
 def test_jxc005_flags_collective_diverging_across_cond_branches():

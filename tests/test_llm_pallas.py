@@ -212,15 +212,18 @@ def test_attn_kernel_engine_validation(params):
 
 
 def test_unsupported_config_degrades_with_warning_not_error(params, monkeypatch):
-    """kernel_supported says no -> ONE warning, attn_kernel resolves to
-    'xla', and the engine serves normally (never an error)."""
+    """kernel_supported says no -> the engine refuses at construction
+    with the typed error (an explicit kernel request is never served by
+    the XLA path under the kernel's name). The test keeps its old name:
+    it is the rewritten fallback test, not a new one."""
     import ray_tpu.llm.pallas.paged_attn as pa
+    from ray_tpu.exceptions import AttnKernelUnavailableError
 
     monkeypatch.setattr(pa, "kernel_supported", lambda *a, **k: (False, "simulated platform gap"))
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
-        eng = _engine(params, "pallas")
-    assert eng.attn_kernel == "xla"
-    assert sum("falling back" in str(x.message) for x in w) == 1
-    out = eng.generate(_prompts(2, seed=1), SamplingParams(temperature=0.0, max_tokens=4))
-    assert all(len(o.token_ids) == 4 for o in out)
+        with pytest.raises(AttnKernelUnavailableError, match="simulated platform gap"):
+            _engine(params, "pallas")
+    assert not any("falling back" in str(x.message) for x in w)
+    # the typed error is a ValueError, like every other constructor refusal
+    assert issubclass(AttnKernelUnavailableError, ValueError)

@@ -151,7 +151,6 @@ def test_ring_attention_chunked_path():
     [Tl, Tl] score matrix is never built, only [Tl, chunk] slabs."""
     import functools
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.parallel.ring_attention import ring_attention_local
@@ -161,12 +160,12 @@ def test_ring_attention_chunked_path():
     q = jax.random.normal(jax.random.PRNGKey(1), (B, H, T, D))
     k = jax.random.normal(jax.random.PRNGKey(2), (B, H, T, D))
     v = jax.random.normal(jax.random.PRNGKey(3), (B, H, T, D))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention_local, axis_name="sp", causal=True, chunk=4),
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3,
         out_specs=P(None, None, "sp", None),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(q, k, v)
     ref = attention_xla(q, k, v, causal=True)
