@@ -915,18 +915,24 @@ def worker_entry(conn, worker_id: str, node_id: str, env: dict | None = None):
     try:
         client.run()
     finally:
-        # final observability flush: the worker's last spans (e.g. a
-        # decode replica's finish span) and its last second of metric
-        # increments must not die with the process
-        try:
-            from ray_tpu.util import tracing as _tracing
+        flush_observability()
 
-            _tracing.shutdown()
-        except Exception:
-            pass
-        try:
-            from ray_tpu.util.metrics import _registry as _metrics_registry
 
-            _metrics_registry.flush_once()
-        except Exception:
-            pass
+def flush_observability():
+    """Final observability flush: the worker's last spans (e.g. a decode
+    replica's finish span) and its last second of metric increments must
+    not die with the process. Run on the worker's exit path, and by a
+    serve replica at the end of prepare_shutdown — the controller kills
+    that process next (SIGTERM, no handler), so the exit path never runs."""
+    try:
+        from ray_tpu.util import tracing as _tracing
+
+        _tracing.shutdown()
+    except Exception:
+        pass
+    try:
+        from ray_tpu.util.metrics import _registry as _metrics_registry
+
+        _metrics_registry.flush_once()
+    except Exception:
+        pass

@@ -49,6 +49,7 @@ import numpy as np
 
 from ray_tpu.llm.kvplane.index import prefix_key, token_bytes
 from ray_tpu.llm.sampling import SamplingParams
+from ray_tpu.llm.telemetry import NO_STAGE, stage
 
 
 @dataclass
@@ -73,6 +74,7 @@ class RequestState:
     admit_seq: int = -1
     preemptions: int = 0
     # telemetry lifecycle stamps (llm/telemetry.py; host wall clocks only)
+    t_ingress: float | None = None  # serving entry (telemetry.INGRESS_T), before parse/encode/admission
     t_submit: float = 0.0
     t_admit: float = 0.0
     t_first: float = 0.0
@@ -2428,15 +2430,16 @@ class LLMEngine:
         tel = self._tel
         t0 = time.perf_counter() if tel is not None else 0.0
         try:
-            with self._lock:
+            with tel.begin_step() if tel is not None else NO_STAGE, self._lock:
                 self._last_spec_drain = None
                 self._step_emitted = 0
-                wave = self._stage_admission()
-                admitted = self._stage_prefill(wave)
-                if self.kv_layout == "paged":
-                    self._paged_grow()
-                reported = self._stage_decode(admitted)
-                outs = self._build_outputs(reported)
+                with stage(tel, "llm.step.admission"):
+                    wave = self._stage_admission()
+                with stage(tel, "llm.step.prefill"):
+                    admitted = self._stage_prefill(wave)
+                reported = self._stage_decode(admitted, tel)
+                with stage(tel, "llm.step.outputs"):
+                    outs = self._build_outputs(reported)
                 if tel is not None:
                     tel.on_step(t0, len(admitted), self._step_emitted, self._last_spec_drain)
             if self._kv_plane is not None:
@@ -2455,26 +2458,39 @@ class LLMEngine:
                 tel.dump_on_error(exc)
             raise
 
-    def _stage_decode(self, admitted: list) -> list:
+    def _stage_decode(self, admitted: list, tel) -> list:
         """DECODE stage: advance every occupied slot one tick. Device-
         resident mode dispatches the fused (or speculative) step and
         drains the PREVIOUS one; sync mode is the blocking oracle loop.
         Prefill-only requests never reach here — they finished (and freed
-        their slot) inside the prefill stage."""
+        their slot) inside the prefill stage. Three telemetry stages:
+        dispatch (host time to enqueue), drain_wait (the host blocked on
+        the device's readback), emit (finish detection, queue puts)."""
+        spec = self._spec_cfg is not None
+        with stage(tel, "llm.step.dispatch"):
+            if self.kv_layout == "paged":
+                self._paged_grow()
+            if self._device_resident:
+                prev = self._pending
+                self._pending = None
+                if spec:
+                    self._dispatch_spec(prev)
+                else:
+                    self._dispatch_fused()
+                if tel is not None and self._pending is not None:
+                    tel.dispatch_t = time.time()
         if self._device_resident:
-            prev = self._pending
-            self._pending = None
-            if self._spec_cfg is not None:
-                self._dispatch_spec(prev)
-                emitted = self._drain_spec(prev)
-            else:
-                self._dispatch_fused()
-                emitted = self._drain(prev)
+            with stage(tel, "llm.step.drain_wait"):
+                host = self._drain_wait(prev)
+            with stage(tel, "llm.step.emit"):
+                emitted = self._drain_spec(prev, host) if spec else self._drain(prev, host)
             self._step_emitted = len(emitted)
             return admitted + emitted
         # sync mode: every active lane (just-admitted ones included)
         # emitted a token this step — the returned list IS the emit set
-        reported = self._sync_decode()
+        # (its in-step readback and emission are one stage, drain_wait)
+        with stage(tel, "llm.step.drain_wait"):
+            reported = self._sync_decode()
         self._step_emitted = len(reported)
         return reported
 
@@ -2516,14 +2532,22 @@ class LLMEngine:
         self._dtokens = toks
         self._pending = (toks, logps, [(st, st.slot) for st in active])
 
-    def _drain(self, pending) -> list:
-        """Read back and emit the PREVIOUS step's tokens (blocks only on
-        work that overlapped the current step's dispatch)."""
+    def _drain_wait(self, pending) -> tuple:
+        """Read back the PREVIOUS step's (or speculative round's) device
+        outputs: the one place the device-resident loop blocks, and only
+        on work that overlapped the current step's dispatch. -> the host
+        arrays, in the pending tuple's order, for _drain/_drain_spec."""
+        if pending is None:
+            return ()
+        return tuple(np.asarray(a) for a in pending[:-1])  # tpulint: disable=CCR002 — sanctioned one-step-delayed drain readback (overlaps next step's compute)
+
+    def _drain(self, pending, host: tuple | None = None) -> list:
+        """Emit the PREVIOUS step's tokens from their read-back arrays
+        (``host``: _drain_wait's, read back here when not passed)."""
         if pending is None:
             return []
-        toks_d, logps_d, lanes = pending
-        toks = np.asarray(toks_d)  # tpulint: disable=CCR002 — sanctioned one-step-delayed drain readback (overlaps next step's compute)
-        logps = np.asarray(logps_d)  # tpulint: disable=CCR002 — sanctioned one-step-delayed drain readback (overlaps next step's compute)
+        lanes = pending[-1]
+        toks, logps = host if host is not None else self._drain_wait(pending)
         emitted = []
         for st, slot in lanes:
             if st.finished:
@@ -2592,18 +2616,17 @@ class LLMEngine:
         lanes = [(st, st.slot, int(self._lane_k[st.slot])) for st in active]
         self._pending = (emit, logps, acc, lanes)
 
-    def _drain_spec(self, pending) -> list:
-        """Read back and emit the PREVIOUS speculative round: up to
-        accepted+1 tokens per lane, stopping at finish (stop ids /
-        max_tokens mid-round) and, for the paged layout, at the cache
-        row's capacity — the same point the plain path's page growth
-        finishes a row-exhausted sequence with reason 'length'."""
+    def _drain_spec(self, pending, host: tuple | None = None) -> list:
+        """Emit the PREVIOUS speculative round from its read-back arrays
+        (``host``, as for _drain): up to accepted+1 tokens per lane,
+        stopping at finish (stop ids / max_tokens mid-round) and, for the
+        paged layout, at the cache row's capacity — the same point the
+        plain path's page growth finishes a row-exhausted sequence with
+        reason 'length'."""
         if pending is None:
             return []
-        emit_d, logps_d, acc_d, lanes = pending
-        emit = np.asarray(emit_d)  # tpulint: disable=CCR002 — sanctioned one-round-delayed spec drain readback
-        logps = np.asarray(logps_d)  # tpulint: disable=CCR002 — sanctioned one-round-delayed spec drain readback
-        acc = np.asarray(acc_d)  # tpulint: disable=CCR002 — sanctioned one-round-delayed spec drain readback
+        lanes = pending[-1]
+        emit, logps, acc = host if host is not None else self._drain_wait(pending)
         row_cap = (
             self._pcfg.max_pages_per_seq * self._pcfg.page_size if self.kv_layout == "paged" else None
         )

@@ -27,6 +27,40 @@ from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
 
 
 # ---------------------------------------------------------------------------
+# Step programs say their names. ``jax.jit(partial(...))`` has no ``__name__``
+# to offer, so XLA called prefill, decode and extend alike ``jit__unknown``
+# in profiler traces, compile logs and the persistent cache; every step
+# program is jitted through ``named_jit`` instead and shows up as
+# ``jit_<name>``. Trace readers rely on two words: exactly one program that
+# runs once per decode step has ``fused`` in its name (the paged layout's
+# second program a token is ``llm_kv_append``), every prefill program has
+# ``prefill`` in its name whatever its bucket, and nothing else has either.
+# ---------------------------------------------------------------------------
+STEP_PROGRAM_NAMES = frozenset({
+    "llm_prefill", "llm_kv_insert", "llm_decode_step", "llm_extend",  # slot layout
+    "llm_kv_insert_pages", "llm_paged_attn", "llm_kv_append",  # paged layout
+    "llm_extend_paged_attn", "llm_kv_append_chunk",
+    "llm_fused_step", "llm_fused_paged_step",  # device-resident decode (one per layout)
+    "llm_verify_step", "llm_verify_paged_attn", "llm_verify_append",  # speculative verify
+    "llm_draft_propose", "llm_draft_prefill", "llm_draft_kv_insert", "llm_draft_steps",
+})
+
+
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under the stable program name ``name``
+    (one of STEP_PROGRAM_NAMES). Arguments pass through by position, so
+    ``donate_argnums`` mean what they meant on ``fn``."""
+    if name not in STEP_PROGRAM_NAMES:
+        raise ValueError(f"{name!r} is not a documented step program name")  # tpulint: disable=ERR002 — programmer error at engine construction, never client-visible
+
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
+
+
+# ---------------------------------------------------------------------------
 # Tensor parallelism over the ICI mesh: the fused decode hot path is
 # re-expressed under shard_map so the per-layer TP all-reduce is an
 # EXPLICIT psum the runtime controls (instead of a GSPMD-inserted
@@ -763,8 +797,9 @@ def make_fused_fns(cfg: LlamaConfig, mesh=None, tp_collective: str = "fp", kv_qu
     from ray_tpu.parallel.mesh import axis_size
 
     if mesh is not None and axis_size(mesh, "tp") > 1:
-        return jax.jit(_sharded_fused_slots(cfg, mesh, tp_collective, kv_quant), donate_argnums=(1, 3, 4, 5, 6))
-    return jax.jit(partial(fused_step, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6))
+        return named_jit("llm_fused_step", _sharded_fused_slots(cfg, mesh, tp_collective, kv_quant),
+                         donate_argnums=(1, 3, 4, 5, 6))
+    return named_jit("llm_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6))
 
 
 @jaxcheck.entry(
@@ -852,10 +887,12 @@ def make_fused_paged_fns(cfg: LlamaConfig, mesh=None, tp_collective: str = "fp",
     from ray_tpu.parallel.mesh import axis_size
 
     if mesh is not None and axis_size(mesh, "tp") > 1:
-        attn_fn = jax.jit(_sharded_fused_paged(cfg, mesh, tp_collective, kv_quant), donate_argnums=(3, 5, 6, 7, 8))
+        attn_fn = named_jit("llm_fused_paged_step", _sharded_fused_paged(cfg, mesh, tp_collective, kv_quant),
+                            donate_argnums=(3, 5, 6, 7, 8))
     else:
-        attn_fn = jax.jit(partial(paged_fused_step, cfg=cfg, attn_impl=attn_impl), donate_argnums=(3, 5, 6, 7, 8))
-    append_fn = jax.jit(append_paged, donate_argnums=(0,))
+        attn_fn = named_jit("llm_fused_paged_step", partial(paged_fused_step, cfg=cfg, attn_impl=attn_impl),
+                            donate_argnums=(3, 5, 6, 7, 8))
+    append_fn = named_jit("llm_kv_append", append_paged, donate_argnums=(0,))
     return attn_fn, append_fn
 
 
@@ -1003,10 +1040,10 @@ def make_runner_fns(cfg: LlamaConfig, mesh=None):
     """Jitted (prefill, insert, decode, extend) closures for an engine."""
     from ray_tpu.llm import kv_cache as kvc
 
-    prefill_fn = jax.jit(partial(prefill, cfg=cfg, mesh=mesh))
-    insert_fn = jax.jit(kvc.insert_sequence, donate_argnums=(0,))
-    decode_fn = jax.jit(partial(decode_step, cfg=cfg), donate_argnums=(1,))
-    extend_fn = jax.jit(partial(extend, cfg=cfg), donate_argnums=(1,))
+    prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
+    insert_fn = named_jit("llm_kv_insert", kvc.insert_sequence, donate_argnums=(0,))
+    decode_fn = named_jit("llm_decode_step", partial(decode_step, cfg=cfg), donate_argnums=(1,))
+    extend_fn = named_jit("llm_extend", partial(extend, cfg=cfg), donate_argnums=(1,))
     return prefill_fn, insert_fn, decode_fn, extend_fn
 
 
@@ -1020,12 +1057,12 @@ def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla", mesh=None):
     both read-only halves ("xla" oracle / "pallas" fused kernel)."""
     from ray_tpu.llm import paged_kv as pkv
 
-    prefill_fn = jax.jit(partial(prefill, cfg=cfg, mesh=mesh))
-    insert_fn = jax.jit(pkv.insert_pages, donate_argnums=(0,))
-    attn_fn = jax.jit(partial(decode_attn_paged, cfg=cfg, attn_impl=attn_impl))
-    append_fn = jax.jit(append_paged, donate_argnums=(0,))
-    ext_attn_fn = jax.jit(partial(extend_attn_paged, cfg=cfg, attn_impl=attn_impl))
-    ext_append_fn = jax.jit(append_chunk_paged, donate_argnums=(0,))
+    prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
+    insert_fn = named_jit("llm_kv_insert_pages", pkv.insert_pages, donate_argnums=(0,))
+    attn_fn = named_jit("llm_paged_attn", partial(decode_attn_paged, cfg=cfg, attn_impl=attn_impl))
+    append_fn = named_jit("llm_kv_append", append_paged, donate_argnums=(0,))
+    ext_attn_fn = named_jit("llm_extend_paged_attn", partial(extend_attn_paged, cfg=cfg, attn_impl=attn_impl))
+    ext_append_fn = named_jit("llm_kv_append_chunk", append_chunk_paged, donate_argnums=(0,))
 
     def decode_fn(params, pool, tables, lengths, tokens):
         write_page, write_off = decode_write_targets(tables, lengths, pool["k"].shape[2])

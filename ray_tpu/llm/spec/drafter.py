@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from ray_tpu.lint import jaxcheck
 from ray_tpu.llm import kv_cache as kvc
-from ray_tpu.llm.model_runner import _sds, _sds_cache, _sds_params, decode_step, prefill
+from ray_tpu.llm.model_runner import _sds, _sds_cache, _sds_params, decode_step, named_jit, prefill
 from ray_tpu.models.llama import LlamaConfig
 
 
@@ -117,7 +117,7 @@ class NGramDrafter:
     def __init__(self, k: int = 4, n: int = 3):
         self.k = int(k)
         self.n = int(n)
-        self._propose = jax.jit(partial(ngram_propose, n=self.n, k=self.k))
+        self._propose = named_jit("llm_draft_propose", partial(ngram_propose, n=self.n, k=self.k))
 
     def init_slots(self, num_slots: int, max_seq_len: int, prefill_buckets: tuple) -> None:
         pass
@@ -207,9 +207,9 @@ class ModelDrafter:
         self.cfg = config
         self.k = int(k)
         self.params = params if params is not None else init_params(config, jax.random.PRNGKey(seed))
-        self._prefill = jax.jit(partial(prefill, cfg=config))
-        self._insert = jax.jit(kvc.insert_sequence, donate_argnums=(0,))
-        self._draft = jax.jit(partial(draft_steps, cfg=config, k=self.k), donate_argnums=(1,))
+        self._prefill = named_jit("llm_draft_prefill", partial(prefill, cfg=config))
+        self._insert = named_jit("llm_draft_kv_insert", kvc.insert_sequence, donate_argnums=(0,))
+        self._draft = named_jit("llm_draft_steps", partial(draft_steps, cfg=config, k=self.k), donate_argnums=(1,))
         self.cache = None
         self._buckets: tuple = ()
 

@@ -55,6 +55,7 @@ from ray_tpu.llm.model_runner import (
     _tp_reduce,
     _tp_shard_map,
     _trace_cfg,
+    named_jit,
 )
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
@@ -308,8 +309,9 @@ def make_spec_verify_slots(cfg: LlamaConfig, k: int, mesh=None, tp_collective: s
 
     if mesh is not None and axis_size(mesh, "tp") > 1:
         body = _sharded_spec_verify_slots(cfg, mesh, tp_collective, kv_quant)
-        return jax.jit(body, donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9, 10))
-    return jax.jit(partial(spec_verify_slots, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9, 10))
+        return named_jit("llm_verify_step", body, donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9, 10))
+    return named_jit("llm_verify_step", partial(spec_verify_slots, cfg=cfg),
+                     donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9, 10))
 
 
 # ---------------------------------------------------------------------------
@@ -495,14 +497,14 @@ def make_spec_verify_paged(cfg: LlamaConfig, k: int, mesh=None, tp_collective: s
     from ray_tpu.parallel.mesh import axis_size
 
     if mesh is not None and axis_size(mesh, "tp") > 1:
-        attn_fn = jax.jit(
-            _sharded_spec_verify_paged(cfg, mesh, tp_collective, kv_quant),
+        attn_fn = named_jit(
+            "llm_verify_paged_attn", _sharded_spec_verify_paged(cfg, mesh, tp_collective, kv_quant),
             donate_argnums=(3, 5, 6, 7, 8, 9, 10, 11, 12),
         )
     else:
-        attn_fn = jax.jit(partial(spec_verify_paged, cfg=cfg, attn_impl=attn_impl),
-                          donate_argnums=(3, 5, 6, 7, 8, 9, 10, 11, 12))
-    append_fn = jax.jit(spec_append_paged, donate_argnums=(0,))
+        attn_fn = named_jit("llm_verify_paged_attn", partial(spec_verify_paged, cfg=cfg, attn_impl=attn_impl),
+                            donate_argnums=(3, 5, 6, 7, 8, 9, 10, 11, 12))
+    append_fn = named_jit("llm_verify_append", spec_append_paged, donate_argnums=(0,))
     return attn_fn, append_fn
 
 

@@ -21,9 +21,15 @@ Three pieces:
   host wall ms, occupancy, queue depth, spec round accounting, handoff
   events, recompile sentinel) plus a ring of finished-request lifecycle
   records (submit/admit/first-token/finish stamps, per-token ITL
-  samples). ``LLMEngine.telemetry()`` returns the snapshot; on an engine
-  error the ring is dumped as JSONL into the session dir for
-  postmortems. The recompile sentinel watches each registered
+  samples). ``LLMEngine.telemetry()`` returns the snapshot of the rings;
+  beside them the recorder keeps the FLIGHT LOG, every step and request
+  since the engine started (bounded: ``LOG_STEPS``/``LOG_REQUESTS``),
+  written once as JSONL into the session dir when the replica stops
+  (``LLMServer.shutdown``) or the engine dies, and read back by
+  ``load_flight()``. Nothing is written while requests are served. Each
+  step record carries its STAGES (``stage()``: where the host's time in
+  a step went, and the same spans as ``TraceAnnotation``s on the
+  profiler's clock). The recompile sentinel watches each registered
   fixed-shape fused entry's jit cache: the serving hot path compiles
   ONCE per entry, so any growth after the first program is a bug
   (a varying static arg, a dtype drifting per step) and gets its own
@@ -41,14 +47,76 @@ Three pieces:
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import json
+import logging
 import os
 import threading
 import time
 import uuid
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from ray_tpu.util import tracing
+
+logger = logging.getLogger("ray_tpu.llm")
+
+# Stages of one serving step, in the order they run. ``llm.step.*`` are
+# timed inside LLMEngine.step (children of the ``llm.step`` annotation);
+# ``llm.stepper.*`` by the serve stepper between two steps, and land on
+# the row of the step that follows. drain_wait is the host blocked on the
+# device (the delayed readback); everything else is the host's own work.
+STAGES = {  # annotation name -> the step record's column (milliseconds)
+    "llm.step.admission": "admission_ms",
+    "llm.step.prefill": "prefill_ms",
+    "llm.step.dispatch": "dispatch_ms",
+    "llm.step.drain_wait": "drain_wait_ms",
+    "llm.step.emit": "emit_ms",
+    "llm.step.outputs": "outputs_ms",
+    "llm.stepper.deliver": "stepper_deliver_ms",
+    "llm.stepper.wait": "stepper_wait_ms",
+}
+_STAGE_IX = {name: i for i, name in enumerate(STAGES)}
+
+# time.time() at which the serving ingress took the request now being
+# admitted on this thread/task (OpenAIServer.__call__ sets it, on_submit
+# reads it): the request's first stamp, before parse/encode/admission
+INGRESS_T: contextvars.ContextVar = contextvars.ContextVar("rt_llm_ingress_t", default=None)
+
+NO_STAGE = contextlib.nullcontext()
+
+
+class _Stage:
+    """One stage, two clocks, one pair of stamps: a TraceAnnotation for
+    the profiler (~0.4 us while no profile is active; inside one, a host
+    span on the profiler's own clock, next to the device lines) and the
+    duration added to the step's flight record."""
+
+    __slots__ = ("_acc", "_ix", "_ann", "_t0")
+
+    def __init__(self, acc: list, name: str):
+        self._acc = acc
+        self._ix = _STAGE_IX[name]
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._acc[self._ix] += (time.perf_counter() - self._t0) * 1e3
+        self._ann.__exit__(*exc)
+        return False
+
+
+def stage(tel: "EngineTelemetry | None", name: str):
+    """``with stage(tel, "llm.step.prefill"): ...`` — the ONE way a stage
+    is stamped (name from STAGES). A no-op context for an engine built
+    with telemetry=False. Reads no device value, adds no host callback."""
+    return _Stage(tel._stage_ms, name) if tel is not None else NO_STAGE
 
 # SLO histogram boundaries (seconds): decode steps are single-digit ms on
 # chip, prefill stalls are tens-to-hundreds of ms, a cold compile is
@@ -258,11 +326,27 @@ class FlightRecorder:
         "step", "t", "phase", "wall_ms", "admitted", "emitted", "batch", "waiting",
         "occupied_tokens", "capacity_tokens", "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
-    )
+        # the step's start and the instant its fused program was enqueued
+        # (both time.time(); dispatch_t absent where none was), then the
+        # stage durations
+        "t0", "dispatch_t",
+    ) + tuple(STAGES.values())
+
+    # The flight log's bound: it holds a run whole — 10 minutes at 20
+    # steps/s, 2,000 requests (about 7 MB of step rows and 11 MB of
+    # request records at 150 tokens each, tests/test_llm_flight.py).
+    # Past it the oldest go, and the log's header says how many.
+    LOG_STEPS = 12_000
+    LOG_REQUESTS = 2_000
 
     def __init__(self, max_steps: int = 512, max_requests: int = 256):
         self.steps: deque = deque(maxlen=max_steps)
         self.requests: deque = deque(maxlen=max_requests)
+        # the flight log shares the rings' records (one tuple / one dict
+        # each, appended twice)
+        self.log_steps: deque = deque(maxlen=self.LOG_STEPS)
+        self.log_requests: deque = deque(maxlen=self.LOG_REQUESTS)
+        self.request_count = 0
         # async prefix-fetch spans (engine fetch worker): cross-checking
         # a fetch record's [t0, t1] against step records' timestamps is
         # the item-3a overlap evidence the bench and tests read
@@ -307,11 +391,27 @@ class FlightRecorder:
         prepended here)."""
         with self._lock:
             self.step_count += 1
-            self.steps.append((self.step_count,) + row)
+            row = (self.step_count,) + row
+            self.steps.append(row)
+            self.log_steps.append(row)
 
     def record_request(self, rec: dict) -> None:
         with self._lock:
+            self.request_count += 1
             self.requests.append(rec)
+            self.log_requests.append(rec)
+
+    def stamp_request(self, request_id: str, **fields) -> dict | None:
+        """Add late stamps (the stream's yields, which end after the
+        engine finished the request) to a recorded request; -> the record,
+        or None if it is not (or no longer) in the log. The request
+        finished moments ago, so the scan from the newest end is short."""
+        with self._lock:
+            for rec in reversed(self.log_requests):
+                if rec["request_id"] == request_id:
+                    rec.update(fields)
+                    return rec
+        return None
 
     def record_fetch(self, rec: dict) -> None:
         with self._lock:
@@ -324,25 +424,30 @@ class FlightRecorder:
             fetches = [dict(r) for r in self.fetches]
             count = self.step_count
             recs = dict(self.recompiles)
-        steps = []
-        for row in rows:
-            d = dict(zip(self.STEP_FIELDS, row))
-            # drop layout-/mode-inapplicable fields (None) for readability
-            steps.append({k: v for k, v in d.items() if v is not None})
-        return {"step_count": count, "steps": steps, "requests": reqs,
+        return {"step_count": count, "steps": [self._step_dict(row) for row in rows], "requests": reqs,
                 "fetches": fetches, "recompiles": recs}
 
+    def _step_dict(self, row: tuple) -> dict:
+        # drop layout-/mode-inapplicable fields (None) for readability
+        return {k: v for k, v in zip(self.STEP_FIELDS, row) if v is not None}
+
     def dump_jsonl(self, path: str, header: dict | None = None) -> str:
-        """Write the ring as JSONL (one header line, then one line per
-        step record, then one per request record) for postmortems."""
-        snap = self.snapshot()
+        """Write the flight log as JSONL: one header line, then one line
+        per step record, then one per request record. The header counts
+        what the log's bound dropped (``dropped_steps``/``_requests``)."""
+        with self._lock:
+            rows = list(self.log_steps)
+            reqs = [dict(r) for r in self.log_requests]
+            head = {"kind": "flight_header", "ts": time.time(), "pid": os.getpid(),
+                    "recompiles": dict(self.recompiles),
+                    "steps": len(rows), "dropped_steps": self.step_count - len(rows),
+                    "requests": len(reqs), "dropped_requests": self.request_count - len(reqs)}
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
-            f.write(json.dumps({"kind": "flight_header", "ts": time.time(),
-                                "recompiles": snap["recompiles"], **(header or {})}) + "\n")
-            for rec in snap["steps"]:
-                f.write(json.dumps({"kind": "step", **rec}) + "\n")
-            for rec in snap["requests"]:
+            f.write(json.dumps({**head, **(header or {})}) + "\n")
+            for row in rows:
+                f.write(json.dumps({"kind": "step", **self._step_dict(row)}) + "\n")
+            for rec in reqs:
                 f.write(json.dumps({"kind": "request", **rec}) + "\n")
         return path
 
@@ -422,6 +527,13 @@ class EngineTelemetry:
         # flight record; the gauge shows the lifetime mean)
         self._last_preemptions = 0
         self._dumped = False
+        # the step under way: stage durations (ms, by _STAGE_IX; stage()
+        # adds, on_step records and zeroes), its start and the instant
+        # its fused program was enqueued (time.time(); the engine sets
+        # dispatch_t, None where a step dispatched nothing)
+        self._stage_ms = [0.0] * len(STAGES)
+        self._step_t0 = 0.0
+        self.dispatch_t: float | None = None
         # per-step ICI wire bytes of the fused step's collectives: a
         # one-shot jaxpr accounting turned into a LIVE series (counter
         # advanced every dispatched step). 0 on tp=1 engines; computed
@@ -502,6 +614,7 @@ class EngineTelemetry:
         span_id) joins an existing trace — the disagg decode side passes
         the context the handoff carried so ONE trace id spans replicas."""
         st.t_submit = float(submitted_at) if submitted_at is not None else time.time()
+        st.t_ingress = INGRESS_T.get()
         # latched HERE: the prefill stage consumes st.prefilled (sets it
         # None) before the slot binds, so on_bind can't tell a transferred
         # block from a local prefill anymore
@@ -571,6 +684,14 @@ class EngineTelemetry:
         self.recorder.record_request({
             "request_id": st.request_id,
             "reason": reason,
+            # the request path's boundary stamps, all time.time():
+            # ingress (serving entry, before parse/encode/admission) ->
+            # submit (engine queue) -> admit (prefill done) -> first
+            # token (engine emit) -> first/last yield (the stream's
+            # generator; stamped by on_stream once the stream ends)
+            "ingress_t": st.t_ingress,
+            "first_yield_t": None,
+            "last_yield_t": None,
             "submit_t": st.t_submit,
             "admit_t": st.t_admit,
             "first_token_t": st.t_first,
@@ -582,8 +703,11 @@ class EngineTelemetry:
             "prompt_tokens": len(st.prompt_token_ids),
             "preemptions": st.preemptions,
             "trace_id": st.trace[0] if st.trace else None,
+            "span_id": st.trace[1] if st.trace else None,
         })
         if st.trace is not None:
+            if st.t_ingress:
+                self._span(st, "llm.ingress", st.t_ingress, st.t_submit)
             if st.t_first:
                 self._span(st, "llm.decode", st.t_first, now)
             # the root span: the whole request, recorded last so child
@@ -594,6 +718,20 @@ class EngineTelemetry:
                 int(st.t_submit * 1e9), int(now * 1e9),
                 {"request_id": st.request_id, "reason": reason,
                  "tokens": len(st.token_ids), "stage": self.tags["stage"]},
+            )
+
+    def on_stream(self, request_id: str, first_yield_t: float, last_yield_t: float) -> None:
+        """The serving stream of a finished request ended: when its
+        generator yielded its first and last token chunk. Called from the
+        replica's request thread AFTER on_finish (the stream outlives the
+        engine's last token), so the stamps are added to the recorded
+        request, and the ``llm.stream`` span is built from that record."""
+        rec = self.recorder.stamp_request(request_id, first_yield_t=first_yield_t, last_yield_t=last_yield_t)
+        if rec is not None and rec["trace_id"] is not None and first_yield_t:
+            tracing.record_span(
+                "llm.stream", "internal", rec["trace_id"], uuid.uuid4().hex[:16], rec["span_id"],
+                int(first_yield_t * 1e9), int(last_yield_t * 1e9),
+                {"request_id": request_id, "stage": self.tags["stage"]},
             )
 
     def on_prefix_hit(self, tier: str, tokens: int, nbytes: int = 0) -> None:
@@ -672,12 +810,21 @@ class EngineTelemetry:
         )
 
     # -- per-step ----------------------------------------------------------
+    def begin_step(self):
+        """Head of engine.step(): stamp the step's start, -> the
+        ``llm.step`` annotation (parent of the stage spans, carrying the
+        step's number) for the step to run under."""
+        self._step_t0 = time.time()
+        return TraceAnnotation("llm.step", step=self.recorder.step_count + 1)
+
     def on_step(self, t0: float, n_admitted: int, n_emitted: int, spec_drained: tuple | None) -> None:
         """Called at the tail of engine.step() under the engine lock.
         Everything read here is host shadow state."""
         eng = self.engine
         now = time.time()
         wall_ms = (time.perf_counter() - t0) * 1e3
+        stages, dispatch_t = self._stage_ms, self.dispatch_t
+        self._stage_ms, self.dispatch_t = [0.0] * len(STAGES), None
         slots_in_use = sum(1 for s in eng._slots if s is not None)
         waiting = len(eng._waiting)
         phase = (
@@ -715,6 +862,7 @@ class EngineTelemetry:
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],
+            self._step_t0, dispatch_t, *[round(ms, 4) for ms in stages],
         ))
 
         if slots_in_use and eng._device_resident and self._wire_bytes_per_step:
@@ -742,12 +890,15 @@ class EngineTelemetry:
             except Exception:  # noqa: BLE001 — observers never break the step
                 pass
 
-    # -- postmortem --------------------------------------------------------
-    def dump_on_error(self, exc: BaseException) -> str | None:
-        """Engine died mid-step: persist the flight ring as JSONL under
-        the session dir (once — the serve stepper surfaces the SAME
-        exception to every waiter). Returns the path, or None if dumping
-        itself failed (a dying engine must still raise its real error)."""
+    # -- the flight log ----------------------------------------------------
+    def write_flight_log(self, error: BaseException | None = None) -> str | None:
+        """Persist the flight log as JSONL under the session dir
+        (``llm_flight/``), ONCE per engine life: when the replica stops
+        (LLMServer.shutdown, off the stepper thread) or, with ``error``,
+        when the engine died mid-step (the serve stepper surfaces the
+        SAME exception to every waiter). Returns the path, or None if
+        already written or if writing itself failed (a dying engine must
+        still raise its real error)."""
         if self._dumped:
             return None
         self._dumped = True
@@ -755,24 +906,66 @@ class EngineTelemetry:
             from ray_tpu.util.state import session_dir
 
             d = os.path.join(session_dir(), "llm_flight")
-            path = os.path.join(d, f"flight-{os.getpid()}-{int(time.time() * 1e3)}.jsonl")
+            path = os.path.join(d, f"flight-{os.getpid()}-{time.time_ns()}.jsonl")
             eng = self.engine
-            return self.recorder.dump_jsonl(path, header={
-                "error": f"{type(exc).__name__}: {exc}",
+            header = {
                 "tags": self.tags,
                 "kv_layout": eng.kv_layout,
                 "kv_dtype": str(eng.kv_dtype),
                 "max_num_seqs": eng.max_num_seqs,
                 "device_resident": eng._device_resident,
-            })
+            }
+            if error is not None:
+                header["error"] = f"{type(error).__name__}: {error}"
+            return self.recorder.dump_jsonl(path, header=header)
         except Exception:
+            logger.warning("flight log not written", exc_info=True)
             return None
+
+    def dump_on_error(self, exc: BaseException) -> str | None:
+        """Engine died mid-step: the postmortem is the flight log."""
+        return self.write_flight_log(error=exc)
 
     def snapshot(self) -> dict:
         snap = self.recorder.snapshot()
         snap["tags"] = dict(self.tags)
         snap["wire_bytes_per_step"] = self._wire_bytes_per_step or 0.0
         return snap
+
+
+def load_flight(pid: int | None = None) -> dict:
+    """Merge every flight log of the session (each replica process
+    writes its own under the shared session dir, like the span files
+    ``tracing.load_spans`` merges): -> {"headers", "steps", "requests"},
+    every step and request carrying the ``pid`` that wrote it. A torn
+    last line (a process killed while writing) is skipped."""
+    from ray_tpu.util.state import session_dir
+
+    d = os.path.join(session_dir(pid), "llm_flight")
+    out: dict = {"headers": [], "steps": [], "requests": []}
+    try:
+        names = sorted(os.listdir(d))
+    except OSError:
+        return out
+    for n in names:
+        writer = None
+        try:
+            with open(os.path.join(d, n)) as f:
+                for line in f:
+                    try:
+                        rec = json.loads(line)
+                    except ValueError:
+                        continue
+                    kind = rec.pop("kind", None)
+                    if kind == "flight_header":
+                        writer = rec.get("pid")
+                        out["headers"].append(rec)
+                    elif kind in ("step", "request"):
+                        rec["pid"] = writer
+                        out[kind + "s"].append(rec)
+        except OSError:
+            continue
+    return out
 
 
 # ----------------------------------------------------------------------

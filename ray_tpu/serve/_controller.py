@@ -35,6 +35,9 @@ from ray_tpu.serve._replica import Replica
 logger = logging.getLogger("ray_tpu.serve")
 
 CONTROLLER_NAME = "SERVE_CONTROLLER"
+# serve.shutdown() waits 10 s for graceful_shutdown: a replica's drain on
+# full teardown is cut at this many seconds (plus a second of slack)
+SHUTDOWN_DRAIN_CAP_S = 8.0
 
 
 @dataclass
@@ -208,15 +211,34 @@ class ServeController:
         return {"status": "RUNNING" if ok else "DEPLOYING", "deployments": deps}
 
     def graceful_shutdown(self):
+        """Stop every replica the way scale-down does (_finalize_stopping):
+        ``prepare_shutdown`` first — which reaches the deployment's
+        drain()/shutdown() hook, so a replica's last work (an LLM
+        replica's flight log, its final spans and metrics) is done —
+        then kill. All replicas at once; each gets its deployment's
+        graceful_shutdown_timeout_s, capped at SHUTDOWN_DRAIN_CAP_S so
+        that this call returns inside the wait of ``serve.shutdown()``."""
         with self._lock:
             self._shutdown = True
+            replicas, refs, wait_s = [], [], 0.0
             for ds in self._deployments.values():
+                budget = min(ds.config.graceful_shutdown_timeout_s, SHUTDOWN_DRAIN_CAP_S)
                 for r in ds.replicas:
+                    replicas.append(r)
                     try:
-                        ray_tpu.kill(r.actor)
+                        # a STOPPING replica was already asked (scale-down)
+                        refs.append(r.stop_ref or r.actor.prepare_shutdown.remote(budget))
+                        wait_s = max(wait_s, budget)
                     except Exception:
                         pass
                 ds.replicas.clear()
+        if refs:
+            ray_tpu.wait(refs, num_returns=len(refs), timeout=wait_s + 1.0)
+        for r in replicas:
+            try:
+                ray_tpu.kill(r.actor, no_restart=True)
+            except Exception:
+                pass
         return True
 
     # ------------------------------------------------------------ reconcile

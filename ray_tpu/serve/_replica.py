@@ -138,6 +138,9 @@ class Replica:
                 self._loop.call_soon_threadsafe(self._loop.stop)
             except Exception:
                 pass
+        from ray_tpu.core.worker_main import flush_observability
+
+        flush_observability()  # the controller kills this process next
         return True
 
     # -- data plane --

@@ -19,18 +19,24 @@ Llama inference on autoscaling TPU replicas).
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 from dataclasses import dataclass, field
 
 from ray_tpu import chaos
 from ray_tpu.exceptions import GetTimeoutError
+from ray_tpu.llm.telemetry import INGRESS_T, stage
 from ray_tpu.serve.overload import (
     AdmissionController,
     OverloadedError,  # noqa: F401 (re-export: the ingress's typed 429)
     ReplicaDrainingError,  # noqa: F401 (re-export)
     StepperDiedError,
 )
+
+# how long a stream's generator waits on its token queue before it looks
+# again at the stepper's health and its own deadline
+_STREAM_POLL_S = 5.0
 
 
 @dataclass
@@ -197,7 +203,8 @@ class LLMServer:
                 if plane is not None:
                     plane.maybe_heartbeat()
                 # block until a request arrives (no idle busy-poll)
-                self._work.wait(timeout=1.0)
+                with stage(self.engine._tel, "llm.stepper.wait"):
+                    self._work.wait(timeout=1.0)
                 self._work.clear()
                 continue
             try:
@@ -230,7 +237,8 @@ class LLMServer:
 
                 self._fail_all_waiters(traceback.format_exc())
                 return
-            self._deliver_outputs(outs)
+            with stage(self.engine._tel, "llm.stepper.deliver"):
+                self._deliver_outputs(outs)
 
     def _fail_all_waiters(self, reason: str) -> None:
         """The ONE failure sweep for a stepper that will never step again
@@ -416,12 +424,37 @@ class LLMServer:
         drain(), and __del__. Waiters still blocked on in-flight work
         fail fast (nothing will ever step them) instead of riding out
         their timeouts; drain() settles in-flight work FIRST, so its
-        final shutdown finds none."""
+        final shutdown finds none. With the stepper stopped, the
+        engine's flight log (llm/telemetry.py: every step and request of
+        this replica's life) is written to the session dir, once."""
         self._stop_stepper()
         with self._lock:
             pending = bool(self._events)
         if pending or self.engine.has_unfinished():
             self._fail_all_waiters("replica shut down (stepper stopped) with requests in flight")
+        if self.engine._tel is not None:
+            self.engine._tel.write_flight_log()
+
+    def profile(self, action: str, logdir: str | None = None) -> float:
+        """Operator hook: trace this replica with jax.profiler from the
+        inside (only the process that holds a chip can trace it).
+        ``profile("start", logdir)`` begins a trace — device planes plus
+        the ``llm.step.*``/``llm.stepper.*`` annotations on the same
+        clock, the Python tracer off (util/profiling.py);
+        ``profile("stop")`` ends it and writes ``logdir``. Returns
+        time.time() as the call returns, so a caller can tell how long
+        the profiler held the replica."""
+        from ray_tpu.util import profiling
+
+        if action == "start":
+            if not logdir:
+                raise ValueError("profile('start') needs a logdir")  # tpulint: disable=ERR002 — operator-API argument validation, never client-visible
+            profiling.start_trace(logdir)
+        elif action == "stop":
+            profiling.stop_trace()
+        else:
+            raise ValueError(f"profile action must be 'start' or 'stop', got {action!r}")  # tpulint: disable=ERR002 — operator-API argument validation, never client-visible
+        return time.time()
 
     def drain(self, timeout_s: float = 30.0, mode: str = "abort") -> dict:
         """Graceful drain, the replica's half of fleet failover:
@@ -689,6 +722,16 @@ class OpenAIServer(LLMServer):
 
     # -- HTTP entry --
     def __call__(self, request):
+        # the request's first stamp on this replica, before the body is
+        # parsed, the prompt encoded and admission checked: the engine's
+        # record takes it at on_submit (same thread, same context)
+        ingress = INGRESS_T.set(time.time())
+        try:
+            return self._route(request)
+        finally:
+            INGRESS_T.reset(ingress)
+
+    def _route(self, request):
         path = getattr(request, "path", "/")
         if path.endswith("/models"):
             return {"object": "list", "data": [{"id": self.model_id, "object": "model", "owned_by": "ray_tpu"}]}
@@ -729,8 +772,6 @@ class OpenAIServer(LLMServer):
         its typed OverloadedError at call time — before any stream
         machinery engages — and the proxies (which fetch the first item
         before committing the 200 header) can surface the 429."""
-        import queue as _queue
-
         from ray_tpu.llm import SamplingParams
 
         params = SamplingParams(**self._sampling(body))
@@ -740,7 +781,7 @@ class OpenAIServer(LLMServer):
         # we own the queue: a tiny request can finish (and leave the
         # engine registry) before add_request even returns, so the state
         # must never be looked up there afterwards
-        out_q = _queue.SimpleQueue()
+        out_q = queue.SimpleQueue()
         rid = self.engine.add_request(list(prompt_ids), params, out_queue=out_q)
         self._work.set()
         if self._stopped:
@@ -763,34 +804,43 @@ class OpenAIServer(LLMServer):
 
     def _stream_tokens(self, rid: str, out_q, chat: bool):
         """The generator half of _stream_completion (admission already
-        done): drain the request's token queue into SSE chunks."""
+        done): drain the request's token queue into SSE chunks. Stamps
+        the first and last token chunk it yields (two stamps a request,
+        none per token) into the request's flight record when it ends."""
         import json as _json
-        import time as _time
 
         key = "delta" if chat else "text"
         obj = "chat.completion.chunk" if chat else "text_completion"
-        deadline = _time.monotonic() + 300.0
-        while True:
-            if self._stepper_error is not None:
-                raise StepperDiedError(f"llm stepper died:\n{self._stepper_error}")
-            try:
-                tok = out_q.get(timeout=min(5.0, max(0.1, deadline - _time.monotonic())))
-            except _queue.Empty as e:
-                if _time.monotonic() > deadline:
-                    self.engine.abort_request(rid)
-                    # typed (504, retryable) and chained: GetTimeoutError
-                    # IS-A TimeoutError, so pre-taxonomy callers still match
-                    raise GetTimeoutError(f"stream {rid} produced no token for 300s") from e
-                continue
-            if tok is None:
+        deadline = time.monotonic() + 300.0
+        first_yield_t = last_yield_t = 0.0
+        try:
+            while True:
                 if self._stepper_error is not None:
                     raise StepperDiedError(f"llm stepper died:\n{self._stepper_error}")
-                break
-            piece = self._decode([tok])
-            content = {"role": "assistant", "content": piece} if chat else piece
-            yield "data: " + _json.dumps(
-                {"id": rid, "object": obj, "model": self.model_id, "choices": [{"index": 0, key: content}]}
-            ) + "\n\n"
+                try:
+                    tok = out_q.get(timeout=min(_STREAM_POLL_S, max(0.1, deadline - time.monotonic())))
+                except queue.Empty as e:
+                    if time.monotonic() > deadline:
+                        self.engine.abort_request(rid)
+                        # typed (504, retryable) and chained: GetTimeoutError
+                        # IS-A TimeoutError, so pre-taxonomy callers still match
+                        raise GetTimeoutError(f"stream {rid} produced no token for 300s") from e
+                    continue
+                if tok is None:
+                    if self._stepper_error is not None:
+                        raise StepperDiedError(f"llm stepper died:\n{self._stepper_error}")
+                    break
+                piece = self._decode([tok])
+                content = {"role": "assistant", "content": piece} if chat else piece
+                chunk = "data: " + _json.dumps(
+                    {"id": rid, "object": obj, "model": self.model_id, "choices": [{"index": 0, key: content}]}
+                ) + "\n\n"
+                last_yield_t = time.time()
+                first_yield_t = first_yield_t or last_yield_t
+                yield chunk
+        finally:
+            if self.engine._tel is not None and first_yield_t:
+                self.engine._tel.on_stream(rid, first_yield_t, last_yield_t)
         yield "data: [DONE]\n\n"
 
 

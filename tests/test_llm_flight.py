@@ -1,0 +1,387 @@
+"""PR 24's serving instrumentation: step programs that say their names, the
+stages inside a step, the flight log that holds a run and is written when
+the replica stops, the boundary stamps along a request's way, and the
+graceful ``serve.shutdown()`` that lets a replica write them.
+
+Host-side and CPU-only: nothing here times the device, and no assert is
+a speed (the 1.05x cost gate lives in tests/test_perf_smoke.py).
+"""
+
+import json
+import os
+import queue
+import threading
+import time
+
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from ray_tpu.llm import LLMEngine, SamplingParams, telemetry  # noqa: E402
+from ray_tpu.llm.model_runner import (  # noqa: E402
+    STEP_PROGRAM_NAMES,
+    make_fused_fns,
+    make_fused_paged_fns,
+    named_jit,
+)
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.serve.llm import LLMConfig, LLMServer, OpenAIServer  # noqa: E402
+
+CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
+STEP_STAGES = [f for name, f in telemetry.STAGES.items() if name.startswith("llm.step.")]
+
+
+def _engine(**kw):
+    kw.setdefault("max_num_seqs", 2)
+    kw.setdefault("max_seq_len", 128)
+    kw.setdefault("enable_prefix_caching", False)
+    return LLMEngine(CFG, **kw)
+
+
+def _server(cls=LLMServer, **engine_kwargs):
+    engine_kwargs.setdefault("max_num_seqs", 4)
+    engine_kwargs.setdefault("max_seq_len", 128)
+    return cls(LLMConfig(model_config=CFG, engine_kwargs=engine_kwargs))
+
+
+@pytest.fixture
+def session(tmp_path, monkeypatch):
+    """A session dir of this test's own: the flight log and ``load_flight`` both ask
+    ``util.state.session_dir`` at call time."""
+    from ray_tpu.util import state
+
+    monkeypatch.setattr(state, "session_dir", lambda pid=None: str(tmp_path))
+    return tmp_path
+
+
+# ------------------------------------------------------------- program names
+def _jitted(eng) -> dict:
+    """attribute -> jitted handle, of every step program the engine holds."""
+    names = ("_prefill", "_insert", "_decode", "_extend", "_fused_step", "_fused_attn", "_fused_append",
+             "_verify_step", "_verify_attn", "_verify_append")
+    return {n: getattr(eng, n) for n in names if hasattr(getattr(eng, n, None), "lower")}
+
+
+@pytest.mark.parametrize("layout,per_token", [("slots", {"llm_fused_step"}), ("paged", {"llm_fused_paged_step", "llm_kv_append"})])
+def test_step_programs_say_their_names(layout, per_token):
+    eng = _engine(kv_layout=layout)
+    fns = _jitted(eng)
+    names = {n: f.__name__ for n, f in fns.items()}
+    assert set(names.values()) <= STEP_PROGRAM_NAMES and not [v for v in names.values() if "unknown" in v]
+    # the two words the trace readers go by: one `fused` program a decode step, `prefill` on prefill alone
+    assert [v for v in names.values() if "fused" in v] == [next(iter(per_token - {"llm_kv_append"}))]
+    assert [n for n, v in names.items() if "prefill" in v] == ["_prefill"]
+    decode = {names[n] for n in ("_fused_step", "_fused_attn", "_fused_append") if n in names}
+    assert decode == per_token
+    # the lowered module, which is what the profiler's ``XLA Modules`` line and the compile cache show
+    toks = jax.numpy.zeros((1, 64), "int32")
+    assert "jit_llm_prefill" in eng._prefill.lower(eng.params, toks, jax.numpy.ones((1,), "int32")).as_text()[:400]
+    # the recompile sentinel still finds every fused entry it watched before
+    eng.generate([[1, 2, 3]], SamplingParams(max_tokens=3))
+    watched = {name for name, (fn, warm) in eng._tel.recorder._entries.items() if warm}
+    assert {n.lstrip("_") for n in names if n.startswith("_fused")} <= watched
+
+
+def test_tp_factories_name_their_programs_like_the_single_chip_ones():
+    from jax.sharding import Mesh
+
+    import numpy as np
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    assert make_fused_fns(CFG, mesh=mesh).__name__ == "llm_fused_step"
+    attn, append = make_fused_paged_fns(CFG, mesh=mesh)
+    assert (attn.__name__, append.__name__) == ("llm_fused_paged_step", "llm_kv_append")
+    with pytest.raises(ValueError, match="not a documented step program name"):
+        named_jit("llm_mystery", lambda x: x)
+
+
+def test_speculative_programs_are_named_too():
+    from ray_tpu.llm.spec import SpecConfig
+
+    eng = _engine(speculative=SpecConfig(k=2))
+    names = {f.__name__ for f in _jitted(eng).values()} | {eng._drafter._propose.__name__}
+    assert {"llm_verify_step", "llm_draft_propose"} <= names <= STEP_PROGRAM_NAMES
+
+
+# ------------------------------------------------------------------- stages
+def test_stages_sum_to_the_step_and_drain_wait_is_the_device(monkeypatch):
+    eng = _engine()
+    eng.generate([[1, 2, 3]], SamplingParams(max_tokens=2))  # compile
+    real = eng._drain_wait
+
+    def slow_device(pending):
+        if pending is not None:
+            time.sleep(0.02)  # the device takes 20 ms a step: the readback blocks that long
+        return real(pending)
+
+    monkeypatch.setattr(eng, "_drain_wait", slow_device)
+    eng.generate([[4, 5, 6, 7], [8, 9]], SamplingParams(max_tokens=8))
+    steps = [s for s in eng.telemetry()["steps"] if s["wall_ms"] >= 20.0]
+    assert len(steps) >= 6
+    for s in steps:
+        assert sum(s[f] for f in STEP_STAGES) == pytest.approx(s["wall_ms"], rel=0.05)
+        assert s["t0"] <= s["t"] and s["t"] - s["t0"] == pytest.approx(s["wall_ms"] * 1e-3, abs=2e-3)
+    decode = [s for s in steps if s["phase"] == "decode"]
+    assert decode and all(s["drain_wait_ms"] > 0.8 * s["wall_ms"] for s in decode)
+    # the fused program was enqueued inside the step, before the host went to wait for the last one
+    assert all(s["t0"] <= s["dispatch_t"] <= s["t"] - 0.015 for s in decode if s["batch"])
+    admitting = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+    assert admitting and all(s["prefill_ms"] > 0 for s in admitting)
+
+
+def test_an_uninstrumented_engine_steps_through_the_same_code():
+    eng = _engine(telemetry=False)
+    out = eng.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
+    assert len(out[0].token_ids) == 4 and eng.telemetry() == {}
+
+
+def test_stepper_stages_land_on_the_row_of_the_step_that_follows():
+    srv = _server()
+    try:
+        time.sleep(0.15)  # the stepper idles, blocked on _work
+        srv.generate([1, 2, 3], {"max_tokens": 4})
+        steps = srv.telemetry()["steps"][-5:]
+        first = next(s for s in steps if s.get("admitted"))
+        assert first["stepper_wait_ms"] >= 100.0
+        assert all(s["stepper_deliver_ms"] >= 0.0 and s["stepper_wait_ms"] == 0.0 for s in steps if s["step"] > first["step"])
+    finally:
+        srv.shutdown()
+
+
+# --------------------------------------------------------------- the flight log
+def test_the_log_holds_a_run_whole_while_the_rings_stay_short(session):
+    srv = _server()
+    srv.generate([1, 2, 3], {"max_tokens": 6})
+    n0 = srv.telemetry()["step_count"]
+    for _ in range(3000):  # the stepper idles (nothing unfinished); each of these is a recorded step
+        srv.engine.step()
+    for i in range(3):
+        srv.generate([4 + i, 5, 6], {"max_tokens": 3})
+    assert not os.listdir(session), "nothing is written while requests are served"
+    srv.shutdown()
+    snap = srv.telemetry()
+    assert len(snap["steps"]) == 512 and snap["step_count"] >= n0 + 3000
+    log = telemetry.load_flight()
+    (header,) = log["headers"]
+    assert header["pid"] == os.getpid() and header["dropped_steps"] == 0 and header["dropped_requests"] == 0
+    assert [s["step"] for s in log["steps"]] == list(range(1, snap["step_count"] + 1))
+    assert header["steps"] == len(log["steps"]) and "error" not in header
+    # the prewarm's two requests, then the four of this test
+    assert [r["request_id"] for r in log["requests"]][-4:] == [r["request_id"] for r in snap["requests"]][-4:]
+    assert all(set(telemetry.STAGES.values()) <= set(s) and s["pid"] == os.getpid() for s in log["steps"])
+    srv.shutdown()  # written once
+    assert len(os.listdir(session / "llm_flight")) == 1
+
+
+def test_the_logs_bound_drops_the_oldest_and_says_how_many(session, monkeypatch):
+    monkeypatch.setattr(telemetry.FlightRecorder, "LOG_STEPS", 100)
+    monkeypatch.setattr(telemetry.FlightRecorder, "LOG_REQUESTS", 2)
+    eng = _engine()
+    for i in range(4):
+        eng.generate([[1 + i, 2, 3]], SamplingParams(max_tokens=2))
+    for _ in range(150):
+        eng.step()
+    assert eng._tel.write_flight_log() is not None
+    (header,) = telemetry.load_flight()["headers"]
+    total = eng.telemetry()["step_count"]
+    assert (header["steps"], header["dropped_steps"]) == (100, total - 100)
+    assert (header["requests"], header["dropped_requests"]) == (2, 2)
+
+
+def test_the_logs_memory_stays_under_its_stated_bound():
+    """12,000 step rows and 2,000 requests of 150 tokens: the bound PERF.md states (20 MB)."""
+    import tracemalloc
+
+    rec = telemetry.FlightRecorder()
+    floats = {"t", "wall_ms", "t0", "dispatch_t", *telemetry.STAGES.values()}  # the rest are counts, a phase, or None
+    tracemalloc.start()
+    for i in range(rec.LOG_STEPS):
+        rec.record_step(tuple(1.5 + i + k if f in floats else "decode" if f == "phase" else 12
+                              for k, f in enumerate(rec.STEP_FIELDS[1:])))
+    for i in range(rec.LOG_REQUESTS):
+        rec.record_request({"request_id": f"req-{i}", "submit_t": 1.5 + i, "itl_s": [0.06 + i * 1e-9 + k * 1e-9 for k in range(150)],
+                            **{k: 2.5 + i for k in ("ingress_t", "admit_t", "first_token_t", "finish_t", "first_yield_t", "last_yield_t")}})
+    held, _ = tracemalloc.get_traced_memory()
+    tracemalloc.stop()
+    assert len(rec.log_steps) == 12_000 and len(rec.log_requests) == 2_000
+    assert held < 20e6, f"the flight log holds {held / 1e6:.1f} MB"
+
+
+def test_load_flight_merges_two_processes_and_skips_a_torn_last_line(session):
+    pad = (None,) * (len(telemetry.FlightRecorder.STEP_FIELDS) - 3)
+    for pid, n in ((111, 3), (222, 2)):
+        rec = telemetry.FlightRecorder()
+        for i in range(n):
+            rec.record_step((100.0 * pid + i, "decode") + pad)
+            rec.record_request({"request_id": f"req-{i}", "submit_t": 100.0 * pid + i})
+        rec.dump_jsonl(str(session / "llm_flight" / f"flight-{pid}-1.jsonl"), header={"pid": pid})
+    with open(session / "llm_flight" / "flight-222-1.jsonl", "a") as f:
+        f.write('{"kind": "step", "step": 3, "t": 2')  # the process died here
+    (session / "llm_flight" / "flight-333-1.jsonl").write_text("")
+    log = telemetry.load_flight()
+    assert [h["pid"] for h in log["headers"]] == [111, 222]
+    assert [(s["pid"], s["step"]) for s in log["steps"]] == [(111, 1), (111, 2), (111, 3), (222, 1), (222, 2)]
+    assert [(r["pid"], r["request_id"]) for r in log["requests"]] == [(111, "req-0"), (111, "req-1"), (111, "req-2"), (222, "req-0"), (222, "req-1")]
+
+
+def test_an_engine_error_writes_the_same_log_once(session):
+    eng = _engine()
+    eng.generate([[1, 2, 3]], SamplingParams(max_tokens=2))
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected")
+
+    eng._fused_step = boom
+    eng.add_request([4, 5, 6], SamplingParams(max_tokens=4))
+    with pytest.raises(RuntimeError, match="injected"):
+        while eng.has_unfinished():
+            eng.step()
+    log = telemetry.load_flight()
+    assert "injected" in log["headers"][0]["error"] and log["steps"] and log["requests"]
+    assert eng._tel.write_flight_log() is None
+
+
+# ----------------------------------------------------------- the request path
+def test_boundary_stamps_of_a_streamed_request_are_in_order(session):
+    srv = _server(OpenAIServer)
+    try:
+        chunks = list(srv({"prompt": [1, 2, 3, 4], "max_tokens": 6, "stream": True}))
+        assert len(chunks) == 7 and chunks[-1].startswith("data: [DONE]")
+        unary = srv({"prompt": [5, 6, 7], "max_tokens": 3})
+        recs = {r["request_id"]: r for r in srv.telemetry()["requests"]}
+        rid = json.loads(chunks[0][6:])["id"]
+        r = recs[rid]
+        order = [r[k] for k in ("ingress_t", "submit_t", "admit_t", "first_token_t", "first_yield_t", "last_yield_t")]
+        assert all(order) and order == sorted(order)
+        assert r["finish_t"] <= r["last_yield_t"]  # the stream outlives the engine's last token
+        # the engine's per-token emit times are in the record already: first_token_t plus the running sum of itl_s
+        assert r["first_token_t"] + sum(r["itl_s"]) <= r["last_yield_t"] and len(r["itl_s"]) == 5
+        u = recs[unary["id"]]
+        assert u["ingress_t"] <= u["submit_t"] and u["first_yield_t"] is None
+        # the ingress stamp belongs to its request: a later call that is no request of this ingress has none
+        direct = srv.generate([8, 9], {"max_tokens": 2})
+        assert {x["request_id"]: x for x in srv.telemetry()["requests"]}[direct["request_id"]]["ingress_t"] is None
+    finally:
+        srv.shutdown()
+    assert rid in {x["request_id"] for x in telemetry.load_flight()["requests"]}
+
+
+def test_a_starved_stream_goes_on_past_its_poll(monkeypatch):
+    """``_stream_tokens`` named ``_queue.Empty`` with ``_queue`` imported in another function: a stream
+    that waited a whole poll (5 s) for a token died with NameError (34 of 34 requests at 2 req/s, PR 23)."""
+    from ray_tpu.serve import llm as serve_llm
+
+    monkeypatch.setattr(serve_llm, "_STREAM_POLL_S", 0.05)
+    srv = _server(OpenAIServer)
+    try:
+        q = queue.SimpleQueue()
+
+        def late():
+            time.sleep(0.4)  # eight polls with nothing to take
+            q.put(17)
+            q.put(None)
+
+        t = threading.Thread(target=late)
+        t.start()
+        chunks = list(srv._stream_tokens("req-starved", q, chat=False))
+        t.join(timeout=5)
+        assert not t.is_alive() and len(chunks) == 2 and json.loads(chunks[0][6:])["choices"][0]["text"] == [17]
+    finally:
+        srv.shutdown()
+
+
+def test_request_spans_gain_ingress_and_stream_under_tracing(session):
+    from ray_tpu.util import tracing
+
+    tracing.configure(True)
+    srv = _server(OpenAIServer)
+    try:
+        chunks = list(srv({"prompt": [1, 2, 3], "max_tokens": 4, "stream": True}))
+        rid = json.loads(chunks[0][6:])["id"]
+    finally:
+        srv.shutdown()
+        tracing.shutdown()
+        tracing.configure(False)
+    spans = [s for s in tracing.load_spans() if s["attrs"].get("request_id") == rid]
+    by = {s["name"]: s for s in spans}
+    assert {"llm.request", "llm.ingress", "llm.admission", "llm.prefill", "llm.decode", "llm.stream"} <= set(by)
+    root = by["llm.request"]
+    assert all(s["parent_id"] == root["span_id"] and s["trace_id"] == root["trace_id"] for n, s in by.items() if n != "llm.request")
+    rec = next(r for r in telemetry.load_flight()["requests"] if r["request_id"] == rid)
+    # no second source of time: the spans are the record's stamps
+    assert by["llm.ingress"]["start_ns"] == int(rec["ingress_t"] * 1e9) and by["llm.ingress"]["end_ns"] == int(rec["submit_t"] * 1e9)
+    assert by["llm.stream"]["start_ns"] == int(rec["first_yield_t"] * 1e9) and by["llm.stream"]["end_ns"] == int(rec["last_yield_t"] * 1e9)
+
+
+# ------------------------------------------------------------- the profiler hook
+def test_profile_hook_records_the_stage_annotations(tmp_path):
+    from jax.profiler import ProfileData
+
+    from benchmark import xplane
+
+    srv = _server()
+    try:
+        t0 = time.time()
+        assert srv.profile("start", str(tmp_path)) >= t0
+        srv.generate([1, 2, 3], {"max_tokens": 4})
+        srv.profile("stop")
+        with pytest.raises(ValueError):
+            srv.profile("pause")
+    finally:
+        srv.shutdown()
+    names = {ev.name for plane in ProfileData.from_file(xplane.find_xplane(str(tmp_path))).planes
+             for line in plane.lines for ev in line.events if ev.name.startswith("llm.")}
+    assert {"llm.step", "llm.step.prefill", "llm.step.dispatch", "llm.step.drain_wait", "llm.stepper.deliver"} <= names
+    assert not hasattr(__import__("ray_tpu.util.profiling", fromlist=["x"]), "WallProfiler")
+
+
+# ------------------------------------------------------------ serve.shutdown
+def test_serve_shutdown_stops_replicas_before_it_kills_them(tmp_path):
+    """``graceful_shutdown`` shot every replica (``ray_tpu.kill`` is SIGTERM, no handler), so neither a
+    deployment's shutdown hook nor an LLM replica's last spans and flight log survived ``serve.shutdown()``."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import build_openai_app
+    from ray_tpu.util import tracing
+
+    marker = str(tmp_path / "stopped")
+
+    @serve.deployment
+    class WithHook:
+        def __call__(self, x):
+            return x
+
+        def shutdown(self):
+            with open(marker, "w") as f:
+                f.write("clean")
+
+    ray_tpu.shutdown()
+    os.environ["RT_TRACING"] = "1"
+    tracing.configure(True)
+    try:
+        ray_tpu.init(num_cpus=4)
+        hook = serve.run(WithHook.bind(), name="hook_app", route_prefix="/hook")
+        assert hook.remote(1).result(timeout_s=60) == 1
+        app = build_openai_app(LLMConfig(model_config=CFG, engine_kwargs={"max_num_seqs": 4, "max_seq_len": 128}))
+        h = serve.run(app, name="oai", route_prefix="/v1", blocking_timeout_s=240.0)
+        rids = []
+        for i in range(3):
+            chunks = list(h.options(stream=True).remote({"prompt": [1 + i, 2, 3], "max_tokens": 4, "stream": True}))
+            assert chunks[-1].startswith("data: [DONE]") and len(chunks) == 5
+            rids.append(json.loads(chunks[0][6:])["id"])
+        t0 = time.time()
+        serve.shutdown()
+        assert time.time() - t0 < 10.0
+        assert open(marker).read() == "clean"
+        log = telemetry.load_flight()
+        served = {r["request_id"]: r for r in log["requests"]}
+        assert set(rids) <= set(served) and log["headers"][-1]["pid"] != os.getpid()
+        assert all(served[r]["ingress_t"] and served[r]["last_yield_t"] for r in rids)
+        # the worker's span file: the last request's spans, the stream's among them, reached the disk
+        names = {s["name"] for s in tracing.load_spans() if s["attrs"].get("request_id") == rids[-1]}
+        assert {"llm.request", "llm.ingress", "llm.stream"} <= names
+    finally:
+        os.environ.pop("RT_TRACING", None)
+        tracing.configure(False)
+        serve.shutdown()
+        ray_tpu.shutdown()

@@ -123,7 +123,8 @@ def test_engine_error_dumps_flight_jsonl():
         while eng.has_unfinished():
             eng.step()
     d = os.path.join(session_dir(), "llm_flight")
-    dumps = sorted(os.listdir(d))
+    # this process's: serve replicas of the session write their logs here too
+    dumps = sorted(n for n in os.listdir(d) if n.startswith(f"flight-{os.getpid()}-"))
     assert dumps, "engine error produced no flight dump"
     lines = [json.loads(ln) for ln in open(os.path.join(d, dumps[-1])) if ln.strip()]
     header = lines[0]
