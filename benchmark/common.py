@@ -1,5 +1,5 @@
-"""What every part of the harness shares: where the files are, how a cell's names resolve to
-its files, and how a configuration file becomes the program's model config."""
+"""What every part of the harness shares: where the files are, and how a cell's names resolve to
+its files: its configuration, its model family, its metrics' readers."""
 
 from __future__ import annotations
 
@@ -9,10 +9,6 @@ import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-
-# sizes of the CPU rehearsal (--rehearse): wiring only, never a measurement
-REHEARSAL_SIZES = {"hidden_size": 128, "intermediate_size": 256, "num_hidden_layers": 2,
-                   "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 512}
 
 
 def load_benchmark() -> dict:
@@ -29,6 +25,8 @@ def resolve_cell(bench: dict, workload: str) -> dict:
     entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     with open(os.path.join(ROOT, entry["file"])) as f:
         config = json.load(f)
+    if "family" not in config:
+        raise SystemExit(f"{entry['file']} names no \"family\": benchmark/families/<family>.py has the model's block and its plain reference")
 
     def mine(m):
         return "workloads" not in m or workload in m["workloads"]
@@ -37,18 +35,19 @@ def resolve_cell(bench: dict, workload: str) -> dict:
             "metrics": {k: [m for m in bench[k] if mine(m)] for k in ("end_to_end", "per_layer")}}
 
 
-def llama_kwargs(c: dict, max_seq_len: int, **extra) -> dict:
-    """The program's ``LlamaConfig`` keywords for a configuration file's published keys."""
-    return dict(vocab_size=c["vocab_size"], hidden_size=c["hidden_size"], intermediate_size=c["intermediate_size"],
-                num_layers=c["num_hidden_layers"], num_heads=c["num_attention_heads"],
-                num_kv_heads=c["num_key_value_heads"], head_dim=c.get("head_dim"), max_seq_len=max_seq_len,
-                rope_theta=float(c["rope_theta"]), rms_eps=float(c["rms_norm_eps"]),
-                tie_embeddings=bool(c["tie_word_embeddings"]),
-                dtype={"bfloat16": "bfloat16", "float32": "float32"}[c.get("torch_dtype", "bfloat16")], **extra)
+def load_family(name: str):
+    """The model family a configuration file names: the module ``benchmark/families/<name>.py``
+    (``benchmark/families/__init__.py`` says what it defines). Imported by that name, so that a
+    worker process finds the same module and what it defines pickles by reference."""
+    from benchmark.families import NAMES
 
-
-def rehearsal_config(c: dict) -> dict:
-    return {**c, **REHEARSAL_SIZES, "torch_dtype": "float32"}
+    if not os.path.exists(os.path.join(HERE, "families", name + ".py")):
+        raise SystemExit(f"no family {name!r}: benchmark/families/{name}.py is not there")
+    family = importlib.import_module("benchmark.families." + name)
+    missing = [n for n in NAMES if not callable(getattr(family, n, None))]
+    if missing:
+        raise SystemExit(f"benchmark/families/{name}.py lacks {missing}")
+    return family
 
 
 def load_reader(metric: str):

@@ -1,20 +1,12 @@
-"""Operations and bytes a call needs, computed from shapes. The yardstick's half of every
-utilisation and roofline share: the program supplies only the time.
+"""Operations and bytes a kernel's call needs, computed from shapes. The yardstick's half of every
+roofline share: the program supplies only the time. What a whole block needs for a trained token
+is its family's to count (``benchmark/families/<family>.py::train_flops_per_token``).
 
 Sizes come from a configuration file's published keys (``hidden_size`` ...), never from the
 program's own config object.
 """
 
 from __future__ import annotations
-
-
-def matmul_params(c: dict) -> int:
-    """Parameters that take part in a matrix multiplication for every token: the blocks and the
-    output head. The embedding table is a lookup, not a matmul, and is left out."""
-    h, i, L = c["hidden_size"], c["intermediate_size"], c["num_hidden_layers"]
-    hd = c.get("head_dim") or h // c["num_attention_heads"]
-    q, kv = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
-    return L * (h * q + 2 * h * kv + q * h + 3 * h * i) + h * c["vocab_size"]
 
 
 def attention_flops_fwd(c: dict, batch: int, seq: int, causal: bool = True) -> float:
@@ -24,13 +16,6 @@ def attention_flops_fwd(c: dict, batch: int, seq: int, causal: bool = True) -> f
     hd = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
     f = 4.0 * batch * c["num_attention_heads"] * seq * seq * hd
     return f / 2 if causal else f
-
-
-def train_flops_per_token(c: dict, seq: int) -> float:
-    """FLOPs the forward and backward passes require per trained token: 6 per matmul parameter
-    plus three times the causal attention forward. Recomputation (remat) is not counted."""
-    attn = 3.0 * c["num_hidden_layers"] * attention_flops_fwd(c, 1, seq) / seq
-    return 6.0 * matmul_params(c) + attn
 
 
 def flash_roofline(c: dict, batch: int, seq: int, peaks: dict, itemsize: int = 2) -> dict:

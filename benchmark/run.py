@@ -10,6 +10,10 @@ one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
 ``breakdown``. With ``--trace 0`` the metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics. Everything else worth reading is on earlier lines.
 
+A run ends when its processes have (``benchmark/reaper.py``): once the driver has returned, and
+before the result line, every process that inherited this run's marker is waited for, killed if
+it stays, and counted on a ``[run] processes`` line. A failed or interrupted run does the same.
+
 This process never initialises a JAX backend: the replica or train worker the scheduler binds is
 the only holder of the chip. No chip, fewer chips than the cell asks for, or a ``device_kind``
 that ``benchmark/peaks.py`` does not list: the run fails and prints no result.
@@ -38,6 +42,24 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)  # workers inherit sys.path and import benchmark.* by name
 
 
+def end_of_run(reaper, t_down: float) -> None:
+    """The driver has returned, and its last act was ``ray_tpu.shutdown()``, which leaves the
+    multiprocessing forkserver and resource tracker to an exit hook and joins each worker for a
+    second. Stop the two helpers now, by the program's own hook, so that what is left is only what
+    should not be; then wait for everything that carries the run's marker, and say what there was."""
+    from benchmark.reaper import WAIT_S
+    from ray_tpu.core.node import stop_forkserver
+
+    found = reaper.close()
+    stop_forkserver()
+    r = reaper.reap(found, t_down)
+    print(f"[run] processes: {r['found']} of the {r['ever']} that carried this run's marker were alive when the driver "
+          f"returned; the last was gone {r['outlived_s']:.2f} s after that; SIGKILL after {WAIT_S:.0f} s to {len(r['killed'])} "
+          f"{r['killed']}; they were {r['who']}", flush=True)
+    if r["left"]:
+        raise SystemExit(f"process(es) {r['left']} of this run would not end: no result")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -55,6 +77,7 @@ def main() -> int:
         print(f"the program is not here: {e}", file=sys.stderr)
         return 2
     from benchmark import common, xplane
+    from benchmark.reaper import Reaper
 
     # the program's own helper (util/compile_cache.py) places the persistent compile cache: where
     # JAX_COMPILATION_CACHE_DIR says, else at one fixed path in the checkout. Here only: cache every
@@ -79,11 +102,16 @@ def main() -> int:
         from benchmark import train_cell as driver
     else:
         raise SystemExit(f"traffic kind {kind!r} has no driver")
-    if a.sweep:
-        if kind != "serve":
-            raise SystemExit("--sweep is for the open loop of a serving cell")
-        return driver.sweep(a, cell)
-    res = driver.run(a, cell, T_PROC0)
+    if a.sweep and kind != "serve":
+        raise SystemExit("--sweep is for the open loop of a serving cell")
+    reaper = Reaper()
+    reaper.start()  # before the runtime: the forkserver and every worker inherit the marker
+    try:
+        if a.sweep:
+            return driver.sweep(a, cell)
+        res = driver.run(a, cell, T_PROC0)
+    finally:
+        end_of_run(reaper, time.time())
 
     dev = res["device"]
     on_tpu = dev["platform"] == "tpu"
@@ -95,6 +123,8 @@ def main() -> int:
         res["obs"]["peaks"] = peaks_of(dev["kind"])
     per_layer = common.read_per_layer(per_layer_names, res["obs"])  # off the TPU: wiring only, never printed as metrics
     e2e = {m["name"]: res["end_to_end"][m["name"]] for m in cell["metrics"]["end_to_end"] if m["name"] in res["end_to_end"]}
+    compared = "[run] compared: " + "; ".join(f"{what}: {value} (limit {limit})" for what, value, limit in res["compared"])
+    print(compared, flush=True)
     print(f"[run] end to end: {json.dumps(res['end_to_end'])}", flush=True)
     print(f"[run] per layer: {json.dumps(per_layer)}", flush=True)
     trace = res.get("trace") or {}
@@ -117,6 +147,7 @@ def main() -> int:
     if a.trace and trace.get("window_s"):
         line["breakdown"] = {"device_ops": xplane.top(trace["ops"], 10), "idle_gaps": trace.get("idle_gaps", [])[:10]}
     print(json.dumps(line), flush=True)
+    print(compared, file=sys.stderr, flush=True)  # each number compared beside its limit, as standard error's last line too
     return 0
 
 

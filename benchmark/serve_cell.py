@@ -50,6 +50,13 @@ def default_buckets(max_seq_len: int) -> list[int]:
     return out + [max_seq_len]
 
 
+def engine_kwargs(sv: dict, seed: int) -> dict:
+    """The engine's keywords: what the configuration's ``serving`` block says under ``engine_kwargs``
+    (a cache layout, the sizing of a second kind of state), and beside it the seed and the sizes
+    every serving cell states."""
+    return {**sv.get("engine_kwargs", {}), "seed": seed, "max_num_seqs": sv["max_num_seqs"], "max_seq_len": sv["max_seq_len"]}
+
+
 class Client:
     """Sends one request through the streaming handle and stamps every token it receives."""
 
@@ -120,13 +127,13 @@ def deployed(a, cell: dict):
     """The runtime up, ``BenchServer`` deployed and warm: yields (handle, info, config, mix, requests plan)."""
     import ray_tpu
     from ray_tpu import serve
-    from ray_tpu.models.llama import LlamaConfig
     from ray_tpu.serve.llm import LLMConfig
 
     from benchmark.serve_worker import BenchServer
 
     name, chips = cell["cell"]["name"], int(cell["cell"]["chips"])
-    config = common.rehearsal_config(cell["config"]) if a.rehearse else cell["config"]
+    family = common.load_family(cell["config"]["family"])
+    config = family.rehearsal(cell["config"]) if a.rehearse else cell["config"]
     mix = traffic.load_mix(cell["cell"]["traffic"], name)
     sv = dict(cell["config"]["serving"])
     if a.rehearse:
@@ -145,11 +152,11 @@ def deployed(a, cell: dict):
             raise SystemExit(f"cell {name} needs {chips} TPU chip(s); the runtime found {have}")
         on_tpu = have >= chips
         llm = LLMConfig(
-            model_config=LlamaConfig(**common.llama_kwargs(config, sv["max_seq_len"], remat=False)),
-            engine_kwargs={"seed": a.seed % (2**31), "max_num_seqs": sv["max_num_seqs"], "max_seq_len": sv["max_seq_len"]},
+            model_config=family.program_config(config, sv["max_seq_len"], remat=False),
+            engine_kwargs=engine_kwargs(sv, a.seed % (2**31)),
             tensor_parallel_size=tp, max_ongoing_requests=sv["max_ongoing_requests"],
             model_id=cell["cell"]["config"])
-        bench = {"seed": a.seed % (2**31), "warm": warm_plan(mix, default_buckets(sv["max_seq_len"])),
+        bench = {"seed": a.seed % (2**31), "family": config["family"], "warm": warm_plan(mix, default_buckets(sv["max_seq_len"])),
                  "warm_batch_max": sv["warm_batch_max"]}
         opts = {"name": "BenchServer", "max_ongoing_requests": sv["max_ongoing_requests"], "num_replicas": 1,
                 # construction compiles every warm shape; the controller must not replace the replica meanwhile
@@ -171,7 +178,7 @@ def deployed(a, cell: dict):
                 raise SystemExit(f"the replica was not up after 1100 s: {st}")
             time.sleep(0.5)
         info = h.bench_info.remote().result(timeout_s=120)
-        say(f"replica up in {time.time() - t_up:.1f}s (weights {info['weights_s']:.1f}s, engine+warm-up {info['init_s']:.1f}s, "
+        say(f"family {config['family']}; replica up in {time.time() - t_up:.1f}s (weights {info['weights_s']:.1f}s, engine+warm-up {info['init_s']:.1f}s, "
             f"warm-up {info['warm']}); device {info['device']}; weights {info['weights_bytes'] / 1e9:.2f} GB; "
             f"kv {info['kv']['layout']}/{info['kv']['dtype']} {info['kv']['bytes_per_token']} B/token, "
             f"{info['kv']['allocated_bytes'] / 1e9:.2f} GB for {info['kv']['slots_total']} x {sv['max_seq_len']}; buckets {info['prefill_buckets']}")
@@ -276,8 +283,11 @@ def run(a, cell: dict, t_proc0: float) -> dict:
     per_request = [{k: r[k] for k in ("index", "rid", "due", "sent", "done", "error", "prompt_tokens", "max_tokens")}
                    | {"first": r["stamps"][0] if r["stamps"] else None, "engine": worker["requests"].get(r["rid"])}
                    for r in records]
+    compared = [["served logprob, max |served - reference|", ref.get("max_abs_dlogprob"), ref.get("tolerance")],
+                ["greedy token, max gap below the reference's best", ref.get("max_margin"), ref.get("tolerance")],
+                ["requests of the window that failed", summary["failed"], f"under {summary['attempted']} attempted"]]
     return {"correct": correct, "attempted": summary["attempted"], "failed": summary["failed"], "end_to_end": e2e,
-            "requests": per_request,
+            "requests": per_request, "compared": compared,
             "obs": obs, "device": dev, "memory_peak_bytes": worker["memory_peak_bytes"], "trace": worker.get("trace")}
 
 

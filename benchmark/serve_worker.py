@@ -40,12 +40,12 @@ class BenchServer(OpenAIServer):
         import jax
 
         t0 = time.time()
+        self._family = common.load_family(self._bench["family"])
         if int(llm_config.tensor_parallel_size or 1) == 1 and llm_config.params is None:
-            from ray_tpu.models.llama import init_params
             from ray_tpu.util.compile_cache import enable_compile_cache
 
             enable_compile_cache()
-            cfg = llm_config.model_config
+            cfg, init_params = llm_config.model_config, self._family.init_params
             # one jitted call from the seed, in the dtype served; a tp engine does the same itself, sharded
             llm_config.params = jax.jit(lambda k: init_params(cfg, k))(
                 jax.random.PRNGKey(int(llm_config.engine_kwargs.get("seed", 0))))
@@ -174,13 +174,11 @@ class BenchServer(OpenAIServer):
             served = list(pool.map(serve_one, samples))
         params = dict(self.engine.params)
         if sabotage:
-            from ray_tpu.models.llama import init_params
-
-            cfg = self.engine.config
+            cfg, init_params = self.engine.config, self._family.init_params
             seed = int(self._bench["seed"]) + 1
             params["embed"] = jax.jit(lambda k: init_params(cfg, k)["embed"])(jax.random.PRNGKey(seed))
         t0 = time.time()
-        res = reference.check_served(params, config, served, tol)
+        res = reference.check_served(self._family.reference_logprobs, params, config, served, tol)
         res["seconds"] = time.time() - t0
         res["lengths"] = [[len(s["prompt"]), len(s["tokens"])] for s in served]
         return res

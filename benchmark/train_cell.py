@@ -8,7 +8,7 @@ import json
 import os
 import time
 
-from benchmark import common, flops, traffic
+from benchmark import common, traffic
 from benchmark.peaks import peaks_of
 
 
@@ -27,21 +27,22 @@ def train_loop(config: dict):
 
     from benchmark import reference, stats, xplane
     from ray_tpu import train
-    from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn, param_logical_axes
     from ray_tpu.parallel.mesh import create_mesh
     from ray_tpu.parallel.train_step import make_train_step, shard_batch
 
     c, mix, seed, seconds = config["config"], config["mix"], config["seed"], config["seconds"]
+    family = common.load_family(c["family"])
     compiles = common.record_lowerings()
     batch, seq = int(mix["global_batch"]), int(mix["seq_len"])
-    cfg = LlamaConfig(**common.llama_kwargs(c, seq, **config["model_extra"]))
+    cfg = family.program_config(c, seq, **config["model_extra"])
     devs = jax.devices()
     mesh = create_mesh(dp=len(devs))
     opt = mix["optimizer"]
     tx = {"adamw": optax.adamw}[opt["name"]](opt["learning_rate"], weight_decay=opt["weight_decay"])
     t_build = time.time()
-    init_fn, compile_step, _ = make_train_step(partial(loss_fn, config=cfg, mesh=mesh), tx, mesh, param_logical_axes(cfg))
-    state, shardings = init_fn(jax.random.PRNGKey(seed), partial(init_params, cfg))
+    init_fn, compile_step, _ = make_train_step(partial(family.loss_fn, config=cfg, mesh=mesh), tx, mesh,
+                                               family.param_logical_axes(cfg))
+    state, shardings = init_fn(jax.random.PRNGKey(seed), partial(family.init_params, cfg))
     step = compile_step(shardings)
 
     def host_batch(i):
@@ -50,7 +51,7 @@ def train_loop(config: dict):
     def ref_params(params):
         if not config["sabotage"]:
             return params
-        return {**params, "embed": jax.jit(lambda k: init_params(cfg, k)["embed"])(jax.random.PRNGKey(seed + 1))}
+        return {**params, "embed": jax.jit(lambda k: family.init_params(cfg, k)["embed"])(jax.random.PRNGKey(seed + 1))}
 
     losses, i = [], 0
     for _ in range(int(mix["warmup_steps"])):
@@ -60,7 +61,7 @@ def train_loop(config: dict):
     build_s = time.time() - t_build
     first = host_batch(i)
     t_ref = time.time()
-    ref_first = reference.loss(ref_params(state.params), first, c)
+    ref_first = reference.loss(family.reference_logprobs, ref_params(state.params), first, c)
     ref_s = time.time() - t_ref
 
     trace_dir, traced, trace_host, spans = config.get("trace_dir"), None, [0.0, 0.0], []
@@ -95,10 +96,11 @@ def train_loop(config: dict):
     in_window = [x for x in compiles if t0 <= x[0] < ends[-1]]
 
     last = host_batch(i)
-    ref_last = reference.loss(ref_params(state.params), last, c)
+    ref_last = reference.loss(family.reference_logprobs, ref_params(state.params), last, c)
     state, m = step(state, shard_batch(last, mesh))
     loss_last = float(m["loss"])
     mem = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in jax.local_devices())
+    lowered = step.lower(state, shard_batch(last, mesh)).as_text()
     out = {
         "device": {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)},
         "t0": t0, "step_ends": ends, "summary": stats.train_summary(ends, t0, batch * seq),
@@ -106,7 +108,7 @@ def train_loop(config: dict):
         "loss_last": loss_last, "ref_last": ref_last, "losses_finite": all(x == x and abs(x) != float("inf") for x in window_losses),
         "build_s": build_s, "reference_s": ref_s, "memory_peak_bytes": mem,
         "compiles_in_window": len(in_window), "compiled_in_window": sorted({x[1] for x in in_window})[:20],
-        "kernel_in_program": "tpu_custom_call" in step.lower(state, shard_batch(last, mesh)).as_text(),
+        "kernels_missing": [name for name, marker in family.kernels_expected(c).items() if marker not in lowered],
     }
     if trace_dir:
         out["trace"] = xplane.reduce_trace_dir(trace_dir, spans, trace_host[0])
@@ -120,7 +122,8 @@ def run(a, cell: dict, t_proc0: float) -> dict:
     from ray_tpu.train import RunConfig, ScalingConfig
 
     name, chips = cell["cell"]["name"], int(cell["cell"]["chips"])
-    config = common.rehearsal_config(cell["config"]) if a.rehearse else cell["config"]
+    family = common.load_family(cell["config"]["family"])
+    config = family.rehearsal(cell["config"]) if a.rehearse else cell["config"]
     mix = traffic.load_mix(cell["cell"]["traffic"], name)
     extra = dict(cell["config"].get("training", {}))
     if a.rehearse:
@@ -148,23 +151,26 @@ def run(a, cell: dict, t_proc0: float) -> dict:
         raise SystemExit(f"the train worker runs on {dev['platform']}, not a TPU")
     tol = float(cell["config"]["tolerance"]["loss_abs"])
     d_first, d_last = abs(m["loss_first"] - m["ref_first"]), abs(m["loss_last"] - m["ref_last"])
-    say(f"worker device {dev}; build+init+warm-up {m['build_s']:.1f}s, reference loss {m['reference_s']:.1f}s; "
+    say(f"family {config['family']}; worker device {dev}; build+init+warm-up {m['build_s']:.1f}s, reference loss {m['reference_s']:.1f}s; "
         f"window: {json.dumps(s)}")
     say(f"loss vs plain reference: first measured step {m['loss_first']:.4f} vs {m['ref_first']:.4f} (d {d_first:.4f}), "
         f"step after the window {m['loss_last']:.4f} vs {m['ref_last']:.4f} (d {d_last:.4f}), tolerance {tol}; "
-        f"warm-up losses {m['warmup_losses']}; flash kernel in the step program: {m['kernel_in_program']}")
+        f"warm-up losses {m['warmup_losses']}; kernels expected in the step program {sorted(family.kernels_expected(config))}, "
+        f"missing {m['kernels_missing']}")
     say(f"compiles in the window: {m['compiles_in_window']} {m['compiled_in_window']}; peak memory {m['memory_peak_bytes'] / 1e9:.2f} GB")
     if dev["platform"] == "tpu":
         # the rate again, in other units: FLOPs the passes require (remat's recompute not counted) over the published peak
-        per_token = flops.train_flops_per_token(config, int(mix["seq_len"]))
+        per_token = family.train_flops_per_token(config, int(mix["seq_len"]))
         say(f"mfu {100.0 * s['train_tokens_per_s'] * per_token / (dev['count'] * peaks_of(dev['kind'])['bf16_flops']):.2f}% "
             f"at {per_token / 1e9:.3f} GFLOP a token")
     correct = d_first <= tol and d_last <= tol and m["losses_finite"]
-    if dev["platform"] == "tpu" and not m["kernel_in_program"]:
-        say("FAIL the flash kernel is not in the step program on the TPU")
+    if dev["platform"] == "tpu" and m["kernels_missing"]:
+        say(f"FAIL not in the step program on the TPU: {m['kernels_missing']}")
         correct = False
     obs = {"cell": cell["cell"], "config": config, "mix": mix, "seconds": float(a.seconds), "train": m, "device": dev,
            "worker": {"compiles_in_window": m["compiles_in_window"], "trace": m.get("trace")}}
     e2e = {"setup_s": m["t0"] - t_proc0, "train_tokens_per_s": s["train_tokens_per_s"]}
-    return {"correct": bool(correct), "attempted": s["steps"], "failed": 0, "end_to_end": e2e, "obs": obs,
+    compared = [["loss, first measured step, |program - reference|", d_first, tol], ["loss, step after the window, |program - reference|", d_last, tol],
+                ["kernels missing from the step program", len(m["kernels_missing"]) if dev["platform"] == "tpu" else 0, 0]]
+    return {"correct": bool(correct), "attempted": s["steps"], "failed": 0, "end_to_end": e2e, "obs": obs, "compared": compared,
             "device": dev, "memory_peak_bytes": m["memory_peak_bytes"], "trace": m.get("trace")}

@@ -1,7 +1,7 @@
 """CPU tests of the benchmark's own code (benchmark/): the traffic generator, the arithmetic from
 samples to metrics, the trace reduction, the plain reference against models/llama.py at a tiny
-size, the FLOP counts, and that every name in BENCHMARK.json resolves to its file. No timing
-asserts, no sockets, no chip."""
+size (through ``benchmark/families/llama.py``), the FLOP counts, and that every name in
+BENCHMARK.json resolves to its file. No timing asserts, no sockets, no chip."""
 
 import json
 import os
@@ -214,34 +214,32 @@ def test_reduction_of_the_recorded_tpu_trace():
 def tiny():
     import jax
 
-    from ray_tpu.models.llama import LlamaConfig, init_params
-
-    c = common.rehearsal_config({"rope_theta": 1e6, "rms_norm_eps": 1e-5, "tie_word_embeddings": False})
-    cfg = LlamaConfig(**common.llama_kwargs(c, 64, remat=False, attention_impl="xla"))
-    return c, cfg, init_params(cfg, jax.random.PRNGKey(3))
+    llama = common.load_family("llama")
+    c = llama.rehearsal({"family": "llama", "rope_theta": 1e6, "rms_norm_eps": 1e-5, "tie_word_embeddings": False})
+    cfg = llama.program_config(c, 64, remat=False, attention_impl="xla")
+    return c, cfg, llama.init_params(cfg, jax.random.PRNGKey(3))
 
 
 def test_reference_logprobs_agree_with_the_programs_forward(tiny):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import reference
     from ray_tpu.models.llama import forward
 
     c, cfg, params = tiny
     toks = np.random.default_rng(0).integers(1, 500, 48)
     want = jax.nn.log_softmax(forward(params, jnp.asarray(toks[None], jnp.int32), cfg)[0], axis=-1)
-    got = reference.logprobs(params, list(toks), c, 10, 40)
+    got = common.load_family("llama").reference_logprobs(params, list(toks), c, 10, 40)
     assert np.abs(np.asarray(got) - np.asarray(want[10:40])).max() < 2e-4
 
 
 def test_reference_loss_agrees_with_the_programs_loss(tiny):
     from benchmark import reference
-    from ray_tpu.models.llama import loss_fn
 
+    llama = common.load_family("llama")
     c, cfg, params = tiny
     batch = traffic.train_batch(5, 0, 2, 32, c["vocab_size"])
-    assert reference.loss(params, batch, c) == pytest.approx(float(loss_fn(params, batch, cfg)), abs=2e-4)
+    assert reference.loss(llama.reference_logprobs, params, batch, c) == pytest.approx(float(llama.loss_fn(params, batch, cfg)), abs=2e-4)
 
 
 @pytest.mark.parametrize("wrong", ["seed", "token", "logprob"])
@@ -249,48 +247,49 @@ def test_check_served_notices(tiny, wrong):
     import jax
 
     from benchmark import reference
-    from ray_tpu.models.llama import init_params
 
+    llama = common.load_family("llama")
+    logprobs, init_params = llama.reference_logprobs, llama.init_params
     c, cfg, params = tiny
     prompt = [int(t) for t in np.random.default_rng(1).integers(1, 500, 20)]
     toks, lps = [], []
     for _ in range(6):  # greedy decoding by the reference itself
-        lp = np.asarray(reference.logprobs(params, prompt + toks, c, len(prompt) + len(toks) - 1, len(prompt) + len(toks)))[0]
+        lp = np.asarray(logprobs(params, prompt + toks, c, len(prompt) + len(toks) - 1, len(prompt) + len(toks)))[0]
         toks.append(int(lp.argmax()))
         lps.append(float(lp.max()))
     sample = {"prompt": prompt, "tokens": toks, "logprobs": lps, "greedy": True}
-    good = reference.check_served(params, c, [sample], tol=0.05)
+    good = reference.check_served(logprobs, params, c, [sample], tol=0.05)
     assert good["ok"] and good["greedy_top1"] == 6 and good["max_abs_dlogprob"] < 1e-4
     if wrong == "seed":
         other = {**params, "embed": init_params(cfg, jax.random.PRNGKey(4))["embed"]}
-        assert not reference.check_served(other, c, [sample], tol=0.05)["ok"]
+        assert not reference.check_served(logprobs, other, c, [sample], tol=0.05)["ok"]
     elif wrong == "token":
         bad = {**sample, "tokens": [toks[0], (toks[1] + 1) % 500] + toks[2:]}
-        assert not reference.check_served(params, c, [bad], tol=0.05)["ok"]
+        assert not reference.check_served(logprobs, params, c, [bad], tol=0.05)["ok"]
     else:
         bad = {**sample, "logprobs": [lps[0] - 0.2] + lps[1:]}
-        assert not reference.check_served(params, c, [bad], tol=0.05)["ok"]
+        assert not reference.check_served(logprobs, params, c, [bad], tol=0.05)["ok"]
 
 
 # -------------------------------------------------------------------------------- flops, peaks
 @pytest.mark.parametrize("config", CONFIGS)
 def test_matmul_params_and_published_count(config):
-    from ray_tpu.models.llama import LlamaConfig
-
     with open(os.path.join(common.HERE, "configs", config + ".json")) as f:
         c = json.load(f)
-    cfg = LlamaConfig(**common.llama_kwargs(c, 2048))
+    family = common.load_family(c["family"])
+    cfg = family.program_config(c, 2048)
     assert cfg.num_params() == c["parameters"]
     norms = c["num_hidden_layers"] * 2 * c["hidden_size"] + c["hidden_size"]
-    assert flops.matmul_params(c) == cfg.num_params() - c["vocab_size"] * c["hidden_size"] - norms
+    assert family.matmul_params(c) == cfg.num_params() - c["vocab_size"] * c["hidden_size"] - norms
     assert cfg.hd == c["head_dim"] == 128
 
 
 def test_train_flops_and_flash_roofline():
     with open(os.path.join(common.HERE, "configs", "mistral-7b-v0.3-d6.json")) as f:
         c = json.load(f)
-    per_tok = flops.train_flops_per_token(c, 2048)
-    assert per_tok == pytest.approx(6 * flops.matmul_params(c) + 3 * c["num_hidden_layers"] * 2 * 32 * 2048 * 128)
+    family = common.load_family(c["family"])
+    per_tok = family.train_flops_per_token(c, 2048)
+    assert per_tok == pytest.approx(6 * family.matmul_params(c) + 3 * c["num_hidden_layers"] * 2 * 32 * 2048 * 128)
     r = flops.flash_roofline(c, 8, 2048, peaks_of("TPU v5 lite"))
     assert r["fwd"]["flops"] == pytest.approx(4 * 8 * 32 * 2048 * 2048 * 128 / 2) and r["bwd"]["flops"] == pytest.approx(2.5 * r["fwd"]["flops"])
     assert r["fwd"]["bound"] == r["bwd"]["bound"] == "compute"
