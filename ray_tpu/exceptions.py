@@ -185,3 +185,12 @@ class AttnKernelUnavailableError(RayTpuError, ValueError):
     shape or mesh the kernel cannot serve. Raised at engine
     construction: an explicit kernel request is never silently served
     by another path."""
+
+
+class HybridModelUnsupportedError(RayTpuError, ValueError):
+    """An engine serving a hybrid model (recurrent layers beside
+    attention layers) was asked for a feature that assumes a sequence's
+    state is its keys and values: prefix reuse, speculative verify, the
+    paged or int8 cache, a tensor-parallel mesh, disaggregated prefill,
+    the KV plane, migration or suspend. Raised by the feature's name,
+    at construction or at the call; never a quiet wrong answer."""

@@ -34,6 +34,7 @@ from typing import Callable
 # not in CLI code — so tests and the CI gate agree on coverage.
 ENTRY_MODULES = (
     "ray_tpu.llm.model_runner",
+    "ray_tpu.llm.hybrid_runner",
     "ray_tpu.llm.disagg.scatter",
     "ray_tpu.llm.kvplane.quant",
     "ray_tpu.llm.pallas.paged_attn",

@@ -39,6 +39,7 @@ batteries-included loop.
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
 import time
@@ -50,6 +51,22 @@ import numpy as np
 from ray_tpu.llm.kvplane.index import prefix_key, token_bytes
 from ray_tpu.llm.sampling import SamplingParams
 from ray_tpu.llm.telemetry import NO_STAGE, stage
+
+logger = logging.getLogger("ray_tpu.llm")
+
+# public methods that move a sequence between engines as keys and values alone, and what each is
+# called in the refusal a hybrid model's engine gives instead (its recurrent layers' state is in
+# no handoff, migration or KV-plane format)
+KV_ONLY_METHODS = {
+    "add_prefill_request": "disaggregated prefill (add_prefill_request)",
+    "prefill_remote": "disaggregated prefill (prefill_remote)",
+    "add_prefilled": "a transferred KV block (add_prefilled)",
+    "checkpoint_request": "migration (checkpoint_request)",
+    "restore_request": "migration (restore_request)",
+    "suspend_request": "suspend (suspend_request)",
+    "resume_suspended": "suspend (resume_suspended)",
+    "adopt_prefetched": "the KV plane (adopt_prefetched)",
+}
 
 
 @dataclass
@@ -280,8 +297,12 @@ class PrefixCache:
 class LLMEngine:
     """Continuous-batching engine over a slot KV cache.
 
-    config: ray_tpu.models.llama.LlamaConfig; params: matching pytree (if
-    None, randomly initialized — useful for tests/benchmarks).
+    config: ray_tpu.models.llama.LlamaConfig, or a hybrid model's
+    description (ray_tpu.models.nemotron_h.NemotronHConfig: layers of
+    several kinds, a recurrent state per sequence kept in a state cache
+    beside the KV rows; its step programs are llm/hybrid_runner.py's);
+    params: matching pytree (if None, randomly initialized — useful for
+    tests/benchmarks).
     """
 
     def __init__(
@@ -391,6 +412,25 @@ class LLMEngine:
         enable_compile_cache()
         self.config = config
         self.mesh = mesh
+        # a hybrid model (layers of several kinds, a recurrent state per sequence beside keys
+        # and values) is known by its description and takes its step programs from
+        # llm/hybrid_runner.py; what it cannot do yet is refused there, by name
+        self._hybrid = hasattr(config, "layer_kinds")
+        if self._hybrid:
+            from ray_tpu.llm.hybrid_runner import refuse, refuser
+
+            init_params = type(config).init_params  # noqa: F811 - the description carries its own weights
+            refuse(config, kv_layout=kv_layout, cache_dtype=cache_dtype, mesh=mesh,
+                   speculative=speculative, kv_plane=kv_plane)
+            for name, what in KV_ONLY_METHODS.items():
+                setattr(self, name, refuser(what))
+            if enable_prefix_caching:
+                # the default would engage: a hit needs a snapshot of the recurrent state at the
+                # block boundary, which nothing keeps yet. Off, and said once an engine.
+                logger.info("prefix caching is off for a hybrid model: a hit would need the recurrent "
+                            "state at the block boundary; prefix_cache_stats() answers {}")
+                enable_prefix_caching = False
+        self._kv_layers = getattr(config, "num_kv_layers", config.num_layers)  # layers that keep keys and values
         if tp_collective not in ("fp", "int8"):
             raise ValueError(f"tp_collective must be 'fp' or 'int8', got {tp_collective!r}")
         self.tp_collective = tp_collective
@@ -469,13 +509,14 @@ class LLMEngine:
             self._admit_counter = 0
         else:
             self.attn_kernel = "xla"  # slot layout: no page gather to fuse
-            self._prefill, self._insert, self._decode, self._extend = make_runner_fns(config, mesh=mesh)
+            if not self._hybrid:  # the hybrid's programs are built below, once the loop's mode is known
+                self._prefill, self._insert, self._decode, self._extend = make_runner_fns(config, mesh=mesh)
 
         cache_cfg = (
             None
             if kv_layout == "paged"
             else kvc.CacheConfig(
-                num_layers=config.num_layers,
+                num_layers=self._kv_layers,
                 num_slots=self.max_num_seqs,
                 max_seq_len=self.max_seq_len,
                 num_kv_heads=config.num_kv_heads,
@@ -500,6 +541,11 @@ class LLMEngine:
                 self.pool = pkv.alloc(self._pcfg)
             else:
                 self.cache = kvc.alloc(cache_cfg)
+            if self._hybrid:
+                from ray_tpu.llm import state_cache
+
+                # the recurrent layers' state, one entry a slot, beside the KV rows
+                self.state = state_cache.alloc(config, self.max_num_seqs)
         else:
             param_sh, cache_sh = self._mesh_shardings(mesh)
             if params is not None:
@@ -617,6 +663,15 @@ class LLMEngine:
                 f"hidden_size ({config.hidden_size}) must divide by tp ({axis_size(mesh, 'tp')}) "
                 "to chunk the int8 quantized all-reduce payload; use tp_collective='fp'"
             )
+        if self._hybrid:
+            from ray_tpu.llm.hybrid_runner import make_hybrid_fns
+
+            self._prefill, self._insert, self._state_insert, step_fn = make_hybrid_fns(config, self._device_resident)
+            if self._device_resident:
+                self._fused_step = step_fn
+            else:
+                self._decode = step_fn
+        self._moe_stats = None  # the drained step's expert-routing counters (hybrid models)
         if self._device_resident:
             from ray_tpu.llm.model_runner import make_delta_fns, make_fused_fns, make_fused_paged_fns
 
@@ -626,7 +681,7 @@ class LLMEngine:
                     config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
                     attn_impl=self.attn_kernel,
                 )
-            else:
+            elif not self._hybrid:
                 self._fused_step = make_fused_fns(
                     config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant
                 )
@@ -776,7 +831,7 @@ class LLMEngine:
         from ray_tpu.llm.kv_quant import bytes_per_token
 
         cfg = self.config
-        per_tok = bytes_per_token(cfg.num_layers, cfg.num_kv_heads, cfg.hd, self.kv_dtype)
+        per_tok = bytes_per_token(self._kv_layers, cfg.num_kv_heads, cfg.hd, self.kv_dtype)
         with self._lock:
             arrs = self.pool if self.kv_layout == "paged" else self.cache
             allocated = int(sum(int(a.nbytes) for name, a in arrs.items() if name != "length"))
@@ -805,6 +860,12 @@ class LLMEngine:
                 )
             out["occupied_tokens"] = occupied
             out["occupied_bytes"] = occupied * int(per_tok)
+            if self._hybrid:
+                # the state cache beside the KV rows: fixed bytes a slot, whatever the lengths
+                from ray_tpu.llm import state_cache
+
+                out["state_bytes_per_slot"] = state_cache.bytes_per_slot(cfg)
+                out["state_allocated_bytes"] = int(sum(int(a.nbytes) for a in self.state.values()))
             return out
 
     def _mesh_shardings(self, mesh):
@@ -2076,7 +2137,8 @@ class LLMEngine:
         for i, (_, _, prompt) in enumerate(group):
             toks[i, : len(prompt)] = prompt
             lens[i] = len(prompt)
-        logits, ks, vs = self._prefill(self.params, jnp.asarray(toks), jnp.asarray(lens))
+        # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true length
+        logits, ks, vs, *new_state = self._prefill(self.params, jnp.asarray(toks), jnp.asarray(lens))
         for i, (st, slot, prompt) in enumerate(group):
             n = len(prompt)
             if self._prefix_cache is not None and not st.token_ids:
@@ -2094,6 +2156,10 @@ class LLMEngine:
                     self._push_table(slot)
             else:
                 self.cache = self._insert(self.cache, slot, ks[:, i], vs[:, i], n)
+                if new_state:
+                    # replaces whatever the slot's last sequence left: the reset of a recycled slot
+                    with stage(self._tel, "llm.step.state_insert"):
+                        self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), new_state[0])
             self._bind_slot(st, slot, logits[i : i + 1])
 
     def _admit_special_paged(self, st: RequestState, slot: int, pref, prompt):
@@ -2432,6 +2498,7 @@ class LLMEngine:
         try:
             with tel.begin_step() if tel is not None else NO_STAGE, self._lock:
                 self._last_spec_drain = None
+                self._moe_stats = None
                 self._step_emitted = 0
                 with stage(tel, "llm.step.admission"):
                     wave = self._stage_admission()
@@ -2494,6 +2561,13 @@ class LLMEngine:
         self._step_emitted = len(reported)
         return reported
 
+    def _lane_mask(self, active: list) -> np.ndarray:
+        """[slots] bool, true where a lane is bound to a live sequence: a hybrid's step keeps
+        the other lanes out of its routing counters."""
+        mask = np.zeros((self.max_num_seqs,), np.bool_)
+        mask[[st.slot for st in active]] = True
+        return mask
+
     def _dispatch_fused(self):
         """Launch the fused device step for the current occupancy; never
         blocks on results (stored in self._pending for the next call)."""
@@ -2518,6 +2592,16 @@ class LLMEngine:
             self.pool = self._fused_append(self.pool, wp, wo, k_new, v_new)
             for st in active:
                 self._lengths[st.slot] += 1  # host shadow, no upload
+        elif self._hybrid:
+            (self.cache, self.state, toks, logps, moe, self._dkeys,
+             self._dtemps, self._dtopk, self._dtopp) = self._fused_step(
+                self.params, self.cache, self.state, self._dtokens, self._dkeys,
+                self._dtemps, self._dtopk, self._dtopp, self._lane_mask(active),
+            )
+            self._dtokens = toks
+            # the routing counters ride the tokens' delayed readback: no sync of their own
+            self._pending = (toks, logps, moe, [(st, st.slot) for st in active])
+            return
         else:
             (self.cache, toks, logps, self._dkeys,
              self._dtemps, self._dtopk, self._dtopp) = self._fused_step(
@@ -2547,7 +2631,9 @@ class LLMEngine:
         if pending is None:
             return []
         lanes = pending[-1]
-        toks, logps = host if host is not None else self._drain_wait(pending)
+        toks, logps, *moe = host if host is not None else self._drain_wait(pending)
+        if moe:
+            self._moe_stats = moe[0]
         emitted = []
         for st, slot in lanes:
             if st.finished:
@@ -2692,6 +2778,10 @@ class LLMEngine:
             )
             for st in active:
                 self._lengths[st.slot] += 1
+        elif self._hybrid:
+            logits, self.cache, self.state, moe = self._decode(
+                self.params, self.cache, self.state, jnp.asarray(self._next_tokens), self._lane_mask(active))
+            self._moe_stats = np.asarray(moe)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
         else:
             logits, self.cache = self._decode(self.params, self.cache, jnp.asarray(self._next_tokens))
         toks, logps, keys = self._sample(

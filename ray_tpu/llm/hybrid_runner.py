@@ -1,0 +1,191 @@
+"""Step programs for a hybrid model: layers of several kinds in one stack,
+each keeping its own kind of state (``ray_tpu.models.nemotron_h``).
+
+Three programs, under stable names a trace reader finds (``prefill`` and
+``fused`` in a name mean what they mean in ``model_runner``):
+
+- ``llm_hybrid_prefill``: a batch of same-bucket prompts, right-padded.
+  Attention masks padding; a recurrence would run over it, so every
+  recurrent layer hands back its state AT each prompt's true length.
+- ``llm_state_insert``: a prefilled sequence's recurrent state into its
+  slot of the state cache (``llm/state_cache.py``), replacing whatever
+  the slot's last sequence left; its keys and values go through the slot
+  cache's own ``llm_kv_insert``.
+- ``llm_hybrid_fused_step``: decode -> sample -> advance, one program a
+  token. Both caches ride the layer loop as its carry and are updated in
+  place (one token's key and value scattered into the donated rows, one
+  layer's state overwritten), never rebuilt from per-layer outputs.
+
+The engine picks these from the config object: a description with
+``layer_kinds`` is a hybrid, and carries its mixers (``config.model``)
+and its weights' initialisation (``config.init_params``), so that
+neither this file nor the engine names a model. What the hybrid cannot
+do yet is refused by name in ``refuse`` and ``refuser``.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.exceptions import HybridModelUnsupportedError
+from ray_tpu.lint import jaxcheck
+from ray_tpu.llm import state_cache
+from ray_tpu.llm.model_runner import _sds, _sds_lanes, named_jit
+from ray_tpu.ops.layers import rms_norm
+
+# one step's expert-routing counters, in the order the fused step returns them
+MOE_STATS = ("experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load")
+
+
+def refuse(config, *, kv_layout, cache_dtype, mesh, speculative, kv_plane) -> None:
+    """Engine features that assume "a sequence's state is its keys and values", each refused by
+    its name at construction: reusing, verifying, moving or re-laying-out a sequence would need
+    a snapshot, a rollback or a codec of the recurrent state, and none is built."""
+    why = {
+        "kv_layout='paged'": kv_layout != "slots",
+        "cache_dtype='int8'": cache_dtype is not None and str(cache_dtype).lower() in ("int8", "i8"),
+        "tensor_parallel_size > 1 (a mesh)": mesh is not None,
+        "speculative decoding": speculative is not None,
+        "the cluster KV plane (kv_plane)": kv_plane is not None,
+    }
+    for what, asked in why.items():
+        if asked:
+            raise HybridModelUnsupportedError(
+                f"{what} is not built for a hybrid model ({type(config).__name__}, layers "
+                f"{''.join(sorted(set(config.layer_pattern)))}): its recurrent layers keep a state per sequence "
+                "that this feature would have to snapshot, roll back, shard or ship, and only keys and values can be")
+
+
+def refuser(what: str):
+    """What an engine of a hybrid model answers where a sequence would be moved as keys and
+    values alone (``engine.KV_ONLY_METHODS``)."""
+    def refused(*args, **kwargs):
+        raise HybridModelUnsupportedError(
+            f"{what} is not built for a hybrid model: its recurrent layers' state is in no handoff, "
+            "migration or KV-plane format, and keys and values alone do not resume the sequence")
+    return refused
+
+
+def prefill(params, tokens, length, cfg, mesh=None):
+    """tokens [B, T_pad] right-padded, length [B] -> (last-token logits [B, vocab] f32,
+    k, v [La, B, T_pad, kv, hd], state {name: [Lm, B, ...]} at each prompt's true length)."""
+    x, out = cfg.model.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
+    x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
+    logits = jnp.dot(x_last, params["unembed"], preferred_element_type=jnp.float32)
+    return logits, out.pop("k"), out.pop("v"), out
+
+
+def decode_step(params, cache, state, tokens, active, cfg):
+    """Advance every slot one token. cache: the slot KV rows of the attention layers; state: the
+    state cache of the recurrent layers; active [B] bool: lanes bound to a live sequence (the
+    others compute garbage nobody reads, and are kept out of the routing counters).
+    -> (logits [B, vocab] f32, cache, state, MOE_STATS as float32 [4])."""
+    B, model = tokens.shape[0], cfg.model
+    lengths = cache["length"]
+    pos = jnp.minimum(lengths, cache["k"].shape[2] - 1)
+    lanes = jnp.arange(B, dtype=jnp.int32)
+    dt, sd = params["embed"].dtype, cfg.stream_dtype
+    x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
+
+    def of_layer(a, i):
+        return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+    def layer(kind, w, i, x, carry):
+        k_all, v_all, st, stats = carry
+        xn = rms_norm(x, w["norm"], cfg.rms_eps)
+        if kind == "mamba":
+            y, ssm, conv = model.mamba2_step(w, xn.astype(dt), of_layer(st["ssm"], i), of_layer(st["conv"], i), cfg)
+            st = {"ssm": jax.lax.dynamic_update_index_in_dim(st["ssm"], ssm.astype(st["ssm"].dtype), i, 0),
+                  "conv": jax.lax.dynamic_update_index_in_dim(st["conv"], conv.astype(st["conv"].dtype), i, 0)}
+        elif kind == "moe":
+            y, s = model.moe_step(w, xn, active, cfg)
+            stats = jnp.stack([stats[0] + s[0], stats[1] + s[1], jnp.maximum(stats[2], s[2])])
+        else:
+            q, k, v = model.qkv(w, xn.astype(dt), cfg)
+            k_all = k_all.at[i, lanes, pos].set(k.astype(k_all.dtype))
+            v_all = v_all.at[i, lanes, pos].set(v.astype(v_all.dtype))
+            y = model.attn_step(w, q, of_layer(k_all, i), of_layer(v_all, i), lengths, cfg)
+        return x + y.astype(sd), (k_all, v_all, st, stats)
+
+    x, (k_all, v_all, state, stats) = model.run_layers(
+        cfg, params, x, (cache["k"], cache["v"], dict(state), jnp.zeros((3,), jnp.float32)), layer)
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(dt)
+    logits = jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
+    n_moe = max(cfg.count("moe"), 1)
+    total = cfg.num_experts_per_tok * jnp.sum(active.astype(jnp.float32))
+    moe = jnp.stack([stats[0] / n_moe, stats[1] / n_moe, total, stats[2]])
+    return logits, {"k": k_all, "v": v_all, "length": lengths + 1}, state, moe
+
+
+def fused_step(params, cache, state, tokens, keys, temps, top_k, top_p, active, cfg):  # tpulint: disable=JXC001 — tokens is the previous step's output, still held for the delayed readback (as in model_runner.fused_step); active is a fresh 1-byte-a-lane host mask
+    """ONE program for the hybrid's decode hot path: decode -> sample -> append keys and values
+    -> overwrite recurrent state -> advance lengths. Lanes are donated and handed back as in
+    ``model_runner.fused_step``; the routing counters ride the same delayed readback as the
+    tokens."""
+    from ray_tpu.llm.sampling import sample
+
+    logits, cache, state, moe = decode_step(params, cache, state, tokens, active, cfg)
+    toks, logps, new_keys = sample(logits, keys, temps, top_k, top_p)
+    return cache, state, toks, logps, moe, new_keys, temps, top_k, top_p
+
+
+def make_hybrid_fns(cfg, device_resident: bool):
+    """Jitted (prefill, kv insert, state insert, step) for an engine; ``step`` is the fused step
+    of the device-resident loop, or the plain decode step of the synchronous oracle loop."""
+    from ray_tpu.llm import kv_cache as kvc
+
+    prefill_fn = named_jit("llm_hybrid_prefill", partial(prefill, cfg=cfg))
+    insert_fn = named_jit("llm_kv_insert", kvc.insert_sequence, donate_argnums=(0,))
+    state_insert_fn = named_jit("llm_state_insert", state_cache.insert_state, donate_argnums=(0,))
+    if device_resident:
+        step_fn = named_jit("llm_hybrid_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+    else:
+        step_fn = named_jit("llm_hybrid_decode_step", partial(decode_step, cfg=cfg), donate_argnums=(1, 2))
+    return prefill_fn, insert_fn, state_insert_fn, step_fn
+
+
+# ---------------------------------------------------------------------------
+# jaxcheck shape buckets: tile-true widths at a size that traces in seconds
+# ---------------------------------------------------------------------------
+def _trace_cfg():
+    from ray_tpu.models.nemotron_h import NemotronHConfig  # the one description there is to trace
+
+    return NemotronHConfig(
+        vocab_size=32256, hidden_size=1024, layer_pattern="ME*ME*ME", mamba_num_heads=16, mamba_head_dim=64,
+        n_groups=8, ssm_state_size=128, n_routed_experts=16, expert_start=0, num_local_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=1024, moe_shared_expert_intermediate_size=2048,
+        num_heads=8, num_kv_heads=8, head_dim=128, max_seq_len=512)
+
+
+def _sds_caches(cfg, B: int, S: int):
+    kv = _sds((cfg.num_kv_layers, B, S, cfg.num_kv_heads, cfg.hd), jnp.dtype(cfg.dtype))
+    return {"k": kv, "v": kv, "length": _sds((B,), jnp.int32)}, jax.eval_shape(lambda: state_cache.alloc(cfg, B))
+
+
+def _bucket_prefill(B=4, T=128):
+    cfg = _trace_cfg()
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    return (params, _sds((B, T), jnp.int32), _sds((B,), jnp.int32), cfg), {}
+
+
+def _bucket_fused(B=8, S=256):
+    cfg = _trace_cfg()
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    cache, state = _sds_caches(cfg, B, S)
+    return (params, cache, state) + _sds_lanes(B) + (_sds((B,), jnp.bool_), cfg), {}
+
+
+def _bucket_state_insert(B=8, Bp=4):
+    cfg = _trace_cfg()
+    state = jax.eval_shape(lambda: state_cache.alloc(cfg, B))
+    new = jax.eval_shape(lambda: state_cache.alloc(cfg, Bp))
+    return (state, _sds((), jnp.int32), _sds((), jnp.int32), new), {}
+
+
+jaxcheck.entry(name="llm.hybrid_prefill", shapes={"b4_t128": _bucket_prefill})(prefill)
+jaxcheck.entry(name="llm.hybrid_fused_step", shapes={"b8_s256": _bucket_fused},
+               donate=("cache", "state", "keys", "temps", "top_k", "top_p"), donate_bytes=0)(fused_step)
+jaxcheck.entry(name="llm.state_insert", shapes={"b8": _bucket_state_insert}, donate=("state",))(state_cache.insert_state)

@@ -43,6 +43,8 @@ STEP_PROGRAM_NAMES = frozenset({
     "llm_fused_step", "llm_fused_paged_step",  # device-resident decode (one per layout)
     "llm_verify_step", "llm_verify_paged_attn", "llm_verify_append",  # speculative verify
     "llm_draft_propose", "llm_draft_prefill", "llm_draft_kv_insert", "llm_draft_steps",
+    # hybrid models (llm/hybrid_runner.py): recurrent state beside the slot KV rows
+    "llm_hybrid_prefill", "llm_state_insert", "llm_hybrid_fused_step", "llm_hybrid_decode_step",
 })
 
 

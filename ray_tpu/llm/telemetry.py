@@ -71,6 +71,8 @@ logger = logging.getLogger("ray_tpu.llm")
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
     "llm.step.prefill": "prefill_ms",
+    # inside llm.step.prefill: a hybrid model's recurrent state written into its slot
+    "llm.step.state_insert": "state_insert_ms",
     "llm.step.dispatch": "dispatch_ms",
     "llm.step.drain_wait": "drain_wait_ms",
     "llm.step.emit": "emit_ms",
@@ -170,6 +172,10 @@ METRICS: dict[str, dict] = {
     "rt_llm_kv_hbm_bytes": {
         "kind": "gauge", "tags": _SERVE_TAGS,
         "desc": "occupied KV bytes (scale-inclusive for int8 caches)",
+    },
+    "rt_llm_state_hbm_bytes": {
+        "kind": "gauge", "tags": _SERVE_TAGS,
+        "desc": "allocated bytes of the per-sequence state cache beside the KV cache (hybrid models; 0 otherwise)",
     },
     "rt_llm_queue_depth": {
         "kind": "gauge", "tags": _SERVE_TAGS,
@@ -330,6 +336,10 @@ class FlightRecorder:
         # (both time.time(); dispatch_t absent where none was), then the
         # stage durations
         "t0", "dispatch_t",
+        # a hybrid model's drained decode step (llm/hybrid_runner.MOE_STATS): held experts that
+        # got a token (mean over expert layers), (token, expert) pairs served here and asked
+        # for in all, most tokens at one expert; absent for a model without routed experts
+        "experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load",
     ) + tuple(STAGES.values())
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
@@ -455,6 +465,9 @@ class FlightRecorder:
 # ----------------------------------------------------------------------
 # engine-facing facade
 # ----------------------------------------------------------------------
+_NO_MOE = (None,) * 4  # a step row's routing counters for a model without routed experts
+
+
 class EngineTelemetry:
     """Everything LLMEngine calls, one object. All entry points are
     host-only and cheap; the engine holds its own lock while calling in,
@@ -485,6 +498,9 @@ class EngineTelemetry:
         self._b_slots = self.m["rt_llm_slots_in_use"].bind(self.tags)
         self._b_occ = self.m["rt_llm_kv_occupancy"].bind(self.tags)
         self._b_hbm = self.m["rt_llm_kv_hbm_bytes"].bind(self.tags)
+        # the state cache is allocated once and never grows: its gauge is set here, not per step
+        self._state_bytes = float(sum(int(a.nbytes) for a in getattr(engine, "state", {}).values()))
+        self.m["rt_llm_state_hbm_bytes"].bind(self.tags).set(self._state_bytes)
         self._b_spec = self.m["rt_llm_spec_acceptance"].bind(self.tags)
         # prefix-reuse tiers (cluster KV plane): per-ADMISSION events, so
         # pre-bound handles keep them off the per-step budget entirely
@@ -510,7 +526,7 @@ class EngineTelemetry:
         from ray_tpu.llm.kv_quant import bytes_per_token
 
         cfg = engine.config
-        self._bytes_per_token = int(bytes_per_token(cfg.num_layers, cfg.num_kv_heads, cfg.hd, engine.kv_dtype))
+        self._bytes_per_token = int(bytes_per_token(engine._kv_layers, cfg.num_kv_heads, cfg.hd, engine.kv_dtype))
         if engine.kv_layout == "paged":
             self._capacity_tokens = (engine._pcfg.num_pages - 1) * engine._pcfg.page_size
         else:
@@ -856,13 +872,15 @@ class EngineTelemetry:
 
         paged = eng.kv_layout == "paged"
         sd = spec_drained or (None, None)
+        moe = eng._moe_stats  # host array of the drained step (hybrid models), else None
+        moe = _NO_MOE if moe is None else tuple(round(float(v), 3) for v in moe)
         self.recorder.record_step((
             now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, waiting,
             occupied, capacity,
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],
-            self._step_t0, dispatch_t, *[round(ms, 4) for ms in stages],
+            self._step_t0, dispatch_t, *moe, *[round(ms, 4) for ms in stages],
         ))
 
         if slots_in_use and eng._device_resident and self._wire_bytes_per_step:

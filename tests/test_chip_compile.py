@@ -170,3 +170,56 @@ def test_fsdp4_loss_and_grad_with_flash_kernel_compile_for_v5e(topo):
     tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=NamedSharding(mesh, shard_batch_spec(mesh)))
     _, txt = _compile(jax.value_and_grad(partial(loss_fn, config=cfg, mesh=mesh)), params, {"tokens": tok, "targets": tok})
     assert txt.count("tpu_custom_call") >= 3  # forward, dq and dk/dv kernels
+
+
+def _hybrid_at_the_benchmarks_size(one_chip):
+    """The configuration of the cell ``nemotron-3-nano-ep2.chat``: published widths, 16 layers,
+    64 of 128 experts, 32 slots x 4096 (benchmark/configs/nemotron-3-nano-30b-a3b-ep2.json)."""
+    import json
+    import os
+
+    from benchmark import common
+    from ray_tpu.llm import state_cache
+    from ray_tpu.models import nemotron_h as nh
+
+    with open(os.path.join(common.HERE, "configs", "nemotron-3-nano-30b-a3b-ep2.json")) as f:
+        c = json.load(f)
+    # off the TPU "auto" picks the XLA attention; the chip runs the flash kernel
+    cfg = common.load_family(c["family"]).program_config(c, c["serving"]["max_seq_len"], attention_impl="pallas")
+    params = _on(jax.eval_shape(lambda: nh.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    kv = jax.ShapeDtypeStruct((cfg.num_kv_layers, 32, 4096, cfg.num_kv_heads, cfg.hd), jnp.bfloat16, sharding=one_chip)
+    cache = {"k": kv, "v": kv, "length": jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)}
+    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, 32)), one_chip)
+
+
+def test_hybrid_fused_step_fits_one_v5e_and_updates_its_caches_in_place(one_chip):
+    """PR 29: 9.84 GiB of weights, 0.25 GiB of KV rows and 0.45 GiB of recurrent state in one
+    decode program. Both caches ride the layer loop's carry: the compiler must alias them to the
+    donated inputs and need no temporary of their size (the Llama slot step holds a second copy
+    of its cache, PERF.md section 7), and must not copy a layer's experts out of the stack."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, cache, state = _hybrid_at_the_benchmarks_size(one_chip)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    lanes = (s((32,), jnp.int32), s((32, 2), jnp.uint32), s((32,), jnp.float32), s((32,), jnp.int32), s((32,), jnp.float32))
+    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+    mem = step.lower(params, cache, state, *lanes, s((32,), jnp.bool_)).compile().memory_analysis()
+    caches = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)))
+    assert 10.4 * 2**30 < mem.argument_size_in_bytes < 10.7 * 2**30 and 0.69 * 2**30 < caches < 0.71 * 2**30
+    assert mem.alias_size_in_bytes >= caches
+    assert mem.temp_size_in_bytes < 0.1 * 2**30
+
+
+def test_hybrid_prefill_fits_beside_weights_and_caches_on_one_v5e(one_chip):
+    """The largest prefill the cell warms (4 x 2048, 49,152 routed pairs through the grouped
+    matmul) beside 10.54 GiB of weights and caches: under 15.75 GiB, with the flash kernel."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _hybrid_at_the_benchmarks_size(one_chip)
+    tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in txt
+    assert mem.temp_size_in_bytes < 2.0 * 2**30  # no copy of the experts (4.3 GiB), no layer's worth of them (0.6 GiB x 4)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.70 * 2**30 < 15.0 * 2**30

@@ -129,6 +129,32 @@ def test_stages_sum_to_the_step_and_drain_wait_is_the_device(monkeypatch):
     assert admitting and all(s["prefill_ms"] > 0 for s in admitting)
 
 
+def test_a_hybrid_models_rows_carry_routing_counters_and_the_state_insert_stage():
+    """PR 29: the step record grew four counters of a hybrid model's expert layers (read back with
+    the tokens, one step late) and the stage that writes a prefilled sequence's recurrent state
+    into its slot, inside ``llm.step.prefill``. A model without such layers leaves the counters out."""
+    from ray_tpu.llm.hybrid_runner import MOE_STATS
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    fields = telemetry.FlightRecorder.STEP_FIELDS
+    assert set(MOE_STATS) <= set(fields) and "state_insert_ms" in fields and telemetry.STAGES["llm.step.state_insert"] == "state_insert_ms"
+    plain = _engine()
+    plain.generate([[1, 2, 3]], SamplingParams(max_tokens=3))
+    assert all(not set(MOE_STATS) & set(s) and s["state_insert_ms"] == 0.0 for s in plain.telemetry()["steps"])
+    cfg = NemotronHConfig.tiny(num_local_experts=4)
+    eng = LLMEngine(cfg, max_num_seqs=2, max_seq_len=64)
+    eng.generate([[1, 2, 3, 4, 5], [6, 7]], SamplingParams(max_tokens=5))
+    steps = eng.telemetry()["steps"]
+    admitting = [s for s in steps if s.get("admitted")]
+    assert admitting and all(0 < s["state_insert_ms"] <= s["prefill_ms"] for s in admitting)
+    drained = [s for s in steps if "experts_hit" in s]
+    assert len(drained) >= 4 and all(set(MOE_STATS) <= set(s) for s in drained)
+    for s in drained:  # 2 lanes x 2 experts a token asked for; chip 0 of two holds 4 of the 8
+        assert s["moe_pairs_total"] in (2.0, 4.0) and 0 <= s["moe_pairs_local"] <= s["moe_pairs_total"]
+        assert s["experts_hit"] <= min(4, s["moe_pairs_local"]) and s["moe_max_load"] <= s["moe_pairs_total"] / 2
+    assert eng._tel._state_bytes == eng.kv_cache_stats()["state_allocated_bytes"] > 0 and plain._tel._state_bytes == 0
+
+
 def test_an_uninstrumented_engine_steps_through_the_same_code():
     eng = _engine(telemetry=False)
     out = eng.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
