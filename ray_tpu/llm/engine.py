@@ -1815,9 +1815,11 @@ class LLMEngine:
 
     def _prefix_fits(self, n_p: int, prompt_len: int) -> bool:
         """Suffix-overrun admissibility for a prefix boundary: the
-        bucket-padded remaining suffix must fit the cache row, or the
-        extend's dynamic_update_slice would CLAMP the start and silently
-        corrupt the prefix. The ONE predicate both the local lookup and
+        bucket-padded remaining suffix must fit the cache row (the slot
+        layout's extend wrote its chunk with a dynamic_update_slice, which
+        CLAMPS the start and silently corrupts the prefix; it scatters by
+        position and drops the overrun now, so for that layout this guard
+        only costs hits: ROADMAP A5). The ONE predicate both the local lookup and
         the remote candidate filter apply — the two tiers can never
         disagree on admissibility."""
         return n_p + _bucket(prompt_len - n_p, self.prefill_buckets) <= self.max_seq_len

@@ -111,37 +111,6 @@ def insert_sequence(cache: dict, slot, k_new, v_new, length, k_scale=None, v_sca
     return {"k": k, "v": v, "length": lens}
 
 
-def append_token_layer(k_layer, v_layer, k_t, v_t, lengths):
-    """Append one token's K/V per slot at position lengths[b].
-
-    k_layer/v_layer: [slots, S, kv, hd]; k_t/v_t: [slots, kv, hd].
-    Inactive slots are written too (at their stale length) — harmless, the
-    attention mask never reads past `length`.
-    """
-
-    def _upd(cache_b, t_b, pos):
-        return jax.lax.dynamic_update_slice(
-            cache_b, t_b[None].astype(cache_b.dtype), (pos, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        )
-
-    k = jax.vmap(_upd)(k_layer, k_t, lengths)
-    v = jax.vmap(_upd)(v_layer, v_t, lengths)
-    return k, v
-
-
-def append_scale_layer(scale_layer, s_t, lengths):
-    """Per-slot scale append companion to append_token_layer.
-
-    scale_layer: [slots, kv, S] (position axis last); s_t: [slots, kv];
-    lengths: [slots] write positions.
-    """
-
-    def _upd(sc_b, s_b, pos):
-        return jax.lax.dynamic_update_slice(sc_b, s_b[:, None], (jnp.zeros((), jnp.int32), pos))
-
-    return jax.vmap(_upd)(scale_layer, s_t, lengths)
-
-
 def extract_sequence(cache: dict, slot, T: int):
     """Read one slot's first ``T`` cached positions as a contiguous block.
 

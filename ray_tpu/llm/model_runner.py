@@ -2,8 +2,17 @@
 
 Both are pure functions over the same parameter pytree as
 ray_tpu.models.llama (training and serving share weights); layers are
-iterated with `lax.scan` so compile time is constant in depth and the KV
-cache rides the scan as stacked per-layer xs/ys.
+iterated with `lax.scan` so compile time is constant in depth.
+
+Where the slot cache goes through that scan decides what a decode step
+costs. A cache that enters as the scan's `xs` and leaves as its `ys` is a
+second stacked array to the compiler: it allocates one beside the donated
+input (6.25 GiB of temporaries for a 6.0 GiB cache on a v5e), writes every
+layer's full rows into it and copies the whole of it every step. So
+`decode_step` keeps the stacked K and V (and an int8 cache's scales) in the
+scan's CARRY, writes one token a layer into them in place and reads its
+layer back by index; the donated buffers are then the only cache there is.
+`extend` and `spec/verify.py`'s block forward do the same.
 
 Prefill runs the causal flash path on one (padded) prompt and returns the
 per-layer K/V to be inserted into a cache slot. Decode advances every slot
@@ -294,6 +303,25 @@ def _mlp(x, layer, cfg: LlamaConfig, tpc: TpSpec | None = None):
     return x + _tp_reduce(jnp.dot(jax.nn.silu(g) * u, layer["w_down"]), tpc)
 
 
+def _layer_of(stacked, i):
+    """Layer ``i`` of a stacked cache leaf ``[L, ...]`` (i: a traced index inside the layer loop)."""
+    return jax.lax.dynamic_index_in_dim(stacked, i, 0, keepdims=False)
+
+
+def _scan_layers_carrying_cache(layer_fn, x, params, cache):
+    """Run ``layer_fn(x, kv, layer, i) -> (x, kv)`` over the layers with the
+    slot cache's stacked leaves ``kv = {k, v[, k_scale, v_scale]}`` in the
+    scan's CARRY (never its xs/ys: see the module docstring), so a layer
+    writes its tokens into the donated arrays where they lie and reads its
+    rows back with ``_layer_of``. Returns (x, the updated leaves)."""
+    def step(carry, xs):
+        return layer_fn(carry[0], dict(carry[1]), *xs), None
+
+    kv = {name: leaf for name, leaf in cache.items() if name != "length"}
+    layer_ix = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    return jax.lax.scan(step, (x, kv), (params["layers"], layer_ix))[0]
+
+
 @jaxcheck.entry(
     name="llm.prefill",
     shapes={"b8_t128": _bucket_prefill, "b8_t256": lambda: _bucket_prefill(T=256)},
@@ -350,6 +378,14 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
     new cache). The new token is written at position cache.length[b] and
     attends to positions 0..length[b] inclusive.
 
+    The layer loop carries ``(x, stacked cache leaves)`` and scans over
+    ``(params["layers"], layer index)`` (_scan_layers_carrying_cache): each
+    layer writes ONE token a lane into the stacked arrays
+    (``.at[i, lanes, write_pos].set``) and reads its own layer back by
+    index. Nothing of the cache's size is returned from the body, so a
+    donated cache is updated where it lies (why not xs/ys: the module
+    docstring).
+
     An int8 cache (k_scale/v_scale present) quantizes the appended token
     INSIDE this program and dequantizes the row for attention at the f32
     compute dtype the score/value einsums already use (kv_quant.py) —
@@ -379,56 +415,48 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
     # mask: new token sits at index `length`, may attend to 0..length
     attn_ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]  # [B,1,1,S]
 
-    def layer_fn(x, xs):
-        from ray_tpu.llm.kv_cache import append_scale_layer, append_token_layer
+    lanes = jnp.arange(B, dtype=jnp.int32)
+    write_pos = jnp.minimum(lengths, S - 1)
+
+    def layer_fn(x, kv, layer, i):  # kv: k, v [L, B, S, nkv, hd]; int8: scales [L, B, nkv, S]
         from ray_tpu.llm.kv_quant import quantize_heads
 
-        if quant:
-            layer, k_cache, v_cache, k_sc, v_sc = xs  # scales: [B, nkv, S]
-        else:
-            layer, k_cache, v_cache = xs  # k/v_cache: [B, S, nkv, hd]
         xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q, k_t, v_t = _qkv(xn, layer, cfg)  # q: [B,1,nh,hd]
         qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [B,1,nh,hd]
         kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
 
-        write_pos = jnp.minimum(lengths, S - 1)
         k_tok, v_tok = kh[:, 0], v_t[:, 0]
         if quant:
             k_tok, sk = quantize_heads(k_tok)  # [B, kv, hd] i8, [B, kv] f32
             v_tok, sv = quantize_heads(v_tok)
-            k_sc = append_scale_layer(k_sc, sk, write_pos)
-            v_sc = append_scale_layer(v_sc, sv, write_pos)
-        k_cache, v_cache = append_token_layer(k_cache, v_cache, k_tok, v_tok, write_pos)
+            # the two index arrays are split by the kv slice, so the indexed slots are [B, kv]
+            kv["k_scale"] = kv["k_scale"].at[i, lanes, :, write_pos].set(sk)
+            kv["v_scale"] = kv["v_scale"].at[i, lanes, :, write_pos].set(sv)
+        # ONE token a lane; inactive lanes are written too (at their stale
+        # length): harmless, the mask never reads past `length`
+        kv["k"] = kv["k"].at[i, lanes, write_pos].set(k_tok.astype(kv["k"].dtype))
+        kv["v"] = kv["v"].at[i, lanes, write_pos].set(v_tok.astype(kv["v"].dtype))
         # GQA attention against the cache: head h uses kv head h // rep
         qg = qh[:, 0].reshape(B, nkv, rep, hd)
-        kc = k_cache.transpose(0, 2, 1, 3)  # [B,nkv,S,hd]
-        vc = v_cache.transpose(0, 2, 1, 3)
+        kc = _layer_of(kv["k"], i).transpose(0, 2, 1, 3)  # [B,nkv,S,hd]
+        vc = _layer_of(kv["v"], i).transpose(0, 2, 1, 3)
         if quant:
-            kc = kc.astype(jnp.float32) * k_sc[..., None]
-            vc = vc.astype(jnp.float32) * v_sc[..., None]
+            kc = kc.astype(jnp.float32) * _layer_of(kv["k_scale"], i)[..., None]
+            vc = vc.astype(jnp.float32) * _layer_of(kv["v_scale"], i)[..., None]
         scores = jnp.einsum("bgrh,bgsh->bgrs", qg, kc, preferred_element_type=jnp.float32) / jnp.sqrt(hd)
         scores = jnp.where(attn_ok, scores, -jnp.inf)  # [B,1,1,S] bcast
         probs = jax.nn.softmax(scores, axis=-1)
         o = jnp.einsum("bgrs,bgsh->bgrh", probs, vc.astype(jnp.float32)).reshape(B, 1, nh * hd).astype(x.dtype)
         x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
         x = _mlp(x, layer, cfg, tpc)
-        return x, ((k_cache, v_cache, k_sc, v_sc) if quant else (k_cache, v_cache))
+        return x, kv
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if quant:
-        xs += (cache["k_scale"], cache["v_scale"])
-    x, ys = jax.lax.scan(layer_fn, x, xs)
+    x, kv = _scan_layers_carrying_cache(layer_fn, x, params, cache)
     x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps)
     unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = _tp_gather_logits(jnp.dot(x, unembed, preferred_element_type=jnp.float32), tpc)
-    if quant:
-        ks, vs, kscs, vscs = ys
-        new_cache = {"k": ks, "v": vs, "k_scale": kscs, "v_scale": vscs, "length": lengths + 1}
-    else:
-        ks, vs = ys
-        new_cache = {"k": ks, "v": vs, "length": lengths + 1}
-    return logits, new_cache
+    return logits, {**kv, "length": lengths + 1}
 
 
 def extend(params, cache, slot, tokens, length, cfg: LlamaConfig):
@@ -462,31 +490,36 @@ def extend(params, cache, slot, tokens, length, cfg: LlamaConfig):
     attn_ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= positions[:, None])[None, None]  # [1,1,T,S]
     zero = jnp.zeros((), jnp.int32)
 
-    def layer_fn(x, xs):
+    def row_of(a, i):  # layer i's rows of this slot: [S, nkv, hd] (scales: [nkv, S])
+        return jax.lax.dynamic_slice(a, (i, slot) + (zero,) * (a.ndim - 2), (1, 1) + a.shape[2:])[0, 0]
+
+    def layer_fn(x, kv, layer, i):  # the stacked leaves ride the carry, as in decode_step
         from ray_tpu.llm.kv_quant import quantize_heads
 
-        if quant:
-            layer, k_row, v_row, k_sc, v_sc = xs  # scales: [nkv, S]
-        else:
-            layer, k_row, v_row = xs  # [S, nkv, hd] for this slot
         xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q, k_t, v_t = _qkv(xn, layer, cfg)  # [1, T, nh/nkv, hd]
         qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)  # [1, nh, T, hd]
         kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [1, T, nkv, hd]
         k_suf, v_suf = kh[0], v_t[0]  # [T, nkv, hd]
+        # a scatter by position, not a dynamic_update_slice at `start`: the
+        # chip's compiler updates the carried cache in place for this and
+        # copies the whole of it for that; and a padded tail that runs past
+        # the horizon is dropped where the slice would be clamped and shift
+        # the whole chunk over the prefix (engine._prefix_fits guards that)
         if quant:
             k_suf, sk = quantize_heads(k_suf)  # sk: [T, nkv]
             v_suf, sv = quantize_heads(v_suf)
-            k_sc = jax.lax.dynamic_update_slice(k_sc, sk.T, (zero, start))
-            v_sc = jax.lax.dynamic_update_slice(v_sc, sv.T, (zero, start))
-        k_row = jax.lax.dynamic_update_slice(k_row, k_suf.astype(k_row.dtype), (start, zero, zero))
-        v_row = jax.lax.dynamic_update_slice(v_row, v_suf.astype(v_row.dtype), (start, zero, zero))
+            # the index arrays are split by the kv slice: the indexed slots are [T, nkv]
+            kv["k_scale"] = kv["k_scale"].at[i, slot, :, positions].set(sk, mode="drop")
+            kv["v_scale"] = kv["v_scale"].at[i, slot, :, positions].set(sv, mode="drop")
+        kv["k"] = kv["k"].at[i, slot, positions].set(k_suf.astype(kv["k"].dtype), mode="drop")
+        kv["v"] = kv["v"].at[i, slot, positions].set(v_suf.astype(kv["v"].dtype), mode="drop")
         qg = qh[0].reshape(nkv, rep, T, hd)
-        kc = k_row.transpose(1, 0, 2)  # [nkv, S, hd]
-        vc = v_row.transpose(1, 0, 2)
+        kc = row_of(kv["k"], i).transpose(1, 0, 2)  # [nkv, S, hd]
+        vc = row_of(kv["v"], i).transpose(1, 0, 2)
         if quant:
-            kc = kc.astype(jnp.float32) * k_sc[..., None]
-            vc = vc.astype(jnp.float32) * v_sc[..., None]
+            kc = kc.astype(jnp.float32) * row_of(kv["k_scale"], i)[..., None]
+            vc = vc.astype(jnp.float32) * row_of(kv["v_scale"], i)[..., None]
         scores = jnp.einsum("grth,gsh->grts", qg, kc, preferred_element_type=jnp.float32) / jnp.sqrt(hd)
         scores = jnp.where(attn_ok[0], scores, -jnp.inf)  # [nkv, rep, T, S] vs [1, T, S]
         probs = jax.nn.softmax(scores, axis=-1)
@@ -494,28 +527,14 @@ def extend(params, cache, slot, tokens, length, cfg: LlamaConfig):
         o = o.transpose(2, 0, 1, 3).reshape(1, T, nh * hd).astype(x.dtype)
         x = x + jnp.dot(o, layer["wo"])
         x = _mlp(x, layer, cfg)
-        return x, ((k_row, v_row, k_sc, v_sc) if quant else (k_row, v_row))
+        return x, kv
 
-    xs = (params["layers"], cache["k"][:, slot], cache["v"][:, slot])  # [L, S, nkv, hd]
-    if quant:
-        xs += (cache["k_scale"][:, slot], cache["v_scale"][:, slot])  # [L, nkv, S]
-    x, ys = jax.lax.scan(layer_fn, x, xs)
+    x, kv = _scan_layers_carrying_cache(layer_fn, x, params, cache)
     x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)  # [T, H]
     x_last = x[jnp.maximum(length - 1, 0)]
     unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
-    if quant:
-        k_new, v_new, ksc_new, vsc_new = ys
-    else:
-        k_new, v_new = ys
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new[:, None], (zero, slot, zero, zero, zero))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new[:, None], (zero, slot, zero, zero, zero))
-    lens = cache["length"].at[slot].set(start + length)
-    if quant:
-        ksc = jax.lax.dynamic_update_slice(cache["k_scale"], ksc_new[:, None], (zero, slot, zero, zero))
-        vsc = jax.lax.dynamic_update_slice(cache["v_scale"], vsc_new[:, None], (zero, slot, zero, zero))
-        return logits, {"k": k, "v": v, "k_scale": ksc, "v_scale": vsc, "length": lens}
-    return logits, {"k": k, "v": v, "length": lens}
+    return logits, {**kv, "length": cache["length"].at[slot].set(start + length)}
 
 
 def decode_attn_paged(params, pool, tables, lengths, tokens, cfg: LlamaConfig, tpc: TpSpec | None = None,

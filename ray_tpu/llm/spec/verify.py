@@ -38,9 +38,11 @@ from ray_tpu.lint import jaxcheck
 from ray_tpu.llm.model_runner import (
     TpSpec,
     _cache_pspecs,
+    _layer_of,
     _mlp,
     _param_pspecs,
     _qkv,
+    _scan_layers_carrying_cache,
     _sds,
     _sds_cache,
     _sds_cache_q,
@@ -152,8 +154,10 @@ def _forward_block_slots(params, cache, toks_blk, cfg: LlamaConfig, tpc: TpSpec 
     as decode_step does per token. ``tpc``: shard_map body mode, as on
     decode_step — verify compiles SPMD like the fused step, with the
     per-layer all-reduce explicit (and optionally int8 on the wire).
-    Returns (logits [B, T, V] f32, ks, vs) — plus (k_scales, v_scales)
-    [L, B, kv, S] when quantized."""
+    The stacked cache leaves ride the layer loop's carry and are written
+    in place, as in decode_step (as the scan's xs/ys they were a second
+    cache to the compiler). Returns (logits [B, T, V] f32, the updated
+    leaves {k, v[, k_scale, v_scale]})."""
     B, T = toks_blk.shape
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     rep = nh // nkv
@@ -167,13 +171,9 @@ def _forward_block_slots(params, cache, toks_blk, cfg: LlamaConfig, tpc: TpSpec 
     # query i sits at position length+i and may attend cache 0..length+i
     attn_ok = (jnp.arange(S, dtype=jnp.int32)[None, None, :] <= positions[:, :, None])[:, None, None]  # [B,1,1,T,S]
 
-    def layer_fn(x, xs):
+    def layer_fn(x, kv, layer, i):
         from ray_tpu.llm.kv_quant import quantize_heads
 
-        if quant:
-            layer, k_cache, v_cache, k_sc, v_sc = xs  # scales: [B, kv, S]
-        else:
-            layer, k_cache, v_cache = xs  # [B, S, kv, hd]
         xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
         q, k_t, v_t = _qkv(xn, layer, cfg)  # [B, T, nh/nkv, hd]
         qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)  # [B, nh, T, hd]
@@ -184,32 +184,29 @@ def _forward_block_slots(params, cache, toks_blk, cfg: LlamaConfig, tpc: TpSpec 
             v_blk, sv = quantize_heads(v_blk)
             # mixed advanced/slice indexing puts the [B, T] index dims
             # first: the indexed scale slots are [B, T, kv]
-            k_sc = k_sc.at[rows, :, positions].set(sk, mode="drop")
-            v_sc = v_sc.at[rows, :, positions].set(sv, mode="drop")
-        k_cache = k_cache.at[rows, positions].set(k_blk.astype(k_cache.dtype), mode="drop")
-        v_cache = v_cache.at[rows, positions].set(v_blk.astype(v_cache.dtype), mode="drop")
+            kv["k_scale"] = kv["k_scale"].at[i, rows, :, positions].set(sk, mode="drop")
+            kv["v_scale"] = kv["v_scale"].at[i, rows, :, positions].set(sv, mode="drop")
+        kv["k"] = kv["k"].at[i, rows, positions].set(k_blk.astype(kv["k"].dtype), mode="drop")
+        kv["v"] = kv["v"].at[i, rows, positions].set(v_blk.astype(kv["v"].dtype), mode="drop")
         qg = qh.reshape(B, nkv, rep, T, hd)
-        kc = k_cache.transpose(0, 2, 1, 3)  # [B, nkv, S, hd]
-        vc = v_cache.transpose(0, 2, 1, 3)
+        kc = _layer_of(kv["k"], i).transpose(0, 2, 1, 3)  # [B, nkv, S, hd]
+        vc = _layer_of(kv["v"], i).transpose(0, 2, 1, 3)
         if quant:
-            kc = kc.astype(jnp.float32) * k_sc[..., None]
-            vc = vc.astype(jnp.float32) * v_sc[..., None]
+            kc = kc.astype(jnp.float32) * _layer_of(kv["k_scale"], i)[..., None]
+            vc = vc.astype(jnp.float32) * _layer_of(kv["v_scale"], i)[..., None]
         scores = jnp.einsum("bgrth,bgsh->bgrts", qg, kc, preferred_element_type=jnp.float32) / jnp.sqrt(hd)
         scores = jnp.where(attn_ok, scores, -jnp.inf)
         o = jnp.einsum("bgrts,bgsh->bgrth", jax.nn.softmax(scores, axis=-1), vc.astype(jnp.float32))
         o = o.transpose(0, 3, 1, 2, 4).reshape(B, T, nh * hd).astype(x.dtype)
         x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
         x = _mlp(x, layer, cfg, tpc)
-        return x, ((k_cache, v_cache, k_sc, v_sc) if quant else (k_cache, v_cache))
+        return x, kv
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if quant:
-        xs += (cache["k_scale"], cache["v_scale"])
-    x, ys = jax.lax.scan(layer_fn, x, xs)
+    x, kv = _scan_layers_carrying_cache(layer_fn, x, params, cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     logits = _tp_gather_logits(jnp.einsum("bth,hv->btv", x, unembed, preferred_element_type=jnp.float32), tpc)
-    return (logits,) + tuple(ys)
+    return logits, kv
 
 
 def _bucket_spec_verify(B=8, S=256, k=4, H=517):
@@ -249,14 +246,12 @@ def spec_verify_slots(
     TOKEN lane is also donated: the host reads the round's results from
     the dedicated emit/logps/acc outputs, never from the token lane."""
     toks_blk = jnp.concatenate([tokens[:, None], proposals], axis=1)
-    logits, *kv_out = _forward_block_slots(params, cache, toks_blk, cfg, tpc)
+    logits, kv = _forward_block_slots(params, cache, toks_blk, cfg, tpc)
     emit, logps, acc, final, new_keys = _accept_and_sample(
         logits, proposals, spec_k, keys, temps, top_k, top_p
     )
     hist, hist_len = _update_hist(hist, hist_len, emit, acc)
-    new_cache = {"k": kv_out[0], "v": kv_out[1], "length": cache["length"] + acc + 1}
-    if len(kv_out) == 4:  # int8 cache: the scale lanes ride the rollback too
-        new_cache["k_scale"], new_cache["v_scale"] = kv_out[2], kv_out[3]
+    new_cache = {**kv, "length": cache["length"] + acc + 1}  # an int8 cache's scale lanes ride the rollback too
     return new_cache, emit, logps, acc, final, new_keys, temps, top_k, top_p, spec_k, hist, hist_len
 
 

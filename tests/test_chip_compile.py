@@ -122,6 +122,59 @@ def test_fused_slot_decode_step_compiles_for_v5e(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 << 30  # fits the chip's HBM
 
 
+def _internlm2_1_8b():
+    """InternLM2-1.8B at its published sizes (benchmark/configs/internlm2-1.8b.json), 4096 positions a slot."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig(vocab_size=92544, hidden_size=2048, intermediate_size=8192, num_layers=24, num_heads=16,
+                       num_kv_heads=8, head_dim=128, max_seq_len=4096, rope_theta=1e6, remat=False)
+
+
+@pytest.mark.parametrize("slots", [12, 16])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+def test_fused_slot_decode_step_updates_its_cache_in_place_at_internlm2_sizes(one_chip, cache_dtype, slots):
+    """PR 30: the cache rides the layer loop's carry, so the compiler aliases every leaf to the
+    donated input and keeps no copy of it. InternLM2-1.8B at its published sizes (the benchmark's
+    `internlm2-1.8b` cells run 12 x 4096): with the cache as the scan's xs and ys the same program
+    held a second cache as temporaries, 6.25 GiB for 6.0 GiB, and 16 x 4096 was refused at 15.77
+    of 15.75 GiB (PERF.md sections 4 and 6)."""
+    from ray_tpu.llm.model_runner import _sds_cache, _sds_cache_q, _sds_lanes, _sds_params, fused_step
+
+    cfg = _internlm2_1_8b()
+    cache = (_sds_cache_q if cache_dtype == "int8" else _sds_cache)(cfg, slots, cfg.max_seq_len)
+    args = _on((_sds_params(cfg), cache) + _sds_lanes(slots), one_chip)
+    step = jax.jit(partial(fused_step, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6))  # make_fused_fns' donation set
+    mem = step.lower(*args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < 0.5 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+@pytest.mark.parametrize("program", ["extend", "spec_verify"])
+def test_extend_and_verify_update_the_slot_cache_in_place_at_internlm2_sizes(one_chip, program):
+    """The two other programs whose layer loop carries the slot cache (PR 30), 12 x 4096 in bf16:
+    the speculative verify step held a second cache as the fused step did (4.74 GiB of
+    temporaries), and a chunk written by dynamic_update_slice at a traced (layer, slot, start)
+    makes this compiler copy the whole carried cache (4.50 GiB) where a scatter by position is
+    done in place (0.13 GiB)."""
+    from ray_tpu.llm.model_runner import _sds, _sds_cache, _sds_lanes, _sds_params, extend
+    from ray_tpu.llm.spec.verify import spec_verify_slots
+
+    cfg = _internlm2_1_8b()
+    B, k, cache = 12, 4, _sds_cache(cfg, 12, 4096)
+    if program == "extend":
+        args = (_sds_params(cfg), cache, _sds((), jnp.int32), _sds((512,), jnp.int32), _sds((), jnp.int32))
+        step = jax.jit(partial(extend, cfg=cfg), donate_argnums=(1,))
+    else:
+        lanes = _sds_lanes(B)
+        args = (_sds_params(cfg), cache, _sds((B, k), jnp.int32), *lanes, _sds((B,), jnp.int32),
+                _sds((B, 517), jnp.int32), _sds((B,), jnp.int32))
+        step = jax.jit(partial(spec_verify_slots, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9, 10))
+    mem = step.lower(*_on(args, one_chip)).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < 0.5 * 2**30
+
+
 # ---------------------------------------------------------------------------
 # four chips: GSPMD cannot partition a Mosaic kernel on its own, so the flash
 # kernel must sit under shard_map wherever a program spans several devices
@@ -151,6 +204,31 @@ def test_tp4_prefill_with_flash_kernel_compiles_for_v5e(topo):
                           mesh, (P(), P()))
     _, txt = _compile(partial(prefill, cfg=cfg, mesh=mesh), params, toks, lens)
     assert "tpu_custom_call" in txt and "all-reduce" in txt
+
+
+def test_tp4_fused_slot_decode_step_updates_its_cache_in_place(topo):
+    """The fused step as a shard_map body over tp=4 (no cell runs it yet): Mistral-7B-v0.3 at its
+    published sizes and full depth, 16 x 4096 (benchmark/configs/mistral-7b-v0.3-tp4.json). A chip's
+    2.0 GiB of cache are aliased and its temporaries are 0.003 GiB, where they were 2.07 GiB with the
+    cache as the layer scan's xs and ys (PR 30)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.llm.model_runner import (
+        _cache_pspecs, _param_pspecs, _sds_cache, _sds_lanes, _sds_params, _sharded_fused_slots,
+    )
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.parallel.mesh import create_mesh
+
+    cfg = LlamaConfig(vocab_size=32768, hidden_size=4096, intermediate_size=14336, num_layers=32, num_heads=32,
+                      num_kv_heads=8, head_dim=128, max_seq_len=4096, rope_theta=1e6, remat=False)
+    mesh, slots = create_mesh(tp=4, devices=topo.devices), 16
+    cache = _sds_cache(cfg, slots, cfg.max_seq_len)
+    args = (_sharded(_sds_params(cfg), mesh, _param_pspecs(cfg, mesh)), _sharded(cache, mesh, _cache_pspecs("slots", False)),
+            *_sharded(_sds_lanes(slots), mesh, (P(),) * 5))
+    step = jax.jit(_sharded_fused_slots(cfg, mesh, "fp", False), donate_argnums=(1, 3, 4, 5, 6))
+    mem = step.lower(*args).compile().memory_analysis()  # bytes on each device
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache)) // 4
+    assert mem.temp_size_in_bytes < 0.25 * 2**30
 
 
 def test_fsdp4_loss_and_grad_with_flash_kernel_compile_for_v5e(topo):

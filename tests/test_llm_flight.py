@@ -401,7 +401,9 @@ def test_serve_shutdown_stops_replicas_before_it_kills_them(tmp_path):
         assert open(marker).read() == "clean"
         log = telemetry.load_flight()
         served = {r["request_id"]: r for r in log["requests"]}
-        assert set(rids) <= set(served) and log["headers"][-1]["pid"] != os.getpid()
+        # some log is the replica's own (files merge in the order of their names, pids compared as text:
+        # which header comes last says nothing)
+        assert set(rids) <= set(served) and any(h["pid"] != os.getpid() for h in log["headers"])
         assert all(served[r]["ingress_t"] and served[r]["last_yield_t"] for r in rids)
         # the worker's span file: the last request's spans, the stream's among them, reached the disk
         names = {s["name"] for s in tracing.load_spans() if s["attrs"].get("request_id") == rids[-1]}
