@@ -50,7 +50,7 @@ TOL = 0.25
 # shapes
 # ----------------------------------------------------------------------------------------------
 def serve_shape(tiny: bool) -> dict:
-    """bench_serve.py's ~1B serving shape at full width and depth."""
+    """A ~1B serving shape at full width and depth."""
     if tiny:
         return dict(vocab_size=512, hidden_size=128, intermediate_size=256, num_layers=2, num_heads=4,
                     num_kv_heads=4, max_seq_len=256, remat=False, dtype="float32")
@@ -59,7 +59,7 @@ def serve_shape(tiny: bool) -> dict:
 
 
 def train_shape(tiny: bool) -> tuple[dict, int, int, int]:
-    """bench.py's SFT shape: (config, batch, seq, steps)."""
+    """The 8 x 2048 SFT shape: (config, batch, seq, steps)."""
     if tiny:
         return dict(vocab_size=512, hidden_size=128, intermediate_size=256, num_layers=2, num_heads=4,
                     num_kv_heads=2, max_seq_len=128), 4, 128, 3
@@ -279,7 +279,7 @@ def phase_reference(a, inp: dict) -> dict:
 
 
 # ----------------------------------------------------------------------------------------------
-# phase 3: train — JaxTrainer, one TPU worker, parallel/train_step.py at bench.py's SFT shape
+# phase 3: train — JaxTrainer, one TPU worker, parallel/train_step.py at the 8 x 2048 SFT shape
 # ----------------------------------------------------------------------------------------------
 def _train_loop(config: dict):
     """Runs in the train worker (the process that holds the chips)."""
@@ -317,7 +317,7 @@ def _train_loop(config: dict):
     wq = state.params["layers"]["wq"]
     shard_devs = sorted({s.device.id for s in wq.addressable_shards})
     shard_frac = wq.addressable_shards[0].data.size / wq.size
-    # loss parity (bench.py): the sharded jitted step must report the loss an unsharded direct
+    # loss parity: the sharded jitted step must report the loss an unsharded direct
     # loss_fn eval computes on the same initial params — here through the XLA attention, so the
     # flash kernel is checked against an independent path as well
     ref_loss = float(jax.jit(partial(loss_fn, config=replace(cfg, attention_impl="xla")))(state.params, data))

@@ -44,8 +44,6 @@ class Config:
     object_store_memory: int = 2 * 1024 * 1024 * 1024
     # Fraction of the store above which LRU-evictable objects are released.
     object_store_eviction_threshold: float = 0.8
-    # Use the C++ shared-memory store when the extension is built.
-    use_native_object_store: bool = True
     # Spill cold sealed objects to disk under memory pressure instead of
     # evicting them (reference: local_object_manager.h:43); restore on read.
     object_spilling_enabled: bool = True
@@ -84,15 +82,10 @@ class Config:
     scheduler_spread_threshold: float = 0.5
     # Max task retries on worker crash when not overridden per task.
     default_max_retries: int = 3
-    # Worker lease/dispatch batch size.
-    dispatch_batch_size: int = 64
 
     # --- worker pool ---
-    num_workers_soft_limit: int = 0  # 0 => num_cpus
     worker_start_method: str = "forkserver"
     prestart_workers: bool = True
-    worker_register_timeout_s: float = 60.0
-    idle_worker_killing_time_s: float = 300.0
 
     # --- health / failure detection ---
     # Reference: gcs_health_check_manager.h — period + failure threshold.
@@ -112,12 +105,6 @@ class Config:
     # worker_killing_policy.h) ---
     memory_monitor_refresh_ms: int = 250  # 0 disables
     memory_usage_threshold: float = 0.95
-    # Actor restart backoff.
-    actor_restart_backoff_s: float = 0.1
-
-    # --- fault injection (reference: rpc_chaos.h, RAY_testing_rpc_failure) ---
-    # Format: "method1=N,method2=M" — fail the first N calls of method1.
-    testing_rpc_failure: str = ""
 
     # --- data ---
     # Blocks observed above this size are split into ~this-sized chunks
@@ -142,16 +129,6 @@ class Config:
     # the ref pump stalls past the grace window).
     owned_object_leak_backstop_s: float = 30.0
 
-    # --- llm serving ---
-    # Device-resident decode loop: per-step state (tokens, PRNG keys,
-    # sampling params, block tables, lengths) lives on device, mutated by
-    # one fused jitted step + small scatter deltas; token readback trails
-    # the dispatch by one step. RT_LLM_DEVICE_RESIDENT=0 restores the
-    # synchronous host-driven loop (also the equivalence-test oracle).
-    llm_device_resident: bool = True
-    # Batch same-bucket prompt prefills into one forward at admission.
-    llm_batch_prefill: bool = True
-
     # --- collective / mesh ---
     collective_timeout_s: float = 120.0
 
@@ -161,14 +138,8 @@ class Config:
     # reference's lineage eviction under max_lineage_bytes).
     max_lineage_tasks: int = 20_000
 
-    # --- observability ---
-    task_events_buffer_size: int = 100_000
-    metrics_report_interval_s: float = 5.0
-    log_to_driver: bool = True
-
     # --- misc ---
     session_dir: str = "/tmp/ray_tpu"
-    enable_timeline: bool = True
 
     extra: dict = field(default_factory=dict)
 

@@ -12,8 +12,8 @@ import os
 import threading
 
 _rng_lock = threading.Lock()
-# os.urandom is a syscall (~30us each — it dominated the put hot path in
-# bench_core); amortize it by drawing entropy in 4 KiB blocks. fork safety:
+# os.urandom is a syscall (~30us each — it dominated the put hot path);
+# amortize it by drawing entropy in 4 KiB blocks. fork safety:
 # the pool is keyed by pid, so children never replay the parent's bytes.
 _POOL_SIZE = 4096
 _pool = b""

@@ -155,7 +155,7 @@ class Scheduler:
     def on_object_sealed(self, obj_id):
         # lock-free fast path: most seals (puts, task returns nobody waits
         # on yet) have no registered waiter, and taking the scheduler lock
-        # per seal dominated put_small in bench_core. Safe because
+        # per seal dominated small puts. Safe because
         # submit() re-checks store.contains(dep) UNDER the lock after
         # registering: a seal that misses the index here is seen by that
         # re-check (dict reads are GIL-atomic). The wake stays

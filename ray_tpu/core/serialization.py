@@ -31,7 +31,7 @@ class Serialized:
 
 # exact types that cannot contain ObjectRefs or closures: the C pickler
 # handles them directly and the cloudpickle sink machinery is pure
-# overhead (it dominated put_small in bench_core)
+# overhead (it dominated small puts)
 _FAST_TYPES = frozenset({bytes, bytearray, str, int, float, bool, type(None)})
 
 

@@ -9,7 +9,7 @@ instead of plasma mutable objects. After ``compile_channel_dag``:
 
 every ``execute`` writes the input into a pinned ring and every hop is a
 ~30us shm write + doorbell — no task submission, no scheduler, no head
-involvement (~10x under the task round trip measured by bench_core.py).
+involvement.
 
 Topology rules (v1, same-host):
   * every compute node is a method bound on an EXISTING actor handle

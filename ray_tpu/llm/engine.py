@@ -31,10 +31,9 @@ python/ray/llm/_internal/serve/engines/vllm/vllm_engine.py):
   token-identical to the plain path (which stays untouched as the
   subsystem's equivalence oracle).
 
-`device_resident=False` (RT_LLM_DEVICE_RESIDENT=0) keeps the old
-synchronous host-driven loop as the equivalence oracle. Engine steps are
-cheap to drive from an actor or a Serve replica; `generate()` is the
-batteries-included loop.
+`device_resident=False` keeps the old synchronous host-driven loop as the
+equivalence oracle. Engine steps are cheap to drive from an actor or a
+Serve replica; `generate()` is the batteries-included loop.
 """
 
 from __future__ import annotations
@@ -326,8 +325,7 @@ class LLMEngine:
         num_pages: int | None = None,
         page_size: int = 64,
         attn_kernel: str = "xla",
-        device_resident: bool | None = None,
-        batch_prefill: bool | None = None,
+        device_resident: bool = True,
         speculative=None,
         telemetry: bool = True,
         telemetry_tags: dict | None = None,
@@ -359,14 +357,13 @@ class LLMEngine:
         cache HBM, with the fp cache as the accuracy oracle
         (tests/test_llm_kv_int8.py).
 
-        device_resident (default: RT_LLM_DEVICE_RESIDENT, on): the decode
-        hot path keeps ALL per-step state on device — one fused jitted
-        step per token, scheduler changes applied as scatter deltas, and
-        token readback overlapped with the next step's dispatch (emission
-        trails the device by exactly one step). Off = the synchronous
+        device_resident (default on): the decode hot path keeps ALL
+        per-step state on device — one fused jitted step per token,
+        scheduler changes applied as scatter deltas, and token readback
+        overlapped with the next step's dispatch (emission trails the
+        device by exactly one step). Off = the synchronous
         host-driven loop (re-uploads + blocking readback per step), kept
-        as the equivalence oracle. batch_prefill (default:
-        RT_LLM_BATCH_PREFILL, on): same-bucket prompt prefills at
+        as the equivalence oracle. Same-bucket prompt prefills at
         admission run as one batched forward.
 
         speculative (llm.spec.SpecConfig | None): speculative decoding on
@@ -636,11 +633,7 @@ class LLMEngine:
             self._prefix_cache.evict_hook = kv_plane.on_evict
         self.preemption_count = 0
 
-        from ray_tpu._config import get_config
-
-        _c = get_config()
-        self._device_resident = bool(_c.llm_device_resident if device_resident is None else device_resident)
-        self._batch_prefill = bool(_c.llm_batch_prefill if batch_prefill is None else batch_prefill)
+        self._device_resident = bool(device_resident)
         # in-flight fused step awaiting host readback:
         # (tokens [B] dev, logps [B] dev, [(RequestState, slot), ...])
         self._pending = None
@@ -2112,10 +2105,7 @@ class LLMEngine:
         return admitted
 
     def _bucket_groups(self, plains):
-        """Group (st, slot, prompt) triples by prefill bucket; without
-        batch_prefill every request is its own group."""
-        if not self._batch_prefill:
-            return [[p] for p in plains]
+        """Group (st, slot, prompt) triples by prefill bucket."""
         groups: dict[int, list] = {}
         for item in plains:
             T = _bucket(len(item[2]), self.prefill_buckets)
@@ -2778,8 +2768,6 @@ class LLMEngine:
                 jnp.asarray(self._lengths),
                 jnp.asarray(self._next_tokens),
             )
-            for st in active:
-                self._lengths[st.slot] += 1
         elif self._hybrid:
             logits, self.cache, self.state, moe = self._decode(
                 self.params, self.cache, self.state, jnp.asarray(self._next_tokens), self._lane_mask(active))
@@ -2796,6 +2784,12 @@ class LLMEngine:
         toks = np.asarray(toks)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
         logps = np.asarray(logps)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
         self._keys = np.array(keys)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
+        if self.kv_layout == "paged":
+            # only after the readbacks above: on the CPU backend jnp.asarray
+            # aliases the host array, and the dispatched decode reads
+            # self._lengths until it has run
+            for st in active:
+                self._lengths[st.slot] += 1
         for st in active:
             self._emit(st, int(toks[st.slot]), float(logps[st.slot]))  # tpulint: disable=CCR002 — sync mode: reads the just-synced host array
         return active

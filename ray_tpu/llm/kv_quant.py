@@ -1,6 +1,6 @@
 """Int8 KV-cache quantization: per-head amax scales, applied on append.
 
-Decode is HBM-bandwidth-bound (bench_serve's roofline fields), and the
+Decode is HBM-bandwidth-bound, and the
 cache — not the weights — is the binding HBM constraint past the
 threshold kv_cache.py documents, so halving cache bytes both doubles
 servable concurrency at fixed HBM and shrinks the bytes every decode

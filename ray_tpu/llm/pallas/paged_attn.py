@@ -12,7 +12,7 @@ exactly once, dequantizes IN REGISTERS with the exact kv_quant recipe
 (int8 * f32 per-head amax scale at the f32 compute dtype), and folds
 into a flash-style online-softmax carry (m/l/acc). Paged decode becomes
 HBM-roofline-bound on the bytes that must move — the pool pages — and
-nothing else (bench_artifacts/README.md has the v5e byte math).
+nothing else.
 
 Scope and contracts:
 

@@ -70,7 +70,7 @@ class LLMConfig:
     # (smallest prefill bucket + fused decode; prefill+extract on prefill
     # replicas) BEFORE the replica reports healthy, so deployment
     # spin-up — not the first request — pays the XLA compiles, in
-    # parallel across replicas (BENCH_scale.json: disagg_spinup)
+    # parallel across replicas
     prewarm: bool = True
     # admission control / load shedding at the replica ingress
     # (serve/overload.AdmissionConfig). None = the default caps; pass

@@ -11,8 +11,7 @@ REJECTED with a typed ``OverloadedError`` (HTTP 429 + retry-after)
 instead of joining a queue that can only grow — so overload shows up as
 shed rate and queue wait in the telemetry plane while in-flight decode
 lanes keep their ITL (the monolithic engine's failure mode is admission
-waves whose prefill forwards stall every live decode stream; see
-bench_serve.py ``engine_overload_ab``).
+waves whose prefill forwards stall every live decode stream).
 
 Everything the controller reads is HOST state: ``engine.host_load()``
 (scheduler shadow queue/slot/occupancy counters — zero device sync, the

@@ -51,7 +51,7 @@ def _compile(fn, *args):
     return compiled, compiled.as_text()
 
 
-# the training shape of bench.py / chip_smoke.py phase 3. VMEM is checked by
+# the training shape of chip_smoke.py phase 3 (8 x 2048 SFT). VMEM is checked by
 # the compile itself (Mosaic refuses a kernel that scopes more than the chip
 # allows); memory_analysis() below checks what the program takes in HBM
 _B, _H, _T, _D = 8, 16, 2048, 128

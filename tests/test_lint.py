@@ -45,6 +45,33 @@ def test_self_check_baseline_not_stale():
     )
 
 
+def _tracked_files():
+    """What git would commit; in a checkout that is not a repository, what is there."""
+    r = subprocess.run(["git", "ls-files"], cwd=ROOT, capture_output=True, text=True)
+    if r.returncode == 0 and r.stdout.strip():
+        return r.stdout.split("\n")
+    return [os.path.relpath(os.path.join(d, n), ROOT) for d, _, names in os.walk(ROOT) for n in names]
+
+
+def test_readme_names_only_files_that_exist():
+    """README.md describes the system as it is: every file it names, in
+    backticks or in a fenced block, is in the tree (`core/runtime.py`
+    for `ray_tpu/core/runtime.py`: matched by suffix)."""
+    with open(os.path.join(ROOT, "README.md"), encoding="utf-8") as f:
+        text = f.read()
+    fence = re.compile(r"```.*?```", re.S)
+    spans = fence.findall(text) + re.findall(r"`([^`\n]+)`", fence.sub("", text))
+    tracked = ["/" + p for p in _tracked_files()]
+    missing = set()
+    for word in " ".join(spans).split():
+        word = re.sub(r"(::.*|:\d[\d,-]*)$", "", word.rstrip(",:;)"))  # a test's name, a line number
+        if not word.endswith((".py", ".json", ".md")) or re.search(r"[*<>{}$\"'(]", word):
+            continue  # not a file's name, or one with a wildcard or a placeholder
+        if not any(p.endswith("/" + word.lstrip("./")) for p in tracked):
+            missing.add(word)
+    assert not missing, f"README.md names files that are not in the tree: {sorted(missing)}"
+
+
 CORE = os.path.join(PKG, "core")  # the CLI-behavior tests scope to one
 # subtree (where the checked-in baseline's entries live): their contracts
 # are path-independent and a full-tree walk per assertion is tier-1 time

@@ -1,9 +1,8 @@
 """Int8 KV cache (llm/kv_quant.py): the fp cache is the accuracy oracle.
 
 - exact top-1: greedy decode with an int8 cache is token-identical to
-  the fp cache on the bench workload (bench_serve's deterministic copy
-  model — the repetitive-suffix regime the bench itself drives), for
-  BOTH layouts;
+  the fp cache on the deterministic copy model (tests/copy_model.py:
+  the repetitive-suffix regime), for BOTH layouts;
 - bounded logit drift: one decode step over identical state, fp vs int8
   cache, asserted within a small max-|delta| bound AND argmax-equal on a
   random model (no copy-model margins to hide behind);
@@ -18,16 +17,12 @@ Lean by design (tier-1 budget): one module-scoped copy-model parameter
 set; engines are built once per (layout, dtype) and reused.
 """
 
-import os
-import sys
-
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from bench_serve import _copy_model_params  # noqa: E402
+from copy_model import copy_model_params  # noqa: E402
 
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
 from ray_tpu.llm.kv_quant import bytes_per_token, normalize_cache_dtype  # noqa: E402
@@ -40,9 +35,9 @@ GREEDY = SamplingParams(temperature=0.0, max_tokens=12)
 
 @pytest.fixture(scope="module")
 def copy_params():
-    """bench_serve's deterministic copy model on the tiny config: greedy
-    decode provably follows a fixed successor map — the bench workload."""
-    return _copy_model_params(CFG, period=PERIOD)
+    """The deterministic copy model on the tiny config: greedy decode
+    provably follows a fixed successor map."""
+    return copy_model_params(CFG, period=PERIOD)
 
 
 @pytest.fixture(scope="module")

@@ -23,6 +23,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from copy_model import copy_model_params  # noqa: E402
+
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
 from ray_tpu.llm.spec import SpecConfig  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
@@ -180,30 +182,12 @@ def test_tp_divisibility_validation():
 # ---------------------------------------------------------------------------
 # int8 quantized all-reduce: accuracy + bytes-on-the-wire gates
 # ---------------------------------------------------------------------------
-def _successor_params(cfg, period=16):
-    """Decisive-logits 'copy model' (the bench_serve idiom): attention
-    and MLP zeroed, unembed wired so greedy decode follows a fixed
-    successor map token -> (token+1) % period. Same shapes/FLOPs as a
-    real model, but top-1 margins are O(1), not O(1e-3) — exactly the
-    regime where a bounded-drift collective must keep argmax."""
-    p = init_params(cfg, jax.random.PRNGKey(0))
-    z = jax.tree.map(jnp.zeros_like, p["layers"])
-    layers = dict(p["layers"])
-    for k in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
-        layers[k] = z[k]
-    emb = np.asarray(jax.random.normal(jax.random.PRNGKey(1), p["embed"].shape, jnp.float32)) * 0.1
-    un = np.zeros(p["unembed"].shape, np.float32)
-    for t in range(period):
-        un += np.outer(emb[t], np.eye(cfg.vocab_size, dtype=np.float32)[(t + 1) % period]) * 4.0
-    return {**p, "layers": layers, "embed": jnp.asarray(emb), "unembed": jnp.asarray(un)}
-
-
 def test_tp_collective_int8_exact_top1_on_decisive_workload():
     """tp_collective='int8' vs 'fp' vs tp=1: exact top-1 (identical
     greedy streams) on the decisive-logits workload — the acceptance
     gate for shipping half the ICI bytes per layer."""
     cfg = LlamaConfig.tiny(num_heads=4, num_kv_heads=4, dtype="float32", attention_impl="xla")
-    params = _successor_params(cfg)
+    params = copy_model_params(cfg)
     prompts = [[0, 1, 2, 3], [8, 9, 10]]
     sp = SamplingParams(temperature=0.0, max_tokens=12)
     kw = dict(max_num_seqs=2, max_seq_len=64)

@@ -1,9 +1,8 @@
 """Core-runtime performance regression floor.
 
-Thresholds are ~5-10x below the measured numbers on the build machine
-(BENCH_core.json) so VM jitter never trips them, but a structural
-regression (an O(n^2) queue scan, a lost zero-copy path, a serialization
-copy) does. Reference parity: python/ray/_private/ray_perf.py is run in
+Thresholds are ~5-10x below the numbers seen on the build machine's CPU
+so VM jitter never trips them, but a structural regression (an O(n^2)
+queue scan, a lost zero-copy path, a serialization copy) does. Reference parity: python/ray/_private/ray_perf.py is run in
 release tests with recorded floors (release/microbenchmark/).
 """
 
@@ -115,8 +114,7 @@ def test_llm_engine_throughput_floor():
 
 def test_llm_int8_decode_step_floor():
     """Int8-KV decode throughput floor: the quantized step must stay no
-    worse than 1.1x the bf16 step on CPU (the perf gate BENCH_serve.json
-    records on a quiet box — here with interleaved best-of-N so load
+    worse than 1.1x the bf16 step on CPU (interleaved best-of-N, so load
     jitter hits both engines alike). A structural regression — dequant
     materializing the full cache in f32 outside the fused step, a
     per-step requant of old positions, a lost scale-lane donation —
@@ -158,9 +156,8 @@ def test_llm_pallas_interpret_step_within_sane_multiple():
     kernel runs in INTERPRET mode on this CPU container) must stay
     within a sane multiple of the XLA step, with matching greedy output.
     The gate is correctness-PRESENCE, not speed — the interpreter is
-    allowed to be slow (measured ~1.4x on this box; 25x leaves room for
-    any CI) and the real perf claim lives in bench_artifacts/README.md's
-    v5e roofline math. What this catches structurally: the kernel
+    allowed to be slow (~1.4x on this box; 25x leaves room for any CI).
+    What this catches structurally: the kernel
     silently falling off its per-page streaming shape (e.g. a whole-pool
     operand slipping into the grid), which multiplies the interpreted
     step by orders of magnitude, or the opt-in quietly breaking output

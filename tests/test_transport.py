@@ -9,6 +9,7 @@ object crossing a node boundary MUST ride the TCP transfer service
 transfer counters and cached-copy segment names.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -20,6 +21,7 @@ import pytest
 import ray_tpu
 from ray_tpu.core import context, transport
 from ray_tpu.util.scheduling_strategies import NodeAffinitySchedulingStrategy
+from ray_tpu.util.state import session_dir
 
 
 def _pin(node):
@@ -194,8 +196,16 @@ def test_agent_join_over_tcp(rt_start):
     n_before = len(client.node_list())
     env = dict(os.environ)
     env.pop("RT_SHM_NS", None)
+    # this session's head by address, from the file an operator on another
+    # host would copy it from: without --address `rt agent` joins the newest
+    # session on the machine, which under several pytest workers is another
+    # worker's
+    with open(os.path.join(session_dir(), "cluster_info.json")) as f:
+        info = json.load(f)
     proc = subprocess.Popen(
-        [sys.executable, "-m", "ray_tpu.scripts.cli", "agent", "--num-cpus", "2"],
+        [sys.executable, "-m", "ray_tpu.scripts.cli", "agent", "--num-cpus", "2",
+         "--address", "{}:{}".format(*info["agent_address"]), "--authkey", info["authkey"],
+         "--transfer-authkey", info["transfer_authkey"]],
         env=env,
         stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT,
