@@ -44,26 +44,41 @@ class SamplingParams:
             raise ValueError("top_k must be >= 0")
 
 
+def _ordered_keys(x):
+    """float32 -> uint32 whose unsigned order is the floats' order (-inf lowest, -0.0 just under 0.0)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _threshold(keys, mass_from, target):
+    """Per row, the largest uint32 ``t`` with ``mass_from(keys >= t) >= target`` (0 where no ``t``
+    reaches it: everything is kept). ``mass_from`` sums a row's mask over the vocabulary axis and
+    must not grow as the mask shrinks. Found bit by bit from the top, so in 32 passes over the
+    row whatever its length: no sort. ``t`` is always one of the row's own keys, or 0."""
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(mass_from(keys >= cand[..., None]) >= target, cand, t)
+
+    return jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
 def _apply_top_k(logits, top_k):
-    """Mask logits outside the per-row top-k (top_k[b] == 0 disables)."""
-    vocab = logits.shape[-1]
-    # rank of each logit within its row (0 = largest)
-    order = jnp.argsort(logits, axis=-1)[..., ::-1]
-    ranks = jnp.argsort(order, axis=-1)
-    k = jnp.where(top_k <= 0, vocab, top_k)[..., None]
-    return jnp.where(ranks < k, logits, -jnp.inf)
+    """Mask logits under the row's top_k-th largest value (top_k <= 0 disables). Tokens tied with
+    the k-th are all kept, as _apply_top_p keeps those tied at its threshold."""
+    keys = _ordered_keys(logits)
+    k = jnp.where(top_k <= 0, logits.shape[-1], top_k)
+    thresh = _threshold(keys, lambda m: jnp.sum(m, axis=-1, dtype=jnp.int32), k)
+    return jnp.where(keys >= thresh[..., None], logits, -jnp.inf)
 
 
 def _apply_top_p(logits, top_p):
-    """Nucleus filtering: keep the smallest prefix with cumprob >= top_p."""
-    sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # keep tokens while the cumulative mass *before* them is < top_p
-    keep_sorted = (cum - probs) < top_p[..., None]
-    # threshold logit = smallest kept logit per row
-    thresh = jnp.min(jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True)
-    return jnp.where(logits >= thresh, logits, -jnp.inf)
+    """Nucleus filtering: keep the smallest set of most probable tokens whose mass reaches top_p
+    (top_p >= 1 keeps everything), and every token tied with the last one kept."""
+    keys = _ordered_keys(logits)
+    probs = jax.nn.softmax(logits, axis=-1)
+    thresh = _threshold(keys, lambda m: jnp.sum(jnp.where(m, probs, 0.0), axis=-1), top_p)
+    return jnp.where((keys >= thresh[..., None]) | (top_p >= 1.0)[..., None], logits, -jnp.inf)
 
 
 def filter_logits(logits, temperature, top_k, top_p):
@@ -74,10 +89,20 @@ def filter_logits(logits, temperature, top_k, top_p):
     speculative verify step (llm/spec/verify.py) — spec acceptance must
     judge proposals against exactly the distribution plain sampling
     draws from, or rejection sampling would drift off-policy.
+
+    Both filters select by a threshold found in 32 counting passes over
+    the row (_threshold), never by sorting the vocabulary, and a filter
+    that no row of the batch asks for is not run (a lax.cond on the whole
+    batch: under vmap it would become a select that runs both sides, so
+    callers pass the batch, not a row). A row's result does not depend on
+    which branch its neighbours chose. Ties: top-k keeps every token tied
+    with the k-th largest, so more than k where the k-th value repeats.
     """
+    lead = logits.shape[:-1]
+    top_k, top_p = jnp.broadcast_to(top_k, lead), jnp.broadcast_to(top_p, lead)
     scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
-    scaled = _apply_top_k(scaled, top_k)
-    return _apply_top_p(scaled, top_p)
+    scaled = jax.lax.cond(jnp.any(top_k > 0), _apply_top_k, lambda x, _: x, scaled, top_k)
+    return jax.lax.cond(jnp.any(top_p < 1.0), _apply_top_p, lambda x, _: x, scaled, top_p)
 
 
 def sample(logits, key, temperature, top_k, top_p):
@@ -86,17 +111,22 @@ def sample(logits, key, temperature, top_k, top_p):
     logits: [B, V] f32; temperature/top_p: [B] f32; top_k: [B] i32;
     key: [B, 2] u32 per-slot PRNG keys. Returns (tokens [B] i32,
     logprobs [B] f32, new_keys [B, 2]).
+
+    One path whatever the lanes ask for: a step whose lanes are all
+    greedy draws too. A conditional around the filter and the draw would
+    save such a step 0.06 ms of a 15 ms decode step at 12 x 92,544 on a
+    v5e, and 0.17 where a lane asks for top-p (PERF.md section 6, PR 32).
+    Each row's key advances by its own split, whatever its neighbours do.
     """
     logits = logits.astype(jnp.float32)
     greedy_tok = jnp.argmax(logits, axis=-1)
+    scaled = filter_logits(logits, temperature, top_k, top_p)
 
-    def _one(lg, k, temp, tk, tp):
+    def _one(lg, k):
         k1, k2 = jax.random.split(jax.random.wrap_key_data(k, impl="threefry2x32"))
-        scaled = filter_logits(lg[None], temp[None], tk[None], tp[None])[0]
-        tok = jax.random.categorical(k1, scaled)
-        return tok, jax.random.key_data(k2)
+        return jax.random.categorical(k1, lg), jax.random.key_data(k2)
 
-    sampled_tok, new_keys = jax.vmap(_one)(logits, key, temperature, top_k, top_p)
+    sampled_tok, new_keys = jax.vmap(_one)(scaled, key)
     tokens = jnp.where(temperature == 0.0, greedy_tok, sampled_tok).astype(jnp.int32)
     logp = jax.nn.log_softmax(logits, axis=-1)
     chosen_logp = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]

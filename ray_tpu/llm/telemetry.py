@@ -329,7 +329,10 @@ class FlightRecorder:
     and the JSONL dump are cold paths."""
 
     STEP_FIELDS = (
-        "step", "t", "phase", "wall_ms", "admitted", "emitted", "batch", "waiting",
+        "step", "t", "phase", "wall_ms", "admitted", "emitted", "batch",
+        # bound lanes with temperature > 0: the lanes whose top-k or top-p the sampler's counting
+        # passes run for (llm/sampling.py); a step with none takes its tokens from the argmax
+        "sampling_lanes", "waiting",
         "occupied_tokens", "capacity_tokens", "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
         # the step's start and the instant its fused program was enqueued
@@ -842,6 +845,7 @@ class EngineTelemetry:
         stages, dispatch_t = self._stage_ms, self.dispatch_t
         self._stage_ms, self.dispatch_t = [0.0] * len(STAGES), None
         slots_in_use = sum(1 for s in eng._slots if s is not None)
+        sampling_lanes = sum(1 for s in eng._slots if s is not None and s.params.temperature > 0.0)
         waiting = len(eng._waiting)
         phase = (
             "idle" if not n_admitted and not slots_in_use and not n_emitted
@@ -875,7 +879,7 @@ class EngineTelemetry:
         moe = eng._moe_stats  # host array of the drained step (hybrid models), else None
         moe = _NO_MOE if moe is None else tuple(round(float(v), 3) for v in moe)
         self.recorder.record_step((
-            now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, waiting,
+            now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, sampling_lanes, waiting,
             occupied, capacity,
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,

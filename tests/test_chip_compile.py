@@ -175,6 +175,61 @@ def test_extend_and_verify_update_the_slot_cache_in_place_at_internlm2_sizes(one
     assert mem.temp_size_in_bytes < 0.5 * 2**30
 
 
+def _vocabulary_wide_selections(hlo_text: str, vocab: int) -> list[str]:
+    """Instructions of an HLO module that order or select over an array with an axis of ``vocab``
+    entries: a sort, or a top-k however it is spelled (exact or approximate, instruction or custom
+    call). The text names operands without their shapes, so each is looked up where it is defined."""
+    import re
+
+    shaped = re.compile(r"\[(?:\d+,)*%d(?:,\d+)*\]" % vocab)
+    call = re.compile(r"\b(sort|topk|top-k|custom-call)\(([^)]*)\)", re.IGNORECASE)
+    lines = hlo_text.splitlines()
+    defined = {m.group(1): ln for ln in lines if (m := re.match(r"\s*(?:ROOT )?(\S+) = ", ln))}
+    found = []
+    for ln in lines:
+        m = call.search(ln)
+        if m is None or (m.group(1) == "custom-call" and not re.search(r'custom_call_target="[^"]*(?:TopK|Sort)', ln, re.IGNORECASE)):
+            continue
+        operands = [defined.get(name.lstrip("%"), "") for name in re.findall(r"%?[\w.\-]+", m.group(2))]
+        if any(shaped.search(text) for text in [ln, *operands]):
+            found.append(ln.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("program", ["llm_fused_step", "llm_hybrid_fused_step", "llm_fused_paged_step", "llm_verify_step"])
+def test_no_step_program_sorts_the_vocabulary(one_chip, program):
+    """PR 32: the sampler finds its top-k and top-p thresholds in counting passes, so no step
+    program holds a sort (or a top-k) whose operand has the vocabulary as an axis, on either side
+    of its conditionals: the text is the lowered module's, before the compiler drops anything. On
+    the parent each of these held three (`sort.2`, `sort`, `sort.11`: 12 of chat's 27 ms a step).
+    The hybrid router's choice of 6 among 128 experts is not the vocabulary and stays."""
+    from ray_tpu.llm.model_runner import _sds, _sds_cache, _sds_lanes, _sds_params, _sds_pool, fused_step, paged_fused_step
+    from ray_tpu.llm.spec.verify import spec_verify_slots
+
+    cfg, B = _internlm2_1_8b(), 12
+    if program == "llm_fused_step":
+        fn, args = partial(fused_step, cfg=cfg), (_sds_params(cfg), _sds_cache(cfg, B, 4096)) + _sds_lanes(B)
+    elif program == "llm_fused_paged_step":
+        page, max_pg = 16, 4096 // 16
+        fn = partial(paged_fused_step, cfg=cfg)
+        args = (_sds_params(cfg), _sds_pool(cfg, B * max_pg + 1, page), _sds((B, max_pg), jnp.int32), _sds((B,), jnp.int32)) + _sds_lanes(B)
+    elif program == "llm_verify_step":
+        fn = partial(spec_verify_slots, cfg=cfg)
+        args = (_sds_params(cfg), _sds_cache(cfg, B, 4096), _sds((B, 4), jnp.int32), *_sds_lanes(B), _sds((B,), jnp.int32),
+                _sds((B, 517), jnp.int32), _sds((B,), jnp.int32))
+    else:
+        from ray_tpu.llm import hybrid_runner as hr
+
+        cfg, params, cache, state = _hybrid_at_the_benchmarks_size(one_chip)
+        fn, B = partial(hr.fused_step, cfg=cfg), 32
+        args = (params, cache, state, *_sds_lanes(B), _sds((B,), jnp.bool_))
+    txt = jax.jit(fn).lower(*_on(args, one_chip)).compiler_ir(dialect="hlo").as_hlo_text()
+    assert _vocabulary_wide_selections(txt, cfg.vocab_size) == []
+    assert "conditional(" in txt, "each filter sits under a conditional on the whole batch"
+    if program == "llm_hybrid_fused_step":
+        assert _vocabulary_wide_selections(txt, cfg.n_routed_experts), "the reader sees the router's selection over 128 experts"
+
+
 # ---------------------------------------------------------------------------
 # four chips: GSPMD cannot partition a Mosaic kernel on its own, so the flash
 # kernel must sit under shard_map wherever a program spans several devices

@@ -155,6 +155,28 @@ def test_a_hybrid_models_rows_carry_routing_counters_and_the_state_insert_stage(
     assert eng._tel._state_bytes == eng.kv_cache_stats()["state_allocated_bytes"] > 0 and plain._tel._state_bytes == 0
 
 
+def test_every_step_row_counts_the_lanes_that_sample():
+    """PR 32: ``sampling_lanes`` is the count of bound lanes with temperature > 0, from the host's
+    lane table: the lanes whose top-k or top-p make a step run the sampler's counting passes."""
+    eng = _engine(max_num_seqs=4)
+    eng.generate([[1, 2, 3], [4, 5]], SamplingParams(max_tokens=4))
+    greedy_rows = eng.telemetry()["step_count"]
+    params = [SamplingParams(max_tokens=4, temperature=0.8, seed=1), SamplingParams(max_tokens=12),
+              SamplingParams(max_tokens=8, temperature=0.7, top_p=0.9, seed=2)]
+    ids = [eng.add_request([7, 8, 9 + i], sp) for i, sp in enumerate(params)]
+    want = []
+    while eng.has_unfinished():
+        eng.step()
+        bound = {s.request_id for s in eng._slots if s is not None}
+        want.append(sum(1 for i, sp in zip(ids, params) if i in bound and sp.temperature > 0.0))
+    steps = eng.telemetry()["steps"]
+    assert all(isinstance(s["sampling_lanes"], int) and 0 <= s["sampling_lanes"] <= s["batch"] for s in steps)
+    assert all(s["sampling_lanes"] == 0 for s in steps[:greedy_rows])
+    assert [s["sampling_lanes"] for s in steps[greedy_rows:]] == want
+    seen = [n for n, prev in zip(want, [None] + want) if n != prev]
+    assert seen == [2, 1, 0], "both sampled requests, then the longer one alone, then the greedy one alone"
+
+
 def test_an_uninstrumented_engine_steps_through_the_same_code():
     eng = _engine(telemetry=False)
     out = eng.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
