@@ -96,16 +96,6 @@ def parameters_held(c: dict) -> int:
     return sum(p[ch] for ch in pat) + p["embed_and_head"] + p["final_norm"]
 
 
-def matmul_params(c: dict) -> int:
-    """What ``tests/benchmark/test_benchmark_harness.py`` holds every configuration to, in the
-    terms of the block it was written for: the parameters held, less the embedding table (a
-    lookup) and two norm vectors a layer plus the final one. This block has ONE norm a layer and,
-    in its ``M`` layers, a gate norm, the convolution's taps and per-head scalars, so the name
-    does not fit it; nothing here reads it. ``decode_step_least`` and ``train_flops_per_token``
-    count for themselves."""
-    return parameters_held(c) - c["vocab_size"] * c["hidden_size"] - (2 * c["num_hidden_layers"] + 1) * c["hidden_size"]
-
-
 def state_bytes_per_slot(c: dict, itemsize: int = 2) -> int:
     """What the ``M`` layers keep for one sequence: a float32 state and the convolution's window."""
     nh, P, N = c["mamba_num_heads"], c["mamba_head_dim"], c["ssm_state_size"]

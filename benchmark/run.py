@@ -107,11 +107,16 @@ def main() -> int:
     reaper = Reaper()
     reaper.start()  # before the runtime: the forkserver and every worker inherit the marker
     try:
-        if a.sweep:
-            return driver.sweep(a, cell)
-        res = driver.run(a, cell, T_PROC0)
+        res = driver.sweep(a, cell) if a.sweep else driver.run(a, cell, T_PROC0)
     finally:
         end_of_run(reaper, time.time())
+    if a.sweep:
+        # past the knee a sweep leaves client threads blocked on streams of a replica that is gone, and the
+        # interpreter would wait for them at exit (PR 33: 20 minutes, until the call's limit): every process of
+        # the run has ended and the table is printed, so leave without them
+        sys.stdout.flush()
+        sys.stderr.flush()
+        os._exit(res)
 
     dev = res["device"]
     on_tpu = dev["platform"] == "tpu"

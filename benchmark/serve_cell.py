@@ -85,7 +85,8 @@ class Client:
                     rec["rid"] = json.loads(chunk[6:])["id"]
                 rec["stamps"].append(now)
         except Exception as e:  # noqa: BLE001 - a shed (429), a timeout, a dead replica: all count as failed
-            rec["error"] = f"{type(e).__name__}: {e}"[:200]
+            text = f"{type(e).__name__}: {e}"
+            rec["error"] = text if len(text) <= 600 else text[:150] + " [...] " + text[-450:]  # a traceback's cause is at its end
         rec["ended"] = time.time()
         return rec
 
@@ -315,7 +316,8 @@ def sweep(a, cell: dict) -> int:
             row = {"rate_per_s": rate, "sent": sm["attempted"], "failed": sm["failed"],
                    "finished_by_step_end_share": finished_in_step / max(1, sm["attempted"]),
                    "in_flight_at_step_end": backlog, "drain_s": time.time() - t_end,
-                   **{k2: sm.get(k2) for k2 in ("ttft_p50_ms", "ttft_p95_ms", "itl_p50_ms", "itl_p95_ms", "serve_tokens_per_s", "gen_late_p95_ms")}}
+                   **{k2: sm.get(k2) for k2 in ("ttft_p50_ms", "ttft_p95_ms", "itl_p50_ms", "itl_p95_ms", "stalled_gap_share", "in_flight_mean",
+                                                   "serve_tokens_per_s", "gen_late_p95_ms")}}
             rows.append(row)
             say(f"sweep: {json.dumps(row)}")
     print(json.dumps({"sweep": rows, "device": info["device"]}), flush=True)

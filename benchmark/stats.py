@@ -35,6 +35,12 @@ def serve_summary(records: list[dict], t0: float, t1: float, miss_ms: float) -> 
     time to first token and its gaps enter the percentiles as ``miss_ms`` (the drain limit), so
     a failure can only make a tail worse. Tokens per second counts prompt plus generated tokens
     of requests that COMPLETED inside the window, whenever they were due, over the window.
+
+    Printed with the summary and no metric of the benchmark: ``stalled_gap_share``, the share of
+    the gaps over twice the median gap (an admission held the stream); ``itl_quantiles``, the
+    gaps' 90th to 99th percentiles in ms, which say in what population of gaps the bounded 95th
+    rests; ``in_flight_mean``, the requests between sent and done averaged over the window (one
+    that never finished counts to the window's end, one that was refused or broke not at all).
     """
     due = [r for r in records if t0 <= r["due"] < t1]
     ok = [r for r in due if r["error"] is None and r["done"] is not None and len(r["stamps"]) == r["max_tokens"]]
@@ -52,6 +58,10 @@ def serve_summary(records: list[dict], t0: float, t1: float, miss_ms: float) -> 
         out.update(ttft_p50_ms=median(ttft), ttft_p95_ms=percentile(ttft, 95))
     if gaps:
         out.update(itl_p50_ms=median(gaps), itl_p95_ms=percentile(gaps, 95))
+        out["stalled_gap_share"] = sum(g > 2 * out["itl_p50_ms"] for g in gaps) / len(gaps)
+        out["itl_quantiles"] = {str(q): percentile(gaps, q) for q in (90, 92.5, 97.5, 99)}
+    in_flight_s = sum(max(0.0, min(r["done"] or t1, t1) - max(r["sent"], t0)) for r in records if r["error"] is None)
+    out["in_flight_mean"] = in_flight_s / (t1 - t0)
     if late:
         out.update(gen_late_p95_ms=percentile(late, 95))
     return out

@@ -27,6 +27,11 @@ CONFIGS = sorted(f[:-5] for f in os.listdir(os.path.join(common.HERE, "configs")
 READERS = sorted(f[:-3] for f in os.listdir(os.path.join(common.HERE, "metrics")) if f.endswith(".py"))
 
 
+def _config_file(config):
+    with open(os.path.join(common.HERE, "configs", config + ".json")) as f:
+        return json.load(f)
+
+
 # ---------------------------------------------------------------------------------- traffic
 @pytest.mark.parametrize("mix_name", SERVE_MIXES)
 def test_requests_are_a_pure_function_of_the_seed(mix_name):
@@ -144,6 +149,10 @@ def test_serve_summary_on_recorded_samples():
     assert s["ttft_p95_ms"] == pytest.approx(30000.0)  # a failure misses every limit
     assert s["n_gaps"] == 4 + 2 and s["itl_p50_ms"] == pytest.approx(statistics.median([100, 200, 50, 1800, 30000, 30000]))
     assert s["gen_late_p95_ms"] == pytest.approx(stats.percentile([2, 50, 2, 2, 2], 95))
+    # printed beside them and, by their names, on offer to no cell: gaps over twice the median gap (1000: the two failures), requests in flight
+    assert s["stalled_gap_share"] == pytest.approx(2 / 6)
+    assert s["in_flight_mean"] == pytest.approx((0.6 + 0.398 + 0.3 + 0.998 + 4.998) / 10.0)
+    assert s["itl_quantiles"]["99"] == pytest.approx(30000.0) and s["itl_quantiles"]["90"] <= s["itl_quantiles"]["97.5"]
 
 
 def test_train_summary_counts_all_the_steps_and_all_the_time():
@@ -272,21 +281,38 @@ def test_check_served_notices(tiny, wrong):
 
 
 # -------------------------------------------------------------------------------- flops, peaks
+def llama_configs(configs):
+    """The configurations whose block is the ``llama`` family's: the only ones the Llama block's identities are asked of."""
+    return [c for c in configs if _config_file(c)["family"] == "llama"]
+
+
 @pytest.mark.parametrize("config", CONFIGS)
 def test_matmul_params_and_published_count(config):
-    with open(os.path.join(common.HERE, "configs", config + ".json")) as f:
-        c = json.load(f)
-    family = common.load_family(c["family"])
-    cfg = family.program_config(c, 2048)
+    """Whatever its family, a configuration is held to what ITS family says of it: the program's
+    config built from the file counts the parameters the file states, and its heads are as wide as
+    the file says, where the file says (a latent-attention block has no one head width to state)."""
+    c = _config_file(config)
+    cfg = common.load_family(c["family"]).program_config(c, 2048)
     assert cfg.num_params() == c["parameters"]
+    if "head_dim" in c:
+        assert cfg.hd == c["head_dim"]
+
+
+@pytest.mark.parametrize("config", llama_configs(CONFIGS))
+def test_the_llama_familys_matmul_params_are_all_but_the_table_and_the_norms(config):
+    """Only of the ``llama`` family: a block of two norm vectors a layer and a final one, whose
+    every other parameter but the embedding table multiplies each token; heads 128 wide, the width
+    ``flops.flash_roofline`` and the flash kernel's compile tests were written at."""
+    c = _config_file(config)
+    family = common.load_family("llama")
+    cfg = family.program_config(c, 2048)
     norms = c["num_hidden_layers"] * 2 * c["hidden_size"] + c["hidden_size"]
     assert family.matmul_params(c) == cfg.num_params() - c["vocab_size"] * c["hidden_size"] - norms
     assert cfg.hd == c["head_dim"] == 128
 
 
 def test_train_flops_and_flash_roofline():
-    with open(os.path.join(common.HERE, "configs", "mistral-7b-v0.3-d6.json")) as f:
-        c = json.load(f)
+    c = _config_file("mistral-7b-v0.3-d6")
     family = common.load_family(c["family"])
     per_tok = family.train_flops_per_token(c, 2048)
     assert per_tok == pytest.approx(6 * family.matmul_params(c) + 3 * c["num_hidden_layers"] * 2 * 32 * 2048 * 128)
@@ -417,8 +443,7 @@ def test_serve_summary_offers_every_serving_end_to_end_metric(metric):
 
 
 def test_training_readers_on_recorded_observations():
-    with open(os.path.join(common.HERE, "configs", "mistral-7b-v0.3-d6.json")) as f:
-        c = json.load(f)
+    c = _config_file("mistral-7b-v0.3-d6")
     peaks = peaks_of("TPU v5 lite")
     r = flops.flash_roofline(c, 8, 2048, peaks)
     L = c["num_hidden_layers"]

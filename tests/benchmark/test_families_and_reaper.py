@@ -43,10 +43,64 @@ def rehearsal(c):
     return {**_llama.rehearsal(c), "num_hidden_layers": 3}
 '''
 
+# a block that is not the Llama block in any count: three kinds of layer, a gate norm, a convolution and per-head
+# scalars in one of them, and attention heads 256 wide. Its wiring and reference are the hybrid family's, under a
+# name of its own, as a model_config PR would bring them: a family file and a configuration file, nothing else
+WIDE_FAMILY = '''"""A family for the tests: the hybrid block under another name, for a configuration whose heads are 256 wide."""
+from benchmark.families import nemotron_h as _hybrid
+from benchmark.families.nemotron_h import (init_params, kernels_expected, loss_fn, param_logical_axes, program_config,  # noqa: F401
+                                           reference_logprobs, train_flops_per_token)
+
+
+def rehearsal(c):
+    return {**_hybrid.rehearsal(c), "head_dim": 32, "num_attention_heads": 2, "num_key_value_heads": 1}
+'''
+
 
 def _config(name):
     with open(os.path.join(common.HERE, "configs", name + ".json")) as f:
         return json.load(f)
+
+
+def _wide_config():
+    """``configs/nemotron-3-nano-30b-a3b-ep2.json`` with heads 256 wide, counted by its own family's rule."""
+    from benchmark.families import nemotron_h
+
+    c = {**_config("nemotron-3-nano-30b-a3b-ep2"), "family": "wide", "head_dim": 256, "training": {"remat": False, "attention_impl": "xla"}}
+    c["parameters"] = nemotron_h.parameters_held(c)
+    c["tolerance"] = {**c["tolerance"], "loss_abs": 0.002}
+    return c
+
+
+def _copy_with(tmp_path, name: str, family_source: str, c: dict, traffics: tuple) -> dict:
+    """A copy of the benchmark under ``tmp_path`` with one more family, configuration and a cell of it
+    under each of ``traffics``: new files and new entries, as a later PR may bring them. -> its BENCHMARK.json."""
+    bench = common.load_benchmark()
+    for p in bench["paths"]:
+        shutil.copytree(os.path.join(ROOT, p), tmp_path / p, ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "benchmark" / "families" / f"{name}.py").write_text(family_source)
+    (tmp_path / "benchmark" / "configs" / f"{name}.json").write_text(json.dumps(c))
+    bench["configs"].append({"name": name, "source": c["source"], "file": f"benchmark/configs/{name}.json", "reduced": c["reduced"], "why": "a test's"})
+    bench["workloads"] += [{"name": f"{name}.{t}", "config": name, "traffic": t, "chips": 1, "why": "a test's"} for t in traffics]
+    if "chat" in traffics:  # an open-loop cell brings its rate
+        (tmp_path / "benchmark" / "cells" / f"{name}.chat.json").write_text(json.dumps({"rate_per_s": 2.0, "why_rate": "a test's"}))
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("itl_p95_ms", "engine_step_ms") and "chat" in traffics:
+            m["workloads"].append(f"{name}.chat")
+        if m["name"] in ("train_tokens_per_s", "flash_roofline") and "sft-2k" in traffics:
+            m["workloads"].append(f"{name}.sft-2k")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return bench
+
+
+def _nothing_that_was_there_was_touched(tmp_path, bench):
+    for p in bench["paths"]:
+        stack = [filecmp.dircmp(os.path.join(ROOT, p), tmp_path / p, ignore=["__pycache__"])]
+        while stack:
+            d = stack.pop()
+            assert not d.diff_files and not d.left_only, (d.left, d.diff_files, d.left_only)
+            stack += d.subdirs.values()
+
 
 
 # ----------------------------------------------------------------------------------- the seam
@@ -115,9 +169,10 @@ def test_the_reference_file_holds_no_layer_equations():
 
 def test_a_serving_blocks_engine_kwargs_reach_the_engine_beside_the_sizes():
     sv = _config("internlm2-1.8b")["serving"]
-    assert engine_kwargs(sv, 7) == {"seed": 7, "max_num_seqs": 12, "max_seq_len": 4096}  # the files that are there: nothing changes
+    sizes = {"max_num_seqs": sv["max_num_seqs"], "max_seq_len": 4096}
+    assert engine_kwargs(sv, 7) == {"seed": 7, **sizes}  # the files that are there: nothing but the seed and the sizes
     paged = engine_kwargs({**sv, "engine_kwargs": {"kv_layout": "paged", "seed": 1}}, 7)
-    assert paged == {"kv_layout": "paged", "seed": 7, "max_num_seqs": 12, "max_seq_len": 4096}  # the run's seed wins
+    assert paged == {"kv_layout": "paged", "seed": 7, **sizes}  # the run's seed wins
 
 
 # ------------------------------------------------------------------- a second family: new files only
@@ -164,50 +219,95 @@ def _marked(prefix: str) -> list[int]:
     return out
 
 
+def _rehearse_both_drivers(tmp_path, name: str):
+    """``--rehearse`` of the training and the serving driver in the copy: each prints the family it used and
+    agrees with that family's reference, and nothing that carried a run's marker is alive afterwards."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": "--xla_force_host_platform_device_count=1", "PYTHONPATH": ROOT}
+    for cell, says in ((f"{name}.sft-2k", f"[train] family {name};"), (f"{name}.chat", f"[serve] family {name};")):
+        proc = subprocess.Popen([sys.executable, str(tmp_path / "benchmark" / "run.py"), "--workload", cell, "--seed", "3000000019",
+                                 "--seconds", "3", "--trace", "0", "--rehearse"], cwd=tmp_path, env=env, text=True,
+                                stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+        out, _ = proc.communicate(timeout=600)
+        assert proc.returncode == 1 and says in out, out[-3000:]
+        lines = out.strip().splitlines()
+        assert json.loads(lines[-1])["rehearsal"] is True
+        assert any(ln.startswith("[run] processes: ") and "SIGKILL after 20 s to 0 []" in ln for ln in lines), out[-3000:]
+        assert not _marked(f"{proc.pid}-")
+        if cell.endswith(".chat"):
+            assert '[serve] reference: {"ok": true' in out, out[-3000:]
+        else:
+            assert "(d 0.0000), step after the window" in out, out[-3000:]
+
+
 @pytest.mark.slow
 def test_a_second_family_is_new_files_only_and_a_rehearsal_leaves_no_process(tmp_path):
     """Copies the benchmark, drops in ``families/toy.py``, a configuration file and two cells'
     entries, and rehearses both drivers there: each prints the family it used, the reference that
     ran was the toy's, no file that was there changed, and nothing that carried a run's marker is
     alive afterwards."""
-    bench = common.load_benchmark()
-    for p in bench["paths"]:
-        shutil.copytree(os.path.join(ROOT, p), tmp_path / p, ignore=shutil.ignore_patterns("__pycache__"))
-    (tmp_path / "benchmark" / "families" / "toy.py").write_text(TOY_FAMILY)
     c = {**_config("internlm2-1.8b"), "family": "toy", "training": {"remat": False, "attention_impl": "xla"}}
     c["tolerance"] = {**c["tolerance"], "loss_abs": 0.002}
-    (tmp_path / "benchmark" / "configs" / "toy.json").write_text(json.dumps(c))
-    bench["configs"].append({"name": "toy", "source": c["source"], "file": "benchmark/configs/toy.json", "reduced": [], "why": "a test's"})
-    bench["workloads"] += [{"name": "toy.chat", "config": "toy", "traffic": "chat", "chips": 1, "why": "a test's"},
-                           {"name": "toy.sft-2k", "config": "toy", "traffic": "sft-2k", "chips": 1, "why": "a test's"}]
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if m["name"] in ("itl_p95_ms", "engine_step_ms"):
-            m["workloads"].append("toy.chat")
-        if m["name"] == "train_tokens_per_s":
-            m["workloads"].append("toy.sft-2k")
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": "--xla_force_host_platform_device_count=1", "PYTHONPATH": ROOT}
-    for cell, says in (("toy.sft-2k", "[train] family toy;"), ("toy.chat", "[serve] family toy;")):
-        proc = subprocess.Popen([sys.executable, str(tmp_path / "benchmark" / "run.py"), "--workload", cell, "--seed", "3000000019",
-                                 "--seconds", "3", "--trace", "0", "--rehearse"], cwd=tmp_path, env=env, text=True,
-                                stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-        out, _ = proc.communicate(timeout=300)
-        assert proc.returncode == 1 and says in out, out[-3000:]
-        lines = out.strip().splitlines()
-        assert json.loads(lines[-1])["rehearsal"] is True
-        assert any(ln.startswith("[run] processes: ") and "SIGKILL after 20 s to 0 []" in ln for ln in lines), out[-3000:]
-        assert not _marked(f"{proc.pid}-")
-        if cell == "toy.chat":
-            assert '[serve] reference: {"ok": true' in out
-        else:
-            assert "(d 0.0000), step after the window" in out
-    for p in bench["paths"]:  # nothing that was there was touched
-        cmp = filecmp.dircmp(os.path.join(ROOT, p), tmp_path / p, ignore=["__pycache__"])
-        stack = [cmp]
-        while stack:
-            d = stack.pop()
-            assert not d.diff_files and not d.left_only, (d.left, d.diff_files, d.left_only)
-            stack += d.subdirs.values()
+    bench = _copy_with(tmp_path, "toy", TOY_FAMILY, c, ("chat", "sft-2k"))
+    _rehearse_both_drivers(tmp_path, "toy")
+    _nothing_that_was_there_was_touched(tmp_path, bench)
+
+
+# ------------------------------------------ a family that is nothing like Llama's: still new files only
+def _held_to_every_configuration(module) -> list:
+    """The test functions of ``module`` that are parametrised over every file in ``configs/``."""
+    out = []
+    for name, fn in vars(module).items():
+        for mark in getattr(fn, "pytestmark", []) if name.startswith("test_") else []:
+            if mark.name == "parametrize" and mark.args[0] == "config" and list(mark.args[1]) == CONFIGS:
+                out.append(fn)
+    return out
+
+
+def test_a_family_with_heads_256_wide_and_another_block_passes_what_every_configuration_is_held_to(tmp_path, monkeypatch):
+    """The quick twin of the slow test below. A configuration whose family is not ``llama``, whose
+    heads are 256 wide and whose layers are of three kinds (one with a gate norm, a convolution and
+    per-head scalars beside its matrices) is dropped into a copy with its family file; every test
+    that is parametrised over the files in ``configs/`` is then called on it, and passes: none asks
+    a head width or a parameter identity of a block that is not the family's own."""
+    import test_benchmark_harness as harness  # the sibling file: pytest put this directory on sys.path
+
+    import benchmark.families as pkg
+
+    c = _wide_config()
+    _copy_with(tmp_path, "wide", WIDE_FAMILY, c, ("chat", "sft-2k"))
+    monkeypatch.setattr(common, "HERE", str(tmp_path / "benchmark"))
+    monkeypatch.setattr(pkg, "__path__", [str(tmp_path / "benchmark" / "families")])
+    try:
+        held = _held_to_every_configuration(harness) + _held_to_every_configuration(sys.modules[__name__])
+        assert {f.__name__ for f in held} >= {"test_matmul_params_and_published_count", "test_every_configuration_names_a_family_whose_file_exists"}
+        for test in held:
+            test("wide")
+        cfg = common.load_family("wide").program_config(c, 2048)
+        assert cfg.hd == 256 and cfg.num_params() == c["parameters"] != _config("nemotron-3-nano-30b-a3b-ep2")["parameters"]
+        # what only the llama family is asked stays with the llama family, in the copy too
+        copied = sorted(f[:-5] for f in os.listdir(tmp_path / "benchmark" / "configs"))
+        assert "wide" in copied and "wide" not in harness.llama_configs(copied) and harness.llama_configs(copied) == harness.llama_configs(CONFIGS)
+        # the Llama block's identity, asked of this block as it was of every configuration until PR 33, does not hold
+        norms = (2 * c["num_hidden_layers"] + 1) * c["hidden_size"]
+        assert common.load_family("llama").matmul_params(c) != cfg.num_params() - c["vocab_size"] * c["hidden_size"] - norms
+    finally:
+        for name in [m for m in sys.modules if m.startswith("benchmark.families.wide")]:
+            sys.modules.pop(name)
+
+
+@pytest.mark.slow
+def test_a_family_with_heads_256_wide_passes_every_collected_test_and_rehearses_both_drivers(tmp_path):
+    """In a copy with ``families/wide.py``, ``configs/wide.json`` and two cells' entries: every test
+    collected from the copy's ``tests/benchmark`` (the slow ones aside) passes with the new
+    configuration among its cases, both drivers rehearse it against its own reference, and no file
+    that was there changed."""
+    bench = _copy_with(tmp_path, "wide", WIDE_FAMILY, _wide_config(), ("chat", "sft-2k"))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": os.pathsep.join([str(tmp_path), ROOT])}
+    out = subprocess.run([sys.executable, "-m", "pytest", "tests/benchmark", "-q", "-m", "not slow", "-p", "no:cacheprovider", "-rf"],
+                         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0 and " passed" in out.stdout, out.stdout[-3000:] + out.stderr[-2000:]
+    _rehearse_both_drivers(tmp_path, "wide")
+    _nothing_that_was_there_was_touched(tmp_path, bench)
 
 
 # ----------------------------------------------------------------------------- how a run ends
