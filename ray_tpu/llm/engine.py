@@ -297,9 +297,10 @@ class LLMEngine:
     """Continuous-batching engine over a slot KV cache.
 
     config: ray_tpu.models.llama.LlamaConfig, or a hybrid model's
-    description (ray_tpu.models.nemotron_h.NemotronHConfig: layers of
-    several kinds, a recurrent state per sequence kept in a state cache
-    beside the KV rows; its step programs are llm/hybrid_runner.py's);
+    description (a config that mixes in models.hybrid.HybridDescription:
+    layers of several kinds, a recurrent state per sequence kept in a
+    state cache beside the KV rows; its step programs are
+    llm/hybrid_runner.py's);
     params: matching pytree (if None, randomly initialized — useful for
     tests/benchmarks).
     """
@@ -665,6 +666,8 @@ class LLMEngine:
             else:
                 self._decode = step_fn
         self._moe_stats = None  # the drained step's expert-routing counters (hybrid models)
+        # this step's hybrid prefills: (tokens, tokens as padded, programs, summed routing counters)
+        self._prefill_stats = None
         if self._device_resident:
             from ray_tpu.llm.model_runner import make_delta_fns, make_fused_fns, make_fused_paged_fns
 
@@ -2129,7 +2132,8 @@ class LLMEngine:
         for i, (_, _, prompt) in enumerate(group):
             toks[i, : len(prompt)] = prompt
             lens[i] = len(prompt)
-        # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true length
+        # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true
+        # length, and its routing layers' counters (hybrid_runner.PREFILL_STATS)
         logits, ks, vs, *new_state = self._prefill(self.params, jnp.asarray(toks), jnp.asarray(lens))
         for i, (st, slot, prompt) in enumerate(group):
             n = len(prompt)
@@ -2153,6 +2157,14 @@ class LLMEngine:
                     with stage(self._tel, "llm.step.state_insert"):
                         self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), new_state[0])
             self._bind_slot(st, slot, logits[i : i + 1])
+        if new_state and self._tel is not None and "routing" in new_state[0]:
+            # the step's row in the flight log: tokens prefilled, true and as padded, and the
+            # routing counters, read AFTER the first tokens (whose readback the program's end
+            # already waited for): the copy of three floats waits for nothing
+            routing = np.asarray(new_state[0]["routing"])  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
+            seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing))
+            self._prefill_stats = (seen[0] + int(sum(len(p) for _, _, p in group)), seen[1] + Bp * T,
+                                   seen[2] + 1, seen[3] + routing)
 
     def _admit_special_paged(self, st: RequestState, slot: int, pref, prompt):
         """Paged admission for transferred-KV / prefix-cache-hit requests

@@ -1,5 +1,7 @@
 """Step programs for a hybrid model: layers of several kinds in one stack,
-each keeping its own kind of state (``ray_tpu.models.nemotron_h``).
+each keeping its own kind of state. The model is a DESCRIPTION
+(``ray_tpu.models.hybrid.HybridDescription``, which the model files under
+``ray_tpu/models/`` mix into their configs) walked by that module's loops.
 
 Three programs, under stable names a trace reader finds (``prefill`` and
 ``fused`` in a name mean what they mean in ``model_runner``):
@@ -17,10 +19,12 @@ Three programs, under stable names a trace reader finds (``prefill`` and
   layer's state overwritten), never rebuilt from per-layer outputs.
 
 The engine picks these from the config object: a description with
-``layer_kinds`` is a hybrid, and carries its mixers (``config.model``)
-and its weights' initialisation (``config.init_params``), so that
-neither this file nor the engine names a model. What the hybrid cannot
-do yet is refused by name in ``refuse`` and ``refuser``.
+``layer_kinds`` is a hybrid, and carries its mixers (``config.mixers``:
+each kind's two forms), its norm, what each kind keeps
+(``config.cache_spec()``) and its weights' initialisation
+(``config.init_params``), so that neither this file nor the engine names
+a model or a kind of layer. What the hybrid cannot do yet is refused by
+name in ``refuse`` and ``refuser``.
 """
 
 from __future__ import annotations
@@ -34,10 +38,13 @@ from ray_tpu.exceptions import HybridModelUnsupportedError
 from ray_tpu.lint import jaxcheck
 from ray_tpu.llm import state_cache
 from ray_tpu.llm.model_runner import _sds, _sds_lanes, named_jit
-from ray_tpu.ops.layers import rms_norm
+from ray_tpu.models import hybrid
 
 # one step's expert-routing counters, in the order the fused step returns them
 MOE_STATS = ("experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load")
+# an admission's, in the order the prefill returns them (means over the routing layers)
+PREFILL_STATS = ("experts_hit", "moe_pairs_local", "moe_rows_computed")
+ROUTING = hybrid.ROUTING
 
 
 def refuse(config, *, kv_layout, cache_dtype, mesh, speculative, kv_plane) -> None:
@@ -54,8 +61,8 @@ def refuse(config, *, kv_layout, cache_dtype, mesh, speculative, kv_plane) -> No
     for what, asked in why.items():
         if asked:
             raise HybridModelUnsupportedError(
-                f"{what} is not built for a hybrid model ({type(config).__name__}, layers "
-                f"{''.join(sorted(set(config.layer_pattern)))}): its recurrent layers keep a state per sequence "
+                f"{what} is not built for a hybrid model ({type(config).__name__}: {config.kinds_held}): "
+                "its recurrent layers keep a state per sequence "
                 "that this feature would have to snapshot, roll back, shard or ship, and only keys and values can be")
 
 
@@ -71,53 +78,48 @@ def refuser(what: str):
 
 def prefill(params, tokens, length, cfg, mesh=None):
     """tokens [B, T_pad] right-padded, length [B] -> (last-token logits [B, vocab] f32,
-    k, v [La, B, T_pad, kv, hd], state {name: [Lm, B, ...]} at each prompt's true length)."""
-    x, out = cfg.model.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
+    k, v [La, B, T_pad, kv, hd], state {name: [Lm, B, ...]} at each prompt's true length, with
+    PREFILL_STATS as float32 [3] beside the state under ``ROUTING`` where the model routes)."""
+    x, out = hybrid.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
     x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
     logits = jnp.dot(x_last, params["unembed"], preferred_element_type=jnp.float32)
+    if ROUTING in out:
+        out[ROUTING] = jnp.mean(out[ROUTING], axis=0)
     return logits, out.pop("k"), out.pop("v"), out
 
 
 def decode_step(params, cache, state, tokens, active, cfg):
-    """Advance every slot one token. cache: the slot KV rows of the attention layers; state: the
-    state cache of the recurrent layers; active [B] bool: lanes bound to a live sequence (the
-    others compute garbage nobody reads, and are kept out of the routing counters).
+    """Advance every slot one token. cache: the slot KV rows of the layers that keep keys and
+    values; state: the state cache of the layers that keep something per sequence; active [B]
+    bool: lanes bound to a live sequence (the others compute garbage nobody reads, and are kept
+    out of the routing counters).
     -> (logits [B, vocab] f32, cache, state, MOE_STATS as float32 [4])."""
-    B, model = tokens.shape[0], cfg.model
+    B = tokens.shape[0]
     lengths = cache["length"]
     pos = jnp.minimum(lengths, cache["k"].shape[2] - 1)
     lanes = jnp.arange(B, dtype=jnp.int32)
     dt, sd = params["embed"].dtype, cfg.stream_dtype
     x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
-
-    def of_layer(a, i):
-        return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+    per_position = frozenset(name for spec in cfg.cache_spec().values() for name, (_, _, per) in spec.items() if per == "position")
+    ctx = hybrid.StepCtx(lengths, active)
 
     def layer(kind, w, i, x, carry):
-        k_all, v_all, st, stats = carry
-        xn = rms_norm(x, w["norm"], cfg.rms_eps)
-        if kind == "mamba":
-            y, ssm, conv = model.mamba2_step(w, xn.astype(dt), of_layer(st["ssm"], i), of_layer(st["conv"], i), cfg)
-            st = {"ssm": jax.lax.dynamic_update_index_in_dim(st["ssm"], ssm.astype(st["ssm"].dtype), i, 0),
-                  "conv": jax.lax.dynamic_update_index_in_dim(st["conv"], conv.astype(st["conv"].dtype), i, 0)}
-        elif kind == "moe":
-            y, s = model.moe_step(w, xn, active, cfg)
+        arrays, stats = carry
+        view = hybrid.LayerCache(arrays, per_position, i, lanes, pos)
+        y, s = cfg.mixers[kind].step(w, cfg.norm(x, w["norm"]), view, ctx)
+        if s is not None:
             stats = jnp.stack([stats[0] + s[0], stats[1] + s[1], jnp.maximum(stats[2], s[2])])
-        else:
-            q, k, v = model.qkv(w, xn.astype(dt), cfg)
-            k_all = k_all.at[i, lanes, pos].set(k.astype(k_all.dtype))
-            v_all = v_all.at[i, lanes, pos].set(v.astype(v_all.dtype))
-            y = model.attn_step(w, q, of_layer(k_all, i), of_layer(v_all, i), lengths, cfg)
-        return x + y.astype(sd), (k_all, v_all, st, stats)
+        return x + y.astype(sd), (view.arrays, stats)
 
-    x, (k_all, v_all, state, stats) = model.run_layers(
-        cfg, params, x, (cache["k"], cache["v"], dict(state), jnp.zeros((3,), jnp.float32)), layer)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(dt)
+    arrays = {**{name: cache[name] for name in per_position}, **state}
+    x, (arrays, stats) = hybrid.run_layers(cfg, params, x, (arrays, jnp.zeros((3,), jnp.float32)), layer)
+    x = cfg.norm(x, params["final_norm"]).astype(dt)
     logits = jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
-    n_moe = max(cfg.count("moe"), 1)
-    total = cfg.num_experts_per_tok * jnp.sum(active.astype(jnp.float32))
-    moe = jnp.stack([stats[0] / n_moe, stats[1] / n_moe, total, stats[2]])
-    return logits, {"k": k_all, "v": v_all, "length": lengths + 1}, state, moe
+    n = max(cfg.routing_layers, 1)
+    total = cfg.expert_layer.top_k * jnp.sum(active.astype(jnp.float32)) if cfg.routing_layers else jnp.zeros((), jnp.float32)
+    moe = jnp.stack([stats[0] / n, stats[1] / n, total, stats[2]])
+    cache = {**{name: arrays.pop(name) for name in per_position}, "length": lengths + 1}
+    return logits, cache, arrays, moe
 
 
 def fused_step(params, cache, state, tokens, keys, temps, top_k, top_p, active, cfg):  # tpulint: disable=JXC001 — tokens is the previous step's output, still held for the delayed readback (as in model_runner.fused_step); active is a fresh 1-byte-a-lane host mask
@@ -150,14 +152,7 @@ def make_hybrid_fns(cfg, device_resident: bool):
 # ---------------------------------------------------------------------------
 # jaxcheck shape buckets: tile-true widths at a size that traces in seconds
 # ---------------------------------------------------------------------------
-def _trace_cfg():
-    from ray_tpu.models.nemotron_h import NemotronHConfig  # the one description there is to trace
-
-    return NemotronHConfig(
-        vocab_size=32256, hidden_size=1024, layer_pattern="ME*ME*ME", mamba_num_heads=16, mamba_head_dim=64,
-        n_groups=8, ssm_state_size=128, n_routed_experts=16, expert_start=0, num_local_experts=8,
-        num_experts_per_tok=2, moe_intermediate_size=1024, moe_shared_expert_intermediate_size=2048,
-        num_heads=8, num_kv_heads=8, head_dim=128, max_seq_len=512)
+_trace_cfg = hybrid.trace_description
 
 
 def _sds_caches(cfg, B: int, S: int):

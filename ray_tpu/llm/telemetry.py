@@ -343,6 +343,12 @@ class FlightRecorder:
         # got a token (mean over expert layers), (token, expert) pairs served here and asked
         # for in all, most tokens at one expert; absent for a model without routed experts
         "experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load",
+        # a hybrid model's ADMITTING step, of that step's prefills: tokens taken in, true and as
+        # padded to bucket and batch, then llm/hybrid_runner.PREFILL_STATS, each a mean over the
+        # routing layers: held experts that got a pair (mean over the step's prefill programs),
+        # (token, expert) pairs served here and rows of the grouped matmul's blocks in use (sums
+        # over them). Under names of their own: the four above stay the drained DECODE step's
+        "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed",
     ) + tuple(STAGES.values())
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
@@ -469,6 +475,7 @@ class FlightRecorder:
 # engine-facing facade
 # ----------------------------------------------------------------------
 _NO_MOE = (None,) * 4  # a step row's routing counters for a model without routed experts
+_NO_PREFILL = (None,) * 5  # and its prefill counters where the step admitted nothing through a hybrid's prefill
 
 
 class EngineTelemetry:
@@ -878,6 +885,12 @@ class EngineTelemetry:
         sd = spec_drained or (None, None)
         moe = eng._moe_stats  # host array of the drained step (hybrid models), else None
         moe = _NO_MOE if moe is None else tuple(round(float(v), 3) for v in moe)
+        pf, eng._prefill_stats = eng._prefill_stats, None
+        if pf is None:
+            moe += _NO_PREFILL
+        else:
+            tokens, padded, programs, (hit, pairs, rows) = pf
+            moe += (tokens, padded, round(float(hit) / programs, 3), round(float(pairs), 3), round(float(rows), 3))
         self.recorder.record_step((
             now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, sampling_lanes, waiting,
             occupied, capacity,

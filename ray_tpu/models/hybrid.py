@@ -1,0 +1,294 @@
+"""The layer loop of a hybrid decoder: one walk, any DESCRIPTION of layers (ROADMAP C1).
+
+A hybrid model is a stack of sub-blocks ``x = x + mixer(norm(x))`` whose mixers
+are of several kinds, each keeping its own kind of state per sequence. A model
+file states WHAT its layers are; this file walks them. A description
+(``HybridDescription``, mixed into the model's config dataclass) says:
+
+- ``layer_kinds``: the kind of every sub-block, in order;
+- ``mixers``: kind -> ``Mixer``: the named scope of that kind in a profile, its
+  two forms (``seq`` over a padded sequence with true lengths; ``step`` for one
+  token a lane against cached state), and whether it routes tokens to experts;
+- ``cache_spec()``: kind -> {name: (shape, dtype, "position" | "sequence")}: what
+  ONE layer of that kind keeps, per position of a sequence (the slot KV rows,
+  named ``k`` and ``v``) or once per sequence (the state cache);
+- ``norm(x, w)``: the pre-norm of every sub-block and the final norm;
+- ``stream_dtype``, ``init_params``, ``num_params()``.
+
+Parameters are stacked by layer kind (``params[kind][name]``: [layers of that
+kind, ...]) so that the loops can index them; ``embed``, ``unembed`` and
+``final_norm`` stand beside the kinds.
+
+Two loops over one description:
+
+- ``scan_layers`` for a sequence (prefill and training): one scan whose body
+  switches on the layer's kind, so a program's size follows the kinds and not the
+  depth; what the layers keep for the cache leaves the loop with one row for each
+  layer that keeps it.
+- ``run_layers`` for a decode step: a scan over the repeated period of the pattern
+  (``layer_plan``), with the caches in its carry, updated in place through a
+  ``LayerCache``.
+
+Neither loop, nor the step programs of ``llm/hybrid_runner.py`` that call them,
+names a model or a kind of layer. A uniform model is the special case of one kind
+and a period of one.
+"""
+
+from __future__ import annotations
+
+import math
+from collections import Counter
+from typing import Any, Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.layers import cross_entropy_loss
+
+# what a routing layer's sequence form reports beside its output, as one more thing it "keeps":
+# float32 [3], see ``models/experts.moe_seq``
+ROUTING = "routing"
+
+
+class Mixer(NamedTuple):
+    """One kind of layer, as the loops and the step programs see it."""
+
+    scope: str  # the named scope around every layer of this kind, in every program
+    seq: Callable  # (w, xn [B,T,H], SeqCtx) -> (y [B,T,H], kept {name: one layer's entry})
+    step: Callable  # (w, xn [B,H], LayerCache, StepCtx) -> (y [B,H], routing counters [3] or None)
+    routes: bool = False  # its sequence form keeps ROUTING, its step form hands back counters
+
+
+class SeqCtx(NamedTuple):
+    lengths: Any  # [B] int32: true lengths of the right-padded sequences
+    mesh: Any  # the mesh a kernel has to be told about, or None
+    stacked: Any  # the serving path: (the kind's stacked weights, this layer's index); else None
+
+
+class StepCtx(NamedTuple):
+    lengths: Any  # [B] int32: positions already held = the new token's position
+    active: Any  # [B] bool: lanes bound to a live sequence
+
+
+class HybridDescription:
+    """What the loops, the engine and the cache manager read off a model's config. The config
+    dataclass that mixes this in provides ``layer_kinds``, ``mixers``, ``cache_spec()``,
+    ``norm``, ``stream_dtype``, ``init_params`` and ``num_params``."""
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_kinds)
+
+    def count(self, kind: str) -> int:
+        return self.layer_kinds.count(kind)
+
+    def keeping(self, name: str) -> tuple:
+        """Indices, among all layers, of the layers that keep ``name`` (a cache entry or ROUTING)."""
+        kinds = {k for k, spec in self.cache_spec().items() if name in spec}
+        if name == ROUTING:
+            kinds = {k for k, m in self.mixers.items() if m.routes}
+        return tuple(n for n, k in enumerate(self.layer_kinds) if k in kinds)
+
+    @property
+    def num_kv_layers(self) -> int:
+        return len(self.keeping("k"))
+
+    @property
+    def routing_layers(self) -> int:
+        return len(self.keeping(ROUTING))
+
+    @property
+    def kinds_held(self) -> str:
+        """What a refusal says this model is made of."""
+        return ", ".join(f"{self.count(k)} x {k}" for k in dict.fromkeys(self.layer_kinds))
+
+    @property
+    def layer_plan(self) -> tuple:
+        """(period, repeats, tail): the longest prefix of the pattern that is a block repeated
+        at least twice, and the kinds that follow it. ``run_layers`` scans over the repeats."""
+        kinds, best = tuple(self.layer_kinds), ((), 0)
+        for p in range(1, len(kinds) // 2 + 1):
+            r = 1
+            while kinds[r * p:(r + 1) * p] == kinds[:p]:
+                r += 1
+            if r >= 2 and r * p > best[1] * len(best[0]):
+                best = (kinds[:p], r)
+        period, r = best
+        return period, r, kinds[r * len(period):]
+
+
+# --------------------------------------------------------------- the layer loops
+def _layer_weights(params, kind, i):
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), params[kind])
+
+
+def run_layers(config, params, x, carry, layer_fn):
+    """Walk the layer pattern with LARGE state in the carry (a decode step's caches, updated in
+    place): ``layer_fn(kind, w, i, x, carry) -> (x, carry)`` with ``w`` one layer's weights and
+    ``i`` its index among the layers of its kind (traced inside the scan over the repeated
+    period, a plain int in the tail). The program holds one body per layer of the period and of
+    the tail. Why not one body per kind (``scan_layers``): a conditional's branch hands back
+    every carried array, and the chip's compiler copies the ones a branch did not touch, 8 GB a
+    step for 0.75 GB of caches (compiled for a described v5e, PR 29)."""
+    period, repeats, tail = config.layer_plan
+    per = Counter(period)
+
+    def apply(kind, i, x, carry):
+        with jax.named_scope(config.mixers[kind].scope):
+            return layer_fn(kind, _layer_weights(params, kind, i), i, x, carry)
+
+    def block(xc, r):
+        x, carry = xc
+        seen = Counter()
+        for kind in period:
+            x, carry = apply(kind, r * per[kind] + seen[kind], x, carry)
+            seen[kind] += 1
+        return (x, carry), None
+
+    if repeats:
+        (x, carry), _ = jax.lax.scan(block, (x, carry), jnp.arange(repeats, dtype=jnp.int32))
+    seen = Counter({k: repeats * n for k, n in per.items()})
+    for kind in tail:
+        x, carry = apply(kind, seen[kind], x, carry)
+        seen[kind] += 1
+    return x, carry
+
+
+def scan_layers(config, params, x, layer_fn, empty):
+    """Walk the layer pattern over a SEQUENCE in one scan whose body switches on the layer's
+    kind: ``layer_fn(kind, w, i, x) -> (x, kept)`` with ``kept`` what that layer keeps, a dict
+    with some of ``empty``'s entries (``empty``: name -> zeros of one layer's entry).
+    -> (x, {name: [layers that keep it, ...]}). One body per KIND, so a prefill program's size
+    and compile time follow the kinds and not the depth: 7 s a program against 17 s for
+    ``run_layers``' nine bodies at 16 layers (compiled for a described v5e, PR 29), and a serving
+    replica warms some twenty. What is kept rides the carry and is written OUTSIDE the switch, at
+    the layer's row among the layers that keep that entry (a layer that does not writes zeros to
+    a spare last row): stacked as the scan's output it would hold a row for EVERY layer, 1.6 GB of
+    zeros beside 0.2 GB of keys and values at 24 sub-blocks x 8 x 4096 positions."""
+    kinds = sorted(set(config.layer_kinds))
+    which = jnp.asarray([kinds.index(k) for k in config.layer_kinds], jnp.int32)
+    among = jnp.asarray([config.layer_kinds[:n].count(k) for n, k in enumerate(config.layer_kinds)], jnp.int32)
+    keepers = {name: config.keeping(name) for name in empty}
+    row = {name: jnp.asarray([keepers[name].index(n) if n in keepers[name] else len(keepers[name])
+                              for n in range(config.num_layers)], jnp.int32) for name in empty}
+
+    def branch(kind):
+        def run(i, x):
+            with jax.named_scope(config.mixers[kind].scope):
+                x, kept = layer_fn(kind, _layer_weights(params, kind, i), i, x)
+            return x, {n: kept[n].astype(z.dtype) if n in kept else z for n, z in empty.items()}
+        return run
+
+    branches = [branch(k) for k in kinds]
+
+    def body(xo, ki):
+        x, out = xo
+        x, kept = jax.lax.switch(ki[0], branches, ki[1], x)
+        return (x, {n: jax.lax.dynamic_update_index_in_dim(out[n], kept[n], ki[2][n], 0) for n in out}), None
+
+    out = {n: jnp.zeros((len(keepers[n]) + 1,) + z.shape, z.dtype) for n, z in empty.items()}
+    (x, out), _ = jax.lax.scan(jax.checkpoint(body) if config.remat else body, (x, out), (which, among, row))
+    return x, {n: a[:-1] for n, a in out.items()}
+
+
+class LayerCache:
+    """One layer's window onto the caches that ride ``run_layers``' carry, for a mixer's step
+    form: ``read(name)`` is that layer's entry for every lane ([B, *shape], or a layer's rows
+    [B, S, *shape] for a per-position entry), ``write(name, value)`` overwrites a per-sequence
+    entry or puts ONE position's value at each lane's current position. Both act on the stacked
+    arrays in place (a dynamic slice of, a scatter or an update into the donated carry); the
+    arrays as they stand afterwards are ``arrays``."""
+
+    def __init__(self, arrays: dict, per_position: frozenset, i, lanes, pos):
+        self.arrays, self._per_position, self._i, self._lanes, self._pos = dict(arrays), per_position, i, lanes, pos
+
+    def read(self, name: str):
+        return jax.lax.dynamic_index_in_dim(self.arrays[name], self._i, 0, keepdims=False)
+
+    def write(self, name: str, value) -> None:
+        a = self.arrays[name]
+        if name in self._per_position:
+            self.arrays[name] = a.at[self._i, self._lanes, self._pos].set(value.astype(a.dtype))
+        else:
+            self.arrays[name] = jax.lax.dynamic_update_index_in_dim(a, value.astype(a.dtype), self._i, 0)
+
+
+# ---------------------------------------------------- what descriptions share
+def init_stacked(groups: dict, count, keys, dt) -> dict:
+    """Weights stacked by layer kind from ``groups``: kind -> {name: (shape of one layer, fan_in
+    or fill)}: a float fills, an int draws N(0, fan_in^-1/2) in float32 and casts to ``dt``, a
+    layer at a time (the float32 draw of all the experts at once would not fit the chip).
+    ``count(kind)`` layers of each kind that has any; ``keys`` an iterator of PRNG keys."""
+    def fill(shape, how, n):
+        if isinstance(how, float):
+            return jnp.full((n,) + shape, how, dt)
+        return jax.lax.map(lambda k: (jax.random.normal(k, shape, jnp.float32) * how ** -0.5).astype(dt),
+                           jax.random.split(next(keys), n))
+
+    return {g: {name: fill(shape, how, count(g)) for name, (shape, how) in group.items()}
+            for g, group in groups.items() if count(g)}
+
+
+def attend_slot_rows(q, k_cache, v_cache, lengths, num_kv_heads: int):
+    """One token a lane (its query q [B,nh,hd]) against a layer's rows k/v_cache [B,S,kv,hd], in
+    which the new token's key and value already sit at index lengths[b]: grouped-query softmax
+    attention over the positions held. -> [B, nh*hd] float32."""
+    B, S = k_cache.shape[:2]
+    nh, hd = q.shape[1:]
+    qg = q.reshape(B, num_kv_heads, nh // num_kv_heads, hd)
+    scores = jnp.einsum("bgrh,bsgh->bgrs", qg, k_cache, preferred_element_type=jnp.float32) / math.sqrt(hd)
+    ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]
+    probs = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bgrs,bsgh->bgrh", probs, v_cache.astype(jnp.float32)).reshape(B, nh * hd)
+
+
+# ------------------------------------------------------------- sequence forward
+def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = False):
+    """tokens [B,T] right-padded, lengths [B] -> the final-norm'd stream [B,T,H] and, with
+    ``collect`` (the serving prefill; its routing layers run the grouped matmul, which has no
+    backward pass), what each layer keeps, by entry name: per-position entries [layers, B, T,
+    *shape], per-sequence entries [layers, B, *shape] AT each sequence's true length, and
+    ``ROUTING`` [routing layers, 3]."""
+    c = config
+    B, T = tokens.shape
+    dt, sd = params["embed"].dtype, c.stream_dtype
+    x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
+    empty = {}
+    if collect:
+        for spec in c.cache_spec().values():
+            for name, (shape, dtype, per) in spec.items():
+                empty[name] = jnp.zeros(((B, T) if per == "position" else (B,)) + tuple(shape), jnp.dtype(dtype))
+        if c.routing_layers:
+            empty[ROUTING] = jnp.zeros((3,), jnp.float32)
+
+    def layer(kind, w, i, x):
+        ctx = SeqCtx(lengths, mesh, (params[kind], i) if collect else None)
+        y, kept = c.mixers[kind].seq(w, c.norm(x, w["norm"]), ctx)
+        return x + y.astype(sd), kept if collect else {}
+
+    x, out = scan_layers(c, params, x, layer, empty)
+    return c.norm(x, params["final_norm"]).astype(dt), out
+
+
+def forward(params, tokens, config, mesh=None):
+    """tokens [B,T] -> logits [B,T,vocab] f32, every position real."""
+    lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
+    x, _ = forward_hidden(params, tokens, lengths, config, mesh)
+    return jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
+
+
+def loss_fn(params, batch, config, mesh=None):
+    """batch: {tokens [B,T], targets [B,T] (-100 = ignore)} -> scalar loss."""
+    return cross_entropy_loss(forward(params, batch["tokens"], config, mesh=mesh), batch["targets"])
+
+
+def trace_description():
+    """A description at tile-true widths that traces in seconds: what ``lint/jaxcheck`` runs the
+    step programs of ``llm/hybrid_runner.py`` over, so that the runner itself names no model."""
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    return NemotronHConfig(
+        vocab_size=32256, hidden_size=1024, layer_pattern="ME*ME*ME", mamba_num_heads=16, mamba_head_dim=64,
+        n_groups=8, ssm_state_size=128, n_routed_experts=16, expert_start=0, num_local_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=1024, moe_shared_expert_intermediate_size=2048,
+        num_heads=8, num_kv_heads=8, head_dim=128, max_seq_len=512)

@@ -7,15 +7,14 @@ causal convolution per sequence), ``E`` routed experts plus a shared expert
 (keeps nothing), ``*`` grouped-query attention with no position embedding
 (keys and values per position). Position is carried by the Mamba layers.
 
-This file is the first step of ROADMAP C1: the model is a DESCRIPTION
-(``layer_kinds``, ``cache_spec()`` = what a layer of each kind keeps per
-sequence, ``layer_plan`` = how the pattern repeats) walked by a loop, and
-parameters are stacked by layer kind so that the loop can index them:
-``scan_layers`` for a sequence (one scan, the body switches on the kind:
-a program's size follows the kinds) and ``run_layers`` for a decode step
-(a scan over the repeated period of the pattern, so that the caches in
-its carry are updated in place). A uniform model is the special case of
-one kind and a period of one: Llama can move onto the same loops.
+This file is a DESCRIPTION (ROADMAP C1): ``layer_kinds``, ``mixers`` = the
+two forms of each kind, ``cache_spec()`` = what a layer of each kind keeps
+per sequence, with parameters stacked by layer kind. The loops that walk it
+(``scan_layers`` for a sequence, ``run_layers`` for a decode step, the
+sequence forward) are ``models/hybrid.py``'s, shared with every other
+description; the expert layer is ``models/experts.py``'s, configured here as
+a sigmoid router with a correction bias, relu² experts of two matrices and
+a plain shared expert.
 
 The residual stream is in the weights' dtype, as published
 (``residual_in_fp32`` false), or float32 where that key is true; norms
@@ -26,33 +25,30 @@ Every mixer comes in two forms: over a padded sequence with its true
 length (prefill and training; padded positions advance no state) and for
 one token against cached state (decode). The expert layer drops no token
 and is told which experts this chip holds (``expert_start``,
-``num_local_experts``): the router scores all published experts, this
-chip computes what its own give for the tokens routed to them and adds
-the shared expert; a token whose choice lives on another chip gets
-nothing from that choice here (expert parallelism without its exchange).
+``num_local_experts``).
 """
 
 from __future__ import annotations
 
 import math
-import sys
-from collections import Counter
 from dataclasses import dataclass
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import experts
+from ray_tpu.models.experts import ExpertLayer, experts_dense, experts_grouped, route  # noqa: F401 - the layer's parts, by the names they had here
+from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot_rows, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
-from ray_tpu.ops.layers import cross_entropy_loss, rms_norm
+from ray_tpu.ops.layers import rms_norm
 
 # pattern character -> (parameter group, named scope in a profile)
 KINDS = {"M": ("mamba", "mamba2"), "E": ("moe", "moe"), "*": ("attn", "attn")}
-SCOPES = dict(KINDS.values())
 
 
 @dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(HybridDescription):
     vocab_size: int = 131072  # rows of the embedding and head held here
     hidden_size: int = 2688
     layer_pattern: str = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -101,16 +97,9 @@ class NemotronHConfig:
             raise ValueError(f"layer_pattern holds {sorted(bad)}; the kinds are {sorted(KINDS)}")
         if self.d_inner % self.n_groups or self.mamba_num_heads % self.n_groups:
             raise ValueError("n_groups must divide the Mamba heads and their inner width")
-        if not 0 <= self.expert_start <= self.expert_start + self.local_experts <= self.n_routed_experts:
-            raise ValueError("the experts held must lie inside the router's width")
+        _ = self.expert_layer  # raises where the experts held do not lie inside the router's width
 
     # ---- the description the layer loop, the engine and the cache manager read
-    @property
-    def model(self):
-        """The module that holds this description's mixers and loops: the step programs of
-        ``llm/hybrid_runner.py`` take them from here, so neither they nor the engine name a model."""
-        return sys.modules[__name__]
-
     def init_params(self, key):
         return init_params(self, key)
 
@@ -119,15 +108,49 @@ class NemotronHConfig:
         return tuple(KINDS[c][0] for c in self.layer_pattern)
 
     @property
-    def num_layers(self) -> int:
-        return len(self.layer_pattern)
+    def mixers(self) -> dict:
+        """kind -> its scope in a profile and its two forms (``models/hybrid.Mixer``)."""
+        dt = jnp.dtype(self.dtype)
 
-    def count(self, kind: str) -> int:
-        return self.layer_kinds.count(kind)
+        def mamba_seq(w, xn, ctx):
+            y, ssm, conv = mamba2_seq(w, xn.astype(dt), ctx.lengths, self)
+            return y, {"ssm": ssm, "conv": conv}
+
+        def mamba_step(w, xn, cache, ctx):
+            y, ssm, conv = mamba2_step(w, xn.astype(dt), cache.read("ssm"), cache.read("conv"), self)
+            cache.write("ssm", ssm)
+            cache.write("conv", conv)
+            return y, None
+
+        def experts_seq(w, xn, ctx):
+            y, counters = experts.moe_seq(w, xn, ctx.lengths, self, stacked=ctx.stacked)
+            return y, {ROUTING: counters}
+
+        def experts_step(w, xn, cache, ctx):  # through THIS module's ``experts_dense``, looked up at the call: a test swaps it
+            return experts.moe_step(w, xn, ctx.active, self, dense=experts_dense)
+
+        def attention_seq(w, xn, ctx):
+            y, k, v = attn_seq(w, xn.astype(dt), self, ctx.mesh)
+            return y, {"k": k, "v": v}
+
+        def attention_step(w, xn, cache, ctx):
+            q, k, v = qkv(w, xn.astype(dt), self)
+            cache.write("k", k)
+            cache.write("v", v)
+            return attn_step(w, q, cache.read("k"), cache.read("v"), ctx.lengths, self), None
+
+        forms = {"mamba": (mamba_seq, mamba_step, False), "attn": (attention_seq, attention_step, False),
+                 "moe": (experts_seq, experts_step, True)}
+        return {kind: Mixer(scope, *forms[kind]) for kind, scope in KINDS.values()}
+
+    def norm(self, x, w):
+        return rms_norm(x, w, self.rms_eps)
 
     @property
-    def num_kv_layers(self) -> int:
-        return self.count("attn")
+    def expert_layer(self) -> ExpertLayer:
+        return ExpertLayer(num_experts=self.n_routed_experts, top_k=self.num_experts_per_tok, expert_start=self.expert_start,
+                           local_experts=self.num_local_experts, score="sigmoid", bias=True, norm_topk=self.norm_topk_prob,
+                           scale=self.routed_scaling_factor, act="relu2", shared_gated=False)
 
     @property
     def hd(self) -> int:
@@ -147,7 +170,7 @@ class NemotronHConfig:
 
     @property
     def local_experts(self) -> int:
-        return self.n_routed_experts if self.num_local_experts is None else self.num_local_experts
+        return self.expert_layer.held
 
     def cache_spec(self) -> dict:
         """kind -> {name: (shape, dtype, "position" | "sequence")}: what ONE layer of that kind
@@ -159,20 +182,6 @@ class NemotronHConfig:
                       "conv": ((self.conv_kernel - 1, self.conv_dim), self.dtype, "sequence")},
             "moe": {},
         }
-
-    @property
-    def layer_plan(self) -> tuple:
-        """(period, repeats, tail): the longest prefix of the pattern that is a block repeated
-        at least twice, and the kinds that follow it. The loop scans over the repeats."""
-        kinds, best = self.layer_kinds, ((), 0)
-        for p in range(1, len(kinds) // 2 + 1):
-            r = 1
-            while kinds[r * p:(r + 1) * p] == kinds[:p]:
-                r += 1
-            if r >= 2 and r * p > best[1] * len(best[0]):
-                best = (kinds[:p], r)
-        period, r = best
-        return period, r, kinds[r * len(period):]
 
     def num_params(self) -> int:
         """Parameters held here (the chip's share of experts and vocabulary)."""
@@ -222,15 +231,7 @@ def init_params(config: NemotronHConfig, key):
     c, dt = config, jnp.dtype(config.dtype)
     keys = iter(jax.random.split(key, 64))
 
-    def fill(shape, how, n):
-        if isinstance(how, float):
-            return jnp.full((n,) + shape, how, dt)
-        # a layer at a time: the float32 draw of all the experts at once would not fit the chip
-        return jax.lax.map(lambda k: (jax.random.normal(k, shape, jnp.float32) * how ** -0.5).astype(dt),
-                           jax.random.split(next(keys), n))
-
-    params = {g: {name: fill(shape, how, c.count(g)) for name, (shape, how) in group.items()}
-              for g, group in _shapes(c).items() if c.count(g)}
+    params = init_stacked(_shapes(c), c.count, keys, dt)
     if c.count("mamba"):
         n, nh = c.count("mamba"), c.mamba_num_heads
         step = jnp.exp(jax.random.uniform(next(keys), (n, nh)) * (math.log(c.time_step_max) - math.log(c.time_step_min))
@@ -293,70 +294,6 @@ def param_logical_axes(config: NemotronHConfig):
     axes = {g: {n: (None,) + a for n, a in group.items()} for g, group in lead.items() if config.count(g)}
     axes.update(embed=("vocab", "embed"), unembed=("embed", "vocab"), final_norm=(None,))
     return axes
-
-
-# --------------------------------------------------------------- the layer loop
-def _layer_weights(params, kind, i):
-    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), params[kind])
-
-
-def run_layers(config: NemotronHConfig, params, x, carry, layer_fn):
-    """Walk the layer pattern with LARGE state in the carry (a decode step's caches, updated in
-    place): ``layer_fn(kind, w, i, x, carry) -> (x, carry)`` with ``w`` one layer's weights and
-    ``i`` its index among the layers of its kind (traced inside the scan over the repeated
-    period, a plain int in the tail). The program holds one body per layer of the period and of
-    the tail. Why not one body per kind (``scan_layers``): a conditional's branch hands back
-    every carried array, and the chip's compiler copies the ones a branch did not touch, 8 GB a
-    step for 0.75 GB of caches (compiled for a described v5e, PR 29)."""
-    period, repeats, tail = config.layer_plan
-    per = Counter(period)
-
-    def apply(kind, i, x, carry):
-        with jax.named_scope(SCOPES[kind]):
-            return layer_fn(kind, _layer_weights(params, kind, i), i, x, carry)
-
-    def block(xc, r):
-        x, carry = xc
-        seen = Counter()
-        for kind in period:
-            x, carry = apply(kind, r * per[kind] + seen[kind], x, carry)
-            seen[kind] += 1
-        return (x, carry), None
-
-    if repeats:
-        (x, carry), _ = jax.lax.scan(block, (x, carry), jnp.arange(repeats, dtype=jnp.int32))
-    seen = Counter({k: repeats * n for k, n in per.items()})
-    for kind in tail:
-        x, carry = apply(kind, seen[kind], x, carry)
-        seen[kind] += 1
-    return x, carry
-
-
-def scan_layers(config: NemotronHConfig, params, x, layer_fn, empty):
-    """Walk the layer pattern over a SEQUENCE in one scan whose body switches on the layer's
-    kind: ``layer_fn(kind, w, i, x) -> (x, kept)`` with ``kept`` what that layer keeps for the
-    cache, a dict with some of ``empty``'s entries (``empty``: name -> zeros of one layer's
-    entry). -> (x, {name: [layers, ...]} with a row for EVERY layer, zeros where a layer keeps
-    no such entry). One body per KIND, so a prefill program's size and compile time follow the
-    kinds and not the depth: 7 s a program against 17 s for ``run_layers``' nine bodies at 16
-    layers (compiled for a described v5e, PR 29), and a serving replica warms some twenty."""
-    kinds = sorted(set(config.layer_kinds))
-    which = jnp.asarray([kinds.index(k) for k in config.layer_kinds], jnp.int32)
-    among = jnp.asarray([config.layer_kinds[:n].count(k) for n, k in enumerate(config.layer_kinds)], jnp.int32)
-
-    def branch(kind):
-        def run(i, x):
-            with jax.named_scope(SCOPES[kind]):
-                x, kept = layer_fn(kind, _layer_weights(params, kind, i), i, x)
-            return x, {n: kept[n].astype(z.dtype) if n in kept else z for n, z in empty.items()}
-        return run
-
-    branches = [branch(k) for k in kinds]
-
-    def body(x, ki):
-        return jax.lax.switch(ki[0], branches, ki[1], x)
-
-    return jax.lax.scan(jax.checkpoint(body) if config.remat else body, x, (which, among))
 
 
 # ------------------------------------------------------------------- M: Mamba-2
@@ -464,104 +401,13 @@ def mamba2_step(w, xn, ssm, conv, c: NemotronHConfig):
 
 
 # ------------------------------------------------------------ E: routed experts
-def route(w, x, c: NemotronHConfig):
-    """The published router, in float32 whatever the stream's dtype: sigmoid scores over ALL
-    experts, the top k of score + correction bias, their own scores as weights, normalised and
-    scaled. x [N,H] -> (expert ids [N,k] int32, weights [N,k] f32)."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w["router"].astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + w["router_bias"], c.num_experts_per_tok)
-    wt = jnp.take_along_axis(s, idx, axis=-1)
-    if c.norm_topk_prob:
-        wt = wt / (jnp.sum(wt, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), wt * c.routed_scaling_factor
-
-
-def _relu2(h):
-    return jnp.square(jax.nn.relu(h))
+# ``models/experts.py`` under this description's ``expert_layer``; ``route``, ``experts_dense`` and
+# ``experts_grouped`` are its functions, imported above
+_RELU2_SHARED = ExpertLayer(num_experts=1, top_k=1, act="relu2")
 
 
 def _shared_expert(w, x):
-    return jnp.dot(_relu2(jnp.dot(x, w["shared_up"])), w["shared_down"])
-
-
-def experts_dense(w, x, idx, wt, c: NemotronHConfig):
-    """Every held expert over every row: right for a decode step, whose cost is reading the
-    experts' weights either way. A choice held elsewhere has no column here and adds nothing."""
-    comb = jnp.einsum("nke,nk->en", jax.nn.one_hot(idx - c.expert_start, c.local_experts, dtype=jnp.float32), wt)
-    a = _relu2(jnp.einsum("nh,efh->enf", x, w["w_up"]))
-    return jnp.einsum("enf,efh->nh", (a * comb[..., None]).astype(x.dtype), w["w_down"])
-
-
-def experts_grouped(stacked, layer, x, idx, wt, valid, c: NemotronHConfig):
-    """A grouped matmul in plain XLA: the (row, expert) pairs routed here, laid out by expert, each
-    expert's run padded to whole blocks of rows, and one loop over the blocks IN USE: a block's
-    rows against its expert's two matrices, read straight from the stacked weights. The work
-    follows the pairs (plus at most a block an expert), not experts x rows; no pair is dropped,
-    whatever the load on one expert. ``valid`` [N] keeps padding out of every group. The loop's
-    length is data, so this path has no backward pass (training uses ``experts_dense``).
-    ``stacked["w_up"]``/``["w_down"]`` are the arrays STACKED over the expert layers, and the
-    loop reads expert e of layer ``layer`` from them: a layer's 0.6 GB of experts, sliced out
-    first, would be copied once a layer to become the loop's operand."""
-    N, k = idx.shape
-    M, El, H = N * k, c.local_experts, x.shape[-1]
-    block = 256 if M >= 32768 else 128
-    n_rows = (-(-M // block) + El) * block  # the most that padding to whole blocks can need
-    local = (idx - c.expert_start).reshape(-1)
-    mine = (local >= 0) & (local < El) & jnp.repeat(valid, k)
-    # a pair's place: its rank among the pairs of its expert (a running count, no sort), after
-    # the blocks of the experts before it; what is not ours goes to a spare row that stays zero
-    hot = mine[:, None] & (local[:, None] == jnp.arange(El, dtype=jnp.int32)[None, :])
-    count = jnp.cumsum(hot.astype(jnp.int32), axis=0)
-    sizes = count[-1]
-    blocks_of = (sizes + block - 1) // block
-    last_block = jnp.cumsum(blocks_of)  # one past each expert's last block
-    e_of = jnp.clip(local, 0, El - 1)
-    rank = jnp.take_along_axis(count, e_of[:, None], axis=1)[:, 0] - 1
-    place = jnp.where(mine, (last_block[e_of] - blocks_of[e_of]) * block + rank, n_rows)
-    pair_at = jnp.full((n_rows + 1,), M, jnp.int32).at[place].set(jnp.arange(M, dtype=jnp.int32))
-    scale = jnp.where(mine, wt.reshape(-1), 0.0)
-
-    def one_block(b, ys):
-        e = jnp.sum(last_block <= b).astype(jnp.int32)
-        pair = jax.lax.dynamic_slice_in_dim(pair_at, b * block, block)
-        ok = pair < M  # the padding at the end of an expert's run holds no pair
-        pair = jnp.minimum(pair, M - 1)
-        xb = jnp.where(ok[:, None], jnp.take(x, pair // k, axis=0), 0)
-        up, down = (jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0]
-                    for a in (stacked["w_up"], stacked["w_down"]))
-        yb = jnp.dot(_relu2(jnp.einsum("bh,fh->bf", xb, up)), down)
-        yb = (yb * jnp.where(ok, scale[pair], 0.0)[:, None]).astype(ys.dtype)
-        return jax.lax.dynamic_update_slice(ys, yb, (b * block, jnp.zeros((), jnp.int32)))
-
-    ys = jax.lax.fori_loop(0, last_block[-1], one_block, jnp.zeros((n_rows + 1, H), x.dtype))
-    return jnp.sum(jnp.take(ys, place, axis=0).reshape(N, k, H), axis=1, dtype=jnp.float32).astype(x.dtype)
-
-
-def moe_seq(w, xn, lengths, c: NemotronHConfig, stacked=None):
-    """xn [B,T,H] -> [B,T,H]: routed experts held here plus the shared expert. ``stacked`` =
-    (the expert layers' stacked weights, this layer's index): the serving path's grouped matmul;
-    without it every held expert over every token, which has a backward pass."""
-    B, T, H = xn.shape
-    idx, wt = route(w, xn.reshape(B * T, H), c)  # on the norm as it comes
-    x = xn.reshape(B * T, H).astype(w["w_up"].dtype)
-    valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
-    if stacked is not None:
-        routed = experts_grouped(*stacked, x, idx, wt, valid, c)
-    else:
-        routed = experts_dense(w, x, idx, jnp.where(valid[:, None], wt, 0.0), c)
-    return (routed + _shared_expert(w, x)).reshape(B, T, H)
-
-
-def moe_step(w, xn, active, c: NemotronHConfig):
-    """One token a lane: xn [B,H], active [B] bool -> (out [B,H], [held experts that got a
-    token, pairs served here, most tokens at one expert] over the active lanes, float32)."""
-    idx, wt = route(w, xn, c)  # on the norm as it comes
-    xn = xn.astype(w["w_up"].dtype)
-    hot = jax.nn.one_hot(idx - c.expert_start, c.local_experts, dtype=jnp.float32) * active[:, None, None]
-    load = jnp.sum(hot, axis=(0, 1))
-    stats = jnp.stack([jnp.sum(load > 0).astype(jnp.float32), jnp.sum(load), jnp.max(load)])
-    return experts_dense(w, xn, idx, wt, c) + _shared_expert(w, xn), stats
+    return experts.shared_expert(w, x, _RELU2_SHARED)
 
 
 # ----------------------------------------------------------------- *: attention
@@ -586,56 +432,5 @@ def attn_step(w, q, k_cache, v_cache, lengths, c: NemotronHConfig):
     """One token a lane (its query q [B,nh,hd] from ``qkv``) against a layer's rows
     k/v_cache [B,S,kv,hd], in which the new token's key and value already sit at index
     lengths[b]. -> out [B,H]."""
-    B, S = k_cache.shape[:2]
-    qg = q.reshape(B, c.num_kv_heads, c.num_heads // c.num_kv_heads, c.hd)
-    scores = jnp.einsum("bgrh,bsgh->bgrs", qg, k_cache, preferred_element_type=jnp.float32) / math.sqrt(c.hd)
-    ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]
-    probs = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
-    o = jnp.einsum("bgrs,bsgh->bgrh", probs, v_cache.astype(jnp.float32))
-    return jnp.dot(o.reshape(B, c.num_heads * c.hd).astype(q.dtype), w["wo"])
-
-
-# ------------------------------------------------------------- sequence forward
-def forward_hidden(params, tokens, lengths, config: NemotronHConfig, mesh=None, collect: bool = False):
-    """tokens [B,T] right-padded, lengths [B] -> the final-norm'd stream [B,T,H] and, with
-    ``collect`` (the serving prefill; its expert layers run the grouped matmul, which has no
-    backward pass), what each caching layer keeps: {"k","v" [La,B,T,kv,hd], "ssm"
-    [Lm,B,nh,P,N], "conv" [Lm,B,K-1,C]} with the recurrent state at each sequence's true length."""
-    c = config
-    B, T = tokens.shape
-    dt, sd = params["embed"].dtype, c.stream_dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
-    empty, rows = {}, {}
-    if collect:
-        for kind, spec in c.cache_spec().items():
-            for name, (shape, dtype, per) in spec.items():
-                empty[name] = jnp.zeros(((B, T) if per == "position" else (B,)) + shape, jnp.dtype(dtype))
-                rows[name] = jnp.asarray([n for n, k in enumerate(c.layer_kinds) if k == kind], jnp.int32)
-
-    def layer(kind, w, i, x):
-        xn = rms_norm(x, w["norm"], c.rms_eps)
-        if kind == "mamba":
-            y, ssm, conv = mamba2_seq(w, xn.astype(dt), lengths, c)
-            kept = {"ssm": ssm, "conv": conv}
-        elif kind == "moe":
-            y, kept = moe_seq(w, xn, lengths, c, stacked=(params["moe"], i) if collect else None), {}
-        else:
-            y, k, v = attn_seq(w, xn.astype(dt), c, mesh)
-            kept = {"k": k, "v": v}
-        return x + y.astype(sd), kept if collect else {}
-
-    x, every = scan_layers(c, params, x, layer, empty)
-    out = {name: jnp.take(every[name], rows[name], axis=0) for name in empty}  # the layers that keep it
-    return rms_norm(x, params["final_norm"], c.rms_eps).astype(dt), out
-
-
-def forward(params, tokens, config: NemotronHConfig, mesh=None):
-    """tokens [B,T] -> logits [B,T,vocab] f32, every position real."""
-    lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
-    x, _ = forward_hidden(params, tokens, lengths, config, mesh)
-    return jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
-
-
-def loss_fn(params, batch, config: NemotronHConfig, mesh=None):
-    """batch: {tokens [B,T], targets [B,T] (-100 = ignore)} -> scalar loss."""
-    return cross_entropy_loss(forward(params, batch["tokens"], config, mesh=mesh), batch["targets"])
+    o = attend_slot_rows(q, k_cache, v_cache, lengths, c.num_kv_heads)
+    return jnp.dot(o.astype(q.dtype), w["wo"])

@@ -41,7 +41,7 @@ _STREAM_POLL_S = 5.0
 
 @dataclass
 class LLMConfig:
-    model_config: object = None  # models.llama.LlamaConfig, or models.nemotron_h.NemotronHConfig (a hybrid)
+    model_config: object = None  # models.llama.LlamaConfig, or a hybrid's description (models.hybrid.HybridDescription: models/nemotron_h.py, models/qwen3_next.py)
     params: object = None  # optional pretrained pytree
     engine_kwargs: dict = field(default_factory=dict)  # max_num_seqs, ...
     # OpenAI-style API: model name echoed in responses, and an optional

@@ -356,3 +356,63 @@ def test_hybrid_prefill_fits_beside_weights_and_caches_on_one_v5e(one_chip):
     assert "tpu_custom_call" in txt
     assert mem.temp_size_in_bytes < 2.0 * 2**30  # no copy of the experts (4.3 GiB), no layer's worth of them (0.6 GiB x 4)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.70 * 2**30 < 15.0 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# a second description over the same step programs: the cell qwen3-next-ep4.longdoc
+# ---------------------------------------------------------------------------
+def _qwen3_next_at_the_benchmarks_size(one_chip, slots=16):
+    """The configuration of the cell ``qwen3-next-ep4.longdoc``: published widths, 12 of 48 layers,
+    128 of 512 experts, 16 slots x 4096 (benchmark/configs/qwen3-next-80b-a3b-ep4.json)."""
+    import json
+    import os
+
+    from benchmark import common
+    from ray_tpu.llm import state_cache
+
+    with open(os.path.join(common.HERE, "configs", "qwen3-next-80b-a3b-ep4.json")) as f:
+        c = json.load(f)
+    assert (c["serving"]["max_num_seqs"], c["serving"]["max_seq_len"]) == (slots, 4096)
+    # off the TPU "auto" picks the XLA attention; the chip runs the flash kernel, 256 wide here
+    cfg = common.load_family(c["family"]).program_config(c, 4096, attention_impl="pallas", remat=False)
+    params = _on(jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))), one_chip)
+    kv = jax.ShapeDtypeStruct((cfg.num_kv_layers, slots, 4096, cfg.num_kv_heads, cfg.hd), jnp.bfloat16, sharding=one_chip)
+    cache = {"k": kv, "v": kv, "length": jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)}
+    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, slots)), one_chip)
+
+
+def test_qwen3_next_fused_step_fits_one_v5e_and_updates_both_caches_in_place(one_chip):
+    """PR 34: 10.10 GiB of weights, 0.375 GiB of KV rows (3 layers of heads 256 wide) and 0.29 GiB
+    of recurrent state (9 layers of 32 x 128 x 128 float32 a slot) in one decode program, through
+    the SAME ``hybrid_runner.fused_step`` and layer loop as the Nemotron-H step above: both caches
+    aliased to the donated inputs, and temporaries under ONE layer's rows (128 MiB of K and V)."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, cache, state = _qwen3_next_at_the_benchmarks_size(one_chip)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
+    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+    mem = step.lower(params, cache, state, *lanes, s((16,), jnp.bool_)).compile().memory_analysis()
+    caches = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)))
+    one_layers_rows = 2 * 16 * 4096 * cfg.num_kv_heads * cfg.hd * 2
+    assert 10.7 * 2**30 < mem.argument_size_in_bytes < 10.85 * 2**30 and 0.65 * 2**30 < caches < 0.68 * 2**30
+    assert mem.alias_size_in_bytes >= caches
+    assert mem.temp_size_in_bytes < one_layers_rows == 128 * 2**20
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 1.0), (8, 3.6)])
+def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, prompts, most_gib):
+    """The 4096-bucket prefill (the chunked delta rule a few sequences at a time, the flash kernel at
+    heads 256 wide, the grouped matmul over 128 small experts in slabs) for one prompt (0.65 GiB of
+    temporaries as compiled for PR 34) and for the largest group the cell warms, 8 x 4096 (3.23 GiB
+    and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of caches: under 15.75 GiB."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _qwen3_next_at_the_benchmarks_size(one_chip)
+    tokens = jax.ShapeDtypeStruct((prompts, 4096), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in txt, "the flash kernel, 256 wide"
+    assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (4.5 GiB), no layer's worth of them (0.375 GiB x 12)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.67 * 2**30 < 15.0 * 2**30

@@ -324,3 +324,56 @@ def test_serves_through_the_openai_server_streaming(params):
         assert sp
     finally:
         srv.shutdown()
+
+
+def _second_description():
+    """The other description over the same step programs (``models/qwen3_next.py``), at toy widths."""
+    from ray_tpu.models import qwen3_next as qn
+
+    cfg = qn.Qwen3NextConfig.tiny(vocab_size=C["vocab_size"])
+    return cfg, jax.jit(lambda k: qn.init_params(cfg, k))(jax.random.PRNGKey(3))
+
+
+@pytest.mark.parametrize("kwargs, named", [
+    ({"kv_layout": "paged"}, "kv_layout='paged'"),
+    ({"cache_dtype": "int8"}, "cache_dtype='int8'"),
+    ({"speculative": _Anything()}, "speculative decoding"),
+    ({"kv_plane": _Anything(), "enable_prefix_caching": True}, "KV plane"),
+])
+def test_every_refusal_at_construction_holds_for_the_second_description_and_says_what_it_holds(kwargs, named):
+    cfg, p = _second_description()
+    with pytest.raises(HybridModelUnsupportedError, match=named.replace("(", r"\(").replace(")", r"\)")) as e:
+        engine(p, cfg=cfg, **kwargs)
+    assert "Qwen3NextConfig: 3 x gdn, 5 x moe, 2 x attn" in str(e.value)  # the description says what kinds it holds
+    with pytest.raises(HybridModelUnsupportedError, match=r"NemotronHConfig: 3 x mamba, 3 x moe, 2 x attn"):
+        engine(None, **kwargs)
+
+
+@pytest.mark.parametrize("call, named", [
+    (lambda e: e.add_prefill_request([1, 2, 3]), "disaggregated prefill"),
+    (lambda e: e.add_prefilled([1, 2, 3], {}), "transferred KV block"),
+    (lambda e: e.checkpoint_request("r"), "migration"),
+    (lambda e: e.suspend_request("r"), "suspend"),
+    (lambda e: e.adopt_prefetched([1, 2, 3], None, None), "KV plane"),
+])
+def test_moving_a_sequence_is_refused_for_the_second_description_too(call, named):
+    cfg, p = _second_description()
+    eng = engine(p, cfg=cfg, enable_prefix_caching=True)
+    assert eng._prefix_cache is None and eng.prefix_cache_stats() == {} and set(eng.state) == {"S", "conv"}
+    with pytest.raises(HybridModelUnsupportedError, match=named):
+        call(eng)
+
+
+def test_neither_the_runner_nor_the_engine_names_a_model_or_a_kind_of_layer():
+    """ROADMAP C1 after PR 34: two descriptions over one loop. What a kind of layer is called,
+    computes and keeps comes from the description; the step programs and the engine ask it."""
+    import re
+
+    from ray_tpu.llm import engine as engine_module
+    from ray_tpu.llm import hybrid_runner
+
+    for module in (hybrid_runner, engine_module):
+        with open(module.__file__) as f:
+            text = f.read()
+        found = re.findall(r"nemotron|qwen|mamba|gdn|deltanet|\"attn\"|\"moe\"|'moe'|'attn'", text, flags=re.IGNORECASE)
+        assert not found, (module.__name__, found)
