@@ -561,6 +561,19 @@ class LLMEngine:
                 self.pool = jax.jit(lambda: pkv.alloc(self._pcfg), out_shardings=cache_sh)()
             else:
                 self.cache = jax.jit(lambda: kvc.alloc(cache_cfg), out_shardings=cache_sh)()
+        # positions in one block of the decode step's attention where it runs as the kernel that
+        # reads a lane's live blocks only (ops/slot_attention.py decides: backend, cache dtype,
+        # mesh, tile), else None; the step's blocks read and in all, for the flight log
+        self._attn_block = None
+        self._step_attn_blocks = None
+        if kv_layout == "slots":
+            from ray_tpu.ops import slot_attention
+
+            kv_dt = self.cache["k"].dtype
+            if slot_attention.refusal(kv_dt, config.num_heads, config.num_kv_heads, config.hd, self.max_seq_len,
+                                      quantized=self.kv_quant, sharded=mesh is not None) is None:
+                self._attn_block = slot_attention.block_positions(
+                    self.max_seq_len, config.num_kv_heads, config.hd, kv_dt.itemsize)
         B = self.max_num_seqs
         # per-slot device-side sampling state
         self._temps = np.zeros((B,), np.float32)
@@ -679,7 +692,8 @@ class LLMEngine:
                 )
             elif not self._hybrid:
                 self._fused_step = make_fused_fns(
-                    config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant
+                    config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
+                    partitioned=mesh is not None,
                 )
             self._set_lane, self._set_table, self._set_table_cell = make_delta_fns()
             if mesh is None:
@@ -2547,7 +2561,7 @@ class LLMEngine:
                 if spec:
                     self._dispatch_spec(prev)
                 else:
-                    self._dispatch_fused()
+                    self._dispatch_fused(prev)
                 if tel is not None and self._pending is not None:
                     tel.dispatch_t = time.time()
         if self._device_resident:
@@ -2572,12 +2586,29 @@ class LLMEngine:
         mask[[st.slot for st in active]] = True
         return mask
 
-    def _dispatch_fused(self):
+    def _count_step_attn_blocks(self, active: list, prev) -> tuple:
+        """(blocks of positions the step about to be dispatched reads, blocks the cache holds),
+        over the layers that keep keys and values: a lane reads the blocks up to its new token's
+        position, an unbound lane none. From host state alone: a lane holds its prompt and the
+        tokens emitted so far, and one more where the step still in flight (``prev``) ran it."""
+        blk = self._attn_block
+        in_flight = {id(st) for st, _ in prev[-1]} if prev is not None else ()
+        read = sum(
+            -(-min(len(st.prompt_token_ids) + len(st.token_ids) + (id(st) in in_flight), self.max_seq_len) // blk)
+            for st in active
+        )
+        return read * self._kv_layers, self.max_num_seqs * (self.max_seq_len // blk) * self._kv_layers
+
+    def _dispatch_fused(self, prev=None):
         """Launch the fused device step for the current occupancy; never
-        blocks on results (stored in self._pending for the next call)."""
+        blocks on results (stored in self._pending for the next call).
+        ``prev``: the step still in flight, which the caller has taken."""
         active = [s for s in self._slots if s is not None]
+        self._step_attn_blocks = None
         if not active:
             return
+        if self._attn_block is not None:
+            self._step_attn_blocks = self._count_step_attn_blocks(active, prev)
         # the fused programs donate the sampling lanes and hand them back
         # as passthrough outputs (zero-copy aliases); rebind the handles
         if self.kv_layout == "paged":
@@ -2607,6 +2638,9 @@ class LLMEngine:
             self._pending = (toks, logps, moe, [(st, st.slot) for st in active])
             return
         else:
+            # which lanes are bound: the attention kernel reads nothing for the others (a
+            # shard_map body takes its arguments by position and runs no kernel)
+            live = {} if self._tp_fused else {"live": self._lane_mask(active)}
             (self.cache, toks, logps, self._dkeys,
              self._dtemps, self._dtopk, self._dtopp) = self._fused_step(
                 self.params,
@@ -2616,6 +2650,7 @@ class LLMEngine:
                 self._dtemps,
                 self._dtopk,
                 self._dtopp,
+                **live,
             )
         self._dtokens = toks
         self._pending = (toks, logps, [(st, st.slot) for st in active])

@@ -10,9 +10,14 @@ second stacked array to the compiler: it allocates one beside the donated
 input (6.25 GiB of temporaries for a 6.0 GiB cache on a v5e), writes every
 layer's full rows into it and copies the whole of it every step. So
 `decode_step` keeps the stacked K and V (and an int8 cache's scales) in the
-scan's CARRY, writes one token a layer into them in place and reads its
-layer back by index; the donated buffers are then the only cache there is.
-`extend` and `spec/verify.py`'s block forward do the same.
+scan's CARRY and writes one token a layer into them in place; the donated
+buffers are then the only cache there is. Its attention then reads the
+positions each lane holds out of the stack where they lie
+(`ops/slot_attention.attend`): on a TPU a kernel that streams a lane's live
+blocks of layer `i` and nothing else; elsewhere (and for an int8 cache or a
+partitioned program) the XLA form, which slices layer `i`'s rows out by
+index and masks. `extend` and `spec/verify.py`'s block forward carry the
+cache the same way and read one layer's rows back by index.
 
 Prefill runs the causal flash path on one (padded) prompt and returns the
 per-layer K/V to be inserted into a cache slot. Decode advances every slot
@@ -31,6 +36,7 @@ import jax.numpy as jnp
 
 from ray_tpu.lint import jaxcheck
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops import slot_attention
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
 
@@ -303,9 +309,7 @@ def _mlp(x, layer, cfg: LlamaConfig, tpc: TpSpec | None = None):
     return x + _tp_reduce(jnp.dot(jax.nn.silu(g) * u, layer["w_down"]), tpc)
 
 
-def _layer_of(stacked, i):
-    """Layer ``i`` of a stacked cache leaf ``[L, ...]`` (i: a traced index inside the layer loop)."""
-    return jax.lax.dynamic_index_in_dim(stacked, i, 0, keepdims=False)
+_layer_of = slot_attention.layer_of  # layer i of a stacked cache leaf; ``spec/verify.py`` takes it from here
 
 
 def _scan_layers_carrying_cache(layer_fn, x, params, cache):
@@ -370,7 +374,8 @@ def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
     shapes={"b8_s256": _bucket_decode},
     donate=("cache",),
 )
-def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = None):
+def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = None, live=None,
+                partitioned: bool = False):
     """Advance every slot one token.
 
     tokens: [slots] int32 (next input token per slot, garbage for empty
@@ -381,10 +386,18 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
     The layer loop carries ``(x, stacked cache leaves)`` and scans over
     ``(params["layers"], layer index)`` (_scan_layers_carrying_cache): each
     layer writes ONE token a lane into the stacked arrays
-    (``.at[i, lanes, write_pos].set``) and reads its own layer back by
-    index. Nothing of the cache's size is returned from the body, so a
-    donated cache is updated where it lies (why not xs/ys: the module
-    docstring).
+    (``.at[i, lanes, write_pos].set``) and hands the stack and its index to
+    ``slot_attention.attend``, which reads positions 0..length of layer i
+    from it (the new token among them). Nothing of the cache's size is
+    returned from the body, so a donated cache is updated where it lies
+    (why not xs/ys: the module docstring).
+
+    ``live`` [slots] bool, where the caller knows it (the engine's lane
+    table): lanes bound to a sequence. The attention kernel reads nothing
+    for the others, whose stale lengths would otherwise have it stream rows
+    nobody attends to; None means every lane. ``partitioned``: the program
+    is compiled over a mesh by the SPMD partitioner, which cannot split a
+    Mosaic kernel, so attention keeps its XLA form.
 
     An int8 cache (k_scale/v_scale present) quantizes the appended token
     INSIDE this program and dequantizes the row for attention at the f32
@@ -406,14 +419,11 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
     """
     B = tokens.shape[0]
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    rep = nh // nkv
     quant = "k_scale" in cache
     lengths = cache["length"]
     cos, sin = rotary_embedding(lengths[:, None], cfg.hd, cfg.rope_theta)  # [B, 1, hd/2]
     x = _tp_embed(params["embed"], tokens[:, None], tpc)  # [B, 1, H]
     S = cache["k"].shape[2]
-    # mask: new token sits at index `length`, may attend to 0..length
-    attn_ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]  # [B,1,1,S]
 
     lanes = jnp.arange(B, dtype=jnp.int32)
     write_pos = jnp.minimum(lengths, S - 1)
@@ -437,17 +447,11 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
         # length): harmless, the mask never reads past `length`
         kv["k"] = kv["k"].at[i, lanes, write_pos].set(k_tok.astype(kv["k"].dtype))
         kv["v"] = kv["v"].at[i, lanes, write_pos].set(v_tok.astype(kv["v"].dtype))
-        # GQA attention against the cache: head h uses kv head h // rep
-        qg = qh[:, 0].reshape(B, nkv, rep, hd)
-        kc = _layer_of(kv["k"], i).transpose(0, 2, 1, 3)  # [B,nkv,S,hd]
-        vc = _layer_of(kv["v"], i).transpose(0, 2, 1, 3)
-        if quant:
-            kc = kc.astype(jnp.float32) * _layer_of(kv["k_scale"], i)[..., None]
-            vc = vc.astype(jnp.float32) * _layer_of(kv["v_scale"], i)[..., None]
-        scores = jnp.einsum("bgrh,bgsh->bgrs", qg, kc, preferred_element_type=jnp.float32) / jnp.sqrt(hd)
-        scores = jnp.where(attn_ok, scores, -jnp.inf)  # [B,1,1,S] bcast
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bgrs,bgsh->bgrh", probs, vc.astype(jnp.float32)).reshape(B, 1, nh * hd).astype(x.dtype)
+        # GQA attention against the cache (head h uses kv head h // rep), the new token read
+        # back from it: positions 0..length of layer i, out of the stack where they lie
+        o = slot_attention.attend(qh[:, 0], kv["k"], kv["v"], i, lengths, nkv, live=live, k_scale=kv.get("k_scale"),
+                                  v_scale=kv.get("v_scale"), sharded=partitioned or tpc is not None)
+        o = o.reshape(B, 1, nh * hd).astype(x.dtype)
         x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
         x = _mlp(x, layer, cfg, tpc)
         return x, kv
@@ -751,6 +755,8 @@ def fused_step(
     top_p,
     cfg: LlamaConfig,
     tpc: TpSpec | None = None,
+    live=None,
+    partitioned: bool = False,
 ):
     """ONE program for the slot layout's whole decode hot path: decode ->
     sample -> append-KV -> advance lengths. Nothing in it touches the
@@ -770,7 +776,7 @@ def fused_step(
     """
     from ray_tpu.llm.sampling import sample
 
-    logits, cache = decode_step(params, cache, tokens, cfg, tpc)
+    logits, cache = decode_step(params, cache, tokens, cfg, tpc, live, partitioned)
     toks, logps, new_keys = sample(logits, keys, temps, top_k, top_p)
     return cache, toks, logps, new_keys, temps, top_k, top_p
 
@@ -810,17 +816,21 @@ def _sharded_fused_slots(cfg: LlamaConfig, mesh, tp_collective: str, kv_quant: b
     )
 
 
-def make_fused_fns(cfg: LlamaConfig, mesh=None, tp_collective: str = "fp", kv_quant: bool = False):
+def make_fused_fns(cfg: LlamaConfig, mesh=None, tp_collective: str = "fp", kv_quant: bool = False,
+                   partitioned: bool = False):
     """Jit of fused_step with the production donation set. With a tp>1
     mesh the step compiles as ONE SPMD program via shard_map — the
     per-layer tp all-reduce is an explicit psum inside it, quantized to
-    int8 on the wire when tp_collective="int8"."""
+    int8 on the wire when tp_collective="int8". ``partitioned``: the
+    engine's arrays are sharded over some other mesh and the compiler
+    partitions the plain program (no Mosaic kernel in it then)."""
     from ray_tpu.parallel.mesh import axis_size
 
     if mesh is not None and axis_size(mesh, "tp") > 1:
         return named_jit("llm_fused_step", _sharded_fused_slots(cfg, mesh, tp_collective, kv_quant),
                          donate_argnums=(1, 3, 4, 5, 6))
-    return named_jit("llm_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6))
+    return named_jit("llm_fused_step", partial(fused_step, cfg=cfg, partitioned=partitioned),
+                     donate_argnums=(1, 3, 4, 5, 6))
 
 
 @jaxcheck.entry(
@@ -1063,7 +1073,8 @@ def make_runner_fns(cfg: LlamaConfig, mesh=None):
 
     prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
     insert_fn = named_jit("llm_kv_insert", kvc.insert_sequence, donate_argnums=(0,))
-    decode_fn = named_jit("llm_decode_step", partial(decode_step, cfg=cfg), donate_argnums=(1,))
+    decode_fn = named_jit("llm_decode_step", partial(decode_step, cfg=cfg, partitioned=mesh is not None),
+                          donate_argnums=(1,))
     extend_fn = named_jit("llm_extend", partial(extend, cfg=cfg), donate_argnums=(1,))
     return prefill_fn, insert_fn, decode_fn, extend_fn
 

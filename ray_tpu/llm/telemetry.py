@@ -333,7 +333,14 @@ class FlightRecorder:
         # bound lanes with temperature > 0: the lanes whose top-k or top-p the sampler's counting
         # passes run for (llm/sampling.py); a step with none takes its tokens from the argmax
         "sampling_lanes", "waiting",
-        "occupied_tokens", "capacity_tokens", "pages_free", "pages_total",
+        "occupied_tokens", "capacity_tokens",
+        # the decode program this step dispatched, where its attention is the kernel that reads a
+        # lane's live blocks only (ops/slot_attention.py): blocks of positions it reads and blocks
+        # the slot cache holds, over the layers that keep keys and values. Their ratio is the share
+        # of the cache the step moves. Absent where the XLA form runs (it reads all of it) and in a
+        # step that dispatched nothing
+        "attn_blocks_read", "attn_blocks_total",
+        "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
         # the step's start and the instant its fused program was enqueued
         # (both time.time(); dispatch_t absent where none was), then the
@@ -893,7 +900,7 @@ class EngineTelemetry:
             moe += (tokens, padded, round(float(hit) / programs, 3), round(float(pairs), 3), round(float(rows), 3))
         self.recorder.record_step((
             now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, sampling_lanes, waiting,
-            occupied, capacity,
+            occupied, capacity, *(eng._step_attn_blocks or (None, None)),
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],

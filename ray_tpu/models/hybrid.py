@@ -27,7 +27,10 @@ Two loops over one description:
   layer that keeps it.
 - ``run_layers`` for a decode step: a scan over the repeated period of the pattern
   (``layer_plan``), with the caches in its carry, updated in place through a
-  ``LayerCache``.
+  ``LayerCache``. An attention layer's keys and values are read from the stacked rows
+  where they lie (``attend_slot`` -> ``ops/slot_attention.attend``, the op the Llama
+  decode step calls too): a lane's live blocks on a TPU, the layer's rows sliced out
+  and masked elsewhere.
 
 Neither loop, nor the step programs of ``llm/hybrid_runner.py`` that call them,
 names a model or a kind of layer. A uniform model is the special case of one kind
@@ -36,13 +39,13 @@ and a period of one.
 
 from __future__ import annotations
 
-import math
 from collections import Counter
 from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import slot_attention
 from ray_tpu.ops.layers import cross_entropy_loss
 
 # what a routing layer's sequence form reports beside its output, as one more thing it "keeps":
@@ -193,17 +196,24 @@ def scan_layers(config, params, x, layer_fn, empty):
 
 class LayerCache:
     """One layer's window onto the caches that ride ``run_layers``' carry, for a mixer's step
-    form: ``read(name)`` is that layer's entry for every lane ([B, *shape], or a layer's rows
-    [B, S, *shape] for a per-position entry), ``write(name, value)`` overwrites a per-sequence
-    entry or puts ONE position's value at each lane's current position. Both act on the stacked
-    arrays in place (a dynamic slice of, a scatter or an update into the donated carry); the
-    arrays as they stand afterwards are ``arrays``."""
+    form: ``read(name)`` is that layer's entry for every lane ([B, *shape]: a recurrent state),
+    ``write(name, value)`` overwrites a per-sequence entry or puts ONE position's value at each
+    lane's current position, ``stacked(name)`` hands out the whole array and the layer's index,
+    for a per-position entry whose rows [B, S, *shape] an op reads in part (``attend_slot``;
+    ``read`` would slice all S positions of every lane out). All act on the stacked arrays in
+    place (a dynamic slice of, a scatter or an update into the donated carry); the arrays as
+    they stand afterwards are ``arrays``."""
 
     def __init__(self, arrays: dict, per_position: frozenset, i, lanes, pos):
         self.arrays, self._per_position, self._i, self._lanes, self._pos = dict(arrays), per_position, i, lanes, pos
 
     def read(self, name: str):
         return jax.lax.dynamic_index_in_dim(self.arrays[name], self._i, 0, keepdims=False)
+
+    def stacked(self, name: str):
+        """(the whole stacked array, this layer's index in it): for an op that reads a part of
+        the layer's entry from where it lies, where ``read`` would slice all of it out."""
+        return self.arrays[name], self._i
 
     def write(self, name: str, value) -> None:
         a = self.arrays[name]
@@ -229,17 +239,12 @@ def init_stacked(groups: dict, count, keys, dt) -> dict:
             for g, group in groups.items() if count(g)}
 
 
-def attend_slot_rows(q, k_cache, v_cache, lengths, num_kv_heads: int):
-    """One token a lane (its query q [B,nh,hd]) against a layer's rows k/v_cache [B,S,kv,hd], in
-    which the new token's key and value already sit at index lengths[b]: grouped-query softmax
-    attention over the positions held. -> [B, nh*hd] float32."""
-    B, S = k_cache.shape[:2]
-    nh, hd = q.shape[1:]
-    qg = q.reshape(B, num_kv_heads, nh // num_kv_heads, hd)
-    scores = jnp.einsum("bgrh,bsgh->bgrs", qg, k_cache, preferred_element_type=jnp.float32) / math.sqrt(hd)
-    ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]
-    probs = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bgrs,bsgh->bgrh", probs, v_cache.astype(jnp.float32)).reshape(B, nh * hd)
+def attend_slot(q, cache: LayerCache, ctx: StepCtx, num_kv_heads: int):
+    """One token a lane (its query q [B,nh,hd]) against the positions its lane holds in THIS
+    layer's keys and values, the new token's among them (written through ``cache`` before the
+    call): ``ops/slot_attention.attend`` on the stacked rows where they lie. -> [B, nh*hd] f32."""
+    (k, i), (v, _) = cache.stacked("k"), cache.stacked("v")
+    return slot_attention.attend(q, k, v, i, ctx.lengths, num_kv_heads, live=ctx.active)
 
 
 # ------------------------------------------------------------- sequence forward

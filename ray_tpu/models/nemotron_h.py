@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import experts
 from ray_tpu.models.experts import ExpertLayer, experts_dense, experts_grouped, route  # noqa: F401 - the layer's parts, by the names they had here
-from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot_rows, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
+from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import rms_norm
 
@@ -137,7 +137,7 @@ class NemotronHConfig(HybridDescription):
             q, k, v = qkv(w, xn.astype(dt), self)
             cache.write("k", k)
             cache.write("v", v)
-            return attn_step(w, q, cache.read("k"), cache.read("v"), ctx.lengths, self), None
+            return attn_step(w, q, cache, ctx, self), None
 
         forms = {"mamba": (mamba_seq, mamba_step, False), "attn": (attention_seq, attention_step, False),
                  "moe": (experts_seq, experts_step, True)}
@@ -428,9 +428,9 @@ def attn_seq(w, xn, c: NemotronHConfig, mesh=None):
     return jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, c.num_heads * c.hd), w["wo"]), k, v
 
 
-def attn_step(w, q, k_cache, v_cache, lengths, c: NemotronHConfig):
-    """One token a lane (its query q [B,nh,hd] from ``qkv``) against a layer's rows
-    k/v_cache [B,S,kv,hd], in which the new token's key and value already sit at index
-    lengths[b]. -> out [B,H]."""
-    o = attend_slot_rows(q, k_cache, v_cache, lengths, c.num_kv_heads)
+def attn_step(w, q, cache, ctx, c: NemotronHConfig):
+    """One token a lane (its query q [B,nh,hd] from ``qkv``) against the positions its lane holds
+    in this layer's keys and values (``cache``: the layer's ``LayerCache``, the new token's key
+    and value already written through it at index ctx.lengths[b]). -> out [B,H]."""
+    o = attend_slot(q, cache, ctx, c.num_kv_heads)
     return jnp.dot(o.astype(q.dtype), w["wo"])

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import experts
 from ray_tpu.models.experts import ExpertLayer
-from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot_rows, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
+from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rotary_embedding
 
@@ -144,7 +144,7 @@ class Qwen3NextConfig(HybridDescription):
             q, gate, k, v = gated_attn_qkv(w, xn.astype(dt)[:, None], ctx.lengths[:, None], self)
             cache.write("k", k[:, 0])
             cache.write("v", v[:, 0])
-            o = attend_slot_rows(q[:, 0], cache.read("k"), cache.read("v"), ctx.lengths, self.num_kv_heads)
+            o = attend_slot(q[:, 0], cache, ctx, self.num_kv_heads)
             return _gated_out(w, o, gate[:, 0], dt), None
 
         def experts_seq(w, xn, ctx):

@@ -1,0 +1,226 @@
+"""Attention of a slot decode step: one new token a lane against the stacked slot cache.
+
+The slot layout keeps every layer's keys and values in two arrays ``[L, slots, S, kv, hd]`` that
+ride the layer loop's carry (``llm/model_runner.py``, ``models/hybrid.py``). A decode step's
+attention needs, of layer ``i``, the positions each lane holds: ``0 .. lengths[b]``, the last of
+them the token the layer has just written. ``attend`` is that op, in two forms:
+
+- the XLA form (``attend_rows`` over layer ``i``'s rows sliced out of the stack): the oracle, and
+  what runs off the TPU, on an int8 cache and inside a ``shard_map`` body. It reads all ``S``
+  positions of all lanes and masks: 7.5 of a 17.8 ms step went to the slice alone at 14 x 4096
+  InternLM2 slots, and from 15 slots the slice no longer fitted the chip's fast memory (PERF.md
+  section 6, PR 35).
+- the Pallas kernel: a grid over (lane, block of positions) whose index map reads the layer index
+  and the lanes' bounds from SMEM, so that a block streams HBM -> VMEM from where it lies in the
+  stack, once, and ONLY IF it holds a live position. A block past a lane's bound repeats the index
+  of the block before it, which the pipeline does not fetch again, and its body is skipped; a lane
+  bound to no sequence repeats the previous live lane's last block and so reads nothing. No
+  layer's rows are materialised, so nothing depends on the slot count.
+
+Inside the kernel the stack is seen as ``[L, slots, S * kv, hd]`` (the same bytes: a position's
+``(kv, hd)`` tile is ``kv`` rows), so a block is a plain 2-D ``[blk * kv, hd]`` matrix whatever
+``kv`` is. Scores for ALL heads against ALL rows are one MXU product ``[nh, hd] x [hd, blk * kv]``
+in the cache's dtype with float32 accumulation (exact products, as the XLA form's); the columns of
+the other kv heads are masked out (the MXU idles in a decode step anyway). Softmax runs in float32
+with the usual running max and sum. The probabilities stay float32: they are split into three
+bfloat16 terms (8 + 8 + 8 mantissa bits) that multiply the bfloat16 values exactly, so the second
+product accumulates what a float32 x float32 one would.
+
+Which form runs is decided by what the code can see (``refusal``): the backend, the cache's dtype,
+whether the caller is a ``shard_map`` body, and the tile's shape. There is no knob.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG = -1e30  # finite stand-in for -inf: exp(_NEG - m) is 0 and (_NEG - _NEG) is not NaN
+
+# bytes of one K (or V) block; the pipeline holds two of each. 14 x 4096 InternLM2 slots timed on a
+# v5e (PERF.md section 6, PR 35) chose between 1 MiB (512 positions of 8 x 128) and 2 MiB
+_BLOCK_BYTES = 1 << 20
+
+
+# --------------------------------------------------------------------------- the XLA form
+def attend_rows(q, k_rows, v_rows, lengths, num_kv_heads: int):
+    """One token a lane (its query q [B,nh,hd]) against a layer's rows k/v_rows [B,S,kv,hd], in
+    which the new token's key and value already sit at index lengths[b]: grouped-query softmax
+    attention over the positions held. -> [B, nh*hd] float32."""
+    B, S = k_rows.shape[:2]
+    nh, hd = q.shape[1:]
+    qg = q.reshape(B, num_kv_heads, nh // num_kv_heads, hd)
+    scores = jnp.einsum("bgrh,bsgh->bgrs", qg, k_rows, preferred_element_type=jnp.float32) / math.sqrt(hd)
+    ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]
+    probs = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bgrs,bsgh->bgrh", probs, v_rows.astype(jnp.float32)).reshape(B, nh * hd)
+
+
+def layer_of(stacked, i):
+    """Layer ``i`` of a stacked cache leaf ``[L, ...]`` (i: a traced index inside the layer loop)."""
+    return jax.lax.dynamic_index_in_dim(stacked, i, 0, keepdims=False)
+
+
+# --------------------------------------------------------------------------- which form
+def block_positions(S: int, num_kv_heads: int, head_dim: int, itemsize: int, block_bytes: int = _BLOCK_BYTES) -> int:
+    """Positions in one block: the largest power of two that divides ``S`` and whose K rows take
+    at most ``block_bytes``. kv 8 x hd 128 in bfloat16 -> 512, kv 2 x hd 128 -> 2048, kv 2 x hd 256
+    -> 1024: one kernel, tiles by the bytes a position takes."""
+    cap = max(block_bytes // (num_kv_heads * head_dim * itemsize), 1)
+    return math.gcd(S, 1 << (cap.bit_length() - 1))
+
+
+def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: int, *,
+            quantized: bool = False, sharded: bool = False) -> str | None:
+    """Why the kernel does NOT serve this call (the XLA form then does), or None. Off the TPU the
+    answer is always a reason: tier-1 runs on the CPU and the Pallas interpreter under every engine
+    test would cost the suite minutes; tests run the interpreter by asking for it. On the TPU the
+    shapes let through are the ones compiled for a v5e in ``tests/test_chip_compile.py``."""
+    if jax.default_backend() != "tpu":
+        return f"backend {jax.default_backend()!r}: the kernel is compiled for the TPU only"
+    if sharded:
+        return "inside a shard_map body (tensor parallel): a Mosaic kernel is not partitioned, and no cell runs it"
+    if quantized:
+        return "an int8 cache: the scales are not streamed by this kernel, and no cell runs it"
+    dt = jnp.dtype(cache_dtype)
+    if dt != jnp.bfloat16:
+        return f"a {dt.name} cache: the kernel has been compiled for bfloat16 rows only"
+    if head_dim % 128 or head_dim > 256:
+        return f"head_dim {head_dim}: compiled at 128 and 256 (a multiple of the 128 lanes)"
+    if num_kv_heads & (num_kv_heads - 1) or num_kv_heads > 8:
+        return f"{num_kv_heads} kv heads: compiled at 2 and 8 (a power of two, at most 8)"
+    if head_dim != 128 and num_kv_heads != 8:
+        return (f"{num_kv_heads} kv heads x head_dim {head_dim}: a position's heads lie in ({num_kv_heads}, 128) tiles, which "
+                "are [S * kv, hd] rows only after a copy of the whole cache (402 MB of temporaries at 3 x 16 x 4096 x 2 x 256, "
+                "compiled for a v5e in PR 35)")
+    if num_heads % 16 or num_heads > 32:
+        return f"{num_heads} query heads: compiled at 16 and 32 (whole bfloat16 tiles of 16 rows)"
+    blk = block_positions(S, num_kv_heads, head_dim, dt.itemsize)
+    if blk * num_kv_heads < 512:
+        return f"{S} positions a slot: no block of at least {512 // num_kv_heads} positions divides it"
+    return None
+
+
+# --------------------------------------------------------------------------- the kernel
+def _kernel(layer_ref, bound_ref, src_ref, last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, *,
+            blk: int, kv: int, rep: int, scale: float):
+    """Grid step (lane b, block j): fold the block's positions below ``bound[b]`` into the lane's
+    running max (m), sum (l) and weighted values (o_ref, which the block axis revisits)."""
+    del layer_ref, src_ref, last_ref  # the index maps' business
+    b, j = pl.program_id(0), pl.program_id(1)
+    bound = bound_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(j * blk < bound)  # a block with no live position: not fetched (the index map), not computed
+    def _fold():
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]  # [nh, hd], [blk*kv, hd] x 2
+        nh, cols = q.shape[0], k.shape[0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        row = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 1)
+        # column c is kv head c % kv of position c // kv; query head h reads kv head h // rep
+        ok = (col % kv == row // rep) & (j * blk + col // kv < bound)
+        s = jnp.where(ok, s, _NEG)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))  # every row has position j*blk: real
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)  # masked columns: exp(_NEG - m) == 0
+        l_scr[...] = jnp.broadcast_to(l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        if v.dtype == jnp.bfloat16:
+            # float32 probabilities as three bfloat16 terms, stacked so that V is loaded once
+            hi = p.astype(jnp.bfloat16)
+            r1 = p - hi.astype(jnp.float32)
+            mid = r1.astype(jnp.bfloat16)
+            lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+            pv = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), v, preferred_element_type=jnp.float32)
+            pv = (pv[:nh] + pv[nh:2 * nh]) + pv[2 * nh:]
+        else:
+            pv = jnp.dot(p, v.astype(jnp.float32), preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
+        o_ref[...] = o_ref[...] * alpha + pv
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _normalise():
+        l = l_scr[:, :1]
+        o_ref[...] = o_ref[...] / jnp.where(l > 0.0, l, 1.0)  # a lane with no position folded nothing: zeros
+
+
+def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None, interpret: bool = False):
+    """The kernel form. q [B,nh,hd]; k/v_stack [L,B,S,kv,hd]; layer: int32 scalar (traced or not);
+    bound [B] int32: lane b attends positions 0 .. bound[b]-1 of layer ``layer`` (0: the lane is
+    bound to no sequence, reads nothing and gets zeros). -> [B, nh*hd] float32."""
+    B, nh, hd = q.shape
+    L, _, S, kv, _ = k_stack.shape
+    blk = block or block_positions(S, kv, hd, k_stack.dtype.itemsize)
+    nblk = S // blk
+    if S % blk:
+        raise ValueError(f"a block of {blk} positions does not divide {S}")  # tpulint: disable=ERR002 — a programmer's error at trace time
+    bound = jnp.clip(bound.astype(jnp.int32), 0, S)
+    lane = jnp.arange(B, dtype=jnp.int32)
+    live = bound > 0
+    # where an empty lane's steps point: the live lane before it, at its last block (the index the
+    # step before had, so nothing is fetched); before the first live lane, that lane's block 0
+    before = jax.lax.cummax(jnp.where(live, lane, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    last = jnp.where(before >= 0, (jnp.maximum(bound, 1) - 1)[src] // blk, 0)
+
+    def rows(b, j, layer_ref, bound_ref, src_ref, last_ref):
+        return layer_ref[0], src_ref[b], jnp.minimum(jnp.where(bound_ref[b] > 0, j, nblk), last_ref[b]), 0
+
+    per_lane = lambda b, j, *_: (b, 0, 0)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_kernel, blk=blk, kv=kv, rep=nh // kv, scale=1.0 / math.sqrt(hd)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, nblk),
+            in_specs=[
+                pl.BlockSpec((None, nh, hd), per_lane),
+                pl.BlockSpec((None, None, blk * kv, hd), rows),
+                pl.BlockSpec((None, None, blk * kv, hd), rows),
+            ],
+            out_specs=pl.BlockSpec((None, nh, hd), per_lane),
+            scratch_shapes=[pltpu.VMEM((nh, 128), jnp.float32), pltpu.VMEM((nh, 128), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, nh, hd), jnp.float32),
+        interpret=interpret,
+        name="slot_decode_attention",
+        # an empty lane's steps lean on the step before them: the grid runs in order
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=48 << 20)}),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), bound, src, last,
+      q, k_stack.reshape(L, B, S * kv, hd), v_stack.reshape(L, B, S * kv, hd))
+    return out.reshape(B, nh * hd)
+
+
+# --------------------------------------------------------------------------- the op
+def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None, k_scale=None, v_scale=None,
+           sharded: bool = False):
+    """One token a lane (its query q [B,nh,hd]) against layer ``layer`` of the stacked slot cache
+    k/v_stack [L,B,S,kv,hd], in which the new token's key and value already sit at index
+    lengths[b]. ``live`` [B] bool, where the caller knows it: lanes bound to a sequence (the kernel
+    reads nothing for the others; the XLA form computes what nobody reads, as it always has).
+    k/v_scale [L,B,kv,S]: an int8 cache's scales. ``sharded``: the caller is a shard_map body.
+    -> [B, nh*hd] float32."""
+    S = k_stack.shape[2]
+    why = refusal(k_stack.dtype, q.shape[1], num_kv_heads, q.shape[2], S, quantized=k_scale is not None, sharded=sharded)
+    if why is None:
+        bound = jnp.minimum(lengths, S - 1) + 1
+        # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
+        return attend_kernel(q, k_stack, v_stack, layer, bound if live is None else jnp.where(live, bound, 0),
+                             interpret=jax.default_backend() != "tpu")
+    k_rows, v_rows = layer_of(k_stack, layer), layer_of(v_stack, layer)
+    if k_scale is not None:  # dequantize at the float32 the products already accumulate in
+        k_rows = k_rows.astype(jnp.float32) * layer_of(k_scale, layer).transpose(0, 2, 1)[..., None]
+        v_rows = v_rows.astype(jnp.float32) * layer_of(v_scale, layer).transpose(0, 2, 1)[..., None]
+    return attend_rows(q, k_rows, v_rows, lengths, num_kv_heads)

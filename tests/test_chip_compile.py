@@ -416,3 +416,97 @@ def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on
     assert "tpu_custom_call" in txt, "the flash kernel, 256 wide"
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (4.5 GiB), no layer's worth of them (0.375 GiB x 12)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.67 * 2**30 < 15.0 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# PR 35: the slot decode step's attention as a kernel over the stacked cache
+# (ops/slot_attention.py). ``refusal`` asks jax.default_backend(), which says
+# "cpu" here: the tests answer for the chip, as the guide says a test may
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _kv_bytes(cache):
+    return sum(a.size * a.dtype.itemsize for n, a in cache.items() if n != "length")
+
+
+@pytest.mark.parametrize("kv, hd, nh, slots, layers", [(8, 128, 16, 16, 24), (2, 128, 32, 32, 2)],
+                         ids=["internlm2_kv8_hd128", "nemotron_kv2_hd128"])
+def test_slot_attention_kernel_compiles_for_v5e_and_copies_nothing(one_chip, as_on_a_tpu, kv, hd, nh, slots, layers):
+    """The tiles the gate lets through, at the cells' sizes: a Mosaic kernel, the stack read where
+    it lies (seen as [L, slots, S*kv, hd] by a bitcast), no temporary of any size to speak of."""
+    from ray_tpu.ops import slot_attention as sa
+
+    assert sa.refusal(jnp.bfloat16, nh, kv, hd, 4096) is None
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    stack = sds((layers, slots, 4096, kv, hd), jnp.bfloat16)
+    compiled, txt = _compile(sa.attend_kernel, sds((slots, nh, hd), jnp.bfloat16), stack, stack, sds((), jnp.int32), sds((slots,), jnp.int32))
+    assert "tpu_custom_call" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_slot_attention_gate_refuses_qwen3_nexts_tile_for_the_copy_it_would_force(one_chip, as_on_a_tpu):
+    """2 kv heads x 256: the compiler lays a position's heads out in (2, 128) tiles, and seeing them
+    as rows costs a copy of K and V (402 MB here). The gate says so and the XLA form stays."""
+    from ray_tpu.ops import slot_attention as sa
+
+    assert "copy of the whole cache" in sa.refusal(jnp.bfloat16, 16, 2, 256, 4096)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    stack = sds((3, 16, 4096, 2, 256), jnp.bfloat16)
+    compiled, _ = _compile(sa.attend_kernel, sds((16, 16, 256), jnp.bfloat16), stack, stack, sds((), jnp.int32), sds((16,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes >= 2 * stack.size * 2
+
+
+@pytest.mark.parametrize("slots", [16, 24])
+def test_fused_slot_decode_step_holds_no_layers_rows_at_internlm2_sizes(one_chip, as_on_a_tpu, slots):
+    """PR 35: attention reads the stacked cache through the kernel, so the step holds no
+    ``[1, slots, 4096, 8, 128]`` slice of a layer (the 3.74 ms x 2 of every step at 14 slots, an HBM
+    temporary from 15 slots on: PERF.md section 6), whatever the slot count: the whole cache aliased,
+    temporaries far under ONE layer's K rows (128 MiB at 16 slots), 13.4 GiB of arguments at 24."""
+    import re
+
+    from ray_tpu.llm.model_runner import _sds_cache, _sds_lanes, _sds_params, fused_step
+
+    cfg = _internlm2_1_8b()
+    cache = _sds_cache(cfg, slots, cfg.max_seq_len)
+    args = _on((_sds_params(cfg), cache) + _sds_lanes(slots), one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    step = jax.jit(partial(fused_step, cfg=cfg), donate_argnums=(1, 3, 4, 5, 6))
+    compiled = step.lower(*args, live=live).compile()
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert "tpu_custom_call" in txt and not re.search(r"bf16\[1,%d,4096,8,128\]" % slots, txt)
+    assert mem.alias_size_in_bytes >= _kv_bytes(cache)
+    assert mem.temp_size_in_bytes < 32 * 2**20 < slots * 4096 * 8 * 128 * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+def test_nemotron_fused_step_holds_no_layers_rows_at_its_cells_size(one_chip, as_on_a_tpu):
+    """The hybrid's two attention layers through the same op (``hybrid.attend_slot`` hands it the
+    stacked leaf and the layer's index): a kernel in the step, no ``[1, 32, 4096, 2, 128]`` slice,
+    both caches still aliased."""
+    import re
+
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, cache, state = _hybrid_at_the_benchmarks_size(one_chip)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    lanes = (s((32,), jnp.int32), s((32, 2), jnp.uint32), s((32,), jnp.float32), s((32,), jnp.int32), s((32,), jnp.float32))
+    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+    compiled = step.lower(params, cache, state, *lanes, s((32,), jnp.bool_)).compile()
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert "slot_decode_attention" in txt and not re.search(r"bf16\[1,32,4096,2,128\]", txt)
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)))
+    assert mem.temp_size_in_bytes < 0.1 * 2**30
+
+
+def test_qwen3_next_fused_step_keeps_the_xla_form(one_chip, as_on_a_tpu):
+    """The gate's refusal at work: no kernel in the step of the tile it refused."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, cache, state = _qwen3_next_at_the_benchmarks_size(one_chip)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
+    txt = jax.jit(partial(hr.fused_step, cfg=cfg)).lower(params, cache, state, *lanes, s((16,), jnp.bool_)).compile().as_text()
+    assert "slot_decode_attention" not in txt

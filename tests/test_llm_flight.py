@@ -177,6 +177,44 @@ def test_every_step_row_counts_the_lanes_that_sample():
     assert seen == [2, 1, 0], "both sampled requests, then the longer one alone, then the greedy one alone"
 
 
+def test_step_rows_count_the_blocks_the_attention_kernel_reads():
+    """PR 35: ``attn_blocks_read`` / ``attn_blocks_total`` of the decode program a step dispatched,
+    reckoned on the host (prompt + tokens emitted + the step in flight) where the attention runs as
+    the kernel that reads live blocks only. Here the engine is told by hand that it does, in blocks
+    of 16 positions: the rows agree with the lengths the device holds after each step, and with a
+    request worked out by hand. Where the XLA form runs (every engine off the TPU) they are absent."""
+    import numpy as np
+
+    plain = _engine()
+    plain.generate([[1, 2, 3]], SamplingParams(max_tokens=3))
+    assert all("attn_blocks_read" not in s and "attn_blocks_total" not in s for s in plain.telemetry()["steps"])
+
+    eng = _engine(max_num_seqs=3)
+    eng._attn_block, layers, per_lane = 16, CFG.num_layers, 128 // 16
+    # by hand: a prompt of 15 tokens. Its first decode step writes position 15 and reads 16
+    # positions, one block; the next reads 17, two blocks. 8 tokens come from decode steps, and the
+    # loop, which learns of the end one step late, dispatches a ninth; then nothing is bound
+    eng.generate([list(range(1, 16))], SamplingParams(max_tokens=9))
+    rows = [s for s in eng.telemetry()["steps"] if "attn_blocks_read" in s]
+    assert [s["attn_blocks_read"] for s in rows] == [1 * layers] + [2 * layers] * 8
+    assert {s["attn_blocks_total"] for s in rows} == {3 * per_lane * layers}
+    # against the device: lanes admitted and finished at different steps, lengths across block edges
+    seen = len(eng.telemetry()["steps"])
+    for n, m in ((30, 6), (1, 20), (47, 3), (16, 12)):
+        eng.add_request(list(range(1, n + 1)), SamplingParams(max_tokens=m))
+    want = []
+    while eng.has_unfinished():
+        eng.step()
+        if eng._pending is None:
+            want.append(None)
+            continue
+        held = np.asarray(eng.cache["length"])  # after the step: each dispatched lane's new token counted
+        want.append(sum(-(-int(held[slot]) // 16) for _, slot in eng._pending[-1]) * layers)
+    got = [s.get("attn_blocks_read") for s in eng.telemetry()["steps"][seen:]]
+    assert got == want and len({w for w in want if w}) > 3
+    assert all(s["attn_blocks_read"] <= s["attn_blocks_total"] for s in eng.telemetry()["steps"] if "attn_blocks_read" in s)
+
+
 def test_an_uninstrumented_engine_steps_through_the_same_code():
     eng = _engine(telemetry=False)
     out = eng.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
