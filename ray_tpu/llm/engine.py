@@ -54,8 +54,8 @@ from ray_tpu.llm.telemetry import NO_STAGE, stage
 logger = logging.getLogger("ray_tpu.llm")
 
 # public methods that move a sequence between engines as keys and values alone, and what each is
-# called in the refusal a hybrid model's engine gives instead (its recurrent layers' state is in
-# no handoff, migration or KV-plane format)
+# called in the refusal a hybrid model's engine gives instead (what its description keeps, a state
+# per sequence or a latent per position, is in no handoff, migration or KV-plane format)
 KV_ONLY_METHODS = {
     "add_prefill_request": "disaggregated prefill (add_prefill_request)",
     "prefill_remote": "disaggregated prefill (prefill_remote)",
@@ -402,7 +402,7 @@ class LLMEngine:
         import jax.numpy as jnp
 
         from ray_tpu.llm import kv_cache as kvc
-        from ray_tpu.llm.model_runner import make_paged_runner_fns, make_runner_fns
+        from ray_tpu.llm.model_runner import device_free_bytes, make_paged_runner_fns, make_runner_fns
         from ray_tpu.llm.sampling import sample
         from ray_tpu.models.llama import init_params
         from ray_tpu.util.compile_cache import enable_compile_cache
@@ -421,14 +421,18 @@ class LLMEngine:
             refuse(config, kv_layout=kv_layout, cache_dtype=cache_dtype, mesh=mesh,
                    speculative=speculative, kv_plane=kv_plane)
             for name, what in KV_ONLY_METHODS.items():
-                setattr(self, name, refuser(what))
+                setattr(self, name, refuser(what, config))
             if enable_prefix_caching:
                 # the default would engage: a hit needs a snapshot of the recurrent state at the
                 # block boundary, which nothing keeps yet. Off, and said once an engine.
-                logger.info("prefix caching is off for a hybrid model: a hit would need the recurrent "
-                            "state at the block boundary; prefix_cache_stats() answers {}")
+                logger.info("prefix caching is off for a hybrid model: a hit would need what its description "
+                            "keeps (a recurrent state at the block boundary, a latent layer's rows), which the "
+                            "prefix store does not hold; prefix_cache_stats() answers {}")
                 enable_prefix_caching = False
-        self._kv_layers = getattr(config, "num_kv_layers", config.num_layers)  # layers that keep keys and values
+        self._kv_layers = getattr(config, "num_kv_layers", config.num_layers)  # layers that keep keys and values (or a latent) per position
+        # a description names what it keeps per position (``k`` and ``v`` by head, or a latent layer's
+        # rows): the slot cache is allocated, inserted into and counted from these
+        self._kv_entries = config.position_entries() if self._hybrid else None
         if tp_collective not in ("fp", "int8"):
             raise ValueError(f"tp_collective must be 'fp' or 'int8', got {tp_collective!r}")
         self.tp_collective = tp_collective
@@ -512,7 +516,7 @@ class LLMEngine:
 
         cache_cfg = (
             None
-            if kv_layout == "paged"
+            if kv_layout == "paged" or self._hybrid
             else kvc.CacheConfig(
                 num_layers=self._kv_layers,
                 num_slots=self.max_num_seqs,
@@ -537,6 +541,8 @@ class LLMEngine:
                 from ray_tpu.llm import paged_kv as pkv
 
                 self.pool = pkv.alloc(self._pcfg)
+            elif self._hybrid:
+                self.cache = kvc.alloc_entries(self._kv_entries, self.max_num_seqs, self.max_seq_len)
             else:
                 self.cache = kvc.alloc(cache_cfg)
             if self._hybrid:
@@ -569,11 +575,14 @@ class LLMEngine:
         if kv_layout == "slots":
             from ray_tpu.ops import slot_attention
 
-            kv_dt = self.cache["k"].dtype
-            if slot_attention.refusal(kv_dt, config.num_heads, config.num_kv_heads, config.hd, self.max_seq_len,
-                                      quantized=self.kv_quant, sharded=mesh is not None) is None:
+            tile = getattr(config, "slot_attention_tile", None) or dict(
+                num_heads=config.num_heads, num_kv_heads=config.num_kv_heads, head_dim=config.hd)
+            kv_dt = next(a for name, a in self.cache.items() if name != "length").dtype
+            if slot_attention.refusal(kv_dt, **tile, S=self.max_seq_len, quantized=self.kv_quant,
+                                      sharded=mesh is not None) is None:
+                # a block holds so many positions of the rows that are values too (a latent layer's latent rows)
                 self._attn_block = slot_attention.block_positions(
-                    self.max_seq_len, config.num_kv_heads, config.hd, kv_dt.itemsize)
+                    self.max_seq_len, tile["num_kv_heads"], tile.get("value_dim", tile["head_dim"]), kv_dt.itemsize)
         B = self.max_num_seqs
         # per-slot device-side sampling state
         self._temps = np.zeros((B,), np.float32)
@@ -738,6 +747,12 @@ class LLMEngine:
 
             self._tel = EngineTelemetry(self, telemetry_tags)
             self._tel.register_fused_entries()
+        # the memory ONE prefill program may take: what the device has free now that weights, cache
+        # and state are resident (None where the backend keeps no account, as the CPU's), and what
+        # each shape that has run takes by the compiler's account (``_prefill_batch``)
+        self._prefill_room = device_free_bytes(next(a for name, a in (self.pool if kv_layout == "paged" else self.cache).items()
+                                                    if name != "length"))
+        self._prefill_need: dict[tuple[int, int], int] = {}
 
     def _init_spec(self, spec_cfg, _put):
         """Speculative decoding state: drafter, adaptive-k controller,
@@ -838,14 +853,12 @@ class LLMEngine:
         for int8), allocated vs occupied HBM, and slot/page occupancy.
         Sits next to spec_stats()/prefix_cache_stats() on the engine and
         the serve replica."""
-        from ray_tpu.llm.kv_quant import bytes_per_token
-
         cfg = self.config
-        per_tok = bytes_per_token(self._kv_layers, cfg.num_kv_heads, cfg.hd, self.kv_dtype)
+        per_tok = self.kv_bytes_per_token()
         with self._lock:
             arrs = self.pool if self.kv_layout == "paged" else self.cache
             allocated = int(sum(int(a.nbytes) for name, a in arrs.items() if name != "length"))
-            devs = sorted(arrs["k"].devices(), key=lambda d: d.id)
+            devs = sorted(next(a for name, a in arrs.items() if name != "length").devices(), key=lambda d: d.id)
             out = {
                 # the devices that HOLD the cache, as this process sees them
                 "device": {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)},
@@ -870,13 +883,34 @@ class LLMEngine:
                 )
             out["occupied_tokens"] = occupied
             out["occupied_bytes"] = occupied * int(per_tok)
+            if self._prefill_room is not None:
+                # what one prefill program may take of the device, and what each shape that has run does take
+                out["prefill_room_bytes"] = self._prefill_room
+                out["prefill_program_bytes"] = {f"{b}x{t}": need for (b, t), need in sorted(self._prefill_need.items())}
             if self._hybrid:
                 # the state cache beside the KV rows: fixed bytes a slot, whatever the lengths
                 from ray_tpu.llm import state_cache
 
+                out["entries"] = self.kv_entries()
                 out["state_bytes_per_slot"] = state_cache.bytes_per_slot(cfg)
                 out["state_allocated_bytes"] = int(sum(int(a.nbytes) for a in self.state.values()))
             return out
+
+    def kv_entries(self) -> dict:
+        """What a position of the cache is made of: entry -> [layers that keep it, its shape, its dtype]."""
+        cfg = self.config
+        entries = self._kv_entries or {n: (self._kv_layers, (cfg.num_kv_heads, cfg.hd), self.kv_dtype) for n in ("k", "v")}
+        return {name: [layers, list(shape), str(dtype)] for name, (layers, shape, dtype) in entries.items()}
+
+    def kv_bytes_per_token(self) -> int:
+        """Honest bytes one position of one sequence takes, over all layers (an int8 cache's scales included)."""
+        if self._kv_entries is not None:
+            from ray_tpu.llm.kv_cache import entry_bytes_per_token
+
+            return entry_bytes_per_token(self._kv_entries)
+        from ray_tpu.llm.kv_quant import bytes_per_token
+
+        return int(bytes_per_token(self._kv_layers, self.config.num_kv_heads, self.config.hd, self.kv_dtype))
 
     def _mesh_shardings(self, mesh):
         """Tensor-parallel serving (reference capability: the vLLM engine's
@@ -2122,12 +2156,42 @@ class LLMEngine:
         return admitted
 
     def _bucket_groups(self, plains):
-        """Group (st, slot, prompt) triples by prefill bucket."""
+        """Group (st, slot, prompt) triples by prefill bucket, a bucket's group in runs of as many
+        prompts as ONE prefill program may take (``_prefill_batch``)."""
         groups: dict[int, list] = {}
         for item in plains:
             T = _bucket(len(item[2]), self.prefill_buckets)
             groups.setdefault(T, []).append(item)
-        return list(groups.values())
+        runs = []
+        for T, group in groups.items():
+            n = self._prefill_batch(T, len(group))
+            runs += [group[i:i + n] for i in range(0, len(group), n)]
+        return runs
+
+    def _prefill_batch(self, T: int, waiting: int) -> int:
+        """The most prompts of bucket T that one prefill program takes: the next power of two over
+        those waiting, halved while its temporaries and results would not fit what the device has
+        free beside weights and cache. An admission wave is as many prompts as there are free
+        slots, and a prefill holds something for every position (a latent layer's expanded keys
+        and values: 88 KB), so the wave that fits the slots need not fit the memory. One prompt is
+        never split: where even that does not fit, the device says so."""
+        n = 1 << (waiting - 1).bit_length()
+        while n > 1 and self._prefill_room is not None and self._prefill_bytes(n, T) > self._prefill_room:
+            n //= 2
+        return n
+
+    def _prefill_bytes(self, n: int, T: int) -> int:
+        """Bytes the prefill of n prompts of bucket T takes beside its arguments: the compiler's
+        account where the shape has run (``_admit_prefill_batch`` keeps it), else reckoned by
+        positions from the largest shape of the bucket that has, else of any; 0 before any has."""
+        known = self._prefill_need
+        if (n, T) in known:
+            return known[n, T]
+        like = [(b * t, need) for (b, t), need in known.items() if t == T] or [(b * t, need) for (b, t), need in known.items()]
+        if not like:
+            return 0
+        positions, need = max(like)
+        return need * n * T // positions
 
     def _admit_prefill_batch(self, group):
         """One batched forward prefills every prompt in the group (all in
@@ -2148,7 +2212,18 @@ class LLMEngine:
             lens[i] = len(prompt)
         # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true
         # length, and its routing layers' counters (hybrid_runner.PREFILL_STATS)
-        logits, ks, vs, *new_state = self._prefill(self.params, jnp.asarray(toks), jnp.asarray(lens))
+        ks = vs = rows = kept = None
+        toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+        if self._hybrid:
+            # what each layer keeps per position and per sequence, by entry name (hybrid_runner.prefill)
+            logits, rows, kept = self._prefill(self.params, toks, lens)
+        else:
+            logits, ks, vs = self._prefill(self.params, toks, lens)
+        if self._prefill_room is not None and (Bp, T) not in self._prefill_need:
+            # the shape's first run (a warm-up's, where there is one): the executable is the one just run, nothing compiles
+            from ray_tpu.llm.model_runner import program_bytes
+
+            self._prefill_need[Bp, T] = program_bytes(self._prefill, self.params, toks, lens)
         for i, (st, slot, prompt) in enumerate(group):
             n = len(prompt)
             if self._prefix_cache is not None and not st.token_ids:
@@ -2164,18 +2239,20 @@ class LLMEngine:
                 self._lengths[slot] = n
                 if self._device_resident:
                     self._push_table(slot)
-            else:
+            elif not self._hybrid:
                 self.cache = self._insert(self.cache, slot, ks[:, i], vs[:, i], n)
-                if new_state:
+            else:
+                self.cache = self._insert(self.cache, slot, {name: a[:, i] for name, a in rows.items()}, n)
+                if self.state:
                     # replaces whatever the slot's last sequence left: the reset of a recycled slot
                     with stage(self._tel, "llm.step.state_insert"):
-                        self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), new_state[0])
+                        self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), kept)
             self._bind_slot(st, slot, logits[i : i + 1])
-        if new_state and self._tel is not None and "routing" in new_state[0]:
+        if kept and self._tel is not None and "routing" in kept:
             # the step's row in the flight log: tokens prefilled, true and as padded, and the
             # routing counters, read AFTER the first tokens (whose readback the program's end
             # already waited for): the copy of three floats waits for nothing
-            routing = np.asarray(new_state[0]["routing"])  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
+            routing = np.asarray(kept["routing"])  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
             seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing))
             self._prefill_stats = (seen[0] + int(sum(len(p) for _, _, p in group)), seen[1] + Bp * T,
                                    seen[2] + 1, seen[3] + routing)

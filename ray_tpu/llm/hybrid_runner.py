@@ -11,8 +11,10 @@ Three programs, under stable names a trace reader finds (``prefill`` and
   recurrent layer hands back its state AT each prompt's true length.
 - ``llm_state_insert``: a prefilled sequence's recurrent state into its
   slot of the state cache (``llm/state_cache.py``), replacing whatever
-  the slot's last sequence left; its keys and values go through the slot
-  cache's own ``llm_kv_insert``.
+  the slot's last sequence left; what it keeps per position (keys and
+  values, or a latent layer's rows: the description's
+  ``position_entries()``) goes through the slot cache's own
+  ``llm_kv_insert``.
 - ``llm_hybrid_fused_step``: decode -> sample -> advance, one program a
   token. Both caches ride the layer loop as its carry and are updated in
   place (one token's key and value scattered into the donated rows, one
@@ -47,10 +49,20 @@ PREFILL_STATS = ("experts_hit", "moe_pairs_local", "moe_rows_computed")
 ROUTING = hybrid.ROUTING
 
 
+def _kept(config) -> str:
+    """What a sequence of this description is, beside keys and values by head: the words of a refusal."""
+    per_sequence = sorted(state_cache.sequence_entries(config))
+    latent = sorted(set(config.position_entries()) - {"k", "v"})
+    parts = ([f"its recurrent layers keep a state per sequence ({', '.join(per_sequence)})"] if per_sequence else []) \
+        + ([f"its attention layers keep {' and '.join(latent)} per position, not keys and values by head"] if latent else [])
+    return " and ".join(parts) or "its layers are walked by the description loop"
+
+
 def refuse(config, *, kv_layout, cache_dtype, mesh, speculative, kv_plane) -> None:
-    """Engine features that assume "a sequence's state is its keys and values", each refused by
-    its name at construction: reusing, verifying, moving or re-laying-out a sequence would need
-    a snapshot, a rollback or a codec of the recurrent state, and none is built."""
+    """Engine features that assume "a sequence's state is its keys and values by head, in the one
+    step program", each refused by its name at construction: reusing, verifying, moving or
+    re-laying-out a sequence would need a snapshot, a rollback or a codec of what the description
+    keeps (``_kept``), and none is built."""
     why = {
         "kv_layout='paged'": kv_layout != "slots",
         "cache_dtype='int8'": cache_dtype is not None and str(cache_dtype).lower() in ("int8", "i8"),
@@ -62,45 +74,48 @@ def refuse(config, *, kv_layout, cache_dtype, mesh, speculative, kv_plane) -> No
         if asked:
             raise HybridModelUnsupportedError(
                 f"{what} is not built for a hybrid model ({type(config).__name__}: {config.kinds_held}): "
-                "its recurrent layers keep a state per sequence "
-                "that this feature would have to snapshot, roll back, shard or ship, and only keys and values can be")
+                f"{_kept(config)}, "
+                "which this feature would have to snapshot, roll back, shard or ship, and only keys and values by head can be")
 
 
-def refuser(what: str):
+def refuser(what: str, config):
     """What an engine of a hybrid model answers where a sequence would be moved as keys and
     values alone (``engine.KV_ONLY_METHODS``)."""
     def refused(*args, **kwargs):
         raise HybridModelUnsupportedError(
-            f"{what} is not built for a hybrid model: its recurrent layers' state is in no handoff, "
-            "migration or KV-plane format, and keys and values alone do not resume the sequence")
+            f"{what} is not built for a hybrid model: {_kept(config)}, which no handoff, migration or "
+            "KV-plane format carries, and keys and values by head alone do not resume the sequence")
     return refused
 
 
 def prefill(params, tokens, length, cfg, mesh=None):
     """tokens [B, T_pad] right-padded, length [B] -> (last-token logits [B, vocab] f32,
-    k, v [La, B, T_pad, kv, hd], state {name: [Lm, B, ...]} at each prompt's true length, with
-    PREFILL_STATS as float32 [3] beside the state under ``ROUTING`` where the model routes)."""
+    rows {name: [layers that keep it, B, T_pad, *shape]}: the per-position entries (``k`` and ``v``
+    [La, B, T_pad, kv, hd] of an attention layer with heads), state {name: [Lm, B, ...]} at each
+    prompt's true length, with PREFILL_STATS as float32 [3] beside the state under ``ROUTING``
+    where the model routes)."""
     x, out = hybrid.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
     x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
     logits = jnp.dot(x_last, params["unembed"], preferred_element_type=jnp.float32)
     if ROUTING in out:
         out[ROUTING] = jnp.mean(out[ROUTING], axis=0)
-    return logits, out.pop("k"), out.pop("v"), out
+    return logits, {name: out.pop(name) for name in cfg.position_entries()}, out
 
 
 def decode_step(params, cache, state, tokens, active, cfg):
-    """Advance every slot one token. cache: the slot KV rows of the layers that keep keys and
-    values; state: the state cache of the layers that keep something per sequence; active [B]
+    """Advance every slot one token. cache: the slot rows of the layers that keep something per
+    position; state: the state cache of the layers that keep something per sequence; active [B]
     bool: lanes bound to a live sequence (the others compute garbage nobody reads, and are kept
     out of the routing counters).
     -> (logits [B, vocab] f32, cache, state, MOE_STATS as float32 [4])."""
     B = tokens.shape[0]
     lengths = cache["length"]
-    pos = jnp.minimum(lengths, cache["k"].shape[2] - 1)
+    per_position = frozenset(cfg.position_entries())
+    horizon = cache[next(iter(per_position))].shape[2]  # every entry is [layers, slots, positions, ...]
+    pos = jnp.minimum(lengths, horizon - 1)
     lanes = jnp.arange(B, dtype=jnp.int32)
     dt, sd = params["embed"].dtype, cfg.stream_dtype
     x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
-    per_position = frozenset(name for spec in cfg.cache_spec().values() for name, (_, _, per) in spec.items() if per == "position")
     ctx = hybrid.StepCtx(lengths, active)
 
     def layer(kind, w, i, x, carry):
@@ -140,7 +155,7 @@ def make_hybrid_fns(cfg, device_resident: bool):
     from ray_tpu.llm import kv_cache as kvc
 
     prefill_fn = named_jit("llm_hybrid_prefill", partial(prefill, cfg=cfg))
-    insert_fn = named_jit("llm_kv_insert", kvc.insert_sequence, donate_argnums=(0,))
+    insert_fn = named_jit("llm_kv_insert", kvc.insert_entries, donate_argnums=(0,))
     state_insert_fn = named_jit("llm_state_insert", state_cache.insert_state, donate_argnums=(0,))
     if device_resident:
         step_fn = named_jit("llm_hybrid_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
@@ -156,8 +171,10 @@ _trace_cfg = hybrid.trace_description
 
 
 def _sds_caches(cfg, B: int, S: int):
-    kv = _sds((cfg.num_kv_layers, B, S, cfg.num_kv_heads, cfg.hd), jnp.dtype(cfg.dtype))
-    return {"k": kv, "v": kv, "length": _sds((B,), jnp.int32)}, jax.eval_shape(lambda: state_cache.alloc(cfg, B))
+    from ray_tpu.llm import kv_cache as kvc
+
+    return (jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), B, S)),
+            jax.eval_shape(lambda: state_cache.alloc(cfg, B)))
 
 
 def _bucket_prefill(B=4, T=128):

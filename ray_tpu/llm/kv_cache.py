@@ -6,6 +6,12 @@ serving lifetime. The cache is a pytree of stacked per-layer arrays
     k, v: [L, slots, max_seq_len, kv_heads, head_dim]
     length: [slots] int32   (tokens currently valid per slot; 0 = empty)
 
+``k`` and ``v`` are the per-position ENTRIES of a model whose attention keeps keys and values by
+head. A hybrid description names its own (``HybridDescription.position_entries()``: ``k`` and ``v``
+again, or a latent layer's ``c_kv`` [r] and ``k_r`` [rope]); the cache is then one stacked array
+``[layers that keep it, slots, max_seq_len, *shape]`` for each, allocated, inserted into and counted
+by the same functions (``alloc_entries``, ``insert_entries``, ``entry_bytes_per_token``).
+
 A "slot" is one concurrent sequence. Admission = prefill writes a new
 sequence's K/V into a free slot at offset 0; decode appends one token per
 active slot per step via per-slot dynamic_update_slice. This is the
@@ -36,6 +42,7 @@ last, so scale tiles waste nothing — see kv_quant.py).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import jax
@@ -54,6 +61,30 @@ class CacheConfig:
     dtype: str = "bfloat16"  # bf16/f32 variants, or "int8" (kv_quant.py)
 
 
+def alloc_entries(entries: dict, num_slots: int, max_seq_len: int) -> dict:
+    """The slot cache of ``entries``: name -> (layers, shape of one position, dtype)."""
+    cache = {name: jnp.zeros((layers, num_slots, max_seq_len) + tuple(shape), jnp.dtype(dtype))
+             for name, (layers, shape, dtype) in entries.items()}
+    return {**cache, "length": jnp.zeros((num_slots,), dtype=jnp.int32)}
+
+
+def entry_bytes_per_token(entries: dict) -> int:
+    """Bytes one position of one sequence takes in the cache of ``entries``, over all its layers."""
+    return sum(layers * math.prod(shape) * jnp.dtype(dtype).itemsize for layers, shape, dtype in entries.values())
+
+
+def insert_entries(cache: dict, slot, new: dict, length) -> dict:
+    """Write a prefilled sequence's entries into ``slot`` at offset 0. ``new[name]``: [layers,
+    T_pad, *shape] (the padded tail is garbage and stays masked by ``length``). slot/length:
+    traced scalars, so one compiled program serves every slot and every prefill bucket."""
+    zero = jnp.zeros((), dtype=jnp.int32)
+    out = {}
+    for name, arr in new.items():
+        start = (zero, jnp.asarray(slot, jnp.int32)) + (zero,) * (arr.ndim - 1)
+        out[name] = jax.lax.dynamic_update_slice(cache[name], arr[:, None].astype(cache[name].dtype), start)
+    return {**out, "length": cache["length"].at[slot].set(jnp.asarray(length, jnp.int32))}
+
+
 def alloc(cfg: CacheConfig) -> dict:
     shape = (cfg.num_layers, cfg.num_slots, cfg.max_seq_len, cfg.num_kv_heads, cfg.head_dim)
     if is_int8(cfg.dtype):
@@ -67,12 +98,8 @@ def alloc(cfg: CacheConfig) -> dict:
             "v_scale": jnp.zeros(sshape, dtype=jnp.float32),
             "length": jnp.zeros((cfg.num_slots,), dtype=jnp.int32),
         }
-    dt = jnp.dtype(cfg.dtype)
-    return {
-        "k": jnp.zeros(shape, dtype=dt),
-        "v": jnp.zeros(shape, dtype=dt),
-        "length": jnp.zeros((cfg.num_slots,), dtype=jnp.int32),
-    }
+    one = (cfg.num_layers, (cfg.num_kv_heads, cfg.head_dim), cfg.dtype)
+    return alloc_entries({"k": one, "v": one}, cfg.num_slots, cfg.max_seq_len)
 
 
 def insert_sequence(cache: dict, slot, k_new, v_new, length, k_scale=None, v_scale=None):
@@ -95,20 +122,19 @@ def insert_sequence(cache: dict, slot, k_new, v_new, length, k_scale=None, v_sca
         k_new = dequantize(k_new, k_scale.transpose(0, 2, 1))
         v_new = dequantize(v_new, v_scale.transpose(0, 2, 1))
         k_scale = v_scale = None
-    if quant:
-        if k_scale is None:  # fp block -> quantize on insert
-            k_new, sk = quantize_heads(k_new)  # sk: [L, T, kv]
-            v_new, sv = quantize_heads(v_new)
-            k_scale, v_scale = sk.transpose(0, 2, 1), sv.transpose(0, 2, 1)
-        s_start = (zero, jnp.asarray(slot, jnp.int32), zero, zero)
-        k_sc = jax.lax.dynamic_update_slice(cache["k_scale"], k_scale[:, None].astype(jnp.float32), s_start)
-        v_sc = jax.lax.dynamic_update_slice(cache["v_scale"], v_scale[:, None].astype(jnp.float32), s_start)
+    if not quant:
+        return insert_entries(cache, slot, {"k": k_new, "v": v_new}, length)
+    if k_scale is None:  # fp block -> quantize on insert
+        k_new, sk = quantize_heads(k_new)  # sk: [L, T, kv]
+        v_new, sv = quantize_heads(v_new)
+        k_scale, v_scale = sk.transpose(0, 2, 1), sv.transpose(0, 2, 1)
+    s_start = (zero, jnp.asarray(slot, jnp.int32), zero, zero)
+    k_sc = jax.lax.dynamic_update_slice(cache["k_scale"], k_scale[:, None].astype(jnp.float32), s_start)
+    v_sc = jax.lax.dynamic_update_slice(cache["v_scale"], v_scale[:, None].astype(jnp.float32), s_start)
     k = jax.lax.dynamic_update_slice(cache["k"], k_new[:, None].astype(cache["k"].dtype), start)
     v = jax.lax.dynamic_update_slice(cache["v"], v_new[:, None].astype(cache["v"].dtype), start)
     lens = cache["length"].at[slot].set(jnp.asarray(length, jnp.int32))
-    if quant:
-        return {"k": k, "v": v, "k_scale": k_sc, "v_scale": v_sc, "length": lens}
-    return {"k": k, "v": v, "length": lens}
+    return {"k": k, "v": v, "k_scale": k_sc, "v_scale": v_sc, "length": lens}
 
 
 def extract_sequence(cache: dict, slot, T: int):

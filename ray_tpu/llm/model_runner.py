@@ -77,6 +77,26 @@ def named_jit(name: str, fn, **jit_kwargs):
     return jax.jit(program, **jit_kwargs)
 
 
+def program_bytes(fn, *args) -> int:
+    """Bytes the jitted program ``fn`` takes for these arguments beside them, by the compiler's own
+    account: its temporaries and what it hands back. After a call with the same arguments the
+    executable is the one that ran and nothing compiles."""
+    account = fn.lower(*args).compile().memory_analysis()
+    return int(account.temp_size_in_bytes + account.output_size_in_bytes - account.alias_size_in_bytes)
+
+
+def device_free_bytes(array) -> int | None:
+    """Bytes free on the devices that hold ``array``, the least of them; None where the backend
+    keeps no account of its memory (the CPU's)."""
+    free = []
+    for d in array.devices():
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free.append(int(stats["bytes_limit"]) - int(stats["bytes_in_use"]))
+    return min(free)
+
+
 # ---------------------------------------------------------------------------
 # Tensor parallelism over the ICI mesh: the fused decode hot path is
 # re-expressed under shard_map so the per-layer TP all-reduce is an

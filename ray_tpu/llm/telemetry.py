@@ -540,10 +540,7 @@ class EngineTelemetry:
         self._b_preempt.inc(0.0)
         # per-step constants, computed once (the on_step path must stay
         # in the tens-of-microseconds)
-        from ray_tpu.llm.kv_quant import bytes_per_token
-
-        cfg = engine.config
-        self._bytes_per_token = int(bytes_per_token(engine._kv_layers, cfg.num_kv_heads, cfg.hd, engine.kv_dtype))
+        self._bytes_per_token = engine.kv_bytes_per_token()
         if engine.kv_layout == "paged":
             self._capacity_tokens = (engine._pcfg.num_pages - 1) * engine._pcfg.page_size
         else:
@@ -956,6 +953,9 @@ class EngineTelemetry:
                 "kv_dtype": str(eng.kv_dtype),
                 "max_num_seqs": eng.max_num_seqs,
                 "device_resident": eng._device_resident,
+                # what the cache holds for a position, and of which entries it is made
+                "kv_bytes_per_token": eng.kv_bytes_per_token(),
+                "kv_entries": eng.kv_entries(),
             }
             if error is not None:
                 header["error"] = f"{type(error).__name__}: {error}"

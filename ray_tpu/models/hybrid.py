@@ -10,8 +10,9 @@ file states WHAT its layers are; this file walks them. A description
   two forms (``seq`` over a padded sequence with true lengths; ``step`` for one
   token a lane against cached state), and whether it routes tokens to experts;
 - ``cache_spec()``: kind -> {name: (shape, dtype, "position" | "sequence")}: what
-  ONE layer of that kind keeps, per position of a sequence (the slot KV rows,
-  named ``k`` and ``v``) or once per sequence (the state cache);
+  ONE layer of that kind keeps, per position of a sequence (the slot cache's
+  rows: ``k`` and ``v`` of an attention layer with heads, ``c_kv`` and ``k_r`` of
+  a latent one) or once per sequence (the state cache);
 - ``norm(x, w)``: the pre-norm of every sub-block and the final norm;
 - ``stream_dtype``, ``init_params``, ``num_params()``.
 
@@ -26,8 +27,8 @@ Two loops over one description:
   depth; what the layers keep for the cache leaves the loop with one row for each
   layer that keeps it.
 - ``run_layers`` for a decode step: a scan over the repeated period of the pattern
-  (``layer_plan``), with the caches in its carry, updated in place through a
-  ``LayerCache``. An attention layer's keys and values are read from the stacked rows
+  (``layer_plan``; what stands before and after it is unrolled), with the caches in
+  its carry, updated in place through a ``LayerCache``. An attention layer's keys and values are read from the stacked rows
   where they lie (``attend_slot`` -> ``ops/slot_attention.attend``, the op the Llama
   decode step calls too): a lane's live blocks on a TPU, the layer's rows sliced out
   and masked elsewhere.
@@ -73,6 +74,17 @@ class StepCtx(NamedTuple):
     active: Any  # [B] bool: lanes bound to a live sequence
 
 
+class LayerPlan(NamedTuple):
+    """How ``run_layers`` walks a pattern: ``head``, then ``period`` x ``repeats``, then ``tail``.
+    The head stands last among the fields: readers of a plan from before it had one index the
+    first three."""
+
+    period: tuple
+    repeats: int
+    tail: tuple
+    head: tuple = ()
+
+
 class HybridDescription:
     """What the loops, the engine and the cache manager read off a model's config. The config
     dataclass that mixes this in provides ``layer_kinds``, ``mixers``, ``cache_spec()``,
@@ -92,9 +104,23 @@ class HybridDescription:
             kinds = {k for k, m in self.mixers.items() if m.routes}
         return tuple(n for n, k in enumerate(self.layer_kinds) if k in kinds)
 
+    def position_entries(self) -> dict:
+        """name -> (layers that keep it, shape, dtype) of every per-position entry of the cache:
+        what the slot cache (``llm/kv_cache.py``) is allocated from."""
+        return {name: (self.count(kind), tuple(shape), dtype)
+                for kind, spec in self.cache_spec().items()
+                for name, (shape, dtype, per) in spec.items() if per == "position"}
+
     @property
     def num_kv_layers(self) -> int:
-        return len(self.keeping("k"))
+        """Layers that keep something per position (keys and values, or a latent)."""
+        return max([layers for layers, _, _ in self.position_entries().values()], default=0)
+
+    @property
+    def slot_attention_tile(self) -> dict:
+        """What ``ops/slot_attention.refusal`` is asked about the decode step's attention: here
+        the tile of a layer that keeps ``k`` and ``v`` by head."""
+        return dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.hd)
 
     @property
     def routing_layers(self) -> int:
@@ -106,18 +132,21 @@ class HybridDescription:
         return ", ".join(f"{self.count(k)} x {k}" for k in dict.fromkeys(self.layer_kinds))
 
     @property
-    def layer_plan(self) -> tuple:
-        """(period, repeats, tail): the longest prefix of the pattern that is a block repeated
-        at least twice, and the kinds that follow it. ``run_layers`` scans over the repeats."""
-        kinds, best = tuple(self.layer_kinds), ((), 0)
-        for p in range(1, len(kinds) // 2 + 1):
-            r = 1
-            while kinds[r * p:(r + 1) * p] == kinds[:p]:
-                r += 1
-            if r >= 2 and r * p > best[1] * len(best[0]):
-                best = (kinds[:p], r)
-        period, r = best
-        return period, r, kinds[r * len(period):]
+    def layer_plan(self) -> LayerPlan:
+        """The stretch of the pattern that is a block repeated at least twice and covers the most
+        layers, with the kinds before it (``head``: a leading dense layer) and after it (``tail``).
+        ``run_layers`` scans over the repeats and unrolls the rest. Of two stretches that cover
+        as many layers, the one that starts earlier, then the shorter period."""
+        kinds, best = tuple(self.layer_kinds), LayerPlan((), 0, ())
+        for h in range(len(kinds)):
+            rest = kinds[h:]
+            for p in range(1, len(rest) // 2 + 1):
+                r = 1
+                while rest[r * p:(r + 1) * p] == rest[:p]:
+                    r += 1
+                if r >= 2 and r * p > best.repeats * len(best.period):
+                    best = LayerPlan(rest[:p], r, rest[r * p:], kinds[:h])
+        return best if best.repeats else LayerPlan((), 0, kinds)
 
 
 # --------------------------------------------------------------- the layer loops
@@ -129,32 +158,37 @@ def run_layers(config, params, x, carry, layer_fn):
     """Walk the layer pattern with LARGE state in the carry (a decode step's caches, updated in
     place): ``layer_fn(kind, w, i, x, carry) -> (x, carry)`` with ``w`` one layer's weights and
     ``i`` its index among the layers of its kind (traced inside the scan over the repeated
-    period, a plain int in the tail). The program holds one body per layer of the period and of
-    the tail. Why not one body per kind (``scan_layers``): a conditional's branch hands back
-    every carried array, and the chip's compiler copies the ones a branch did not touch, 8 GB a
-    step for 0.75 GB of caches (compiled for a described v5e, PR 29)."""
-    period, repeats, tail = config.layer_plan
-    per = Counter(period)
+    period, a plain int in the head and the tail). The program holds one body per layer of the
+    head, of the period and of the tail. Why not one body per kind (``scan_layers``): a
+    conditional's branch hands back every carried array, and the chip's compiler copies the ones
+    a branch did not touch, 8 GB a step for 0.75 GB of caches (compiled for a described v5e, PR 29)."""
+    period, repeats, tail, head = config.layer_plan
+    per, seen = Counter(period), Counter()
 
     def apply(kind, i, x, carry):
         with jax.named_scope(config.mixers[kind].scope):
             return layer_fn(kind, _layer_weights(params, kind, i), i, x, carry)
 
+    def unrolled(kinds, x, carry):
+        for kind in kinds:
+            x, carry = apply(kind, seen[kind], x, carry)
+            seen[kind] += 1
+        return x, carry
+
     def block(xc, r):
         x, carry = xc
-        seen = Counter()
+        at = Counter(first)
         for kind in period:
-            x, carry = apply(kind, r * per[kind] + seen[kind], x, carry)
-            seen[kind] += 1
+            x, carry = apply(kind, r * per[kind] + at[kind], x, carry)
+            at[kind] += 1
         return (x, carry), None
 
+    x, carry = unrolled(head, x, carry)
+    first = dict(seen)  # the layers of each kind that stand before the period
     if repeats:
         (x, carry), _ = jax.lax.scan(block, (x, carry), jnp.arange(repeats, dtype=jnp.int32))
-    seen = Counter({k: repeats * n for k, n in per.items()})
-    for kind in tail:
-        x, carry = apply(kind, seen[kind], x, carry)
-        seen[kind] += 1
-    return x, carry
+    seen.update({k: repeats * n for k, n in per.items()})
+    return unrolled(tail, x, carry)
 
 
 def scan_layers(config, params, x, layer_fn, empty):

@@ -26,6 +26,15 @@ with the usual running max and sum. The probabilities stay float32: they are spl
 bfloat16 terms (8 + 8 + 8 mantissa bits) that multiply the bfloat16 values exactly, so the second
 product accumulates what a float32 x float32 one would.
 
+A LATENT layer (``attend_latent``; ``models/glm4_moe_lite.py``) keeps no heads: a position is one
+latent row ``c_kv`` [r] and one rotated key ``k_r`` [rope, in whole 128-lane tiles: the model file
+says why], two stacked arrays ``[L, slots, S, r]`` and ``[L, slots, S, rope]``, and every query head
+reads the SAME row, as key (all r + rope columns) and, in ``c_kv``, as value. Its kernel
+(``latent_decode_attention`` in a trace) is the same grid over (lane, block), the same bounds and
+index tables in SMEM and the same fold; a block of ``c_kv`` is streamed once and used twice, scores
+are ``q_lat . c_kv + q_rope . k_r`` (two MXU products), and the query heads are padded to whole
+bfloat16 tiles of 16 rows. Its XLA form is ``attend_rows`` with keys wider than values.
+
 Which form runs is decided by what the code can see (``refusal``): the backend, the cache's dtype,
 whether the caller is a ``shard_map`` body, and the tile's shape. There is no knob.
 """
@@ -48,17 +57,19 @@ _BLOCK_BYTES = 1 << 20
 
 
 # --------------------------------------------------------------------------- the XLA form
-def attend_rows(q, k_rows, v_rows, lengths, num_kv_heads: int):
-    """One token a lane (its query q [B,nh,hd]) against a layer's rows k/v_rows [B,S,kv,hd], in
-    which the new token's key and value already sit at index lengths[b]: grouped-query softmax
-    attention over the positions held. -> [B, nh*hd] float32."""
+def attend_rows(q, k_rows, v_rows, lengths, num_kv_heads: int, scale: float | None = None):
+    """One token a lane (its query q [B,nh,hd]) against a layer's rows k_rows [B,S,kv,hd] and
+    v_rows [B,S,kv,vd] (vd = hd, but for a latent layer), in which the new token's key and value
+    already sit at index lengths[b]: grouped-query softmax attention over the positions held,
+    scores over sqrt(hd) unless ``scale`` says otherwise. -> [B, nh*vd] float32."""
     B, S = k_rows.shape[:2]
     nh, hd = q.shape[1:]
     qg = q.reshape(B, num_kv_heads, nh // num_kv_heads, hd)
-    scores = jnp.einsum("bgrh,bsgh->bgrs", qg, k_rows, preferred_element_type=jnp.float32) / math.sqrt(hd)
+    scores = jnp.einsum("bgrh,bsgh->bgrs", qg, k_rows, preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]
     probs = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bgrs,bsgh->bgrh", probs, v_rows.astype(jnp.float32)).reshape(B, nh * hd)
+    return jnp.einsum("bgrs,bsgh->bgrh", probs, v_rows.astype(jnp.float32)).reshape(B, nh * v_rows.shape[-1])
 
 
 def layer_of(stacked, i):
@@ -76,11 +87,13 @@ def block_positions(S: int, num_kv_heads: int, head_dim: int, itemsize: int, blo
 
 
 def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: int, *,
-            quantized: bool = False, sharded: bool = False) -> str | None:
+            quantized: bool = False, sharded: bool = False, value_dim: int | None = None) -> str | None:
     """Why the kernel does NOT serve this call (the XLA form then does), or None. Off the TPU the
     answer is always a reason: tier-1 runs on the CPU and the Pallas interpreter under every engine
     test would cost the suite minutes; tests run the interpreter by asking for it. On the TPU the
-    shapes let through are the ones compiled for a v5e in ``tests/test_chip_compile.py``."""
+    shapes let through are the ones compiled for a v5e in ``tests/test_chip_compile.py``.
+    ``value_dim`` narrower than ``head_dim``: a latent layer's tile, one row a position whose first
+    ``value_dim`` columns (``c_kv``) are also the value and whose rest (``k_r``) is key only."""
     if jax.default_backend() != "tpu":
         return f"backend {jax.default_backend()!r}: the kernel is compiled for the TPU only"
     if sharded:
@@ -90,6 +103,16 @@ def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: in
     dt = jnp.dtype(cache_dtype)
     if dt != jnp.bfloat16:
         return f"a {dt.name} cache: the kernel has been compiled for bfloat16 rows only"
+    if value_dim is not None and value_dim != head_dim:
+        rope = head_dim - value_dim
+        if num_kv_heads != 1 or value_dim % 128 or value_dim > 512 or rope != 128:
+            return (f"a latent row of {value_dim} + {rope} on {num_kv_heads} kv head(s): compiled at 512 + 128 on one (whole "
+                    "128-lane tiles: a narrower rotated key reaches the kernel only through a copy of its whole stack)")
+        if num_heads > 32:
+            return f"{num_heads} query heads: compiled at 20 (padded to at most two bfloat16 tiles of 16 rows)"
+        if block_positions(S, 1, value_dim, dt.itemsize) < 512:
+            return f"{S} positions a slot: no block of at least 512 positions divides it"
+        return None
     if head_dim % 128 or head_dim > 256:
         return f"head_dim {head_dim}: compiled at 128 and 256 (a multiple of the 128 lanes)"
     if num_kv_heads & (num_kv_heads - 1) or num_kv_heads > 8:
@@ -107,6 +130,46 @@ def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: in
 
 
 # --------------------------------------------------------------------------- the kernel
+def _start(j, m_scr, l_scr, o_ref):
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _fold_block(s, ok, v, m_scr, l_scr, o_ref):
+    """Fold one block's scores s [nh, cols] (float32; ``ok``: the columns a row may read) and
+    values v [cols, vd] into the lane's running max (m), sum (l) and weighted values (o_ref)."""
+    nh = s.shape[0]
+    s = jnp.where(ok, s, _NEG)
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))  # every row has position j*blk: real
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)  # masked columns: exp(_NEG - m) == 0
+    l_scr[...] = jnp.broadcast_to(l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_scr.shape)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    if v.dtype == jnp.bfloat16:
+        # float32 probabilities as three bfloat16 terms, stacked so that V is loaded once
+        hi = p.astype(jnp.bfloat16)
+        r1 = p - hi.astype(jnp.float32)
+        mid = r1.astype(jnp.bfloat16)
+        lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        pv = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), v, preferred_element_type=jnp.float32)
+        pv = (pv[:nh] + pv[nh:2 * nh]) + pv[2 * nh:]
+    else:
+        pv = jnp.dot(p, v.astype(jnp.float32), preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
+    o_ref[...] = o_ref[...] * alpha + pv
+
+
+def _finish(j, l_scr, o_ref):
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _normalise():
+        l = l_scr[:, :1]
+        o_ref[...] = o_ref[...] / jnp.where(l > 0.0, l, 1.0)  # a lane with no position folded nothing: zeros
+
+
 def _kernel(layer_ref, bound_ref, src_ref, last_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, *,
             blk: int, kv: int, rep: int, scale: float):
     """Grid step (lane b, block j): fold the block's positions below ``bound[b]`` into the lane's
@@ -114,12 +177,7 @@ def _kernel(layer_ref, bound_ref, src_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
     del layer_ref, src_ref, last_ref  # the index maps' business
     b, j = pl.program_id(0), pl.program_id(1)
     bound = bound_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        o_ref[...] = jnp.zeros_like(o_ref)
+    _start(j, m_scr, l_scr, o_ref)
 
     @pl.when(j * blk < bound)  # a block with no live position: not fetched (the index map), not computed
     def _fold():
@@ -130,39 +188,39 @@ def _kernel(layer_ref, bound_ref, src_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 1)
         # column c is kv head c % kv of position c // kv; query head h reads kv head h // rep
         ok = (col % kv == row // rep) & (j * blk + col // kv < bound)
-        s = jnp.where(ok, s, _NEG)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))  # every row has position j*blk: real
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # masked columns: exp(_NEG - m) == 0
-        l_scr[...] = jnp.broadcast_to(l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_scr.shape)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        if v.dtype == jnp.bfloat16:
-            # float32 probabilities as three bfloat16 terms, stacked so that V is loaded once
-            hi = p.astype(jnp.bfloat16)
-            r1 = p - hi.astype(jnp.float32)
-            mid = r1.astype(jnp.bfloat16)
-            lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-            pv = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), v, preferred_element_type=jnp.float32)
-            pv = (pv[:nh] + pv[nh:2 * nh]) + pv[2 * nh:]
-        else:
-            pv = jnp.dot(p, v.astype(jnp.float32), preferred_element_type=jnp.float32,
-                         precision=jax.lax.Precision.HIGHEST)
-        o_ref[...] = o_ref[...] * alpha + pv
+        _fold_block(s, ok, v, m_scr, l_scr, o_ref)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _normalise():
-        l = l_scr[:, :1]
-        o_ref[...] = o_ref[...] / jnp.where(l > 0.0, l, 1.0)  # a lane with no position folded nothing: zeros
+    _finish(j, l_scr, o_ref)
 
 
-def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None, interpret: bool = False):
-    """The kernel form. q [B,nh,hd]; k/v_stack [L,B,S,kv,hd]; layer: int32 scalar (traced or not);
-    bound [B] int32: lane b attends positions 0 .. bound[b]-1 of layer ``layer`` (0: the lane is
-    bound to no sequence, reads nothing and gets zeros). -> [B, nh*hd] float32."""
-    B, nh, hd = q.shape
-    L, _, S, kv, _ = k_stack.shape
-    blk = block or block_positions(S, kv, hd, k_stack.dtype.itemsize)
+def _latent_kernel(layer_ref, bound_ref, src_ref, last_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref, m_scr, l_scr, *,
+                   blk: int, scale: float):
+    """``_kernel`` for a latent layer: the block of latent rows c [blk, r] is the key, with the
+    rotated keys r [blk, rope] beside it, AND the value; every (padded) query head reads every row."""
+    del layer_ref, src_ref, last_ref
+    b, j = pl.program_id(0), pl.program_id(1)
+    bound = bound_ref[b]
+    _start(j, m_scr, l_scr, o_ref)
+
+    @pl.when(j * blk < bound)
+    def _fold():
+        c = c_ref[...]
+        nt = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(ql_ref[...], c, nt, preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[...], r_ref[...], nt, preferred_element_type=jnp.float32)) * scale
+        ok = j * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < bound
+        _fold_block(s, ok, c, m_scr, l_scr, o_ref)
+
+    _finish(j, l_scr, o_ref)
+
+
+def _launch(kernel, name: str, layer, bound, queries, stacks, blk_rows: int, blk: int, out_width: int, interpret: bool):
+    """The grid over (lane, block of ``blk`` positions) that both forms share. ``queries``: arrays
+    [B, nh, w], one tile a lane; ``stacks``: arrays [L, B, rows, w] read ``blk_rows`` rows at a time
+    from layer ``layer``; bound [B] int32: lane b attends positions 0 .. bound[b]-1 (0: the lane is
+    bound to no sequence, reads nothing and gets zeros). -> [B, nh, out_width] float32."""
+    B, nh = queries[0].shape[:2]
+    S = stacks[0].shape[2] * blk // blk_rows
     nblk = S // blk
     if S % blk:
         raise ValueError(f"a block of {blk} positions does not divide {S}")  # tpulint: disable=ERR002 — a programmer's error at trace time
@@ -179,28 +237,51 @@ def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None
         return layer_ref[0], src_ref[b], jnp.minimum(jnp.where(bound_ref[b] > 0, j, nblk), last_ref[b]), 0
 
     per_lane = lambda b, j, *_: (b, 0, 0)  # noqa: E731
-    out = pl.pallas_call(
-        functools.partial(_kernel, blk=blk, kv=kv, rep=nh // kv, scale=1.0 / math.sqrt(hd)),
+    return pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B, nblk),
-            in_specs=[
-                pl.BlockSpec((None, nh, hd), per_lane),
-                pl.BlockSpec((None, None, blk * kv, hd), rows),
-                pl.BlockSpec((None, None, blk * kv, hd), rows),
-            ],
-            out_specs=pl.BlockSpec((None, nh, hd), per_lane),
+            in_specs=[pl.BlockSpec((None, nh, q.shape[2]), per_lane) for q in queries]
+            + [pl.BlockSpec((None, None, blk_rows, a.shape[3]), rows) for a in stacks],
+            out_specs=pl.BlockSpec((None, nh, out_width), per_lane),
             scratch_shapes=[pltpu.VMEM((nh, 128), jnp.float32), pltpu.VMEM((nh, 128), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, nh, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, nh, out_width), jnp.float32),
         interpret=interpret,
-        name="slot_decode_attention",
+        name=name,
         # an empty lane's steps lean on the step before them: the grid runs in order
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=48 << 20)}),
-    )(jnp.asarray(layer, jnp.int32).reshape(1), bound, src, last,
-      q, k_stack.reshape(L, B, S * kv, hd), v_stack.reshape(L, B, S * kv, hd))
+    )(jnp.asarray(layer, jnp.int32).reshape(1), bound, src, last, *queries, *stacks)
+
+
+def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None, interpret: bool = False):
+    """The kernel form. q [B,nh,hd]; k/v_stack [L,B,S,kv,hd]; layer: int32 scalar (traced or not);
+    bound [B] int32: lane b attends positions 0 .. bound[b]-1 of layer ``layer`` (0: the lane is
+    bound to no sequence, reads nothing and gets zeros). -> [B, nh*hd] float32."""
+    B, nh, hd = q.shape
+    L, _, S, kv, _ = k_stack.shape
+    blk = block or block_positions(S, kv, hd, k_stack.dtype.itemsize)
+    kernel = functools.partial(_kernel, blk=blk, kv=kv, rep=nh // kv, scale=1.0 / math.sqrt(hd))
+    out = _launch(kernel, "slot_decode_attention", layer, bound, [q],
+                  [k_stack.reshape(L, B, S * kv, hd), v_stack.reshape(L, B, S * kv, hd)], blk * kv, blk, hd, interpret)
     return out.reshape(B, nh * hd)
+
+
+def attend_latent_kernel(q_lat, q_rope, c_stack, r_stack, layer, bound, scale: float, *, block: int | None = None,
+                         interpret: bool = False):
+    """The latent kernel form. q_lat [B,nh,r], q_rope [B,nh,rope]; c_stack [L,B,S,r], r_stack
+    [L,B,S,rope]; bound as in ``attend_kernel``. -> [B, nh*r] float32: each head's attention-
+    weighted mean of the latent rows, for the value projection that follows."""
+    B, nh, r = q_lat.shape
+    S = c_stack.shape[2]
+    blk = block or block_positions(S, 1, r, c_stack.dtype.itemsize)
+    pad = ((0, 0), (0, -nh % 16), (0, 0))  # whole bfloat16 tiles of query rows; a padded row reads what every row reads and is cut off
+    q_lat, q_rope = jnp.pad(q_lat.astype(c_stack.dtype), pad), jnp.pad(q_rope.astype(c_stack.dtype), pad)
+    out = _launch(functools.partial(_latent_kernel, blk=blk, scale=scale), "latent_decode_attention", layer, bound,
+                  [q_lat, q_rope], [c_stack, r_stack], blk, blk, r, interpret)
+    return out[:, :nh].reshape(B, nh * r)
 
 
 # --------------------------------------------------------------------------- the op
@@ -224,3 +305,21 @@ def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None,
         k_rows = k_rows.astype(jnp.float32) * layer_of(k_scale, layer).transpose(0, 2, 1)[..., None]
         v_rows = v_rows.astype(jnp.float32) * layer_of(v_scale, layer).transpose(0, 2, 1)[..., None]
     return attend_rows(q, k_rows, v_rows, lengths, num_kv_heads)
+
+
+def attend_latent(q_lat, q_rope, c_stack, r_stack, layer, lengths, *, scale: float, live=None, sharded: bool = False):
+    """``attend`` for a latent layer: one token a lane, its absorbed queries q_lat [B,nh,r] and
+    rotated q_rope [B,nh,<=rope], against layer ``layer`` of the stacked latent rows c_stack
+    [L,B,S,r] and rotated keys r_stack [L,B,S,rope] (zeros after the key's own columns), in which
+    the new token's already sit at index lengths[b]: ``softmax((q_lat . c_kv + q_rope . k_r) *
+    scale)`` over the positions held, times the latent rows themselves. -> [B, nh*r] float32."""
+    S, r = c_stack.shape[2], c_stack.shape[-1]
+    q_rope = jnp.pad(q_rope, ((0, 0), (0, 0), (0, r_stack.shape[-1] - q_rope.shape[-1])))
+    why = refusal(c_stack.dtype, q_lat.shape[1], 1, r + r_stack.shape[-1], S, sharded=sharded, value_dim=r)
+    if why is None:
+        bound = jnp.minimum(lengths, S - 1) + 1
+        return attend_latent_kernel(q_lat, q_rope, c_stack, r_stack, layer, bound if live is None else jnp.where(live, bound, 0),
+                                    scale, interpret=jax.default_backend() != "tpu")
+    c_rows, r_rows = layer_of(c_stack, layer), layer_of(r_stack, layer)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(c_rows.dtype)
+    return attend_rows(q, jnp.concatenate([c_rows, r_rows], axis=-1)[:, :, None], c_rows[:, :, None], lengths, 1, scale)

@@ -510,3 +510,88 @@ def test_qwen3_next_fused_step_keeps_the_xla_form(one_chip, as_on_a_tpu):
     lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
     txt = jax.jit(partial(hr.fused_step, cfg=cfg)).lower(params, cache, state, *lanes, s((16,), jnp.bool_)).compile().as_text()
     assert "slot_decode_attention" not in txt
+
+
+# ---------------------------------------------------------------------------
+# PR 36: a third description, latent attention (models/glm4_moe_lite.py): the cell
+# glm-4.7-flash-d8.longdoc-16k. The slot cache holds a latent row and one rotated key a
+# position; the decode step attends on them where they lie (ops/slot_attention.attend_latent)
+# ---------------------------------------------------------------------------
+def _glm_at_the_benchmarks_size(one_chip):
+    """The configuration of the cell ``glm-4.7-flash-d8.longdoc-16k``: published widths, 8 of 47
+    layers whole, 16 slots x 16,384 (benchmark/configs/glm-4.7-flash-d8.json)."""
+    import json
+    import os
+
+    from benchmark import common
+    from ray_tpu.llm import kv_cache as kvc
+    from ray_tpu.llm import state_cache
+
+    with open(os.path.join(common.HERE, "configs", "glm-4.7-flash-d8.json")) as f:
+        c = json.load(f)
+    slots, S = c["serving"]["max_num_seqs"], c["serving"]["max_seq_len"]
+    assert (slots, S) == (16, 16384)
+    cfg = common.load_family(c["family"]).program_config(c, S, attention_impl="pallas", remat=False)
+    params = _on(jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))), one_chip)
+    cache = _on(jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), slots, S)), one_chip)
+    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, slots)), one_chip)
+
+
+def test_latent_attention_kernel_compiles_for_v5e_and_copies_nothing(one_chip, as_on_a_tpu):
+    """The latent tile passes the gate (and PR 35's tiles still do): a Mosaic kernel under its own
+    name, the latent rows and the rotated keys read where they lie, no temporary to speak of."""
+    from ray_tpu.ops import slot_attention as sa
+
+    assert sa.refusal(jnp.bfloat16, 20, 1, 640, 16384, value_dim=512) is None
+    assert sa.refusal(jnp.bfloat16, 16, 8, 128, 4096) is None and sa.refusal(jnp.bfloat16, 32, 2, 128, 4096) is None
+    assert "copy of the whole cache" in sa.refusal(jnp.bfloat16, 16, 2, 256, 4096)
+    assert "copy of its whole stack" in sa.refusal(jnp.bfloat16, 20, 1, 576, 16384, value_dim=512)
+    assert "query heads" in sa.refusal(jnp.bfloat16, 40, 1, 640, 16384, value_dim=512)
+    assert "int8" in sa.refusal(jnp.bfloat16, 20, 1, 640, 16384, value_dim=512, quantized=True)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    c_stack, r_stack = sds((8, 16, 16384, 512), jnp.bfloat16), sds((8, 16, 16384, 128), jnp.bfloat16)
+    compiled, txt = _compile(partial(sa.attend_latent_kernel, scale=1 / 16), sds((16, 20, 512), jnp.bfloat16), sds((16, 20, 128), jnp.bfloat16),
+                             c_stack, r_stack, sds((), jnp.int32), sds((16,), jnp.int32))
+    assert "tpu_custom_call" in txt and "latent_decode_attention" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_glm_fused_step_reads_the_latent_rows_where_they_lie(one_chip, as_on_a_tpu):
+    """The fused step at 16 x 16,384 through the SAME ``hybrid_runner.fused_step`` and layer loop as
+    the two hybrids (head ``mla ffn``, then ``mla moe`` x 7 scanned): the whole latent cache aliased
+    to the donated input, under 32 MiB of temporaries, and no slice of a layer's rows (256 MiB of
+    latents at 16 x 16,384) in the compiled text."""
+    import re
+
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, cache, state = _glm_at_the_benchmarks_size(one_chip)
+    assert state == {} and cfg.layer_plan == (("mla", "moe"), 7, (), ("mla", "ffn"))
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
+    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+    compiled = step.lower(params, cache, state, *lanes, s((16,), jnp.bool_)).compile()
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert "latent_decode_attention" in txt and not re.search(r"bf16\[1,16,16384,(512|128|640)\]", txt)
+    assert _kv_bytes(cache) == 16 * 16384 * 10240 and mem.alias_size_in_bytes >= _kv_bytes(cache)
+    assert mem.temp_size_in_bytes < 32 * 2**20
+    print("glm fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+
+
+@pytest.mark.parametrize("prompts", [1, 2])
+def test_glm_prefill_of_the_16384_bucket_fits_beside_weights_and_cache_on_one_v5e(one_chip, prompts):
+    """The 16,384-bucket prefill in the EXPANDED form (the flash kernel at 20 heads, keys and values
+    256 wide; the dense layer and the grouped matmul in slabs of 8,192 rows) for one prompt and for
+    the largest group the cell warms, beside 9.62 GiB of weights and the cache: under 15.75 GiB."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, cache, _ = _glm_at_the_benchmarks_size(one_chip)
+    tokens = jax.ShapeDtypeStruct((prompts, 16384), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("glm prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    assert "tpu_custom_call" in txt, "the flash kernel, 20 heads of 256"
+    cache_on_chip = _compile(lambda c: c, cache)[0].memory_analysis().argument_size_in_bytes
+    print("cache on chip:", cache_on_chip / 2**30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + cache_on_chip < 15.75 * 2**30

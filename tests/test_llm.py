@@ -134,6 +134,32 @@ def test_prefill_bucketing_no_recompile_per_length(params):
     assert o2.token_ids == full_forward_greedy(params, [1, 2, 3, 4, 5], 3)
 
 
+def test_a_prefill_wave_is_cut_to_what_the_device_has_free(params):
+    """An admission wave is as many prompts as there are free slots; ONE prefill program takes of
+    them what fits the device's free memory by the compiler's account of the shapes that have
+    run, for every model. The CPU keeps no account of its memory, so the test plays the device."""
+    prompts = [list(range(1, n + 1)) for n in (5, 9, 12, 7, 40)]  # four in the 16 bucket, one in the 64
+    sp = SamplingParams(max_tokens=3)
+    eng = LLMEngine(CFG, params, max_num_seqs=8, max_seq_len=96, prefill_buckets=(16, 64))
+    assert eng._prefill_room is None and eng._prefill_batch(16, 5) == 8, "no account: the wave whole, padded to a power of two"
+    want = [o.token_ids for o in eng.generate(prompts, sp)]
+    assert want == [full_forward_greedy(params, p, 3) for p in prompts] and eng._prefill_need == {}
+    eng._prefill_room = 1 << 60
+    assert eng._prefill_bytes(4, 16) == 0, "before any shape has run nothing is known, and nothing refused"
+    eng.generate(prompts[:2], sp)
+    need = eng._prefill_need[2, 16]
+    # a shape that has not run is reckoned by positions from the largest of its bucket that has, else of any
+    assert (eng._prefill_bytes(2, 16), eng._prefill_bytes(8, 16), eng._prefill_bytes(1, 64)) == (need, 4 * need, 2 * need)
+    eng._prefill_room = 2 * need
+    assert (eng._prefill_batch(16, 4), eng._prefill_batch(16, 3), eng._prefill_batch(16, 1), eng._prefill_batch(64, 2)) == (4, 4, 1, 1)
+    eng._prefill_room = need - 1
+    assert eng._prefill_batch(16, 8) == 1 and eng._prefill_batch(64, 1) == 1, "one prompt is never split"
+    runs, real = [], eng._admit_prefill_batch
+    eng._admit_prefill_batch = lambda group: (runs.append(len(group)), real(group))[1]
+    assert [o.token_ids for o in eng.generate(prompts, sp)] == want and runs == [1, 1, 1, 1, 1]
+    assert eng.kv_cache_stats()["prefill_program_bytes"] == {"1x16": eng._prefill_need[1, 16], "2x16": need, "1x64": eng._prefill_need[1, 64]}
+
+
 def test_serve_llm_deployment_batches_concurrent_requests(rt_start):
     """BASELINE config #4 shape: Serve replicas wrap the engine; concurrent
     requests interleave in one continuous batch per replica."""

@@ -49,14 +49,15 @@ def check(params, samples, tol=TOL):
 
 
 def test_layer_plan_scans_the_repeated_period_and_unrolls_the_tail():
-    assert CFG.layer_plan == (("mamba", "moe", "attn"), 2, ("mamba", "moe"))
+    assert CFG.layer_plan == (("mamba", "moe", "attn"), 2, ("mamba", "moe"), ())
     published = nh.NemotronHConfig()
-    period, repeats, tail = published.layer_plan
+    period, repeats, tail, head = published.layer_plan
+    assert head == ()
     assert "".join(k[0] for k in period) == "mmmmmam" and repeats == 5 and len(tail) == 52 - 35
     cut = dataclasses.replace(published, layer_pattern="MEMEM*EMEMEM*EME")
     assert cut.layer_plan[1] == 2 and len(cut.layer_plan[0]) == 7 and cut.layer_plan[2] == ("mamba", "moe")
     assert (cut.count("mamba"), cut.count("moe"), cut.count("attn")) == (7, 7, 2)
-    assert nh.NemotronHConfig(layer_pattern="M*E").layer_plan == ((), 0, ("mamba", "attn", "moe"))
+    assert nh.NemotronHConfig(layer_pattern="M*E").layer_plan == ((), 0, ("mamba", "attn", "moe"), ())
 
 
 def test_sequence_forward_matches_the_reference(params):
@@ -218,8 +219,8 @@ def test_the_comparison_fails_a_state_at_the_padded_length_and_a_dropped_token(p
         real = eng._prefill
 
         def at_padded_length(params, toks, lens):
-            logits, ks, vs, _ = real(params, toks, lens)
-            return logits, ks, vs, real(params, toks, jnp.full_like(lens, toks.shape[1]))[3]
+            logits, rows, _ = real(params, toks, lens)
+            return logits, rows, real(params, toks, jnp.full_like(lens, toks.shape[1]))[2]
 
         eng._prefill = at_padded_length
     else:  # a capacity fault: one lane's token gets nothing from its experts, every decode step
@@ -364,8 +365,61 @@ def test_moving_a_sequence_is_refused_for_the_second_description_too(call, named
         call(eng)
 
 
+def _third_description():
+    """A description with no recurrent layer at all (``models/glm4_moe_lite.py``: latent attention,
+    a dense layer, experts), at toy widths."""
+    from ray_tpu.models import glm4_moe_lite as glm
+
+    cfg = glm.Glm4MoeLiteConfig.tiny(vocab_size=C["vocab_size"])
+    return cfg, jax.jit(lambda k: glm.init_params(cfg, k))(jax.random.PRNGKey(5))
+
+
+@pytest.mark.parametrize("kwargs, named", [
+    ({"kv_layout": "paged"}, "kv_layout='paged'"),
+    ({"cache_dtype": "int8"}, "cache_dtype='int8'"),
+    ({"speculative": _Anything()}, "speculative decoding"),
+    ({"kv_plane": _Anything(), "enable_prefix_caching": True}, "KV plane"),
+    ({"mesh": "tp2"}, "tensor_parallel_size > 1"),
+])
+def test_every_refusal_at_construction_holds_for_the_latent_description_and_is_worded_truly(params, kwargs, named):
+    """The third description keeps NO state per sequence: its refusal says what it does keep (a
+    latent and a rotated key per position, not keys and values by head) and speaks of no
+    recurrent layer; the descriptions that have recurrent layers are still told so, by entry."""
+    if kwargs.get("mesh") == "tp2":
+        from ray_tpu.parallel.mesh import create_mesh
+
+        kwargs = {"mesh": create_mesh(tp=2, devices=jax.devices()[:2])}
+    cfg, p = _third_description()
+    with pytest.raises(HybridModelUnsupportedError, match=named.replace("(", r"\(").replace(")", r"\)")) as e:
+        engine(p, cfg=cfg, **kwargs)
+    said = str(e.value)
+    assert "Glm4MoeLiteConfig: 4 x mla, 1 x ffn, 3 x moe" in said and "keep c_kv and k_r per position" in said and "recurrent" not in said
+    with pytest.raises(HybridModelUnsupportedError, match=r"its recurrent layers keep a state per sequence \(conv, ssm\)") as e:
+        engine(params, **kwargs)
+    assert "per position" not in str(e.value)
+
+
+@pytest.mark.parametrize("call, named", [
+    (lambda e: e.add_prefill_request([1, 2, 3]), "disaggregated prefill"),
+    (lambda e: e.prefill_remote([1, 2, 3]), "disaggregated prefill"),
+    (lambda e: e.add_prefilled([1, 2, 3], {}), "transferred KV block"),
+    (lambda e: e.checkpoint_request("r"), "migration"),
+    (lambda e: e.restore_request({}), "migration"),
+    (lambda e: e.suspend_request("r"), "suspend"),
+    (lambda e: e.resume_suspended("r"), "suspend"),
+    (lambda e: e.adopt_prefetched([1, 2, 3], None, None), "KV plane"),
+])
+def test_moving_a_sequence_is_refused_for_the_latent_description_and_is_worded_truly(call, named):
+    cfg, p = _third_description()
+    eng = engine(p, cfg=cfg, enable_prefix_caching=True)  # off for a description, and said once
+    assert eng._prefix_cache is None and eng.prefix_cache_stats() == {} and eng.state == {} and set(eng.cache) == {"c_kv", "k_r", "length"}
+    with pytest.raises(HybridModelUnsupportedError, match=named) as e:
+        call(eng)
+    assert "keep c_kv and k_r per position" in str(e.value) and "recurrent" not in str(e.value)
+
+
 def test_neither_the_runner_nor_the_engine_names_a_model_or_a_kind_of_layer():
-    """ROADMAP C1 after PR 34: two descriptions over one loop. What a kind of layer is called,
+    """ROADMAP C1 after PR 36: three descriptions over one loop. What a kind of layer is called,
     computes and keeps comes from the description; the step programs and the engine ask it."""
     import re
 
@@ -375,5 +429,5 @@ def test_neither_the_runner_nor_the_engine_names_a_model_or_a_kind_of_layer():
     for module in (hybrid_runner, engine_module):
         with open(module.__file__) as f:
             text = f.read()
-        found = re.findall(r"nemotron|qwen|mamba|gdn|deltanet|\"attn\"|\"moe\"|'moe'|'attn'", text, flags=re.IGNORECASE)
+        found = re.findall(r"nemotron|qwen|glm|mamba|gdn|deltanet|\"attn\"|\"moe\"|\"mla\"|'moe'|'attn'|c_kv|k_r\b", text, flags=re.IGNORECASE)
         assert not found, (module.__name__, found)

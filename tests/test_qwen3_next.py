@@ -60,10 +60,10 @@ def check(params, samples, tol=TOL):
 
 def test_the_description_is_two_sub_blocks_a_layer_and_the_loop_finds_its_period():
     assert CFG.layer_kinds == ("gdn", "moe", "attn", "moe", "gdn", "moe", "attn", "moe", "gdn", "moe")
-    assert CFG.layer_plan == (("gdn", "moe", "attn", "moe"), 2, ("gdn", "moe"))
+    assert CFG.layer_plan == (("gdn", "moe", "attn", "moe"), 2, ("gdn", "moe"), ())
     published = qn.Qwen3NextConfig()
-    period, repeats, tail = published.layer_plan
-    assert period == ("gdn", "moe") * 3 + ("attn", "moe") and repeats == 12 and tail == ()
+    period, repeats, tail, head = published.layer_plan
+    assert period == ("gdn", "moe") * 3 + ("attn", "moe") and repeats == 12 and tail == () and head == ()
     assert (published.count("gdn"), published.count("attn"), published.count("moe")) == (36, 12, 48)
     cut = dataclasses.replace(published, num_hidden_layers=12)
     assert cut.layer_plan[1] == 3 and (cut.num_kv_layers, cut.routing_layers, cut.num_layers) == (3, 12, 24)
@@ -279,8 +279,8 @@ def test_the_comparison_fails_lower_precision_and_a_wrong_state(params, fault, m
         real_prefill = eng._prefill
 
         def at_padded_length(params, toks, lens):
-            logits, ks, vs, _ = real_prefill(params, toks, lens)
-            return logits, ks, vs, real_prefill(params, toks, jnp.full_like(lens, toks.shape[1]))[3]
+            logits, rows, _ = real_prefill(params, toks, lens)
+            return logits, rows, real_prefill(params, toks, jnp.full_like(lens, toks.shape[1]))[2]
 
         eng._prefill = at_padded_length
     res = check(params, served(eng.generate(ps, sp), ps, sp))
