@@ -43,7 +43,7 @@ from ray_tpu.llm.model_runner import _sds, _sds_lanes, named_jit
 from ray_tpu.models import hybrid
 
 # one step's expert-routing counters, in the order the fused step returns them
-MOE_STATS = ("experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load")
+MOE_STATS = ("experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load", "experts_read")
 # an admission's, in the order the prefill returns them (means over the routing layers)
 PREFILL_STATS = ("experts_hit", "moe_pairs_local", "moe_rows_computed")
 ROUTING = hybrid.ROUTING
@@ -107,7 +107,7 @@ def decode_step(params, cache, state, tokens, active, cfg):
     position; state: the state cache of the layers that keep something per sequence; active [B]
     bool: lanes bound to a live sequence (the others compute garbage nobody reads, and are kept
     out of the routing counters).
-    -> (logits [B, vocab] f32, cache, state, MOE_STATS as float32 [4])."""
+    -> (logits [B, vocab] f32, cache, state, MOE_STATS as float32 [5])."""
     B = tokens.shape[0]
     lengths = cache["length"]
     per_position = frozenset(cfg.position_entries())
@@ -116,23 +116,22 @@ def decode_step(params, cache, state, tokens, active, cfg):
     lanes = jnp.arange(B, dtype=jnp.int32)
     dt, sd = params["embed"].dtype, cfg.stream_dtype
     x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
-    ctx = hybrid.StepCtx(lengths, active)
 
     def layer(kind, w, i, x, carry):
         arrays, stats = carry
         view = hybrid.LayerCache(arrays, per_position, i, lanes, pos)
-        y, s = cfg.mixers[kind].step(w, cfg.norm(x, w["norm"]), view, ctx)
+        y, s = cfg.mixers[kind].step(w, cfg.norm(x, w["norm"]), view, hybrid.StepCtx(lengths, active, (params[kind], i)))
         if s is not None:
-            stats = jnp.stack([stats[0] + s[0], stats[1] + s[1], jnp.maximum(stats[2], s[2])])
+            stats = jnp.stack([stats[0] + s[0], stats[1] + s[1], jnp.maximum(stats[2], s[2]), stats[3] + s[3]])
         return x + y.astype(sd), (view.arrays, stats)
 
     arrays = {**{name: cache[name] for name in per_position}, **state}
-    x, (arrays, stats) = hybrid.run_layers(cfg, params, x, (arrays, jnp.zeros((3,), jnp.float32)), layer)
+    x, (arrays, stats) = hybrid.run_layers(cfg, params, x, (arrays, jnp.zeros((4,), jnp.float32)), layer)
     x = cfg.norm(x, params["final_norm"]).astype(dt)
     logits = jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
     n = max(cfg.routing_layers, 1)
     total = cfg.expert_layer.top_k * jnp.sum(active.astype(jnp.float32)) if cfg.routing_layers else jnp.zeros((), jnp.float32)
-    moe = jnp.stack([stats[0] / n, stats[1] / n, total, stats[2]])
+    moe = jnp.stack([stats[0] / n, stats[1] / n, total, stats[2], stats[3] / n])
     cache = {**{name: arrays.pop(name) for name in per_position}, "length": lengths + 1}
     return logits, cache, arrays, moe
 

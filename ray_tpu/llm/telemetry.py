@@ -348,8 +348,10 @@ class FlightRecorder:
         "t0", "dispatch_t",
         # a hybrid model's drained decode step (llm/hybrid_runner.MOE_STATS): held experts that
         # got a token (mean over expert layers), (token, expert) pairs served here and asked
-        # for in all, most tokens at one expert; absent for a model without routed experts
-        "experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load",
+        # for in all, most tokens at one expert, held experts whose weights the step read (mean over
+        # expert layers: the experts hit, since the step loops over those); absent for a model
+        # without routed experts
+        "experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load", "experts_read",
         # a hybrid model's ADMITTING step, of that step's prefills: tokens taken in, true and as
         # padded to bucket and batch, then llm/hybrid_runner.PREFILL_STATS, each a mean over the
         # routing layers: held experts that got a pair (mean over the step's prefill programs),
@@ -481,7 +483,7 @@ class FlightRecorder:
 # ----------------------------------------------------------------------
 # engine-facing facade
 # ----------------------------------------------------------------------
-_NO_MOE = (None,) * 4  # a step row's routing counters for a model without routed experts
+_NO_MOE = (None,) * 5  # a step row's routing counters for a model without routed experts
 _NO_PREFILL = (None,) * 5  # and its prefill counters where the step admitted nothing through a hybrid's prefill
 
 

@@ -10,11 +10,15 @@ the chosen scores are normalised and by what they are scaled, the expert's form
 gated by ``sigmoid(x . w_sg)``. Everything else is one code path:
 
 - ``route``: float32 on whatever the norm hands it, whatever the stream's dtype.
-- ``experts_dense``: every held expert over every row; a decode step's form,
-  whose cost is reading the experts' weights either way, and the form that has
-  a backward pass.
+- ``experts_dense``: every held expert over every row: the form that has a
+  backward pass (training, the plain forward), and what the tests hold the two
+  others to. Its cost is reading every held expert's weights.
 - ``experts_grouped``: a grouped matmul in plain XLA over the (row, expert)
   pairs routed HERE, for prefill; no pair is dropped whatever one expert's load.
+- ``experts_step``: a decode step's form: the held experts that a BOUND lane
+  chose, one after another, every lane against one expert's matrices read
+  straight out of the stacked weights (a kernel on a TPU, ``ops/step_experts.py``;
+  a loop elsewhere). Its cost is reading the experts hit, and no others.
 - ``moe_seq`` / ``moe_step``: the layer over a padded sequence and for one token
   a lane, each with the routing counters the flight log carries.
 
@@ -37,6 +41,8 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops import step_experts
 
 # rows of one block of the grouped matmul; pairs beyond this many take blocks twice as tall
 BLOCK, TALL_FROM = 128, 32768
@@ -109,8 +115,8 @@ def shared_expert(w, x, s: ExpertLayer):
 
 
 def experts_dense(w, x, idx, wt, c):
-    """Every held expert over every row: right for a decode step, whose cost is reading the
-    experts' weights either way. A choice held elsewhere has no column here and adds nothing."""
+    """Every held expert over every row, whatever the rows chose: the form with a backward pass.
+    A choice held elsewhere has no column here and adds nothing."""
     s = c.expert_layer
     comb = jnp.einsum("nke,nk->en", jax.nn.one_hot(idx - s.expert_start, s.held, dtype=jnp.float32), wt)
     a = _hidden(s, x, w["w_up"], w.get("w_gate"), "nh,efh->enf")
@@ -196,13 +202,51 @@ def moe_seq(w, xn, lengths, c, stacked=None):
     return (routed + shared_expert(w, x, s)).reshape(B, T, H), counters
 
 
-def moe_step(w, xn, active, c, dense=experts_dense):
-    """One token a lane: xn [B,H], active [B] bool -> (out [B,H], [held experts that got a
-    token, pairs served here, most tokens at one expert] over the active lanes, float32)."""
+def experts_step(stacked, layer, x, idx, wt, active, c):
+    """A decode step's routed experts: x [B,H], one row a lane -> (out [B,H], held experts read,
+    int32). ``experts_dense``'s mathematics (the same operands, float32 accumulation, every
+    chosen expert held here contributes) over the held experts that a lane of ``active`` chose,
+    and no others: an expert's matrices are read where they lie in the arrays STACKED over the
+    expert layers, at ``(layer, e)`` (``experts_grouped`` says why not from a layer sliced out),
+    all B lanes go against them, and what a lane did not choose is multiplied by a combine weight
+    of zero. A lane that is not bound pulls no expert in, whatever garbage it routes. On a TPU one
+    kernel walks the hit experts' ids (``ops/step_experts.py``: the next expert is fetched under
+    this one's products); elsewhere, and where the kernel refuses the shapes, a loop whose length
+    is data does, one expert an iteration."""
+    s = c.expert_layer
+    El = s.held
+    hot = jax.nn.one_hot(idx - s.expert_start, El, dtype=jnp.float32) * active[:, None, None]
+    comb = jnp.einsum("nke,nk->ne", hot, wt)
+    hit = jnp.sum(hot, axis=(0, 1)) > 0
+    n_hit = jnp.sum(hit, dtype=jnp.int32)
+    # the hit experts' ids, compacted to the front by a running count (no sort)
+    ids = jnp.zeros((El,), jnp.int32).at[jnp.where(hit, jnp.cumsum(hit) - 1, El)].set(jnp.arange(El, dtype=jnp.int32), mode="drop")
+    mats = [stacked[n] for n in s.matrices]
+    if step_experts.refusal(mats[0].dtype, x.shape[1], mats[0].shape[2], len(mats)) is None:
+        # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
+        return step_experts.hit_experts(mats, layer, x, comb, ids, n_hit, s.act, interpret=jax.default_backend() != "tpu").astype(x.dtype), n_hit
+
+    def one_expert(j, acc):
+        e = ids[j]
+        *gate, up, down = (jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0] for a in mats)
+        a = _hidden(s, x, up, gate[0] if gate else None, "bh,fh->bf")
+        a = (a * jax.lax.dynamic_slice_in_dim(comb, e, 1, axis=1)).astype(x.dtype)
+        return acc + jnp.dot(a, down, preferred_element_type=jnp.float32)
+
+    out = jax.lax.fori_loop(0, n_hit, one_expert, jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype), n_hit
+
+
+def moe_step(w, xn, active, c, stacked):
+    """One token a lane: xn [B,H], active [B] bool, ``stacked`` = (the expert layers' stacked
+    weights, this layer's index) -> (out [B,H], [held experts that got a token, pairs served
+    here, most tokens at one expert, held experts whose weights the step read] over the active
+    lanes, float32)."""
     s = c.expert_layer
     idx, wt = route(w, xn, c)  # on the norm as it comes
     xn = xn.astype(w["w_up"].dtype)
     hot = jax.nn.one_hot(idx - s.expert_start, s.held, dtype=jnp.float32) * active[:, None, None]
     load = jnp.sum(hot, axis=(0, 1))
-    stats = jnp.stack([jnp.sum(load > 0).astype(jnp.float32), jnp.sum(load), jnp.max(load)])
-    return dense(w, xn, idx, wt, c) + shared_expert(w, xn, s), stats
+    routed, read = experts_step(*stacked, xn, idx, wt, active, c)
+    stats = jnp.stack([jnp.sum(load > 0).astype(jnp.float32), jnp.sum(load), jnp.max(load), read.astype(jnp.float32)])
+    return routed + shared_expert(w, xn, s), stats

@@ -136,7 +136,7 @@ class Glm4MoeLiteConfig(HybridDescription):
         return {"mla": Mixer("mla", attention_seq, attention_step),
                 "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt)), {}),
                              lambda w, xn, cache, ctx: (ffn(w, xn.astype(dt)), None)),
-                "moe": Mixer("moe", experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self), True)}
+                "moe": Mixer("moe", experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self, ctx.stacked), True)}
 
     def norm(self, x, w):
         return rms_norm(x, w, self.rms_eps)

@@ -59,7 +59,7 @@ class Mixer(NamedTuple):
 
     scope: str  # the named scope around every layer of this kind, in every program
     seq: Callable  # (w, xn [B,T,H], SeqCtx) -> (y [B,T,H], kept {name: one layer's entry})
-    step: Callable  # (w, xn [B,H], LayerCache, StepCtx) -> (y [B,H], routing counters [3] or None)
+    step: Callable  # (w, xn [B,H], LayerCache, StepCtx) -> (y [B,H], routing counters [4] or None)
     routes: bool = False  # its sequence form keeps ROUTING, its step form hands back counters
 
 
@@ -72,6 +72,7 @@ class SeqCtx(NamedTuple):
 class StepCtx(NamedTuple):
     lengths: Any  # [B] int32: positions already held = the new token's position
     active: Any  # [B] bool: lanes bound to a live sequence
+    stacked: Any  # (the kind's stacked weights, this layer's index), as in ``SeqCtx``
 
 
 class LayerPlan(NamedTuple):

@@ -126,9 +126,6 @@ class NemotronHConfig(HybridDescription):
             y, counters = experts.moe_seq(w, xn, ctx.lengths, self, stacked=ctx.stacked)
             return y, {ROUTING: counters}
 
-        def experts_step(w, xn, cache, ctx):  # through THIS module's ``experts_dense``, looked up at the call: a test swaps it
-            return experts.moe_step(w, xn, ctx.active, self, dense=experts_dense)
-
         def attention_seq(w, xn, ctx):
             y, k, v = attn_seq(w, xn.astype(dt), self, ctx.mesh)
             return y, {"k": k, "v": v}
@@ -140,7 +137,7 @@ class NemotronHConfig(HybridDescription):
             return attn_step(w, q, cache, ctx, self), None
 
         forms = {"mamba": (mamba_seq, mamba_step, False), "attn": (attention_seq, attention_step, False),
-                 "moe": (experts_seq, experts_step, True)}
+                 "moe": (experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self, ctx.stacked), True)}
         return {kind: Mixer(scope, *forms[kind]) for kind, scope in KINDS.values()}
 
     def norm(self, x, w):

@@ -152,7 +152,7 @@ class Qwen3NextConfig(HybridDescription):
             return y, {ROUTING: counters}
 
         forms = {"gdn": (rule_seq, rule_step, False), "attn": (attention_seq, attention_step, False),
-                 "moe": (experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self), True)}
+                 "moe": (experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self, ctx.stacked), True)}
         return {kind: Mixer(SCOPES[kind], *forms[kind]) for kind in forms}
 
     def norm(self, x, w):

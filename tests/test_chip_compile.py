@@ -595,3 +595,41 @@ def test_glm_prefill_of_the_16384_bucket_fits_beside_weights_and_cache_on_one_v5
     cache_on_chip = _compile(lambda c: c, cache)[0].memory_analysis().argument_size_in_bytes
     print("cache on chip:", cache_on_chip / 2**30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + cache_on_chip < 15.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# PR 37: the decode step's routed experts are the experts a bound lane chose, one after another
+# (models/experts.experts_step): on a TPU one kernel whose grid walks their ids, each expert's
+# matrices read from the stacked weights where they lie (ops/step_experts.py)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("cell, lanes_n, a_layers_experts_gb", [("nemotron", 32, 1.28), ("qwen3_next", 16, 0.81), ("glm", 16, 1.21)])
+def test_fused_step_walks_the_experts_hit_and_copies_no_layers_experts(one_chip, as_on_a_tpu, cell, lanes_n, a_layers_experts_gb):
+    """Each hybrid cell's fused step at its own size: the gate lets the cell's expert through whole,
+    the kernel is in the step, both caches are aliased to the donated inputs, temporaries stay
+    under 0.1 GiB where one layer's held experts are 1.28 / 0.81 / 1.21 GB, and no array of a
+    layer's experts (``[held, F, H]``: the dense form's operand, or a slice made for the kernel)
+    is anywhere in the program."""
+    import re
+
+    from ray_tpu.llm import hybrid_runner as hr
+    from ray_tpu.ops import step_experts
+
+    at_size = {"nemotron": _hybrid_at_the_benchmarks_size, "qwen3_next": _qwen3_next_at_the_benchmarks_size, "glm": _glm_at_the_benchmarks_size}
+    cfg, params, cache, state = at_size[cell](one_chip)
+    layers, held, F, H = params["moe"]["w_up"].shape
+    matrices = len(cfg.expert_layer.matrices)
+    assert held == cfg.expert_layer.held and layers == cfg.count("moe")
+    assert abs(matrices * held * F * H * 2 / 1e9 - a_layers_experts_gb) < 0.01
+    assert step_experts.refusal(jnp.bfloat16, H, F, matrices) is None and step_experts.tile_rows(F, H, matrices, 2) == F
+    assert "float32" in step_experts.refusal(jnp.float32, H, F, matrices) and "128-lane" in step_experts.refusal(jnp.bfloat16, H + 64, F, matrices)
+    assert step_experts.tile_rows(4 * 1856, 2688, 2, 2) == 1856 and "no tile" in step_experts.refusal(jnp.bfloat16, 128, 65537, 2)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    n = lanes_n
+    lanes = (s((n,), jnp.int32), s((n, 2), jnp.uint32), s((n,), jnp.float32), s((n,), jnp.int32), s((n,), jnp.float32))
+    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+    compiled = step.lower(params, cache, state, *lanes, s((n,), jnp.bool_)).compile()
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert "step_experts" in txt
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)) if a.ndim > 1)
+    assert mem.temp_size_in_bytes < 0.1 * 2**30
+    assert not re.search(rf"bf16\\[(1,)?{held},{F},{H}\\]", txt), "a layer's experts, sliced out of the stack"

@@ -209,6 +209,7 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(params):
     assert res["ok"] and res["tokens"] == 40, res
     rows = [s for s in eng.telemetry()["steps"] if "experts_hit" in s]
     assert rows and all(0 < r["experts_hit"] <= 8 and r["moe_pairs_local"] == r["moe_pairs_total"] for r in rows), "every expert is held here"
+    assert all(r["experts_read"] == r["experts_hit"] for r in rows), "a decode step reads the experts its lanes hit, and no others (PR 37)"
 
 
 def test_the_synchronous_loop_is_the_fused_steps_oracle(params):

@@ -5,6 +5,7 @@ drops nothing and is told its experts, and every refusal by its name. Toy widths
 of layer, a pattern with a repeated period (the loop's scan) and a tail (its unrolled part)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,7 @@ from benchmark import reference
 from benchmark.families import nemotron_h as family
 from ray_tpu.exceptions import HybridModelUnsupportedError
 from ray_tpu.llm import LLMEngine, SamplingParams
+from ray_tpu.models import experts
 from ray_tpu.models import nemotron_h as nh
 
 # the configuration file's side of the toy model: chip 0 of two, experts 0-3 of 8
@@ -224,8 +226,8 @@ def test_the_comparison_fails_a_state_at_the_padded_length_and_a_dropped_token(p
 
         eng._prefill = at_padded_length
     else:  # a capacity fault: one lane's token gets nothing from its experts, every decode step
-        real_dense = nh.experts_dense
-        monkeypatch.setattr(nh, "experts_dense", lambda w, x, idx, wt, c: real_dense(w, x, idx, wt.at[0].set(0.0), c))
+        real = experts.experts_step
+        monkeypatch.setattr(experts, "experts_step", lambda stacked, layer, x, idx, wt, active, c: real(stacked, layer, x, idx, wt.at[0].set(0.0), active, c))
     res = check(params, served(eng.generate(ps, sp), ps, sp))
     assert not res["ok"] and res["max_abs_dlogprob"] > 100 * TOL, res
 
@@ -431,3 +433,90 @@ def test_neither_the_runner_nor_the_engine_names_a_model_or_a_kind_of_layer():
             text = f.read()
         found = re.findall(r"nemotron|qwen|glm|mamba|gdn|deltanet|\"attn\"|\"moe\"|\"mla\"|'moe'|'attn'|c_kv|k_r\b", text, flags=re.IGNORECASE)
         assert not found, (module.__name__, found)
+
+
+# ---------------------------------------------------------------------------
+# PR 37: a decode step's routed experts are a loop over the held experts that a BOUND lane chose
+# (``experts.experts_step``), held to ``experts_dense`` for each description's expert layer
+# ---------------------------------------------------------------------------
+@functools.cache
+def _expert_layer_of(name):
+    """(a config object for ``experts``, its expert layers' stacked weights [layers, ...]) at toy
+    widths: each description's own ``ExpertLayer`` as chip 0 of two (experts 0-3 of 8); GLM's
+    description holds every expert (as its cell does), so its layer is cut to the same share."""
+    import importlib
+    from types import SimpleNamespace
+
+    config = {"nemotron_h": "NemotronHConfig", "qwen3_next": "Qwen3NextConfig", "glm4_moe_lite": "Glm4MoeLiteConfig"}[name]
+    tiny = getattr(importlib.import_module(f"ray_tpu.models.{name}"), config).tiny
+    cfg = tiny() if name == "glm4_moe_lite" else tiny(num_local_experts=4)
+    stacked = jax.jit(cfg.init_params)(jax.random.PRNGKey(3))["moe"]
+    s = cfg.expert_layer
+    if s.held == s.num_experts:
+        s = dataclasses.replace(s, local_experts=s.num_experts // 2)
+        stacked = {n: a[:, :s.held] if n in s.matrices else a for n, a in stacked.items()}
+    assert (s.held, s.num_experts) == (4, 8) and stacked["w_up"].shape[:2] == (cfg.count("moe"), 4) and cfg.count("moe") >= 2
+    return SimpleNamespace(expert_layer=s, hidden_size=cfg.hidden_size), stacked
+
+
+@pytest.mark.parametrize("case", ["no_lane_active", "one_lane", "every_held_expert_hit", "all_choices_on_another_chip", "unbound_lanes_hold_garbage"])
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+@pytest.mark.parametrize("name", ["nemotron_h", "qwen3_next", "glm4_moe_lite"])
+def test_the_step_form_reads_the_experts_its_bound_lanes_hit_and_equals_the_dense_form(name, form, case, monkeypatch):
+    """Both forms of ``experts_step``: the loop that runs off the TPU, and the TPU's kernel run by
+    the Pallas interpreter (asked for by swapping its gate, as ``tests/test_slot_attention.py`` does)."""
+    from ray_tpu.ops import step_experts
+
+    assert "backend 'cpu'" in step_experts.refusal(jnp.bfloat16, 2688, 1856, 2)
+    c, stacked = _expert_layer_of(name)
+    if form == "kernel":  # with a buffer so small that an expert's 32 rows go through in two tiles of 16
+        monkeypatch.setattr(step_experts, "refusal", lambda *a: None)
+        monkeypatch.setattr(step_experts, "_TILE_BYTES", 3 * c.hidden_size * 4 * 16)
+        assert step_experts.tile_rows(stacked["w_up"].shape[2], c.hidden_size, len(c.expert_layer.matrices), 4) == 16 < stacked["w_up"].shape[2]
+    s, layer, B = c.expert_layer, 1, 8
+    w = jax.tree.map(lambda a: a[layer], stacked)
+    x = jax.random.normal(jax.random.PRNGKey(21), (B, c.hidden_size))
+    idx, wt = experts.route(w, x, c)
+    active = jnp.ones((B,), bool)
+    if case == "no_lane_active":
+        active = jnp.zeros((B,), bool)
+    elif case == "one_lane":  # the last lane that chose an expert held here
+        lane = int(np.flatnonzero(((np.asarray(idx) >= s.expert_start) & (np.asarray(idx) < s.expert_start + s.held)).any(axis=1))[-1])
+        active = jnp.arange(B) == lane
+    elif case == "every_held_expert_hit":  # lane b chooses held experts b*k .. b*k+k-1 (mod held): 8 lanes cover all of them
+        idx = s.expert_start + (jnp.arange(B * s.top_k, dtype=jnp.int32) % s.held).reshape(B, s.top_k)
+    elif case == "all_choices_on_another_chip":
+        idx = (s.expert_start + s.held + idx % (s.num_experts - s.held)).astype(jnp.int32)
+    else:
+        active = jnp.arange(B) % 2 == 0
+    step = jax.jit(lambda x, idx, wt, active: experts.experts_step(stacked, layer, x, idx, wt, active, c))
+    got, read = step(x, idx, wt, active)
+    want = experts.experts_dense(w, x, idx, wt * active[:, None], c)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    chosen = (np.asarray(idx) - s.expert_start)[np.asarray(active)]
+    assert int(read) == len(np.unique(chosen[(chosen >= 0) & (chosen < s.held)])), "the walk's length is the held experts that a bound lane chose"
+    if case in ("no_lane_active", "all_choices_on_another_chip"):
+        assert int(read) == 0 and not np.asarray(got).any()
+    elif case == "every_held_expert_hit":
+        assert int(read) == s.held and np.abs(np.asarray(got)).min(axis=1).max() > 0
+    elif case == "one_lane":
+        assert 1 <= int(read) <= s.top_k and not np.asarray(got)[np.arange(B) != lane].any() and np.abs(np.asarray(got)[lane]).max() > 0
+    else:  # what an unbound lane holds, and so where its garbage routes, changes nothing for the bound ones
+        garbage = jnp.where(active[:, None], x, 1e4 * jax.random.normal(jax.random.PRNGKey(22), x.shape))
+        idx2, wt2 = experts.route(w, garbage, c)
+        assert (np.asarray(idx2) != np.asarray(idx)).any()
+        got2, read2 = step(garbage, idx2, wt2, active)
+        np.testing.assert_array_equal(np.asarray(got2)[::2], np.asarray(got)[::2])
+        assert int(read2) == int(read) > 0 and not np.asarray(got2)[1::2].any()
+
+
+def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(params):
+    """``experts_read`` beside ``experts_hit`` in a step row (``hybrid_runner.MOE_STATS``): means
+    over the expert layers of the held experts whose weights the step read and that got a token.
+    The step loops over the experts hit, so the two are equal in every decode row."""
+    eng = engine(params)
+    ps = prompts(8, (12, 30, 7, 21, 44, 9))
+    eng.generate(ps, [SamplingParams(max_tokens=6 + 3 * i, temperature=0.0) for i in range(len(ps))])
+    rows = [s for s in eng.telemetry()["steps"] if "experts_hit" in s]
+    assert len(rows) >= 10 and all(r["experts_read"] == r["experts_hit"] for r in rows)
+    assert len({r["experts_read"] for r in rows}) > 1 and all(0 < r["experts_read"] <= CFG.expert_layer.held for r in rows)

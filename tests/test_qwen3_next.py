@@ -122,7 +122,7 @@ def test_gated_attention_step_against_its_sequence_form(params):
     for t in range(T):
         lengths = jnp.full((2,), t, jnp.int32)
         view = hybrid.LayerCache(arrays, frozenset({"k", "v"}), 0, jnp.arange(2), lengths)
-        y_t, _ = step(w, xn[:, t], view, hybrid.StepCtx(lengths, jnp.ones((2,), bool)))
+        y_t, _ = step(w, xn[:, t], view, hybrid.StepCtx(lengths, jnp.ones((2,), bool), None))
         arrays = view.arrays
         np.testing.assert_allclose(y_t, y[:, t], atol=1e-5)
     np.testing.assert_allclose(arrays["k"][0, :, :T], k, atol=1e-6)
@@ -162,7 +162,7 @@ def test_the_expert_block_grouped_dense_and_one_token_a_lane_agree(params):
     dense_form, _ = experts.moe_seq(w, xs, lengths, CFG)
     real = np.arange(100)[None, :] < np.asarray(lengths)[:, None]
     np.testing.assert_allclose(np.asarray(served_form)[real], np.asarray(dense_form)[real], atol=1e-4)
-    lane, stats = experts.moe_step(w, xs[:, 3], jnp.ones((2,), bool), CFG)
+    lane, stats = experts.moe_step(w, xs[:, 3], jnp.ones((2,), bool), CFG, (params["moe"], 0))
     np.testing.assert_allclose(lane, served_form[:, 3], atol=1e-4)
     # the counters: pairs served here over the real rows, held experts hit, rows of whole blocks
     local = (np.asarray(idx).reshape(2, 100, -1)[real] < CFG.local_experts).sum()
@@ -234,6 +234,7 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(params):
     steps = eng.telemetry()["steps"]
     rows = [s for s in steps if "experts_hit" in s]
     assert rows and all(0 < r["experts_hit"] <= 4 and r["moe_pairs_local"] <= r["moe_pairs_total"] for r in rows)
+    assert all(r["experts_read"] == r["experts_hit"] for r in rows), "a decode step reads the experts its lanes hit, and no others (PR 37)"
     admitting = [s for s in steps if s.get("admitted")]
     assert admitting and all("prefill_tokens" in s for s in admitting)
     for s in admitting:
