@@ -2199,55 +2199,77 @@ class LLMEngine:
         of two so compile count stays (buckets x log2(max_num_seqs));
         padding rows carry length 1 and produce garbage that is never
         inserted. This is how forward-only prefill reaches training-step
-        MXU utilization instead of B=1 dispatch overhead."""
+        MXU utilization instead of B=1 dispatch overhead.
+
+        Two stamped stages inside ``llm.step.prefill``, once a group:
+        ``llm.step.prefill.launch`` (the host until the prefill program
+        and every sequence's inserts are enqueued) and
+        ``llm.step.prefill.first_tokens`` (the host blocked reading the
+        first tokens back, one sequence after another), and the group's
+        three stamps on the step's row (``prefill_dispatch_t``: prefill
+        enqueued, inserts enqueued, first tokens read), against which a
+        trace's prefill executions are set (util/profiling.summarize)."""
         import jax.numpy as jnp
 
-        T = _bucket(max(len(p) for _, _, p in group), self.prefill_buckets)
-        B = len(group)
-        Bp = 1 << (B - 1).bit_length()
-        toks = np.zeros((Bp, T), np.int32)
-        lens = np.ones((Bp,), np.int32)
-        for i, (_, _, prompt) in enumerate(group):
-            toks[i, : len(prompt)] = prompt
-            lens[i] = len(prompt)
-        # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true
-        # length, and its routing layers' counters (hybrid_runner.PREFILL_STATS)
-        ks = vs = rows = kept = None
-        toks, lens = jnp.asarray(toks), jnp.asarray(lens)
-        if self._hybrid:
-            # what each layer keeps per position and per sequence, by entry name (hybrid_runner.prefill)
-            logits, rows, kept = self._prefill(self.params, toks, lens)
-        else:
-            logits, ks, vs = self._prefill(self.params, toks, lens)
-        if self._prefill_room is not None and (Bp, T) not in self._prefill_need:
-            # the shape's first run (a warm-up's, where there is one): the executable is the one just run, nothing compiles
-            from ray_tpu.llm.model_runner import program_bytes
-
-            self._prefill_need[Bp, T] = program_bytes(self._prefill, self.params, toks, lens)
-        for i, (st, slot, prompt) in enumerate(group):
-            n = len(prompt)
-            if self._prefix_cache is not None and not st.token_ids:
-                stored = self._prefix_cache.store(prompt, ks[:, i], vs[:, i], self.prefill_buckets)
-                if stored is not None and self._kv_plane is not None:
-                    # the block every other replica would re-prefill —
-                    # publish it to the cluster tier (llm/kvplane/)
-                    self._plane_publish(prompt, ks[:, i], vs[:, i], *stored)
-            if self.kv_layout == "paged":
-                page = self._pcfg.page_size
-                table_row = jnp.asarray(self._tables[slot])
-                self.pool = self._insert(self.pool, table_row[: T // page], ks[:, i], vs[:, i])
-                self._lengths[slot] = n
-                if self._device_resident:
-                    self._push_table(slot)
-            elif not self._hybrid:
-                self.cache = self._insert(self.cache, slot, ks[:, i], vs[:, i], n)
+        tel = self._tel
+        with stage(tel, "llm.step.prefill.launch"):
+            T = _bucket(max(len(p) for _, _, p in group), self.prefill_buckets)
+            B = len(group)
+            Bp = 1 << (B - 1).bit_length()
+            toks = np.zeros((Bp, T), np.int32)
+            lens = np.ones((Bp,), np.int32)
+            for i, (_, _, prompt) in enumerate(group):
+                toks[i, : len(prompt)] = prompt
+                lens[i] = len(prompt)
+            # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true
+            # length, and its routing layers' counters (hybrid_runner.PREFILL_STATS)
+            ks = vs = rows = kept = None
+            toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+            if self._hybrid:
+                # what each layer keeps per position and per sequence, by entry name (hybrid_runner.prefill)
+                logits, rows, kept = self._prefill(self.params, toks, lens)
             else:
-                self.cache = self._insert(self.cache, slot, {name: a[:, i] for name, a in rows.items()}, n)
-                if self.state:
-                    # replaces whatever the slot's last sequence left: the reset of a recycled slot
-                    with stage(self._tel, "llm.step.state_insert"):
-                        self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), kept)
-            self._bind_slot(st, slot, logits[i : i + 1])
+                logits, ks, vs = self._prefill(self.params, toks, lens)
+            t_dispatch = time.time() if tel is not None else 0.0
+            if self._prefill_room is not None and (Bp, T) not in self._prefill_need:
+                # the shape's first run (a warm-up's, where there is one): the executable is the one just run, nothing compiles
+                from ray_tpu.llm.model_runner import program_bytes
+
+                self._prefill_need[Bp, T] = program_bytes(self._prefill, self.params, toks, lens)
+            # every sequence's rows into its slot, all enqueued behind the prefill before the host
+            # waits for anything
+            for i, (st, slot, prompt) in enumerate(group):
+                n = len(prompt)
+                if self._prefix_cache is not None and not st.token_ids:
+                    stored = self._prefix_cache.store(prompt, ks[:, i], vs[:, i], self.prefill_buckets)
+                    if stored is not None and self._kv_plane is not None:
+                        # the block every other replica would re-prefill —
+                        # publish it to the cluster tier (llm/kvplane/)
+                        self._plane_publish(prompt, ks[:, i], vs[:, i], *stored)
+                if self.kv_layout == "paged":
+                    page = self._pcfg.page_size
+                    table_row = jnp.asarray(self._tables[slot])
+                    self.pool = self._insert(self.pool, table_row[: T // page], ks[:, i], vs[:, i])
+                    self._lengths[slot] = n
+                    if self._device_resident:
+                        self._push_table(slot)
+                elif not self._hybrid:
+                    self.cache = self._insert(self.cache, slot, ks[:, i], vs[:, i], n)
+                else:
+                    self.cache = self._insert(self.cache, slot, {name: a[:, i] for name, a in rows.items()}, n)
+                    if self.state:
+                        # replaces whatever the slot's last sequence left: the reset of a recycled slot
+                        with stage(tel, "llm.step.state_insert"):
+                            self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), kept)
+            t_launched = time.time() if tel is not None else 0.0
+        with stage(tel, "llm.step.prefill.first_tokens"):
+            # the first token of each: its sample waits for the prefill program's end
+            for i, (st, slot, _) in enumerate(group):
+                self._bind_slot(st, slot, logits[i : i + 1])
+        if tel is not None:
+            if tel.prefill_dispatch_t is None:
+                tel.prefill_dispatch_t = []
+            tel.prefill_dispatch_t.append([t_dispatch, t_launched, time.time()])
         if kept and self._tel is not None and "routing" in kept:
             # the step's row in the flight log: tokens prefilled, true and as padded, and the
             # routing counters, read AFTER the first tokens (whose readback the program's end

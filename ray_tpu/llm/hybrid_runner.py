@@ -41,6 +41,7 @@ from ray_tpu.lint import jaxcheck
 from ray_tpu.llm import state_cache
 from ray_tpu.llm.model_runner import _sds, _sds_lanes, named_jit
 from ray_tpu.models import hybrid
+from ray_tpu.util.profiling import scope, scoped
 
 # one step's expert-routing counters, in the order the fused step returns them
 MOE_STATS = ("experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load", "experts_read")
@@ -95,8 +96,9 @@ def prefill(params, tokens, length, cfg, mesh=None):
     prompt's true length, with PREFILL_STATS as float32 [3] beside the state under ``ROUTING``
     where the model routes)."""
     x, out = hybrid.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
-    x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
-    logits = jnp.dot(x_last, params["unembed"], preferred_element_type=jnp.float32)
+    with scope("head"):
+        x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
+        logits = jnp.dot(x_last, params["unembed"], preferred_element_type=jnp.float32)
     if ROUTING in out:
         out[ROUTING] = jnp.mean(out[ROUTING], axis=0)
     return logits, {name: out.pop(name) for name in cfg.position_entries()}, out
@@ -115,7 +117,8 @@ def decode_step(params, cache, state, tokens, active, cfg):
     pos = jnp.minimum(lengths, horizon - 1)
     lanes = jnp.arange(B, dtype=jnp.int32)
     dt, sd = params["embed"].dtype, cfg.stream_dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
+    with scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
 
     def layer(kind, w, i, x, carry):
         arrays, stats = carry
@@ -127,8 +130,9 @@ def decode_step(params, cache, state, tokens, active, cfg):
 
     arrays = {**{name: cache[name] for name in per_position}, **state}
     x, (arrays, stats) = hybrid.run_layers(cfg, params, x, (arrays, jnp.zeros((4,), jnp.float32)), layer)
-    x = cfg.norm(x, params["final_norm"]).astype(dt)
-    logits = jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
+    with scope("head"):
+        x = cfg.norm(x, params["final_norm"]).astype(dt)
+        logits = jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
     n = max(cfg.routing_layers, 1)
     total = cfg.expert_layer.top_k * jnp.sum(active.astype(jnp.float32)) if cfg.routing_layers else jnp.zeros((), jnp.float32)
     moe = jnp.stack([stats[0] / n, stats[1] / n, total, stats[2], stats[3] / n])
@@ -154,8 +158,8 @@ def make_hybrid_fns(cfg, device_resident: bool):
     from ray_tpu.llm import kv_cache as kvc
 
     prefill_fn = named_jit("llm_hybrid_prefill", partial(prefill, cfg=cfg))
-    insert_fn = named_jit("llm_kv_insert", kvc.insert_entries, donate_argnums=(0,))
-    state_insert_fn = named_jit("llm_state_insert", state_cache.insert_state, donate_argnums=(0,))
+    insert_fn = named_jit("llm_kv_insert", scoped("cache", kvc.insert_entries), donate_argnums=(0,))
+    state_insert_fn = named_jit("llm_state_insert", scoped("cache", state_cache.insert_state), donate_argnums=(0,))
     if device_resident:
         step_fn = named_jit("llm_hybrid_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
     else:

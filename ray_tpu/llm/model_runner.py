@@ -39,6 +39,7 @@ from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops import slot_attention
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
+from ray_tpu.util.profiling import SCOPES, scope, scoped  # noqa: F401 - SCOPES: the table beside STEP_PROGRAM_NAMES, where a reader looks for it
 
 
 # ---------------------------------------------------------------------------
@@ -61,6 +62,12 @@ STEP_PROGRAM_NAMES = frozenset({
     # hybrid models (llm/hybrid_runner.py): recurrent state beside the slot KV rows
     "llm_hybrid_prefill", "llm_state_insert", "llm_hybrid_fused_step", "llm_hybrid_decode_step",
 })
+
+
+# Inside a step program the work says its names too: every ``jax.named_scope`` is one of
+# ``SCOPES`` (scope name -> role; the table lives in ``util/profiling.py``, below the models that
+# set the scopes and beside the reduction that reads a trace by them, and is imported here), set
+# through ``scope()``, which refuses a name the table lacks as ``named_jit`` refuses a program's.
 
 
 def named_jit(name: str, fn, **jit_kwargs):
@@ -323,10 +330,11 @@ def _qkv(xn, layer, cfg: LlamaConfig):
 
 
 def _mlp(x, layer, cfg: LlamaConfig, tpc: TpSpec | None = None):
-    xn = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-    g = jnp.dot(xn, layer["w_gate"])
-    u = jnp.dot(xn, layer["w_up"])
-    return x + _tp_reduce(jnp.dot(jax.nn.silu(g) * u, layer["w_down"]), tpc)
+    with scope("mlp"):
+        xn = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        g = jnp.dot(xn, layer["w_gate"])
+        u = jnp.dot(xn, layer["w_up"])
+        return x + _tp_reduce(jnp.dot(jax.nn.silu(g) * u, layer["w_down"]), tpc)
 
 
 _layer_of = slot_attention.layer_of  # layer i of a stacked cache leaf; ``spec/verify.py`` takes it from here
@@ -343,7 +351,8 @@ def _scan_layers_carrying_cache(layer_fn, x, params, cache):
 
     kv = {name: leaf for name, leaf in cache.items() if name != "length"}
     layer_ix = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
-    return jax.lax.scan(step, (x, kv), (params["layers"], layer_ix))[0]
+    with scope("cache"):  # the loop's own work is on its carry, the cache; a layer's stands under ``attn`` and ``mlp``
+        return jax.lax.scan(step, (x, kv), (params["layers"], layer_ix))[0]
 
 
 @jaxcheck.entry(
@@ -362,30 +371,34 @@ def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
     B, T = tokens.shape
     positions = jnp.arange(T, dtype=jnp.int32)
     cos, sin = rotary_embedding(positions, cfg.hd, cfg.rope_theta)
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
 
     def layer_fn(x, layer):
-        xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(xn, layer, cfg)
-        qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
-        kh = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
-        o = flash_attention_on_mesh(qh, kh, v.transpose(0, 2, 1, 3), mesh, cfg.attention_impl)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.num_heads * cfg.hd)
-        x = x + jnp.dot(o, layer["wo"])
+        with scope("attn"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            q, k, v = _qkv(xn, layer, cfg)
+            qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
+            kh = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
+            o = flash_attention_on_mesh(qh, kh, v.transpose(0, 2, 1, 3), mesh, cfg.attention_impl)
+            o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.num_heads * cfg.hd)
+            x = x + jnp.dot(o, layer["wo"])
         x = _mlp(x, layer, cfg)
         # cache stores rope'd keys (decode appends rope'd keys too)
         return x, (kh.transpose(0, 2, 1, 3), v)
 
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn, policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
-    x, (ks, vs) = jax.lax.scan(layer_fn, x, params["layers"])
+    with scope("cache"):  # the loop's own work: each layer's keys and values stacked as they leave it
+        x, (ks, vs) = jax.lax.scan(layer_fn, x, params["layers"])
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    # only the last real token's logits matter: gather before the unembed
-    # matmul so prefill does a [B, H] x [H, V] instead of [B*T, H] x [H, V]
-    x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
+    with scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        # only the last real token's logits matter: gather before the unembed
+        # matmul so prefill does a [B, H] x [H, V] instead of [B*T, H] x [H, V]
+        x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
+        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
     return logits, ks, vs
 
 
@@ -442,7 +455,8 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
     quant = "k_scale" in cache
     lengths = cache["length"]
     cos, sin = rotary_embedding(lengths[:, None], cfg.hd, cfg.rope_theta)  # [B, 1, hd/2]
-    x = _tp_embed(params["embed"], tokens[:, None], tpc)  # [B, 1, H]
+    with scope("embed"):
+        x = _tp_embed(params["embed"], tokens[:, None], tpc)  # [B, 1, H]
     S = cache["k"].shape[2]
 
     lanes = jnp.arange(B, dtype=jnp.int32)
@@ -451,35 +465,38 @@ def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = No
     def layer_fn(x, kv, layer, i):  # kv: k, v [L, B, S, nkv, hd]; int8: scales [L, B, nkv, S]
         from ray_tpu.llm.kv_quant import quantize_heads
 
-        xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q, k_t, v_t = _qkv(xn, layer, cfg)  # q: [B,1,nh,hd]
-        qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [B,1,nh,hd]
-        kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
+        with scope("attn"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            q, k_t, v_t = _qkv(xn, layer, cfg)  # q: [B,1,nh,hd]
+            qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [B,1,nh,hd]
+            kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
 
-        k_tok, v_tok = kh[:, 0], v_t[:, 0]
-        if quant:
-            k_tok, sk = quantize_heads(k_tok)  # [B, kv, hd] i8, [B, kv] f32
-            v_tok, sv = quantize_heads(v_tok)
-            # the two index arrays are split by the kv slice, so the indexed slots are [B, kv]
-            kv["k_scale"] = kv["k_scale"].at[i, lanes, :, write_pos].set(sk)
-            kv["v_scale"] = kv["v_scale"].at[i, lanes, :, write_pos].set(sv)
-        # ONE token a lane; inactive lanes are written too (at their stale
-        # length): harmless, the mask never reads past `length`
-        kv["k"] = kv["k"].at[i, lanes, write_pos].set(k_tok.astype(kv["k"].dtype))
-        kv["v"] = kv["v"].at[i, lanes, write_pos].set(v_tok.astype(kv["v"].dtype))
-        # GQA attention against the cache (head h uses kv head h // rep), the new token read
-        # back from it: positions 0..length of layer i, out of the stack where they lie
-        o = slot_attention.attend(qh[:, 0], kv["k"], kv["v"], i, lengths, nkv, live=live, k_scale=kv.get("k_scale"),
-                                  v_scale=kv.get("v_scale"), sharded=partitioned or tpc is not None)
-        o = o.reshape(B, 1, nh * hd).astype(x.dtype)
-        x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
+            k_tok, v_tok = kh[:, 0], v_t[:, 0]
+            with scope("cache"):
+                if quant:
+                    k_tok, sk = quantize_heads(k_tok)  # [B, kv, hd] i8, [B, kv] f32
+                    v_tok, sv = quantize_heads(v_tok)
+                    # the two index arrays are split by the kv slice, so the indexed slots are [B, kv]
+                    kv["k_scale"] = kv["k_scale"].at[i, lanes, :, write_pos].set(sk)
+                    kv["v_scale"] = kv["v_scale"].at[i, lanes, :, write_pos].set(sv)
+                # ONE token a lane; inactive lanes are written too (at their stale
+                # length): harmless, the mask never reads past `length`
+                kv["k"] = kv["k"].at[i, lanes, write_pos].set(k_tok.astype(kv["k"].dtype))
+                kv["v"] = kv["v"].at[i, lanes, write_pos].set(v_tok.astype(kv["v"].dtype))
+            # GQA attention against the cache (head h uses kv head h // rep), the new token read
+            # back from it: positions 0..length of layer i, out of the stack where they lie
+            o = slot_attention.attend(qh[:, 0], kv["k"], kv["v"], i, lengths, nkv, live=live, k_scale=kv.get("k_scale"),
+                                      v_scale=kv.get("v_scale"), sharded=partitioned or tpc is not None)
+            o = o.reshape(B, 1, nh * hd).astype(x.dtype)
+            x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
         x = _mlp(x, layer, cfg, tpc)
         return x, kv
 
     x, kv = _scan_layers_carrying_cache(layer_fn, x, params, cache)
-    x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps)
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = _tp_gather_logits(jnp.dot(x, unembed, preferred_element_type=jnp.float32), tpc)
+    with scope("head"):
+        x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps)
+        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = _tp_gather_logits(jnp.dot(x, unembed, preferred_element_type=jnp.float32), tpc)
     return logits, {**kv, "length": lengths + 1}
 
 
@@ -508,7 +525,8 @@ def extend(params, cache, slot, tokens, length, cfg: LlamaConfig):
     start = cache["length"][slot]
     positions = start + jnp.arange(T, dtype=jnp.int32)
     cos, sin = rotary_embedding(positions, cfg.hd, cfg.rope_theta)
-    x = jnp.take(params["embed"], tokens[None, :], axis=0)  # [1, T, H]
+    with scope("embed"):
+        x = jnp.take(params["embed"], tokens[None, :], axis=0)  # [1, T, H]
     # token i (at absolute pos start+i) sees cache pos j iff j <= start+i;
     # stale cache beyond the suffix is masked out by the same bound
     attn_ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= positions[:, None])[None, None]  # [1,1,T,S]
@@ -520,45 +538,49 @@ def extend(params, cache, slot, tokens, length, cfg: LlamaConfig):
     def layer_fn(x, kv, layer, i):  # the stacked leaves ride the carry, as in decode_step
         from ray_tpu.llm.kv_quant import quantize_heads
 
-        xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q, k_t, v_t = _qkv(xn, layer, cfg)  # [1, T, nh/nkv, hd]
-        qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)  # [1, nh, T, hd]
-        kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [1, T, nkv, hd]
-        k_suf, v_suf = kh[0], v_t[0]  # [T, nkv, hd]
-        # a scatter by position, not a dynamic_update_slice at `start`: the
-        # chip's compiler updates the carried cache in place for this and
-        # copies the whole of it for that; and a padded tail that runs past
-        # the horizon is dropped where the slice would be clamped and shift
-        # the whole chunk over the prefix (engine._prefix_fits guards that)
-        if quant:
-            k_suf, sk = quantize_heads(k_suf)  # sk: [T, nkv]
-            v_suf, sv = quantize_heads(v_suf)
-            # the index arrays are split by the kv slice: the indexed slots are [T, nkv]
-            kv["k_scale"] = kv["k_scale"].at[i, slot, :, positions].set(sk, mode="drop")
-            kv["v_scale"] = kv["v_scale"].at[i, slot, :, positions].set(sv, mode="drop")
-        kv["k"] = kv["k"].at[i, slot, positions].set(k_suf.astype(kv["k"].dtype), mode="drop")
-        kv["v"] = kv["v"].at[i, slot, positions].set(v_suf.astype(kv["v"].dtype), mode="drop")
-        qg = qh[0].reshape(nkv, rep, T, hd)
-        kc = row_of(kv["k"], i).transpose(1, 0, 2)  # [nkv, S, hd]
-        vc = row_of(kv["v"], i).transpose(1, 0, 2)
-        if quant:
-            kc = kc.astype(jnp.float32) * row_of(kv["k_scale"], i)[..., None]
-            vc = vc.astype(jnp.float32) * row_of(kv["v_scale"], i)[..., None]
-        scores = jnp.einsum("grth,gsh->grts", qg, kc, preferred_element_type=jnp.float32) / jnp.sqrt(hd)
-        scores = jnp.where(attn_ok[0], scores, -jnp.inf)  # [nkv, rep, T, S] vs [1, T, S]
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("grts,gsh->grth", probs, vc.astype(jnp.float32))
-        o = o.transpose(2, 0, 1, 3).reshape(1, T, nh * hd).astype(x.dtype)
-        x = x + jnp.dot(o, layer["wo"])
+        with scope("attn"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            q, k_t, v_t = _qkv(xn, layer, cfg)  # [1, T, nh/nkv, hd]
+            qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)  # [1, nh, T, hd]
+            kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [1, T, nkv, hd]
+            k_suf, v_suf = kh[0], v_t[0]  # [T, nkv, hd]
+            # a scatter by position, not a dynamic_update_slice at `start`: the
+            # chip's compiler updates the carried cache in place for this and
+            # copies the whole of it for that; and a padded tail that runs past
+            # the horizon is dropped where the slice would be clamped and shift
+            # the whole chunk over the prefix (engine._prefix_fits guards that)
+            with scope("cache"):
+                if quant:
+                    k_suf, sk = quantize_heads(k_suf)  # sk: [T, nkv]
+                    v_suf, sv = quantize_heads(v_suf)
+                    # the index arrays are split by the kv slice: the indexed slots are [T, nkv]
+                    kv["k_scale"] = kv["k_scale"].at[i, slot, :, positions].set(sk, mode="drop")
+                    kv["v_scale"] = kv["v_scale"].at[i, slot, :, positions].set(sv, mode="drop")
+                kv["k"] = kv["k"].at[i, slot, positions].set(k_suf.astype(kv["k"].dtype), mode="drop")
+                kv["v"] = kv["v"].at[i, slot, positions].set(v_suf.astype(kv["v"].dtype), mode="drop")
+            qg = qh[0].reshape(nkv, rep, T, hd)
+            kc = row_of(kv["k"], i).transpose(1, 0, 2)  # [nkv, S, hd]
+            vc = row_of(kv["v"], i).transpose(1, 0, 2)
+            if quant:
+                kc = kc.astype(jnp.float32) * row_of(kv["k_scale"], i)[..., None]
+                vc = vc.astype(jnp.float32) * row_of(kv["v_scale"], i)[..., None]
+            scores = jnp.einsum("grth,gsh->grts", qg, kc, preferred_element_type=jnp.float32) / jnp.sqrt(hd)
+            scores = jnp.where(attn_ok[0], scores, -jnp.inf)  # [nkv, rep, T, S] vs [1, T, S]
+            probs = jax.nn.softmax(scores, axis=-1)
+            o = jnp.einsum("grts,gsh->grth", probs, vc.astype(jnp.float32))
+            o = o.transpose(2, 0, 1, 3).reshape(1, T, nh * hd).astype(x.dtype)
+            x = x + jnp.dot(o, layer["wo"])
         x = _mlp(x, layer, cfg)
         return x, kv
 
     x, kv = _scan_layers_carrying_cache(layer_fn, x, params, cache)
-    x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)  # [T, H]
-    x_last = x[jnp.maximum(length - 1, 0)]
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
-    return logits, {**kv, "length": cache["length"].at[slot].set(start + length)}
+    with scope("head"):
+        x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)  # [T, H]
+        x_last = x[jnp.maximum(length - 1, 0)]
+        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
+    with scope("cache"):
+        return logits, {**kv, "length": cache["length"].at[slot].set(start + length)}
 
 
 def decode_attn_paged(params, pool, tables, lengths, tokens, cfg: LlamaConfig, tpc: TpSpec | None = None,
@@ -588,7 +610,8 @@ def decode_attn_paged(params, pool, tables, lengths, tokens, cfg: LlamaConfig, t
     rep = nh // nkv
     quant = "k_scale" in pool
     cos, sin = rotary_embedding(lengths[:, None], cfg.hd, cfg.rope_theta)
-    x = _tp_embed(params["embed"], tokens[:, None], tpc)  # [B, 1, H]
+    with scope("embed"):
+        x = _tp_embed(params["embed"], tokens[:, None], tpc)  # [B, 1, H]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
 
     from ray_tpu.llm.paged_kv import _paged_attn_batch
@@ -599,25 +622,28 @@ def decode_attn_paged(params, pool, tables, lengths, tokens, cfg: LlamaConfig, t
         else:
             layer, k_pool_l, v_pool_l = xs  # [P, page, kv, hd]
             k_sc_l = v_sc_l = None
-        xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q, k_t, v_t = _qkv(xn, layer, cfg)  # [B, 1, nh/nkv, hd]
-        qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
-        kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
-        qg = qh[:, 0].reshape(B, nkv, rep, hd)
-        o = _paged_attn_batch(qg, k_pool_l, v_pool_l, tables, lengths, scale, k_self=kh[:, 0], v_self=v_t[:, 0],
-                              k_scale_l=k_sc_l, v_scale_l=v_sc_l, impl=attn_impl)
-        o = o.reshape(B, 1, nh * hd).astype(x.dtype)
-        x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
+        with scope("attn"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            q, k_t, v_t = _qkv(xn, layer, cfg)  # [B, 1, nh/nkv, hd]
+            qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
+            kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)
+            qg = qh[:, 0].reshape(B, nkv, rep, hd)
+            o = _paged_attn_batch(qg, k_pool_l, v_pool_l, tables, lengths, scale, k_self=kh[:, 0], v_self=v_t[:, 0],
+                                  k_scale_l=k_sc_l, v_scale_l=v_sc_l, impl=attn_impl)
+            o = o.reshape(B, 1, nh * hd).astype(x.dtype)
+            x = x + _tp_reduce(jnp.dot(o, layer["wo"]), tpc)
         x = _mlp(x, layer, cfg, tpc)
         return x, (kh[:, 0], v_t[:, 0])
 
     xs = (params["layers"], pool["k"], pool["v"])
     if quant:
         xs += (pool["k_scale"], pool["v_scale"])
-    x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
-    x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps)
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = _tp_gather_logits(jnp.dot(x, unembed, preferred_element_type=jnp.float32), tpc)
+    with scope("cache"):  # the loop's own work: each layer's new key and value stacked as they leave it
+        x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
+    with scope("head"):
+        x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps)
+        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = _tp_gather_logits(jnp.dot(x, unembed, preferred_element_type=jnp.float32), tpc)
     return logits, k_new, v_new
 
 
@@ -626,6 +652,10 @@ def append_paged(pool, write_page, write_off, k_new, v_new):
     token K/V at (write_page[b], write_off[b]) for every layer. An int8
     pool quantizes here — the append program IS the quantizer, so the
     attention half stays read-only and the aliasing split holds."""
+    return scoped("cache", _append_paged)(pool, write_page, write_off, k_new, v_new)
+
+
+def _append_paged(pool, write_page, write_off, k_new, v_new):
     if "k_scale" in pool:
         from ray_tpu.llm.kv_quant import quantize_heads
 
@@ -649,17 +679,19 @@ def decode_write_targets(tables, lengths, page: int):
     """(write_page [B], write_off [B]) for each slot's next token (trash
     page for rows past the table edge)."""
     B = lengths.shape[0]
-    page_ix = jnp.minimum(lengths // page, tables.shape[1] - 1)
-    write_page = tables[jnp.arange(B, dtype=jnp.int32), page_ix]
-    return write_page, lengths % page
+    with scope("cache"):
+        page_ix = jnp.minimum(lengths // page, tables.shape[1] - 1)
+        write_page = tables[jnp.arange(B, dtype=jnp.int32), page_ix]
+        return write_page, lengths % page
 
 
 def extend_write_targets(table_row, start, T: int, page: int):
     """(write_page [T], write_off [T]) for a suffix chunk at absolute
     positions start..start+T-1."""
     positions = jnp.asarray(start, jnp.int32) + jnp.arange(T, dtype=jnp.int32)
-    page_ix = jnp.minimum(positions // page, table_row.shape[0] - 1)
-    return table_row[page_ix], positions % page
+    with scope("cache"):
+        page_ix = jnp.minimum(positions // page, table_row.shape[0] - 1)
+        return table_row[page_ix], positions % page
 
 
 def decode_step_paged(params, pool, tables, lengths, tokens, cfg: LlamaConfig, attn_impl: str = "xla"):
@@ -687,7 +719,8 @@ def extend_attn_paged(params, pool, table_row, start, tokens, length, cfg: Llama
     start = jnp.asarray(start, jnp.int32)
     positions = start + jnp.arange(T, dtype=jnp.int32)
     cos, sin = rotary_embedding(positions, cfg.hd, cfg.rope_theta)
-    x = jnp.take(params["embed"], tokens[None, :], axis=0)  # [1, T, H]
+    with scope("embed"):
+        x = jnp.take(params["embed"], tokens[None, :], axis=0)  # [1, T, H]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
 
     from ray_tpu.llm.paged_kv import _paged_attn_seq, _paged_attn_seq_batch
@@ -698,32 +731,35 @@ def extend_attn_paged(params, pool, table_row, start, tokens, length, cfg: Llama
         else:
             layer, k_pool_l, v_pool_l = xs
             k_sc_l = v_sc_l = None
-        xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q, k_t, v_t = _qkv(xn, layer, cfg)  # [1, T, nh/nkv, hd]
-        qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)  # [1, nh, T, hd]
-        kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [1, T, nkv, hd]
-        qg = qh[0].reshape(nkv, rep, T, hd)
-        if attn_impl == "pallas":
-            o = _paged_attn_seq_batch(
-                qg[None], k_pool_l, v_pool_l, table_row[None], start[None], kh, v_t, scale,
-                k_scale_l=k_sc_l, v_scale_l=v_sc_l, impl=attn_impl,
-            )[0]
-        else:
-            o = _paged_attn_seq(qg, k_pool_l, v_pool_l, table_row, start, kh[0], v_t[0], scale,
-                                k_scale_l=k_sc_l, v_scale_l=v_sc_l)
-        o = o.transpose(2, 0, 1, 3).reshape(1, T, nh * hd).astype(x.dtype)
-        x = x + jnp.dot(o, layer["wo"])
+        with scope("attn"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            q, k_t, v_t = _qkv(xn, layer, cfg)  # [1, T, nh/nkv, hd]
+            qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)  # [1, nh, T, hd]
+            kh = apply_rope(k_t.transpose(0, 2, 1, 3), cos, sin).transpose(0, 2, 1, 3)  # [1, T, nkv, hd]
+            qg = qh[0].reshape(nkv, rep, T, hd)
+            if attn_impl == "pallas":
+                o = _paged_attn_seq_batch(
+                    qg[None], k_pool_l, v_pool_l, table_row[None], start[None], kh, v_t, scale,
+                    k_scale_l=k_sc_l, v_scale_l=v_sc_l, impl=attn_impl,
+                )[0]
+            else:
+                o = _paged_attn_seq(qg, k_pool_l, v_pool_l, table_row, start, kh[0], v_t[0], scale,
+                                    k_scale_l=k_sc_l, v_scale_l=v_sc_l)
+            o = o.transpose(2, 0, 1, 3).reshape(1, T, nh * hd).astype(x.dtype)
+            x = x + jnp.dot(o, layer["wo"])
         x = _mlp(x, layer, cfg)
         return x, (kh[0], v_t[0])
 
     xs = (params["layers"], pool["k"], pool["v"])
     if quant:
         xs += (pool["k_scale"], pool["v_scale"])
-    x, (k_chunk, v_chunk) = jax.lax.scan(layer_fn, x, xs)
-    x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)  # [T, H]
-    x_last = x[jnp.maximum(length - 1, 0)]
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
+    with scope("cache"):  # the loop's own work, as in ``decode_attn_paged``
+        x, (k_chunk, v_chunk) = jax.lax.scan(layer_fn, x, xs)
+    with scope("head"):
+        x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)  # [T, H]
+        x_last = x[jnp.maximum(length - 1, 0)]
+        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = jnp.dot(x_last, unembed, preferred_element_type=jnp.float32)
     return logits, k_chunk, v_chunk
 
 
@@ -731,6 +767,10 @@ def append_chunk_paged(pool, write_page, write_off, k_chunk, v_chunk):
     """Scatter-only half of paged chunked-prefill: write the suffix K/V
     rows (write_page/write_off: [T]) for every layer. An int8 pool
     quantizes here, exactly as append_paged does for decode."""
+    return scoped("cache", _append_chunk_paged)(pool, write_page, write_off, k_chunk, v_chunk)
+
+
+def _append_chunk_paged(pool, write_page, write_off, k_chunk, v_chunk):
     if "k_scale" in pool:
         from ray_tpu.llm.kv_quant import quantize_heads
 
@@ -1092,7 +1132,7 @@ def make_runner_fns(cfg: LlamaConfig, mesh=None):
     from ray_tpu.llm import kv_cache as kvc
 
     prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
-    insert_fn = named_jit("llm_kv_insert", kvc.insert_sequence, donate_argnums=(0,))
+    insert_fn = named_jit("llm_kv_insert", scoped("cache", kvc.insert_sequence), donate_argnums=(0,))
     decode_fn = named_jit("llm_decode_step", partial(decode_step, cfg=cfg, partitioned=mesh is not None),
                           donate_argnums=(1,))
     extend_fn = named_jit("llm_extend", partial(extend, cfg=cfg), donate_argnums=(1,))
@@ -1110,7 +1150,7 @@ def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla", mesh=None):
     from ray_tpu.llm import paged_kv as pkv
 
     prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
-    insert_fn = named_jit("llm_kv_insert_pages", pkv.insert_pages, donate_argnums=(0,))
+    insert_fn = named_jit("llm_kv_insert_pages", scoped("cache", pkv.insert_pages), donate_argnums=(0,))
     attn_fn = named_jit("llm_paged_attn", partial(decode_attn_paged, cfg=cfg, attn_impl=attn_impl))
     append_fn = named_jit("llm_kv_append", append_paged, donate_argnums=(0,))
     ext_attn_fn = named_jit("llm_extend_paged_attn", partial(extend_attn_paged, cfg=cfg, attn_impl=attn_impl))

@@ -15,6 +15,8 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.util.profiling import scope
+
 
 @dataclass(frozen=True)
 class SamplingParams:
@@ -98,11 +100,12 @@ def filter_logits(logits, temperature, top_k, top_p):
     which branch its neighbours chose. Ties: top-k keeps every token tied
     with the k-th largest, so more than k where the k-th value repeats.
     """
-    lead = logits.shape[:-1]
-    top_k, top_p = jnp.broadcast_to(top_k, lead), jnp.broadcast_to(top_p, lead)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
-    scaled = jax.lax.cond(jnp.any(top_k > 0), _apply_top_k, lambda x, _: x, scaled, top_k)
-    return jax.lax.cond(jnp.any(top_p < 1.0), _apply_top_p, lambda x, _: x, scaled, top_p)
+    with scope("sample"):
+        lead = logits.shape[:-1]
+        top_k, top_p = jnp.broadcast_to(top_k, lead), jnp.broadcast_to(top_p, lead)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
+        scaled = jax.lax.cond(jnp.any(top_k > 0), _apply_top_k, lambda x, _: x, scaled, top_k)
+        return jax.lax.cond(jnp.any(top_p < 1.0), _apply_top_p, lambda x, _: x, scaled, top_p)
 
 
 def sample(logits, key, temperature, top_k, top_p):
@@ -118,16 +121,17 @@ def sample(logits, key, temperature, top_k, top_p):
     v5e, and 0.17 where a lane asks for top-p (PERF.md section 6, PR 32).
     Each row's key advances by its own split, whatever its neighbours do.
     """
-    logits = logits.astype(jnp.float32)
-    greedy_tok = jnp.argmax(logits, axis=-1)
-    scaled = filter_logits(logits, temperature, top_k, top_p)
+    with scope("sample"):
+        logits = logits.astype(jnp.float32)
+        greedy_tok = jnp.argmax(logits, axis=-1)
+        scaled = filter_logits(logits, temperature, top_k, top_p)
 
-    def _one(lg, k):
-        k1, k2 = jax.random.split(jax.random.wrap_key_data(k, impl="threefry2x32"))
-        return jax.random.categorical(k1, lg), jax.random.key_data(k2)
+        def _one(lg, k):
+            k1, k2 = jax.random.split(jax.random.wrap_key_data(k, impl="threefry2x32"))
+            return jax.random.categorical(k1, lg), jax.random.key_data(k2)
 
-    sampled_tok, new_keys = jax.vmap(_one)(scaled, key)
-    tokens = jnp.where(temperature == 0.0, greedy_tok, sampled_tok).astype(jnp.int32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    chosen_logp = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
-    return tokens, chosen_logp, new_keys
+        sampled_tok, new_keys = jax.vmap(_one)(scaled, key)
+        tokens = jnp.where(temperature == 0.0, greedy_tok, sampled_tok).astype(jnp.int32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        chosen_logp = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
+        return tokens, chosen_logp, new_keys

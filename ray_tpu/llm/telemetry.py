@@ -60,6 +60,7 @@ from collections import deque
 from jax.profiler import TraceAnnotation
 
 from ray_tpu.util import tracing
+from ray_tpu.util.profiling import SCOPES
 
 logger = logging.getLogger("ray_tpu.llm")
 
@@ -71,8 +72,13 @@ logger = logging.getLogger("ray_tpu.llm")
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
     "llm.step.prefill": "prefill_ms",
-    # inside llm.step.prefill: a hybrid model's recurrent state written into its slot
+    # inside llm.step.prefill, once a group of the wave: the host from the group's start until its
+    # prefill program and its inserts are dispatched, then the host blocked reading the group's
+    # first tokens back (sums over the wave's groups)
+    "llm.step.prefill.launch": "prefill_launch_ms",
+    # inside llm.step.prefill.launch: a hybrid model's recurrent state written into its slot
     "llm.step.state_insert": "state_insert_ms",
+    "llm.step.prefill.first_tokens": "first_token_wait_ms",
     "llm.step.dispatch": "dispatch_ms",
     "llm.step.drain_wait": "drain_wait_ms",
     "llm.step.emit": "emit_ms",
@@ -81,6 +87,9 @@ STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.stepper.wait": "stepper_wait_ms",
 }
 _STAGE_IX = {name: i for i, name in enumerate(STAGES)}
+# stages timed INSIDE another: their time is in the outer stage's too
+INSIDE = {"llm.step.prefill.launch": "llm.step.prefill", "llm.step.prefill.first_tokens": "llm.step.prefill",
+          "llm.step.state_insert": "llm.step.prefill.launch"}
 
 # time.time() at which the serving ingress took the request now being
 # admitted on this thread/task (OpenAIServer.__call__ sets it, on_submit
@@ -343,9 +352,11 @@ class FlightRecorder:
         "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
         # the step's start and the instant its fused program was enqueued
-        # (both time.time(); dispatch_t absent where none was), then the
-        # stage durations
-        "t0", "dispatch_t",
+        # (both time.time(); dispatch_t absent where none was); of an ADMITTING
+        # step, for each group of its wave [its prefill program enqueued, its
+        # inserts enqueued too (launch's end), its first tokens read back];
+        # then the stage durations
+        "t0", "dispatch_t", "prefill_dispatch_t",
         # a hybrid model's drained decode step (llm/hybrid_runner.MOE_STATS): held experts that
         # got a token (mean over expert layers), (token, expert) pairs served here and asked
         # for in all, most tokens at one expert, held experts whose weights the step read (mean over
@@ -361,7 +372,7 @@ class FlightRecorder:
     ) + tuple(STAGES.values())
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
-    # steps/s, 2,000 requests (about 7 MB of step rows and 11 MB of
+    # steps/s, 2,000 requests (about 8 MB of step rows and 11 MB of
     # request records at 150 tokens each, tests/test_llm_flight.py).
     # Past it the oldest go, and the log's header says how many.
     LOG_STEPS = 12_000
@@ -566,6 +577,7 @@ class EngineTelemetry:
         self._stage_ms = [0.0] * len(STAGES)
         self._step_t0 = 0.0
         self.dispatch_t: float | None = None
+        self.prefill_dispatch_t: list | None = None  # the engine appends a group's three stamps
         # per-step ICI wire bytes of the fused step's collectives: a
         # one-shot jaxpr accounting turned into a LIVE series (counter
         # advanced every dispatched step). 0 on tp=1 engines; computed
@@ -855,8 +867,8 @@ class EngineTelemetry:
         eng = self.engine
         now = time.time()
         wall_ms = (time.perf_counter() - t0) * 1e3
-        stages, dispatch_t = self._stage_ms, self.dispatch_t
-        self._stage_ms, self.dispatch_t = [0.0] * len(STAGES), None
+        stages, dispatch_t, prefill_t = self._stage_ms, self.dispatch_t, self.prefill_dispatch_t
+        self._stage_ms, self.dispatch_t, self.prefill_dispatch_t = [0.0] * len(STAGES), None, None
         slots_in_use = sum(1 for s in eng._slots if s is not None)
         sampling_lanes = sum(1 for s in eng._slots if s is not None and s.params.temperature > 0.0)
         waiting = len(eng._waiting)
@@ -903,7 +915,7 @@ class EngineTelemetry:
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],
-            self._step_t0, dispatch_t, *moe, *[round(ms, 4) for ms in stages],
+            self._step_t0, dispatch_t, prefill_t, *moe, *[round(ms, 4) for ms in stages],
         ))
 
         if slots_in_use and eng._device_resident and self._wire_bytes_per_step:
@@ -958,6 +970,8 @@ class EngineTelemetry:
                 # what the cache holds for a position, and of which entries it is made
                 "kv_bytes_per_token": eng.kv_bytes_per_token(),
                 "kv_entries": eng.kv_entries(),
+                # what the names in a trace of this replica mean: named scope -> role
+                "scopes": dict(SCOPES),
             }
             if error is not None:
                 header["error"] = f"{type(error).__name__}: {error}"
@@ -1009,6 +1023,75 @@ def load_flight(pid: int | None = None) -> dict:
                         out[kind + "s"].append(rec)
         except OSError:
             continue
+    return out
+
+
+def dispatch_stamps(steps: list) -> dict:
+    """{"fused": [...], "prefill": [...]}: the host stamps (time.time()) of every fused step and
+    of every prefill program the rows say were dispatched, in order: what a trace's executions of
+    the programs with that word in their name are set against (``util/profiling.summarize``)."""
+    steps = sorted(steps, key=lambda s: s["t0"])
+    return {"fused": [s["dispatch_t"] for s in steps if s.get("dispatch_t")],
+            "prefill": [g[0] for s in steps for g in s.get("prefill_dispatch_t") or ()]}
+
+
+IN_STEP = "in step, no stage"  # the engine's lock, on_step's own time
+
+
+def timeline(steps: list) -> list[tuple]:
+    """Where the host was, from one replica's step rows: (label, start, end) on time.time()'s
+    clock, in order and without overlap, from the first row's start to the last one's end. A row
+    holds its start (``t0``), the stamp at its end (``t``: on_step's first act) and its stages'
+    durations; the stages run in STAGES' order and end at ``t``, so each one's edges follow by
+    walking back from there, and what is left before the first is the wait for the engine's
+    lock. Inside ``prefill``, a group's launch and first-token wait lie where its stamps
+    (``prefill_dispatch_t``) say, the state inserts (whose sum alone is known) at the launches'
+    ends; what is neither is ``prefill``. Between two steps the stepper's wait ends at the next
+    step's start and its delivery stands before that. Labels: STAGES' names less their prefix."""
+    def label(name):
+        return name.split(".", 2)[2] if name.startswith("llm.step.") else name[len("llm."):]
+
+    out: list[tuple] = []
+
+    def put(what, a, b):
+        if b > a:
+            out.append((what, a, b))
+
+    last_end = None
+    for s in sorted(steps, key=lambda s: s["t0"]):
+        t0, t = s["t0"], max(s["t"], s["t0"])
+        ms = lambda name: float(s.get(STAGES[name]) or 0.0) * 1e-3  # noqa: E731
+        if last_end is not None and t0 > last_end:
+            wait = min(ms("llm.stepper.wait"), t0 - last_end)
+            deliver = min(ms("llm.stepper.deliver"), t0 - wait - last_end)
+            put(IN_STEP, last_end, t0 - wait - deliver)
+            put("stepper.deliver", t0 - wait - deliver, t0 - wait)
+            put("stepper.wait", t0 - wait, t0)
+        t0 = t0 if last_end is None else max(t0, last_end)
+        edges, at = {}, t
+        for name in reversed([n for n in STAGES if n.startswith("llm.step.") and n not in INSIDE]):
+            edges[name] = (max(at - ms(name), t0), at)
+            at = edges[name][0]
+        put(IN_STEP, t0, at)
+        for name, (a, b) in sorted(edges.items(), key=lambda kv: kv[1][0]):
+            groups = s.get("prefill_dispatch_t") if name == "llm.step.prefill" else None
+            if not groups:
+                put(label(name), a, b)
+                continue
+            # launch g = [start, launched], first tokens g = [launched, read], and the next group's launch starts there
+            later = sum(g[1] - h[2] for h, g in zip(groups, groups[1:]))
+            at = min(max(groups[0][1] - max(ms("llm.step.prefill.launch") - later, 0.0), a), b)
+            put("prefill", a, at)
+            insert = ms("llm.step.state_insert") / len(groups)
+            for _, launched, read in groups:
+                launched, read = min(max(launched, at), b), min(max(read, at), b)
+                cut = max(launched - insert, at)
+                put("prefill.launch", at, cut)
+                put("state_insert", cut, launched)
+                put("prefill.first_tokens", launched, read)
+                at = max(read, launched)
+            put("prefill", at, b)
+        last_end = t
     return out
 
 

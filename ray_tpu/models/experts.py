@@ -22,6 +22,11 @@ gated by ``sigmoid(x . w_sg)``. Everything else is one code path:
 - ``moe_seq`` / ``moe_step``: the layer over a padded sequence and for one token
   a lane, each with the routing counters the flight log carries.
 
+In a profile the layer's parts stand under sub-scopes of the layer's own (``moe``):
+``moe.route`` the router and its top k, ``moe.place`` laying the pairs out by expert
+and the gathers into and out of the blocks, ``moe.blocks`` the experts' matmuls (the
+grouped matmul's loop, a step's hit experts), ``moe.shared`` the shared expert.
+
 The layer is told which experts this chip holds (``expert_start``,
 ``local_experts``): the router scores all published experts, this chip computes
 what its own give for the tokens routed to them and adds the shared expert; a
@@ -43,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import step_experts
+from ray_tpu.util.profiling import scope, scoped
 
 # rows of one block of the grouped matmul; pairs beyond this many take blocks twice as tall
 BLOCK, TALL_FROM = 128, 32768
@@ -131,35 +137,39 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
     M, El, H = N * k, s.held, x.shape[-1]
     block = 2 * BLOCK if M >= TALL_FROM else BLOCK
     n_rows = (-(-M // block) + El) * block  # the most that padding to whole blocks can need
-    local = (idx - s.expert_start).reshape(-1)
-    mine = (local >= 0) & (local < El) & jnp.repeat(valid, k)
-    # a pair's place: its rank among the pairs of its expert (a running count, no sort), after
-    # the blocks of the experts before it; what is not ours goes to a spare row that stays zero
-    hot = mine[:, None] & (local[:, None] == jnp.arange(El, dtype=jnp.int32)[None, :])
-    count = jnp.cumsum(hot.astype(jnp.int32), axis=0)
-    sizes = count[-1]
-    blocks_of = (sizes + block - 1) // block
-    last_block = jnp.cumsum(blocks_of)  # one past each expert's last block
-    e_of = jnp.clip(local, 0, El - 1)
-    rank = jnp.take_along_axis(count, e_of[:, None], axis=1)[:, 0] - 1
-    place = jnp.where(mine, (last_block[e_of] - blocks_of[e_of]) * block + rank, n_rows)
-    pair_at = jnp.full((n_rows + 1,), M, jnp.int32).at[place].set(jnp.arange(M, dtype=jnp.int32))
-    scale = jnp.where(mine, wt.reshape(-1), 0.0)
+    with scope("moe.place"):
+        local = (idx - s.expert_start).reshape(-1)
+        mine = (local >= 0) & (local < El) & jnp.repeat(valid, k)
+        # a pair's place: its rank among the pairs of its expert (a running count, no sort), after
+        # the blocks of the experts before it; what is not ours goes to a spare row that stays zero
+        hot = mine[:, None] & (local[:, None] == jnp.arange(El, dtype=jnp.int32)[None, :])
+        count = jnp.cumsum(hot.astype(jnp.int32), axis=0)
+        sizes = count[-1]
+        blocks_of = (sizes + block - 1) // block
+        last_block = jnp.cumsum(blocks_of)  # one past each expert's last block
+        e_of = jnp.clip(local, 0, El - 1)
+        rank = jnp.take_along_axis(count, e_of[:, None], axis=1)[:, 0] - 1
+        place = jnp.where(mine, (last_block[e_of] - blocks_of[e_of]) * block + rank, n_rows)
+        pair_at = jnp.full((n_rows + 1,), M, jnp.int32).at[place].set(jnp.arange(M, dtype=jnp.int32))
+        scale = jnp.where(mine, wt.reshape(-1), 0.0)
     mats = [stacked[n] for n in s.matrices]
 
     def one_block(b, ys):
         e = jnp.sum(last_block <= b).astype(jnp.int32)
-        pair = jax.lax.dynamic_slice_in_dim(pair_at, b * block, block)
-        ok = pair < M  # the padding at the end of an expert's run holds no pair
-        pair = jnp.minimum(pair, M - 1)
-        xb = jnp.where(ok[:, None], jnp.take(x, pair // k, axis=0), 0)
+        with scope("moe.place"):  # the gather into the block
+            pair = jax.lax.dynamic_slice_in_dim(pair_at, b * block, block)
+            ok = pair < M  # the padding at the end of an expert's run holds no pair
+            pair = jnp.minimum(pair, M - 1)
+            xb = jnp.where(ok[:, None], jnp.take(x, pair // k, axis=0), 0)
         *gate, up, down = (jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0] for a in mats)
         yb = jnp.dot(_hidden(s, xb, up, gate[0] if gate else None, "bh,fh->bf"), down)
         yb = (yb * jnp.where(ok, scale[pair], 0.0)[:, None]).astype(ys.dtype)
         return jax.lax.dynamic_update_slice(ys, yb, (b * block, jnp.zeros((), jnp.int32)))
 
-    ys = jax.lax.fori_loop(0, last_block[-1], one_block, jnp.zeros((n_rows + 1, H), x.dtype))
-    out = jnp.sum(jnp.take(ys, place, axis=0).reshape(N, k, H), axis=1, dtype=jnp.float32).astype(x.dtype)
+    with scope("moe.blocks"):
+        ys = jax.lax.fori_loop(0, last_block[-1], one_block, jnp.zeros((n_rows + 1, H), x.dtype))
+    with scope("moe.place"):  # and the gather out of them
+        out = jnp.sum(jnp.take(ys, place, axis=0).reshape(N, k, H), axis=1, dtype=jnp.float32).astype(x.dtype)
     return out, sizes, last_block[-1] * block
 
 
@@ -186,11 +196,12 @@ def moe_seq(w, xn, lengths, c, stacked=None):
     s = c.expert_layer
     B, T, H = xn.shape
     N = B * T
-    idx, wt = route(w, xn.reshape(N, H), c)  # on the norm as it comes
+    idx, wt = scoped("moe.route", route)(w, xn.reshape(N, H), c)  # on the norm as it comes
     x = xn.reshape(N, H).astype(w["w_up"].dtype)
     valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
     if stacked is None:
-        routed, counters = experts_dense(w, x, idx, jnp.where(valid[:, None], wt, 0.0), c), jnp.zeros((3,), jnp.float32)
+        routed = scoped("moe.blocks", experts_dense)(w, x, idx, jnp.where(valid[:, None], wt, 0.0), c)
+        counters = jnp.zeros((3,), jnp.float32)
     else:
         if N > SLAB_ROWS and N % SLAB_ROWS == 0:
             slabs = jax.tree.map(lambda a: a.reshape((N // SLAB_ROWS, SLAB_ROWS) + a.shape[1:]), (x, idx, wt, valid))
@@ -199,7 +210,7 @@ def moe_seq(w, xn, lengths, c, stacked=None):
         else:
             routed, sizes, rows = _grouped(*stacked, x, idx, wt, valid, c)
         counters = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes), rows]).astype(jnp.float32)
-    return (routed + shared_expert(w, x, s)).reshape(B, T, H), counters
+    return (routed + scoped("moe.shared", shared_expert)(w, x, s)).reshape(B, T, H), counters
 
 
 def experts_step(stacked, layer, x, idx, wt, active, c):
@@ -215,16 +226,18 @@ def experts_step(stacked, layer, x, idx, wt, active, c):
     is data does, one expert an iteration."""
     s = c.expert_layer
     El = s.held
-    hot = jax.nn.one_hot(idx - s.expert_start, El, dtype=jnp.float32) * active[:, None, None]
-    comb = jnp.einsum("nke,nk->ne", hot, wt)
-    hit = jnp.sum(hot, axis=(0, 1)) > 0
-    n_hit = jnp.sum(hit, dtype=jnp.int32)
-    # the hit experts' ids, compacted to the front by a running count (no sort)
-    ids = jnp.zeros((El,), jnp.int32).at[jnp.where(hit, jnp.cumsum(hit) - 1, El)].set(jnp.arange(El, dtype=jnp.int32), mode="drop")
+    with scope("moe.place"):
+        hot = jax.nn.one_hot(idx - s.expert_start, El, dtype=jnp.float32) * active[:, None, None]
+        comb = jnp.einsum("nke,nk->ne", hot, wt)
+        hit = jnp.sum(hot, axis=(0, 1)) > 0
+        n_hit = jnp.sum(hit, dtype=jnp.int32)
+        # the hit experts' ids, compacted to the front by a running count (no sort)
+        ids = jnp.zeros((El,), jnp.int32).at[jnp.where(hit, jnp.cumsum(hit) - 1, El)].set(jnp.arange(El, dtype=jnp.int32), mode="drop")
     mats = [stacked[n] for n in s.matrices]
     if step_experts.refusal(mats[0].dtype, x.shape[1], mats[0].shape[2], len(mats)) is None:
         # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
-        return step_experts.hit_experts(mats, layer, x, comb, ids, n_hit, s.act, interpret=jax.default_backend() != "tpu").astype(x.dtype), n_hit
+        with scope("moe.blocks"):
+            return step_experts.hit_experts(mats, layer, x, comb, ids, n_hit, s.act, interpret=jax.default_backend() != "tpu").astype(x.dtype), n_hit
 
     def one_expert(j, acc):
         e = ids[j]
@@ -233,8 +246,9 @@ def experts_step(stacked, layer, x, idx, wt, active, c):
         a = (a * jax.lax.dynamic_slice_in_dim(comb, e, 1, axis=1)).astype(x.dtype)
         return acc + jnp.dot(a, down, preferred_element_type=jnp.float32)
 
-    out = jax.lax.fori_loop(0, n_hit, one_expert, jnp.zeros(x.shape, jnp.float32))
-    return out.astype(x.dtype), n_hit
+    with scope("moe.blocks"):
+        out = jax.lax.fori_loop(0, n_hit, one_expert, jnp.zeros(x.shape, jnp.float32))
+        return out.astype(x.dtype), n_hit
 
 
 def moe_step(w, xn, active, c, stacked):
@@ -243,10 +257,10 @@ def moe_step(w, xn, active, c, stacked):
     here, most tokens at one expert, held experts whose weights the step read] over the active
     lanes, float32)."""
     s = c.expert_layer
-    idx, wt = route(w, xn, c)  # on the norm as it comes
+    idx, wt = scoped("moe.route", route)(w, xn, c)  # on the norm as it comes
     xn = xn.astype(w["w_up"].dtype)
     hot = jax.nn.one_hot(idx - s.expert_start, s.held, dtype=jnp.float32) * active[:, None, None]
     load = jnp.sum(hot, axis=(0, 1))
     routed, read = experts_step(*stacked, xn, idx, wt, active, c)
     stats = jnp.stack([jnp.sum(load > 0).astype(jnp.float32), jnp.sum(load), jnp.max(load), read.astype(jnp.float32)])
-    return routed + shared_expert(w, xn, s), stats
+    return routed + scoped("moe.shared", shared_expert)(w, xn, s), stats

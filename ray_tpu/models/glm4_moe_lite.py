@@ -58,6 +58,7 @@ from ray_tpu.models.nemotron_h import _anchor_routing  # one orthogonal matrix f
 from ray_tpu.ops import slot_attention
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
+from ray_tpu.util.profiling import scope
 
 # rows (batch x padded length) the dense layer takes at once: its two hidden activations are
 # 40 KB a row at the published width, 1.3 GB for a 2 x 16,384 prefill
@@ -256,7 +257,7 @@ def mla_down(w, xn, positions, c: Glm4MoeLiteConfig):
     c_q [B,T,q_lora_rank] after its norm, and what a position keeps: c_kv [B,T,kv_lora_rank] after
     its norm and the one key k_r [B,T,rope_row] after its rotation (zeros after its rope columns),
     with the rotation's (cos, sin)."""
-    with jax.named_scope("mla.down"):
+    with scope("mla.down"):
         c_q = rms_norm(jnp.dot(xn, w["w_qa"]), w["q_norm"], c.rms_eps)
         kva = jnp.dot(xn, w["w_kva"])
         c_kv = rms_norm(kva[..., :c.kv_lora_rank], w["kv_norm"], c.rms_eps)
@@ -280,13 +281,13 @@ def mla_seq(w, xn, c: Glm4MoeLiteConfig, mesh=None):
     B, T, _ = xn.shape
     nh = c.num_heads
     c_q, c_kv, k_r, rope = mla_down(w, xn, jnp.arange(T, dtype=jnp.int32), c)
-    with jax.named_scope("mla.expand"):
+    with scope("mla.expand"):
         q_nope, q_rope = _queries(w, c_q, rope, c)
         k_nope = jnp.einsum("btr,rnd->bntd", c_kv, w["w_kb"].reshape(c.kv_lora_rank, nh, c.qk_nope_head_dim))
         v = jnp.einsum("btr,rnd->bntd", c_kv, w["w_vb"].reshape(c.kv_lora_rank, nh, c.v_head_dim))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r[:, None, :, :c.qk_rope_head_dim], (B, nh, T, c.qk_rope_head_dim))], axis=-1)
-    with jax.named_scope("mla.attn"):
+    with scope("mla.attn"):
         o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl)
     y = jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, nh * c.v_head_dim).astype(xn.dtype), w["wo"])
     return y, c_kv, k_r
@@ -300,14 +301,14 @@ def mla_step(w, xn, cache, ctx, c: Glm4MoeLiteConfig):
     c_q, c_kv, k_r, rope = mla_down(w, xn[:, None], ctx.lengths[:, None], c)
     cache.write("c_kv", c_kv[:, 0])
     cache.write("k_r", k_r[:, 0])
-    with jax.named_scope("mla.expand"):
+    with scope("mla.expand"):
         q_nope, q_rope = _queries(w, c_q, rope, c)  # [B,nh,1,.]
-    with jax.named_scope("mla.absorb"):
+    with scope("mla.absorb"):
         q_lat = jnp.einsum("bnd,rnd->bnr", q_nope[:, :, 0], w["w_kb"].reshape(r, nh, c.qk_nope_head_dim))
-    with jax.named_scope("mla.attn"):
+    with scope("mla.attn"):
         (c_stack, i), (r_stack, _) = cache.stacked("c_kv"), cache.stacked("k_r")
         o_lat = slot_attention.attend_latent(q_lat, q_rope[:, :, 0], c_stack, r_stack, i, ctx.lengths,
                                              scale=c.qk_head_dim ** -0.5, live=ctx.active)  # [B, nh*r] f32
-    with jax.named_scope("mla.expand"):
+    with scope("mla.expand"):
         o = jnp.einsum("bnr,rnd->bnd", o_lat.reshape(B, nh, r).astype(xn.dtype), w["w_vb"].reshape(r, nh, c.v_head_dim))
     return jnp.dot(o.reshape(B, nh * c.v_head_dim), w["wo"])

@@ -6,7 +6,9 @@ file states WHAT its layers are; this file walks them. A description
 (``HybridDescription``, mixed into the model's config dataclass) says:
 
 - ``layer_kinds``: the kind of every sub-block, in order;
-- ``mixers``: kind -> ``Mixer``: the named scope of that kind in a profile, its
+- ``mixers``: kind -> ``Mixer``: the named scope of that kind in a profile (a name of
+  ``util/profiling.SCOPES``, which says what role the kind plays; a name outside it raises
+  where the program is traced), its
   two forms (``seq`` over a padded sequence with true lengths; ``step`` for one
   token a lane against cached state), and whether it routes tokens to experts;
 - ``cache_spec()``: kind -> {name: (shape, dtype, "position" | "sequence")}: what
@@ -48,6 +50,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops import slot_attention
 from ray_tpu.ops.layers import cross_entropy_loss
+from ray_tpu.util.profiling import scope
 
 # what a routing layer's sequence form reports beside its output, as one more thing it "keeps":
 # float32 [3], see ``models/experts.moe_seq``
@@ -167,7 +170,7 @@ def run_layers(config, params, x, carry, layer_fn):
     per, seen = Counter(period), Counter()
 
     def apply(kind, i, x, carry):
-        with jax.named_scope(config.mixers[kind].scope):
+        with scope(config.mixers[kind].scope):
             return layer_fn(kind, _layer_weights(params, kind, i), i, x, carry)
 
     def unrolled(kinds, x, carry):
@@ -187,7 +190,8 @@ def run_layers(config, params, x, carry, layer_fn):
     x, carry = unrolled(head, x, carry)
     first = dict(seen)  # the layers of each kind that stand before the period
     if repeats:
-        (x, carry), _ = jax.lax.scan(block, (x, carry), jnp.arange(repeats, dtype=jnp.int32))
+        with scope("cache"):  # the loop's own work is on its carry, the caches; a layer's stands under its kind's scope
+            (x, carry), _ = jax.lax.scan(block, (x, carry), jnp.arange(repeats, dtype=jnp.int32))
     seen.update({k: repeats * n for k, n in per.items()})
     return unrolled(tail, x, carry)
 
@@ -212,7 +216,7 @@ def scan_layers(config, params, x, layer_fn, empty):
 
     def branch(kind):
         def run(i, x):
-            with jax.named_scope(config.mixers[kind].scope):
+            with scope(config.mixers[kind].scope):
                 x, kept = layer_fn(kind, _layer_weights(params, kind, i), i, x)
             return x, {n: kept[n].astype(z.dtype) if n in kept else z for n, z in empty.items()}
         return run
@@ -222,10 +226,12 @@ def scan_layers(config, params, x, layer_fn, empty):
     def body(xo, ki):
         x, out = xo
         x, kept = jax.lax.switch(ki[0], branches, ki[1], x)
-        return (x, {n: jax.lax.dynamic_update_index_in_dim(out[n], kept[n], ki[2][n], 0) for n in out}), None
+        with scope("cache"):
+            return (x, {n: jax.lax.dynamic_update_index_in_dim(out[n], kept[n], ki[2][n], 0) for n in out}), None
 
     out = {n: jnp.zeros((len(keepers[n]) + 1,) + z.shape, z.dtype) for n, z in empty.items()}
-    (x, out), _ = jax.lax.scan(jax.checkpoint(body) if config.remat else body, (x, out), (which, among, row))
+    with scope("cache"):  # the loop's own work (what it copies of its carry is what the layers keep); a layer's stands under its kind's scope
+        (x, out), _ = jax.lax.scan(jax.checkpoint(body) if config.remat else body, (x, out), (which, among, row))
     return x, {n: a[:-1] for n, a in out.items()}
 
 
@@ -237,7 +243,8 @@ class LayerCache:
     for a per-position entry whose rows [B, S, *shape] an op reads in part (``attend_slot``;
     ``read`` would slice all S positions of every lane out). All act on the stacked arrays in
     place (a dynamic slice of, a scatter or an update into the donated carry); the arrays as
-    they stand afterwards are ``arrays``."""
+    they stand afterwards are ``arrays``. A position's write is scoped ``cache``; a per-sequence
+    entry's stays in its caller's scope (a recurrent state's in ``<kind>.state``)."""
 
     def __init__(self, arrays: dict, per_position: frozenset, i, lanes, pos):
         self.arrays, self._per_position, self._i, self._lanes, self._pos = dict(arrays), per_position, i, lanes, pos
@@ -253,7 +260,8 @@ class LayerCache:
     def write(self, name: str, value) -> None:
         a = self.arrays[name]
         if name in self._per_position:
-            self.arrays[name] = a.at[self._i, self._lanes, self._pos].set(value.astype(a.dtype))
+            with scope("cache"):
+                self.arrays[name] = a.at[self._i, self._lanes, self._pos].set(value.astype(a.dtype))
         else:
             self.arrays[name] = jax.lax.dynamic_update_index_in_dim(a, value.astype(a.dtype), self._i, 0)
 
@@ -292,7 +300,8 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
     c = config
     B, T = tokens.shape
     dt, sd = params["embed"].dtype, c.stream_dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
+    with scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
     empty = {}
     if collect:
         for spec in c.cache_spec().values():
@@ -307,19 +316,23 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
         return x + y.astype(sd), kept if collect else {}
 
     x, out = scan_layers(c, params, x, layer, empty)
-    return c.norm(x, params["final_norm"]).astype(dt), out
+    with scope("head"):
+        return c.norm(x, params["final_norm"]).astype(dt), out
 
 
 def forward(params, tokens, config, mesh=None):
     """tokens [B,T] -> logits [B,T,vocab] f32, every position real."""
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _ = forward_hidden(params, tokens, lengths, config, mesh)
-    return jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
+    with scope("head"):
+        return jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
 
 
 def loss_fn(params, batch, config, mesh=None):
     """batch: {tokens [B,T], targets [B,T] (-100 = ignore)} -> scalar loss."""
-    return cross_entropy_loss(forward(params, batch["tokens"], config, mesh=mesh), batch["targets"])
+    logits = forward(params, batch["tokens"], config, mesh=mesh)
+    with scope("head"):
+        return cross_entropy_loss(logits, batch["targets"])
 
 
 def trace_description():

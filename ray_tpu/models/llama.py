@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, cross_entropy_loss, rms_norm, rotary_embedding
+from ray_tpu.util.profiling import scope, scoped
 
 
 @dataclass(frozen=True)
@@ -136,7 +137,7 @@ def init_params(config: LlamaConfig, key) -> dict:
     return params
 
 
-def _attention_block(x, layer, config: LlamaConfig, cos, sin, positions, mesh=None):
+def _attention(x, layer, config: LlamaConfig, cos, sin, positions, mesh=None):
     B, T, H = x.shape
     nh, nkv, hd = config.num_heads, config.num_kv_heads, config.hd
     xn = rms_norm(x, layer["attn_norm"], config.rms_eps)
@@ -161,11 +162,16 @@ def _attention_block(x, layer, config: LlamaConfig, cos, sin, positions, mesh=No
     return x + jnp.dot(o, layer["wo"])
 
 
-def _mlp_block(x, layer, config: LlamaConfig):
+def _mlp(x, layer, config: LlamaConfig):
     xn = rms_norm(x, layer["mlp_norm"], config.rms_eps)
     g = jnp.dot(xn, layer["w_gate"])
     u = jnp.dot(xn, layer["w_up"])
     return x + jnp.dot(jax.nn.silu(g) * u, layer["w_down"])
+
+
+# the block's two halves under the scopes the serving step programs give them (``llm/model_runner.py``)
+_attention_block = scoped("attn", _attention)
+_mlp_block = scoped("mlp", _mlp)
 
 
 def _layer_fn(x, layer, config: LlamaConfig, cos, sin, positions, mesh=None):
@@ -180,7 +186,8 @@ def forward(params: dict, tokens, config: LlamaConfig, positions=None, mesh=None
     if positions is None:
         positions = jnp.arange(T, dtype=jnp.int32)
     cos, sin = rotary_embedding(positions, config.hd, config.rope_theta, dtype=jnp.float32)
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
 
     layer_fn = partial(_layer_fn, config=config, cos=cos, sin=sin, positions=positions, mesh=mesh)
     if config.remat:
@@ -198,15 +205,17 @@ def forward(params: dict, tokens, config: LlamaConfig, positions=None, mesh=None
             layer = jax.tree.map(lambda p: p[i], params["layers"])
             x = layer_fn(x, layer)
 
-    x = rms_norm(x, params["final_norm"], config.rms_eps)
-    unembed = params["embed"].T if config.tie_embeddings else params["unembed"]
-    return jnp.dot(x, unembed, preferred_element_type=jnp.float32)
+    with scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_eps)
+        unembed = params["embed"].T if config.tie_embeddings else params["unembed"]
+        return jnp.dot(x, unembed, preferred_element_type=jnp.float32)
 
 
 def loss_fn(params, batch, config: LlamaConfig, mesh=None):
     """batch: {tokens [B,T], targets [B,T] (-100 = ignore)} -> scalar loss."""
     logits = forward(params, batch["tokens"], config, mesh=mesh)
-    return cross_entropy_loss(logits, batch["targets"])
+    with scope("head"):
+        return cross_entropy_loss(logits, batch["targets"])
 
 
 def flops_per_token(config: LlamaConfig, seq_len: int | None = None) -> float:

@@ -42,6 +42,7 @@ from ray_tpu.models.experts import ExpertLayer, experts_dense, experts_grouped, 
 from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import rms_norm
+from ray_tpu.util.profiling import scope
 
 # pattern character -> (parameter group, named scope in a profile)
 KINDS = {"M": ("mamba", "mamba2"), "E": ("moe", "moe"), "*": ("attn", "attn")}
@@ -117,8 +118,11 @@ class NemotronHConfig(HybridDescription):
             return y, {"ssm": ssm, "conv": conv}
 
         def mamba_step(w, xn, cache, ctx):
-            y, ssm, conv = mamba2_step(w, xn.astype(dt), cache.read("ssm"), cache.read("conv"), self)
-            cache.write("ssm", ssm)
+            with scope("mamba2.state"):  # the state's read here, its decay and write in ``mamba2_step``, its way back below
+                ssm = cache.read("ssm")
+            y, ssm, conv = mamba2_step(w, xn.astype(dt), ssm, cache.read("conv"), self)
+            with scope("mamba2.state"):
+                cache.write("ssm", ssm)
             cache.write("conv", conv)
             return y, None
 
@@ -391,9 +395,10 @@ def mamba2_step(w, xn, ssm, conv, c: NemotronHConfig):
     window = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)], axis=1)  # [B,K,C]
     out = jnp.sum(window.astype(jnp.float32) * w["conv_w"].astype(jnp.float32), axis=1) + w["conv_b"].astype(jnp.float32)
     x, Bm, Cm, dt, A = _mamba_ssm_inputs(w, out, dt, c)
-    Bm, Cm = (jnp.repeat(a, c.mamba_num_heads // c.n_groups, axis=1)[:, :, None, :] for a in (Bm, Cm))  # [B,nh,1,N]
-    ssm = ssm * jnp.exp(dt * A)[..., None, None] + (x * dt[..., None])[..., None] * Bm
-    y = jnp.sum(ssm * Cm, axis=-1) + x * w["D"][:, None]
+    with scope("mamba2.state"):
+        Bm, Cm = (jnp.repeat(a, c.mamba_num_heads // c.n_groups, axis=1)[:, :, None, :] for a in (Bm, Cm))  # [B,nh,1,N]
+        ssm = ssm * jnp.exp(dt * A)[..., None, None] + (x * dt[..., None])[..., None] * Bm
+        y = jnp.sum(ssm * Cm, axis=-1) + x * w["D"][:, None]
     return _mamba_out(w, y, z, c, xn.dtype), ssm, window[:, 1:]
 
 

@@ -55,6 +55,7 @@ from ray_tpu.models.experts import ExpertLayer
 from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rotary_embedding
+from ray_tpu.util.profiling import scope
 
 SCOPES = {"gdn": "gdn", "attn": "gated_attn", "moe": "moe"}
 # positions (batch x padded length) a DeltaNet layer takes through the chunked rule at once: a
@@ -131,8 +132,11 @@ class Qwen3NextConfig(HybridDescription):
             return y, {"S": S, "conv": conv}
 
         def rule_step(w, xn, cache, ctx):
-            y, S, conv = gdn_step(w, xn.astype(dt), cache.read("S"), cache.read("conv"), self)
-            cache.write("S", S)
+            with scope("gdn.state"):  # the state's read here, its decay and write in ``gdn_step``, its way back below
+                S = cache.read("S")
+            y, S, conv = gdn_step(w, xn.astype(dt), S, cache.read("conv"), self)
+            with scope("gdn.state"):
+                cache.write("S", S)
             cache.write("conv", conv)
             return y, None
 
@@ -372,24 +376,25 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None):
     nc = (T + pad) // C
     q, k = q.reshape(B, nc, C, G, K), k.reshape(B, nc, C, G, K)
     v, g, beta = v.reshape(B, nc, C, G, R, V), g.reshape(B, nc, C, G, R), beta.reshape(B, nc, C, G, R)
-    gc = jnp.cumsum(g, axis=2)  # log of the decay since the chunk's start, <= 0
-    seg = jnp.moveaxis(gc[:, :, :, None] - gc[:, :, None, :], (2, 3), (4, 5))  # [B,nc,G,R,t,s]: log gamma_t / gamma_s
-    at_or_before = jnp.tril(jnp.ones((C, C), bool))
-    decay = jnp.where(at_or_before, jnp.exp(jnp.where(at_or_before, seg, 0.0)), 0.0)
-    kk = es("bctgk,bcsgk->bcgts", k, k)[:, :, :, None]  # [B,nc,G,1,t,s]
-    beta_t = jnp.moveaxis(beta, 2, 4)  # [B,nc,G,R,t]
-    A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1), beta_t[..., None] * decay * kk, 0.0)
-    # (I + A)^-1, float32: A^C = 0, so the product below ends after log2(C) factors
-    inv, power = jnp.eye(C, dtype=jnp.float32) - A, A
-    for _ in range(max(0, math.ceil(math.log2(C)) - 1)):
-        power = jnp.einsum("...ts,...su->...tu", power, power, precision=hi)
-        inv = inv + jnp.einsum("...ts,...su->...tu", inv, power, precision=hi)
-    w_v = es("bcgrts,bcsgrv->bcgrtv", inv, v * beta[..., None])
-    w_k = es("bcgrts,bcsgrk->bcgrtk", inv, k[:, :, :, :, None] * (beta * jnp.exp(gc))[..., None])
-    qk = es("bctgk,bcsgk->bcgts", q, k)[:, :, :, None] * decay  # [B,nc,G,R,t,s], s <= t
-    q_in = q[:, :, :, :, None] * jnp.exp(gc)[..., None]  # [B,nc,C,G,R,K]: gamma_t q_t
-    k_out = k[:, :, :, :, None] * jnp.exp(gc[:, :, -1:] - gc)[..., None]  # gamma_C / gamma_s k_s
-    whole = jnp.exp(gc[:, :, -1])  # [B,nc,G,R]
+    with scope("gdn.chunk"):  # what runs on all chunks at once, the triangular inverse included
+        gc = jnp.cumsum(g, axis=2)  # log of the decay since the chunk's start, <= 0
+        seg = jnp.moveaxis(gc[:, :, :, None] - gc[:, :, None, :], (2, 3), (4, 5))  # [B,nc,G,R,t,s]: log gamma_t / gamma_s
+        at_or_before = jnp.tril(jnp.ones((C, C), bool))
+        decay = jnp.where(at_or_before, jnp.exp(jnp.where(at_or_before, seg, 0.0)), 0.0)
+        kk = es("bctgk,bcsgk->bcgts", k, k)[:, :, :, None]  # [B,nc,G,1,t,s]
+        beta_t = jnp.moveaxis(beta, 2, 4)  # [B,nc,G,R,t]
+        A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1), beta_t[..., None] * decay * kk, 0.0)
+        # (I + A)^-1, float32: A^C = 0, so the product below ends after log2(C) factors
+        inv, power = jnp.eye(C, dtype=jnp.float32) - A, A
+        for _ in range(max(0, math.ceil(math.log2(C)) - 1)):
+            power = jnp.einsum("...ts,...su->...tu", power, power, precision=hi)
+            inv = inv + jnp.einsum("...ts,...su->...tu", inv, power, precision=hi)
+        w_v = es("bcgrts,bcsgrv->bcgrtv", inv, v * beta[..., None])
+        w_k = es("bcgrts,bcsgrk->bcgrtk", inv, k[:, :, :, :, None] * (beta * jnp.exp(gc))[..., None])
+        qk = es("bctgk,bcsgk->bcgts", q, k)[:, :, :, None] * decay  # [B,nc,G,R,t,s], s <= t
+        q_in = q[:, :, :, :, None] * jnp.exp(gc)[..., None]  # [B,nc,C,G,R,K]: gamma_t q_t
+        k_out = k[:, :, :, :, None] * jnp.exp(gc[:, :, -1:] - gc)[..., None]  # gamma_C / gamma_s k_s
+        whole = jnp.exp(gc[:, :, -1])  # [B,nc,G,R]
 
     def pass_on(S, chunk_):
         w_v_c, w_k_c, qk_c, q_c, k_c, whole_c = chunk_
@@ -397,8 +402,9 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None):
         o = es("btgrk,bgrkv->btgrv", q_c, S) + es("bgrts,bgrsv->btgrv", qk_c, u)
         return S * whole_c[..., None, None] + es("bsgrk,bgrsv->bgrkv", k_c, u), o
 
-    per_chunk = tuple(jnp.moveaxis(a, 1, 0) for a in (w_v, w_k, qk, q_in, k_out, whole))
-    S_end, o = jax.lax.scan(pass_on, jnp.zeros((B, G, R, K, V), jnp.float32), per_chunk)
+    with scope("gdn.scan"):  # the state passed from chunk to chunk
+        per_chunk = tuple(jnp.moveaxis(a, 1, 0) for a in (w_v, w_k, qk, q_in, k_out, whole))
+        S_end, o = jax.lax.scan(pass_on, jnp.zeros((B, G, R, K, V), jnp.float32), per_chunk)
     return jnp.moveaxis(o, 0, 1).reshape(B, nc * C, G, R, V)[:, :T], S_end
 
 
@@ -440,11 +446,12 @@ def gdn_step(w, xn, S, conv, c: Qwen3NextConfig):
     window = jnp.concatenate([conv, mixed[:, None].astype(conv.dtype)], axis=1)  # [B,K,C]
     out = jnp.sum(window.astype(jnp.float32) * w["conv_w"].astype(jnp.float32), axis=1)
     q, k, v, beta, g = _gdn_inputs(w, out, ba, c)
-    q, k = (jnp.repeat(a, R, axis=1)[..., None] for a in (q, k))  # [B,nv,dk,1]: a key head serves R value heads
-    S = S * jnp.exp(g)[..., None, None]
-    u = beta[..., None] * (v - jnp.sum(S * k, axis=-2))
-    S = S + k * u[..., None, :]
-    o = jnp.sum(S * q, axis=-2)
+    with scope("gdn.state"):
+        q, k = (jnp.repeat(a, R, axis=1)[..., None] for a in (q, k))  # [B,nv,dk,1]: a key head serves R value heads
+        S = S * jnp.exp(g)[..., None, None]
+        u = beta[..., None] * (v - jnp.sum(S * k, axis=-2))
+        S = S + k * u[..., None, :]
+        o = jnp.sum(S * q, axis=-2)
     return _gdn_out(w, o, z.reshape(o.shape), c, xn.dtype), S, window[:, 1:]
 
 

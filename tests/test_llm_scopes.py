@@ -1,0 +1,202 @@
+"""The scope vocabulary is whole (PR 39): for a toy configuration of each of the four
+descriptions, every step program an engine builds and runs, lowered on the CPU, has every
+``dot_general``, convolution, custom call, scatter, gather and ``dynamic_update_slice`` under a
+scope of ``util/profiling.SCOPES``; and a scope outside the table raises where it is traced."""
+
+import os
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from ray_tpu.llm import LLMEngine, SamplingParams, hybrid_runner, model_runner  # noqa: E402
+from ray_tpu.llm.model_runner import STEP_PROGRAM_NAMES  # noqa: E402
+from ray_tpu.models import hybrid  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.util import profiling  # noqa: E402
+from ray_tpu.util.profiling import SCOPES, UNSCOPED, scope, scope_of  # noqa: E402
+
+HEAVY = ("stablehlo.dot_general", "stablehlo.convolution", "stablehlo.custom_call", "stablehlo.scatter",
+         "stablehlo.gather", "stablehlo.dynamic_update_slice")
+
+SLOTS = ["llm_prefill", "llm_kv_insert", "llm_fused_step", "llm_extend", "llm_decode_step"]
+PAGED = ["llm_kv_insert_pages", "llm_fused_paged_step", "llm_kv_append", "llm_paged_attn", "llm_extend_paged_attn",
+         "llm_kv_append_chunk"]
+HYBRID = ["llm_hybrid_prefill", "llm_kv_insert", "llm_state_insert", "llm_hybrid_fused_step", "llm_hybrid_decode_step"]
+PROGRAMS = {"llama": SLOTS, "llama_paged": PAGED, "nemotron_h": HYBRID, "qwen3_next": HYBRID,
+            "glm4_moe_lite": [p for p in HYBRID if p != "llm_state_insert"]}  # latent attention keeps nothing per sequence
+
+
+class Recording:
+    """A step program that keeps the lowering of its first call."""
+
+    def __init__(self, name, jitted, sink):
+        self._name, self._jitted, self._sink = name, jitted, sink
+
+    def __call__(self, *args, **kwargs):
+        if self._name not in self._sink:
+            self._sink[self._name] = self._jitted.lower(*args, **kwargs)
+        return self._jitted(*args, **kwargs)
+
+    def __getattr__(self, attr):
+        return getattr(self._jitted, attr)
+
+
+def _config(description):
+    if description.startswith("llama"):
+        return LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
+    if description == "nemotron_h":
+        from ray_tpu.models.nemotron_h import NemotronHConfig
+
+        return NemotronHConfig.tiny(num_local_experts=4)
+    if description == "qwen3_next":
+        from ray_tpu.models.qwen3_next import Qwen3NextConfig
+
+        return Qwen3NextConfig.tiny(num_local_experts=4)
+    from ray_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
+
+    return Glm4MoeLiteConfig.tiny()
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """description -> {program name: its lowering}, each description's engines run once."""
+    done: dict = {}
+
+    def of(description):
+        if description in done:
+            return done[description]
+        sink: dict = {}
+        real = model_runner.named_jit
+
+        def recording(name, fn, **kw):
+            return Recording(name, real(name, fn, **kw), sink)
+
+        patch = pytest.MonkeyPatch()
+        patch.setattr(model_runner, "named_jit", recording)
+        patch.setattr(hybrid_runner, "named_jit", recording)
+        try:
+            cfg = _config(description)
+            kw = {"max_num_seqs": 2, "max_seq_len": 128, "prefill_buckets": (16, 32, 64)}
+            if description == "llama_paged":
+                kw.update(kv_layout="paged", page_size=16)
+            hybrid_model = hasattr(cfg, "layer_kinds")
+            if not hybrid_model:
+                kw.update(enable_prefix_caching=True, prefix_block=16)
+            prompt = list(np.random.default_rng(0).integers(1, cfg.vocab_size - 1, size=40))
+            sp = SamplingParams(max_tokens=3)
+            for device_resident in (True, False):
+                eng = LLMEngine(cfg, device_resident=device_resident, **kw)
+                eng.generate([prompt, prompt[:9]], sp)
+                if not hybrid_model:
+                    eng.generate([prompt[:33] + [5, 6, 7]], sp)  # a cached prefix: the suffix goes through extend
+        finally:
+            patch.undo()
+        done[description] = sink
+        return sink
+
+    return of
+
+
+def _name(op) -> str:
+    """The name at the head of an operation's location: its path of scopes, or '' where it has none."""
+    text = str(op.location)
+    return text[5:text.index('"', 5)] if text.startswith('loc("') else ""
+
+
+def unscoped_ops(lowering) -> list[str]:
+    """The heavy operations of a lowered program that stand under no scope of the table. An
+    operation inside a private function (a jitted helper, a loop's body) carries the path from
+    that function's start: it counts as scoped where its own path holds a table name or every
+    call of its function, up to the program's entry, stands under one."""
+    from jax._src.lib.mlir import ir
+
+    module = lowering.compiler_ir()
+    heavy, calls, public = [], {}, set()
+    for func in module.body.operations:
+        fname = str(func.attributes["sym_name"]).strip('"')
+        if "sym_visibility" not in func.attributes or "private" not in str(func.attributes["sym_visibility"]):
+            public.add(fname)
+
+        def visit(op, fname=fname):
+            kind = op.operation.name
+            if kind in HEAVY:
+                heavy.append((fname, kind, _name(op)))
+            elif kind == "func.call":
+                calls.setdefault(str(op.attributes["callee"]).lstrip("@"), []).append((fname, _name(op)))
+            return ir.WalkResult.ADVANCE
+
+        func.operation.walk(visit)
+
+    seen: dict = {}
+
+    def covered(fname) -> bool:
+        if fname in public or fname not in calls:
+            return False
+        if fname not in seen:
+            seen[fname] = False  # a cycle cannot cover itself
+            seen[fname] = all(scope_of(path) != UNSCOPED or covered(caller) for caller, path in calls[fname])
+        return seen[fname]
+
+    return [f"{kind} at {path!r} in @{fname}" for fname, kind, path in heavy if scope_of(path) == UNSCOPED and not covered(fname)]
+
+
+@pytest.mark.parametrize("description,program", [(d, p) for d, ps in PROGRAMS.items() for p in ps])
+def test_every_heavy_operation_of_a_step_program_stands_under_a_table_scope(lowered, description, program):
+    programs = lowered(description)
+    assert program in programs and program in STEP_PROGRAM_NAMES, f"{description} never ran {program}: it ran {sorted(programs)}"
+    assert unscoped_ops(programs[program]) == []
+
+
+@pytest.mark.parametrize("description", sorted(PROGRAMS))
+def test_the_engine_ran_no_step_program_the_cases_above_leave_out(lowered, description):
+    assert set(lowered(description)) <= set(PROGRAMS[description]) | {"llm_prefill"}  # the paged engine prefills by the slot program
+
+
+def test_the_training_step_shares_the_blocks_scopes():
+    """``models/llama.py``'s block is the one the trainer differentiates: forward and backward
+    stand under ``attn`` and ``mlp``."""
+    from ray_tpu.models import llama
+
+    cfg = LlamaConfig.tiny(dtype="float32", remat=False)
+    params = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 16), "int32"), "targets": jax.ShapeDtypeStruct((2, 16), "int32")}
+    lowering = jax.jit(jax.grad(lambda p, b: llama.loss_fn(p, b, cfg))).lower(params, batch)
+    # what stays outside: the layer scan stacking what the backward pass needs (``jvp()/while/body``): autodiff's, not the block's
+    assert [u for u in unscoped_ops(lowering) if "dynamic_update_slice at 'jit(<lambda>)/jvp()/while/body" not in u
+            and "dynamic_update_slice at 'jit(<lambda>)/transpose(jvp())/while/body" not in u] == []
+    # where the transformations wrap a scope's name instead of the path around it, the name is read through them
+    assert scope_of("jit(step)/jit(main)/jvp(mlp)/dot_general") == "mlp"
+    assert scope_of("jit(step)/jit(main)/transpose(jvp(attn))/dot_general") == "attn"
+
+
+def test_a_scope_outside_the_table_raises_where_it_is_traced():
+    with pytest.raises(ValueError, match="not a documented scope"):
+        scope("mystery")
+    from ray_tpu.models.nemotron_h import NemotronHConfig
+
+    cfg = NemotronHConfig.tiny()
+
+    class Renamed(type(cfg)):
+        @property
+        def mixers(self):
+            return {k: m._replace(scope="mamba3") if k == "mamba" else m for k, m in super().mixers.items()}
+
+    bad = Renamed(**{f: getattr(cfg, f) for f in cfg.__dataclass_fields__})
+    params = jax.eval_shape(lambda: bad.init_params(jax.random.PRNGKey(0)))
+    with pytest.raises(ValueError, match="'mamba3' is not a documented scope"):
+        jax.eval_shape(lambda p: hybrid.forward(p, jax.numpy.zeros((1, 8), "int32"), bad), params)
+
+
+def test_the_table_says_a_role_for_every_name_and_sub_scopes_name_their_kind():
+    assert set(SCOPES.values()) == {"mixer", "ffn", "state", "embed", "head", "sample", "cache"}
+    for name in SCOPES:
+        if "." in name:
+            assert name.split(".")[0] in SCOPES and profiling.under(name, name.split(".")[0])
+    assert scope_of("jit(llm_hybrid_prefill)/jit(main)/while/body/cond/branch_1_fun/moe/moe.blocks/while/body/dot_general") == "moe.blocks"
+    assert scope_of("jit(llm_fused_step)/jit(main)/while/body/attn/cache/scatter") == "cache"
+    assert scope_of("jit(set_lane)/jit(main)/scatter") == UNSCOPED
+    assert model_runner.SCOPES is SCOPES  # the table a reader finds beside STEP_PROGRAM_NAMES

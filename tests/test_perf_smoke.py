@@ -198,27 +198,32 @@ def test_llm_pallas_interpret_step_within_sane_multiple():
     )
 
 
-def test_llm_telemetry_zero_overhead_gate():
-    """ISSUE 10 acceptance: the instrumented device-resident decode step
-    stays <= 1.05x the uninstrumented one (interleaved rounds, >= the
-    gate's best-of-3, so load jitter degrades both modes alike).
-    Telemetry is host-side only — a tuple append into the flight ring,
-    pre-bound metric handles, gauges sampled every 16th step — and must
-    never force a device readback; a regression here means
-    instrumentation leaked into the hot path (a per-step sync, a
-    per-token device->host pull, an unbounded per-step allocation).
+def test_llm_telemetry_zero_overhead_gate(monkeypatch):
+    """ISSUE 10 acceptance: being observed costs the device-resident decode step at most 5% of
+    its own time, and nothing of it reads a device value.
 
-    Methodology notes, learned the hard way on a loaded 2-core CI box:
-    ONE engine with `_tel` toggled between rounds (two engines compare
-    independent jit caches, whose layout luck alone exceeds 5%), a
-    SERVING-SCALE model (~tens of ms/step, the regime the claim is
-    about: the fixed ~0.1 ms host cost must be small RELATIVE to a real
-    step — on the micro tiny-model step the same cost is ~4% and the
-    gate measures box noise instead), and per-mode BEST (min) over the
-    interleaved rounds — each mode's least-contended pass; medians drag
-    in whole-round scheduler/memory-pressure swings that dwarf 5%."""
+    Judged by the telemetry's OWN host time a step: every entry point the engine calls
+    (``begin_step``, ``on_step``, ``on_emit``, ``on_bind``, ``on_submit``, ``on_finish``, and each
+    ``stage()``'s construction, entry and exit, less its body) is timed where it runs, summed
+    over a round and divided by the round's steps. Until PR 39 the gate compared best-of-rounds
+    wall clock of an instrumented engine with a plain one: a ratio of two times of tens of
+    milliseconds, each of which a loaded box swings by more than the 5% asked of their ratio
+    (six xdist workers: it failed on the tree that stood). The time inside the calls is a
+    thousandth of the step's and swings with it, not against it. Budget: 0.5 ms a step in
+    absolute terms (a tuple append, pre-bound metric handles, gauges every 16th step, nine
+    stamped stages: some 0.1 ms on an idle box) and 5% of the round's own step time, on the
+    best of three rounds (one preempted call does not fail the gate, a per-step sync or a
+    per-token pull does: either costs every step of every round).
+
+    A model whose step takes milliseconds (6 ms here, 0.10 ms of it the telemetry's, my run,
+    PR 39; 9 and 0.14-0.18 with six busy processes beside it): on the micro tiny-model step the
+    same fixed cost is several percent."""
     pytest.importorskip("jax")
+    import jax
+
     from ray_tpu.llm import LLMEngine, SamplingParams
+    from ray_tpu.llm import engine as engine_module
+    from ray_tpu.llm import telemetry
     from ray_tpu.models.llama import LlamaConfig
 
     cfg = LlamaConfig(
@@ -231,35 +236,45 @@ def test_llm_telemetry_zero_overhead_gate():
     eng = LLMEngine(cfg, max_num_seqs=B, max_seq_len=128, enable_prefix_caching=False)
     eng.generate(prompts, SamplingParams(max_tokens=2))  # compile everything
     tel = eng._tel
-    rounds = {True: [], False: []}
-    # >= best-of-3 interleaved pairs, extending adaptively: under heavy
-    # box contention (full-suite runs swing a round 2.5x) six draws may
-    # not give BOTH modes a clean slice, so keep drawing until the
-    # best-vs-best comparison clears the gate or the round budget is
-    # spent — more data can only make a true regression MORE damning
-    for r in range(18):
-        for instrumented in ([True, False] if r % 2 == 0 else [False, True]):
-            eng._tel = tel if instrumented else None
-            for p in prompts:
-                eng.add_request(p, SamplingParams(max_tokens=G))
-            while eng.num_waiting:
-                eng.step()
-            t0 = time.perf_counter()
-            steps = 0
-            while eng.has_unfinished():
-                eng.step()
-                steps += 1
-            rounds[instrumented].append((time.perf_counter() - t0) / max(steps, 1))
-        if r >= 2 and min(rounds[True]) <= 1.05 * min(rounds[False]):
-            break
-    eng._tel = tel
-    best = {m: min(v) for m, v in rounds.items()}
-    assert best[True] <= 1.05 * best[False], (
-        f"telemetry overhead breached the 1.05x gate: instrumented "
-        f"{best[True] * 1e3:.3f} ms/step vs plain {best[False] * 1e3:.3f} ms/step "
-        f"({best[True] / best[False]:.3f}x; rounds tel={[round(x * 1e3, 2) for x in rounds[True]]} "
-        f"plain={[round(x * 1e3, 2) for x in rounds[False]]})"
-    )
+    spent = [0.0]
+
+    def timed(fn):
+        def run(*args, **kwargs):
+            t = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                spent[0] += time.perf_counter() - t
+        return run
+
+    for name in ("begin_step", "on_step", "on_emit", "on_bind", "on_submit", "on_finish"):
+        monkeypatch.setattr(tel, name, timed(getattr(tel, name)))
+    monkeypatch.setattr(engine_module, "stage", timed(telemetry.stage))  # a stage's construction
+    monkeypatch.setattr(telemetry._Stage, "__enter__", timed(telemetry._Stage.__enter__))  # its two stamps, not its body
+    monkeypatch.setattr(telemetry._Stage, "__exit__", timed(telemetry._Stage.__exit__))
+
+    rounds = []
+    for _ in range(3):
+        for p in prompts:
+            eng.add_request(p, SamplingParams(max_tokens=G))
+        while eng.num_waiting:
+            eng.step()
+        spent[0], steps, t0 = 0.0, 0, time.perf_counter()
+        while eng.has_unfinished():
+            eng.step()
+            steps += 1
+        rounds.append((spent[0] / max(steps, 1), (time.perf_counter() - t0) / max(steps, 1)))
+    own, step = min(rounds)
+    assert own <= 0.5e-3 and own <= 0.05 * step, (
+        f"telemetry took {own * 1e3:.3f} ms of a {step * 1e3:.3f} ms step on the best of "
+        f"{[(round(a * 1e3, 3), round(b * 1e3, 2)) for a, b in rounds]} (ms own, ms a step)")
+    # no stage reads a device value: every row of the flight ring is the host's own numbers
+    snap = tel.recorder.snapshot()
+    assert snap["steps"] and all(set(telemetry.STAGES.values()) <= set(s) for s in snap["steps"])
+    assert not [v for row in tel.recorder.steps for v in row if isinstance(v, jax.Array)]
+    assert all(isinstance(s[f], float) for s in snap["steps"] for f in telemetry.STAGES.values())
+    decode = [s for s in snap["steps"] if s["phase"] == "decode" and s["batch"]]
+    assert decode and all(s["dispatch_t"] >= s["t0"] for s in decode)
 
 
 def test_actor_call_floor(rt):
