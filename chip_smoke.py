@@ -639,7 +639,7 @@ def main() -> int:
     ap.add_argument("--tiny", action="store_true", help="CPU rehearsal at toy sizes; never reports ok")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--sabotage", choices=("reference",), default=None,
-                    help="give the named phase wrong data; the run must then fail (tested)")
+                    help="give the named phase wrong data; the run must then fail, and ends after that phase (tested)")
     ap.add_argument("--phase", choices=sorted(PHASES), default=None, help=argparse.SUPPRESS)
     a = ap.parse_args()
 
@@ -673,9 +673,10 @@ def main() -> int:
         serve = phase("serve", {"requests": requests})
         if serve["passed"]:  # the reference needs the served tokens
             phase("reference", {"requests": requests, "outputs": serve["outputs"]})
-        phase("train")
-        phase("paged", {"requests": requests})
-        phase("handover", {"kind": probe["device"]["kind"]})
+        if not a.sabotage:  # a sabotaged run has failed by now; its test asks no more of it, and the rehearsal runs the rest
+            phase("train")
+            phase("paged", {"requests": requests})
+            phase("handover", {"kind": probe["device"]["kind"]})
         expected = ("probe", "serve", "reference", "train", "paged", "handover")
     else:
         serve = phase("tp4-serve", {"requests": requests})

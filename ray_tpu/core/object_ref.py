@@ -7,6 +7,7 @@ ObjectRefGenerator (streaming returns, _raylet.pyx:1067).
 from __future__ import annotations
 
 import threading
+from collections import deque
 
 from ray_tpu.core.ids import ObjectID
 
@@ -24,6 +25,13 @@ def _client():
 _rc_lock = threading.Lock()
 _rc_counts: dict[bytes, int] = {}
 _rc_events: list[tuple[bytes, bool]] = []  # (id, True=register / False=release)
+# ids of refs that were finalized and are not counted down yet. ``ObjectRef.__del__`` runs wherever
+# the collector runs, which is also INSIDE a holder of ``_rc_lock`` on the same thread: a collection
+# that began under ``local_ref_count`` finalized a ref whose ``_decref`` then waited for the lock
+# its own thread held, for ever, and every later ref of the process behind it (a whole test run
+# hung so, PR 40; PR 31's and PR 34's "every process asleep" have the same shape). So a finalizer
+# takes no lock: it appends here (atomic), and whoever next takes the lock counts down first.
+_rc_dead: deque[bytes] = deque()
 _rc_enabled = True
 _ref_sink = threading.local()  # active serialization sinks (serialize())
 
@@ -47,12 +55,30 @@ def pop_ref_sink(token: int):
         stack.pop()
 
 
+def _count_down_the_dead():
+    """Apply the finalized refs' decrements, in the order they died. The caller holds ``_rc_lock``."""
+    while _rc_dead:
+        try:
+            k = _rc_dead.popleft()
+        except IndexError:  # another holder-to-be cannot race us (we hold the lock); a finalizer only appends
+            return
+        c = _rc_counts.get(k)
+        if c is None:
+            continue
+        if c <= 1:
+            del _rc_counts[k]
+            _rc_events.append((k, False))
+        else:
+            _rc_counts[k] = c - 1
+
+
 def _incref(obj_id: ObjectID):
     if not _rc_enabled:
         return
     try:
         k = obj_id.binary()
         with _rc_lock:
+            _count_down_the_dead()
             c = _rc_counts.get(k, 0)
             _rc_counts[k] = c + 1
             if c == 0:
@@ -65,29 +91,23 @@ def _decref(obj_id: ObjectID):
     if not _rc_enabled:
         return
     try:
-        k = obj_id.binary()
-        with _rc_lock:
-            c = _rc_counts.get(k)
-            if c is None:
-                return
-            if c <= 1:
-                del _rc_counts[k]
-                _rc_events.append((k, False))
-            else:
-                _rc_counts[k] = c - 1
+        _rc_dead.append(obj_id.binary())
     except Exception:
         pass  # interpreter teardown
 
 
 def drain_ref_events() -> list[tuple[bytes, bool]]:
     with _rc_lock:
+        _count_down_the_dead()
         ev, _rc_events[:] = list(_rc_events), []
         return ev
 
 
 def local_ref_count(obj_id: ObjectID) -> int:
+    k = obj_id.binary()
     with _rc_lock:
-        return _rc_counts.get(obj_id.binary(), 0)
+        _count_down_the_dead()
+        return _rc_counts.get(k, 0)
 
 
 _note_hint = None  # lazily bound direct.note_hint (avoids per-ref import)

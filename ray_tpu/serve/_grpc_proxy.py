@@ -22,6 +22,7 @@ import json
 import threading
 
 import ray_tpu
+from ray_tpu.serve._proxy import ROUTES_TIMEOUT_S
 from ray_tpu.serve.handle import DeploymentHandle
 
 _METHOD_UNARY = "/ray_tpu.serve.Generic/Call"
@@ -62,7 +63,7 @@ class GrpcProxy:
             h = self._handles.get(app)
         if h is not None:
             return h
-        apps = ray_tpu.get(self._controller.list_applications.remote())
+        apps = ray_tpu.get(self._controller.list_applications.remote(), timeout=ROUTES_TIMEOUT_S)
         if app not in apps:
             raise KeyError(f"no application {app!r} (have {sorted(apps)})")
         h = DeploymentHandle(self._controller, app, apps[app]["ingress"])

@@ -39,6 +39,9 @@ class Request:
         return self.body.decode()
 
 
+ROUTES_TIMEOUT_S = 30.0
+
+
 class RouteTableMixin:
     """Controller route table shared by the sync and async proxies: cached
     refresh (one controller round-trip per interval; forced refreshes on
@@ -57,7 +60,9 @@ class RouteTableMixin:
         if now - self._routes_at < interval:
             return
         self._routes_at = now
-        apps = ray_tpu.get(self._controller.list_applications.remote())
+        # bounded: the controller answers from memory in milliseconds, and a connection must not wait
+        # for ever on a reply that was lost (the request then fails with a 500 and the next one asks again)
+        apps = ray_tpu.get(self._controller.list_applications.remote(), timeout=ROUTES_TIMEOUT_S)
         with self._routes_lock:
             known = set(self._routes)
             for app_name, info in apps.items():

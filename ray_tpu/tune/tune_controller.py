@@ -24,6 +24,10 @@ from ray_tpu.tune import schedulers as sched
 from ray_tpu.tune.trial import ERROR, PAUSED, PENDING, RUNNING, TERMINATED, Trial
 
 POLL_INTERVAL_S = float(os.environ.get("RT_TUNE_POLL_INTERVAL_S", "0.05"))
+# a trial actor answers ``poll`` from a thread of its own in milliseconds; a reply that has not come
+# after this long will not come (the actor's process is gone or wedged), and the trial is treated as
+# one whose actor died instead of the controller waiting for ever (a whole test run hung here, PR 31)
+POLL_TIMEOUT_S = 60.0
 
 
 def _stage_root() -> str:
@@ -403,7 +407,7 @@ class TuneController:
                 continue
             pending = self._pending.setdefault(trial.trial_id, [])
             try:
-                p = ray_tpu.get(actor.poll.remote())
+                p = ray_tpu.get(actor.poll.remote(), timeout=POLL_TIMEOUT_S)
                 pending.extend(p["reports"])
             except Exception:
                 trial.error = "actor died"
@@ -426,7 +430,7 @@ class TuneController:
                     # the run may have finished (and enqueued reports)
                     # between our poll above and this check — drain again
                     try:
-                        pending.extend(ray_tpu.get(actor.poll.remote())["reports"])
+                        pending.extend(ray_tpu.get(actor.poll.remote(), timeout=POLL_TIMEOUT_S)["reports"])
                     except Exception:
                         pass
                     if pending:

@@ -131,22 +131,38 @@ def dump_cluster_info(client) -> str:
     return path
 
 
-def load_latest_cluster_info() -> dict | None:
-    """Newest cluster_info.json across live sessions (for `rt agent`)."""
+def _newest(name: str, a_head_finds_itself: bool) -> str | None:
+    """The path of ``name`` in the session this process belongs to, else the newest across the
+    machine's sessions. A head exports ``RT_SESSION_PID`` to itself and to everything it starts,
+    so a process that carries it is told which session is its own; "the newest" is then a guess
+    it does not need, and on a machine with several heads (six pytest workers) often another
+    head's, or a file a head left behind. ``a_head_finds_itself``: whether a process that IS the
+    head of its session (it carries its own pid) means that session too: yes for its own state
+    dump, no for an address to attach to."""
     root = os.path.join("/tmp", "ray_tpu")
-    best, best_ts = None, -1.0
     try:
         sessions = os.listdir(root)
     except FileNotFoundError:
         return None
+    own = os.environ.get("RT_SESSION_PID", "")
+    if own and (a_head_finds_itself or own != str(os.getpid())) and os.path.exists(os.path.join(root, f"session_{own}", name)):
+        sessions = [f"session_{own}"]
+    best, best_ts = None, -1.0
     for s in sessions:
-        p = os.path.join(root, s, "cluster_info.json")
+        p = os.path.join(root, s, name)
         try:
             ts = os.path.getmtime(p)
         except OSError:
             continue
         if ts > best_ts:
             best, best_ts = p, ts
+    return best
+
+
+def load_latest_cluster_info() -> dict | None:
+    """The join credentials of this process's session, else of the newest live one (for `rt agent`
+    and ``init(address="auto")``)."""
+    best = _newest("cluster_info.json", a_head_finds_itself=False)
     if best is None:
         return None
     with open(best) as f:
@@ -159,21 +175,8 @@ def load_latest_cluster_info() -> dict | None:
 
 
 def load_latest_state() -> dict | None:
-    """Newest state.json across sessions (CLI entry)."""
-    root = os.path.join("/tmp", "ray_tpu")
-    best, best_ts = None, -1.0
-    try:
-        sessions = os.listdir(root)
-    except FileNotFoundError:
-        return None
-    for s in sessions:
-        p = os.path.join(root, s, "state.json")
-        try:
-            ts = os.path.getmtime(p)
-        except OSError:
-            continue
-        if ts > best_ts:
-            best, best_ts = p, ts
+    """The state dump of this process's session, else the newest across sessions (CLI entry)."""
+    best = _newest("state.json", a_head_finds_itself=True)
     if best is None:
         return None
     with open(best) as f:

@@ -1,0 +1,344 @@
+"""What every description over the ONE hybrid loop (``models/hybrid.py``, ``llm/hybrid_runner.py``)
+is held to, written once: the sequence form against the family's plain reference, prefill then
+decode through the engine against it, the synchronous loop as the fused step's oracle, the
+comparison failing each planted fault, the OpenAI server streaming, every refusal by its name.
+
+pytest does not collect this module. A description's file states a ``Description`` as ``DESC``,
+keeps its module-scoped ``params`` fixture and does ``from hybrid_battery import *``: the tests
+below are then collected there, against that description, beside what is truly the file's own.
+A fourth description costs a ``DESC``, not a file of copies. ``eng`` is ONE engine a module at the
+default arguments: for a test that leaves it as it found it, or plants its fault on the engine
+object through ``monkeypatch``; a test that patches a module before the step programs are traced,
+or needs other arguments, builds its own with ``engine(...)``."""
+
+import dataclasses
+import re
+from typing import Any, Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference
+from ray_tpu.exceptions import HybridModelUnsupportedError
+from ray_tpu.llm import LLMEngine, SamplingParams
+from ray_tpu.models import experts, hybrid
+
+ENGINE_KW = {"max_num_seqs": 4, "max_seq_len": 128, "prefill_buckets": (16, 32, 64)}
+
+
+class Fault(NamedTuple):
+    """A mistake the comparison must catch. ``plant(desc, params, eng, monkeypatch)`` returns the
+    engine to serve with (``eng`` itself where the fault is planted on it); the served
+    log-probabilities are then off the reference's by more than ``over`` tolerances (``margin``:
+    or the reference's top-1 leads the served token by as much)."""
+    plant: Callable
+    over: float = 1.0
+    margin: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class Description:
+    family: Any  # benchmark/families/<name>.py: the plain reference and the configuration file's side
+    c: dict  # the family's rehearsal configuration
+    cfg: Any  # the program's description of the same model
+    tol: float  # where the comparison fails
+    agrees_to: float  # what program and reference agree to, in a log-probability
+    state_bytes_per_slot: int
+    kv_bytes_per_token: int  # as the chip stores a position
+    poison: dict  # cache entry -> what a finished sequence's rows are overwritten with before the second round
+    faults: dict  # name -> Fault
+    refusal_says: tuple  # what a refusal says of this description ...
+    refusal_says_not: tuple  # ... and what it must not
+    shares: tuple = ()  # (the family's key for the expert count, chips, the reference layer's keywords)
+
+
+def prompts(desc, seed, lengths):
+    rs = np.random.RandomState(seed)
+    return [[int(t) for t in rs.randint(1, desc.c["vocab_size"] - 1, size=n)] for n in lengths]
+
+
+def engine(cfg, params, **kw):
+    return LLMEngine(cfg, params, **{**ENGINE_KW, **kw})
+
+
+def served(outs, ps, sampling):
+    return [{"prompt": p, "tokens": o.token_ids, "logprobs": o.logprobs, "greedy": sp.temperature == 0.0}
+            for o, p, sp in zip(outs, ps, sampling)]
+
+
+def check(desc, params, samples):
+    return reference.check_served(desc.family.reference_logprobs, params, desc.c, samples, desc.tol)
+
+
+def steps_after(eng, mark):
+    """The flight log's step rows since ``eng.telemetry()["step_count"]`` was ``mark``."""
+    return [s for s in eng.telemetry()["steps"] if s["step"] > mark]
+
+
+@pytest.fixture(scope="module")
+def desc(request):
+    return request.module.DESC
+
+
+@pytest.fixture(scope="module")
+def eng(desc, params):
+    return engine(desc.cfg, params)
+
+
+def pytest_generate_tests(metafunc):
+    if "fault" in metafunc.fixturenames:
+        metafunc.parametrize("fault", list(metafunc.module.DESC.faults), indirect=True)
+
+
+@pytest.fixture
+def fault(request, desc):
+    return desc.faults[request.param]
+
+
+# ------------------------------------------------------------------ the program against the reference
+def test_sequence_forward_matches_the_reference(desc, params):
+    toks = np.asarray(prompts(desc, 0, (37, 37)), np.int32)  # 37: whole chunks of 8 and a rest
+    logits = hybrid.forward(params, jnp.asarray(toks), desc.cfg)
+    for b in range(2):
+        ref = desc.family.reference_logprobs(params, toks[b], desc.c, 0, 37)
+        np.testing.assert_allclose(jax.nn.log_softmax(logits[b], -1), ref, atol=desc.agrees_to)
+
+
+def test_prefill_then_decode_through_the_engine_matches_the_reference(desc, params, eng):
+    """Admission waves of batched same-bucket prefills at lengths off the bucket and off the chunk,
+    more requests than slots (so slots are recycled), greedy and seeded, an abort in the middle, and
+    before the second round every slot's old state and rows poisoned: all of it against the
+    reference's full forward."""
+    s, mark = desc.cfg.expert_layer, eng.telemetry()["step_count"]
+    lengths = (5, 19, 23, 40, 7, 33, 18, 61, 9)
+    ps = prompts(desc, 1, lengths)
+    sampling = [SamplingParams(max_tokens=10, temperature=0.0 if i % 3 else 0.8, top_p=0.95, seed=i, logprobs=True)
+                for i in range(len(ps))]
+    ids = [eng.add_request(p, sp) for p, sp in zip(ps, sampling)]
+    finals, steps = {}, 0
+    while eng.has_unfinished():
+        steps += 1
+        if steps == 4:
+            assert eng.abort_request(ids[1])
+        finals.update({o.request_id: o for o in eng.step() if o.finished})
+    assert finals[ids[1]].finish_reason == "aborted" and len(finals[ids[1]].token_ids) < 10
+    keep = [i for i in range(len(ps)) if i != 1]
+    res = check(desc, params, served([finals[ids[i]] for i in keep], [ps[i] for i in keep], [sampling[i] for i in keep]))
+    assert res["ok"] and res["tokens"] == 80 and res["max_abs_dlogprob"] < desc.agrees_to, res
+    stats = eng.kv_cache_stats()
+    assert stats["state_bytes_per_slot"] == desc.state_bytes_per_slot and stats["bytes_per_token"] == desc.kv_bytes_per_token
+    assert stats["state_allocated_bytes"] == 4 * desc.state_bytes_per_slot and eng.prefix_cache_stats() == {}
+    assert all(a.dtype == jnp.float32 for a in eng.state.values())
+    # every slot has held a sequence by now: poison what they left, then serve again
+    eng.state = jax.tree.map(lambda a: jnp.full_like(a, jnp.nan), eng.state)
+    eng.cache = {**eng.cache, **{name: jnp.full_like(eng.cache[name], value) for name, value in desc.poison.items()}}
+    ps2 = prompts(desc, 2, (31, 12, 50, 6, 17))
+    sp2 = [SamplingParams(max_tokens=8, temperature=0.0, logprobs=True)] * len(ps2)
+    res = check(desc, params, served(eng.generate(ps2, sp2), ps2, sp2))
+    assert res["ok"] and res["tokens"] == 40, res
+    # the flight log: decode rows carry the routing counters of the drained step, admitting rows the prefills' five
+    new = steps_after(eng, mark)
+    rows = [r for r in new if "experts_hit" in r]
+    assert rows and all(0 < r["experts_hit"] <= s.held and r["moe_pairs_local"] <= r["moe_pairs_total"] for r in rows)
+    assert all(r["moe_pairs_total"] % s.top_k == 0 and r["moe_max_load"] >= 1 for r in rows)
+    assert s.held < s.num_experts or all(r["moe_pairs_local"] == r["moe_pairs_total"] for r in rows), "every expert is held here"
+    assert not eng.state or any(r.get("state_insert_ms", 0) > 0 for r in new)
+    admitting = [r for r in new if r.get("admitted")]
+    assert admitting and all("prefill_tokens" in r for r in admitting)
+    for r in admitting:
+        assert 0 < r["prefill_tokens"] <= r["prefill_tokens_padded"] and r["prefill_tokens_padded"] % 16 == 0
+        assert 0 < r["prefill_moe_pairs_local"] <= r["moe_rows_computed"] and r["prefill_moe_pairs_local"] <= s.top_k * r["prefill_tokens"]
+        assert 0 < r["prefill_experts_hit"] <= s.held
+    assert sum(r["prefill_tokens"] for r in admitting) == sum(lengths) + sum(len(p) for p in ps2)
+    assert not any("prefill_tokens" in r for r in new if not r.get("admitted"))
+
+
+def test_the_synchronous_loop_is_the_fused_steps_oracle(desc, params, eng):
+    ps = prompts(desc, 3, (9, 30, 14, 47, 22))
+    sp = SamplingParams(max_tokens=7, temperature=0.0, logprobs=True)
+    a = eng.generate(ps, sp)
+    b = engine(desc.cfg, params, device_resident=False).generate(ps, sp)
+    assert [o.token_ids for o in a] == [o.token_ids for o in b]
+    np.testing.assert_allclose([o.logprobs for o in a], [o.logprobs for o in b], atol=desc.tol / 100)
+
+
+def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(desc, eng):
+    """``experts_read`` beside ``experts_hit`` in a step row (``hybrid_runner.MOE_STATS``): means
+    over the expert layers of the held experts whose weights the step read and that got a token.
+    The step loops over the experts hit, so the two are equal in every decode row."""
+    mark = eng.telemetry()["step_count"]
+    ps = prompts(desc, 8, (12, 30, 7, 21, 44, 9))
+    eng.generate(ps, [SamplingParams(max_tokens=6 + 3 * i, temperature=0.0) for i in range(len(ps))])
+    rows = [r for r in steps_after(eng, mark) if "experts_hit" in r]
+    assert len(rows) >= 10 and all(r["experts_read"] == r["experts_hit"] for r in rows)
+    assert len({r["experts_read"] for r in rows}) > 1 and all(0 < r["experts_read"] <= desc.cfg.expert_layer.held for r in rows)
+
+
+# ------------------------------------------------------------------------------ the planted faults
+def bf16_state(kind, entry):
+    """The recurrent state kept in bfloat16: the precision below the stated one."""
+    def plant(desc, params, eng, monkeypatch):
+        @dataclasses.dataclass(frozen=True)
+        class Bf16State(type(desc.cfg)):
+            def cache_spec(self):
+                spec = super().cache_spec()
+                shape, _, per = spec[kind][entry]
+                return {**spec, kind: {**spec[kind], entry: (shape, "bfloat16", per)}}
+
+        low = engine(Bf16State(**dataclasses.asdict(desc.cfg)), params)
+        assert low.state[entry].dtype == jnp.bfloat16
+        return low
+    return plant
+
+
+def slot_not_reset(desc, params, eng, monkeypatch):
+    """A recycled slot keeps the last sequence's state (the clean round before left one in every slot): no insert at admission."""
+    monkeypatch.setattr(eng, "_state_insert", lambda state, slot, row, new: state)
+    return eng
+
+
+def padded_length(desc, params, eng, monkeypatch):
+    """The recurrence run over the padding too: the state at the bucket's length, not the prompt's."""
+    real = eng._prefill
+
+    def at_padded_length(params, toks, lens):
+        logits, rows, _ = real(params, toks, lens)
+        return logits, rows, real(params, toks, jnp.full_like(lens, toks.shape[1]))[2]
+
+    monkeypatch.setattr(eng, "_prefill", at_padded_length)
+    return eng
+
+
+def patched(module, name, wrap):
+    """A fault in ``module.name``, planted before a fresh engine traces its step programs: ``wrap(real)`` is what stands there."""
+    def plant(desc, params, eng, monkeypatch):
+        monkeypatch.setattr(module, name, wrap(getattr(module, name)))
+        return engine(desc.cfg, params)
+    return plant
+
+
+def test_the_comparison_fails_each_planted_fault(desc, params, eng, fault, monkeypatch):
+    ps = prompts(desc, 4, (21, 38, 11, 27))  # none on a bucket (32, 64, 16, 32), none on a chunk
+    sp = [SamplingParams(max_tokens=24, temperature=0.0, logprobs=True)] * len(ps)
+    assert check(desc, params, served(eng.generate(ps, sp), ps, sp))["ok"]
+    res = check(desc, params, served(fault.plant(desc, params, eng, monkeypatch).generate(ps, sp), ps, sp))
+    off = max(res["max_abs_dlogprob"], res["max_margin"]) if fault.margin else res["max_abs_dlogprob"]
+    assert not res["ok"] and off > fault.over * desc.tol, res
+
+
+# -------------------------------------------------------------------------------------- the server
+def test_serves_through_the_openai_server_streaming(desc, params):
+    """LLMConfig(model_config=<the description>) through OpenAIServer: the normal serving path."""
+    from ray_tpu.serve.llm import LLMConfig, OpenAIServer
+
+    srv = OpenAIServer(LLMConfig(model_config=desc.cfg, params=params, model_id="toy-description", engine_kwargs=dict(ENGINE_KW)))
+    try:
+        assert srv.engine._hybrid and srv.engine._device_resident
+        p = prompts(desc, 5, (26,))[0]
+        chunks = list(srv({"prompt": p, "max_tokens": 6, "stream": True}))
+        assert chunks[-1].startswith("data: [DONE]") and len(chunks) >= 7
+        out = srv.generate(p, {"max_tokens": 6, "logprobs": True})
+        assert check(desc, params, [{"prompt": p, "tokens": out["token_ids"], "logprobs": out["logprobs"], "greedy": True}])["ok"]
+    finally:
+        srv.shutdown()
+
+
+# ------------------------------------------------------------------------------------ the refusals
+class _Anything:
+    vocab_size = 512  # the rehearsal configurations' own
+
+
+def _says(desc, error):
+    said = str(error.value)
+    assert all(words in said for words in desc.refusal_says) and not any(words in said for words in desc.refusal_says_not), said
+    return said
+
+
+@pytest.mark.parametrize("kwargs, named", [
+    ({"kv_layout": "paged"}, "kv_layout='paged'"),
+    ({"cache_dtype": "int8"}, "cache_dtype='int8'"),
+    ({"speculative": _Anything()}, "speculative decoding"),
+    ({"kv_plane": _Anything(), "enable_prefix_caching": True}, "KV plane"),
+    ({"mesh": "tp2"}, "tensor_parallel_size > 1"),
+])
+def test_what_the_hybrid_cannot_do_is_refused_at_construction_by_name(desc, params, kwargs, named):
+    """The refusal names what was asked, says what kinds of layer the description holds and what a
+    sequence of it keeps (a state per sequence, a latent per position), and nothing it does not."""
+    if kwargs.get("mesh") == "tp2":
+        from ray_tpu.parallel.mesh import create_mesh
+
+        kwargs = {"mesh": create_mesh(tp=2, devices=jax.devices()[:2])}
+    with pytest.raises(HybridModelUnsupportedError, match=re.escape(named)) as e:
+        engine(desc.cfg, params, **kwargs)
+    assert f"{type(desc.cfg).__name__}: {desc.cfg.kinds_held}" in _says(desc, e)
+
+
+@pytest.mark.parametrize("call, named", [
+    (lambda e: e.add_prefill_request([1, 2, 3]), "disaggregated prefill"),
+    (lambda e: e.prefill_handoff([1, 2, 3]), "disaggregated prefill"),
+    (lambda e: e.prefill_remote([1, 2, 3]), "disaggregated prefill"),
+    (lambda e: e.add_prefilled([1, 2, 3], {}), "transferred KV block"),
+    (lambda e: e.checkpoint_request("r"), "migration"),
+    (lambda e: e.restore_request({}), "migration"),
+    (lambda e: e.suspend_request("r"), "suspend"),
+    (lambda e: e.resume_suspended("r"), "suspend"),
+    (lambda e: e.adopt_prefetched([1, 2, 3], None, None), "KV plane"),
+])
+def test_moving_a_sequence_is_refused_at_the_call_by_name(desc, eng, call, named):
+    assert eng._prefix_cache is None and eng.prefix_cache_stats() == {}  # asked for by default, off for a description, and said once
+    with pytest.raises(HybridModelUnsupportedError, match=named) as e:
+        call(eng)
+    _says(desc, e)
+
+
+# ---------------------------------------------------------------- for the descriptions that are a share
+def test_the_chips_shares_add_up_to_the_uncut_expert_layer(desc):
+    """Each chip of the deployment holds an equal run of the experts; the routed parts of all of
+    them, with what every chip computes alike (the shared expert) counted once, are the uncut
+    reference layer. (Not in ``__all__``: for the descriptions that have expert-share fields.)"""
+    key, chips, ref_kw = desc.shares
+    whole = desc.family.program_config({**desc.c, key: 8, "deployment": None}, 128)
+    s, each = whole.expert_layer, 8 // chips
+    params = jax.jit(whole.init_params)(jax.random.PRNGKey(11))
+    group = jax.tree.map(lambda a: a[:1], jiggled(params)["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(12), (40, whole.hidden_size))
+    ref, _ = desc.family._experts(x, group, 0, first=0, top_k=s.top_k, **ref_kw)
+    layer = jax.tree.map(lambda a: a[0], group)
+    xn = whole.norm(x, layer["norm"])
+    idx, wt = experts.route(layer, xn, whole)
+    total = experts.shared_expert(layer, xn, s)
+    for chip in range(chips):
+        share = dataclasses.replace(whole, expert_start=each * chip, num_local_experts=each)
+        w = {**layer, **{n: layer[n][each * chip:each * chip + each] for n in s.matrices}}
+        routed = experts.experts_grouped(jax.tree.map(lambda a: a[None], w), 0, xn, idx, wt, jnp.ones((40,), bool), share)
+        assert np.abs(np.asarray(routed)).max() > 0
+        total = total + routed
+    np.testing.assert_allclose(x + total, ref, atol=1e-4)
+
+
+def one_by_one(w, x, idx, wt, cfg):
+    """Each (token, chosen expert) pair computed alone: what no dispatch may lose."""
+    out = np.zeros(x.shape, np.float32)
+    for n in range(x.shape[0]):
+        for e, g in zip(np.asarray(idx[n]), np.asarray(wt[n])):
+            e = int(e) - cfg.expert_start
+            if 0 <= e < cfg.local_experts:
+                up = x[n] @ w["w_up"][e].T
+                h = jnp.square(jax.nn.relu(up)) if cfg.expert_layer.act == "relu2" else jax.nn.silu(x[n] @ w["w_gate"][e].T) * up
+                out[n] += g * np.asarray(h @ w["w_down"][e])
+    return out
+
+
+def jiggled(params):
+    """Norm weights off their initial 0 and 1, so that ``1 + w`` against a plain ``w`` shows."""
+    def jig(path, a):
+        if "norm" in str(path[-1]):
+            return a + 0.1 * jax.random.normal(jax.random.PRNGKey(len(str(path))), a.shape, a.dtype)
+        return a
+    return jax.tree_util.tree_map_with_path(jig, params)
+
+
+# what ``import *`` hands a description's file: the fixtures, the hook that parametrizes ``fault``, and every test but the share's
+__all__ = ["desc", "eng", "fault", "pytest_generate_tests"] + [n for n in dir() if n.startswith("test_") and n != "test_the_chips_shares_add_up_to_the_uncut_expert_layer"]

@@ -100,7 +100,7 @@ def test_appo_cartpole_learns():
     for _ in range(22):
         r = algo.train()
         best = max(best, r["env_runners"]["episode_return_mean"])
-        if best >= 60:
+        if best >= 40:  # the assertion below holds: further iterations cannot change the verdict
             break
     assert best >= 40, f"APPO failed to learn: best={best}"
     algo.stop()

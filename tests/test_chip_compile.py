@@ -220,7 +220,7 @@ def test_no_step_program_sorts_the_vocabulary(one_chip, program):
     else:
         from ray_tpu.llm import hybrid_runner as hr
 
-        cfg, params, cache, state = _hybrid_at_the_benchmarks_size(one_chip)
+        cfg, params, cache, state = _cell_at_its_size(one_chip, "nemotron")
         fn, B = partial(hr.fused_step, cfg=cfg), 32
         args = (params, cache, state, *_sds_lanes(B), _sds((B,), jnp.bool_))
     txt = jax.jit(fn).lower(*_on(args, one_chip)).compiler_ir(dialect="hlo").as_hlo_text()
@@ -305,24 +305,32 @@ def test_fsdp4_loss_and_grad_with_flash_kernel_compile_for_v5e(topo):
     assert txt.count("tpu_custom_call") >= 3  # forward, dq and dk/dv kernels
 
 
-def _hybrid_at_the_benchmarks_size(one_chip):
-    """The configuration of the cell ``nemotron-3-nano-ep2.chat``: published widths, 16 layers,
-    64 of 128 experts, 32 slots x 4096 (benchmark/configs/nemotron-3-nano-30b-a3b-ep2.json)."""
+# the hybrid cells' configuration files (benchmark/configs/) and what the harness passes their families beside them
+CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widths, 16 layers, 64 of 128 experts, 32 slots x 4096
+         "qwen3_next": ("qwen3-next-80b-a3b-ep4.json", {"remat": False}),  # 12 of 48 layers, 128 of 512 experts, 16 slots x 4096
+         "glm": ("glm-4.7-flash-d8.json", {"remat": False})}  # 8 of 47 layers whole, 16 slots x 16,384
+
+
+def _cell_at_its_size(one_chip, cell):
+    """``(cfg, params, cache, state)`` of a hybrid cell as shapes on the chip: the cell's own
+    configuration file, its slots and horizon, the slot cache allocated from the description's
+    per-position entries as the engine allocates it."""
     import json
     import os
 
     from benchmark import common
+    from ray_tpu.llm import kv_cache as kvc
     from ray_tpu.llm import state_cache
-    from ray_tpu.models import nemotron_h as nh
 
-    with open(os.path.join(common.HERE, "configs", "nemotron-3-nano-30b-a3b-ep2.json")) as f:
+    config, program_kw = CELLS[cell]
+    with open(os.path.join(common.HERE, "configs", config)) as f:
         c = json.load(f)
+    slots, S = c["serving"]["max_num_seqs"], c["serving"]["max_seq_len"]
     # off the TPU "auto" picks the XLA attention; the chip runs the flash kernel
-    cfg = common.load_family(c["family"]).program_config(c, c["serving"]["max_seq_len"], attention_impl="pallas")
-    params = _on(jax.eval_shape(lambda: nh.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
-    kv = jax.ShapeDtypeStruct((cfg.num_kv_layers, 32, 4096, cfg.num_kv_heads, cfg.hd), jnp.bfloat16, sharding=one_chip)
-    cache = {"k": kv, "v": kv, "length": jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)}
-    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, 32)), one_chip)
+    cfg = common.load_family(c["family"]).program_config(c, S, attention_impl="pallas", **program_kw)
+    params = _on(jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))), one_chip)
+    cache = _on(jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), slots, S)), one_chip)
+    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, slots)), one_chip)
 
 
 def test_hybrid_fused_step_fits_one_v5e_and_updates_its_caches_in_place(one_chip):
@@ -332,7 +340,7 @@ def test_hybrid_fused_step_fits_one_v5e_and_updates_its_caches_in_place(one_chip
     of its cache, PERF.md section 7), and must not copy a layer's experts out of the stack."""
     from ray_tpu.llm import hybrid_runner as hr
 
-    cfg, params, cache, state = _hybrid_at_the_benchmarks_size(one_chip)
+    cfg, params, cache, state = _cell_at_its_size(one_chip, "nemotron")
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     lanes = (s((32,), jnp.int32), s((32, 2), jnp.uint32), s((32,), jnp.float32), s((32,), jnp.int32), s((32,), jnp.float32))
     step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
@@ -348,7 +356,7 @@ def test_hybrid_prefill_fits_beside_weights_and_caches_on_one_v5e(one_chip):
     matmul) beside 10.54 GiB of weights and caches: under 15.75 GiB, with the flash kernel."""
     from ray_tpu.llm import hybrid_runner as hr
 
-    cfg, params, _, _ = _hybrid_at_the_benchmarks_size(one_chip)
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "nemotron")
     tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
     lengths = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
     compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
@@ -361,26 +369,6 @@ def test_hybrid_prefill_fits_beside_weights_and_caches_on_one_v5e(one_chip):
 # ---------------------------------------------------------------------------
 # a second description over the same step programs: the cell qwen3-next-ep4.longdoc
 # ---------------------------------------------------------------------------
-def _qwen3_next_at_the_benchmarks_size(one_chip, slots=16):
-    """The configuration of the cell ``qwen3-next-ep4.longdoc``: published widths, 12 of 48 layers,
-    128 of 512 experts, 16 slots x 4096 (benchmark/configs/qwen3-next-80b-a3b-ep4.json)."""
-    import json
-    import os
-
-    from benchmark import common
-    from ray_tpu.llm import state_cache
-
-    with open(os.path.join(common.HERE, "configs", "qwen3-next-80b-a3b-ep4.json")) as f:
-        c = json.load(f)
-    assert (c["serving"]["max_num_seqs"], c["serving"]["max_seq_len"]) == (slots, 4096)
-    # off the TPU "auto" picks the XLA attention; the chip runs the flash kernel, 256 wide here
-    cfg = common.load_family(c["family"]).program_config(c, 4096, attention_impl="pallas", remat=False)
-    params = _on(jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))), one_chip)
-    kv = jax.ShapeDtypeStruct((cfg.num_kv_layers, slots, 4096, cfg.num_kv_heads, cfg.hd), jnp.bfloat16, sharding=one_chip)
-    cache = {"k": kv, "v": kv, "length": jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)}
-    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, slots)), one_chip)
-
-
 def test_qwen3_next_fused_step_fits_one_v5e_and_updates_both_caches_in_place(one_chip):
     """PR 34: 10.10 GiB of weights, 0.375 GiB of KV rows (3 layers of heads 256 wide) and 0.29 GiB
     of recurrent state (9 layers of 32 x 128 x 128 float32 a slot) in one decode program, through
@@ -388,7 +376,7 @@ def test_qwen3_next_fused_step_fits_one_v5e_and_updates_both_caches_in_place(one
     aliased to the donated inputs, and temporaries under ONE layer's rows (128 MiB of K and V)."""
     from ray_tpu.llm import hybrid_runner as hr
 
-    cfg, params, cache, state = _qwen3_next_at_the_benchmarks_size(one_chip)
+    cfg, params, cache, state = _cell_at_its_size(one_chip, "qwen3_next")
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
     step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
@@ -408,7 +396,7 @@ def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on
     and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of caches: under 15.75 GiB."""
     from ray_tpu.llm import hybrid_runner as hr
 
-    cfg, params, _, _ = _qwen3_next_at_the_benchmarks_size(one_chip)
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "qwen3_next")
     tokens = jax.ShapeDtypeStruct((prompts, 4096), jnp.int32, sharding=one_chip)
     lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
     compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
@@ -430,6 +418,29 @@ def as_on_a_tpu(monkeypatch):
 
 def _kv_bytes(cache):
     return sum(a.size * a.dtype.itemsize for n, a in cache.items() if n != "length")
+
+
+@pytest.fixture(scope="module")
+def fused_step_for_the_chip(one_chip):
+    """``cell -> (cfg, params, cache, state, compiled)``: a hybrid cell's fused step at the cell's
+    own size, with the forms the chip runs (the caller holds ``as_on_a_tpu``) and its caches
+    donated as the engine donates them, compiled ONCE for the tests that read it."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    memo = {}
+
+    def compiled_for(cell):
+        assert jax.default_backend() == "tpu", "the gates are asked as on the chip"
+        if cell not in memo:
+            cfg, params, cache, state = _cell_at_its_size(one_chip, cell)
+            s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+            n = cache["length"].shape[0]
+            lanes = (s((n,), jnp.int32), s((n, 2), jnp.uint32), s((n,), jnp.float32), s((n,), jnp.int32), s((n,), jnp.float32))
+            step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
+            memo[cell] = (cfg, params, cache, state, step.lower(params, cache, state, *lanes, s((n,), jnp.bool_)).compile())
+        return memo[cell]
+
+    return compiled_for
 
 
 @pytest.mark.parametrize("kv, hd, nh, slots, layers", [(8, 128, 16, 16, 24), (2, 128, 32, 32, 2)],
@@ -482,34 +493,22 @@ def test_fused_slot_decode_step_holds_no_layers_rows_at_internlm2_sizes(one_chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
 
 
-def test_nemotron_fused_step_holds_no_layers_rows_at_its_cells_size(one_chip, as_on_a_tpu):
+def test_nemotron_fused_step_holds_no_layers_rows_at_its_cells_size(fused_step_for_the_chip, as_on_a_tpu):
     """The hybrid's two attention layers through the same op (``hybrid.attend_slot`` hands it the
     stacked leaf and the layer's index): a kernel in the step, no ``[1, 32, 4096, 2, 128]`` slice,
     both caches still aliased."""
     import re
 
-    from ray_tpu.llm import hybrid_runner as hr
-
-    cfg, params, cache, state = _hybrid_at_the_benchmarks_size(one_chip)
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    lanes = (s((32,), jnp.int32), s((32, 2), jnp.uint32), s((32,), jnp.float32), s((32,), jnp.int32), s((32,), jnp.float32))
-    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
-    compiled = step.lower(params, cache, state, *lanes, s((32,), jnp.bool_)).compile()
+    _, _, cache, state, compiled = fused_step_for_the_chip("nemotron")
     mem, txt = compiled.memory_analysis(), compiled.as_text()
     assert "slot_decode_attention" in txt and not re.search(r"bf16\[1,32,4096,2,128\]", txt)
     assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)))
     assert mem.temp_size_in_bytes < 0.1 * 2**30
 
 
-def test_qwen3_next_fused_step_keeps_the_xla_form(one_chip, as_on_a_tpu):
+def test_qwen3_next_fused_step_keeps_the_xla_form(fused_step_for_the_chip, as_on_a_tpu):
     """The gate's refusal at work: no kernel in the step of the tile it refused."""
-    from ray_tpu.llm import hybrid_runner as hr
-
-    cfg, params, cache, state = _qwen3_next_at_the_benchmarks_size(one_chip)
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
-    txt = jax.jit(partial(hr.fused_step, cfg=cfg)).lower(params, cache, state, *lanes, s((16,), jnp.bool_)).compile().as_text()
-    assert "slot_decode_attention" not in txt
+    assert "slot_decode_attention" not in fused_step_for_the_chip("qwen3_next")[-1].as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -517,26 +516,6 @@ def test_qwen3_next_fused_step_keeps_the_xla_form(one_chip, as_on_a_tpu):
 # glm-4.7-flash-d8.longdoc-16k. The slot cache holds a latent row and one rotated key a
 # position; the decode step attends on them where they lie (ops/slot_attention.attend_latent)
 # ---------------------------------------------------------------------------
-def _glm_at_the_benchmarks_size(one_chip):
-    """The configuration of the cell ``glm-4.7-flash-d8.longdoc-16k``: published widths, 8 of 47
-    layers whole, 16 slots x 16,384 (benchmark/configs/glm-4.7-flash-d8.json)."""
-    import json
-    import os
-
-    from benchmark import common
-    from ray_tpu.llm import kv_cache as kvc
-    from ray_tpu.llm import state_cache
-
-    with open(os.path.join(common.HERE, "configs", "glm-4.7-flash-d8.json")) as f:
-        c = json.load(f)
-    slots, S = c["serving"]["max_num_seqs"], c["serving"]["max_seq_len"]
-    assert (slots, S) == (16, 16384)
-    cfg = common.load_family(c["family"]).program_config(c, S, attention_impl="pallas", remat=False)
-    params = _on(jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))), one_chip)
-    cache = _on(jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), slots, S)), one_chip)
-    return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, slots)), one_chip)
-
-
 def test_latent_attention_kernel_compiles_for_v5e_and_copies_nothing(one_chip, as_on_a_tpu):
     """The latent tile passes the gate (and PR 35's tiles still do): a Mosaic kernel under its own
     name, the latent rows and the rotated keys read where they lie, no temporary to speak of."""
@@ -556,21 +535,15 @@ def test_latent_attention_kernel_compiles_for_v5e_and_copies_nothing(one_chip, a
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_glm_fused_step_reads_the_latent_rows_where_they_lie(one_chip, as_on_a_tpu):
+def test_glm_fused_step_reads_the_latent_rows_where_they_lie(fused_step_for_the_chip, as_on_a_tpu):
     """The fused step at 16 x 16,384 through the SAME ``hybrid_runner.fused_step`` and layer loop as
     the two hybrids (head ``mla ffn``, then ``mla moe`` x 7 scanned): the whole latent cache aliased
     to the donated input, under 32 MiB of temporaries, and no slice of a layer's rows (256 MiB of
     latents at 16 x 16,384) in the compiled text."""
     import re
 
-    from ray_tpu.llm import hybrid_runner as hr
-
-    cfg, params, cache, state = _glm_at_the_benchmarks_size(one_chip)
+    cfg, _, cache, state, compiled = fused_step_for_the_chip("glm")
     assert state == {} and cfg.layer_plan == (("mla", "moe"), 7, (), ("mla", "ffn"))
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    lanes = (s((16,), jnp.int32), s((16, 2), jnp.uint32), s((16,), jnp.float32), s((16,), jnp.int32), s((16,), jnp.float32))
-    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
-    compiled = step.lower(params, cache, state, *lanes, s((16,), jnp.bool_)).compile()
     mem, txt = compiled.memory_analysis(), compiled.as_text()
     assert "latent_decode_attention" in txt and not re.search(r"bf16\[1,16,16384,(512|128|640)\]", txt)
     assert _kv_bytes(cache) == 16 * 16384 * 10240 and mem.alias_size_in_bytes >= _kv_bytes(cache)
@@ -585,7 +558,7 @@ def test_glm_prefill_of_the_16384_bucket_fits_beside_weights_and_cache_on_one_v5
     the largest group the cell warms, beside 9.62 GiB of weights and the cache: under 15.75 GiB."""
     from ray_tpu.llm import hybrid_runner as hr
 
-    cfg, params, cache, _ = _glm_at_the_benchmarks_size(one_chip)
+    cfg, params, cache, _ = _cell_at_its_size(one_chip, "glm")
     tokens = jax.ShapeDtypeStruct((prompts, 16384), jnp.int32, sharding=one_chip)
     lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
     compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
@@ -603,7 +576,7 @@ def test_glm_prefill_of_the_16384_bucket_fits_beside_weights_and_cache_on_one_v5
 # matrices read from the stacked weights where they lie (ops/step_experts.py)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("cell, lanes_n, a_layers_experts_gb", [("nemotron", 32, 1.28), ("qwen3_next", 16, 0.81), ("glm", 16, 1.21)])
-def test_fused_step_walks_the_experts_hit_and_copies_no_layers_experts(one_chip, as_on_a_tpu, cell, lanes_n, a_layers_experts_gb):
+def test_fused_step_walks_the_experts_hit_and_copies_no_layers_experts(fused_step_for_the_chip, as_on_a_tpu, cell, lanes_n, a_layers_experts_gb):
     """Each hybrid cell's fused step at its own size: the gate lets the cell's expert through whole,
     the kernel is in the step, both caches are aliased to the donated inputs, temporaries stay
     under 0.1 GiB where one layer's held experts are 1.28 / 0.81 / 1.21 GB, and no array of a
@@ -611,23 +584,16 @@ def test_fused_step_walks_the_experts_hit_and_copies_no_layers_experts(one_chip,
     is anywhere in the program."""
     import re
 
-    from ray_tpu.llm import hybrid_runner as hr
     from ray_tpu.ops import step_experts
 
-    at_size = {"nemotron": _hybrid_at_the_benchmarks_size, "qwen3_next": _qwen3_next_at_the_benchmarks_size, "glm": _glm_at_the_benchmarks_size}
-    cfg, params, cache, state = at_size[cell](one_chip)
+    cfg, params, cache, state, compiled = fused_step_for_the_chip(cell)
     layers, held, F, H = params["moe"]["w_up"].shape
     matrices = len(cfg.expert_layer.matrices)
-    assert held == cfg.expert_layer.held and layers == cfg.count("moe")
+    assert held == cfg.expert_layer.held and layers == cfg.count("moe") and cache["length"].shape == (lanes_n,)
     assert abs(matrices * held * F * H * 2 / 1e9 - a_layers_experts_gb) < 0.01
     assert step_experts.refusal(jnp.bfloat16, H, F, matrices) is None and step_experts.tile_rows(F, H, matrices, 2) == F
     assert "float32" in step_experts.refusal(jnp.float32, H, F, matrices) and "128-lane" in step_experts.refusal(jnp.bfloat16, H + 64, F, matrices)
     assert step_experts.tile_rows(4 * 1856, 2688, 2, 2) == 1856 and "no tile" in step_experts.refusal(jnp.bfloat16, 128, 65537, 2)
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    n = lanes_n
-    lanes = (s((n,), jnp.int32), s((n, 2), jnp.uint32), s((n,), jnp.float32), s((n,), jnp.int32), s((n,), jnp.float32))
-    step = jax.jit(partial(hr.fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
-    compiled = step.lower(params, cache, state, *lanes, s((n,), jnp.bool_)).compile()
     mem, txt = compiled.memory_analysis(), compiled.as_text()
     assert "step_experts" in txt
     assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)) if a.ndim > 1)

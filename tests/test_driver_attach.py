@@ -19,6 +19,9 @@ _DRIVER_ENV = {
     "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
     "JAX_PLATFORMS": "cpu",
+    # what a head exports to everything it starts: ``address="auto"`` is then THIS worker's head, not the
+    # newest session on a machine where five other pytest workers start and stop heads of their own
+    "RT_SESSION_PID": str(os.getpid()),
 }
 
 
@@ -68,13 +71,36 @@ def test_external_driver_tasks_objects_and_named_actors(rt_start):
     assert ray_tpu.get(c.add.remote(1)) == 13
 
 
+def test_auto_is_the_session_a_process_belongs_to_not_the_newest_on_the_machine(rt_start):
+    """Another head's ``cluster_info.json``, newer than this one's and of a live process, does not
+    draw a driver that carries this head's pid in ``RT_SESSION_PID`` (``state.load_latest_cluster_info``)."""
+    import json
+    import shutil
+
+    from ray_tpu.util.state import session_dir
+
+    other = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"])
+    try:
+        os.makedirs(session_dir(other.pid), exist_ok=True)
+        with open(os.path.join(session_dir(other.pid), "cluster_info.json"), "w") as f:
+            json.dump({"pid": other.pid, "agent_address": ["127.0.0.1", 1], "authkey": "00"}, f)
+        p = _run_driver("from ray_tpu.util.state import load_latest_cluster_info as info; print('HEAD', info()['pid'])")
+        assert f"HEAD {os.getpid()}" in p.stdout, (p.stdout, p.stderr[-1500:])
+    finally:
+        other.kill()
+        other.wait()
+        shutil.rmtree(session_dir(other.pid), ignore_errors=True)
+
+
 def test_driver_attach_requires_authkey(rt_start):
     """A dialer without the session authkey must be rejected at the mp
     auth handshake — the same gate agents pass through."""
-    from ray_tpu.util.state import load_latest_cluster_info
+    import json
 
-    info = load_latest_cluster_info()
-    assert info is not None
+    from ray_tpu.util.state import session_dir
+
+    with open(os.path.join(session_dir(), "cluster_info.json")) as f:  # this head's own, not the machine's newest
+        info = json.load(f)
     host, port = info["agent_address"]
     p = _run_driver(
         f"""

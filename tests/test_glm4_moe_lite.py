@@ -14,9 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import reference
+import hybrid_battery as battery
 from benchmark.families import glm4_moe_lite as family
-from ray_tpu.llm import LLMEngine, SamplingParams
+from hybrid_battery import *  # noqa: F401,F403 - the tests every description is held to, collected here against DESC
+from hybrid_battery import engine
+from ray_tpu.llm import SamplingParams
 from ray_tpu.llm import hybrid_runner as hr
 from ray_tpu.llm import kv_cache as kvc
 from ray_tpu.models import glm4_moe_lite as glm
@@ -27,34 +29,44 @@ from ray_tpu.ops import slot_attention as sa
 C = family.rehearsal({"rope_theta": 1000000, "n_shared_experts": 1, "norm_topk_prob": True, "routed_scaling_factor": 1.8,
                       "rms_norm_eps": 1e-5, "family": "glm4_moe_lite"})
 CFG = family.program_config(C, 128, remat=False)
+
+
+def _a_wrong_cache(fault):
+    """The mistakes a latent cache invites: the cached key kept before its rotation, the latent kept
+    before its norm, and the latent rounded to bfloat16 (the nearest precision below this test's
+    float32) on its way into the cache."""
+    def wrap(real):
+        def down(w, xn, positions, c):
+            c_q, c_kv, k_r, rope = real(w, xn, positions, c)
+            if fault == "key_not_rotated":
+                k_r = jnp.pad(jnp.dot(xn, w["w_kva"])[..., c.kv_lora_rank:], ((0, 0), (0, 0), (0, c.rope_row - c.qk_rope_head_dim)))
+            elif fault == "latent_not_normed":
+                c_kv = jnp.dot(xn, w["w_kva"])[..., :c.kv_lora_rank]
+            else:
+                c_kv = c_kv.astype(jnp.bfloat16).astype(c_kv.dtype)
+            return c_q, c_kv, k_r, rope
+        return down
+    return battery.patched(glm, "mla_down", wrap)
+
+
 # float32 program against float32 reference: the same mathematics summed in another order (the
 # absorbed products, the grouped matmul, blocks of queries). They agree to 2e-6 in a
 # log-probability; what a wrong rotation, a missed position or a cache row of another slot does
 # is over 1e-2 (the faults below)
-TOL = 1e-4
+DESC = battery.Description(
+    family=family, c=C, cfg=CFG, tol=1e-4, agrees_to=2e-5,
+    state_bytes_per_slot=0, kv_bytes_per_token=3 * (32 + 128) * 4,  # the rotated key in whole lane tiles: 128, not the published 4
+    # the latent is a value too, and a masked position still multiplies its value by zero in the XLA form: large, not NaN
+    poison={"c_kv": 1e4, "k_r": jnp.nan},
+    faults={"key_not_rotated": battery.Fault(_a_wrong_cache("key_not_rotated"), over=100, margin=True),
+            "latent_not_normed": battery.Fault(_a_wrong_cache("latent_not_normed"), over=100, margin=True),
+            "bfloat16_latent": battery.Fault(_a_wrong_cache("bfloat16_latent"), over=3, margin=True)},
+    refusal_says=("keep c_kv and k_r per position",), refusal_says_not=("recurrent",))
 
 
 @pytest.fixture(scope="module")
 def params():
     return jax.jit(lambda k: glm.init_params(CFG, k))(jax.random.PRNGKey(11))
-
-
-def prompts(seed, lengths):
-    rs = np.random.RandomState(seed)
-    return [[int(t) for t in rs.randint(1, C["vocab_size"] - 1, size=n)] for n in lengths]
-
-
-def engine(params, cfg=CFG, **kw):
-    return LLMEngine(cfg, params, **{"max_num_seqs": 4, "max_seq_len": 128, "prefill_buckets": (16, 32, 64), **kw})
-
-
-def served(outs, ps, sampling):
-    return [{"prompt": p, "tokens": o.token_ids, "logprobs": o.logprobs, "greedy": sp.temperature == 0.0}
-            for o, p, sp in zip(outs, ps, sampling)]
-
-
-def check(params, samples, tol=TOL):
-    return reference.check_served(family.reference_logprobs, params, C, samples, tol)
 
 
 # ------------------------------------------------------------------------------ the description
@@ -139,13 +151,6 @@ def test_the_slot_cache_is_allocated_from_cache_spec_for_all_three_descriptions(
 
 
 # ------------------------------------------------------------------ the program against the reference
-def test_sequence_forward_matches_the_reference(params):
-    toks = prompts(3, (50,))[0]
-    logits = hybrid.forward(params, jnp.asarray([toks], jnp.int32), CFG)
-    want = family.reference_logprobs(params, toks, C, 0, len(toks))
-    np.testing.assert_allclose(np.asarray(jax.nn.log_softmax(logits[0], axis=-1)), np.asarray(want), atol=2e-5, rtol=0)
-
-
 def test_the_counts_are_the_programs(params):
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
     assert n == CFG.num_params() == family.parameters_held(C)
@@ -157,7 +162,7 @@ def test_the_absorbed_step_equals_the_expanded_sequence_form_position_by_positio
     (the expanded form: keys and values of every head), at every position of two sequences of
     different lengths, one lane left unbound."""
     T = 24
-    toks = np.asarray(prompts(4, (T, T)), np.int32)
+    toks = np.asarray(battery.prompts(DESC, 4, (T, T)), np.int32)
     want = np.asarray(hybrid.forward(params, jnp.asarray(toks), CFG))  # [2, T, V]
     cache = kvc.alloc_entries(CFG.position_entries(), 3, 32)
     step = jax.jit(partial(hr.decode_step, cfg=CFG))
@@ -174,76 +179,13 @@ def test_the_absorbed_step_equals_the_expanded_sequence_form_position_by_positio
     assert not np.asarray(rows["k_r"])[..., CFG.qk_rope_head_dim:].any() and np.asarray(rows["k_r"])[..., :CFG.qk_rope_head_dim].any()
 
 
-def test_prefill_then_decode_through_the_engine_matches_the_reference(params):
-    """Admission waves of batched same-bucket prefills at lengths off the bucket, more requests
-    than slots (so slots are recycled), greedy and seeded, an abort in the middle, and before the
-    second round every slot's old rows poisoned: all of it against the reference's full forward,
-    log-probabilities within TOL."""
-    eng = engine(params)
-    lengths = (5, 19, 23, 40, 7, 33, 18, 61, 9)
-    ps = prompts(1, lengths)
-    sampling = [SamplingParams(max_tokens=10, temperature=0.0 if i % 3 else 0.8, top_p=0.95, seed=i, logprobs=True)
-                for i in range(len(ps))]
-    ids = [eng.add_request(p, sp) for p, sp in zip(ps, sampling)]
-    finals, steps = {}, 0
-    while eng.has_unfinished():
-        steps += 1
-        if steps == 4:
-            assert eng.abort_request(ids[1])
-        finals.update({o.request_id: o for o in eng.step() if o.finished})
-    keep = [i for i in range(len(ps)) if i != 1]
-    res = check(params, served([finals[ids[i]] for i in keep], [ps[i] for i in keep], [sampling[i] for i in keep]))
-    assert res["ok"] and res["tokens"] == 80 and res["max_abs_dlogprob"] < 2e-5, res
+def test_the_latent_cache_is_counted_as_the_chip_stores_it(eng):
+    """What the family counts is the latent and the key as published; the chip stores the key in whole lane tiles."""
     stats = eng.kv_cache_stats()
     assert stats["bytes_per_token"] == 3 * (32 + 128) * 4 and stats["allocated_bytes"] == 4 * 128 * stats["bytes_per_token"]
     assert stats["entries"] == {"c_kv": [3, [32], "float32"], "k_r": [3, [128], "float32"]}
-    assert (stats["state_bytes_per_slot"], stats["state_allocated_bytes"]) == (0, 0) and eng.state == {} and eng.prefix_cache_stats() == {}
-    # what the family counts is the latent and the key as published; the chip stores the key in whole lane tiles
+    assert (stats["state_bytes_per_slot"], stats["state_allocated_bytes"]) == (0, 0) and eng.state == {} and set(eng.cache) == {"c_kv", "k_r", "length"}
     assert family.kv_bytes_per_token(C, itemsize=4) == 3 * (32 + 4) * 4 < stats["bytes_per_token"]
-    # every slot has held a sequence by now: poison what they left (the latent is a value too, and a
-    # masked position still multiplies its value by zero in the XLA form: large, not NaN), then serve again
-    eng.cache = {**eng.cache, "c_kv": jnp.full_like(eng.cache["c_kv"], 1e4), "k_r": jnp.full_like(eng.cache["k_r"], jnp.nan)}
-    ps2 = prompts(2, (31, 12, 50, 6, 17))
-    sp2 = [SamplingParams(max_tokens=8, temperature=0.0, logprobs=True)] * len(ps2)
-    res = check(params, served(eng.generate(ps2, sp2), ps2, sp2))
-    assert res["ok"] and res["tokens"] == 40, res
-    rows = [s for s in eng.telemetry()["steps"] if "experts_hit" in s]
-    assert rows and all(0 < r["experts_hit"] <= 8 and r["moe_pairs_local"] == r["moe_pairs_total"] for r in rows), "every expert is held here"
-    assert all(r["experts_read"] == r["experts_hit"] for r in rows), "a decode step reads the experts its lanes hit, and no others (PR 37)"
-
-
-def test_the_synchronous_loop_is_the_fused_steps_oracle(params):
-    ps = prompts(8, (9, 30, 14))
-    sp = [SamplingParams(max_tokens=8, temperature=0.0, logprobs=True)] * len(ps)
-    fused = engine(params).generate(ps, sp)
-    sync = engine(params, device_resident=False).generate(ps, sp)
-    assert [o.token_ids for o in fused] == [o.token_ids for o in sync]
-    np.testing.assert_allclose([o.logprobs for o in fused], [o.logprobs for o in sync], atol=1e-6)
-
-
-@pytest.mark.parametrize("fault", ["key_not_rotated", "latent_not_normed", "bfloat16_latent"])
-def test_the_comparison_fails_what_a_wrong_cache_would_serve(params, fault, monkeypatch):
-    """The tolerance is tight enough for the mistakes a latent cache invites: the cached key kept
-    before its rotation, the latent kept before its norm, and the latent rounded to bfloat16 (the
-    nearest precision below this test's float32) on its way into the cache."""
-    real = glm.mla_down
-
-    def down(w, xn, positions, c):
-        c_q, c_kv, k_r, rope = real(w, xn, positions, c)
-        if fault == "key_not_rotated":
-            k_r = jnp.pad(jnp.dot(xn, w["w_kva"])[..., c.kv_lora_rank:], ((0, 0), (0, 0), (0, c.rope_row - c.qk_rope_head_dim)))
-        elif fault == "latent_not_normed":
-            c_kv = jnp.dot(xn, w["w_kva"])[..., :c.kv_lora_rank]
-        else:
-            c_kv = c_kv.astype(jnp.bfloat16).astype(c_kv.dtype)
-        return c_q, c_kv, k_r, rope
-
-    ps = prompts(6, (21, 38, 11, 27))
-    sp = [SamplingParams(max_tokens=16, temperature=0.0, logprobs=True)] * len(ps)
-    assert check(params, served(engine(params).generate(ps, sp), ps, sp))["ok"]
-    monkeypatch.setattr(glm, "mla_down", down)
-    res = check(params, served(engine(params).generate(ps, sp), ps, sp))
-    assert not res["ok"] and max(res["max_abs_dlogprob"], res["max_margin"]) > (3 * TOL if fault == "bfloat16_latent" else 1e-2), res
 
 
 def test_a_sigmoid_router_with_its_bias_chooses_as_the_reference_does(params):
@@ -251,7 +193,7 @@ def test_a_sigmoid_router_with_its_bias_chooses_as_the_reference_does(params):
     by the scores themselves. With a bias that matters (random, as large as the scores' spread) the
     program still chooses what the reference chooses and agrees with it."""
     biased = {**params, "moe": {**params["moe"], "router_bias": 0.5 * jax.random.normal(jax.random.PRNGKey(2), params["moe"]["router_bias"].shape)}}
-    toks = prompts(9, (40,))[0]
+    toks = battery.prompts(DESC, 9, (40,))[0]
     choices = []
     family.hidden_states(biased, toks + [0] * (-len(toks) % family.PAD_TO), C, choices)
     plain = []
@@ -260,22 +202,6 @@ def test_a_sigmoid_router_with_its_bias_chooses_as_the_reference_does(params):
     logits = hybrid.forward(biased, jnp.asarray([toks], jnp.int32), CFG)
     want = family.reference_logprobs(biased, toks, C, 0, len(toks))
     np.testing.assert_allclose(np.asarray(jax.nn.log_softmax(logits[0], axis=-1)), np.asarray(want), atol=2e-5, rtol=0)
-
-
-def test_serves_through_the_openai_server_streaming(params):
-    from ray_tpu.serve.llm import LLMConfig, OpenAIServer
-
-    srv = OpenAIServer(LLMConfig(model_config=CFG, params=params, model_id="toy-latent",
-                                 engine_kwargs={"max_num_seqs": 4, "max_seq_len": 128, "prefill_buckets": (16, 32, 64)}))
-    try:
-        assert srv.engine._hybrid and srv.engine._device_resident
-        p = prompts(5, (26,))[0]
-        chunks = list(srv({"prompt": p, "max_tokens": 6, "stream": True}))
-        assert chunks[-1].startswith("data: [DONE]") and len(chunks) >= 7
-        out = srv.generate(p, {"max_tokens": 6, "logprobs": True})
-        assert check(params, [{"prompt": p, "tokens": out["token_ids"], "logprobs": out["logprobs"], "greedy": True}])["ok"]
-    finally:
-        srv.shutdown()
 
 
 # ------------------------------------------------------------------------------ the latent kernel
@@ -367,16 +293,16 @@ def test_the_engine_runs_the_latent_kernel_where_the_gate_allows_and_counts_its_
     """More requests than slots with the kernel forced on (interpreted): the same greedy tokens as
     the XLA form, the stacked entries handed over whole, and the flight log's step rows carry the
     blocks the step reads and the blocks the cache holds, over the layers that keep a latent."""
-    ps = prompts(7, (5, 21, 9, 14, 3))
+    ps = battery.prompts(DESC, 7, (5, 21, 9, 14, 3))
     sp = [SamplingParams(max_tokens=8, temperature=0.0) for _ in ps]
     kw = dict(max_num_seqs=2, max_seq_len=128, prefill_buckets=(16, 32))
-    plain = engine(params, **kw)
+    plain = engine(CFG, params, **kw)
     assert plain._attn_block is None
     want = [o.token_ids for o in plain.generate(ps, sp)]
     calls, real = [], sa.attend_latent_kernel
     monkeypatch.setattr(sa, "attend_latent_kernel", lambda *a, **k: (calls.append((a[2].shape, a[3].shape)), real(*a, **k))[1])
     monkeypatch.setattr(sa, "refusal", lambda *a, **k: None)
-    eng = engine(params, **kw)
+    eng = engine(CFG, params, **kw)
     assert eng._attn_block == 128, "one block of 128 positions a lane at this toy size"
     assert [o.token_ids for o in eng.generate(ps, sp)] == want
     assert calls and all(shapes == ((3, 2, 128, 32), (3, 2, 128, 128)) for shapes in calls), "the stacked entries, not a layer's rows"
@@ -390,12 +316,12 @@ def test_a_wave_that_does_not_fit_the_devices_free_memory_goes_through_in_severa
     The ENGINE bounds one prefill program by what the device has free and by the compiler's own
     account of the shapes that have run (no constant of this description): a same-bucket wave
     beyond it goes through in runs of a power of two of prompts, and serves what it would have."""
-    ps = prompts(12, (20, 30, 25, 31, 19, 60, 40))  # five in the 32 bucket, two in the 64 bucket
+    ps = battery.prompts(DESC, 12, (20, 30, 25, 31, 19, 60, 40))  # five in the 32 bucket, two in the 64 bucket
     sp = [SamplingParams(max_tokens=6, temperature=0.0, logprobs=True)] * len(ps)
-    free = engine(params, max_num_seqs=8)
-    assert free._prefill_room is None and "prefill_room_bytes" not in free.kv_cache_stats(), "the CPU keeps no account: no bound"
-    want = [o.token_ids for o in free.generate(ps, sp)]
-    eng = engine(params, max_num_seqs=8)
+    eng = engine(CFG, params, max_num_seqs=8)
+    assert eng._prefill_room is None and "prefill_room_bytes" not in eng.kv_cache_stats(), "the CPU keeps no account: no bound"
+    want = [o.token_ids for o in eng.generate(ps, sp)]
+    assert eng._prefill_need == {}
     eng._prefill_room = 1 << 60  # a device that keeps an account, first with room for anything: the warm-up
     eng.generate(ps[:2], sp[:2])
     assert set(eng._prefill_need) == {(2, 32)} and eng._prefill_need[2, 32] > 2 * 32 * 4 * CFG.hidden_size
@@ -403,7 +329,7 @@ def test_a_wave_that_does_not_fit_the_devices_free_memory_goes_through_in_severa
     runs, real = [], eng._admit_prefill_batch
     eng._admit_prefill_batch = lambda group: (runs.append(len(group)), real(group))[1]
     outs = eng.generate(ps, sp)
-    assert [o.token_ids for o in outs] == want and check(params, served(outs, ps, sp))["ok"]
+    assert [o.token_ids for o in outs] == want and battery.check(DESC, params, battery.served(outs, ps, sp))["ok"]
     assert sorted(runs) == [1, 1, 1, 2, 2] and set(eng._prefill_need) == {(1, 32), (2, 32), (1, 64)}, "two of 32, one of 64"
     stats = eng.kv_cache_stats()
     assert stats["prefill_room_bytes"] == eng._prefill_room and set(stats["prefill_program_bytes"]) == {"1x32", "2x32", "1x64"}

@@ -25,9 +25,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(ray_tpu.__file__)))
 PKG = os.path.join(ROOT, "ray_tpu")
 
 
-def test_self_check_no_new_findings():
-    findings = lint_paths([PKG], root=ROOT)
-    d = bl.diff(findings, bl.load(bl.default_baseline_path()))
+@pytest.fixture(scope="module")
+def tree_findings():
+    """The whole tree linted ONCE, with the default catalog (TPL, CCR and ERR together:
+    ``lint/rules/__init__.py``): every self-check below reads this one result, a catalog's
+    check its own rules' findings out of it."""
+    return lint_paths([PKG], root=ROOT)
+
+
+def test_self_check_no_new_findings(tree_findings):
+    d = bl.diff(tree_findings, bl.load(bl.default_baseline_path()))
     assert d.new == [], (
         "tpulint found NEW hazards (fix them, or accept deliberate ones "
         "with `python -m ray_tpu.lint ray_tpu/ --update-baseline`):\n"
@@ -35,9 +42,8 @@ def test_self_check_no_new_findings():
     )
 
 
-def test_self_check_baseline_not_stale():
-    findings = lint_paths([PKG], root=ROOT)
-    d = bl.diff(findings, bl.load(bl.default_baseline_path()))
+def test_self_check_baseline_not_stale(tree_findings):
+    d = bl.diff(tree_findings, bl.load(bl.default_baseline_path()))
     assert d.stale == [], (
         "baseline entries no longer reproduce (a finding was fixed): "
         "re-run --update-baseline to shrink the baseline:\n"
@@ -174,7 +180,7 @@ def test_module_entrypoint_and_rt_wiring():
 
 
 # ============================================================ concur gate
-def test_ccr_self_check_clean_modulo_baseline():
+def test_ccr_self_check_clean_modulo_baseline(tree_findings):
     """The concurrency-discipline pass over ray_tpu/ itself: every
     blocking-under-lock / hot-path-sync hazard is either fixed or a
     baseline entry with a hand-written why (the deliberate ones: the
@@ -182,9 +188,9 @@ def test_ccr_self_check_clean_modulo_baseline():
     fails tier-1 — including any regression of the admission-path prefix
     fetch, whose item-3a debt entries were RETIRED when the fetch moved
     off the engine lock (the async fetch worker)."""
-    from ray_tpu.lint.concur import all_concur_rules, concur_rule_ids
+    from ray_tpu.lint.concur import concur_rule_ids
 
-    findings = lint_paths([PKG], root=ROOT, rules=all_concur_rules())
+    findings = [f for f in tree_findings if f.rule in concur_rule_ids()]
     ccr_ids = concur_rule_ids() | {"TPL004"}
     entries = {fp: e for fp, e in bl.load(bl.default_baseline_path()).items()
                if e["rule"] in ccr_ids}
@@ -199,7 +205,7 @@ def test_ccr_self_check_clean_modulo_baseline():
     assert d.suppressed >= 7
 
 
-def test_ccr_baseline_holds_no_stale_roadmap_debt():
+def test_ccr_baseline_holds_no_stale_roadmap_debt(tree_findings):
     """A baseline entry citing a ROADMAP item as accepted DEBT must stop
     existing once the code stops tripping the rule — debt entries that
     outlive their hazard would silently mask a regression reintroducing
@@ -219,9 +225,9 @@ def test_ccr_baseline_holds_no_stale_roadmap_debt():
     assert not any(e["path"].endswith("llm/engine.py") for e in entries.values())
     # the stale-drop path proves the remaining ledger is live: a full
     # concur pass uses every entry it keeps (bl.diff flags unused budget)
-    from ray_tpu.lint.concur import all_concur_rules, concur_rule_ids
+    from ray_tpu.lint.concur import concur_rule_ids
 
-    findings = lint_paths([PKG], root=ROOT, rules=all_concur_rules())
+    findings = [f for f in tree_findings if f.rule in concur_rule_ids()]
     ccr_ids = concur_rule_ids() | {"TPL004"}
     ccr_entries = {fp: e for fp, e in entries.items() if e["rule"] in ccr_ids}
     d = bl.diff(findings, ccr_entries)
@@ -255,8 +261,8 @@ def test_cli_select_ccr001_runs_only_that_rule(tmp_path, capsys):
 
 
 def test_cli_concur_flag_scopes_to_ccr_catalog(tmp_path, capsys):
-    # --concur over the tree runs clean against the committed baseline
-    assert lint_main([PKG, "--root", ROOT, "--concur"]) == 0
+    # --concur over the subtree that holds the baseline's entries runs clean against the committed baseline
+    assert lint_main([CORE, "--root", ROOT, "--concur"]) == 0
     # and it implies the CCR selection: a TPL002 drop is NOT reported
     bad = tmp_path / "bad.py"
     bad.write_text("def kick(actor):\n    actor.ping.remote()\n")
@@ -328,7 +334,8 @@ def test_jaxcheck_traces_at_least_thirty_entries():
 def test_cli_jax_flag_and_rt_wiring():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
-        [sys.executable, "-m", "ray_tpu.scripts.cli", "lint", "ray_tpu", "--root", ROOT, "--jax"],
+        # the AST pass over a small subtree (test_module_entrypoint_and_rt_wiring lints the whole one); --jax traces every entry whatever the path
+        [sys.executable, "-m", "ray_tpu.scripts.cli", "lint", "ray_tpu/ops", "--root", ROOT, "--jax"],
         capture_output=True, text=True, cwd=ROOT, env=env, timeout=600,
     )
     assert r.returncode == 0, r.stdout + r.stderr
@@ -461,16 +468,16 @@ def test_cli_jax_only_select_skips_ast_but_validates_paths(tmp_path):
 
 
 # ============================================================ fault gate
-def test_err_self_check_clean_modulo_baseline():
+def test_err_self_check_clean_modulo_baseline(tree_findings):
     """The fault-discipline pass over ray_tpu/ itself: every swallowed
     exception / non-taxonomy raise / dropped cause chain / unbounded
     retry or transport wait is either fixed or a baseline entry with a
     hand-written why (the deliberate ones: the direct plane's best-effort
     probes, telemetry's never-load-bearing emits, the proxies'
     gone-client closes). Any NEW ERR finding fails tier-1."""
-    from ray_tpu.lint.fault import all_fault_rules, fault_rule_ids
+    from ray_tpu.lint.fault import fault_rule_ids
 
-    findings = lint_paths([PKG], root=ROOT, rules=all_fault_rules())
+    findings = [f for f in tree_findings if f.rule in fault_rule_ids()]
     err_ids = fault_rule_ids() | {"TPL007"}
     entries = {fp: e for fp, e in bl.load(bl.default_baseline_path()).items()
                if e["rule"] in err_ids}
@@ -503,8 +510,8 @@ def test_err_baseline_entries_all_carry_written_whys():
 
 
 def test_cli_fault_flag_scopes_to_err_catalog(tmp_path, capsys):
-    # --fault over the tree runs clean against the committed baseline
-    assert lint_main([PKG, "--root", ROOT, "--fault"]) == 0
+    # --fault over the subtree that holds the baseline's entries runs clean against the committed baseline
+    assert lint_main([CORE, "--root", ROOT, "--fault"]) == 0
     # and it implies the ERR selection: a TPL002 drop is NOT reported...
     bad = tmp_path / "bad.py"
     bad.write_text("def kick(actor):\n    actor.ping.remote()\n")

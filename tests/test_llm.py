@@ -23,17 +23,27 @@ def params():
     return init_params(CFG, jax.random.PRNGKey(0))
 
 
+@pytest.fixture(scope="module")
+def eng(params):
+    """ONE engine of two slots for the tests that leave it idle as they found it: built and compiled once."""
+    return LLMEngine(CFG, params, max_num_seqs=2, max_seq_len=64)
+
+
+_padded_forward = jax.jit(lambda params, toks: forward(params, toks, CFG))
+
+
 def full_forward_greedy(params, prompt, n_tokens):
-    """Oracle: recompute the whole sequence every step, argmax last logit."""
+    """Oracle: recompute the whole sequence every step, argmax last logit. The sequence is padded
+    to one length (a causal model's logits at a position do not see what follows it), so the
+    forward compiles once and not once a length."""
     toks = list(prompt)
     for _ in range(n_tokens):
-        logits = forward(params, jnp.asarray([toks]), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
+        logits = _padded_forward(params, jnp.asarray([toks + [0] * (CFG.max_seq_len - len(toks))]))
+        toks.append(int(jnp.argmax(logits[0, len(toks) - 1])))
     return toks[len(prompt):]
 
 
-def test_greedy_decode_matches_full_forward(params):
-    eng = LLMEngine(CFG, params, max_num_seqs=2, max_seq_len=64)
+def test_greedy_decode_matches_full_forward(params, eng):
     prompt = [3, 17, 40, 7, 99]
     out = eng.generate(prompt, SamplingParams(max_tokens=12, temperature=0.0))
     oracle = full_forward_greedy(params, prompt, 12)
@@ -49,9 +59,8 @@ def test_batched_prompts_match_sequential(params):
         assert o.token_ids == full_forward_greedy(params, p, 8), f"prompt {p}"
 
 
-def test_continuous_batching_under_load(params):
+def test_continuous_batching_under_load(params, eng):
     """10 requests through 2 slots: all finish, each correct."""
-    eng = LLMEngine(CFG, params, max_num_seqs=2, max_seq_len=64)
     prompts = [[i + 1, i + 2] for i in range(10)]
     ids = [eng.add_request(p, SamplingParams(max_tokens=5)) for p in prompts]
     assert eng.num_waiting == 10
@@ -71,8 +80,7 @@ def test_continuous_batching_under_load(params):
         assert finals[rid].token_ids == full_forward_greedy(params, p, 5)
 
 
-def test_stop_tokens_and_abort(params):
-    eng = LLMEngine(CFG, params, max_num_seqs=2, max_seq_len=64)
+def test_stop_tokens_and_abort(params, eng):
     # discover greedy token stream, then use its 3rd token as a stop id
     oracle = full_forward_greedy(params, [4, 4], 6)
     stop = oracle[2]
@@ -87,8 +95,7 @@ def test_stop_tokens_and_abort(params):
     assert not eng.abort_request(rid)  # already gone
 
 
-def test_sampling_seeded_and_temperature(params):
-    eng = LLMEngine(CFG, params, max_num_seqs=2, max_seq_len=64)
+def test_sampling_seeded_and_temperature(params, eng):
     sp = SamplingParams(max_tokens=10, temperature=1.0, seed=7)
     a = eng.generate([2, 3], sp).token_ids
     b = eng.generate([2, 3], sp).token_ids
@@ -98,14 +105,12 @@ def test_sampling_seeded_and_temperature(params):
     assert a != c or len(set(a)) == 1
 
 
-def test_top_k_one_is_greedy(params):
-    eng = LLMEngine(CFG, params, max_num_seqs=1, max_seq_len=64)
+def test_top_k_one_is_greedy(params, eng):
     out = eng.generate([9, 9], SamplingParams(max_tokens=8, temperature=5.0, top_k=1, seed=0))
     assert out.token_ids == full_forward_greedy(params, [9, 9], 8)
 
 
-def test_streaming(params):
-    eng = LLMEngine(CFG, params, max_num_seqs=1, max_seq_len=64)
+def test_streaming(params, eng):
     rid = eng.add_request([5, 6], SamplingParams(max_tokens=4), stream=True)
     st = eng._requests[rid]
     got = []

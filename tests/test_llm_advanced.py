@@ -8,15 +8,16 @@ engine pool -> dataset). Parity here is exact greedy-token equality with
 the full-recompute oracle.
 """
 
-import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
 import ray_tpu  # noqa: E402
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, forward, init_params  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from test_llm import full_forward_greedy as oracle  # noqa: E402 - greedy by the padded full forward, of the same toy configuration
+
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
 
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=128)
 GREEDY = SamplingParams(max_tokens=6, temperature=0.0)
@@ -25,14 +26,6 @@ GREEDY = SamplingParams(max_tokens=6, temperature=0.0)
 @pytest.fixture(scope="module")
 def params():
     return init_params(CFG, jax.random.PRNGKey(0))
-
-
-def oracle(params, prompt, n):
-    toks = list(prompt)
-    for _ in range(n):
-        logits = forward(params, jnp.asarray([toks]), CFG)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 # ---------------------------------------------------------------- prefix cache

@@ -54,6 +54,8 @@ from ray_tpu.serve.overload import (  # noqa: E402
     is_overloaded,
 )
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=128)
 SP = SamplingParams(max_tokens=6, temperature=0.0)
 RNG = np.random.default_rng(11)
@@ -680,6 +682,17 @@ def _kv_router_pair(params, **sp_defaults):
     return srv0, srv1, router
 
 
+def _hold_in_flight():
+    """Slow every stepper tick (the chaos plane's own delay rule, until the next ``chaos.clear()``)
+    for a test that needs its request IN FLIGHT when a notice lands: sixteen tokens of the toy
+    model are over in some 16 ms on an idle machine, before ``_wait_tokens`` has looked twice
+    or the preemption has taken the engine's lock. Without it
+    ``test_chaos_preempt_seeded_and_checkpoint_lost`` failed 8 of 10 runs ALONE (PR 40: "never
+    reached 4 tokens in flight" six times, ``resumed == 0`` twice) and passed beside five busy
+    workers. At 50 ms a tick the notice has 400 ms and more to land in."""
+    chaos.inject("serve.step", delay_s=0.05)
+
+
 def _wait_tokens(srv, n, deadline_s=30.0):
     deadline = time.time() + deadline_s
     while time.time() < deadline:
@@ -725,6 +738,7 @@ def test_chaos_preempt_migrates_inflight_to_peer(params, rt, oracle):
 
         th1 = threading.Thread(target=client_router)
         th2 = threading.Thread(target=client_direct)
+        _hold_in_flight()
         th1.start(), th2.start()
         _wait_tokens(srv0, 4)
         # the preemption notice: SIGTERM-with-deadline, delivered once
@@ -778,6 +792,7 @@ def test_chaos_preempt_seeded_and_checkpoint_lost(params, rt, oracle):
             results["out"] = router.generate(list(PROMPT), dict(sp))
 
         th = threading.Thread(target=client)
+        _hold_in_flight()
         th.start()
         _wait_tokens(srv0, 4)
         chaos.inject("serve.preempt", drop_prob=1.0, max_hits=1)
@@ -810,6 +825,7 @@ def test_chaos_preempt_seeded_and_checkpoint_lost(params, rt, oracle):
                 results2["out"] = router2.generate(list(PROMPT), dict(sp))
 
             th2 = threading.Thread(target=client2)
+            _hold_in_flight()
             th2.start()
             _wait_tokens(srv1, 4)
             chaos.inject("direct.get_owned_view", raises=ObjectLostError, max_hits=8)
@@ -843,10 +859,12 @@ def test_preempt_deadline_zero_aborts_typed(params, rt, oracle):
             results["out"] = router.generate(list(PROMPT), {"max_tokens": 16, "temperature": 0.0})
 
         th = threading.Thread(target=client)
+        _hold_in_flight()
         th.start()
         _wait_tokens(srv0, 2)
         t0 = time.perf_counter()
         res = srv0.preempt(deadline_s=0.0)  # SIGTERM with no grace left
+        chaos.clear()
         th.join(timeout=120)
         assert not th.is_alive()
         assert time.perf_counter() - t0 < 60.0

@@ -32,6 +32,8 @@ from ray_tpu.llm.disagg import (  # noqa: E402
 )
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
 
 

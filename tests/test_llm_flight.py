@@ -122,7 +122,9 @@ def test_stages_sum_to_the_step_and_drain_wait_is_the_device(monkeypatch):
         assert sum(s[f] for f in STEP_STAGES) == pytest.approx(s["wall_ms"], rel=0.05)
         assert s["t0"] <= s["t"] and s["t"] - s["t0"] == pytest.approx(s["wall_ms"] * 1e-3, abs=2e-3)
     decode = [s for s in steps if s["phase"] == "decode"]
-    assert decode and all(s["drain_wait_ms"] > 0.8 * s["wall_ms"] for s in decode)
+    # the device's 20 ms are in ``drain_wait_ms`` and in no other stage; as a share of the step (0.8 of it, until PR 40)
+    # it failed beside five busy workers, whose host stages take more than 5 ms
+    assert decode and all(s["drain_wait_ms"] >= 19.0 for s in decode)
     # the fused program was enqueued inside the step, before the host went to wait for the last one
     assert all(s["t0"] <= s["dispatch_t"] <= s["t"] - 0.015 for s in decode if s["batch"])
     admitting = [s for s in eng.telemetry()["steps"] if s.get("admitted")]

@@ -28,6 +28,8 @@ from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
 from ray_tpu.llm.kv_quant import bytes_per_token, normalize_cache_dtype  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
 PERIOD = 8
 GREEDY = SamplingParams(temperature=0.0, max_tokens=12)

@@ -38,10 +38,13 @@ jax = pytest.importorskip("jax")
 
 import ray_tpu  # noqa: E402
 from ray_tpu import chaos  # noqa: E402
-from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
+from ray_tpu.llm import SamplingParams  # noqa: E402
 from ray_tpu.llm.kvplane import KVPlaneClient, PrefixIndex, boundary_keys  # noqa: E402
 from ray_tpu.llm.migrate import MigrationError, MigrationLostError  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from test_llm_migrate import _mk, _run_until  # noqa: E402 - an engine of the same toy configuration; stepping by the clock
+
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
 
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=128)
 SP = SamplingParams(max_tokens=6, temperature=0.0)
@@ -76,32 +79,12 @@ def oracle_fp(params):
 
 
 def _engine(params, plane=None, **kw):
-    kw.setdefault("max_num_seqs", 2)
-    kw.setdefault("max_seq_len", 128)
-    return LLMEngine(CFG, params, kv_plane=plane, **kw)
-
-
-def _mk(params, layout="slots", dtype=None, **kw):
-    kw.setdefault("max_num_seqs", 2)
-    kw.setdefault("max_seq_len", 128)
-    return LLMEngine(CFG, params, kv_layout=layout, cache_dtype=dtype, **kw)
+    return _mk(params, kv_plane=plane, **kw)
 
 
 def _client(idx, rid, **kw):
     kw.setdefault("publish_min_hits", 1)
     return KVPlaneClient(idx, rid, **kw)
-
-
-def _run_until(eng, rid, n_tokens, budget=500):
-    """Step until the request has emitted >= n_tokens (host view)."""
-    for _ in range(budget):
-        with eng._lock:
-            st = eng._requests.get(rid)
-            done = st is None or st.finished or len(st.token_ids) >= n_tokens
-        if done:
-            return
-        eng.step()
-    raise AssertionError(f"request never reached {n_tokens} tokens")
 
 
 def _drain(eng, rid):

@@ -48,6 +48,8 @@ from ray_tpu.llm.kvplane import (  # noqa: E402
 from ray_tpu.llm.kvplane.index import prefix_key  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=128)
 SP = SamplingParams(max_tokens=6, temperature=0.0)
 RNG = np.random.default_rng(7)

@@ -12,6 +12,8 @@ the object-plane publish/fetch lifecycle (MigrationLostError bounded,
 never a hang), and both routers' resume-on-peer failover leg.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -33,7 +35,7 @@ from ray_tpu.llm.migrate import (  # noqa: E402
 from ray_tpu.llm.spec import SpecConfig  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
-pytestmark = pytest.mark.migrate
+pytestmark = [pytest.mark.migrate, pytest.mark.usefixtures("shared_step_programs")]  # an oracle, a source and a destination a case: one set of programs a configuration
 
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=128)
 RNG = np.random.default_rng(17)
@@ -55,15 +57,20 @@ def _mk(params, layout="slots", dtype=None, spec=False, **kw):
     return LLMEngine(CFG, params, kv_layout=layout, cache_dtype=dtype, **kw)
 
 
-def _run_until(eng, rid, n_tokens, budget=500):
-    """Step until the request has emitted >= n_tokens (host view)."""
-    for _ in range(budget):
+def _run_until(eng, rid, n_tokens, deadline_s=60.0):
+    """Step until the request has emitted >= n_tokens (host view). By the clock, not by a count of
+    steps: a request deferred behind its async prefix lookup makes ``step()`` return at once, and
+    500 such steps were over before the fetch worker had run once (beside five busy xdist
+    workers, PR 40's fifth whole run); a step that emits nothing yields to that worker."""
+    end = time.time() + deadline_s
+    while time.time() < end:
         with eng._lock:
             st = eng._requests.get(rid)
             done = st is None or st.finished or len(st.token_ids) >= n_tokens
         if done:
             return
-        eng.step()
+        if not eng.step():
+            time.sleep(0.001)
     raise AssertionError(f"request never reached {n_tokens} tokens")
 
 

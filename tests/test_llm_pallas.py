@@ -38,7 +38,7 @@ from ray_tpu.llm.kv_quant import quantize_heads  # noqa: E402
 from ray_tpu.llm.paged_kv import _paged_attn_batch, _paged_attn_seq_batch  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
-pytestmark = pytest.mark.pallas
+pytestmark = [pytest.mark.pallas, pytest.mark.usefixtures("shared_step_programs")]  # engines of equal configurations compile their step programs once (conftest.py)
 
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
 PAGE = 32

@@ -62,7 +62,7 @@ def _config(description):
 
 
 @pytest.fixture(scope="module")
-def lowered():
+def lowered(shared_step_programs):
     """description -> {program name: its lowering}, each description's engines run once."""
     done: dict = {}
 
@@ -70,7 +70,7 @@ def lowered():
         if description in done:
             return done[description]
         sink: dict = {}
-        real = model_runner.named_jit
+        real = model_runner.named_jit  # conftest's memo: the synchronous engine's prefill IS the fused engine's, compiled once
 
         def recording(name, fn, **kw):
             return Recording(name, real(name, fn, **kw), sink)

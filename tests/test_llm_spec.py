@@ -18,6 +18,8 @@ jax = pytest.importorskip("jax")
 from ray_tpu.llm import LLMEngine, SamplingParams, SpecConfig  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
 
 

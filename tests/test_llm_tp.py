@@ -30,6 +30,8 @@ from ray_tpu.llm.spec import SpecConfig  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.parallel.mesh import create_mesh  # noqa: E402
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(num_heads=4, num_kv_heads=4, dtype="float32", attention_impl="xla", max_seq_len=256)
 
 

@@ -19,6 +19,8 @@ from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.llm.sampling import SamplingParams
 from ray_tpu.models.llama import LlamaConfig, init_params
 
+pytestmark = pytest.mark.usefixtures("shared_step_programs")  # many engines of equal configurations: their step programs compile once (conftest.py)
+
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
 
 

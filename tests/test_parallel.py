@@ -73,8 +73,8 @@ def test_flash_attention_grads():
     def ref(q, k, v):
         return attention_xla(q, k, v, causal=True).sum()
 
-    g1 = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
@@ -140,8 +140,8 @@ def test_ring_attention_grads_match_reference():
     def ref(q, k, v):
         return (attention_xla(q, k, v, causal=True) ** 2).sum()
 
-    g1 = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
@@ -167,11 +167,11 @@ def test_ring_attention_chunked_path():
         out_specs=P(None, None, "sp", None),
         check_vma=False,
     )
-    out = fn(q, k, v)
+    out = jax.jit(fn)(q, k, v)
     ref = attention_xla(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-    g = jax.grad(lambda q, k, v: (fn(q, k, v) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: (attention_xla(q, k, v, causal=True) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(lambda q, k, v: (fn(q, k, v) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(lambda q, k, v: (attention_xla(q, k, v, causal=True) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
@@ -229,12 +229,13 @@ def test_pipeline_parallel_parity_and_training():
     batch = {"tokens": tokens, "targets": targets}
 
     np.testing.assert_allclose(
-        np.asarray(pp_forward(pp_params, tokens, cfg, mesh, num_microbatches=4)),
+        np.asarray(jax.jit(lambda p: pp_forward(p, tokens, cfg, mesh, num_microbatches=4))(pp_params)),
         np.asarray(forward(params, tokens, cfg)),
         atol=1e-5,
     )
-    g_ref = jax.grad(lambda p: loss_fn(p, batch, cfg))(params)
-    g_pp = jax.grad(lambda p: pp_loss_fn(p, batch, cfg, mesh, num_microbatches=4))(pp_params)
+    # one compiled program each: taken op by op, the pipeline's backward pass alone was 30 s of this test
+    g_ref = jax.jit(jax.grad(lambda p: loss_fn(p, batch, cfg)))(params)
+    g_pp = jax.jit(jax.grad(lambda p: pp_loss_fn(p, batch, cfg, mesh, num_microbatches=4)))(pp_params)
     g_pp = {**g_pp, "layers": from_stage_stacked(g_pp["layers"])}
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5),
@@ -296,12 +297,12 @@ def test_interleaved_pipeline_parity_and_training():
     )
 
     np.testing.assert_allclose(
-        np.asarray(pp_forward(pp_params, tokens, cfg, mesh, num_microbatches=4, virtual_stages=v)),
+        np.asarray(jax.jit(lambda p: pp_forward(p, tokens, cfg, mesh, num_microbatches=4, virtual_stages=v))(pp_params)),
         np.asarray(forward(params, tokens, cfg)),
         atol=1e-5,
     )
-    g_ref = jax.grad(lambda p: loss_fn(p, batch, cfg))(params)
-    g_pp = jax.grad(lambda p: pp_loss_fn(p, batch, cfg, mesh, num_microbatches=4, virtual_stages=v))(pp_params)
+    g_ref = jax.jit(jax.grad(lambda p: loss_fn(p, batch, cfg)))(params)
+    g_pp = jax.jit(jax.grad(lambda p: pp_loss_fn(p, batch, cfg, mesh, num_microbatches=4, virtual_stages=v)))(pp_params)
     g_pp = {**g_pp, "layers": from_stage_stacked(g_pp["layers"])}
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5),
@@ -359,7 +360,7 @@ def test_pp_sp_ring_attention_parity():
         np.asarray(forward(params, tokens, cfg)),
         atol=2e-4,
     )
-    g_ref = jax.grad(lambda p: loss_fn(p, batch, cfg))(params)
+    g_ref = jax.jit(jax.grad(lambda p: loss_fn(p, batch, cfg)))(params)
     g_pp = jax.jit(jax.grad(lambda p: pp_loss_fn(p, batch, cfg, mesh, num_microbatches=4)))(pp_params)
     g_pp = {**g_pp, "layers": from_stage_stacked(g_pp["layers"])}
     jax.tree.map(

@@ -22,11 +22,15 @@ def rt():
 
 
 def _rate(op, n):
+    """Calls a second by the BEST of ``n`` timed calls: a structural regression slows every call, five busy xdist workers
+    slow some (the mean of four read 216 tasks/s against a floor of 300 once in PR 40's whole runs; the best reads 16,800 alone)."""
     op()  # warm
-    t0 = time.perf_counter()
+    best = float("inf")
     for _ in range(n):
+        t0 = time.perf_counter()
         op()
-    return n / (time.perf_counter() - t0)
+        best = min(best, time.perf_counter() - t0)
+    return 1.0 / best
 
 
 def test_task_throughput_floor(rt):
@@ -113,42 +117,39 @@ def test_llm_engine_throughput_floor():
 
 
 def test_llm_int8_decode_step_floor():
-    """Int8-KV decode throughput floor: the quantized step must stay no
-    worse than 1.1x the bf16 step on CPU (interleaved best-of-N, so load
-    jitter hits both engines alike). A structural regression — dequant
-    materializing the full cache in f32 outside the fused step, a
-    per-step requant of old positions, a lost scale-lane donation —
-    shows up as the int8 step falling far behind bf16's."""
+    """Int8-KV decode step floor: the quantized fused step may cost no more than 1.1x the bf16
+    step. A structural regression (dequant materializing the full cache in f32 outside the fused
+    step, a per-step requant of old positions, a lost scale-lane donation) is what the gate is for.
+
+    Judged by the COMPILER'S account of the two programs the engines run, not by their wall
+    clock: until PR 40 this compared best-of-three step times of two engines, a ratio that reads
+    0.58-0.59 alone and that six xdist workers on one box swung past 1.1 (ROADMAP C11). Each
+    regression above has a count: a requant of old positions is arithmetic over the whole cache
+    every step (flops; the int8 step has 1.06x the bf16 step's here, for the dequant of the rows
+    it reads), a full-cache dequant is a temporary of the cache's size in f32 (temp bytes; the
+    two steps' differ by 32 bytes), a lost donation is a cache that is not aliased to its input."""
     pytest.importorskip("jax")
-    from ray_tpu.llm import LLMEngine, SamplingParams
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm import model_runner as mr
     from ray_tpu.models.llama import LlamaConfig
 
     cfg = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
-    B, P, G = 4, 32, 24
-    rng = np.random.default_rng(0)
-    prompts = [list(rng.integers(1, cfg.vocab_size - 1, size=P)) for _ in range(B)]
-    engines = {}
-    for dt in ("bfloat16", "int8"):
-        eng = LLMEngine(cfg, max_num_seqs=B, max_seq_len=128, enable_prefix_caching=False, cache_dtype=dt)
-        eng.generate(prompts, SamplingParams(max_tokens=2))  # compile everything
-        engines[dt] = eng
-    best = {dt: float("inf") for dt in engines}
-    for _ in range(3):  # interleaved rounds: jitter degrades both alike
-        for dt, eng in engines.items():
-            for p in prompts:
-                eng.add_request(p, SamplingParams(max_tokens=G))
-            while eng.num_waiting:
-                eng.step()
-            t0 = time.perf_counter()
-            steps = 0
-            while eng.has_unfinished():
-                eng.step()
-                steps += 1
-            best[dt] = min(best[dt], (time.perf_counter() - t0) / max(steps, 1))
-    assert best["int8"] <= 1.1 * best["bfloat16"], (
-        f"int8 decode step regressed past the 1.1x bf16 gate: "
-        f"int8 {best['int8'] * 1e3:.2f} ms vs bf16 {best['bfloat16'] * 1e3:.2f} ms"
-    )
+    B, S = 4, 128
+    bf16 = {n: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16 if n != "length" else a.dtype) for n, a in mr._sds_cache(cfg, B, S).items()}
+    seen = {}
+    for name, cache in (("bfloat16", bf16), ("int8", mr._sds_cache_q(cfg, B, S))):
+        compiled = mr.make_fused_fns(cfg, kv_quant=name == "int8").lower(mr._sds_params(cfg), cache, *mr._sds_lanes(B)).compile()
+        mem, cost = compiled.memory_analysis(), compiled.cost_analysis()
+        held = sum(a.size * a.dtype.itemsize for a in cache.values())
+        assert mem.alias_size_in_bytes >= held, f"{name}: the cache (values, scales, lengths) is not updated in place"
+        seen[name] = (cost["flops"], cost["bytes accessed"], mem.temp_size_in_bytes)
+    (flops, read, temp), (flops_q, read_q, temp_q) = seen["bfloat16"], seen["int8"]
+    cache_f32 = 2 * cfg.num_layers * B * S * cfg.num_kv_heads * cfg.hd * 4
+    assert flops_q <= 1.1 * flops, f"int8 decode step regressed past the 1.1x bf16 gate: {flops_q:.0f} flops vs {flops:.0f}"
+    assert read_q <= 1.1 * read, f"the int8 step reads and writes {read_q:.0f} bytes, the bf16 step {read:.0f}"
+    assert temp_q < temp + cache_f32 // 4, f"the int8 step holds {temp_q - temp} more bytes of temporaries: a dequantized cache is {cache_f32}"
 
 
 def test_llm_pallas_interpret_step_within_sane_multiple():

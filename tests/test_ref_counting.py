@@ -146,3 +146,20 @@ def test_ref_counting_disabled_flag():
         assert client.store.contains(oid)  # nothing freed when disabled
     finally:
         ray_tpu.shutdown()
+
+
+def test_a_ref_finalized_under_the_count_lock_does_not_wait_for_it():
+    """A finalizer runs wherever the collector does, also on a thread that holds the count's lock (a collection that began
+    inside ``local_ref_count`` hung a whole test run, PR 40): it takes no lock, and the next holder counts it down."""
+    from ray_tpu.core import object_ref as oref
+    from ray_tpu.core.ids import ObjectID
+
+    oref.set_ref_counting(True)
+    oid = ObjectID.from_random()
+    first, second = oref.ObjectRef(oid), oref.ObjectRef(oid)
+    assert oref.local_ref_count(oid) == 2
+    with oref._rc_lock:  # as ``_ref_gc_loop`` holds it when a collection starts under it
+        del first  # its ``__del__`` runs here; until PR 40 it waited for the lock this thread holds
+    assert oref.local_ref_count(oid) == 1
+    del second
+    assert oref.local_ref_count(oid) == 0 and (oid.binary(), False) in oref.drain_ref_events()

@@ -90,7 +90,7 @@ def test_ppo_cartpole_learns():
     for _ in range(15):
         r = algo.train()
         best = max(best, r["env_runners"]["episode_return_mean"])
-        if best >= 150:
+        if best >= 120:  # the assertion below holds: further iterations cannot change the verdict
             break
     assert best >= 120, f"PPO failed to learn CartPole: best={best}"
     algo.stop()
@@ -102,6 +102,8 @@ def test_ppo_remote_env_runners(rt_start):
     for _ in range(8):
         r = algo.train()
         best = max(best, r["env_runners"]["episode_return_mean"])
+        if best >= 40:
+            break
     assert best >= 40, f"best={best}"
     algo.stop()
 
@@ -143,7 +145,7 @@ def test_impala_cartpole_learns():
     for _ in range(22):
         r = algo.train()
         best = max(best, r["env_runners"]["episode_return_mean"])
-        if best >= 60:
+        if best >= 40:
             break
     assert best >= 40, f"IMPALA failed to learn: best={best}"
     algo.stop()
@@ -267,7 +269,7 @@ def test_dqn_cartpole_learns():
     for _ in range(80):
         r = algo.train()
         best = max(best, r["env_runners"]["episode_return_mean"])
-        if best >= 120:
+        if best >= 100:
             break
     assert best >= 100, f"DQN failed to learn CartPole: best={best}"
     algo.stop()
@@ -279,7 +281,7 @@ def test_dqn_prioritized_replay_learns():
     for _ in range(60):
         r = algo.train()
         best = max(best, r["env_runners"]["episode_return_mean"])
-        if best >= 80:
+        if best >= 60:
             break
     assert best >= 60, f"prioritized DQN stuck: best={best}"
     algo.stop()
