@@ -688,7 +688,8 @@ class LLMEngine:
             else:
                 self._decode = step_fn
         self._moe_stats = None  # the drained step's expert-routing counters (hybrid models)
-        # this step's hybrid prefills: (tokens, tokens as padded, programs, summed routing counters)
+        # this step's hybrid prefills: (tokens, tokens as padded, programs, summed routing counters,
+        # what the description counts of the programs' shapes)
         self._prefill_stats = None
         if self._device_resident:
             from ray_tpu.llm.model_runner import make_delta_fns, make_fused_fns, make_fused_paged_fns
@@ -2275,9 +2276,10 @@ class LLMEngine:
             # routing counters, read AFTER the first tokens (whose readback the program's end
             # already waited for): the copy of three floats waits for nothing
             routing = np.asarray(kept["routing"])  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
-            seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing))
+            seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
+            counted = self.config.prefill_counters(Bp, T)
             self._prefill_stats = (seen[0] + int(sum(len(p) for _, _, p in group)), seen[1] + Bp * T,
-                                   seen[2] + 1, seen[3] + routing)
+                                   seen[2] + 1, seen[3] + routing, {k: seen[4].get(k, 0) + v for k, v in counted.items()})
 
     def _admit_special_paged(self, st: RequestState, slot: int, pref, prompt):
         """Paged admission for transferred-KV / prefix-cache-hit requests

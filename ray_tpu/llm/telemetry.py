@@ -69,6 +69,11 @@ logger = logging.getLogger("ray_tpu.llm")
 # ``llm.stepper.*`` by the serve stepper between two steps, and land on
 # the row of the step that follows. drain_wait is the host blocked on the
 # device (the delayed readback); everything else is the host's own work.
+# what a hybrid description may count of a prefill program from its shape alone
+# (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
+# takes on that step's row
+PREFILL_COUNTERS = ("kda_chunks",)
+
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
     "llm.step.prefill": "prefill_ms",
@@ -369,6 +374,10 @@ class FlightRecorder:
         # (token, expert) pairs served here and rows of the grouped matmul's blocks in use (sums
         # over them). Under names of their own: the four above stay the drained DECODE step's
         "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed",
+        # and what the description counts of those prefill programs from their shapes alone
+        # (``HybridDescription.prefill_counters``): chunks of the delta rule that the programs ran, padding's
+        # among them, over the layers of Kimi Delta Attention; absent for a description that counts none
+        *PREFILL_COUNTERS,
     ) + tuple(STAGES.values())
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
@@ -495,7 +504,7 @@ class FlightRecorder:
 # engine-facing facade
 # ----------------------------------------------------------------------
 _NO_MOE = (None,) * 5  # a step row's routing counters for a model without routed experts
-_NO_PREFILL = (None,) * 5  # and its prefill counters where the step admitted nothing through a hybrid's prefill
+_NO_PREFILL = (None,) * (5 + len(PREFILL_COUNTERS))  # and its prefill counters where the step admitted nothing through a hybrid's prefill
 
 
 class EngineTelemetry:
@@ -907,8 +916,9 @@ class EngineTelemetry:
         if pf is None:
             moe += _NO_PREFILL
         else:
-            tokens, padded, programs, (hit, pairs, rows) = pf
-            moe += (tokens, padded, round(float(hit) / programs, 3), round(float(pairs), 3), round(float(rows), 3))
+            tokens, padded, programs, (hit, pairs, rows), counted = pf
+            moe += (tokens, padded, round(float(hit) / programs, 3), round(float(pairs), 3), round(float(rows), 3),
+                    *(counted.get(name) for name in PREFILL_COUNTERS))
         self.recorder.record_step((
             now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, sampling_lanes, waiting,
             occupied, capacity, *(eng._step_attn_blocks or (None, None)),

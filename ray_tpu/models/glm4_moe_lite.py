@@ -65,8 +65,48 @@ from ray_tpu.util.profiling import scope
 FFN_ROWS = 8192
 
 
+class LatentAttention:
+    """What ``mla_down``, ``mla_seq`` and ``mla_step`` read off a description beside its widths
+    (``num_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rope_theta``, ``rms_eps``, ``attention_impl``): ``q_lora_rank`` (None: the queries come
+    straight from the stream through ``w_q``, with no latent and no norm of their own) and
+    ``mla_rotates`` (False: NoPE, neither the shared key nor any query column is rotated)."""
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def rope_row(self) -> int:
+        """Columns of the cached shared key: qk_rope_head_dim rounded up to whole 128-lane tiles."""
+        return -(-self.qk_rope_head_dim // 128) * 128
+
+    @property
+    def latent_tile(self) -> dict:
+        """What ``ops/slot_attention.refusal`` is asked about a latent layer's decode attention."""
+        return dict(num_heads=self.num_heads, num_kv_heads=1, head_dim=self.kv_lora_rank + self.rope_row, value_dim=self.kv_lora_rank)
+
+    def latent_entries(self) -> dict:
+        """What one latent layer keeps of every position: the normed latent and the one shared key."""
+        return {"c_kv": ((self.kv_lora_rank,), self.dtype, "position"), "k_r": ((self.rope_row,), self.dtype, "position")}
+
+    def latent_shapes(self, N: int) -> dict:
+        """name -> (shape of one layer, fan_in or fill) of a latent layer's weights (``init_stacked``)."""
+        H, nh, r, rq = self.hidden_size, self.num_heads, self.kv_lora_rank, self.q_lora_rank
+        queries = {"w_q": ((H, nh * self.qk_head_dim), H)} if rq is None else {
+            "w_qa": ((H, rq), H), "q_norm": ((rq,), 1.0), "w_qb": ((rq, nh * self.qk_head_dim), rq)}
+        return {"norm": ((H,), 1.0), **queries, "w_kva": ((H, r + self.qk_rope_head_dim), H), "kv_norm": ((r,), 1.0),
+                "w_kb": ((r, nh * self.qk_nope_head_dim), r), "w_vb": ((r, nh * self.v_head_dim), r),
+                "wo": ((nh * self.v_head_dim, H), nh * self.v_head_dim * N)}
+
+    def latent_axes(self) -> dict:
+        queries = {"w_q": ("embed", "heads")} if self.q_lora_rank is None else {"w_qa": ("embed", None), "q_norm": (None,), "w_qb": (None, "heads")}
+        return {"norm": (None,), **queries, "w_kva": ("embed", None), "kv_norm": (None,), "w_kb": (None, "heads"),
+                "w_vb": (None, "heads"), "wo": ("heads", "embed")}
+
+
 @dataclass(frozen=True)
-class Glm4MoeLiteConfig(HybridDescription):
+class Glm4MoeLiteConfig(LatentAttention, HybridDescription):
     vocab_size: int = 154880
     hidden_size: int = 2048
     num_hidden_layers: int = 47  # published decoder layers: each an attention sub-block and an MLP sub-block
@@ -103,9 +143,6 @@ class Glm4MoeLiteConfig(HybridDescription):
             raise ValueError("first_k_dense_replace counts some of the num_hidden_layers")
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim is rotated in pairs")
-        if self.v_head_dim != self.qk_head_dim:
-            raise ValueError("the expanded form runs the flash kernel, whose keys and values are one width: "
-                             "v_head_dim must equal qk_nope_head_dim + qk_rope_head_dim")
         if self.n_shared_experts != 1:
             raise ValueError("the expert layer has one shared expert")
 
@@ -147,14 +184,7 @@ class Glm4MoeLiteConfig(HybridDescription):
         return ExpertLayer(num_experts=self.n_routed_experts, top_k=self.num_experts_per_tok, score="sigmoid", bias=True,
                            norm_topk=self.norm_topk_prob, scale=self.routed_scaling_factor, act="swiglu", shared_gated=False)
 
-    @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-    @property
-    def rope_row(self) -> int:
-        """Columns of the cached rotated key: qk_rope_head_dim rounded up to whole 128-lane tiles."""
-        return -(-self.qk_rope_head_dim // 128) * 128
+    mla_rotates = True  # the shared key and each head's last qk_rope_head_dim query columns are rotated (``LatentAttention``)
 
     @property
     def stream_dtype(self):
@@ -163,14 +193,12 @@ class Glm4MoeLiteConfig(HybridDescription):
     def cache_spec(self) -> dict:
         """kind -> {name: (shape, dtype, "position" | "sequence")}: a latent layer keeps the normed
         latent and the one rotated key of every position; nothing is kept per sequence."""
-        return {"mla": {"c_kv": ((self.kv_lora_rank,), self.dtype, "position"),
-                        "k_r": ((self.rope_row,), self.dtype, "position")},
-                "ffn": {}, "moe": {}}
+        return {"mla": self.latent_entries(), "ffn": {}, "moe": {}}
 
     @property
     def slot_attention_tile(self) -> dict:
         """What ``ops/slot_attention.refusal`` is asked about this description's decode attention."""
-        return dict(num_heads=self.num_heads, num_kv_heads=1, head_dim=self.kv_lora_rank + self.rope_row, value_dim=self.kv_lora_rank)
+        return self.latent_tile
 
     def num_params(self) -> int:
         """Parameters held here."""
@@ -194,13 +222,10 @@ def _shapes(c: Glm4MoeLiteConfig) -> dict:
     """group -> {name: (shape of one layer, fan_in or fill)}: matrices are N(0, fan_in^-1/2), the
     projections back onto the residual stream 1/sqrt(N) smaller, norms 1. An expert's three
     matrices are stored [F, H]."""
-    H, nh, r, N = c.hidden_size, c.num_heads, c.kv_lora_rank, c.residual_rescale_layers
+    H, N = c.hidden_size, c.residual_rescale_layers
     F, Fm, E = c.intermediate_size, c.moe_intermediate_size, c.n_routed_experts
     return {
-        "mla": {"norm": ((H,), 1.0), "w_qa": ((H, c.q_lora_rank), H), "q_norm": ((c.q_lora_rank,), 1.0),
-                "w_qb": ((c.q_lora_rank, nh * c.qk_head_dim), c.q_lora_rank), "w_kva": ((H, r + c.qk_rope_head_dim), H),
-                "kv_norm": ((r,), 1.0), "w_kb": ((r, nh * c.qk_nope_head_dim), r), "w_vb": ((r, nh * c.v_head_dim), r),
-                "wo": ((nh * c.v_head_dim, H), nh * c.v_head_dim * N)},
+        "mla": c.latent_shapes(N),
         "ffn": {"norm": ((H,), 1.0), "w_gate": ((H, F), H), "w_up": ((H, F), H), "w_down": ((F, H), F * N)},
         "moe": {"norm": ((H,), 1.0), "router": ((H, E), H), "w_gate": ((E, Fm, H), H), "w_up": ((E, Fm, H), H),
                 "w_down": ((E, Fm, H), Fm * N), "shared_gate": ((H, Fm), H), "shared_up": ((H, Fm), H),
@@ -228,8 +253,7 @@ def init_params(config: Glm4MoeLiteConfig, key):
 def param_logical_axes(config: Glm4MoeLiteConfig):
     """Logical axes for ``parallel/mesh.ShardingRules`` (vocabulary, experts and heads are the
     axes a mesh could split; the serving engine refuses a mesh for this model today)."""
-    lead = {"mla": {"norm": (None,), "w_qa": ("embed", None), "q_norm": (None,), "w_qb": (None, "heads"), "w_kva": ("embed", None),
-                    "kv_norm": (None,), "w_kb": (None, "heads"), "w_vb": (None, "heads"), "wo": ("heads", "embed")},
+    lead = {"mla": config.latent_axes(),
             "ffn": {"norm": (None,), "w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")},
             "moe": {"norm": (None,), "router": ("embed", None), "router_bias": (None,), "w_gate": ("expert", "mlp", "embed"),
                     "w_up": ("expert", "mlp", "embed"), "w_down": ("expert", "mlp", "embed"), "shared_gate": ("embed", "mlp"),
@@ -252,32 +276,41 @@ def ffn(w, x):
 
 
 # ------------------------------------------------------- mla: latent attention
-def mla_down(w, xn, positions, c: Glm4MoeLiteConfig):
-    """The two down projections of xn [B,T,H] at ``positions`` [B,T] or [T] -> the query's latent
-    c_q [B,T,q_lora_rank] after its norm, and what a position keeps: c_kv [B,T,kv_lora_rank] after
-    its norm and the one key k_r [B,T,rope_row] after its rotation (zeros after its rope columns),
-    with the rotation's (cos, sin)."""
+def mla_down(w, xn, positions, c: LatentAttention):
+    """The down projections of xn [B,T,H] at ``positions`` [B,T] or [T] -> what the queries come
+    from (their latent c_q [B,T,q_lora_rank] after its norm; xn itself for a description without
+    one), and what a position keeps: c_kv [B,T,kv_lora_rank] after its norm and the one shared key
+    k_r [B,T,rope_row] (zeros after its rope columns), rotated where the description rotates, with
+    the rotation's (cos, sin) or None."""
     with scope("mla.down"):
-        c_q = rms_norm(jnp.dot(xn, w["w_qa"]), w["q_norm"], c.rms_eps)
+        c_q = xn if c.q_lora_rank is None else rms_norm(jnp.dot(xn, w["w_qa"]), w["q_norm"], c.rms_eps)
         kva = jnp.dot(xn, w["w_kva"])
         c_kv = rms_norm(kva[..., :c.kv_lora_rank], w["kv_norm"], c.rms_eps)
-        cos, sin = rotary_embedding(positions, c.qk_rope_head_dim, c.rope_theta)
-        k_r = apply_rope(kva[..., None, :, c.kv_lora_rank:].astype(jnp.float32), cos, sin)[..., 0, :, :].astype(xn.dtype)
+        k_r, rope = kva[..., c.kv_lora_rank:], None
+        if c.mla_rotates:
+            rope = rotary_embedding(positions, c.qk_rope_head_dim, c.rope_theta)
+            k_r = apply_rope(k_r[..., None, :, :].astype(jnp.float32), *rope)[..., 0, :, :].astype(xn.dtype)
         k_r = jnp.pad(k_r, ((0, 0), (0, 0), (0, c.rope_row - c.qk_rope_head_dim)))
-    return c_q, c_kv, k_r, (cos, sin)
+    return c_q, c_kv, k_r, rope
 
 
-def _queries(w, c_q, rope, c: Glm4MoeLiteConfig):
-    """c_q [B,T,r] -> q_nope [B,nh,T,nope], q_rope [B,nh,T,rope] rotated."""
-    wq = w["w_qb"].reshape(c.q_lora_rank, c.num_heads, c.qk_head_dim)
-    q = jnp.einsum("btr,rnd->bntd", c_q, wq)
-    q_rope = apply_rope(q[..., c.qk_nope_head_dim:].astype(jnp.float32), *rope).astype(q.dtype)
+def _queries(w, c_q, rope, c: LatentAttention):
+    """c_q [B,T,r] -> q_nope [B,nh,T,nope], q_rope [B,nh,T,rope] (rotated where ``rope`` is given)."""
+    wq = w["w_q" if c.q_lora_rank is None else "w_qb"]
+    q = jnp.einsum("btr,rnd->bntd", c_q, wq.reshape(wq.shape[0], c.num_heads, c.qk_head_dim))
+    q_rope = q[..., c.qk_nope_head_dim:]
+    if rope is not None:
+        q_rope = apply_rope(q_rope.astype(jnp.float32), *rope).astype(q.dtype)
     return q[..., :c.qk_nope_head_dim], q_rope
 
 
-def mla_seq(w, xn, c: Glm4MoeLiteConfig, mesh=None):
+def mla_seq(w, xn, c: LatentAttention, mesh=None):
     """The EXPANDED form over a padded sequence, positions 0..T-1: xn [B,T,H] -> (out [B,T,H],
-    c_kv [B,T,kv_lora_rank], k_r [B,T,rope_row]) with the latter two as the cache keeps them."""
+    c_kv [B,T,kv_lora_rank], k_r [B,T,rope_row]) with the latter two as the cache keeps them.
+    The flash kernel takes queries, keys and values of ONE width, of 64, 128 or 256 columns: a
+    description whose values are narrower than its keys, or whose keys are of another width (Kimi
+    Linear: 128 + 64 against 128), has both padded with zeros up to the next of those, which
+    changes no score and adds zero columns to the output, cut off again."""
     B, T, _ = xn.shape
     nh = c.num_heads
     c_q, c_kv, k_r, rope = mla_down(w, xn, jnp.arange(T, dtype=jnp.int32), c)
@@ -285,15 +318,18 @@ def mla_seq(w, xn, c: Glm4MoeLiteConfig, mesh=None):
         q_nope, q_rope = _queries(w, c_q, rope, c)
         k_nope = jnp.einsum("btr,rnd->bntd", c_kv, w["w_kb"].reshape(c.kv_lora_rank, nh, c.qk_nope_head_dim))
         v = jnp.einsum("btr,rnd->bntd", c_kv, w["w_vb"].reshape(c.kv_lora_rank, nh, c.v_head_dim))
+        need = max(c.qk_head_dim, c.v_head_dim)
+        width = next((wd for wd in (64, 128, 256) if wd >= need), need)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r[:, None, :, :c.qk_rope_head_dim], (B, nh, T, c.qk_rope_head_dim))], axis=-1)
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, width - a.shape[-1]))) for a in (q, k, v))
     with scope("mla.attn"):
-        o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl)
-    y = jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, nh * c.v_head_dim).astype(xn.dtype), w["wo"])
-    return y, c_kv, k_r
+        o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl, scale=None if width == c.qk_head_dim else c.qk_head_dim ** -0.5)
+    o = o[..., :c.v_head_dim].transpose(0, 2, 1, 3).reshape(B, T, nh * c.v_head_dim)
+    return jnp.dot(o.astype(xn.dtype), w["wo"]), c_kv, k_r
 
 
-def mla_step(w, xn, cache, ctx, c: Glm4MoeLiteConfig):
+def mla_step(w, xn, cache, ctx, c: LatentAttention):
     """The ABSORBED form for one token a lane: xn [B,H] -> out [B,H]. Writes the new position's
     latent and key through ``cache``, then every head attends over the lane's live positions
     where they lie in the stack, on the latent itself."""

@@ -126,6 +126,12 @@ class HybridDescription:
         the tile of a layer that keeps ``k`` and ``v`` by head."""
         return dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.hd)
 
+    def prefill_counters(self, batch: int, length: int) -> dict:
+        """What ONE prefill program of ``batch`` x ``length`` positions (as padded) runs that can be
+        counted from its shape alone, by a name of ``llm/telemetry.PREFILL_COUNTERS``: summed over
+        an admitting step's programs onto that step's row of the flight log. None by default."""
+        return {}
+
     @property
     def routing_layers(self) -> int:
         return len(self.keeping(ROUTING))

@@ -62,6 +62,8 @@ SCOPES = {"gdn": "gdn", "attn": "gated_attn", "moe": "moe"}
 # larger prefill goes through a few sequences at a time, so that its float32 temporaries (a dozen
 # arrays of 4 KB a position and value-head set) stay under a gigabyte beside the weights
 RULE_POSITIONS = 8192
+# positions in a sub-block of a chunk, for a gate that differs by key channel (``_pairs_by_channel``)
+SUB_BLOCK = 16
 
 
 @dataclass(frozen=True)
@@ -255,11 +257,7 @@ def init_params(config: Qwen3NextConfig, key):
     params = init_stacked(_shapes(c), c.count, keys, dt)
     n, nv = c.count("gdn"), c.linear_num_value_heads
     if n:
-        step = jnp.exp(jax.random.uniform(next(keys), (n, nv)) * (math.log(c.time_step_max) - math.log(c.time_step_min))
-                       + math.log(c.time_step_min))
-        step = jnp.maximum(step, c.time_step_floor)
-        params["gdn"]["dt_bias"] = step + jnp.log(-jnp.expm1(-step))  # softplus^-1
-        params["gdn"]["A_log"] = jnp.log(jax.random.uniform(next(keys), (n, nv), minval=1.0, maxval=16.0))
+        params["gdn"]["dt_bias"], params["gdn"]["A_log"] = init_decay(c, next(keys), next(keys), (n, nv), (n, nv))
     embed = jax.random.normal(next(keys), (c.vocab_size, c.hidden_size), jnp.float32)
     if c.router_anchor:
         params["moe"]["router"], embed = _anchor_routing(c, next(keys), embed, dt)
@@ -268,6 +266,14 @@ def init_params(config: Qwen3NextConfig, key):
                          * c.hidden_size ** -0.5).astype(dt)
     params["final_norm"] = jnp.zeros((c.hidden_size,), dt)
     return params
+
+
+def init_decay(c, k_step, k_A, steps: tuple, heads: tuple):
+    """-> (``dt_bias`` of shape ``steps``: the inverse softplus of a log-uniform step in
+    [c.time_step_min, c.time_step_max]; ``A_log`` of shape ``heads``: log U(1, 16)), float32."""
+    step = jnp.exp(jax.random.uniform(k_step, steps) * (math.log(c.time_step_max) - math.log(c.time_step_min)) + math.log(c.time_step_min))
+    step = jnp.maximum(step, c.time_step_floor)
+    return step + jnp.log(-jnp.expm1(-step)), jnp.log(jax.random.uniform(k_A, heads, minval=1.0, maxval=16.0))
 
 
 def _anchor_routing(c: Qwen3NextConfig, key, embed, dt):
@@ -344,10 +350,52 @@ def _gdn_out(w, o, z, c: Qwen3NextConfig, dtype):
     return jnp.dot(y.reshape(*y.shape[:-2], c.value_dim).astype(dtype), w["out_proj"])
 
 
-def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None):
+def _pairs_by_channel(firsts, k, gc, sub: int, es):
+    """For each ``a`` of ``firsts``: ``sum_c a_t[c] k_s[c] exp(gc_t[c] - gc_s[c])`` for s <= t inside
+    a chunk (0 above the diagonal), for a decay that differs by key channel. a, k [B,nc,C,G,K]; gc
+    [B,nc,C,G,R,K] the log of the decay since the chunk's start, falling along C
+    -> a tuple of [B,nc,G,R,t,s] float32.
+
+    The decay cannot be applied to the C x C products after the matmul as a scalar one can: it
+    has to go INTO the operands, ``a_t exp(gc_t)`` and ``k_s exp(-gc_s)``, and ``exp(-gc_s)``
+    leaves float32 inside one chunk (a channel that forgets in one position: 64 x 1.6 = 102 > 88).
+    So the chunk is cut into sub-blocks of ``sub`` positions. A pair in two DIFFERENT sub-blocks
+    takes its exponents relative to the decay at the later sub-block's start, ``exp(gc_t - ref)``
+    and ``exp(ref - gc_s)``: both are <= 1 whatever the gate (gc falls), and the product is one
+    matmul a sub-block row. A pair inside ONE sub-block is summed channel by channel with its own
+    exponent ``gc_t - gc_s <= 0`` (sub x sub x K elementwise products a sub-block, no matmul): no
+    reference point inside a sub-block is safe for every gate."""
+    B, nc, C, G, K = k.shape
+    R, I = gc.shape[4], C // sub
+    blocks = lambda x: x.reshape(B, nc, I, sub, *x.shape[3:])  # noqa: E731
+    k_b, gc_b = blocks(k)[:, :, :, :, :, None], blocks(gc)  # [B,nc,I,sub,G,1|R,K]
+    # across sub-blocks: relative to the decay as sub-block i starts (0 for the first, which has nothing before it)
+    ref = jnp.concatenate([jnp.zeros_like(gc_b[:, :, :1, -1]), gc_b[:, :, :-1, -1]], axis=2)  # [B,nc,I,G,R,K]
+    inward = jnp.exp(gc_b - ref[:, :, :, None])
+    k_out = k[:, :, None, :, :, None] * jnp.exp(jnp.minimum(ref[:, :, :, None] - gc[:, :, None], 0.0))  # [B,nc,I,C,G,R,K]
+    earlier_block = (jnp.arange(C) // sub)[:, None] > (jnp.arange(C) // sub)[None, :]
+    # inside a sub-block: each pair's own exponent, summed over the channels where it stands
+    k_in = k_b[:, :, :, None, :] * jnp.exp(jnp.minimum(gc_b[:, :, :, :, None] - gc_b[:, :, :, None, :], 0.0))  # [B,nc,I,t,s,G,R,K]
+    at_or_before = jnp.tril(jnp.ones((C, C), bool))
+    same_block = jnp.eye(I, dtype=jnp.float32)[:, None, :, None]
+
+    def pairs(a):
+        a_b = blocks(a)[:, :, :, :, :, None]
+        across = es("bcitgrk,bcisgrk->bcgrits", a_b * inward, k_out).reshape(B, nc, G, R, C, C)
+        inside = jnp.moveaxis(jnp.sum(a_b[:, :, :, :, None] * k_in, axis=-1), (2, 3, 4), (4, 5, 6))  # [B,nc,G,R,I,t,s]
+        inside = (inside[:, :, :, :, :, :, None] * same_block).reshape(B, nc, G, R, C, C)
+        return jnp.where(earlier_block, across, 0.0) + jnp.where(at_or_before, inside, 0.0)
+
+    return tuple(pairs(a) for a in firsts)
+
+
+def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None, name: str = "gdn"):
     """The gated delta rule over a sequence from a zero state, blocked in chunks. q, k [B,T,G,K]
-    (key heads), v [B,T,G,R,V] (a key head's R value heads), g (log-decay, <= 0) and beta
-    [B,T,G,R], float32 -> (o [B,T,G,R,V], the state after position T-1 [B,G,R,K,V]).
+    (key heads), v [B,T,G,R,V] (a key head's R value heads), beta [B,T,G,R] and g, the log-decay
+    (<= 0): [B,T,G,R], one gate a head (Gated DeltaNet), or [B,T,G,R,K], one for each of a head's
+    key channels (Kimi Delta Attention: ``S <- Diag(exp(g)) S`` in place of ``exp(g) S``), float32
+    -> (o [B,T,G,R,V], the state after position T-1 [B,G,R,K,V]). ``name``: whose scopes the two
+    stretches stand under (``<name>.chunk``, ``<name>.scan``).
 
     Inside a chunk, with gamma_t the decay since the chunk's start and S_0 the state there, the
     rule's written values ``u_t = beta_t (v_t - S'_t^T k_t)`` solve the unit lower-triangular
@@ -359,7 +407,12 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None):
     (gamma_C / gamma * K)^T U``. A position with beta = 0 and g = 0 writes nothing and decays
     nothing, which is how padding is kept out. The matmuls (but the inverse) take their operands
     in ``operand_dtype`` and accumulate in float32, as the published kernels do with bfloat16;
-    without it they are float32 at ``highest`` precision throughout."""
+    without it they are float32 at ``highest`` precision throughout.
+
+    One body for both gates: everything above holds with gamma a vector over the key channels
+    (``gamma_t / gamma_s`` then stands INSIDE ``k_t.k_s`` and ``q_t.k_s``), and only those two
+    C x C sets of pairs are built differently (``_pairs_by_channel``, in sub-blocks of ``SUB_BLOCK``
+    positions). A gate a head takes the lines it always took."""
     hi = jax.lax.Precision.HIGHEST
     if operand_dtype is None or jnp.dtype(operand_dtype) == jnp.float32:
         def es(spec, a, b):
@@ -375,54 +428,98 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None):
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta))
     nc = (T + pad) // C
     q, k = q.reshape(B, nc, C, G, K), k.reshape(B, nc, C, G, K)
-    v, g, beta = v.reshape(B, nc, C, G, R, V), g.reshape(B, nc, C, G, R), beta.reshape(B, nc, C, G, R)
-    with scope("gdn.chunk"):  # what runs on all chunks at once, the triangular inverse included
+    by_channel = g.ndim == beta.ndim + 1
+    v, g, beta = v.reshape(B, nc, C, G, R, V), g.reshape(B, nc, C, G, R, *g.shape[4:]), beta.reshape(B, nc, C, G, R)
+    with scope(f"{name}.chunk"):  # what runs on all chunks at once, the triangular inverse included
         gc = jnp.cumsum(g, axis=2)  # log of the decay since the chunk's start, <= 0
-        seg = jnp.moveaxis(gc[:, :, :, None] - gc[:, :, None, :], (2, 3), (4, 5))  # [B,nc,G,R,t,s]: log gamma_t / gamma_s
-        at_or_before = jnp.tril(jnp.ones((C, C), bool))
-        decay = jnp.where(at_or_before, jnp.exp(jnp.where(at_or_before, seg, 0.0)), 0.0)
-        kk = es("bctgk,bcsgk->bcgts", k, k)[:, :, :, None]  # [B,nc,G,1,t,s]
         beta_t = jnp.moveaxis(beta, 2, 4)  # [B,nc,G,R,t]
-        A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1), beta_t[..., None] * decay * kk, 0.0)
+        before = jnp.tril(jnp.ones((C, C), bool), -1)
+        if by_channel:
+            kk, qk = _pairs_by_channel((k, q), k, gc, math.gcd(C, SUB_BLOCK), es)  # [B,nc,G,R,t,s], s <= t
+            A = jnp.where(before, beta_t[..., None] * kk, 0.0)
+            gc_k = gc  # [B,nc,C,G,R,K]
+        else:
+            seg = jnp.moveaxis(gc[:, :, :, None] - gc[:, :, None, :], (2, 3), (4, 5))  # [B,nc,G,R,t,s]: log gamma_t / gamma_s
+            at_or_before = jnp.tril(jnp.ones((C, C), bool))
+            decay = jnp.where(at_or_before, jnp.exp(jnp.where(at_or_before, seg, 0.0)), 0.0)
+            kk = es("bctgk,bcsgk->bcgts", k, k)[:, :, :, None]  # [B,nc,G,1,t,s]
+            A = jnp.where(before, beta_t[..., None] * decay * kk, 0.0)
+            qk = es("bctgk,bcsgk->bcgts", q, k)[:, :, :, None] * decay  # [B,nc,G,R,t,s], s <= t
+            gc_k = gc[..., None]  # one gate for all of a head's key channels
         # (I + A)^-1, float32: A^C = 0, so the product below ends after log2(C) factors
         inv, power = jnp.eye(C, dtype=jnp.float32) - A, A
         for _ in range(max(0, math.ceil(math.log2(C)) - 1)):
             power = jnp.einsum("...ts,...su->...tu", power, power, precision=hi)
             inv = inv + jnp.einsum("...ts,...su->...tu", inv, power, precision=hi)
         w_v = es("bcgrts,bcsgrv->bcgrtv", inv, v * beta[..., None])
-        w_k = es("bcgrts,bcsgrk->bcgrtk", inv, k[:, :, :, :, None] * (beta * jnp.exp(gc))[..., None])
-        qk = es("bctgk,bcsgk->bcgts", q, k)[:, :, :, None] * decay  # [B,nc,G,R,t,s], s <= t
-        q_in = q[:, :, :, :, None] * jnp.exp(gc)[..., None]  # [B,nc,C,G,R,K]: gamma_t q_t
-        k_out = k[:, :, :, :, None] * jnp.exp(gc[:, :, -1:] - gc)[..., None]  # gamma_C / gamma_s k_s
-        whole = jnp.exp(gc[:, :, -1])  # [B,nc,G,R]
+        w_k = es("bcgrts,bcsgrk->bcgrtk", inv, k[:, :, :, :, None] * (beta[..., None] * jnp.exp(gc_k)))
+        q_in = q[:, :, :, :, None] * jnp.exp(gc_k)  # [B,nc,C,G,R,K]: gamma_t q_t
+        k_out = k[:, :, :, :, None] * jnp.exp(gc_k[:, :, -1:] - gc_k)  # gamma_C / gamma_s k_s
+        whole = jnp.exp(gc_k[:, :, -1])  # [B,nc,G,R,K or 1]
 
     def pass_on(S, chunk_):
         w_v_c, w_k_c, qk_c, q_c, k_c, whole_c = chunk_
         u = w_v_c - es("bgrtk,bgrkv->bgrtv", w_k_c, S)
         o = es("btgrk,bgrkv->btgrv", q_c, S) + es("bgrts,bgrsv->btgrv", qk_c, u)
-        return S * whole_c[..., None, None] + es("bsgrk,bgrsv->bgrkv", k_c, u), o
+        return S * whole_c[..., None] + es("bsgrk,bgrsv->bgrkv", k_c, u), o
 
-    with scope("gdn.scan"):  # the state passed from chunk to chunk
+    with scope(f"{name}.scan"):  # the state passed from chunk to chunk
         per_chunk = tuple(jnp.moveaxis(a, 1, 0) for a in (w_v, w_k, qk, q_in, k_out, whole))
         S_end, o = jax.lax.scan(pass_on, jnp.zeros((B, G, R, K, V), jnp.float32), per_chunk)
     return jnp.moveaxis(o, 0, 1).reshape(B, nc * C, G, R, V)[:, :T], S_end
+
+
+def delta_rule_step(S, q, k, v, g, beta):
+    """The rule for one position, elementwise in float32: S [B,N,K,V], q, k [B,N,K], v [B,N,V],
+    beta [B,N] and the log-decay g [B,N] (one gate a head) or [B,N,K] (one a key channel)
+    -> (o [B,N,V], S): decay, read ``S'^T k``, write ``k (beta (v - S'^T k))^T``, read out."""
+    q, k = q[..., None], k[..., None]
+    S = S * (jnp.exp(g)[..., None, None] if g.ndim == beta.ndim else jnp.exp(g)[..., None])
+    u = beta[..., None] * (v - jnp.sum(S * k, axis=-2))
+    S = S + k * u[..., None, :]
+    return jnp.sum(S * q, axis=-2), S
+
+
+def short_conv_seq(mixed, taps, lengths):
+    """The causal depthwise convolution without bias over a padded sequence: mixed [B,T,C], taps
+    [K,C] -> (float32 [B,T,C], the window [B,K-1,C] of its last inputs AT each true length)."""
+    K, T = taps.shape[0], mixed.shape[1]
+    padded = jnp.pad(mixed, ((0, 0), (K - 1, 0), (0, 0)))  # index j holds position j - (K-1)
+    taps = taps.astype(jnp.float32)
+    conv = sum(padded[:, j:j + T].astype(jnp.float32) * taps[j] for j in range(K))
+    return conv, jax.vmap(lambda p, n: jax.lax.dynamic_slice_in_dim(p, n, K - 1, 0))(padded, lengths)
+
+
+def short_conv_step(window, mixed, taps):
+    """One position of ``short_conv_seq``: window [B,K-1,C] of the inputs before it, mixed [B,C]
+    -> (float32 [B,C], the window moved on by one)."""
+    window = jnp.concatenate([window, mixed[:, None].astype(window.dtype)], axis=1)  # [B,K,C]
+    return jnp.sum(window.astype(jnp.float32) * taps.astype(jnp.float32), axis=1), window[:, 1:]
+
+
+def a_few_at_a_time(some, xn, lengths):
+    """``some(xn [b,T,H], lengths [b])`` over a batch [B,T,H]: whole where it has at most
+    ``RULE_POSITIONS`` positions, else a few sequences at a time."""
+    B, T, _ = xn.shape
+    at_once = max(1, RULE_POSITIONS // T)
+    if B <= at_once or B % at_once:
+        return some(xn, lengths)
+    parts = jax.lax.map(lambda a: some(*a), (xn.reshape(B // at_once, at_once, T, -1), lengths.reshape(B // at_once, at_once)))
+    return jax.tree.map(lambda a: a.reshape((B,) + a.shape[2:]), parts)
 
 
 def gdn_seq(w, xn, lengths, c: Qwen3NextConfig):
     """xn [B,T,H], lengths [B] -> (out [B,T,H], S [B,nv,dk,dv] f32, conv [B,K-1,C]): the state
     and the convolution's window AT each sequence's true length. A batch of more than
     ``RULE_POSITIONS`` positions goes through a few sequences at a time."""
-    B, T, _ = xn.shape
-    K, nk, nv = c.conv_kernel, c.linear_num_key_heads, c.linear_num_value_heads
+    T = xn.shape[1]
+    nk, nv = c.linear_num_key_heads, c.linear_num_value_heads
     operand = None if xn.dtype == jnp.float32 else xn.dtype
 
     def some(xn, lengths):
         b = xn.shape[0]
         mixed, z, ba = _gdn_split(w, xn, c)
-        padded = jnp.pad(mixed, ((0, 0), (K - 1, 0), (0, 0)))  # index j holds position j - (K-1)
-        taps = w["conv_w"].astype(jnp.float32)
-        conv = sum(padded[:, j:j + T].astype(jnp.float32) * taps[j] for j in range(K))
-        window = jax.vmap(lambda p, n: jax.lax.dynamic_slice_in_dim(p, n, K - 1, 0))(padded, lengths)
+        conv, window = short_conv_seq(mixed, w["conv_w"], lengths)
         q, k, v, beta, g = _gdn_inputs(w, conv, ba, c)
         real = (jnp.arange(T)[None, :] < lengths[:, None])[..., None]
         beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)  # padding writes nothing and decays nothing
@@ -431,28 +528,19 @@ def gdn_seq(w, xn, lengths, c: Qwen3NextConfig):
         y = _gdn_out(w, o.reshape(b, T, nv, -1), z.reshape(b, T, nv, -1), c, xn.dtype)
         return y, S.reshape(b, nv, *S.shape[-2:]), window
 
-    at_once = max(1, RULE_POSITIONS // T)
-    if B <= at_once or B % at_once:
-        return some(xn, lengths)
-    parts = jax.lax.map(lambda a: some(*a), (xn.reshape(B // at_once, at_once, T, -1), lengths.reshape(B // at_once, at_once)))
-    return jax.tree.map(lambda a: a.reshape((B,) + a.shape[2:]), parts)
+    return a_few_at_a_time(some, xn, lengths)
 
 
 def gdn_step(w, xn, S, conv, c: Qwen3NextConfig):
-    """One token: xn [B,H], S [B,nv,dk,dv] f32, conv [B,K-1,C] -> (out [B,H], S, conv). The rule
-    elementwise in float32: decay, read ``S'^T k``, write ``k (beta (v - S'^T k))^T``, read out."""
+    """One token: xn [B,H], S [B,nv,dk,dv] f32, conv [B,K-1,C] -> (out [B,H], S, conv)."""
     R = c.linear_num_value_heads // c.linear_num_key_heads
     mixed, z, ba = _gdn_split(w, xn, c)
-    window = jnp.concatenate([conv, mixed[:, None].astype(conv.dtype)], axis=1)  # [B,K,C]
-    out = jnp.sum(window.astype(jnp.float32) * w["conv_w"].astype(jnp.float32), axis=1)
+    out, window = short_conv_step(conv, mixed, w["conv_w"])
     q, k, v, beta, g = _gdn_inputs(w, out, ba, c)
     with scope("gdn.state"):
-        q, k = (jnp.repeat(a, R, axis=1)[..., None] for a in (q, k))  # [B,nv,dk,1]: a key head serves R value heads
-        S = S * jnp.exp(g)[..., None, None]
-        u = beta[..., None] * (v - jnp.sum(S * k, axis=-2))
-        S = S + k * u[..., None, :]
-        o = jnp.sum(S * q, axis=-2)
-    return _gdn_out(w, o, z.reshape(o.shape), c, xn.dtype), S, window[:, 1:]
+        q, k = (jnp.repeat(a, R, axis=1) for a in (q, k))  # [B,nv,dk]: a key head serves R value heads
+        o, S = delta_rule_step(S, q, k, v, g, beta)
+    return _gdn_out(w, o, z.reshape(o.shape), c, xn.dtype), S, window
 
 
 # --------------------------------------------------------- attn: gated attention

@@ -321,7 +321,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None, im
     return out
 
 
-def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto"):
+def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto", scale: float | None = None):
     """Causal flash_attention over [B, H, T, D] inside a GSPMD program.
     GSPMD cannot partition a Mosaic kernel on its own ("wrap the call in
     a shard_map"), so where the Pallas kernel is selected on a mesh of
@@ -331,12 +331,12 @@ def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto"):
     None (or one device, or the XLA path) for the plain call."""
     if (mesh is None or mesh.size == 1 or not set(mesh.axis_names) <= {"dp", "fsdp", "tp"}
             or not _use_pallas(q, impl)):
-        return flash_attention(q, k, v, True, None, impl)
+        return flash_attention(q, k, v, True, scale, impl)
     from jax.sharding import PartitionSpec as P
 
     batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
     spec = P(batch, "tp" if "tp" in mesh.axis_names else None, None, None)
-    attn = functools.partial(flash_attention, causal=True, scale=None, impl=impl)
+    attn = functools.partial(flash_attention, causal=True, scale=scale, impl=impl)
     return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)(q, k, v)
 
 

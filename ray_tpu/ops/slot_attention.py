@@ -109,7 +109,7 @@ def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: in
             return (f"a latent row of {value_dim} + {rope} on {num_kv_heads} kv head(s): compiled at 512 + 128 on one (whole "
                     "128-lane tiles: a narrower rotated key reaches the kernel only through a copy of its whole stack)")
         if num_heads > 32:
-            return f"{num_heads} query heads: compiled at 20 (padded to at most two bfloat16 tiles of 16 rows)"
+            return f"{num_heads} query heads: compiled at 20 and 32 (padded to at most two bfloat16 tiles of 16 rows)"
         if block_positions(S, 1, value_dim, dt.itemsize) < 512:
             return f"{S} positions a slot: no block of at least 512 positions divides it"
         return None

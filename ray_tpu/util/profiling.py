@@ -51,8 +51,9 @@ SCOPES = {
     "embed": "embed", "head": "head", "sample": "sample", "cache": "cache",
     "attn": "mixer", "gated_attn": "mixer", "mamba2": "mixer",
     "gdn": "mixer", "gdn.chunk": "mixer", "gdn.scan": "mixer",
+    "kda": "mixer", "kda.chunk": "mixer", "kda.scan": "mixer",
     "mla": "mixer", "mla.down": "mixer", "mla.expand": "mixer", "mla.absorb": "mixer", "mla.attn": "mixer",
-    "gdn.state": "state", "mamba2.state": "state",
+    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state",
     "mlp": "ffn", "ffn": "ffn",
     "moe": "ffn", "moe.route": "ffn", "moe.place": "ffn", "moe.blocks": "ffn", "moe.shared": "ffn",
 }

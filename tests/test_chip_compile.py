@@ -308,7 +308,8 @@ def test_fsdp4_loss_and_grad_with_flash_kernel_compile_for_v5e(topo):
 # the hybrid cells' configuration files (benchmark/configs/) and what the harness passes their families beside them
 CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widths, 16 layers, 64 of 128 experts, 32 slots x 4096
          "qwen3_next": ("qwen3-next-80b-a3b-ep4.json", {"remat": False}),  # 12 of 48 layers, 128 of 512 experts, 16 slots x 4096
-         "glm": ("glm-4.7-flash-d8.json", {"remat": False})}  # 8 of 47 layers whole, 16 slots x 16,384
+         "glm": ("glm-4.7-flash-d8.json", {"remat": False}),  # 8 of 47 layers whole, 16 slots x 16,384
+         "kimi": ("kimi-linear-48b-a3b-ep4.json", {"remat": False})}  # 9 of 27 layers, 64 of 256 experts, 16 slots x 4096
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -571,15 +572,78 @@ def test_glm_prefill_of_the_16384_bucket_fits_beside_weights_and_cache_on_one_v5
 
 
 # ---------------------------------------------------------------------------
+# PR 42: a fourth description, Kimi Linear (models/kimi_linear.py): the cell kimi-linear-ep4.longdoc.
+# A state cache (7 layers of Kimi Delta Attention) BESIDE a latent slot cache (2 layers), the latent
+# kernel at 32 heads, the chunked delta rule with a gate by key channel in the prefill
+# ---------------------------------------------------------------------------
+def test_latent_attention_kernel_at_32_heads_compiles_for_v5e_and_the_gate_lets_kimis_tile_through(one_chip, as_on_a_tpu):
+    """32 query heads (two whole bfloat16 tiles, where GLM's 20 are padded to them) on rows of 512 + 128,
+    two latent layers of 16 x 4,096 positions: the gate lets the tile through, a Mosaic kernel under
+    the same name, nothing copied."""
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.ops import slot_attention as sa
+
+    tile = KimiLinearConfig().slot_attention_tile
+    assert tile == dict(num_heads=32, num_kv_heads=1, head_dim=640, value_dim=512) and sa.refusal(jnp.bfloat16, **tile, S=4096) is None
+    assert "compiled at 20 and 32" in sa.refusal(jnp.bfloat16, **{**tile, "num_heads": 48}, S=4096)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    c_stack, r_stack = sds((2, 16, 4096, 512), jnp.bfloat16), sds((2, 16, 4096, 128), jnp.bfloat16)
+    compiled, txt = _compile(partial(sa.attend_latent_kernel, scale=192 ** -0.5), sds((16, 32, 512), jnp.bfloat16), sds((16, 32, 128), jnp.bfloat16),
+                             c_stack, r_stack, sds((), jnp.int32), sds((16,), jnp.int32))
+    assert "tpu_custom_call" in txt and "latent_decode_attention" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_kimi_fused_step_fits_one_v5e_aliases_both_caches_and_slices_no_layers_rows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 16 x 4,096 through the SAME ``hybrid_runner.fused_step`` and layer loop as the
+    three other descriptions (head ``kda ffn``, then ``kda moe kda moe mla moe kda moe`` x 2 scanned):
+    7.96 GiB of weights, 0.16 GiB of latent rows and 0.23 GiB of state; both caches aliased to the
+    donated inputs, under 32 MiB of temporaries, the latent kernel in the step and no slice of a
+    layer's rows (64 MiB of latents at 16 x 4,096) in the compiled text."""
+    import re
+
+    cfg, _, cache, state, compiled = fused_step_for_the_chip("kimi")
+    assert cfg.layer_plan == (("kda", "moe", "kda", "moe", "mla", "moe", "kda", "moe"), 2, (), ("kda", "ffn"))
+    assert set(state) == {"S", "conv"} and set(cache) == {"c_kv", "k_r", "length"}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    caches = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)))
+    assert _kv_bytes(cache) == 16 * 4096 * 2560 and caches - _kv_bytes(cache) - 64 == 16 * 15_196_160
+    assert 8.3 * 2**30 < mem.argument_size_in_bytes < 8.4 * 2**30 and mem.alias_size_in_bytes >= caches
+    assert "latent_decode_attention" in txt and not re.search(r"bf16\[1,16,4096,(512|128|640)\]", txt)
+    assert mem.temp_size_in_bytes < 32 * 2**20
+    print("kimi fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 1.0), (8, 3.3)])
+def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, prompts, most_gib):
+    """The 4096-bucket prefill (the chunked delta rule with its gate by channel, a few sequences at a
+    time; the flash kernel at 32 heads, keys and values padded to 256; the grouped matmul over 64
+    experts in slabs) for one prompt (0.70 GiB of temporaries as compiled for PR 42) and for the
+    largest group the cell warms, 8 x 4096 (2.93 GiB and 0.19 GiB of output), beside 7.96 GiB of
+    weights and 0.38 GiB of caches: under 15.75 GiB."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "kimi")
+    tokens = jax.ShapeDtypeStruct((prompts, 4096), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("kimi prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    assert "tpu_custom_call" in txt, "the flash kernel, 32 heads padded to 256"
+    assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (3.4 GiB), no layer's worth of them (0.42 GiB x 8)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.39 * 2**30 < 15.0 * 2**30
+
+
+# ---------------------------------------------------------------------------
 # PR 37: the decode step's routed experts are the experts a bound lane chose, one after another
 # (models/experts.experts_step): on a TPU one kernel whose grid walks their ids, each expert's
 # matrices read from the stacked weights where they lie (ops/step_experts.py)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("cell, lanes_n, a_layers_experts_gb", [("nemotron", 32, 1.28), ("qwen3_next", 16, 0.81), ("glm", 16, 1.21)])
+@pytest.mark.parametrize("cell, lanes_n, a_layers_experts_gb", [("nemotron", 32, 1.28), ("qwen3_next", 16, 0.81), ("glm", 16, 1.21), ("kimi", 16, 0.91)])
 def test_fused_step_walks_the_experts_hit_and_copies_no_layers_experts(fused_step_for_the_chip, as_on_a_tpu, cell, lanes_n, a_layers_experts_gb):
     """Each hybrid cell's fused step at its own size: the gate lets the cell's expert through whole,
     the kernel is in the step, both caches are aliased to the donated inputs, temporaries stay
-    under 0.1 GiB where one layer's held experts are 1.28 / 0.81 / 1.21 GB, and no array of a
+    under 0.1 GiB where one layer's held experts are 1.28 / 0.81 / 1.21 / 0.91 GB, and no array of a
     layer's experts (``[held, F, H]``: the dense form's operand, or a slice made for the kernel)
     is anywhere in the program."""
     import re
