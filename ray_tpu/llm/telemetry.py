@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import json
 import logging
 import os
@@ -72,7 +73,7 @@ logger = logging.getLogger("ray_tpu.llm")
 # what a hybrid description may count of a prefill program from its shape alone
 # (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
 # takes on that step's row
-PREFILL_COUNTERS = ("kda_chunks",)
+PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks")
 
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
@@ -102,6 +103,18 @@ INSIDE = {"llm.step.prefill.launch": "llm.step.prefill", "llm.step.prefill.first
 INGRESS_T: contextvars.ContextVar = contextvars.ContextVar("rt_llm_ingress_t", default=None)
 
 NO_STAGE = contextlib.nullcontext()
+
+# seconds the cyclic garbage collector has held this process (a collection stops every thread: the
+# stepper blocked on the device wakes only when it ends), and the start of the one under way. One
+# callback a process, registered by the first EngineTelemetry; a step's row takes what its time saw.
+_GC_HELD = [0.0, 0.0]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC_HELD[1] = time.perf_counter()
+    else:
+        _GC_HELD[0] += time.perf_counter() - _GC_HELD[1]
 
 
 class _Stage:
@@ -378,7 +391,9 @@ class FlightRecorder:
         # (``HybridDescription.prefill_counters``): chunks of the delta rule that the programs ran, padding's
         # among them, over the layers of Kimi Delta Attention; absent for a description that counts none
         *PREFILL_COUNTERS,
-    ) + tuple(STAGES.values())
+        # then the stage durations, and the milliseconds of the step that the process spent inside
+        # the garbage collector (every thread held; absent where there were none)
+    ) + tuple(STAGES.values()) + ("gc_ms",)
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
     # steps/s, 2,000 requests (about 8 MB of step rows and 11 MB of
@@ -573,6 +588,9 @@ class EngineTelemetry:
         # zero-overhead gate (the flight RECORD still lands every step)
         self.SAMPLE_EVERY = 16
         self._nstep = 0
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        self._gc_seen = _GC_HELD[0]  # the collector's clock as the last step's row took it
         self._wire_accum = 0.0
         self._tok_accum = 0.0
         # cumulative spec accounting mirrors (deltas per step go into the
@@ -595,8 +613,15 @@ class EngineTelemetry:
         # live EMAs the admission controller reads (serve/overload.py):
         # inter-token latency and per-request service time (admit ->
         # finish wall). One multiply-add on paths already stamping these
-        # clocks — inside the zero-overhead gate's budget.
+        # clocks — inside the zero-overhead gate's budget. The ITL EMA
+        # takes one sample a STEP, the mean of the gaps the step's lanes
+        # saw (_gap_sum / _gap_n, folded in on_step): a sample a token
+        # made its memory shorter than one step at 16 lanes, so that one
+        # stalled step of 2.4 s read as a steady state and the wait
+        # estimate built on it (queued tokens x EMA / slots) shed
+        # requests a moment later (PR 44).
         self.itl_ema_s = 0.0
+        self._gap_sum, self._gap_n = 0.0, 0
         self.service_ema_s = 0.0
         # optional per-sample-tick callback (the admission controller's
         # queue-wait-gauge refresh): called with the current queue depth
@@ -723,8 +748,10 @@ class EngineTelemetry:
             gap = now - st.t_last
             st.itls.append(gap)
             self._b_itl.observe(max(gap, 0.0))
-            g = max(gap, 0.0)
-            self.itl_ema_s = g if self.itl_ema_s == 0.0 else 0.9 * self.itl_ema_s + 0.1 * g
+            # the live EMA takes ONE sample a step, in on_step: the lanes of a step all wait out
+            # the same step, and sixteen gaps of one stall are one observation, not sixteen
+            self._gap_sum += max(gap, 0.0)
+            self._gap_n += 1
         st.t_last = now
         self._tok_accum += 1.0  # flushed into the counter on sample ticks
 
@@ -773,12 +800,13 @@ class EngineTelemetry:
                  "tokens": len(st.token_ids), "stage": self.tags["stage"]},
             )
 
-    def on_stream(self, request_id: str, first_yield_t: float, last_yield_t: float) -> None:
+    def on_stream(self, request_id: str, first_yield_t: float, last_yield_t: float) -> dict | None:
         """The serving stream of a finished request ended: when its
         generator yielded its first and last token chunk. Called from the
         replica's request thread AFTER on_finish (the stream outlives the
         engine's last token), so the stamps are added to the recorded
-        request, and the ``llm.stream`` span is built from that record."""
+        request, and the ``llm.stream`` span is built from that record.
+        -> the record, or None where the request is not (or no longer) in the log."""
         rec = self.recorder.stamp_request(request_id, first_yield_t=first_yield_t, last_yield_t=last_yield_t)
         if rec is not None and rec["trace_id"] is not None and first_yield_t:
             tracing.record_span(
@@ -786,6 +814,7 @@ class EngineTelemetry:
                 int(first_yield_t * 1e9), int(last_yield_t * 1e9),
                 {"request_id": request_id, "stage": self.tags["stage"]},
             )
+        return rec
 
     def on_prefix_hit(self, tier: str, tokens: int, nbytes: int = 0) -> None:
         """A prompt admission reused a cached prefix. ``tier``: "local"
@@ -926,7 +955,13 @@ class EngineTelemetry:
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],
             self._step_t0, dispatch_t, prefill_t, *moe, *[round(ms, 4) for ms in stages],
+            round((_GC_HELD[0] - self._gc_seen) * 1e3, 3) or None,
         ))
+        self._gc_seen = _GC_HELD[0]
+        if self._gap_n:
+            g = self._gap_sum / self._gap_n
+            self.itl_ema_s = g if self.itl_ema_s == 0.0 else 0.9 * self.itl_ema_s + 0.1 * g
+            self._gap_sum, self._gap_n = 0.0, 0
 
         if slots_in_use and eng._device_resident and self._wire_bytes_per_step:
             # accumulate locally (one float add), flush on sample ticks

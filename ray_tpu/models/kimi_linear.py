@@ -58,6 +58,7 @@ from ray_tpu.models.glm4_moe_lite import LatentAttention, ffn, mla_seq, mla_step
 from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
 from ray_tpu.models.nemotron_h import _anchor_routing  # one orthogonal matrix for all expert layers' routers: 8 x 256 columns fit 2,304 dimensions
 from ray_tpu.models.qwen3_next import a_few_at_a_time, delta_rule_chunked, delta_rule_step, init_decay, short_conv_seq, short_conv_step
+from ray_tpu.ops import delta_rule
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.util.profiling import scope
 
@@ -131,7 +132,7 @@ class KimiLinearConfig(LatentAttention, HybridDescription):
         dt = jnp.dtype(self.dtype)
 
         def rule_seq(w, xn, ctx):
-            y, S, conv = kda_seq(w, xn.astype(dt), ctx.lengths, self)
+            y, S, conv = kda_seq(w, xn.astype(dt), ctx.lengths, self, ctx.mesh)
             return y, {"S": S, "conv": conv}
 
         def rule_step(w, xn, cache, ctx):
@@ -211,8 +212,13 @@ class KimiLinearConfig(LatentAttention, HybridDescription):
 
     def prefill_counters(self, batch: int, length: int) -> dict:
         """What one prefill program of ``batch`` x ``length`` positions (as padded) runs that the
-        flight log counts from its shape alone: the chunks of the delta rule, over the KDA layers."""
-        return {"kda_chunks": self.count("kda") * batch * -(-length // min(self.chunk_size, length))}
+        flight log counts from its shape alone: the chunks of the delta rule, over the KDA layers,
+        and how many of them the kernel ran (all, or none where ``ops/delta_rule.refusal`` speaks)."""
+        chunk = min(self.chunk_size, length)
+        chunks = self.count("kda") * batch * -(-length // chunk)
+        operand = None if self.dtype == "float32" else self.dtype
+        refused = delta_rule.refusal(operand, self.kda_head_dim, self.kda_head_dim, chunk)
+        return {"kda_chunks": chunks, "kda_kernel_chunks": 0 if refused else chunks}
 
     def num_params(self) -> int:
         """Parameters held here (the chip's share of experts and vocabulary)."""
@@ -313,10 +319,11 @@ def _kda_out(w, o, low, c: KimiLinearConfig, dtype):
     return jnp.dot(y.reshape(*y.shape[:-2], c.kda_dim).astype(dtype), w["out_proj"])
 
 
-def kda_seq(w, xn, lengths, c: KimiLinearConfig):
+def kda_seq(w, xn, lengths, c: KimiLinearConfig, mesh=None):
     """xn [B,T,H], lengths [B] -> (out [B,T,H], S [B,nh,dk,dk] f32, conv [B,K-1,3*nh*dk]): the state
     and the convolutions' window AT each sequence's true length. A batch of more than
-    ``qwen3_next.RULE_POSITIONS`` positions goes through a few sequences at a time."""
+    ``qwen3_next.RULE_POSITIONS`` positions goes through a few sequences at a time. ``mesh``: the
+    mesh the program runs over, if any (the rule's kernel has to be told)."""
     T = xn.shape[1]
     operand = None if xn.dtype == jnp.float32 else xn.dtype
 
@@ -326,7 +333,7 @@ def kda_seq(w, xn, lengths, c: KimiLinearConfig):
         q, k, v, beta, g = _kda_inputs(w, conv, low, c)
         real = (jnp.arange(T)[None, :] < lengths[:, None])[..., None]
         beta, g = jnp.where(real, beta, 0.0), jnp.where(real[..., None], g, 0.0)  # padding writes nothing and decays nothing
-        o, S = delta_rule_chunked(q, k, v[:, :, :, None], g[:, :, :, None], beta[..., None], c.chunk_size, operand, name="kda")
+        o, S = delta_rule_chunked(q, k, v[:, :, :, None], g[:, :, :, None], beta[..., None], c.chunk_size, operand, name="kda", mesh=mesh)
         return _kda_out(w, o[:, :, :, 0], low, c, xn.dtype), S[:, :, 0], window
 
     return a_few_at_a_time(some, xn, lengths)

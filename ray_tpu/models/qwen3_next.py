@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from ray_tpu.models import experts
 from ray_tpu.models.experts import ExpertLayer
 from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, attend_slot, forward, init_stacked, loss_fn  # noqa: F401 - the shared forward and loss, as the harness's family asks for them
+from ray_tpu.ops import delta_rule
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
 from ray_tpu.ops.layers import apply_rope, rotary_embedding
 from ray_tpu.util.profiling import scope
@@ -63,7 +64,7 @@ SCOPES = {"gdn": "gdn", "attn": "gated_attn", "moe": "moe"}
 # arrays of 4 KB a position and value-head set) stay under a gigabyte beside the weights
 RULE_POSITIONS = 8192
 # positions in a sub-block of a chunk, for a gate that differs by key channel (``_pairs_by_channel``)
-SUB_BLOCK = 16
+SUB_BLOCK = delta_rule.SUB_BLOCK
 
 
 @dataclass(frozen=True)
@@ -389,13 +390,14 @@ def _pairs_by_channel(firsts, k, gc, sub: int, es):
     return tuple(pairs(a) for a in firsts)
 
 
-def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None, name: str = "gdn"):
+def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None, name: str = "gdn", mesh=None):
     """The gated delta rule over a sequence from a zero state, blocked in chunks. q, k [B,T,G,K]
     (key heads), v [B,T,G,R,V] (a key head's R value heads), beta [B,T,G,R] and g, the log-decay
     (<= 0): [B,T,G,R], one gate a head (Gated DeltaNet), or [B,T,G,R,K], one for each of a head's
     key channels (Kimi Delta Attention: ``S <- Diag(exp(g)) S`` in place of ``exp(g) S``), float32
     -> (o [B,T,G,R,V], the state after position T-1 [B,G,R,K,V]). ``name``: whose scopes the two
-    stretches stand under (``<name>.chunk``, ``<name>.scan``).
+    stretches stand under (``<name>.chunk``, ``<name>.scan``). ``mesh``: the mesh the program runs
+    over, if any.
 
     Inside a chunk, with gamma_t the decay since the chunk's start and S_0 the state there, the
     rule's written values ``u_t = beta_t (v_t - S'_t^T k_t)`` solve the unit lower-triangular
@@ -412,7 +414,13 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None, name: s
     One body for both gates: everything above holds with gamma a vector over the key channels
     (``gamma_t / gamma_s`` then stands INSIDE ``k_t.k_s`` and ``q_t.k_s``), and only those two
     C x C sets of pairs are built differently (``_pairs_by_channel``, in sub-blocks of ``SUB_BLOCK``
-    positions). A gate a head takes the lines it always took."""
+    positions). A gate a head takes the lines it always took. A gate by channel runs as ONE kernel
+    that keeps a chunk and the state in fast memory (``ops/delta_rule.py``: the same lines at the
+    same precision, all of it under ``<name>.chunk``) unless its ``refusal`` gives a reason; then,
+    and for a gate a head, the lines below run."""
+    if g.ndim == beta.ndim + 1 and delta_rule.refusal(operand_dtype, q.shape[-1], v.shape[-1], min(chunk, q.shape[1]), mesh=mesh) is None:
+        with scope(f"{name}.chunk"):  # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
+            return delta_rule.delta_rule_by_channel(q, k, v, g, beta, chunk, operand_dtype, interpret=jax.default_backend() != "tpu")
     hi = jax.lax.Precision.HIGHEST
     if operand_dtype is None or jnp.dtype(operand_dtype) == jnp.float32:
         def es(spec, a, b):

@@ -19,6 +19,7 @@ Llama inference on autoscaling TPU replicas).
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
 import time
@@ -33,6 +34,8 @@ from ray_tpu.serve.overload import (
     ReplicaDrainingError,  # noqa: F401 (re-export)
     StepperDiedError,
 )
+
+logger = logging.getLogger("ray_tpu.serve.llm")
 
 # how long a stream's generator waits on its token queue before it looks
 # again at the stepper's health and its own deadline
@@ -134,6 +137,9 @@ class LLMServer:
         self._drain_result: dict | None = None
         self._preempt_deadline_s = float(llm_config.preempt_deadline_s)
         self._stepper_error: str | None = None
+        # streams this replica served, and those that ended other than by their
+        # sentinel after the last token, by cause (stream_stats)
+        self._streams = {"served": 0, "ended_badly": {}}
         self._work = threading.Event()
         # bounded admission at this replica's ingress (serve/overload.py):
         # past the caps generate() sheds with a typed OverloadedError
@@ -401,6 +407,23 @@ class LLMServer:
         """Admission-control counters: admitted, shed by cause and by
         request class, live queue-wait estimate, drain state."""
         return self._admission.stats()
+
+    def stream_stats(self) -> dict:
+        """Streams this replica served to their end, and those that ended
+        other than by their sentinel after the last token, by cause: the
+        consumer closed the stream, the stepper died, no token came in
+        time, or the engine ended the request early (its finish reason).
+        A lost request then shows on the replica's side too."""
+        with self._lock:
+            return {"served": self._streams["served"], "ended_badly": dict(self._streams["ended_badly"])}
+
+    def _stream_ended(self, rid: str, cause: str | None, tokens: int) -> None:
+        with self._lock:
+            self._streams["served"] += 1
+            if cause is not None:
+                self._streams["ended_badly"][cause] = self._streams["ended_badly"].get(cause, 0) + 1
+        if cause is not None:
+            logger.warning("stream %s ended badly after %d token(s): %s", rid, tokens, cause)
 
     def __call__(self, request):
         """HTTP entry: POST {"prompt_token_ids": [...], "sampling_params": {...}}."""
@@ -806,42 +829,58 @@ class OpenAIServer(LLMServer):
         """The generator half of _stream_completion (admission already
         done): drain the request's token queue into SSE chunks. Stamps
         the first and last token chunk it yields (two stamps a request,
-        none per token) into the request's flight record when it ends."""
+        none per token) into the request's flight record when it ends,
+        and counts how it ended (stream_stats)."""
         import json as _json
 
         key = "delta" if chat else "text"
         obj = "chat.completion.chunk" if chat else "text_completion"
         deadline = time.monotonic() + 300.0
         first_yield_t = last_yield_t = 0.0
+        tokens = 0
+        # what a GeneratorExit at a yield leaves standing: the worker's stream
+        # loop closes the generator when its consumer cancelled
+        cause = "closed by the consumer"
+        rec = None
         try:
-            while True:
-                if self._stepper_error is not None:
-                    raise StepperDiedError(f"llm stepper died:\n{self._stepper_error}")
-                try:
-                    tok = out_q.get(timeout=min(_STREAM_POLL_S, max(0.1, deadline - time.monotonic())))
-                except queue.Empty as e:
-                    if time.monotonic() > deadline:
-                        self.engine.abort_request(rid)
-                        # typed (504, retryable) and chained: GetTimeoutError
-                        # IS-A TimeoutError, so pre-taxonomy callers still match
-                        raise GetTimeoutError(f"stream {rid} produced no token for 300s") from e
-                    continue
-                if tok is None:
+            try:
+                while True:
                     if self._stepper_error is not None:
                         raise StepperDiedError(f"llm stepper died:\n{self._stepper_error}")
-                    break
-                piece = self._decode([tok])
-                content = {"role": "assistant", "content": piece} if chat else piece
-                chunk = "data: " + _json.dumps(
-                    {"id": rid, "object": obj, "model": self.model_id, "choices": [{"index": 0, key: content}]}
-                ) + "\n\n"
-                last_yield_t = time.time()
-                first_yield_t = first_yield_t or last_yield_t
-                yield chunk
+                    try:
+                        tok = out_q.get(timeout=min(_STREAM_POLL_S, max(0.1, deadline - time.monotonic())))
+                    except queue.Empty as e:
+                        if time.monotonic() > deadline:
+                            self.engine.abort_request(rid)
+                            # typed (504, retryable) and chained: GetTimeoutError
+                            # IS-A TimeoutError, so pre-taxonomy callers still match
+                            raise GetTimeoutError(f"stream {rid} produced no token for 300s") from e
+                        continue
+                    if tok is None:
+                        if self._stepper_error is not None:
+                            raise StepperDiedError(f"llm stepper died:\n{self._stepper_error}")
+                        break
+                    piece = self._decode([tok])
+                    content = {"role": "assistant", "content": piece} if chat else piece
+                    chunk = "data: " + _json.dumps(
+                        {"id": rid, "object": obj, "model": self.model_id, "choices": [{"index": 0, key: content}]}
+                    ) + "\n\n"
+                    last_yield_t = time.time()
+                    first_yield_t = first_yield_t or last_yield_t
+                    yield chunk
+                    tokens += 1
+            finally:
+                if self.engine._tel is not None and first_yield_t:
+                    rec = self.engine._tel.on_stream(rid, first_yield_t, last_yield_t)
+            yield "data: [DONE]\n\n"
+            # the sentinel came and the consumer took the end: was it after the last token?
+            reason = rec["reason"] if rec is not None else "before a token" if not tokens else "length"
+            cause = None if reason in ("length", "stop") else f"the engine ended it: {reason}"
+        except (StepperDiedError, GetTimeoutError) as e:
+            cause = "stepper died" if isinstance(e, StepperDiedError) else "no token for 300 s"
+            raise
         finally:
-            if self.engine._tel is not None and first_yield_t:
-                self.engine._tel.on_stream(rid, first_yield_t, last_yield_t)
-        yield "data: [DONE]\n\n"
+            self._stream_ended(rid, cause, tokens)
 
 
 class PrefillServer(LLMServer):

@@ -614,13 +614,17 @@ def test_kimi_fused_step_fits_one_v5e_aliases_both_caches_and_slices_no_layers_r
     print("kimi fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
 
 
-@pytest.mark.parametrize("prompts, most_gib", [(1, 1.0), (8, 3.3)])
-def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, prompts, most_gib):
-    """The 4096-bucket prefill (the chunked delta rule with its gate by channel, a few sequences at a
-    time; the flash kernel at 32 heads, keys and values padded to 256; the grouped matmul over 64
-    experts in slabs) for one prompt (0.70 GiB of temporaries as compiled for PR 42) and for the
-    largest group the cell warms, 8 x 4096 (2.93 GiB and 0.19 GiB of output), beside 7.96 GiB of
-    weights and 0.38 GiB of caches: under 15.75 GiB."""
+@pytest.mark.parametrize("prompts, most_gib", [(1, 0.70), (8, 2.93)])
+def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 4096-bucket prefill (the delta rule with its gate by channel as ONE kernel under
+    ``kda.chunk``, PR 44, a few sequences at a time; the flash kernel at 32 heads, keys and values
+    padded to 256; the grouped matmul over 64 experts in slabs) for one prompt (0.52 GiB of
+    temporaries as compiled for PR 44, under PR 42's 0.70) and for the largest group the cell
+    warms, 8 x 4096 (2.67 GiB, under PR 42's 2.93, and 0.19 GiB of output), beside 7.96 GiB of
+    weights and 0.38 GiB of caches: under 15.75 GiB. No ``[.., 64, 64]`` float32 square of a
+    chunk's pairs is left among the program's arrays, and no line of the XLA form's scan."""
+    import re
+
     from ray_tpu.llm import hybrid_runner as hr
 
     cfg, params, _, _ = _cell_at_its_size(one_chip, "kimi")
@@ -630,8 +634,52 @@ def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v
     mem = compiled.memory_analysis()
     print("kimi prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
     assert "tpu_custom_call" in txt, "the flash kernel, 32 heads padded to 256"
+    rule = [line for line in txt.splitlines() if "custom-call(" in line and "delta_rule_by_channel" in line]
+    assert rule and all("tpu_custom_call" in line and "kda.chunk" in line for line in rule), "the rule's kernel, under its scope"
+    assert "kda.scan" not in txt and not re.search(r"f32\[[0-9,]*64,64\]", txt)
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (3.4 GiB), no layer's worth of them (0.42 GiB x 8)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.39 * 2**30 < 15.0 * 2**30
+
+
+def test_delta_rule_kernel_compiles_for_v5e_within_its_vmem_and_copies_nothing(one_chip, as_on_a_tpu):
+    """PR 44: the kernel alone at the cell's tile (32 heads x 128, chunk 64) and its longest
+    bucket, two sequences as ``a_few_at_a_time`` hands them over: the gate lets the tile through,
+    Mosaic takes the kernel inside the VMEM a kernel may scope (it refuses one that asks for
+    more), and q, k, v, the gate and beta go in where a position's heads lie side by side:
+    nothing is transposed or copied on the way in or out."""
+    from ray_tpu.ops import delta_rule as dr
+
+    B, T, G, K = 2, 4096, 32, 128
+    assert dr.refusal(jnp.bfloat16, K, K, 64) is None
+    flat = jax.ShapeDtypeStruct((B, T, G * K), jnp.float32, sharding=one_chip)
+
+    def rule(q, k, v, g, beta):
+        q, k, v, g = (a.reshape(B, T, G, K) for a in (q, k, v, g))
+        o, S = dr.delta_rule_by_channel(q, k, v[:, :, :, None], g[:, :, :, None], beta[..., None], 64, jnp.bfloat16)
+        return o.reshape(B, T, G * K), S
+
+    compiled, txt = _compile(rule, flat, flat, flat, flat, jax.ShapeDtypeStruct((B, T, G), jnp.float32, sharding=one_chip))
+    assert "tpu_custom_call" in txt and "delta_rule_by_channel" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_a_gate_a_head_lowers_to_the_text_it_had_whatever_the_backend(as_on_a_tpu):
+    """Qwen3-Next's rule (one gate a head) at its cell's prefill shape: the by-channel kernel's gate
+    is never asked, so what is lowered as on a TPU is what is lowered here, line for line."""
+    from ray_tpu.models import qwen3_next as qn
+    from ray_tpu.ops import delta_rule as dr
+
+    B, T, G, R, K = 2, 4096, 16, 2, 128
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    args = (s(B, T, G, K), s(B, T, G, K), s(B, T, G, R, K), s(B, T, G, R), s(B, T, G, R))
+    rule = lambda *a: qn.delta_rule_chunked(*a, 64, jnp.bfloat16)  # noqa: E731
+    as_on_the_chip = jax.jit(rule).lower(*args).as_text()
+    assert "gdn.chunk" in jax.jit(rule).lower(*args).as_text(debug_info=True) and "pallas" not in as_on_the_chip
+    jax.default_backend = lambda: "cpu"  # the fixture's monkeypatch puts the real one back
+    assert jax.jit(rule).lower(*args).as_text() == as_on_the_chip
+    by_channel = lambda q, k, v, g, beta: qn.delta_rule_chunked(q, k, v, jnp.broadcast_to(g[..., None], g.shape + (K,)), beta, 64, jnp.bfloat16, name="kda")  # noqa: E731
+    jax.default_backend = lambda: "tpu"
+    assert dr.refusal(jnp.bfloat16, K, K, 64) is None and "pallas_call" in str(jax.make_jaxpr(by_channel)(*args)), "the same call with a gate by channel takes the kernel"
 
 
 # ---------------------------------------------------------------------------

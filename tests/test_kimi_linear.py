@@ -26,6 +26,7 @@ from ray_tpu.models import glm4_moe_lite as glm
 from ray_tpu.models import hybrid
 from ray_tpu.models import kimi_linear as kl
 from ray_tpu.models import qwen3_next as qn
+from ray_tpu.ops import delta_rule
 from ray_tpu.ops import slot_attention as sa
 from ray_tpu.ops.layers import apply_rope, rotary_embedding
 
@@ -107,7 +108,7 @@ def test_the_description_is_a_dense_layer_then_the_period_twice_and_keeps_two_ki
     assert (s.num_experts, s.held, s.top_k, s.score, s.bias, s.norm_topk, s.scale, s.act, s.shared_gated) == (
         256, 64, 8, "sigmoid", True, True, 2.446, "swiglu", False)
     # what a prefill program runs of the rule, from its shape: 7 layers x 8 sequences x 4,096 / 64 chunks
-    assert cut.prefill_counters(8, 4096) == {"kda_chunks": 7 * 8 * 64} and glm.Glm4MoeLiteConfig().prefill_counters(8, 4096) == {}
+    assert cut.prefill_counters(8, 4096) == {"kda_chunks": 7 * 8 * 64, "kda_kernel_chunks": 0} and glm.Glm4MoeLiteConfig().prefill_counters(8, 4096) == {}
 
 
 def test_the_counts_are_the_programs(params):
@@ -242,15 +243,26 @@ def test_one_token_at_a_time_through_both_caches_equals_the_sequence_form(params
     assert here[3] is None and all(np.array_equal(a, b) for a, b in zip(here[:3], there[:3]))
 
 
-def test_an_admitting_row_of_the_flight_log_counts_the_chunks_its_prefills_ran(eng):
+@pytest.mark.parametrize("form", ["the_xla_lines", "the_kernel"])
+def test_an_admitting_row_of_the_flight_log_counts_the_chunks_its_prefills_ran(eng, params, monkeypatch, form):
+    """``kda_kernel_chunks`` beside ``kda_chunks`` says which form ran them: none on the CPU, where
+    ``ops/delta_rule.refusal`` speaks; all of them once it does not (a second engine, so that its
+    prefill programs are traced with the kernel in them, interpreted), and the tokens are the same."""
+    lengths = (20, 9, 41)  # buckets 32, 16 and 64: three programs of one sequence, chunks of 8
+    ps = battery.prompts(DESC, 6, lengths)
+    if form == "the_kernel":
+        want = [o.token_ids for o in eng.generate(ps[:1], SamplingParams(max_tokens=3, temperature=0.0))]
+        monkeypatch.setattr(delta_rule, "refusal", lambda *a, **kw: None)
+        eng, ps, lengths = battery.engine(CFG, params), ps[:1], lengths[:1]
     mark = eng.telemetry()["step_count"]
-    ps = battery.prompts(DESC, 6, (20, 9, 41))  # buckets 32, 16 and 64: three programs of one sequence, chunks of 8
-    eng.generate(ps, SamplingParams(max_tokens=3, temperature=0.0))
+    outs = eng.generate(ps, SamplingParams(max_tokens=3, temperature=0.0))
     rows = battery.steps_after(eng, mark)
     admitting = [r for r in rows if r.get("admitted")]
-    assert sum(r["kda_chunks"] for r in admitting) == CFG.count("kda") * (32 + 16 + 64) // 8
+    assert sum(r["kda_chunks"] for r in admitting) == CFG.count("kda") * sum(1 << (n - 1).bit_length() for n in lengths) // 8
     assert all(r["kda_chunks"] * 8 == CFG.count("kda") * r["prefill_tokens_padded"] for r in admitting)
-    assert not any("kda_chunks" in r for r in rows if not r.get("admitted"))
+    assert all(r["kda_kernel_chunks"] == (r["kda_chunks"] if form == "the_kernel" else 0) for r in admitting)
+    assert not any("kda_chunks" in r or "kda_kernel_chunks" in r for r in rows if not r.get("admitted"))
+    assert form == "the_xla_lines" or [o.token_ids for o in outs] == want
 
 
 # ------------------------------------------------------------------------------ the latent kernel

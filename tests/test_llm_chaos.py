@@ -191,6 +191,43 @@ def test_estimated_queue_wait_feeds_admission(params):
     ac.check(0)  # queue empty again: admits
 
 
+def test_one_stalled_step_does_not_shed_the_requests_that_come_after_it(params):
+    """PR 44's soak: one prefill's first-token wait took 2.2 s where 0.12 is usual, every one of the
+    16 lanes saw that one gap, and the ITL EMA, fed a sample a TOKEN, stood at 1.84 s a moment later:
+    the wait estimate (queued max_tokens x EMA / slots) read 11.5 s with three requests waiting and
+    would have shed the next caller (15 s for class 0) with four, though a slot was free within a
+    second. The EMA takes one sample a STEP: a stall is one observation; a stall that LASTS is a
+    steady state, and the estimate follows it within a few dozen steps and sheds."""
+    from types import SimpleNamespace
+
+    eng = LLMEngine(CFG, params, max_num_seqs=16, max_seq_len=128)
+    tel, now = eng._tel, [100.0]
+    lanes = [SimpleNamespace(t_first=1.0, t_last=now[0], itls=[]) for _ in range(16)]
+
+    def step(gap: float):
+        now[0] += gap
+        for st in lanes:
+            tel.on_emit(st, now[0])
+        tel.on_step(time.perf_counter(), 0, len(lanes), None)
+
+    for _ in range(50):
+        step(0.008)
+    assert tel.itl_ema_s == pytest.approx(0.008)
+    step(2.4)
+    assert tel.itl_ema_s == pytest.approx(0.9 * 0.008 + 0.1 * 2.4)
+    for _ in range(4):
+        eng.add_request(list(PROMPT), SamplingParams(max_tokens=40))
+    ac = AdmissionController(eng)  # the defaults a replica runs with: 30 s, half of it for class 0
+    assert ac.estimate_queue_wait_s() < 3.0
+    ac.check(0)
+    assert ac.stats()["shed_wait"] == 0 and ac.stats()["admitted"] == 1
+    for _ in range(40):
+        step(2.4)
+    with pytest.raises(OverloadedError):
+        ac.check(0)
+    assert ac.stats()["shed_wait"] == 1
+
+
 def test_admission_check_is_cheap(params):
     """The admission test is host-only dict work — cheap enough to sit
     on every ingress without touching the serving budget (the 1.05x

@@ -157,6 +157,27 @@ def test_a_hybrid_models_rows_carry_routing_counters_and_the_state_insert_stage(
     assert eng._tel._state_bytes == eng.kv_cache_stats()["state_allocated_bytes"] > 0 and plain._tel._state_bytes == 0
 
 
+def test_a_step_row_carries_the_time_the_process_spent_collecting_garbage():
+    """A collection holds every thread of the process, the stepper blocked on the device among them
+    (a prefill's first-token wait of 2.2 s beside its usual 0.12, PR 44): the row of the step that
+    sat through it says so (``gc_ms``), and a row with none leaves the column out."""
+    import gc
+
+    eng = _engine()
+    eng.generate([[1, 2, 3]], SamplingParams(max_tokens=2))
+    before = len(eng.telemetry()["steps"])
+    junk = [[i] for i in range(200_000)]  # something for the collector to walk
+    t0 = time.perf_counter()
+    gc.collect()
+    held_ms = (time.perf_counter() - t0) * 1e3
+    eng.generate([[4, 5, 6]], SamplingParams(max_tokens=3))
+    rows = eng.telemetry()["steps"][before:]
+    assert rows and "gc_ms" in telemetry.FlightRecorder.STEP_FIELDS
+    assert rows[0]["gc_ms"] >= 0.8 * held_ms > 0, "the first step after the collection saw it"
+    assert all(r.get("gc_ms", 0.0) < held_ms for r in rows[1:])
+    del junk
+
+
 def test_every_step_row_counts_the_lanes_that_sample():
     """PR 32: ``sampling_lanes`` is the count of bound lanes with temperature > 0, from the host's
     lane table: the lanes whose top-k or top-p make a step run the sampler's counting passes."""
@@ -374,6 +395,39 @@ def test_a_starved_stream_goes_on_past_its_poll(monkeypatch):
         chunks = list(srv._stream_tokens("req-starved", q, chat=False))
         t.join(timeout=5)
         assert not t.is_alive() and len(chunks) == 2 and json.loads(chunks[0][6:])["choices"][0]["text"] == [17]
+    finally:
+        srv.shutdown()
+
+
+@pytest.mark.parametrize("ending", ["to_its_end", "closed_by_the_consumer", "aborted_by_the_engine", "the_stepper_died"])
+def test_the_replica_counts_the_streams_that_ended_badly_by_cause(ending):
+    """``stream_stats``: a stream that gave every token and its ``[DONE]`` counts as served and no more;
+    one whose consumer closed it between two tokens (the worker's stream loop does, when the client
+    cancelled), one the engine ended before its last token, and one whose stepper died each count
+    under their cause, so that a request the client lost shows on the replica's side too."""
+    from ray_tpu.serve.overload import StepperDiedError
+
+    srv = _server(OpenAIServer)
+    try:
+        gen = srv({"prompt": [1, 2, 3], "max_tokens": 40 if ending != "to_its_end" else 4, "stream": True})
+        first = next(gen)
+        rid = json.loads(first[6:])["id"]
+        if ending == "to_its_end":
+            rest = list(gen)
+            assert len(rest) == 4 and rest[-1].startswith("data: [DONE]")
+        elif ending == "closed_by_the_consumer":
+            gen.close()
+        elif ending == "aborted_by_the_engine":
+            assert srv.engine.abort_request(rid)
+            rest = list(gen)
+            assert rest[-1].startswith("data: [DONE]") and len(rest) < 40, "the stream ends by its sentinel all the same, short"
+        else:
+            srv._fail_all_waiters("the test killed it")
+            with pytest.raises(StepperDiedError):
+                list(gen)
+        want = {"to_its_end": {}, "closed_by_the_consumer": {"closed by the consumer": 1},
+                "aborted_by_the_engine": {"the engine ended it: aborted": 1}, "the_stepper_died": {"stepper died": 1}}[ending]
+        assert srv.stream_stats() == {"served": 1, "ended_badly": want}
     finally:
         srv.shutdown()
 
