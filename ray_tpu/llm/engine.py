@@ -572,6 +572,10 @@ class LLMEngine:
         # mesh, tile), else None; the step's blocks read and in all, for the flight log
         self._attn_block = None
         self._step_attn_blocks = None
+        # what a description counts of a decode step from the positions its lanes hold, for the
+        # flight log; asked only of a description that counts something
+        self._step_counted = None
+        self._counts_decode = self._hybrid and bool(config.decode_counters([1]))
         if kv_layout == "slots":
             from ray_tpu.ops import slot_attention
 
@@ -2271,13 +2275,14 @@ class LLMEngine:
             if tel.prefill_dispatch_t is None:
                 tel.prefill_dispatch_t = []
             tel.prefill_dispatch_t.append([t_dispatch, t_launched, time.time()])
-        if kept and self._tel is not None and "routing" in kept:
+        if self._hybrid and self._tel is not None:
             # the step's row in the flight log: tokens prefilled, true and as padded, and the
-            # routing counters, read AFTER the first tokens (whose readback the program's end
-            # already waited for): the copy of three floats waits for nothing
-            routing = np.asarray(kept["routing"])  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
+            # routing counters (zeros for a description that routes nothing), read AFTER the first
+            # tokens (whose readback the program's end already waited for): the copy of three floats
+            # waits for nothing
+            routing = np.asarray(kept["routing"]) if "routing" in kept else np.zeros((3,), np.float32)  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
             seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
-            counted = self.config.prefill_counters(Bp, T)
+            counted = self.config.prefill_counters(Bp, T, lengths=[len(p) for _, _, p in group])
             self._prefill_stats = (seen[0] + int(sum(len(p) for _, _, p in group)), seen[1] + Bp * T,
                                    seen[2] + 1, seen[3] + routing, {k: seen[4].get(k, 0) + v for k, v in counted.items()})
 
@@ -2687,17 +2692,20 @@ class LLMEngine:
         mask[[st.slot for st in active]] = True
         return mask
 
+    def _positions_held(self, active: list, prev) -> list:
+        """Positions each active lane holds as the step about to be dispatched attends, its new
+        token's among them. From host state alone: a lane holds its prompt and the tokens emitted
+        so far, and one more where the step still in flight (``prev``) ran it."""
+        in_flight = {id(st) for st, _ in prev[-1]} if prev is not None else ()
+        return [min(len(st.prompt_token_ids) + len(st.token_ids) + (id(st) in in_flight), self.max_seq_len) for st in active]
+
     def _count_step_attn_blocks(self, active: list, prev) -> tuple:
         """(blocks of positions the step about to be dispatched reads, blocks the cache holds),
         over the layers that keep keys and values: a lane reads the blocks up to its new token's
         position, an unbound lane none. From host state alone: a lane holds its prompt and the
         tokens emitted so far, and one more where the step still in flight (``prev``) ran it."""
         blk = self._attn_block
-        in_flight = {id(st) for st, _ in prev[-1]} if prev is not None else ()
-        read = sum(
-            -(-min(len(st.prompt_token_ids) + len(st.token_ids) + (id(st) in in_flight), self.max_seq_len) // blk)
-            for st in active
-        )
+        read = sum(-(-n // blk) for n in self._positions_held(active, prev))
         return read * self._kv_layers, self.max_num_seqs * (self.max_seq_len // blk) * self._kv_layers
 
     def _dispatch_fused(self, prev=None):
@@ -2705,11 +2713,13 @@ class LLMEngine:
         blocks on results (stored in self._pending for the next call).
         ``prev``: the step still in flight, which the caller has taken."""
         active = [s for s in self._slots if s is not None]
-        self._step_attn_blocks = None
+        self._step_attn_blocks = self._step_counted = None
         if not active:
             return
         if self._attn_block is not None:
             self._step_attn_blocks = self._count_step_attn_blocks(active, prev)
+        if self._counts_decode:
+            self._step_counted = self.config.decode_counters(self._positions_held(active, prev))
         # the fused programs donate the sampling lanes and hand them back
         # as passthrough outputs (zero-copy aliases); rebind the handles
         if self.kv_layout == "paged":

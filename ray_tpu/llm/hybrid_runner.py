@@ -116,9 +116,8 @@ def decode_step(params, cache, state, tokens, active, cfg):
     horizon = cache[next(iter(per_position))].shape[2]  # every entry is [layers, slots, positions, ...]
     pos = jnp.minimum(lengths, horizon - 1)
     lanes = jnp.arange(B, dtype=jnp.int32)
-    dt, sd = params["embed"].dtype, cfg.stream_dtype
     with scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
+        x = hybrid.embed_tokens(params, tokens, cfg)
 
     def layer(kind, w, i, x, carry):
         arrays, stats = carry
@@ -126,13 +125,12 @@ def decode_step(params, cache, state, tokens, active, cfg):
         y, s = cfg.mixers[kind].step(w, cfg.norm(x, w["norm"]), view, hybrid.StepCtx(lengths, active, (params[kind], i)))
         if s is not None:
             stats = jnp.stack([stats[0] + s[0], stats[1] + s[1], jnp.maximum(stats[2], s[2]), stats[3] + s[3]])
-        return x + y.astype(sd), (view.arrays, stats)
+        return hybrid.add_branch(x, y, cfg), (view.arrays, stats)
 
     arrays = {**{name: cache[name] for name in per_position}, **state}
     x, (arrays, stats) = hybrid.run_layers(cfg, params, x, (arrays, jnp.zeros((4,), jnp.float32)), layer)
     with scope("head"):
-        x = cfg.norm(x, params["final_norm"]).astype(dt)
-        logits = jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
+        logits = jnp.dot(hybrid.before_head(x, params, cfg), params["unembed"], preferred_element_type=jnp.float32)
     n = max(cfg.routing_layers, 1)
     total = cfg.expert_layer.top_k * jnp.sum(active.astype(jnp.float32)) if cfg.routing_layers else jnp.zeros((), jnp.float32)
     moe = jnp.stack([stats[0] / n, stats[1] / n, total, stats[2], stats[3] / n])

@@ -73,7 +73,9 @@ logger = logging.getLogger("ray_tpu.llm")
 # what a hybrid description may count of a prefill program from its shape alone
 # (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
 # takes on that step's row
-PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks")
+PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs")
+# and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
+DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live")
 
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
@@ -367,6 +369,11 @@ class FlightRecorder:
         # of the cache the step moves. Absent where the XLA form runs (it reads all of it) and in a
         # step that dispatched nothing
         "attn_blocks_read", "attn_blocks_total",
+        # what the description counts of the decode program this step dispatched from the positions its
+        # lanes hold (``HybridDescription.decode_counters``): blocks of 64 positions, a key-value head's
+        # share each, that the sparse layers read for the blocks their queries chose, and would read
+        # attending to everything; absent for a description that counts none
+        *DECODE_COUNTERS,
         "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
         # the step's start and the instant its fused program was enqueued
@@ -389,7 +396,8 @@ class FlightRecorder:
         "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed",
         # and what the description counts of those prefill programs from their shapes alone
         # (``HybridDescription.prefill_counters``): chunks of the delta rule that the programs ran, padding's
-        # among them, over the layers of Kimi Delta Attention; absent for a description that counts none
+        # among them, over the layers of Kimi Delta Attention; (query, block) pairs that the sparse layers
+        # read at the prompts' true lengths; absent for a description that counts none
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)
@@ -939,7 +947,7 @@ class EngineTelemetry:
 
         paged = eng.kv_layout == "paged"
         sd = spec_drained or (None, None)
-        moe = eng._moe_stats  # host array of the drained step (hybrid models), else None
+        moe = eng._moe_stats if getattr(eng.config, "routing_layers", 0) else None  # host array of the drained step (a hybrid that routes), else None
         moe = _NO_MOE if moe is None else tuple(round(float(v), 3) for v in moe)
         pf, eng._prefill_stats = eng._prefill_stats, None
         if pf is None:
@@ -951,6 +959,7 @@ class EngineTelemetry:
         self.recorder.record_step((
             now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, sampling_lanes, waiting,
             occupied, capacity, *(eng._step_attn_blocks or (None, None)),
+            *((eng._step_counted or {}).get(name) for name in DECODE_COUNTERS),
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],

@@ -126,11 +126,25 @@ class HybridDescription:
         the tile of a layer that keeps ``k`` and ``v`` by head."""
         return dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.hd)
 
-    def prefill_counters(self, batch: int, length: int) -> dict:
-        """What ONE prefill program of ``batch`` x ``length`` positions (as padded) runs that can be
-        counted from its shape alone, by a name of ``llm/telemetry.PREFILL_COUNTERS``: summed over
-        an admitting step's programs onto that step's row of the flight log. None by default."""
+    def prefill_counters(self, batch: int, length: int, lengths=()) -> dict:
+        """What ONE prefill program of ``batch`` x ``length`` positions (as padded) over prompts of
+        the true ``lengths`` runs that can be counted on the host from those alone, by a name of
+        ``llm/telemetry.PREFILL_COUNTERS``: summed over an admitting step's programs onto that step's
+        row of the flight log. None by default."""
         return {}
+
+    def decode_counters(self, positions) -> dict:
+        """What ONE decode step reads for lanes that hold ``positions`` (a list, the new token's
+        among them) that can be counted on the host from those alone, by a name of
+        ``llm/telemetry.DECODE_COUNTERS``: onto that step's row of the flight log. None by default."""
+        return {}
+
+    @property
+    def stream_scales(self) -> tuple:
+        """Constants (on the embedding, on every residual branch ``x + a * y``, on the normed stream
+        before the head) of a model that scales its stream by its width and depth; 1.0 scales nothing
+        and leaves the program as it was."""
+        return 1.0, 1.0, 1.0
 
     @property
     def routing_layers(self) -> int:
@@ -296,6 +310,26 @@ def attend_slot(q, cache: LayerCache, ctx: StepCtx, num_kv_heads: int):
     return slot_attention.attend(q, k, v, i, ctx.lengths, num_kv_heads, live=ctx.active)
 
 
+# ------------------------------------------------------------- the stream's ends and its branches
+def embed_tokens(params, tokens, c):
+    """The stream as it starts: the tokens' embedding rows, times the description's constant if it has one."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    scale = c.stream_scales[0]
+    return x.astype(c.stream_dtype) if scale == 1.0 else (x.astype(jnp.float32) * scale).astype(c.stream_dtype)
+
+
+def add_branch(x, y, c):
+    """``x + a * y``: a sub-block's output onto the stream (``a`` = 1 for every model that does not scale its branches)."""
+    a = c.stream_scales[1]
+    return x + y.astype(x.dtype) if a == 1.0 else x + (y.astype(jnp.float32) * a).astype(x.dtype)
+
+
+def before_head(x, params, c):
+    """The final norm, in the weights' dtype, times the description's constant if it has one: what the head multiplies."""
+    x, scale = c.norm(x, params["final_norm"]), c.stream_scales[2]
+    return (x if scale == 1.0 else x.astype(jnp.float32) * scale).astype(params["embed"].dtype)
+
+
 # ------------------------------------------------------------- sequence forward
 def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = False):
     """tokens [B,T] right-padded, lengths [B] -> the final-norm'd stream [B,T,H] and, with
@@ -305,9 +339,8 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
     ``ROUTING`` [routing layers, 3]."""
     c = config
     B, T = tokens.shape
-    dt, sd = params["embed"].dtype, c.stream_dtype
     with scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(sd)
+        x = embed_tokens(params, tokens, c)
     empty = {}
     if collect:
         for spec in c.cache_spec().values():
@@ -319,11 +352,11 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
     def layer(kind, w, i, x):
         ctx = SeqCtx(lengths, mesh, (params[kind], i) if collect else None)
         y, kept = c.mixers[kind].seq(w, c.norm(x, w["norm"]), ctx)
-        return x + y.astype(sd), kept if collect else {}
+        return add_branch(x, y, c), kept if collect else {}
 
     x, out = scan_layers(c, params, x, layer, empty)
     with scope("head"):
-        return c.norm(x, params["final_norm"]).astype(dt), out
+        return before_head(x, params, c), out
 
 
 def forward(params, tokens, config, mesh=None):

@@ -210,7 +210,7 @@ class KimiLinearConfig(LatentAttention, HybridDescription):
         """What ``ops/slot_attention.refusal`` is asked about this description's decode attention."""
         return self.latent_tile
 
-    def prefill_counters(self, batch: int, length: int) -> dict:
+    def prefill_counters(self, batch: int, length: int, lengths=()) -> dict:
         """What one prefill program of ``batch`` x ``length`` positions (as padded) runs that the
         flight log counts from its shape alone: the chunks of the delta rule, over the KDA layers,
         and how many of them the kernel ran (all, or none where ``ops/delta_rule.refusal`` speaks)."""

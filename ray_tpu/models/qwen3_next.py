@@ -552,16 +552,20 @@ def gdn_step(w, xn, S, conv, c: Qwen3NextConfig):
 
 
 # --------------------------------------------------------- attn: gated attention
-def gated_attn_qkv(w, xn, positions, c: Qwen3NextConfig):
+def gated_attn_qkv(w, xn, positions, c):
     """xn [B,T,H], positions [B,T] or [T] -> q [B,T,nh,hd], its output gate [B,T,nh*hd],
-    k, v [B,T,kv,hd]: q and k normalised per head with ``N`` and rotated (rotate-half) in their
-    first ``rot_dim`` dimensions, the rest passing."""
+    k, v [B,T,kv,hd]: q and k normalised per head with the model's ``N`` (``c.norm``) and rotated
+    (rotate-half) in their first ``rot_dim`` dimensions, the rest passing (0: nothing is rotated).
+    ``c``: whatever has ``num_heads``, ``num_kv_heads``, ``hd``, ``rot_dim``, ``rope_theta`` and
+    ``norm``: this file's config, or one mixer's view of another model's (``models/minicpm_sala.py``)."""
     B, T, _ = xn.shape
     qg = jnp.dot(xn, w["wq"]).reshape(B, T, c.num_heads, 2 * c.hd)
     q, gate = qg[..., :c.hd], qg[..., c.hd:].reshape(B, T, c.num_heads * c.hd)
     k = jnp.dot(xn, w["wk"]).reshape(B, T, c.num_kv_heads, c.hd)
     v = jnp.dot(xn, w["wv"]).reshape(B, T, c.num_kv_heads, c.hd)
-    q, k = rms_norm_1p(q, w["q_norm"], c.rms_eps), rms_norm_1p(k, w["k_norm"], c.rms_eps)
+    q, k = c.norm(q, w["q_norm"]), c.norm(k, w["k_norm"])
+    if not c.rot_dim:
+        return q, gate, k, v
     cos, sin = rotary_embedding(positions, c.rot_dim, c.rope_theta)
 
     def rotate(x):  # [B,T,heads,hd]

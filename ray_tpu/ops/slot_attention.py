@@ -35,7 +35,13 @@ index tables in SMEM and the same fold; a block of ``c_kv`` is streamed once and
 are ``q_lat . c_kv + q_rope . k_r`` (two MXU products), and the query heads are padded to whole
 bfloat16 tiles of 16 rows. Its XLA form is ``attend_rows`` with keys wider than values.
 
-Which form runs is decided by what the code can see (``refusal``): the backend, the cache's dtype,
+A SPARSE layer (``attend_blocks``; ``models/minicpm_sala.py``) reads, for each lane and key-value
+head, the blocks of 64 positions that a TABLE computed in the same step names, not ``0 .. last``:
+its kernel (``sparse_decode_attention`` in a trace) is a grid over (lane, place of the table) whose
+index maps read the table from SMEM, one fetch a key-value head and place, and the same fold; its
+XLA form gathers the blocks and masks.
+
+Which form runs is decided by what the code can see (``refusal``, ``refusal_blocks``): the backend, the cache's dtype,
 whether the caller is a ``shard_map`` body, and the tile's shape. There is no knob.
 """
 
@@ -70,6 +76,29 @@ def attend_rows(q, k_rows, v_rows, lengths, num_kv_heads: int, scale: float | No
     ok = (jnp.arange(S, dtype=jnp.int32)[None, :] <= lengths[:, None])[:, None, None]
     probs = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
     return jnp.einsum("bgrs,bsgh->bgrh", probs, v_rows.astype(jnp.float32)).reshape(B, nh * v_rows.shape[-1])
+
+
+def attend_blocks_rows(q, k_stack, v_stack, layer, lengths, blocks, ok, block: int):
+    """One token a lane (its query q [B,nh,hd]) against the BLOCKS of ``block`` positions that a
+    table names: ``blocks`` [B,G,N] int32, for each lane and key-value head the indices of the
+    blocks its group of query heads reads in layer ``layer`` of the stacked slot cache k/v_stack
+    [L,B,S,G,hd] (``ok`` [B,G,N] bool: false where the entry names none), the new token's key and
+    value already at index lengths[b]: grouped-query softmax attention over the positions at or
+    before lengths[b] of those blocks. The XLA form of ``attend_blocks`` (gather the blocks, mask):
+    the oracle, and what runs off the TPU. The chip's compiler copies each stack whole to see it
+    in blocks (4 x 0.2 GB a step at 2 layers x 16 x 12,288, 2.4 of a 13.9 ms step: my chip run,
+    PR 45), which is what the kernel is for. -> [B, nh*hd] float32."""
+    L, B, S, G, hd = k_stack.shape
+    nh, N = q.shape[1], blocks.shape[-1]
+    lanes, heads = jnp.arange(B)[:, None, None], jnp.arange(G)[None, :, None]
+    # the blocks where they lie in the stack: [B,G,N,block,hd], nothing of the layer's other rows
+    kb, vb = (a.reshape(L, B, S // block, block, G, hd)[layer, lanes, blocks, :, heads] for a in (k_stack, v_stack))
+    scores = jnp.einsum("bgrh,bgnph->bgrnp", q.reshape(B, G, nh // G, hd), kb, preferred_element_type=jnp.float32) / math.sqrt(hd)
+    at = blocks[..., None] * block + jnp.arange(block, dtype=jnp.int32)  # [B,G,N,block]
+    allowed = (ok[..., None] & (at <= lengths[:, None, None, None]))[:, :, None]
+    probs = jax.nn.softmax(jnp.where(allowed, scores, -jnp.inf).reshape(B, G, nh // G, N * block), axis=-1)
+    probs = jnp.where(jnp.any(allowed, axis=(-2, -1)).reshape(B, G, 1, 1), probs, 0.0)  # a table that names nothing (an unbound lane): zeros, as the kernels give
+    return jnp.einsum("bgrs,bgsh->bgrh", probs, vb.astype(jnp.float32).reshape(B, G, N * block, hd)).reshape(B, nh * hd)
 
 
 def layer_of(stacked, i):
@@ -284,6 +313,90 @@ def attend_latent_kernel(q_lat, q_rope, c_stack, r_stack, layer, bound, scale: f
     return out[:, :nh].reshape(B, nh * r)
 
 
+def _blocks_kernel(layer_ref, count_ref, pos_ref, table_ref, q_ref, *refs, block: int, kv: int, rep: int, places: int, scale: float):
+    """Grid step (lane b, place j of its table): fold, for EVERY key-value head g, the block that
+    the table names for (b, g, j) into the lane's running max, sum and weighted values. ``refs``:
+    kv blocks of keys (one a key-value head, each fetched by its own head's table), kv blocks of
+    values, the output and the two scratch arrays; a block is ``block * kv`` rows of the stack seen
+    as [S * kv, hd] (every head's rows of its positions), of which head g's group reads its own."""
+    del layer_ref
+    k_refs, v_refs, (o_ref, m_scr, l_scr) = refs[:kv], refs[kv:2 * kv], refs[2 * kv:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    _start(j, m_scr, l_scr, o_ref)
+
+    @pl.when(j < count_ref[b])  # a place that names nothing: not fetched (the index map repeats the place before it), not computed
+    def _fold():
+        q = q_ref[...]
+        nh, cols = q.shape[0], block * kv
+        nt = (((1,), (1,)), ((), ()))
+        s = jnp.concatenate([jax.lax.dot_general(q, k_ref[...], nt, preferred_element_type=jnp.float32) for k_ref in k_refs], axis=1) * scale
+        row = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 1)
+        # of head g's block, column c is kv head c % kv of position block * table + c // kv; query head h reads kv head h // rep
+        ok = jnp.concatenate([(row // rep == g) & (col % kv == g) & (table_ref[(b * kv + g) * places + j] * block + col // kv <= pos_ref[b])
+                              for g in range(kv)], axis=1)
+        _fold_block(s, ok, jnp.concatenate([v_ref[...] for v_ref in v_refs], axis=0), m_scr, l_scr, o_ref)
+
+    _finish(j, l_scr, o_ref)
+
+
+def attend_blocks_kernel(q, k_stack, v_stack, layer, pos, blocks, count, block: int, *, interpret: bool = False):
+    """The kernel form of ``attend_blocks``. q [B,nh,hd]; k/v_stack [L,B,S,kv,hd]; blocks [B,kv,N]
+    int32 with each lane's first ``count[b]`` places naming blocks (0: the lane reads nothing and
+    gets zeros); pos [B]: the last position a lane may read. A grid over (lane, place) whose index
+    maps read the table from SMEM: a place streams, for each key-value head, the block its group
+    chose from where it lies in the stack. It shares the live-block form's fold (``_fold_block``).
+    -> [B, nh*hd] float32."""
+    B, nh, hd = q.shape
+    L, _, S, kv, _ = k_stack.shape
+    N = blocks.shape[-1]
+    count = jnp.clip(count.astype(jnp.int32), 0, N)
+    # past a lane's count every place repeats its last live one: the pipeline does not fetch the same block again
+    place = jnp.minimum(jnp.arange(N, dtype=jnp.int32)[None, None, :], jnp.maximum(count, 1)[:, None, None] - 1)
+    table = jnp.take_along_axis(blocks.astype(jnp.int32), place, axis=-1).reshape(B * kv * N)
+
+    def rows(g):
+        return lambda b, j, layer_ref, count_ref, pos_ref, table_ref: (layer_ref[0], b, table_ref[(b * kv + g) * N + j], 0)
+
+    per_lane = lambda b, j, *_: (b, 0, 0)  # noqa: E731
+    stacks = [a.reshape(L, B, S * kv, hd) for a in (k_stack, v_stack)]
+    kernel = functools.partial(_blocks_kernel, block=block, kv=kv, rep=nh // kv, places=N, scale=1.0 / math.sqrt(hd))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, N),
+            in_specs=[pl.BlockSpec((None, nh, hd), per_lane)]
+            + [pl.BlockSpec((None, None, block * kv, hd), rows(g)) for _ in stacks for g in range(kv)],
+            out_specs=pl.BlockSpec((None, nh, hd), per_lane),
+            scratch_shapes=[pltpu.VMEM((nh, 128), jnp.float32), pltpu.VMEM((nh, 128), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, nh, hd), jnp.float32),
+        interpret=interpret,
+        name="sparse_decode_attention",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=48 << 20)}),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), count, pos.astype(jnp.int32), table, q, *[a for a in stacks for _ in range(kv)])
+    return out.reshape(B, nh * hd)
+
+
+def refusal_blocks(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, block: int) -> str | None:
+    """Why ``attend_blocks`` does NOT run as the kernel (its XLA form then does), or None: the
+    answer of ``refusal`` to the same questions, for a table of blocks of ``block`` positions."""
+    if jax.default_backend() != "tpu":
+        return f"backend {jax.default_backend()!r}: the kernel is compiled for the TPU only"
+    dt = jnp.dtype(cache_dtype)
+    if dt != jnp.bfloat16:
+        return f"a {dt.name} cache: the kernel has been compiled for bfloat16 rows only"
+    if head_dim != 128 or num_kv_heads & (num_kv_heads - 1) or num_kv_heads > 4:
+        return f"{num_kv_heads} kv heads x head_dim {head_dim}: compiled at 2 x 128 (a power of two of heads, at most 4, 128 lanes)"
+    if num_heads % 16 or num_heads > 32:
+        return f"{num_heads} query heads: compiled at 32 (whole bfloat16 tiles of 16 rows)"
+    if (block * num_kv_heads) % 16:
+        return f"blocks of {block} positions x {num_kv_heads} kv heads: not whole bfloat16 tiles of 16 rows"
+    return None
+
+
 # --------------------------------------------------------------------------- the op
 def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None, k_scale=None, v_scale=None,
            sharded: bool = False):
@@ -305,6 +418,24 @@ def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None,
         k_rows = k_rows.astype(jnp.float32) * layer_of(k_scale, layer).transpose(0, 2, 1)[..., None]
         v_rows = v_rows.astype(jnp.float32) * layer_of(v_scale, layer).transpose(0, 2, 1)[..., None]
     return attend_rows(q, k_rows, v_rows, lengths, num_kv_heads)
+
+
+def attend_blocks(q, k_stack, v_stack, layer, lengths, blocks, ok, block: int, *, live=None):
+    """One token a lane (its query q [B,nh,hd]) against the BLOCKS of ``block`` positions that a
+    table names: ``blocks`` [B,G,N] int32, for each lane and key-value head the blocks its group of
+    query heads reads in layer ``layer`` of the stacked slot cache k/v_stack [L,B,S,G,hd], ``ok``
+    [B,G,N] bool true for the places that name one (a lane's first places, as many in every group:
+    ``ops/sparse_attention.choose_blocks`` hands them out so), the new token's key and value already
+    at index lengths[b]: grouped-query softmax attention over the positions at or before lengths[b]
+    of those blocks. ``live`` [B] bool, where the caller knows it: the lanes whose output is read
+    (the kernel reads nothing for the others; the XLA form computes what nobody reads).
+    -> [B, nh*hd] float32."""
+    if refusal_blocks(k_stack.dtype, q.shape[1], k_stack.shape[3], q.shape[2], block) is None:
+        count = jnp.sum(ok[:, 0], axis=-1)
+        # off the TPU only a test gets here (it swaps ``refusal_blocks``), and runs the same body interpreted
+        return attend_blocks_kernel(q, k_stack, v_stack, layer, lengths, blocks, count if live is None else jnp.where(live, count, 0),
+                                    block, interpret=jax.default_backend() != "tpu")
+    return attend_blocks_rows(q, k_stack, v_stack, layer, lengths, blocks, ok, block)
 
 
 def attend_latent(q_lat, q_rope, c_stack, r_stack, layer, lengths, *, scale: float, live=None, sharded: bool = False):

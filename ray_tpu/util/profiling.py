@@ -53,7 +53,9 @@ SCOPES = {
     "gdn": "mixer", "gdn.chunk": "mixer", "gdn.scan": "mixer",
     "kda": "mixer", "kda.chunk": "mixer", "kda.scan": "mixer",
     "mla": "mixer", "mla.down": "mixer", "mla.expand": "mixer", "mla.absorb": "mixer", "mla.attn": "mixer",
-    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state",
+    "sparse": "mixer", "sparse.select": "mixer", "sparse.attend": "mixer",
+    "lightning": "mixer", "lightning.chunk": "mixer",
+    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state", "lightning.state": "state",
     "mlp": "ffn", "ffn": "ffn",
     "moe": "ffn", "moe.route": "ffn", "moe.place": "ffn", "moe.blocks": "ffn", "moe.shared": "ffn",
 }

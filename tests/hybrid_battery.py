@@ -111,7 +111,7 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(desc, para
     more requests than slots (so slots are recycled), greedy and seeded, an abort in the middle, and
     before the second round every slot's old state and rows poisoned: all of it against the
     reference's full forward."""
-    s, mark = desc.cfg.expert_layer, eng.telemetry()["step_count"]
+    s, mark = desc.cfg.expert_layer if desc.cfg.routing_layers else None, eng.telemetry()["step_count"]
     lengths = (5, 19, 23, 40, 7, 33, 18, 61, 9)
     ps = prompts(desc, 1, lengths)
     sampling = [SamplingParams(max_tokens=10, temperature=0.0 if i % 3 else 0.8, top_p=0.95, seed=i, logprobs=True)
@@ -140,19 +140,22 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(desc, para
     assert res["ok"] and res["tokens"] == 40, res
     # the flight log: decode rows carry the routing counters of the drained step, admitting rows the prefills' five
     new = steps_after(eng, mark)
+    admitting = [r for r in new if r.get("admitted")]
+    assert admitting and all("prefill_tokens" in r for r in admitting)
+    assert all(0 < r["prefill_tokens"] <= r["prefill_tokens_padded"] and r["prefill_tokens_padded"] % 16 == 0 for r in admitting)
+    assert sum(r["prefill_tokens"] for r in admitting) == sum(lengths) + sum(len(p) for p in ps2)
+    assert not any("prefill_tokens" in r for r in new if not r.get("admitted"))
+    assert not eng.state or any(r.get("state_insert_ms", 0) > 0 for r in new)
+    if s is None:  # a description that routes nothing: no decode row carries a routing counter, no prefill served a pair
+        assert not any("experts_hit" in r for r in new) and all(r["prefill_moe_pairs_local"] == 0 for r in admitting)
+        return
     rows = [r for r in new if "experts_hit" in r]
     assert rows and all(0 < r["experts_hit"] <= s.held and r["moe_pairs_local"] <= r["moe_pairs_total"] for r in rows)
     assert all(r["moe_pairs_total"] % s.top_k == 0 and r["moe_max_load"] >= 1 for r in rows)
     assert s.held < s.num_experts or all(r["moe_pairs_local"] == r["moe_pairs_total"] for r in rows), "every expert is held here"
-    assert not eng.state or any(r.get("state_insert_ms", 0) > 0 for r in new)
-    admitting = [r for r in new if r.get("admitted")]
-    assert admitting and all("prefill_tokens" in r for r in admitting)
     for r in admitting:
-        assert 0 < r["prefill_tokens"] <= r["prefill_tokens_padded"] and r["prefill_tokens_padded"] % 16 == 0
         assert 0 < r["prefill_moe_pairs_local"] <= r["moe_rows_computed"] and r["prefill_moe_pairs_local"] <= s.top_k * r["prefill_tokens"]
         assert 0 < r["prefill_experts_hit"] <= s.held
-    assert sum(r["prefill_tokens"] for r in admitting) == sum(lengths) + sum(len(p) for p in ps2)
-    assert not any("prefill_tokens" in r for r in new if not r.get("admitted"))
 
 
 def test_the_synchronous_loop_is_the_fused_steps_oracle(desc, params, eng):
@@ -172,6 +175,9 @@ def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(desc, eng):
     ps = prompts(desc, 8, (12, 30, 7, 21, 44, 9))
     eng.generate(ps, [SamplingParams(max_tokens=6 + 3 * i, temperature=0.0) for i in range(len(ps))])
     rows = [r for r in steps_after(eng, mark) if "experts_hit" in r]
+    if not desc.cfg.routing_layers:  # nothing is routed: the rows say nothing of experts
+        assert not rows and len(steps_after(eng, mark)) >= 10
+        return
     assert len(rows) >= 10 and all(r["experts_read"] == r["experts_hit"] for r in rows)
     assert len({r["experts_read"] for r in rows}) > 1 and all(0 < r["experts_read"] <= desc.cfg.expert_layer.held for r in rows)
 

@@ -309,7 +309,8 @@ def test_fsdp4_loss_and_grad_with_flash_kernel_compile_for_v5e(topo):
 CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widths, 16 layers, 64 of 128 experts, 32 slots x 4096
          "qwen3_next": ("qwen3-next-80b-a3b-ep4.json", {"remat": False}),  # 12 of 48 layers, 128 of 512 experts, 16 slots x 4096
          "glm": ("glm-4.7-flash-d8.json", {"remat": False}),  # 8 of 47 layers whole, 16 slots x 16,384
-         "kimi": ("kimi-linear-48b-a3b-ep4.json", {"remat": False})}  # 9 of 27 layers, 64 of 256 experts, 16 slots x 4096
+         "kimi": ("kimi-linear-48b-a3b-ep4.json", {"remat": False}),  # 9 of 27 layers, 64 of 256 experts, 16 slots x 4096
+         "sala": ("minicpm-sala-9b-d8.json", {"remat": False})}  # layers 9-16 of 32, the whole vocabulary, 16 slots x 12,288
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -711,3 +712,50 @@ def test_fused_step_walks_the_experts_hit_and_copies_no_layers_experts(fused_ste
     assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)) if a.ndim > 1)
     assert mem.temp_size_in_bytes < 0.1 * 2**30
     assert not re.search(rf"bf16\\[(1,)?{held},{F},{H}\\]", txt), "a layer's experts, sliced out of the stack"
+
+
+# ---------------------------------------------------------------------------
+# PR 45: a fifth description, MiniCPM-SALA (models/minicpm_sala.py): the cell minicpm-sala-d8.longdoc-12k.
+# ---------------------------------------------------------------------------
+def test_sala_fused_step_fits_one_v5e_aliases_its_three_caches_and_slices_no_layers_rows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 16 x 12,288 through the SAME ``hybrid_runner.fused_step`` and layer loop as
+    the four other descriptions (head ``sparse``, then ``ffn lightning`` x 6 scanned, tail ``ffn sparse
+    ffn``): 5.25 GiB of weights, 0.375 GiB of keys and values, 0.19 GiB of Lightning state and 12 MiB
+    of compressed keys; all aliased to the donated inputs; the live-block kernel in the step for the
+    lanes that attend densely, the table's kernel for the others (each place's block streamed from
+    where it lies), and neither a slice of a layer's rows (96 MiB of keys at 16 x 12,288) nor a copy
+    of a stack in blocks (the XLA form's 0.2 GiB, four times a step) in the compiled text."""
+    import re
+
+    cfg, _, cache, state, compiled = fused_step_for_the_chip("sala")
+    assert cfg.layer_plan == (("ffn", "lightning"), 6, ("ffn", "sparse", "ffn"), ("sparse",))
+    assert set(state) == {"S", "kc"} and set(cache) == {"k", "v", "length"}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    caches = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves((cache, state)))
+    assert _kv_bytes(cache) == 16 * 12288 * 2048 and caches - _kv_bytes(cache) - 64 == 16 * 13_369_344
+    print("sala fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+    assert 5.8 * 2**30 < mem.argument_size_in_bytes < 5.9 * 2**30 and mem.alias_size_in_bytes >= caches
+    assert "slot_decode_attention" in txt and "sparse_decode_attention" in txt and not re.search(r"bf16\[(1,16,12288|2,16,192,64),2,128\]", txt)
+    assert mem.temp_size_in_bytes < 160 * 2**20  # 143 MiB: a Lightning layer's projections copied out of the stack inside the scan (PERF.md section 7)
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 3.0), (2, 4.0)])
+def test_sala_prefill_of_the_12288_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 12,288-bucket prefill (the selection and the masked attention a tile of 128 queries at a
+    time under ``sparse.select`` and ``sparse.attend``, the Lightning rule in chunks of 128 under
+    ``lightning.chunk``, both a sequence at a time; a 16,384-wide SwiGLU over every position) for one
+    prompt and for the largest group the cell warms, 2 x 12,288, beside 5.25 GiB of weights and 0.58
+    GiB of caches: under 15.75 GiB."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "sala")
+    tokens = jax.ShapeDtypeStruct((prompts, 12288), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("sala prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    assert all(name in txt for name in ("sparse.select", "sparse.attend", "lightning.chunk"))
+    kernel = [line for line in txt.splitlines() if "custom-call(" in line and "sparse_prefill_attention" in line]
+    assert kernel and all("tpu_custom_call" in line and "sparse.attend" in line for line in kernel), "step 5 as one kernel, under its scope"
+    assert mem.temp_size_in_bytes < most_gib * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.58 * 2**30 < 15.0 * 2**30

@@ -1,4 +1,4 @@
-"""The scope vocabulary is whole (PR 39): for a toy configuration of each of the five
+"""The scope vocabulary is whole (PR 39): for a toy configuration of each of the six
 descriptions, every step program an engine builds and runs, lowered on the CPU, has every
 ``dot_general``, convolution, custom call, scatter, gather and ``dynamic_update_slice`` under a
 scope of ``util/profiling.SCOPES``; and a scope outside the table raises where it is traced."""
@@ -28,7 +28,8 @@ PAGED = ["llm_kv_insert_pages", "llm_fused_paged_step", "llm_kv_append", "llm_pa
 HYBRID = ["llm_hybrid_prefill", "llm_kv_insert", "llm_state_insert", "llm_hybrid_fused_step", "llm_hybrid_decode_step"]
 PROGRAMS = {"llama": SLOTS, "llama_paged": PAGED, "nemotron_h": HYBRID, "qwen3_next": HYBRID,
             "glm4_moe_lite": [p for p in HYBRID if p != "llm_state_insert"],  # latent attention keeps nothing per sequence
-            "kimi_linear": HYBRID}  # a state a sequence AND a latent a position
+            "kimi_linear": HYBRID,  # a state a sequence AND a latent a position
+            "minicpm_sala": HYBRID}  # keys and values a position, a state and the compressed keys a sequence
 
 
 class Recording:
@@ -61,6 +62,10 @@ def _config(description):
         from ray_tpu.models.kimi_linear import KimiLinearConfig
 
         return KimiLinearConfig.tiny(num_hidden_layers=5, full_attn_layers=(2, 4), num_local_experts=4)
+    if description == "minicpm_sala":
+        from ray_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+        return MiniCPMSALAConfig.tiny()  # dense below 32: the prompt of 40 chooses its blocks, the one of 9 does not
     from ray_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
 
     return Glm4MoeLiteConfig.tiny()
