@@ -73,7 +73,7 @@ logger = logging.getLogger("ray_tpu.llm")
 # what a hybrid description may count of a prefill program from its shape alone
 # (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
 # takes on that step's row
-PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs")
+PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
 DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live")
 
@@ -396,15 +396,16 @@ class FlightRecorder:
         "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed",
         # and what the description counts of those prefill programs from their shapes alone
         # (``HybridDescription.prefill_counters``): chunks of the delta rule that the programs ran, padding's
-        # among them, over the layers of Kimi Delta Attention; (query, block) pairs that the sparse layers
-        # read at the prompts' true lengths; absent for a description that counts none
+        # among them, over the layers of Kimi Delta Attention (``kda_``) or of Gated DeltaNet (``gdn_``), and how
+        # many of them the kernel ran; (query, block) pairs that the sparse layers read at the prompts' true
+        # lengths; absent for a description that counts none
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)
     ) + tuple(STAGES.values()) + ("gc_ms",)
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
-    # steps/s, 2,000 requests (about 8 MB of step rows and 11 MB of
+    # steps/s, 2,000 requests (about 8.5 MB of step rows and 11 MB of
     # request records at 150 tokens each, tests/test_llm_flight.py).
     # Past it the oldest go, and the log's header says how many.
     LOG_STEPS = 12_000

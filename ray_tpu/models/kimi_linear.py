@@ -211,14 +211,8 @@ class KimiLinearConfig(LatentAttention, HybridDescription):
         return self.latent_tile
 
     def prefill_counters(self, batch: int, length: int, lengths=()) -> dict:
-        """What one prefill program of ``batch`` x ``length`` positions (as padded) runs that the
-        flight log counts from its shape alone: the chunks of the delta rule, over the KDA layers,
-        and how many of them the kernel ran (all, or none where ``ops/delta_rule.refusal`` speaks)."""
-        chunk = min(self.chunk_size, length)
-        chunks = self.count("kda") * batch * -(-length // chunk)
-        operand = None if self.dtype == "float32" else self.dtype
-        refused = delta_rule.refusal(operand, self.kda_head_dim, self.kda_head_dim, chunk)
-        return {"kda_chunks": chunks, "kda_kernel_chunks": 0 if refused else chunks}
+        """``kda_chunks`` and ``kda_kernel_chunks`` of one prefill program (``ops/delta_rule.counters``), over the KDA layers."""
+        return delta_rule.counters("kda", self.count("kda"), batch, length, self.chunk_size, self.dtype, self.kda_head_dim, self.kda_head_dim)
 
     def num_params(self) -> int:
         """Parameters held here (the chip's share of experts and vocabulary)."""

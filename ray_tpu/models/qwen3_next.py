@@ -18,7 +18,8 @@ loop walks ``2 x num_hidden_layers`` sub-blocks of three kinds:
   ``S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T``; ``o_t = S_t^T q_t``. Then
   ``w * (o / sqrt(mean(o²) + eps)) * SiLU(z)`` per head (a plain weight) and the
   output projection. Kept per sequence: ``S`` and the convolution's last three
-  inputs. Prefill runs the rule in chunks (``delta_rule_chunked``); decode one
+  inputs. Prefill runs the rule in chunks (``delta_rule_chunked``: on a TPU one
+  kernel, ``ops/delta_rule.py``, elsewhere its lines in XLA); decode one
   position at a time, elementwise in float32.
 - ``attn`` (scope ``gated_attn``): per head a query and an output gate; q and k
   normalised per head with ``N``; rotate-half RoPE on the FIRST
@@ -209,6 +210,10 @@ class Qwen3NextConfig(HybridDescription):
                     "conv": ((self.conv_kernel - 1, self.conv_dim), self.dtype, "sequence")},
             "moe": {},
         }
+
+    def prefill_counters(self, batch: int, length: int, lengths=()) -> dict:
+        """``gdn_chunks`` and ``gdn_kernel_chunks`` of one prefill program (``ops/delta_rule.counters``), over the DeltaNet layers."""
+        return delta_rule.counters("gdn", self.count("gdn"), batch, length, self.chunk_size, self.dtype, self.linear_key_head_dim, self.linear_value_head_dim)
 
     def num_params(self) -> int:
         """Parameters held here (the chip's share of experts and vocabulary)."""
@@ -414,13 +419,13 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int, operand_dtype=None, name: s
     One body for both gates: everything above holds with gamma a vector over the key channels
     (``gamma_t / gamma_s`` then stands INSIDE ``k_t.k_s`` and ``q_t.k_s``), and only those two
     C x C sets of pairs are built differently (``_pairs_by_channel``, in sub-blocks of ``SUB_BLOCK``
-    positions). A gate a head takes the lines it always took. A gate by channel runs as ONE kernel
-    that keeps a chunk and the state in fast memory (``ops/delta_rule.py``: the same lines at the
-    same precision, all of it under ``<name>.chunk``) unless its ``refusal`` gives a reason; then,
-    and for a gate a head, the lines below run."""
-    if g.ndim == beta.ndim + 1 and delta_rule.refusal(operand_dtype, q.shape[-1], v.shape[-1], min(chunk, q.shape[1]), mesh=mesh) is None:
+    positions). Either gate runs as ONE kernel that keeps a chunk and the state in fast memory
+    (``ops/delta_rule.py``: the same lines at the same precision, the form of the pairs picked by the
+    gate's rank, all of it under ``<name>.chunk``) unless its ``refusal`` gives a reason; then the
+    lines below run."""
+    if delta_rule.refusal(operand_dtype, q.shape[-1], v.shape[-1], min(chunk, q.shape[1]), mesh=mesh) is None:
         with scope(f"{name}.chunk"):  # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
-            return delta_rule.delta_rule_by_channel(q, k, v, g, beta, chunk, operand_dtype, interpret=jax.default_backend() != "tpu")
+            return delta_rule.delta_rule(q, k, v, g, beta, chunk, operand_dtype, interpret=jax.default_backend() != "tpu")
     hi = jax.lax.Precision.HIGHEST
     if operand_dtype is None or jnp.dtype(operand_dtype) == jnp.float32:
         def es(spec, a, b):

@@ -390,12 +390,17 @@ def test_qwen3_next_fused_step_fits_one_v5e_and_updates_both_caches_in_place(one
     assert mem.temp_size_in_bytes < one_layers_rows == 128 * 2**20
 
 
-@pytest.mark.parametrize("prompts, most_gib", [(1, 1.0), (8, 3.6)])
-def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, prompts, most_gib):
-    """The 4096-bucket prefill (the chunked delta rule a few sequences at a time, the flash kernel at
-    heads 256 wide, the grouped matmul over 128 small experts in slabs) for one prompt (0.65 GiB of
-    temporaries as compiled for PR 34) and for the largest group the cell warms, 8 x 4096 (3.23 GiB
-    and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of caches: under 15.75 GiB."""
+@pytest.mark.parametrize("prompts, most_gib", [(1, 0.65), (8, 3.0)])
+def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 4096-bucket prefill (the delta rule with one gate a head as ONE kernel under ``gdn.chunk``,
+    PR 46, a few sequences at a time; the flash kernel at heads 256 wide; the grouped matmul over 128
+    small experts in slabs) for one prompt (0.615 GiB of temporaries as compiled for PR 46; 0.65 for
+    PR 34, with the XLA lines) and for the largest group the cell warms, 8 x 4096 (2.73 GiB; 3.23
+    for PR 34; and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of caches: under
+    15.75 GiB. No ``[.., 64, 64]`` float32 square of a chunk's pairs is left among the program's
+    arrays, and no line of the XLA form's scan."""
+    import re
+
     from ray_tpu.llm import hybrid_runner as hr
 
     cfg, params, _, _ = _cell_at_its_size(one_chip, "qwen3_next")
@@ -403,7 +408,11 @@ def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on
     lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
     compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
     mem = compiled.memory_analysis()
+    print("qwen3-next prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
     assert "tpu_custom_call" in txt, "the flash kernel, 256 wide"
+    rule = [line for line in txt.splitlines() if "custom-call(" in line and "delta_rule_by_head" in line]
+    assert rule and all("tpu_custom_call" in line and "gdn.chunk" in line for line in rule), "the rule's kernel, under its scope"
+    assert "gdn.scan" not in txt and not re.search(r"f32\[[0-9,]*64,64\]", txt)
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (4.5 GiB), no layer's worth of them (0.375 GiB x 12)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.67 * 2**30 < 15.0 * 2**30
 
@@ -642,45 +651,53 @@ def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.39 * 2**30 < 15.0 * 2**30
 
 
-def test_delta_rule_kernel_compiles_for_v5e_within_its_vmem_and_copies_nothing(one_chip, as_on_a_tpu):
-    """PR 44: the kernel alone at the cell's tile (32 heads x 128, chunk 64) and its longest
-    bucket, two sequences as ``a_few_at_a_time`` hands them over: the gate lets the tile through,
-    Mosaic takes the kernel inside the VMEM a kernel may scope (it refuses one that asks for
-    more), and q, k, v, the gate and beta go in where a position's heads lie side by side:
+@pytest.mark.parametrize("rank", ["by_channel", "a_head"])
+def test_delta_rule_kernel_compiles_for_v5e_within_its_vmem_and_copies_nothing(one_chip, as_on_a_tpu, rank):
+    """PR 44, and PR 46 for one gate a head: the kernel alone at the cells' tile (32 heads x 128, chunk
+    64; for a gate a head 16 key heads under 32 value heads, as ``qwen3-next-ep4.longdoc`` has them)
+    and their longest bucket, two sequences as ``a_few_at_a_time`` hands them over: the gate lets the
+    tile through, Mosaic takes the kernel inside the VMEM a kernel may scope (it refuses one that
+    asks for more), and q, k, v, the gate and beta go in where a position's heads lie side by side
+    (a gate a head as beta does, ``[B, T, 32]``, not broadcast to a head's channels):
     nothing is transposed or copied on the way in or out."""
     from ray_tpu.ops import delta_rule as dr
 
-    B, T, G, K = 2, 4096, 32, 128
+    B, T, N, K = 2, 4096, 32, 128
+    G, R = (N, 1) if rank == "by_channel" else (N // 2, 2)
     assert dr.refusal(jnp.bfloat16, K, K, 64) is None
-    flat = jax.ShapeDtypeStruct((B, T, G * K), jnp.float32, sharding=one_chip)
+    flat = lambda heads: jax.ShapeDtypeStruct((B, T, heads * K), jnp.float32, sharding=one_chip)  # noqa: E731
+    a_head = jax.ShapeDtypeStruct((B, T, N), jnp.float32, sharding=one_chip)
 
     def rule(q, k, v, g, beta):
-        q, k, v, g = (a.reshape(B, T, G, K) for a in (q, k, v, g))
-        o, S = dr.delta_rule_by_channel(q, k, v[:, :, :, None], g[:, :, :, None], beta[..., None], 64, jnp.bfloat16)
-        return o.reshape(B, T, G * K), S
+        q, k = (a.reshape(B, T, G, K) for a in (q, k))
+        o, S = dr.delta_rule(q, k, v.reshape(B, T, G, R, K), g.reshape(B, T, G, R, *((K,) if rank == "by_channel" else ())), beta.reshape(B, T, G, R), 64, jnp.bfloat16)
+        return o.reshape(B, T, N * K), S
 
-    compiled, txt = _compile(rule, flat, flat, flat, flat, jax.ShapeDtypeStruct((B, T, G), jnp.float32, sharding=one_chip))
-    assert "tpu_custom_call" in txt and "delta_rule_by_channel" in txt
+    compiled, txt = _compile(rule, flat(G), flat(G), flat(N), flat(N) if rank == "by_channel" else a_head, a_head)
+    assert "tpu_custom_call" in txt and {"by_channel": "delta_rule_by_channel", "a_head": "delta_rule_by_head"}[rank] in txt
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_a_gate_a_head_lowers_to_the_text_it_had_whatever_the_backend(as_on_a_tpu):
-    """Qwen3-Next's rule (one gate a head) at its cell's prefill shape: the by-channel kernel's gate
-    is never asked, so what is lowered as on a TPU is what is lowered here, line for line."""
+def test_a_gate_a_head_lowers_to_the_kernel_as_on_a_tpu_and_to_the_text_it_had_off_it(as_on_a_tpu):
+    """Qwen3-Next's rule (one gate a head) at its cell's prefill shape (2 x 4096, 16 key heads under 32
+    value heads x 128), PR 46: as on a TPU with the cell's bfloat16 operands it is the kernel under
+    ``gdn.chunk`` and no line of the XLA form; off the TPU it is the XLA lines, and float32 operands
+    (every CPU test, the plain reference) keep those lines on both, text for text."""
     from ray_tpu.models import qwen3_next as qn
     from ray_tpu.ops import delta_rule as dr
 
     B, T, G, R, K = 2, 4096, 16, 2, 128
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
     args = (s(B, T, G, K), s(B, T, G, K), s(B, T, G, R, K), s(B, T, G, R), s(B, T, G, R))
-    rule = lambda *a: qn.delta_rule_chunked(*a, 64, jnp.bfloat16)  # noqa: E731
-    as_on_the_chip = jax.jit(rule).lower(*args).as_text()
-    assert "gdn.chunk" in jax.jit(rule).lower(*args).as_text(debug_info=True) and "pallas" not in as_on_the_chip
+    rule = lambda operands: lambda *a: qn.delta_rule_chunked(*a, 64, operands)  # noqa: E731
+    lowered = lambda operands, **kw: jax.jit(rule(operands)).lower(*args).as_text(**kw)  # noqa: E731
+    as_on_the_chip, in_float32 = str(jax.make_jaxpr(rule(jnp.bfloat16))(*args)), lowered(None)  # a Mosaic kernel does not lower for the CPU: its jaxpr
+    assert dr.refusal(jnp.bfloat16, K, K, 64) is None and "pallas_call" in as_on_the_chip and "delta_rule_by_head" in as_on_the_chip
+    assert "cumsum" not in as_on_the_chip and "cumsum" in in_float32, "no line of the XLA form"
+    assert "pallas" not in in_float32 and all(name in lowered(None, debug_info=True) for name in ("gdn.chunk", "gdn.scan"))
     jax.default_backend = lambda: "cpu"  # the fixture's monkeypatch puts the real one back
-    assert jax.jit(rule).lower(*args).as_text() == as_on_the_chip
-    by_channel = lambda q, k, v, g, beta: qn.delta_rule_chunked(q, k, v, jnp.broadcast_to(g[..., None], g.shape + (K,)), beta, 64, jnp.bfloat16, name="kda")  # noqa: E731
-    jax.default_backend = lambda: "tpu"
-    assert dr.refusal(jnp.bfloat16, K, K, 64) is None and "pallas_call" in str(jax.make_jaxpr(by_channel)(*args)), "the same call with a gate by channel takes the kernel"
+    assert "pallas" not in lowered(jnp.bfloat16) and all(name in lowered(jnp.bfloat16, debug_info=True) for name in ("gdn.chunk", "gdn.scan"))
+    assert lowered(None) == in_float32
 
 
 # ---------------------------------------------------------------------------

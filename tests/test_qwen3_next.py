@@ -17,8 +17,10 @@ import hybrid_battery as battery
 from benchmark.families import qwen3_next as family
 from hybrid_battery import *  # noqa: F401,F403 - the tests every description is held to, collected here against DESC
 from hybrid_battery import test_the_chips_shares_add_up_to_the_uncut_expert_layer  # noqa: F401 - chip 0 of four
+from ray_tpu.llm.sampling import SamplingParams
 from ray_tpu.models import experts, hybrid
 from ray_tpu.models import qwen3_next as qn
+from ray_tpu.ops import delta_rule
 
 # the configuration file's side of the toy model: chip 0 of two, experts 0-3 of 8
 C = family.rehearsal({"linear_conv_kernel_dim": 4, "norm_topk_prob": True, "rms_norm_eps": 1e-6,
@@ -49,7 +51,7 @@ def params():
     return battery.jiggled(jax.jit(lambda k: qn.init_params(CFG, k))(jax.random.PRNGKey(7)))
 
 
-def test_the_description_is_two_sub_blocks_a_layer_and_the_loop_finds_its_period():
+def test_the_description_is_two_sub_blocks_a_layer_and_the_loop_finds_its_period(monkeypatch):
     assert CFG.layer_kinds == ("gdn", "moe", "attn", "moe", "gdn", "moe", "attn", "moe", "gdn", "moe")
     assert CFG.layer_plan == (("gdn", "moe", "attn", "moe"), 2, ("gdn", "moe"), ())
     published = qn.Qwen3NextConfig()
@@ -62,6 +64,10 @@ def test_the_description_is_two_sub_blocks_a_layer_and_the_loop_finds_its_period
     assert {k: m.scope for k, m in cut.mixers.items()} == {"gdn": "gdn", "attn": "gated_attn", "moe": "moe"}
     spec = cut.cache_spec()
     assert spec["gdn"]["S"] == ((32, 128, 128), "float32", "sequence") and spec["attn"]["k"][0] == (2, 256) and spec["moe"] == {}
+    # what a prefill program runs of the rule, from its shape: 9 layers x 8 sequences x 4,096 / 64 chunks; off the TPU none in the kernel
+    assert cut.prefill_counters(8, 4096) == {"gdn_chunks": 9 * 8 * 64, "gdn_kernel_chunks": 0}
+    monkeypatch.setattr(delta_rule, "refusal", lambda *a, **kw: None)
+    assert cut.prefill_counters(8, 4096) == {"gdn_chunks": 9 * 8 * 64, "gdn_kernel_chunks": 9 * 8 * 64}
 
 
 @pytest.mark.parametrize("chunk", [8, 5, 64])
@@ -81,6 +87,34 @@ def test_chunked_delta_rule_equals_the_one_position_recurrence(params, chunk):
         np.testing.assert_allclose(s[0], S[b], atol=2e-5)  # not the state after the padding
         np.testing.assert_allclose(cv[0], conv[b], atol=1e-6)
     assert float(jnp.abs(S).max()) > 1e-3
+
+
+@pytest.mark.parametrize("form", ["the_xla_lines", "the_kernel"])
+def test_an_admitting_row_of_the_flight_log_counts_the_chunks_its_prefills_ran(eng, params, monkeypatch, form):
+    """``gdn_kernel_chunks`` beside ``gdn_chunks`` says which form ran them: none on the CPU, where
+    ``ops/delta_rule.refusal`` speaks; all of them once it does not (a second engine, so that its
+    prefill programs are traced with the kernel in them, interpreted: two value heads a key head, one
+    gate a head), and what that engine serves agrees with the plain reference as closely as the XLA
+    lines' does (``agrees_to``: float32 against float32)."""
+    lengths = (20, 9, 41)  # buckets 32, 16 and 64: three programs of one sequence, chunks of 8
+    ps = battery.prompts(DESC, 6, lengths)
+    sampling = [SamplingParams(max_tokens=3, temperature=0.0, logprobs=True)] * len(ps)
+    traced, real = [], delta_rule.delta_rule
+    monkeypatch.setattr(delta_rule, "delta_rule", lambda *a, **kw: traced.append(a[3].ndim) or real(*a, **kw))
+    if form == "the_kernel":
+        monkeypatch.setattr(delta_rule, "refusal", lambda *a, **kw: None)
+        eng = battery.engine(CFG, params)
+    mark = eng.telemetry()["step_count"]
+    outs = eng.generate(ps, sampling)
+    rows = battery.steps_after(eng, mark)
+    admitting = [r for r in rows if r.get("admitted")]
+    assert sum(r["gdn_chunks"] for r in admitting) == CFG.count("gdn") * sum(1 << (n - 1).bit_length() for n in lengths) // 8
+    assert all(r["gdn_chunks"] * 8 == CFG.count("gdn") * r["prefill_tokens_padded"] for r in admitting)
+    assert all(r["gdn_kernel_chunks"] == (r["gdn_chunks"] if form == "the_kernel" else 0) for r in admitting)
+    assert not any("gdn_chunks" in r or "gdn_kernel_chunks" in r for r in rows if not r.get("admitted"))
+    assert set(traced) == ({4} if form == "the_kernel" else set()), "the kernel was traced into the prefill programs, its gate a head: [B,T,G,R]"
+    res = battery.check(DESC, params, battery.served(outs, ps, sampling))
+    assert res["ok"] and res["tokens"] == 9 and res["max_abs_dlogprob"] < DESC.agrees_to, res
 
 
 def test_a_large_prefill_goes_through_the_rule_a_few_sequences_at_a_time(params, monkeypatch):
