@@ -14,7 +14,9 @@ gated by ``sigmoid(x . w_sg)``. Everything else is one code path:
   backward pass (training, the plain forward), and what the tests hold the two
   others to. Its cost is reading every held expert's weights.
 - ``experts_grouped``: a grouped matmul in plain XLA over the (row, expert)
-  pairs routed HERE, for prefill; no pair is dropped whatever one expert's load.
+  pairs routed HERE, for prefill: what it places, gathers and multiplies follows
+  the pairs held here, not the pairs the router made; no pair is dropped
+  whatever one expert's load.
 - ``experts_step``: a decode step's form: the held experts that a BOUND lane
   chose, one after another, every lane against one expert's matrices read
   straight out of the stacked weights (a kernel on a TPU, ``ops/step_experts.py``;
@@ -24,8 +26,11 @@ gated by ``sigmoid(x . w_sg)``. Everything else is one code path:
 
 In a profile the layer's parts stand under sub-scopes of the layer's own (``moe``):
 ``moe.route`` the router and its top k, ``moe.place`` laying the pairs out by expert
-and the gathers into and out of the blocks, ``moe.blocks`` the experts' matmuls (the
-grouped matmul's loop, a step's hit experts), ``moe.shared`` the shared expert.
+(in prefill its three parts by name: ``moe.place.count`` the pairs held here compacted
+and each given its row, ``moe.place.into`` their inputs gathered into the layout,
+``moe.place.out`` their outputs gathered out of it and summed by token), ``moe.blocks``
+the experts' matmuls (the grouped matmul's loop, a step's hit experts), ``moe.shared``
+the shared expert.
 
 The layer is told which experts this chip holds (``expert_start``,
 ``local_experts``): the router scores all published experts, this chip computes
@@ -46,6 +51,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops import step_experts
 from ray_tpu.util.profiling import scope, scoped
@@ -55,6 +61,13 @@ BLOCK, TALL_FROM = 128, 32768
 # the grouped matmul's buffers grow with the rows handed to it (a row of the residual width for
 # every pair that COULD be held here): beyond this many rows a sequence batch goes through in slabs
 SLAB_ROWS = 8192
+# the placement walks the pairs HELD here: ``SLAB`` of them at a time where it ranks them and gathers
+# their inputs, ``ROWS`` of a tile of ``TILE`` tokens at a time where it gathers and sums their outputs
+# (on a v5e a trip costs about 8 us and a row 25 ns: PERF.md section 6, PR 47, has what other sizes read)
+SLAB, TILE, ROWS = 1024, 128, 512
+# an expert's run starts at a multiple of this many rows of the layout, a whole tile of a bfloat16
+# array: a block's matmuls read and write 20% slower at an offset the compiler cannot see aligned
+ALIGN = 16
 
 
 @dataclass(frozen=True)
@@ -129,6 +142,18 @@ def experts_dense(w, x, idx, wt, c):
     return jnp.einsum("enf,efh->nh", (a * comb[..., None]).astype(x.dtype), w["w_down"])
 
 
+def _count_before(flags):
+    """How many of a 1-D array of flags are set before each place, and in all: rows of 128 against
+    a strict triangle on the MXU (0/1 operands, float32 sums: exact) and the rows' totals running.
+    No ``reduce_window`` over the whole length, and no sort."""
+    n = flags.shape[0]
+    f = jnp.pad(flags, (0, -n % 128)).reshape(-1, 128).astype(jnp.bfloat16)
+    within = jnp.dot(f, jnp.triu(jnp.ones((128, 128), jnp.bfloat16), 1), preferred_element_type=jnp.float32)
+    totals = jnp.sum(f, axis=1, dtype=jnp.float32)
+    before = within + (jnp.cumsum(totals) - totals)[:, None]
+    return before.reshape(-1)[:n].astype(jnp.int32), jnp.sum(totals).astype(jnp.int32)
+
+
 def _grouped(stacked, layer, x, idx, wt, valid, c):
     """``experts_grouped`` and what it did: -> (out [N,H], pairs at each held expert [El] int32,
     rows of the blocks in use, int32)."""
@@ -136,50 +161,104 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
     N, k = idx.shape
     M, El, H = N * k, s.held, x.shape[-1]
     block = 2 * BLOCK if M >= TALL_FROM else BLOCK
-    n_rows = (-(-M // block) + El) * block  # the most that padding to whole blocks can need
-    with scope("moe.place"):
+    slabs, tiles = -(-M // SLAB), -(-N // TILE)
+    n_pairs = slabs * SLAB + ROWS  # every pair could be held here; a trip may hang over the end
+    n_rows = -(-(M + El * ALIGN) // SLAB) * SLAB + block  # and every run start on a whole tile of the layout; a slab or a block may hang over
+    i32 = jnp.int32
+    with scope("moe.place"), scope("moe.place.count"):
         local = (idx - s.expert_start).reshape(-1)
         mine = (local >= 0) & (local < El) & jnp.repeat(valid, k)
-        # a pair's place: its rank among the pairs of its expert (a running count, no sort), after
-        # the blocks of the experts before it; what is not ours goes to a spare row that stays zero
-        hot = mine[:, None] & (local[:, None] == jnp.arange(El, dtype=jnp.int32)[None, :])
-        count = jnp.cumsum(hot.astype(jnp.int32), axis=0)
-        sizes = count[-1]
+        scale = jnp.where(mine, wt.reshape(-1), 0.0)
+        ids = jnp.arange(El, dtype=i32)
+        sizes = jnp.sum(mine[:, None] & (local[:, None] == ids[None, :]), axis=0, dtype=i32)
         blocks_of = (sizes + block - 1) // block
         last_block = jnp.cumsum(blocks_of)  # one past each expert's last block
-        e_of = jnp.clip(local, 0, El - 1)
-        rank = jnp.take_along_axis(count, e_of[:, None], axis=1)[:, 0] - 1
-        place = jnp.where(mine, (last_block[e_of] - blocks_of[e_of]) * block + rank, n_rows)
-        pair_at = jnp.full((n_rows + 1,), M, jnp.int32).at[place].set(jnp.arange(M, dtype=jnp.int32))
-        scale = jnp.where(mine, wt.reshape(-1), 0.0)
+        # the runs lie DENSE by expert, each from a multiple of ``ALIGN`` rows: no padding to a whole block
+        tiles_of = (sizes + ALIGN - 1) // ALIGN
+        start = jnp.cumsum(tiles_of) - tiles_of  # in units of ``ALIGN`` rows, so that the compiler sees every offset aligned
+        # the pairs held here, compacted in token order (a running count, no sort): what follows
+        # walks these, ``held`` of them, and never the M that the router made
+        before, held = _count_before(mine)
+        by_token = jnp.full((n_pairs,), M, i32).at[jnp.where(mine, before, n_pairs)].set(jnp.arange(M, dtype=i32), mode="drop")
+        tri = jnp.tril(jnp.ones((SLAB, SLAB), jnp.bfloat16))
+        first_row = (start * ALIGN).astype(jnp.float32)
+
+        def place_slab(j, carry):
+            # a slab of held pairs: each one's row is its expert's first row plus its rank among that
+            # expert's pairs (those of earlier slabs, ``seen``, and a triangle against this slab's one-hot)
+            seen, row_of, pair_at = carry
+            pair = jax.lax.dynamic_slice_in_dim(by_token, j * SLAB, SLAB)
+            ok = pair < M
+            hot = ok[:, None] & (local[jnp.minimum(pair, M - 1)][:, None] == ids[None, :])
+            upto = jnp.dot(tri, hot.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+            row = jnp.sum(jnp.where(hot, upto + (first_row + seen)[None, :], 0.0), axis=1).astype(i32) - 1
+            row = jnp.where(ok, row, n_rows)
+            return seen + upto[-1], jax.lax.dynamic_update_slice_in_dim(row_of, row, j * SLAB, 0), pair_at.at[row].set(pair, mode="drop")
+
+        _, row_of, pair_at = jax.lax.fori_loop(0, (held + SLAB - 1) // SLAB, place_slab, (
+            jnp.zeros((El,), jnp.float32), jnp.zeros((n_pairs,), i32), jnp.full((n_rows,), M, i32)))
+        # a tile of tokens owns a stretch of ``by_token``; it is summed ``ROWS`` pairs a trip
+        edges = jnp.append(before, held)[np.minimum(np.arange(tiles + 1) * TILE * k, M)]
+        trips_of = (edges[1:] - edges[:-1] + ROWS - 1) // ROWS
+        last_trip = jnp.cumsum(trips_of)
+
+    def fill_slab(j, rows):
+        pair = jax.lax.dynamic_slice_in_dim(pair_at, j * SLAB, SLAB)
+        return jax.lax.dynamic_update_slice_in_dim(rows, jnp.take(x, jnp.minimum(pair, M - 1) // k, axis=0), block + j * SLAB, 0)
+
+    with scope("moe.place"), scope("moe.place.into"):  # the held pairs' rows of x, gathered into the layout a slab at a time
+        rows = jax.lax.fori_loop(0, ((start[-1] + tiles_of[-1]) * ALIGN + SLAB - 1) // SLAB, fill_slab, jnp.zeros((block + n_rows, H), x.dtype))
     mats = [stacked[n] for n in s.matrices]
 
-    def one_block(b, ys):
-        e = jnp.sum(last_block <= b).astype(jnp.int32)
-        with scope("moe.place"):  # the gather into the block
-            pair = jax.lax.dynamic_slice_in_dim(pair_at, b * block, block)
-            ok = pair < M  # the padding at the end of an expert's run holds no pair
-            pair = jnp.minimum(pair, M - 1)
-            xb = jnp.where(ok[:, None], jnp.take(x, pair // k, axis=0), 0)
+    def one_block(b, rows):
+        # ONE array holds a row's input ``block`` rows behind where its output goes, and the blocks
+        # run in ascending order of row: what a block overwrites, its overhang past the end of its run
+        # included, are inputs already read, and what it leaves past its run the next run's block rewrites
+        e = jnp.sum(last_block <= b).astype(i32)
+        done = b - (last_block[e] - blocks_of[e])  # whole blocks of this run before this one
+        at = (start[e] + done * (block // ALIGN)).astype(jnp.uint32) * jnp.uint32(ALIGN)  # unsigned: no wrap of a negative index hides the factor
+        xb = jax.lax.dynamic_slice_in_dim(rows, block + at, block)
         *gate, up, down = (jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0] for a in mats)
         yb = jnp.dot(_hidden(s, xb, up, gate[0] if gate else None, "bh,fh->bf"), down)
-        yb = (yb * jnp.where(ok, scale[pair], 0.0)[:, None]).astype(ys.dtype)
-        return jax.lax.dynamic_update_slice(ys, yb, (b * block, jnp.zeros((), jnp.int32)))
+        pair = jnp.minimum(jax.lax.dynamic_slice_in_dim(pair_at, at, block), M - 1)
+        yb = (yb * scale[pair][:, None]).astype(rows.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(rows, yb, at, 0)
 
     with scope("moe.blocks"):
-        ys = jax.lax.fori_loop(0, last_block[-1], one_block, jnp.zeros((n_rows + 1, H), x.dtype))
-    with scope("moe.place"):  # and the gather out of them
-        out = jnp.sum(jnp.take(ys, place, axis=0).reshape(N, k, H), axis=1, dtype=jnp.float32).astype(x.dtype)
+        rows = jax.lax.fori_loop(0, last_block[-1], one_block, rows)
+
+    def sum_rows(t, out):
+        # ``ROWS`` of a tile's pairs: their rows gathered, and added to their tokens by a 0/1
+        # [TILE, ROWS] matrix on the MXU, which sums in float32 as a reduction over k would
+        i = jnp.sum(last_trip <= t).astype(i32)
+        q = edges[i] + (t - (last_trip[i] - trips_of[i])) * ROWS
+        ok = jnp.arange(ROWS, dtype=i32) < edges[i + 1] - q
+        yb = jnp.take(rows, jnp.where(ok, jax.lax.dynamic_slice_in_dim(row_of, q, ROWS), 0), axis=0)
+        token = jax.lax.dynamic_slice_in_dim(by_token, q, ROWS) // k - i * TILE
+        pick = ok[None, :] & (token[None, :] == jnp.arange(TILE, dtype=i32)[:, None])
+        add = jnp.dot(pick.astype(yb.dtype), jnp.where(ok[:, None], yb, 0), preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
+        return jax.lax.dynamic_update_slice_in_dim(out, jax.lax.dynamic_slice_in_dim(out, i * TILE, TILE) + add, i * TILE, 0)
+
+    with scope("moe.place"), scope("moe.place.out"):  # the gather out of the blocks and the sum over a token's pairs
+        out = jax.lax.fori_loop(0, last_trip[-1], sum_rows, jnp.zeros((tiles * TILE, H), jnp.float32))[:N].astype(x.dtype)
     return out, sizes, last_block[-1] * block
 
 
 def experts_grouped(stacked, layer, x, idx, wt, valid, c):
-    """A grouped matmul in plain XLA: the (row, expert) pairs routed here, laid out by expert, each
-    expert's run padded to whole blocks of rows, and one loop over the blocks IN USE: a block's
-    rows against its expert's matrices, read straight from the stacked weights. The work
-    follows the pairs (plus at most a block an expert), not experts x rows; no pair is dropped,
-    whatever the load on one expert. ``valid`` [N] keeps padding out of every group. The loop's
-    length is data, so this path has no backward pass (training uses ``experts_dense``).
+    """A grouped matmul in plain XLA over the (row, expert) pairs routed HERE, whose number is
+    data: every loop below turns as often as those pairs need, none as often as the router's
+    N x k. The held pairs are compacted in token order by a running count (no sort), ``SLAB`` of
+    them at a time get their rows (an expert's first row plus the pair's rank among that expert's
+    pairs: a triangle against the slab's one-hot on the MXU), and the layout is DENSE by expert: no
+    padding between the runs but to a multiple of ``ALIGN`` rows. The pairs' inputs are gathered
+    into it a slab at a time; one loop over the blocks IN USE takes ``block`` rows at its run's
+    offset against its expert's matrices, read straight from the stacked weights, and writes the
+    outputs into the SAME array ``block`` rows before the inputs (``one_block`` says why that is safe);
+    then a tile of ``TILE`` tokens gathers its pairs' rows ``ROWS`` a trip and a 0/1 matrix sums
+    them by token in float32. A pair's product is scaled by its weight in float32 and rounded
+    once, a token's pairs are summed in float32 and rounded once; no pair is dropped, whatever the
+    load on one expert. ``valid`` [N] keeps padding out of every group. The loops' lengths are
+    data, so this path has no backward pass (training uses ``experts_dense``).
     ``stacked[name]`` are the arrays STACKED over the expert layers, and the loop reads expert e
     of layer ``layer`` from them: a layer's experts, sliced out first, would be copied once a
     layer to become the loop's operand."""

@@ -57,7 +57,8 @@ SCOPES = {
     "lightning": "mixer", "lightning.chunk": "mixer",
     "gdn.state": "state", "kda.state": "state", "mamba2.state": "state", "lightning.state": "state",
     "mlp": "ffn", "ffn": "ffn",
-    "moe": "ffn", "moe.route": "ffn", "moe.place": "ffn", "moe.blocks": "ffn", "moe.shared": "ffn",
+    "moe": "ffn", "moe.route": "ffn", "moe.place": "ffn", "moe.place.count": "ffn", "moe.place.into": "ffn", "moe.place.out": "ffn",
+    "moe.blocks": "ffn", "moe.shared": "ffn",
 }
 UNSCOPED = "unscoped"
 

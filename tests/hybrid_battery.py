@@ -324,15 +324,78 @@ def test_the_chips_shares_add_up_to_the_uncut_expert_layer(desc):
     np.testing.assert_allclose(x + total, ref, atol=1e-4)
 
 
+PLACEMENTS = ("one_expert", "none_here", "a_block_and_one_more", "valid_ends_inside_a_block", "tall_blocks", "in_slabs", "many_small_trips")
+
+
+@pytest.mark.parametrize("case", PLACEMENTS)
+def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(desc, params, case, monkeypatch):
+    """The layout of ``experts._grouped`` follows the pairs held here, so its loops' lengths are
+    data: every pair at ONE held expert (a run of many blocks); no pair held here (no trip, zeros
+    out); an expert with exactly ``BLOCK`` pairs beside one with ``BLOCK + 1``; ``valid`` that ends
+    inside a block; the tall blocks; a batch in slabs; slabs, tiles and trips a few rows long, so
+    that every loop turns many times. Each against the pairs computed one by one, and the counters
+    against their definition. (Not in ``__all__``: for the descriptions that route experts.)"""
+    cfg, s = desc.cfg, desc.cfg.expert_layer
+    w = jax.tree.map(lambda a: a[0], params["moe"])
+    N, k, El, B = 300, s.top_k, s.held, experts.BLOCK
+    x = jax.random.normal(jax.random.PRNGKey(21), (N, cfg.hidden_size))
+    idx, wt = experts.route(w, x, cfg)
+    valid = np.ones((N,), bool)
+    elsewhere = [e for e in range(s.num_experts) if not s.expert_start <= e < s.expert_start + El]
+    if case == "one_expert":
+        idx = jnp.full((N, k), s.expert_start + 1, jnp.int32)
+    elif case == "none_here" and elsewhere:
+        idx = jnp.full((N, k), elsewhere[0], jnp.int32)
+    elif case == "none_here":
+        valid[:] = False
+    elif case == "a_block_and_one_more":
+        rest = elsewhere[0] if elsewhere else s.expert_start + 2
+        first, second = np.where(np.arange(N) < B, s.expert_start, rest), np.where(np.arange(N) < B + 1, s.expert_start + 1, rest)
+        idx = jnp.asarray(np.stack([first, second] + [np.full((N,), rest)] * (k - 2), axis=1), jnp.int32)
+    elif case == "valid_ends_inside_a_block":
+        idx = jnp.asarray(np.asarray(idx) % 2 + s.expert_start)  # two experts, runs of more than a block
+        valid[173:] = False
+    elif case == "tall_blocks":
+        monkeypatch.setattr(experts, "TALL_FROM", 64)
+        B = 2 * B
+    elif case == "in_slabs":
+        monkeypatch.setattr(experts, "SLAB_ROWS", 100)
+    elif case == "many_small_trips":
+        monkeypatch.setattr(experts, "SLAB", 16)
+        monkeypatch.setattr(experts, "TILE", 8)
+        monkeypatch.setattr(experts, "ROWS", 4)
+    want = one_by_one(w, x, idx, jnp.where(valid[:, None], wt, 0.0), cfg)
+    if case == "in_slabs":  # the layer over three sequences of 100, the shared expert taken off again
+        lengths = jnp.asarray([100, 100, 100])
+        monkeypatch.setattr(experts, "route", lambda *_: (idx, wt))
+        got, counters = experts.moe_seq(w, x.reshape(3, 100, -1), lengths, cfg, stacked=(params["moe"], 0))
+        got = got.reshape(N, -1) - experts.shared_expert(w, x, s)
+        sizes, rows = None, None
+    else:
+        got, sizes, rows = experts._grouped(params["moe"], 0, x, idx, wt, jnp.asarray(valid), cfg)
+        counters = [int(jnp.sum(sizes > 0)), int(jnp.sum(sizes)), int(rows)]
+    here = (np.asarray(idx) - s.expert_start)[valid]
+    pairs = np.bincount(here[(here >= 0) & (here < El)], minlength=El)
+    assert (np.abs(want).max() > 0) == (pairs.sum() > 0)
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    assert counters[0] == (pairs > 0).sum() and counters[1] == pairs.sum()
+    if sizes is not None:
+        assert (np.asarray(sizes) == pairs).all() and counters[2] == (-(-pairs // B)).sum() * B
+    if case == "none_here":
+        assert counters[2] == 0 and not np.asarray(got).any()
+    if case == "a_block_and_one_more":
+        assert pairs[0] == experts.BLOCK and pairs[1] == experts.BLOCK + 1
+
+
 def one_by_one(w, x, idx, wt, cfg):
     """Each (token, chosen expert) pair computed alone: what no dispatch may lose."""
-    out = np.zeros(x.shape, np.float32)
+    out, s = np.zeros(x.shape, np.float32), cfg.expert_layer
     for n in range(x.shape[0]):
         for e, g in zip(np.asarray(idx[n]), np.asarray(wt[n])):
-            e = int(e) - cfg.expert_start
-            if 0 <= e < cfg.local_experts:
+            e = int(e) - s.expert_start
+            if 0 <= e < s.held:
                 up = x[n] @ w["w_up"][e].T
-                h = jnp.square(jax.nn.relu(up)) if cfg.expert_layer.act == "relu2" else jax.nn.silu(x[n] @ w["w_gate"][e].T) * up
+                h = jnp.square(jax.nn.relu(up)) if s.act == "relu2" else jax.nn.silu(x[n] @ w["w_gate"][e].T) * up
                 out[n] += g * np.asarray(h @ w["w_down"][e])
     return out
 
@@ -347,4 +410,4 @@ def jiggled(params):
 
 
 # what ``import *`` hands a description's file: the fixtures, the hook that parametrizes ``fault``, and every test but the share's
-__all__ = ["desc", "eng", "fault", "pytest_generate_tests"] + [n for n in dir() if n.startswith("test_") and n != "test_the_chips_shares_add_up_to_the_uncut_expert_layer"]
+__all__ = ["desc", "eng", "fault", "pytest_generate_tests"] + [n for n in dir() if n.startswith("test_") and n not in ("test_the_chips_shares_add_up_to_the_uncut_expert_layer", "test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number")]

@@ -390,15 +390,29 @@ def test_qwen3_next_fused_step_fits_one_v5e_and_updates_both_caches_in_place(one
     assert mem.temp_size_in_bytes < one_layers_rows == 128 * 2**20
 
 
-@pytest.mark.parametrize("prompts, most_gib", [(1, 0.65), (8, 3.0)])
+def _no_row_for_every_pair(txt, cfg, positions):
+    """The expert layer's placement follows the pairs held HERE (PR 47): the compiled prefill holds
+    no array with a row of the residual width for every (token, choice) pair the router made
+    (``bf16[40960,2048]`` at Qwen3-Next's 4096 bucket, ``bf16[32768,2304]`` at Kimi's), and none
+    with the k choices of a token on the sublanes (``[4096,10,2048]``, ``[4096,8,2304]``: the copy
+    that the sum over k used to read)."""
+    from ray_tpu.models import experts
+
+    N, k, H = min(positions, experts.SLAB_ROWS), cfg.expert_layer.top_k, cfg.hidden_size
+    assert f"[{N * k},{H}]" not in txt and f"[{N},{k},{H}]" not in txt and f"[{k},{N},{H}]" not in txt
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 0.45), (8, 2.85)])
 def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
     """The 4096-bucket prefill (the delta rule with one gate a head as ONE kernel under ``gdn.chunk``,
     PR 46, a few sequences at a time; the flash kernel at heads 256 wide; the grouped matmul over 128
-    small experts in slabs) for one prompt (0.615 GiB of temporaries as compiled for PR 46; 0.65 for
-    PR 34, with the XLA lines) and for the largest group the cell warms, 8 x 4096 (2.73 GiB; 3.23
-    for PR 34; and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of caches: under
-    15.75 GiB. No ``[.., 64, 64]`` float32 square of a chunk's pairs is left among the program's
-    arrays, and no line of the XLA form's scan."""
+    small experts in slabs) for one prompt (0.397 GiB of temporaries as compiled for PR 47, whose
+    expert layer lays out the pairs held here; 0.615 for PR 46; 0.65 for PR 34, with the XLA lines)
+    and for the largest group the cell warms, 8 x 4096 (2.73 GiB, where the rule's groups set the
+    peak; 3.23 for PR 34; and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of
+    caches: under 15.75 GiB. No ``[.., 64, 64]`` float32 square of a chunk's pairs is left among the
+    program's arrays, no line of the XLA form's scan, and neither the gather of EVERY (token,
+    choice) pair's row out of the blocks nor its copy padded for the sum over k."""
     import re
 
     from ray_tpu.llm import hybrid_runner as hr
@@ -413,6 +427,7 @@ def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on
     rule = [line for line in txt.splitlines() if "custom-call(" in line and "delta_rule_by_head" in line]
     assert rule and all("tpu_custom_call" in line and "gdn.chunk" in line for line in rule), "the rule's kernel, under its scope"
     assert "gdn.scan" not in txt and not re.search(r"f32\[[0-9,]*64,64\]", txt)
+    _no_row_for_every_pair(txt, cfg, prompts * 4096)
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (4.5 GiB), no layer's worth of them (0.375 GiB x 12)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.67 * 2**30 < 15.0 * 2**30
 
@@ -624,15 +639,17 @@ def test_kimi_fused_step_fits_one_v5e_aliases_both_caches_and_slices_no_layers_r
     print("kimi fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
 
 
-@pytest.mark.parametrize("prompts, most_gib", [(1, 0.70), (8, 2.93)])
+@pytest.mark.parametrize("prompts, most_gib", [(1, 0.56), (8, 2.8)])
 def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
     """The 4096-bucket prefill (the delta rule with its gate by channel as ONE kernel under
     ``kda.chunk``, PR 44, a few sequences at a time; the flash kernel at 32 heads, keys and values
     padded to 256; the grouped matmul over 64 experts in slabs) for one prompt (0.52 GiB of
-    temporaries as compiled for PR 44, under PR 42's 0.70) and for the largest group the cell
-    warms, 8 x 4096 (2.67 GiB, under PR 42's 2.93, and 0.19 GiB of output), beside 7.96 GiB of
-    weights and 0.38 GiB of caches: under 15.75 GiB. No ``[.., 64, 64]`` float32 square of a
-    chunk's pairs is left among the program's arrays, and no line of the XLA form's scan."""
+    temporaries as compiled for PR 44, under PR 42's 0.70; 0.514 for PR 47: the rule's operands set
+    the peak, not the expert layer) and for the largest group the cell warms, 8 x 4096 (2.67 GiB,
+    under PR 42's 2.93, and 0.19 GiB of output), beside 7.96 GiB of weights and 0.38 GiB of caches:
+    under 15.75 GiB. No ``[.., 64, 64]`` float32 square of a chunk's pairs is left among the
+    program's arrays, no line of the XLA form's scan, and neither the gather of EVERY (token,
+    choice) pair's row out of the blocks nor its copy padded for the sum over k (PR 47)."""
     import re
 
     from ray_tpu.llm import hybrid_runner as hr
@@ -647,6 +664,7 @@ def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v
     rule = [line for line in txt.splitlines() if "custom-call(" in line and "delta_rule_by_channel" in line]
     assert rule and all("tpu_custom_call" in line and "kda.chunk" in line for line in rule), "the rule's kernel, under its scope"
     assert "kda.scan" not in txt and not re.search(r"f32\[[0-9,]*64,64\]", txt)
+    _no_row_for_every_pair(txt, cfg, prompts * 4096)
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (3.4 GiB), no layer's worth of them (0.42 GiB x 8)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.39 * 2**30 < 15.0 * 2**30
 

@@ -18,6 +18,7 @@ import hybrid_battery as battery
 from benchmark.families import glm4_moe_lite as family
 from hybrid_battery import *  # noqa: F401,F403 - the tests every description is held to, collected here against DESC
 from hybrid_battery import engine
+from hybrid_battery import test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number  # noqa: F401 - it routes experts
 from ray_tpu.llm import SamplingParams
 from ray_tpu.llm import hybrid_runner as hr
 from ray_tpu.llm import kv_cache as kvc

@@ -161,6 +161,24 @@ def test_every_heavy_operation_of_a_step_program_stands_under_a_table_scope(lowe
     assert unscoped_ops(programs[program]) == []
 
 
+@pytest.mark.parametrize("description", ["nemotron_h", "qwen3_next", "glm4_moe_lite", "kimi_linear"])
+def test_a_prefills_placement_stands_under_its_three_parts_by_name(lowered, description):
+    """``moe.place`` in a prefill is ``moe.place.count`` / ``.into`` / ``.out`` (PR 47), so that a traced
+    run says which part of the placement costs what: every gather and scatter of the expert layer
+    outside the blocks' loop stands under one of the three, set INSIDE ``moe.place``."""
+    from jax._src.lib.mlir import ir
+
+    paths = set()
+
+    def visit(op):
+        paths.add(_name(op))
+        return ir.WalkResult.ADVANCE
+
+    lowered(description)["llm_hybrid_prefill"].compiler_ir().operation.walk(visit)
+    placed = {scope_of(p) for p in paths if "/moe.place/" in p}
+    assert placed == {"moe.place.count", "moe.place.into", "moe.place.out"}
+
+
 @pytest.mark.parametrize("description", sorted(PROGRAMS))
 def test_the_engine_ran_no_step_program_the_cases_above_leave_out(lowered, description):
     assert set(lowered(description)) <= set(PROGRAMS[description]) | {"llm_prefill"}  # the paged engine prefills by the slot program

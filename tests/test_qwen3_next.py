@@ -17,6 +17,7 @@ import hybrid_battery as battery
 from benchmark.families import qwen3_next as family
 from hybrid_battery import *  # noqa: F401,F403 - the tests every description is held to, collected here against DESC
 from hybrid_battery import test_the_chips_shares_add_up_to_the_uncut_expert_layer  # noqa: F401 - chip 0 of four
+from hybrid_battery import test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number  # noqa: F401 - it routes experts
 from ray_tpu.llm.sampling import SamplingParams
 from ray_tpu.models import experts, hybrid
 from ray_tpu.models import qwen3_next as qn
