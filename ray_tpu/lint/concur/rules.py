@@ -96,7 +96,7 @@ def _hot_root(name: str) -> bool:
     serve/) is NOT one — only exact ``_drain``/``_drain_spec`` (the
     device-readback tails of the fused step) qualify."""
     return (
-        name in ("step", "on_step", "record_step", "_drain", "_drain_spec", "_sync_decode")
+        name in ("step", "on_step", "record_step", "_drain", "_drain_spec")
         or name.startswith("_stage_")
         or name.startswith("_dispatch")
     )

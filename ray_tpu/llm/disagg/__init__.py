@@ -16,9 +16,10 @@ shipped through the runtime's own object plane:
 
 Serve integration (deployments + builder) lives in ray_tpu.serve.llm
 (PrefillServer / DecodeServer / DisaggRouterServer,
-build_pd_disagg_deployment). The single-engine sync loop remains the
-token-identical oracle: an N_prefill=1/N_decode=1 deployment emits
-exactly its tokens (tests/test_llm_disagg.py).
+build_pd_disagg_deployment). The plain reference (tests/plain_reference.py:
+the whole-sequence forward, no cache) is the token-identical oracle: an
+N_prefill=1/N_decode=1 deployment emits exactly its tokens
+(tests/test_llm_disagg.py).
 """
 
 from ray_tpu.llm.disagg.handoff import (

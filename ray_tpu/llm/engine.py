@@ -31,9 +31,10 @@ python/ray/llm/_internal/serve/engines/vllm/vllm_engine.py):
   token-identical to the plain path (which stays untouched as the
   subsystem's equivalence oracle).
 
-`device_resident=False` keeps the old synchronous host-driven loop as the
-equivalence oracle. Engine steps are cheap to drive from an actor or a
-Serve replica; `generate()` is the batteries-included loop.
+The one loop's reference is plain code that shares nothing with it:
+tests/plain_reference.py and, for a description, its family's reference
+(tests/hybrid_battery.py). Engine steps are cheap to drive from an actor
+or a Serve replica; `generate()` is the batteries-included loop.
 """
 
 from __future__ import annotations
@@ -326,7 +327,6 @@ class LLMEngine:
         num_pages: int | None = None,
         page_size: int = 64,
         attn_kernel: str = "xla",
-        device_resident: bool = True,
         speculative=None,
         telemetry: bool = True,
         telemetry_tags: dict | None = None,
@@ -357,15 +357,6 @@ class LLMEngine:
         dequantize-in-attention — ~2x the servable concurrency at fixed
         cache HBM, with the fp cache as the accuracy oracle
         (tests/test_llm_kv_int8.py).
-
-        device_resident (default on): the decode hot path keeps ALL
-        per-step state on device — one fused jitted step per token,
-        scheduler changes applied as scatter deltas, and token readback
-        overlapped with the next step's dispatch (emission trails the
-        device by exactly one step). Off = the synchronous
-        host-driven loop (re-uploads + blocking readback per step), kept
-        as the equivalence oracle. Same-bucket prompt prefills at
-        admission run as one batched forward.
 
         speculative (llm.spec.SpecConfig | None): speculative decoding on
         the device-resident loop — a drafter proposes up to k tokens per
@@ -501,7 +492,7 @@ class LLMEngine:
                 if not ok:
                     raise AttnKernelUnavailableError(f"attn_kernel='pallas' cannot be served: {why}")
             self.attn_kernel = attn_kernel
-            self._prefill, self._insert, self._decode, self._extend = make_paged_runner_fns(
+            self._prefill, self._insert, self._extend = make_paged_runner_fns(
                 config, attn_impl=attn_kernel, mesh=mesh
             )
             self._page_alloc = pkv.PageAllocator(self._pcfg.num_pages)
@@ -511,8 +502,8 @@ class LLMEngine:
             self._admit_counter = 0
         else:
             self.attn_kernel = "xla"  # slot layout: no page gather to fuse
-            if not self._hybrid:  # the hybrid's programs are built below, once the loop's mode is known
-                self._prefill, self._insert, self._decode, self._extend = make_runner_fns(config, mesh=mesh)
+            if not self._hybrid:  # the hybrid's programs are hybrid_runner's, built below
+                self._prefill, self._insert, self._extend = make_runner_fns(config, mesh=mesh)
 
         cache_cfg = (
             None
@@ -595,7 +586,6 @@ class LLMEngine:
         self._keys = np.array(
             jax.vmap(lambda s: jax.random.key_data(jax.random.PRNGKey(s)))(jnp.arange(B, dtype=jnp.uint32))
         ).astype(np.uint32)
-        self._next_tokens = np.zeros((B,), np.int32)  # input token for next decode per slot
 
         self._slots: list[RequestState | None] = [None] * B
         self._waiting: deque[RequestState] = deque()
@@ -660,7 +650,6 @@ class LLMEngine:
             self._prefix_cache.evict_hook = kv_plane.on_evict
         self.preemption_count = 0
 
-        self._device_resident = bool(device_resident)
         # in-flight fused step awaiting host readback:
         # (tokens [B] dev, logps [B] dev, [(RequestState, slot), ...])
         self._pending = None
@@ -670,13 +659,13 @@ class LLMEngine:
         from ray_tpu.parallel.mesh import axis_size, is_tp_only
 
         self._tp_fused = (
-            mesh is not None and is_tp_only(mesh) and axis_size(mesh, "tp") > 1 and self._device_resident
+            mesh is not None and is_tp_only(mesh) and axis_size(mesh, "tp") > 1
         )
         if tp_collective == "int8" and not self._tp_fused:
             raise ValueError(
                 "tp_collective='int8' quantizes the explicit shard_map all-reduce, which only "
-                "exists on the device-resident fused path over a pure tp>=2 mesh "
-                "(got mesh=%s, device_resident=%s)" % (getattr(mesh, "axis_names", None), self._device_resident)
+                "exists on the fused path over a pure tp>=2 mesh "
+                "(got mesh=%s)" % (getattr(mesh, "axis_names", None),)
             )
         if self._tp_fused and tp_collective == "int8" and config.hidden_size % axis_size(mesh, "tp"):
             raise ValueError(
@@ -686,54 +675,44 @@ class LLMEngine:
         if self._hybrid:
             from ray_tpu.llm.hybrid_runner import make_hybrid_fns
 
-            self._prefill, self._insert, self._state_insert, step_fn = make_hybrid_fns(config, self._device_resident)
-            if self._device_resident:
-                self._fused_step = step_fn
-            else:
-                self._decode = step_fn
+            self._prefill, self._insert, self._state_insert, self._fused_step = make_hybrid_fns(config)
         self._moe_stats = None  # the drained step's expert-routing counters (hybrid models)
         # this step's hybrid prefills: (tokens, tokens as padded, programs, summed routing counters,
         # what the description counts of the programs' shapes)
         self._prefill_stats = None
-        if self._device_resident:
-            from ray_tpu.llm.model_runner import make_delta_fns, make_fused_fns, make_fused_paged_fns
+        from ray_tpu.llm.model_runner import make_delta_fns, make_fused_fns, make_fused_paged_fns
 
-            tp_mesh = mesh if self._tp_fused else None
-            if kv_layout == "paged":
-                self._fused_attn, self._fused_append = make_fused_paged_fns(
-                    config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
-                    attn_impl=self.attn_kernel,
-                )
-            elif not self._hybrid:
-                self._fused_step = make_fused_fns(
-                    config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
-                    partitioned=mesh is not None,
-                )
-            self._set_lane, self._set_table, self._set_table_cell = make_delta_fns()
-            if mesh is None:
-                _put = jnp.asarray
-            else:
-                from jax.sharding import NamedSharding, PartitionSpec as P
+        tp_mesh = mesh if self._tp_fused else None
+        if kv_layout == "paged":
+            self._fused_attn, self._fused_append = make_fused_paged_fns(
+                config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
+                attn_impl=self.attn_kernel,
+            )
+        elif not self._hybrid:
+            self._fused_step = make_fused_fns(
+                config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
+                partitioned=mesh is not None,
+            )
+        self._set_lane, self._set_table, self._set_table_cell = make_delta_fns()
+        if mesh is None:
+            _put = jnp.asarray
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-                _repl = NamedSharding(mesh, P())
-                _put = lambda a: jax.device_put(a, _repl)  # noqa: E731
-            # device-resident decode state; host arrays above stay as the
-            # scheduler's shadow copies (never re-uploaded wholesale)
-            self._dtokens = _put(self._next_tokens)
-            self._dkeys = _put(self._keys)
-            self._dtemps = _put(self._temps)
-            self._dtopk = _put(self._top_k)
-            self._dtopp = _put(self._top_p)
-            if kv_layout == "paged":
-                self._dtables = _put(self._tables)
-                self._dlengths = _put(self._lengths)
+            _repl = NamedSharding(mesh, P())
+            _put = lambda a: jax.device_put(a, _repl)  # noqa: E731
+        # device-resident decode state; host arrays above stay as the
+        # scheduler's shadow copies (never re-uploaded wholesale)
+        self._dtokens = _put(np.zeros((B,), np.int32))  # each lane's input token for the next step
+        self._dkeys = _put(self._keys)
+        self._dtemps = _put(self._temps)
+        self._dtopk = _put(self._top_k)
+        self._dtopp = _put(self._top_p)
+        if kv_layout == "paged":
+            self._dtables = _put(self._tables)
+            self._dlengths = _put(self._lengths)
         self._spec_cfg = None
         if speculative is not None:
-            if not self._device_resident:
-                raise ValueError(
-                    "speculative decoding runs on the device-resident loop only "
-                    "(the plain loop is kept untouched as its equivalence oracle)"
-                )
             if mesh is not None and not self._tp_fused:
                 raise ValueError(
                     "speculative decoding over a mesh needs the shard_map fused path "
@@ -1273,7 +1252,7 @@ class LLMEngine:
             raise MigrationError(
                 "streaming requests cannot migrate (the consumer holds a live token queue)"
             )
-        if self._device_resident and self._pending is not None:
+        if self._pending is not None:
             prev, self._pending = self._pending, None
             if self._spec_cfg is not None:
                 self._drain_spec(prev)
@@ -1349,13 +1328,9 @@ class LLMEngine:
         state.update(k=np.asarray(out[0]), v=np.asarray(out[1]), n=l)
         if len(out) == 4:
             state.update(k_scale=np.asarray(out[2]), v_scale=np.asarray(out[3]))
-        # the LIVE key: on the device-resident loop it advanced on
-        # device (seeded lanes included — restore must continue the
-        # sequence, never reset from the seed); sync keeps it on host
-        if self._device_resident:
-            state["rng_key"] = np.asarray(self._dkeys[slot]).astype(np.uint32)
-        else:
-            state["rng_key"] = np.asarray(self._keys[slot], np.uint32)
+        # the LIVE key: it advanced on device (seeded lanes included —
+        # restore must continue the sequence, never reset from the seed)
+        state["rng_key"] = np.asarray(self._dkeys[slot]).astype(np.uint32)
         if self._tel is not None:
             nbytes = int(state["k"].nbytes + state["v"].nbytes)
             if state.get("k_scale") is not None:
@@ -1620,10 +1595,9 @@ class LLMEngine:
         self._slot_pages[slot] = []
         self._tables[slot, :] = 0
         self._lengths[slot] = 0
-        if self._device_resident:
-            # point the lane at the trash page so in-flight/idle steps
-            # scatter harmlessly instead of into recycled pages
-            self._push_table(slot)
+        # point the lane at the trash page so in-flight/idle steps
+        # scatter harmlessly instead of into recycled pages
+        self._push_table(slot)
 
     def _preempt_for(self, need: int, exclude: RequestState | None = None) -> bool:
         """Recompute-preemption (vLLM's default policy): the YOUNGEST
@@ -1656,7 +1630,7 @@ class LLMEngine:
         page = self._pcfg.page_size
         spec = self._spec_cfg is not None
         pending_k: dict = {}
-        if self._device_resident and self._pending is not None:
+        if self._pending is not None:
             for entry in self._pending[-1]:  # lanes: (st, slot[, k_eff])
                 pending_k[id(entry[0])] = entry[2] if len(entry) > 2 else 0
         for st in [s for s in self._slots if s is not None]:
@@ -1667,8 +1641,7 @@ class LLMEngine:
                 # max_tokens: this call's step is its discarded trailing
                 # step — never grow (let alone PREEMPT a live sequence)
                 # for it; the unallocated-page write lands in the trash
-                # page. Matches the sync oracle, where the finish would
-                # already have freed the slot.
+                # page.
                 continue
             slot = st.slot
             l = int(self._lengths[slot])
@@ -1703,10 +1676,9 @@ class LLMEngine:
                 pg_ix = len(self._slot_pages[slot])
                 self._slot_pages[slot].extend(got)
                 self._tables[slot, pg_ix] = got[0]
-                if self._device_resident:
-                    self._dtables = self._set_table_cell(
-                        self._dtables, np.int32(slot), np.int32(pg_ix), np.int32(got[0])
-                    )
+                self._dtables = self._set_table_cell(
+                    self._dtables, np.int32(slot), np.int32(pg_ix), np.int32(got[0])
+                )
 
     def _pages_needed(self, st: RequestState, pref, prompt) -> int | None:
         """Pages a request needs to admit (prompt bucket + one decode
@@ -2256,8 +2228,7 @@ class LLMEngine:
                     table_row = jnp.asarray(self._tables[slot])
                     self.pool = self._insert(self.pool, table_row[: T // page], ks[:, i], vs[:, i])
                     self._lengths[slot] = n
-                    if self._device_resident:
-                        self._push_table(slot)
+                    self._push_table(slot)
                 elif not self._hybrid:
                     self.cache = self._insert(self.cache, slot, ks[:, i], vs[:, i], n)
                 else:
@@ -2313,31 +2284,19 @@ class LLMEngine:
                 ks_pad[..., : ks_w.shape[2]] = ks_w
                 vs_pad[..., : vs_w.shape[2]] = vs_w
                 scales = (jnp.asarray(ks_pad), jnp.asarray(vs_pad))
-            if self._device_resident:
-                # ONE fused scatter-in (llm/disagg/scatter.py): pool pages
-                # + device table row + device length lane in a single
-                # program — the handoff admission hot path
-                self.pool, self._dtables, self._dlengths = self._scatter_paged(
-                    self.pool, self._dtables, self._dlengths, np.int32(slot),
-                    table_row, jnp.asarray(k_pad), jnp.asarray(v_pad), np.int32(n_real), *scales,
-                )
-                self._lengths[slot] = n_real
-                if self._tel is not None:
-                    self._tel.on_scatter_in(st, t_scatter)
-                if st.resume is not None:
-                    self._bind_resume(st, slot)
-                else:
-                    self._bind_slot(st, slot, jnp.asarray(kv["logits"])[None])
-                return
-            self.pool = self._insert(
-                self.pool, table_row[: T_pad // page], jnp.asarray(k_pad), jnp.asarray(v_pad), *scales
+            # ONE fused scatter-in (llm/disagg/scatter.py): pool pages
+            # + device table row + device length lane in a single
+            # program — the handoff admission hot path
+            self.pool, self._dtables, self._dlengths = self._scatter_paged(
+                self.pool, self._dtables, self._dlengths, np.int32(slot),
+                table_row, jnp.asarray(k_pad), jnp.asarray(v_pad), np.int32(n_real), *scales,
             )
-            # a live-state restore ships no logits: the bind below
-            # splices instead of sampling a first token
-            logits = None if st.resume is not None else jnp.asarray(kv["logits"])[None]
             self._lengths[slot] = n_real
             if self._tel is not None:
                 self._tel.on_scatter_in(st, t_scatter)
+            # a live-state restore ships no logits: the bind below
+            # splices instead of sampling a first token
+            logits = None if st.resume is not None else jnp.asarray(kv["logits"])[None]
         else:
             k_p, v_p, n_p, k_sc, v_sc = pref
             m = n - n_p
@@ -2360,7 +2319,6 @@ class LLMEngine:
             )
             logits = logits[None]
             self._lengths[slot] = n
-        if self._device_resident:
             self._push_table(slot)
         if st.resume is not None:
             self._bind_resume(st, slot)
@@ -2374,9 +2332,8 @@ class LLMEngine:
 
         n = len(prompt)
         if st.prefilled is not None:
-            # disaggregated admission: KV arrived from a prefill engine.
-            # Device-resident mode scatters through the audited disagg
-            # program; the sync oracle keeps the legacy insert. An int8
+            # disaggregated admission: KV arrived from a prefill engine
+            # and scatters in through the audited disagg program. An int8
             # payload carries its wire-layout scales; producer/consumer
             # dtype mismatches requant transparently inside the program.
             kv = st.prefilled
@@ -2384,15 +2341,10 @@ class LLMEngine:
             t_scatter = time.time()
             k_sc, v_sc = kv.get("k_scale"), kv.get("v_scale")
             scales = (jnp.asarray(k_sc), jnp.asarray(v_sc)) if k_sc is not None else ()
-            if self._device_resident:
-                self.cache = self._scatter_slots(
-                    self.cache, np.int32(slot), jnp.asarray(kv["k"]), jnp.asarray(kv["v"]),
-                    np.int32(int(kv["n"])), *scales,
-                )
-            else:
-                self.cache = self._insert(
-                    self.cache, slot, jnp.asarray(kv["k"]), jnp.asarray(kv["v"]), int(kv["n"]), *scales
-                )
+            self.cache = self._scatter_slots(
+                self.cache, np.int32(slot), jnp.asarray(kv["k"]), jnp.asarray(kv["v"]),
+                np.int32(int(kv["n"])), *scales,
+            )
             if self._tel is not None:
                 self._tel.on_scatter_in(st, t_scatter)
             # a live-state restore ships no logits: the bind below
@@ -2441,13 +2393,14 @@ class LLMEngine:
         self._top_p[slot] = p.top_p
         if p.seed is not None:
             self._keys[slot] = np.asarray(jax.random.key_data(jax.random.PRNGKey(p.seed)))  # tpulint: disable=CCR002 — seeded lane key init: host PRNG material, one-time per admission
-        elif self._device_resident:
+        else:
             # the lane's key lives on device (advanced by every fused
             # step); pull its current value for the first-token sample.
             # This blocks on the not-yet-drained in-flight step if one is
-            # pending — the price of exact key parity with the sync
-            # oracle, paid only on seedless admissions and bounded by one
-            # step per admission (the prefill about to run dwarfs it).
+            # pending, on seedless admissions only and bounded by one step
+            # per admission. Nothing asks for this parity any more: the key
+            # may stay on the device or be drawn on the host (ROADMAP.md A4
+            # (a) and A4a take the wait away, and are judged on the chip).
             self._keys[slot] = np.asarray(self._dkeys[slot])  # tpulint: disable=CCR002 — documented first-sample key pull: bounded one pending step per seedless admission
         tok, logp, key = self._sample(
             logits,
@@ -2458,25 +2411,28 @@ class LLMEngine:
         )
         self._keys[slot] = np.asarray(key[0])  # tpulint: disable=CCR002 — post-sample key readback rides the prefill's own sync point
         token = int(tok[0])
-        if self._device_resident:
-            # lane delta: first input token, advanced key, sampling params
-            self._dtokens, self._dkeys, self._dtemps, self._dtopk, self._dtopp = self._set_lane(
-                self._dtokens,
-                self._dkeys,
-                self._dtemps,
-                self._dtopk,
-                self._dtopp,
-                np.int32(slot),
-                np.int32(token),
-                self._keys[slot],
-                np.float32(p.temperature),
-                np.int32(p.top_k),
-                np.float32(p.top_p),
-            )
+        self._push_lane(slot, token, p)  # first input token, advanced key, sampling params
         spec_hist = (st.prompt_token_ids + st.token_ids + [token]) if self._spec_cfg is not None else None
         self._emit(st, token, float(logp[0]))  # tpulint: disable=CCR002 — first-token emit: prefill output is already host-synced here
         if spec_hist is not None:
             self._spec_admit(st, slot, spec_hist)
+
+    def _push_lane(self, slot: int, token: int, p: SamplingParams):
+        """Lane delta: a slot's next input token, its key (``_keys[slot]``) and
+        sampling params into the device-resident decode state."""
+        self._dtokens, self._dkeys, self._dtemps, self._dtopk, self._dtopp = self._set_lane(
+            self._dtokens,
+            self._dkeys,
+            self._dtemps,
+            self._dtopk,
+            self._dtopp,
+            np.int32(slot),
+            np.int32(token),
+            self._keys[slot],
+            np.float32(p.temperature),
+            np.int32(p.top_k),
+            np.float32(p.top_p),
+        )
 
     def _bind_resume(self, st: RequestState, slot: int):
         """Splice a restored live-state request into the decode loop
@@ -2503,21 +2459,7 @@ class LLMEngine:
         # oracle's post-splice draws continue that sequence
         self._keys[slot] = np.asarray(rs["rng_key"], np.uint32)  # tpulint: disable=CCR002 — checkpoint splice: rs is host state from llm/migrate.py, not a device array
         token = int(st.token_ids[-1])
-        self._next_tokens[slot] = token
-        if self._device_resident:
-            self._dtokens, self._dkeys, self._dtemps, self._dtopk, self._dtopp = self._set_lane(
-                self._dtokens,
-                self._dkeys,
-                self._dtemps,
-                self._dtopk,
-                self._dtopp,
-                np.int32(slot),
-                np.int32(token),
-                self._keys[slot],
-                np.float32(p.temperature),
-                np.int32(p.top_k),
-                np.float32(p.top_p),
-            )
+        self._push_lane(slot, token, p)
         if self._spec_cfg is not None:
             spec = rs.get("spec") or {}
             self._controller.restore(st.request_id, spec.get("ema"), spec.get("k"))
@@ -2594,8 +2536,6 @@ class LLMEngine:
             self._tel.on_emit(st)
         if st.out_queue is not None:
             st.out_queue.put(token)
-        if st.slot >= 0:
-            self._next_tokens[st.slot] = token
         if token in st.params.stop_token_ids:
             self._finish(st, "stop")
         elif len(st.token_ids) >= st.params.max_tokens:
@@ -2605,9 +2545,9 @@ class LLMEngine:
         """Admit what fits, advance decode one step, return per-request
         deltas.
 
-        Device-resident mode (default): the fused jitted step is
-        DISPATCHED before the previous step's tokens are read back, so
-        step N's host transfer overlaps step N+1's device compute —
+        The fused jitted step is DISPATCHED before the previous step's
+        tokens are read back, so step N's host transfer overlaps step
+        N+1's device compute —
         emission (streaming tokens, finish detection, slot recycling)
         trails the device by exactly one step, and each sequence runs up
         to one discarded trailing step. Under speculation that trailing
@@ -2650,9 +2590,8 @@ class LLMEngine:
             raise
 
     def _stage_decode(self, admitted: list, tel) -> list:
-        """DECODE stage: advance every occupied slot one tick. Device-
-        resident mode dispatches the fused (or speculative) step and
-        drains the PREVIOUS one; sync mode is the blocking oracle loop.
+        """DECODE stage: advance every occupied slot one tick: dispatch
+        the fused (or speculative) step and drain the PREVIOUS one.
         Prefill-only requests never reach here — they finished (and freed
         their slot) inside the prefill stage. Three telemetry stages:
         dispatch (host time to enqueue), drain_wait (the host blocked on
@@ -2661,29 +2600,20 @@ class LLMEngine:
         with stage(tel, "llm.step.dispatch"):
             if self.kv_layout == "paged":
                 self._paged_grow()
-            if self._device_resident:
-                prev = self._pending
-                self._pending = None
-                if spec:
-                    self._dispatch_spec(prev)
-                else:
-                    self._dispatch_fused(prev)
-                if tel is not None and self._pending is not None:
-                    tel.dispatch_t = time.time()
-        if self._device_resident:
-            with stage(tel, "llm.step.drain_wait"):
-                host = self._drain_wait(prev)
-            with stage(tel, "llm.step.emit"):
-                emitted = self._drain_spec(prev, host) if spec else self._drain(prev, host)
-            self._step_emitted = len(emitted)
-            return admitted + emitted
-        # sync mode: every active lane (just-admitted ones included)
-        # emitted a token this step — the returned list IS the emit set
-        # (its in-step readback and emission are one stage, drain_wait)
+            prev = self._pending
+            self._pending = None
+            if spec:
+                self._dispatch_spec(prev)
+            else:
+                self._dispatch_fused(prev)
+            if tel is not None and self._pending is not None:
+                tel.dispatch_t = time.time()
         with stage(tel, "llm.step.drain_wait"):
-            reported = self._sync_decode()
-        self._step_emitted = len(reported)
-        return reported
+            host = self._drain_wait(prev)
+        with stage(tel, "llm.step.emit"):
+            emitted = self._drain_spec(prev, host) if spec else self._drain(prev, host)
+        self._step_emitted = len(emitted)
+        return admitted + emitted
 
     def _lane_mask(self, active: list) -> np.ndarray:
         """[slots] bool, true where a lane is bound to a live sequence: a hybrid's step keeps
@@ -2908,49 +2838,6 @@ class LLMEngine:
                 int(sum(int(acc[entry[1]]) for entry in lanes)),
             )
         return emitted
-
-    def _sync_decode(self) -> list:
-        """The synchronous host-driven step (device_resident=False): full
-        re-upload of scheduler state, blocking readback before return.
-        Kept as the decode-equivalence oracle and host-debug mode."""
-        import jax.numpy as jnp
-
-        active = [s for s in self._slots if s is not None]
-        if not active:
-            return []
-        if self.kv_layout == "paged":
-            logits, self.pool, _ = self._decode(
-                self.params,
-                self.pool,
-                jnp.asarray(self._tables),
-                jnp.asarray(self._lengths),
-                jnp.asarray(self._next_tokens),
-            )
-        elif self._hybrid:
-            logits, self.cache, self.state, moe = self._decode(
-                self.params, self.cache, self.state, jnp.asarray(self._next_tokens), self._lane_mask(active))
-            self._moe_stats = np.asarray(moe)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
-        else:
-            logits, self.cache = self._decode(self.params, self.cache, jnp.asarray(self._next_tokens))
-        toks, logps, keys = self._sample(
-            logits,
-            jnp.asarray(self._keys),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._top_k),
-            jnp.asarray(self._top_p),
-        )
-        toks = np.asarray(toks)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
-        logps = np.asarray(logps)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
-        self._keys = np.array(keys)  # tpulint: disable=CCR002 — sync mode: the whole point is an in-step readback
-        if self.kv_layout == "paged":
-            # only after the readbacks above: on the CPU backend jnp.asarray
-            # aliases the host array, and the dispatched decode reads
-            # self._lengths until it has run
-            for st in active:
-                self._lengths[st.slot] += 1
-        for st in active:
-            self._emit(st, int(toks[st.slot]), float(logps[st.slot]))  # tpulint: disable=CCR002 — sync mode: reads the just-synced host array
-        return active
 
     def _build_outputs(self, reported: list) -> list[RequestOutput]:  # holds-lock: _lock
         """Per-request deltas for everything that changed this step."""

@@ -150,18 +150,14 @@ def fused_step(params, cache, state, tokens, keys, temps, top_k, top_p, active, 
     return cache, state, toks, logps, moe, new_keys, temps, top_k, top_p
 
 
-def make_hybrid_fns(cfg, device_resident: bool):
-    """Jitted (prefill, kv insert, state insert, step) for an engine; ``step`` is the fused step
-    of the device-resident loop, or the plain decode step of the synchronous oracle loop."""
+def make_hybrid_fns(cfg):
+    """Jitted (prefill, kv insert, state insert, fused step) for an engine."""
     from ray_tpu.llm import kv_cache as kvc
 
     prefill_fn = named_jit("llm_hybrid_prefill", partial(prefill, cfg=cfg))
     insert_fn = named_jit("llm_kv_insert", scoped("cache", kvc.insert_entries), donate_argnums=(0,))
     state_insert_fn = named_jit("llm_state_insert", scoped("cache", state_cache.insert_state), donate_argnums=(0,))
-    if device_resident:
-        step_fn = named_jit("llm_hybrid_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
-    else:
-        step_fn = named_jit("llm_hybrid_decode_step", partial(decode_step, cfg=cfg), donate_argnums=(1, 2))
+    step_fn = named_jit("llm_hybrid_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
     return prefill_fn, insert_fn, state_insert_fn, step_fn
 
 

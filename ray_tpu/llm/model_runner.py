@@ -53,14 +53,14 @@ from ray_tpu.util.profiling import SCOPES, scope, scoped  # noqa: F401 - SCOPES:
 # ``prefill`` in its name whatever its bucket, and nothing else has either.
 # ---------------------------------------------------------------------------
 STEP_PROGRAM_NAMES = frozenset({
-    "llm_prefill", "llm_kv_insert", "llm_decode_step", "llm_extend",  # slot layout
-    "llm_kv_insert_pages", "llm_paged_attn", "llm_kv_append",  # paged layout
+    "llm_prefill", "llm_kv_insert", "llm_extend",  # slot layout
+    "llm_kv_insert_pages", "llm_kv_append",  # paged layout
     "llm_extend_paged_attn", "llm_kv_append_chunk",
-    "llm_fused_step", "llm_fused_paged_step",  # device-resident decode (one per layout)
+    "llm_fused_step", "llm_fused_paged_step",  # the decode step (one per layout)
     "llm_verify_step", "llm_verify_paged_attn", "llm_verify_append",  # speculative verify
     "llm_draft_propose", "llm_draft_prefill", "llm_draft_kv_insert", "llm_draft_steps",
     # hybrid models (llm/hybrid_runner.py): recurrent state beside the slot KV rows
-    "llm_hybrid_prefill", "llm_state_insert", "llm_hybrid_fused_step", "llm_hybrid_decode_step",
+    "llm_hybrid_prefill", "llm_state_insert", "llm_hybrid_fused_step",
 })
 
 
@@ -274,11 +274,6 @@ def _bucket_prefill(B=8, T=128):
     return (_sds_params(cfg), _sds((B, T), jnp.int32), _sds((B,), jnp.int32), cfg), {}
 
 
-def _bucket_decode(B=8, S=256):
-    cfg = _trace_cfg()
-    return (_sds_params(cfg), _sds_cache(cfg, B, S), _sds((B,), jnp.int32), cfg), {}
-
-
 def _bucket_fused(B=8, S=256):
     cfg = _trace_cfg()
     return (_sds_params(cfg), _sds_cache(cfg, B, S)) + _sds_lanes(B) + (cfg,), {}
@@ -402,11 +397,6 @@ def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
     return logits, ks, vs
 
 
-@jaxcheck.entry(
-    name="llm.decode_step",
-    shapes={"b8_s256": _bucket_decode},
-    donate=("cache",),
-)
 def decode_step(params, cache, tokens, cfg: LlamaConfig, tpc: TpSpec | None = None, live=None,
                 partitioned: bool = False):
     """Advance every slot one token.
@@ -1128,39 +1118,30 @@ def paged_fused_step_tp(
 
 
 def make_runner_fns(cfg: LlamaConfig, mesh=None):
-    """Jitted (prefill, insert, decode, extend) closures for an engine."""
+    """Jitted (prefill, insert, extend) closures for an engine; the decode step is make_fused_fns'."""
     from ray_tpu.llm import kv_cache as kvc
 
     prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
     insert_fn = named_jit("llm_kv_insert", scoped("cache", kvc.insert_sequence), donate_argnums=(0,))
-    decode_fn = named_jit("llm_decode_step", partial(decode_step, cfg=cfg, partitioned=mesh is not None),
-                          donate_argnums=(1,))
     extend_fn = named_jit("llm_extend", partial(extend, cfg=cfg), donate_argnums=(1,))
-    return prefill_fn, insert_fn, decode_fn, extend_fn
+    return prefill_fn, insert_fn, extend_fn
 
 
 def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla", mesh=None):
-    """Jitted (prefill, insert_pages, decode, extend) for a paged engine.
+    """Jitted (prefill, insert_pages, extend) for a paged engine; the
+    decode step is make_fused_paged_fns'.
 
-    Decode/extend each compile as TWO programs — read-only attention and
+    Extend compiles as TWO programs — read-only attention and
     scatter-only append — never fused (jitting the combined wrapper would
     reintroduce the same-program gather+scatter aliasing hazard; see
     decode_attn_paged). ``attn_impl`` selects the page-attention body of
-    both read-only halves ("xla" oracle / "pallas" fused kernel)."""
+    the read-only half ("xla" oracle / "pallas" fused kernel)."""
     from ray_tpu.llm import paged_kv as pkv
 
     prefill_fn = named_jit("llm_prefill", partial(prefill, cfg=cfg, mesh=mesh))
     insert_fn = named_jit("llm_kv_insert_pages", scoped("cache", pkv.insert_pages), donate_argnums=(0,))
-    attn_fn = named_jit("llm_paged_attn", partial(decode_attn_paged, cfg=cfg, attn_impl=attn_impl))
-    append_fn = named_jit("llm_kv_append", append_paged, donate_argnums=(0,))
     ext_attn_fn = named_jit("llm_extend_paged_attn", partial(extend_attn_paged, cfg=cfg, attn_impl=attn_impl))
     ext_append_fn = named_jit("llm_kv_append_chunk", append_chunk_paged, donate_argnums=(0,))
-
-    def decode_fn(params, pool, tables, lengths, tokens):
-        write_page, write_off = decode_write_targets(tables, lengths, pool["k"].shape[2])
-        logits, k_new, v_new = attn_fn(params, pool, tables, lengths, tokens)
-        pool = append_fn(pool, write_page, write_off, k_new, v_new)
-        return logits, pool, lengths + 1
 
     def extend_fn(params, pool, table_row, start, tokens, length):
         write_page, write_off = extend_write_targets(table_row, start, tokens.shape[0], pool["k"].shape[2])
@@ -1168,4 +1149,4 @@ def make_paged_runner_fns(cfg: LlamaConfig, attn_impl: str = "xla", mesh=None):
         pool = ext_append_fn(pool, write_page, write_off, k_chunk, v_chunk)
         return logits, pool
 
-    return prefill_fn, insert_fn, decode_fn, extend_fn
+    return prefill_fn, insert_fn, extend_fn

@@ -738,9 +738,9 @@ class EngineTelemetry:
             self._span(st, "llm.prefill", t_prefill_start, now)
 
     def on_emit(self, st, now: float | None = None) -> None:
-        """One token reached the host (the one-step-delayed drain, or the
-        sync oracle's readback — either way this is when a consumer could
-        see it). First token observes TTFT; later ones observe ITL."""
+        """One token reached the host (the first token's sample at
+        admission, or the one-step-delayed drain — either way this is
+        when a consumer could see it). First token observes TTFT; later ones observe ITL."""
         now = time.time() if now is None else now
         if st.t_first == 0.0:
             st.t_first = now
@@ -973,7 +973,7 @@ class EngineTelemetry:
             self.itl_ema_s = g if self.itl_ema_s == 0.0 else 0.9 * self.itl_ema_s + 0.1 * g
             self._gap_sum, self._gap_n = 0.0, 0
 
-        if slots_in_use and eng._device_resident and self._wire_bytes_per_step:
+        if slots_in_use and self._wire_bytes_per_step:
             # accumulate locally (one float add), flush on sample ticks
             self._wire_accum += self._wire_bytes_per_step
         if not sample:
@@ -1021,7 +1021,6 @@ class EngineTelemetry:
                 "kv_layout": eng.kv_layout,
                 "kv_dtype": str(eng.kv_dtype),
                 "max_num_seqs": eng.max_num_seqs,
-                "device_resident": eng._device_resident,
                 # what the cache holds for a position, and of which entries it is made
                 "kv_bytes_per_token": eng.kv_bytes_per_token(),
                 "kv_entries": eng.kv_entries(),

@@ -1,6 +1,6 @@
 """What every description over the ONE hybrid loop (``models/hybrid.py``, ``llm/hybrid_runner.py``)
 is held to, written once: the sequence form against the family's plain reference, prefill then
-decode through the engine against it, the synchronous loop as the fused step's oracle, the
+decode through the engine against it, a seeded lane's stream the same alone and beside others, the
 comparison failing each planted fault, the OpenAI server streaming, every refusal by its name.
 
 pytest does not collect this module. A description's file states a ``Description`` as ``DESC``,
@@ -158,13 +158,28 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(desc, para
         assert 0 < r["prefill_experts_hit"] <= s.held
 
 
-def test_the_synchronous_loop_is_the_fused_steps_oracle(desc, params, eng):
-    ps = prompts(desc, 3, (9, 30, 14, 47, 22))
-    sp = SamplingParams(max_tokens=7, temperature=0.0, logprobs=True)
-    a = eng.generate(ps, sp)
-    b = engine(desc.cfg, params, device_resident=False).generate(ps, sp)
-    assert [o.token_ids for o in a] == [o.token_ids for o in b]
-    np.testing.assert_allclose([o.logprobs for o in a], [o.logprobs for o in b], atol=desc.tol / 100)
+def test_a_seeded_lane_draws_one_stream_alone_and_beside_others_in_a_recycled_slot(desc, params, eng):
+    """Lanes are independent under sampling: a request with ``seed=`` and a temperature draws the
+    same stream served alone and served again behind more requests than there are slots, so that it
+    is admitted into a slot another sequence left while its neighbours, greedy and stochastic, are
+    in the middle of theirs. Its key starts at the seed and advances with its own tokens alone."""
+    p, others = prompts(desc, 3, (22,))[0], prompts(desc, 6, (9, 30, 14, 47, 25, 12))
+    sp = SamplingParams(max_tokens=9, temperature=0.9, top_p=0.95, seed=41, logprobs=True)
+    alone = eng.generate(p, sp)
+    sps = [SamplingParams(max_tokens=4 + 3 * i, temperature=0.7 * (i % 2), seed=i) for i in range(len(others))]
+    ids = [eng.add_request(q, s) for q, s in zip(others[:5] + [p] + others[5:], sps[:5] + [sp] + sps[5:])]
+    beside, neighbours = None, 0
+    while eng.has_unfinished():
+        for o in eng.step():
+            if o.request_id == ids[5] and o.finished:
+                beside = o
+        st = eng._requests.get(ids[5])
+        if st is not None and st.slot >= 0:
+            neighbours = max(neighbours, eng.num_running - 1)
+    assert neighbours >= 2, "the lane never ran beside others"  # and its slot was another's: the first four filled all four
+    assert beside.token_ids == alone.token_ids and len(set(alone.token_ids)) > 1
+    np.testing.assert_allclose(beside.logprobs, alone.logprobs, atol=desc.tol / 100)
+    assert check(desc, params, served([beside], [p], [sp]))["ok"]
 
 
 def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(desc, eng):
@@ -241,7 +256,7 @@ def test_serves_through_the_openai_server_streaming(desc, params):
 
     srv = OpenAIServer(LLMConfig(model_config=desc.cfg, params=params, model_id="toy-description", engine_kwargs=dict(ENGINE_KW)))
     try:
-        assert srv.engine._hybrid and srv.engine._device_resident
+        assert srv.engine._hybrid
         p = prompts(desc, 5, (26,))[0]
         chunks = list(srv({"prompt": p, "max_tokens": 6, "stream": True}))
         assert chunks[-1].startswith("data: [DONE]") and len(chunks) >= 7

@@ -6,14 +6,16 @@ Reference test strategy modeled on python/ray/llm tests (engine behavior)
 full-recompute greedy decoding token for token.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
+import plain_reference  # noqa: E402
 
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, forward, init_params  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=128)
 
@@ -29,18 +31,7 @@ def eng(params):
     return LLMEngine(CFG, params, max_num_seqs=2, max_seq_len=64)
 
 
-_padded_forward = jax.jit(lambda params, toks: forward(params, toks, CFG))
-
-
-def full_forward_greedy(params, prompt, n_tokens):
-    """Oracle: recompute the whole sequence every step, argmax last logit. The sequence is padded
-    to one length (a causal model's logits at a position do not see what follows it), so the
-    forward compiles once and not once a length."""
-    toks = list(prompt)
-    for _ in range(n_tokens):
-        logits = _padded_forward(params, jnp.asarray([toks + [0] * (CFG.max_seq_len - len(toks))]))
-        toks.append(int(jnp.argmax(logits[0, len(toks) - 1])))
-    return toks[len(prompt):]
+full_forward_greedy = partial(plain_reference.full_forward_greedy, CFG)  # the oracle: whole-sequence greedy, of this file's toy configuration
 
 
 def test_greedy_decode_matches_full_forward(params, eng):

@@ -1,13 +1,15 @@
 """Disaggregated prefill/decode serving (llm/disagg/): token-identity
-against the single-engine oracle, handoff codec validation, and the
+against the plain reference, handoff codec validation, and the
 router's bounded failure policy.
 
-The sync single-engine loop is the oracle: a prefill engine extracting
-handoff blocks + a device-resident decode engine scattering them in must
-emit exactly the tokens the oracle emits, for both KV layouts, under
-admission / eviction / preemption / abort, greedy and seeded sampling,
-with speculative decoding composing on the decode side
-(tests mirror tests/test_llm_device_resident.py's methodology).
+The plain reference (tests/plain_reference.py: a whole-sequence forward,
+no cache) is the oracle: a prefill engine extracting handoff blocks + a
+decode engine scattering them in must emit exactly the tokens it
+computes, for both KV layouts, under admission / eviction / preemption /
+abort, greedy and seeded sampling, with speculative decoding composing
+on the decode side (tests mirror tests/test_llm_decode_loop.py's
+methodology). An int8 cache is held to one int8 engine that prefills
+locally: the reference keeps no cache to quantize.
 
 Lean by design (tier-1 budget): one module-scoped prefill engine feeds
 every layout's decode test through the codec round-trip.
@@ -19,6 +21,8 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+
+from plain_reference import drive, reference_stream  # noqa: E402
 
 import ray_tpu  # noqa: E402
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
@@ -55,24 +59,15 @@ def _ship(prefill_eng, prompt):
     return decode_handoff(encode_handoff(prefill_eng.prefill_handoff(prompt)))
 
 
-def _drive(eng, schedule, aborts=None, max_steps=800):
-    """Step an engine over {step: [(admit_fn, rid_key)]} admissions;
-    returns ({key: tokens}, {key: reason})."""
-    finals, reasons, ids = {}, {}, {}
-    last_t = max(schedule)
-    t = 0
-    while t <= last_t or eng.has_unfinished():
-        for admit, key in schedule.get(t, []):
-            ids[admit()] = key
-        if aborts and t in aborts:
-            eng.abort_request([r for r, kk in ids.items() if kk == aborts[t]][0])
-        for o in eng.step():
-            if o.finished and o.request_id in ids:
-                finals[ids[o.request_id]] = o.token_ids
-                reasons[ids[o.request_id]] = o.finish_reason
-        t += 1
-        assert t < max_steps, "schedule never converged"
-    return finals, reasons
+def _streams(eng, reqs, admit, aborts=None):
+    """Drive ``eng`` over ``reqs`` [(prompt, sampling, step)], request i admitted by ``admit(i)``;
+    ``aborts``: {step: index into reqs}. Returns ({i: tokens}, {i: reason})."""
+    order = sorted(range(len(reqs)), key=lambda i: reqs[i][2])  # drive's ordinals: by step, then as listed
+    sched = {}
+    for i in order:
+        sched.setdefault(reqs[i][2], []).append(lambda i=i: admit(i))
+    finals, reasons = drive(eng, sched, aborts and {t: order.index(i) for t, i in aborts.items()})
+    return {order[o]: toks for o, toks in finals.items()}, {order[o]: why for o, why in reasons.items()}
 
 
 def _mk_schedule(rng, n_req, max_len=90, max_tok=12):
@@ -86,52 +81,51 @@ def _mk_schedule(rng, n_req, max_len=90, max_tok=12):
     return reqs
 
 
-def _oracle_streams(params, reqs, engine_kwargs, aborts=None):
-    """The single-engine sync oracle over the same request set."""
-    eng = LLMEngine(CFG, params=params, device_resident=False, **engine_kwargs)
-    sched = {}
-    for i, (prompt, sp, t) in enumerate(reqs):
-        sched.setdefault(t, []).append((lambda p=prompt, s=sp: eng.add_request(p, s), i))
-    return _drive(eng, sched, aborts)
+def _reference_streams(params, reqs):
+    """The plain reference over the same request set: ({i: tokens}, {i: reason})."""
+    both = [reference_stream(CFG, params, prompt, sp) for prompt, sp, _ in reqs]
+    return {i: toks for i, (toks, _) in enumerate(both)}, {i: why for i, (_, why) in enumerate(both)}
+
+
+def _single_engine_streams(params, reqs, engine_kwargs):
+    """One engine that prefills locally, over the same request set: what an int8 cache is held to
+    (the plain reference keeps no cache, so it says nothing of a quantized one)."""
+    eng = LLMEngine(CFG, params=params, **engine_kwargs)
+    return _streams(eng, reqs, lambda i: eng.add_request(reqs[i][0], reqs[i][1]))
 
 
 def _disagg_streams(params, prefill_eng, reqs, engine_kwargs, aborts=None, speculative=None):
-    """Prefill engine -> codec -> device-resident decode engine."""
-    dec = LLMEngine(CFG, params=params, device_resident=True, speculative=speculative, **engine_kwargs)
+    """Prefill engine -> codec -> decode engine."""
+    dec = LLMEngine(CFG, params=params, speculative=speculative, **engine_kwargs)
     handoffs = {i: _ship(prefill_eng, prompt) for i, (prompt, _, _) in enumerate(reqs)}
-    sched = {}
-    for i, (_, sp, t) in enumerate(reqs):
-        sched.setdefault(t, []).append((lambda kv=handoffs[i], s=sp: dec.add_prefilled(kv, s), i))
-    finals, reasons = _drive(dec, sched, aborts)
+    finals, reasons = _streams(dec, reqs, lambda i: dec.add_prefilled(handoffs[i], reqs[i][1]), aborts)
     return finals, reasons, dec
 
 
 def test_disagg_slots_token_identity_with_abort(params, prefill_eng):
-    """Slots decode engine fed by handoffs == sync single-engine oracle,
+    """Slots decode engine fed by handoffs == the plain reference,
     greedy + seeded sampling, with one mid-flight abort riding along."""
     reqs = _mk_schedule(np.random.default_rng(0), 4)
     kw = dict(max_num_seqs=3, max_seq_len=128, enable_prefix_caching=False)
     aborts = {5: 0}  # abort the first request mid-decode
-    sync, sync_r = _oracle_streams(params, reqs, kw, aborts)
+    ref, ref_r = _reference_streams(params, reqs)
     dis, dis_r, _ = _disagg_streams(params, prefill_eng, reqs, kw, aborts)
-    assert set(sync) == set(dis)
-    for key in sync:
-        if sync_r[key] == "aborted":
-            # aborts are host-timed: the two architectures cut the stream
-            # at (up to one token) different points; the surviving prefix
-            # must still be identical
-            n = min(len(sync[key]), len(dis[key]))
-            assert dis[key][:n] == sync[key][:n]
+    assert set(ref) == set(dis)
+    for key in ref:
+        if dis_r[key] == "aborted":
+            # an abort is host-timed: it cuts the stream, and what
+            # survives is a prefix of the reference's
+            assert dis[key] == ref[key][: len(dis[key])] and len(dis[key]) < len(ref[key])
         else:
-            assert dis[key] == sync[key], f"req {key}: disagg {dis[key]} != oracle {sync[key]}"
-            assert dis_r[key] == sync_r[key]
-    assert "aborted" in set(sync_r.values())
+            assert dis[key] == ref[key], f"req {key}: disagg {dis[key]} != reference {ref[key]}"
+            assert dis_r[key] == ref_r[key]
+    assert "aborted" in set(dis_r.values())
 
 
 def test_disagg_paged_token_identity_under_preemption(params, prefill_eng):
     """Paged decode engine with a pool too small for the load: handoff
     admissions + growth preemption (recompute re-prefill ON the decode
-    replica, vLLM semantics) still emit oracle-identical greedy tokens."""
+    replica, vLLM semantics) still emit the plain reference's greedy tokens."""
     rng = np.random.default_rng(1)
     reqs = []
     for i in range(4):
@@ -141,11 +135,11 @@ def test_disagg_paged_token_identity_under_preemption(params, prefill_eng):
         max_num_seqs=3, max_seq_len=256, kv_layout="paged", page_size=32,
         num_pages=8, enable_prefix_caching=False,
     )
-    sync, sync_r = _oracle_streams(params, reqs, kw)
+    ref, ref_r = _reference_streams(params, reqs)
     dis, dis_r, dec = _disagg_streams(params, prefill_eng, reqs, kw)
-    for key in sync:
-        assert dis[key] == sync[key], f"req {key}: disagg {dis[key]} != oracle {sync[key]}"
-    assert dis_r == sync_r
+    for key in ref:
+        assert dis[key] == ref[key], f"req {key}: disagg {dis[key]} != reference {ref[key]}"
+    assert dis_r == ref_r
     assert dec.preemption_count > 0, "schedule never exercised decode-side preemption"
     assert dec._page_alloc.free_pages == dec._pcfg.num_pages - 1  # pool drained clean
 
@@ -244,8 +238,8 @@ def prefill_eng_q8(params):
 
 
 def test_disagg_int8_token_identity(params, prefill_eng_q8):
-    """Int8 producer -> codec -> int8 device-resident consumer emits
-    exactly what the int8 single-engine sync oracle emits (greedy): the
+    """Int8 producer -> codec -> int8 consumer emits exactly what one
+    int8 engine that prefills locally emits (greedy): the
     quantized bytes that leave the producer are the bytes a local
     prefill would have written, so the streams are bit-for-bit the same
     cache state."""
@@ -254,9 +248,9 @@ def test_disagg_int8_token_identity(params, prefill_eng_q8):
         ([9, 10, 11] * 5, SamplingParams(max_tokens=6, temperature=0.0), 1),
     ]
     kw = dict(max_num_seqs=2, max_seq_len=128, enable_prefix_caching=False, cache_dtype="int8")
-    sync, sync_r = _oracle_streams(params, reqs, kw)
+    local, local_r = _single_engine_streams(params, reqs, kw)
     dis, dis_r, _ = _disagg_streams(params, prefill_eng_q8, reqs, kw)
-    assert dis == sync and dis_r == sync_r
+    assert dis == local and dis_r == local_r
 
 
 def test_handoff_codec_validates_quantized_scales(prefill_eng_q8):
@@ -309,7 +303,7 @@ def test_disagg_cross_dtype_requants_transparently(params, prefill_eng, prefill_
     sp = SamplingParams(max_tokens=6, temperature=0.0)
     kw = dict(max_num_seqs=2, max_seq_len=128, enable_prefix_caching=False)
     reqs = [(prompt, sp, 0)]
-    oracle_q8, _ = _oracle_streams(params, reqs, {**kw, "cache_dtype": "int8"})
+    oracle_q8, _ = _single_engine_streams(params, reqs, {**kw, "cache_dtype": "int8"})
 
     # fp producer -> int8 consumer: quantize-on-scatter == local prefill
     dis, _, _ = _disagg_streams(params, prefill_eng, reqs, {**kw, "cache_dtype": "int8"})

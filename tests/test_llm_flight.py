@@ -513,13 +513,19 @@ def test_serve_shutdown_stops_replicas_before_it_kills_them(tmp_path):
             rids.append(json.loads(chunks[0][6:])["id"])
         t0 = time.time()
         serve.shutdown()
-        assert time.time() - t0 < 10.0
+        # What is held is the ORDER of events: ``ray_tpu.kill`` is SIGTERM with no handler, so whatever a replica wrote,
+        # it wrote before it was killed: the hook's marker, the flight log and the last spans below say that stop came
+        # before kill. The clock says only that shutdown rode out none of its waits twice: 10 s, which stood here, is the
+        # call's OWN budget for the controller's ``graceful_shutdown`` (in it 8 s a drain and a second of slack).
+        assert time.time() - t0 < 30.0
         assert open(marker).read() == "clean"
         log = telemetry.load_flight()
-        served = {r["request_id"]: r for r in log["requests"]}
-        # some log is the replica's own (files merge in the order of their names, pids compared as text:
-        # which header comes last says nothing)
-        assert set(rids) <= set(served) and any(h["pid"] != os.getpid() for h in log["headers"])
+        # The session's directory holds the logs of every engine this process has had, each with a ``req-0`` of its own,
+        # and files merge in the order of their names, pids compared as text: which log comes last says nothing (a
+        # record of an earlier test's, without a stream's stamps, stood in for the replica's whenever this process's pid
+        # sorted after the replica's: the driver's whole runs of PRs 42-47). The requests served here finished last.
+        served = {r["request_id"]: r for r in sorted(log["requests"], key=lambda r: r["finish_t"])}
+        assert set(rids) <= set(served) and all(served[r]["pid"] != os.getpid() for r in rids)
         assert all(served[r]["ingress_t"] and served[r]["last_yield_t"] for r in rids)
         # the worker's span file: the last request's spans, the stream's among them, reached the disk
         names = {s["name"] for s in tracing.load_spans() if s["attrs"].get("request_id") == rids[-1]}

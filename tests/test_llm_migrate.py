@@ -19,6 +19,8 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from plain_reference import reference_stream  # noqa: E402
+
 import ray_tpu  # noqa: E402
 from ray_tpu import chaos  # noqa: E402
 from ray_tpu.exceptions import ObjectLostError  # noqa: E402
@@ -155,20 +157,17 @@ def test_cross_layout_migration(params):
     assert toks == want
 
 
-def test_sync_oracle_engine_migration(params):
-    """The synchronous host-driven loop (device_resident=False)
-    checkpoints and restores identically — the equivalence oracle for
-    the device-resident splice."""
-    want, pre, toks = _migrate_mid_decode(
-        params, GREEDY, "slots", None, spec=False, wire=False,
-    )
-    src = _mk(params, device_resident=False)
-    rid = src.add_request(list(PROMPT), GREEDY)
-    _run_until(src, rid, 6)
-    state = src.checkpoint_request(rid)
-    dst = _mk(params, device_resident=False)
-    toks_sync = _finish(dst, dst.restore_request(state))
-    assert toks_sync == want == toks
+def test_a_restored_stream_continues_the_plain_references(params):
+    """Checkpointed mid-decode and restored on a second engine, a stream
+    goes on as the plain reference's (tests/plain_reference.py: the
+    whole-sequence forward with no cache, a seeded lane's key chain from
+    its seed) — everywhere else in this file the splice is compared with
+    another engine's stream."""
+    for sp in (GREEDY, SEEDED):
+        _, pre, toks = _migrate_mid_decode(params, sp, "slots", None, spec=False)
+        want, _ = reference_stream(CFG, params, PROMPT, sp)
+        assert toks == want, f"temp={sp.temperature}: restored {toks} != reference {want}"
+        assert 0 < len(pre) < len(toks) and toks[: len(pre)] == pre
 
 
 def test_spec_controller_state_migrates(params):

@@ -22,10 +22,9 @@ from ray_tpu.util.profiling import SCOPES, UNSCOPED, scope, scope_of  # noqa: E4
 HEAVY = ("stablehlo.dot_general", "stablehlo.convolution", "stablehlo.custom_call", "stablehlo.scatter",
          "stablehlo.gather", "stablehlo.dynamic_update_slice")
 
-SLOTS = ["llm_prefill", "llm_kv_insert", "llm_fused_step", "llm_extend", "llm_decode_step"]
-PAGED = ["llm_kv_insert_pages", "llm_fused_paged_step", "llm_kv_append", "llm_paged_attn", "llm_extend_paged_attn",
-         "llm_kv_append_chunk"]
-HYBRID = ["llm_hybrid_prefill", "llm_kv_insert", "llm_state_insert", "llm_hybrid_fused_step", "llm_hybrid_decode_step"]
+SLOTS = ["llm_prefill", "llm_kv_insert", "llm_fused_step", "llm_extend"]
+PAGED = ["llm_kv_insert_pages", "llm_fused_paged_step", "llm_kv_append", "llm_extend_paged_attn", "llm_kv_append_chunk"]
+HYBRID = ["llm_hybrid_prefill", "llm_kv_insert", "llm_state_insert", "llm_hybrid_fused_step"]
 PROGRAMS = {"llama": SLOTS, "llama_paged": PAGED, "nemotron_h": HYBRID, "qwen3_next": HYBRID,
             "glm4_moe_lite": [p for p in HYBRID if p != "llm_state_insert"],  # latent attention keeps nothing per sequence
             "kimi_linear": HYBRID,  # a state a sequence AND a latent a position
@@ -80,7 +79,7 @@ def lowered(shared_step_programs):
         if description in done:
             return done[description]
         sink: dict = {}
-        real = model_runner.named_jit  # conftest's memo: the synchronous engine's prefill IS the fused engine's, compiled once
+        real = model_runner.named_jit
 
         def recording(name, fn, **kw):
             return Recording(name, real(name, fn, **kw), sink)
@@ -98,11 +97,10 @@ def lowered(shared_step_programs):
                 kw.update(enable_prefix_caching=True, prefix_block=16)
             prompt = list(np.random.default_rng(0).integers(1, cfg.vocab_size - 1, size=40))
             sp = SamplingParams(max_tokens=3)
-            for device_resident in (True, False):
-                eng = LLMEngine(cfg, device_resident=device_resident, **kw)
-                eng.generate([prompt, prompt[:9]], sp)
-                if not hybrid_model:
-                    eng.generate([prompt[:33] + [5, 6, 7]], sp)  # a cached prefix: the suffix goes through extend
+            eng = LLMEngine(cfg, **kw)
+            eng.generate([prompt, prompt[:9]], sp)
+            if not hybrid_model:
+                eng.generate([prompt[:33] + [5, 6, 7]], sp)  # a cached prefix: the suffix goes through extend
         finally:
             patch.undo()
         done[description] = sink
@@ -228,3 +226,19 @@ def test_the_table_says_a_role_for_every_name_and_sub_scopes_name_their_kind():
     assert scope_of("jit(llm_fused_step)/jit(main)/while/body/attn/cache/scatter") == "cache"
     assert scope_of("jit(set_lane)/jit(main)/scatter") == UNSCOPED
     assert model_runner.SCOPES is SCOPES  # the table a reader finds beside STEP_PROGRAM_NAMES
+
+
+def test_step_program_names_holds_the_names_that_are_jitted_and_no_other():
+    """Every name of ``STEP_PROGRAM_NAMES`` is the literal first argument of a ``named_jit`` call somewhere in the
+    package, and every such literal is in the table: a program deleted with its name left behind, or jitted under a
+    name the table lacks (which ``named_jit`` refuses only once it runs), shows here."""
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(model_runner.__file__)))  # ray_tpu/
+    jitted = set()
+    for base, _, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(base, f)) as fh:
+                    jitted |= set(re.findall(r'named_jit\(\s*"(\w+)"', fh.read()))
+    assert jitted == set(STEP_PROGRAM_NAMES), sorted(jitted ^ set(STEP_PROGRAM_NAMES))

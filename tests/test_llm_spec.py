@@ -15,6 +15,8 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from plain_reference import drive  # noqa: E402
+
 from ray_tpu.llm import LLMEngine, SamplingParams, SpecConfig  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 
@@ -26,28 +28,6 @@ CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
 @pytest.fixture(scope="module")
 def params():
     return init_params(CFG, jax.random.PRNGKey(0))
-
-
-def _drive(engine_kwargs, schedule, aborts=None, max_steps=900):
-    """Run one engine over a step-indexed admission schedule (plus an
-    optional {step: admitted-request-ordinal} abort schedule); returns
-    ({request_id: token_ids}, {request_id: finish_reason}, engine)."""
-    eng = LLMEngine(CFG, **engine_kwargs)
-    finals, reasons, ids = {}, {}, []
-    last_t = max(schedule)
-    t = 0
-    while t <= last_t or eng.has_unfinished():
-        for prompt, sp in schedule.get(t, []):
-            ids.append(eng.add_request(prompt, sp))
-        if aborts and t in aborts:
-            eng.abort_request(ids[aborts[t]])
-        for o in eng.step():
-            if o.finished:
-                finals[o.request_id] = o.token_ids
-                reasons[o.request_id] = o.finish_reason
-        t += 1
-        assert t < max_steps, "schedule never converged"
-    return finals, reasons, eng
 
 
 def _mixed_schedule(n=6, seed=0):
@@ -68,11 +48,12 @@ def test_spec_slots_matches_plain_both_drafters(params):
     sched = _mixed_schedule()
     kw = dict(params=params, max_num_seqs=3, max_seq_len=128)
     aborts = {6: 0}
-    plain, plain_r, _ = _drive(dict(kw), sched, aborts)
+    plain, plain_r = drive(LLMEngine(CFG, **kw), sched, aborts)
     spec_ngram = SpecConfig(drafter="ngram", k=3)
     spec_model = SpecConfig(drafter="model", k=3, draft_config=CFG, draft_params=params)
     for spec in (spec_ngram, spec_model):
-        got, got_r, eng = _drive(dict(kw, speculative=spec), sched, aborts)
+        eng = LLMEngine(CFG, speculative=spec, **kw)
+        got, got_r = drive(eng, sched, aborts)
         assert set(got) == set(plain)
         for rid in plain:
             if plain_r[rid] == "aborted":
@@ -145,8 +126,9 @@ def test_spec_paged_preemption_matches_plain(params):
         num_pages=8,  # 7 usable: 2 admits + contended growth
         enable_prefix_caching=False,
     )
-    plain, plain_r, ep = _drive(dict(kw), sched)
-    got, got_r, es = _drive(dict(kw, speculative=SpecConfig(drafter="ngram", k=3)), sched)
+    ep, es = LLMEngine(CFG, **kw), LLMEngine(CFG, speculative=SpecConfig(drafter="ngram", k=3), **kw)
+    plain, plain_r = drive(ep, sched)
+    got, got_r = drive(es, sched)
     assert set(got) == set(plain)
     for rid in plain:
         assert got[rid] == plain[rid], f"{rid}: {got[rid]} != {plain[rid]}"
@@ -226,9 +208,6 @@ def test_spec_adaptive_k_decays_on_misses(params):
 
 
 def test_spec_config_validation(params):
-    with pytest.raises(ValueError, match="device-resident"):
-        LLMEngine(CFG, params, max_num_seqs=1, max_seq_len=64,
-                  device_resident=False, speculative=SpecConfig())
     with pytest.raises(ValueError, match="draft_config"):
         LLMEngine(CFG, params, max_num_seqs=1, max_seq_len=64,
                   speculative=SpecConfig(drafter="model"))

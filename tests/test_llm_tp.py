@@ -1,7 +1,7 @@
 """Tensor-parallel serving equivalence: the shard_map'd fused decode
 path over a TP mesh must emit token-for-token identical output to the
-tp=1 device-resident engine (which test_llm_device_resident.py already
-pins to the synchronous oracle), for both KV layouts, composing with the
+tp=1 engine (which test_llm_decode_loop.py already holds to the
+plain reference, tests/plain_reference.py), for both KV layouts, composing with the
 int8 KV cache and spec-ngram decoding — and the opt-in int8 QUANTIZED
 all-reduce (tp_collective="int8") must keep exact top-1 on a
 decisive-logits workload with bounded logit drift vs the fp collective,
@@ -24,6 +24,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from copy_model import copy_model_params  # noqa: E402
+from plain_reference import drive  # noqa: E402
 
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
 from ray_tpu.llm.spec import SpecConfig  # noqa: E402
@@ -42,28 +43,6 @@ def params():
 
 def _mesh(n=2):
     return create_mesh(tp=n, devices=jax.devices()[:n])
-
-
-def _drive(engine_kwargs, schedule, aborts=None, max_steps=500):
-    """Step one engine over a step-indexed admission schedule (the
-    test_llm_device_resident harness); returns ({rid: tokens}, {rid:
-    reason}, engine)."""
-    eng = LLMEngine(CFG, **engine_kwargs)
-    finals, reasons, ids = {}, {}, []
-    last_t = max(schedule)
-    t = 0
-    while t <= last_t or eng.has_unfinished():
-        for prompt, sp in schedule.get(t, []):
-            ids.append(eng.add_request(prompt, sp))
-        if aborts and t in aborts:
-            eng.abort_request(ids[aborts[t]])
-        for o in eng.step():
-            if o.finished:
-                finals[o.request_id] = o.token_ids
-                reasons[o.request_id] = o.finish_reason
-        t += 1
-        assert t < max_steps, "schedule never converged"
-    return finals, reasons, eng
 
 
 def _mixed_schedule(seed=0, n=6):
@@ -85,14 +64,15 @@ def _mixed_schedule(seed=0, n=6):
 def test_tp2_fused_token_identical(params, layout):
     """TP=2 shard_map fused loop == tp=1 device-resident loop under a
     mixed admission/eviction schedule, greedy + seeded sampling, both KV
-    layouts. The tp=1 engine is the token-identical oracle (itself pinned
-    to the sync loop by test_llm_device_resident.py)."""
+    layouts. The tp=1 engine is the token-identical oracle (itself held
+    to the plain reference by test_llm_decode_loop.py)."""
     sched = _mixed_schedule()
     kw = dict(params=params, max_num_seqs=3, max_seq_len=128, kv_layout=layout)
     if layout == "paged":
         kw["page_size"] = 32
-    base, base_r, _ = _drive(kw, sched)
-    got, got_r, eng = _drive(dict(kw, mesh=_mesh(2)), sched)
+    base, base_r = drive(LLMEngine(CFG, **kw), sched)
+    eng = LLMEngine(CFG, mesh=_mesh(2), **kw)
+    got, got_r = drive(eng, sched)
     assert got == base
     assert got_r == base_r
     # the weights and cache are actually sharded over both chips

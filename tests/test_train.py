@@ -392,11 +392,18 @@ def test_repeated_elasticity_chaos_cycles(tmp_path):
         from ray_tpu.core import context as _core_ctx
         from ray_tpu.core import rpc_chaos
         from ray_tpu.train import ElasticScalingPolicy
+        from ray_tpu.tune.callbacks import Callback
 
         client = _core_ctx.get_client()
         extra = client.add_node({"CPU": 2.0})
-        ws_file = str(tmp_path / "current_ws")
-        TOTAL = 24
+        TOTAL = 32  # steps enough for three cycles where the controller polls late (a loaded machine)
+        committed = []  # (world size, step) of every round the controller COMMITTED, in order
+
+        class Committed(Callback):
+            """The controller calls this as it commits a round (checkpoint registered, metrics appended), in this process."""
+
+            def log_trial_result(self, trial, result):
+                committed.append((result["world_size"], result["step"]))
 
         def loop(config):
             ckpt = train.get_checkpoint()
@@ -410,32 +417,25 @@ def test_repeated_elasticity_chaos_cycles(tmp_path):
                 with open(os.path.join(d, "state.json"), "w") as f:
                     json.dump({"step": step}, f)
                 train.report({"step": step, "world_size": ws}, checkpoint=Checkpoint.from_directory(d))
-                if train.get_context().get_world_rank() == 0:
-                    with open(config["ws_file"], "w") as f:
-                        f.write(f"{ws}:{step}")
                 _time.sleep(0.3)
 
         done = threading.Event()
         cycles_done = [0]
 
-        def read_ws_step():
-            try:
-                with open(ws_file) as f:
-                    ws, step = f.read().split(":")
-                    return int(ws), int(step)
-            except Exception:
-                return 0, -1
-
         def wait_committed(target, prev_step, timeout=150.0):
-            """Block until rank 0 COMMITS a step (train.report returned,
-            so the metric is durably in the history) at the target world
-            size that is NEWER than prev_step. Returns that step, or None
-            on timeout. This is what makes each cycle synchronous: the
-            next transition is not injected until the previous phase has
-            provably landed in the metrics stream."""
+            """Block until the CONTROLLER has committed a step at the
+            target world size that is NEWER than prev_step: its checkpoint
+            is registered (a restart resumes after it) and its metric is
+            in the history. Returns that step, or None on timeout. A
+            worker's own word is not enough: train.report returns once
+            the report is queued, and a node killed before the controller
+            polled it has the step run again at the next world size, the
+            transition gone from the history. This is what makes each
+            cycle synchronous: the next transition is not injected until
+            the previous phase has provably landed in the metrics stream."""
             deadline = _time.monotonic() + timeout
             while _time.monotonic() < deadline and not done.is_set():
-                ws, step = read_ws_step()
+                ws, step = committed[-1] if committed else (0, -1)
                 if ws == target and step > prev_step:
                     return step
                 _time.sleep(0.2)
@@ -466,9 +466,8 @@ def test_repeated_elasticity_chaos_cycles(tmp_path):
         scaling = ScalingConfig(num_workers=2, resources_per_worker={"CPU": 2})
         trainer = DataParallelTrainer(
             loop,
-            train_loop_config={"ws_file": ws_file},
             scaling_config=scaling,
-            run_config=_run_cfg(tmp_path, failure_config=FailureConfig(max_failures=8)),
+            run_config=_run_cfg(tmp_path, failure_config=FailureConfig(max_failures=8), callbacks=[Committed()]),
             scaling_policy=ElasticScalingPolicy(scaling, min_workers=1, max_workers=2, poll_interval_s=0.5),
         )
         result = trainer.fit()
