@@ -533,7 +533,7 @@ class LLMEngine:
 
                 self.pool = pkv.alloc(self._pcfg)
             elif self._hybrid:
-                self.cache = kvc.alloc_entries(self._kv_entries, self.max_num_seqs, self.max_seq_len)
+                self.cache = kvc.alloc_entries(self._kv_entries, self.max_num_seqs, self.max_seq_len, config.ring_entries())
             else:
                 self.cache = kvc.alloc(cache_cfg)
             if self._hybrid:

@@ -53,9 +53,11 @@ ROUTING = hybrid.ROUTING
 def _kept(config) -> str:
     """What a sequence of this description is, beside keys and values by head: the words of a refusal."""
     per_sequence = sorted(state_cache.sequence_entries(config))
-    latent = sorted(set(config.position_entries()) - {"k", "v"})
+    rings = config.ring_entries()
+    latent = sorted(set(config.position_entries()) - {"k", "v"} - set(rings))
     parts = ([f"its recurrent layers keep a state per sequence ({', '.join(per_sequence)})"] if per_sequence else []) \
-        + ([f"its attention layers keep {' and '.join(latent)} per position, not keys and values by head"] if latent else [])
+        + ([f"its attention layers keep {' and '.join(latent)} per position, not keys and values by head"] if latent else []) \
+        + ([f"its window layers keep {' and '.join(sorted(rings))} in a ring of the last {max(rings.values())} positions, not every position"] if rings else [])
     return " and ".join(parts) or "its layers are walked by the description loop"
 
 
@@ -113,22 +115,27 @@ def decode_step(params, cache, state, tokens, active, cfg):
     B = tokens.shape[0]
     lengths = cache["length"]
     per_position = frozenset(cfg.position_entries())
-    horizon = cache[next(iter(per_position))].shape[2]  # every entry is [layers, slots, positions, ...]
-    pos = jnp.minimum(lengths, horizon - 1)
+    rings = frozenset(cfg.ring_entries())
+    # every entry is [layers, slots, rows, ...]: the horizon's rows where it spans the sequence, a window's where it is a ring
+    # (``LayerCache.write`` takes a ring's position modulo its rows, so only the entries that span the sequence bound ``pos``)
+    spans = sorted(per_position - rings)
+    pos = jnp.minimum(lengths, cache[spans[0]].shape[2] - 1) if spans else lengths
     lanes = jnp.arange(B, dtype=jnp.int32)
     with scope("embed"):
         x = hybrid.embed_tokens(params, tokens, cfg)
 
-    def layer(kind, w, i, x, carry):
+    def layer(kind, w, i, riding, carry):
         arrays, stats = carry
-        view = hybrid.LayerCache(arrays, per_position, i, lanes, pos)
-        y, s = cfg.mixers[kind].step(w, cfg.norm(x, w["norm"]), view, hybrid.StepCtx(lengths, active, (params[kind], i)))
+        x, handed = riding  # what sub-blocks hand on rides the loop beside the stream (``hybrid.forward_hidden``)
+        view = hybrid.LayerCache(arrays, per_position, i, lanes, pos, rings)
+        y, s, *more = cfg.mixers[kind].step(w, cfg.norm(x, w["norm"]), view, hybrid.StepCtx(lengths, active, (params[kind], i), handed))
         if s is not None:
             stats = jnp.stack([stats[0] + s[0], stats[1] + s[1], jnp.maximum(stats[2], s[2]), stats[3] + s[3]])
-        return hybrid.add_branch(x, y, cfg), (view.arrays, stats)
+        return (hybrid.add_branch(x, y, cfg), more[0] if cfg.mixers[kind].hands else handed), (view.arrays, stats)
 
     arrays = {**{name: cache[name] for name in per_position}, **state}
-    x, (arrays, stats) = hybrid.run_layers(cfg, params, x, (arrays, jnp.zeros((4,), jnp.float32)), layer)
+    handed = {n: jnp.zeros((B,) + tuple(shape), jnp.dtype(dt)) for n, (shape, dt) in cfg.handed.items()}
+    (x, _), (arrays, stats) = hybrid.run_layers(cfg, params, (x, handed), (arrays, jnp.zeros((4,), jnp.float32)), layer)
     with scope("head"):
         logits = jnp.dot(hybrid.before_head(x, params, cfg), params["unembed"], preferred_element_type=jnp.float32)
     n = max(cfg.routing_layers, 1)
@@ -155,7 +162,7 @@ def make_hybrid_fns(cfg):
     from ray_tpu.llm import kv_cache as kvc
 
     prefill_fn = named_jit("llm_hybrid_prefill", partial(prefill, cfg=cfg))
-    insert_fn = named_jit("llm_kv_insert", scoped("cache", kvc.insert_entries), donate_argnums=(0,))
+    insert_fn = named_jit("llm_kv_insert", scoped("cache", partial(kvc.insert_entries, rings=frozenset(cfg.ring_entries()))), donate_argnums=(0,))
     state_insert_fn = named_jit("llm_state_insert", scoped("cache", state_cache.insert_state), donate_argnums=(0,))
     step_fn = named_jit("llm_hybrid_fused_step", partial(fused_step, cfg=cfg), donate_argnums=(1, 2, 4, 5, 6, 7))
     return prefill_fn, insert_fn, state_insert_fn, step_fn
@@ -170,7 +177,7 @@ _trace_cfg = hybrid.trace_description
 def _sds_caches(cfg, B: int, S: int):
     from ray_tpu.llm import kv_cache as kvc
 
-    return (jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), B, S)),
+    return (jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), B, S, cfg.ring_entries())),
             jax.eval_shape(lambda: state_cache.alloc(cfg, B)))
 
 

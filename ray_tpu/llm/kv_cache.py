@@ -11,6 +11,14 @@ head. A hybrid description names its own (``HybridDescription.position_entries()
 again, or a latent layer's ``c_kv`` [r] and ``k_r`` [rope]); the cache is then one stacked array
 ``[layers that keep it, slots, max_seq_len, *shape]`` for each, allocated, inserted into and counted
 by the same functions (``alloc_entries``, ``insert_entries``, ``entry_bytes_per_token``).
+Not every entry spans ``max_seq_len``: an entry the description names a RING
+(``HybridDescription.ring_entries()``: a sliding-window layer's keys and values, window W) is
+``[layers, slots, min(W, max_seq_len), *shape]`` and holds a sequence's LAST W positions, position
+p at row p mod W: a prompt's last min(length, W) positions are placed at their rows by
+``insert_entries``, a decode step writes at ``length mod W`` (``models/hybrid.LayerCache``) and
+attends over min(length + 1, W) rows, in whatever order they lie. A ring's bytes follow the
+window and not the sequence; what it cannot do (a page table, a handoff, a prefix hit, a copy of
+"the first T positions") the engine refuses by name for every description alike.
 
 A "slot" is one concurrent sequence. Admission = prefill writes a new
 sequence's K/V into a free slot at offset 0; decode appends one token per
@@ -61,9 +69,10 @@ class CacheConfig:
     dtype: str = "bfloat16"  # bf16/f32 variants, or "int8" (kv_quant.py)
 
 
-def alloc_entries(entries: dict, num_slots: int, max_seq_len: int) -> dict:
-    """The slot cache of ``entries``: name -> (layers, shape of one position, dtype)."""
-    cache = {name: jnp.zeros((layers, num_slots, max_seq_len) + tuple(shape), jnp.dtype(dtype))
+def alloc_entries(entries: dict, num_slots: int, max_seq_len: int, rings: dict | None = None) -> dict:
+    """The slot cache of ``entries``: name -> (layers, shape of one position, dtype). ``rings``:
+    name -> W for the entries that hold a sequence's last W positions only (W rows a slot)."""
+    cache = {name: jnp.zeros((layers, num_slots, min((rings or {}).get(name, max_seq_len), max_seq_len)) + tuple(shape), jnp.dtype(dtype))
              for name, (layers, shape, dtype) in entries.items()}
     return {**cache, "length": jnp.zeros((num_slots,), dtype=jnp.int32)}
 
@@ -73,13 +82,21 @@ def entry_bytes_per_token(entries: dict) -> int:
     return sum(layers * math.prod(shape) * jnp.dtype(dtype).itemsize for layers, shape, dtype in entries.values())
 
 
-def insert_entries(cache: dict, slot, new: dict, length) -> dict:
+def insert_entries(cache: dict, slot, new: dict, length, rings: frozenset = frozenset()) -> dict:
     """Write a prefilled sequence's entries into ``slot`` at offset 0. ``new[name]``: [layers,
     T_pad, *shape] (the padded tail is garbage and stays masked by ``length``). slot/length:
-    traced scalars, so one compiled program serves every slot and every prefill bucket."""
+    traced scalars, so one compiled program serves every slot and every prefill bucket. An entry of
+    ``rings`` with fewer rows W than T_pad takes the prompt's LAST min(length, W) positions, each
+    at its row p mod W: a gather of W of the T_pad positions by the true length (row r holds
+    position base + (r - base) mod W with base = max(length - W, 0); while length <= W that is
+    position r itself, and the rows from ``length`` on are garbage that ``length`` masks)."""
     zero = jnp.zeros((), dtype=jnp.int32)
     out = {}
     for name, arr in new.items():
+        W = cache[name].shape[2]
+        if name in rings and arr.shape[1] > W:
+            base = jnp.maximum(jnp.asarray(length, jnp.int32) - W, 0)
+            arr = jnp.take(arr, base + (jnp.arange(W, dtype=jnp.int32) - base) % W, axis=1)
         start = (zero, jnp.asarray(slot, jnp.int32)) + (zero,) * (arr.ndim - 1)
         out[name] = jax.lax.dynamic_update_slice(cache[name], arr[:, None].astype(cache[name].dtype), start)
     return {**out, "length": cache["length"].at[slot].set(jnp.asarray(length, jnp.int32))}

@@ -73,9 +73,9 @@ logger = logging.getLogger("ray_tpu.llm")
 # what a hybrid description may count of a prefill program from its shape alone
 # (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
 # takes on that step's row
-PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks")
+PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
-DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live")
+DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read")
 
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
@@ -372,7 +372,9 @@ class FlightRecorder:
         # what the description counts of the decode program this step dispatched from the positions its
         # lanes hold (``HybridDescription.decode_counters``): blocks of 64 positions, a key-value head's
         # share each, that the sparse layers read for the blocks their queries chose, and would read
-        # attending to everything; absent for a description that counts none
+        # attending to everything; rows of a window layer's ring that the step's bound lanes read, over the
+        # window layers (``swa_rows_read``: min(position + 1, window) a lane and layer); absent for a
+        # description that counts none
         *DECODE_COUNTERS,
         "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
@@ -398,7 +400,9 @@ class FlightRecorder:
         # (``HybridDescription.prefill_counters``): chunks of the delta rule that the programs ran, padding's
         # among them, over the layers of Kimi Delta Attention (``kda_``) or of Gated DeltaNet (``gdn_``), and how
         # many of them the kernel ran; (query, block) pairs that the sparse layers read at the prompts' true
-        # lengths; absent for a description that counts none
+        # lengths; (query, key) pairs inside the window that the sliding-window layers' mathematics needs at
+        # the prompts' true lengths (``swa_pairs``: min(i + 1, window) a position and layer); absent for a
+        # description that counts none
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)

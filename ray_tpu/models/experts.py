@@ -6,8 +6,11 @@ how the router scores (``sigmoid`` or ``softmax`` over ALL published experts),
 whether a correction bias joins the scores for the choice of the top k, whether
 the chosen scores are normalised and by what they are scaled, the expert's form
 (``relu2``: two matrices, ``W_down relu(W_up x)^2``; ``swiglu``: three,
-``W_down (SiLU(W_gate x) * W_up x)``), and whether the shared expert's output is
-gated by ``sigmoid(x . w_sg)``. Everything else is one code path:
+``W_down (SiLU(W_gate x) * W_up x)``; ``reglu``: three, ``W_down (relu(W_gate x) * W_up x)``),
+whether there is a shared expert at all and whether its output is gated by
+``sigmoid(x . w_sg)``. A layer whose routing is decided elsewhere (by a router that reads
+another sub-block's input) is handed it (``routing=`` of ``moe_seq`` / ``moe_step``) and holds
+no router. Everything else is one code path:
 
 - ``route``: float32 on whatever the norm hands it, whatever the stream's dtype.
 - ``experts_dense``: every held expert over every row: the form that has a
@@ -80,12 +83,13 @@ class ExpertLayer:
     bias: bool = False  # a correction bias joins the scores for the CHOICE (never the weights)
     norm_topk: bool = True
     scale: float = 1.0
-    act: str = "swiglu"  # swiglu (w_gate, w_up, w_down) | relu2 (w_up, w_down)
+    act: str = "swiglu"  # swiglu | reglu (w_gate, w_up, w_down) | relu2 (w_up, w_down)
     shared_gated: bool = False
+    shared: bool = True  # False: routed experts alone, no expert that every token passes
 
     def __post_init__(self):
-        if self.score not in ("softmax", "sigmoid") or self.act not in ("swiglu", "relu2"):
-            raise ValueError(f"an expert layer scores by softmax or sigmoid and acts by swiglu or relu2, not {self.score}/{self.act}")
+        if self.score not in ("softmax", "sigmoid") or self.act not in ("swiglu", "reglu", "relu2"):
+            raise ValueError(f"an expert layer scores by softmax or sigmoid and acts by swiglu, reglu or relu2, not {self.score}/{self.act}")
         if not 0 <= self.expert_start <= self.expert_start + self.held <= self.num_experts:
             raise ValueError("the experts held must lie inside the router's width")
 
@@ -95,18 +99,23 @@ class ExpertLayer:
 
     @property
     def matrices(self) -> tuple:
-        return ("w_gate", "w_up", "w_down") if self.act == "swiglu" else ("w_up", "w_down")
+        return ("w_up", "w_down") if self.act == "relu2" else ("w_gate", "w_up", "w_down")
 
 
 def _relu2(h):
     return jnp.square(jax.nn.relu(h))
 
 
+def _gate(s: ExpertLayer):
+    """What a gated expert applies to its gate: ReLU (``reglu``) or SiLU (``swiglu``)."""
+    return jax.nn.relu if s.act == "reglu" else jax.nn.silu
+
+
 def _hidden(s: ExpertLayer, x, up, gate, spec: str):
     """An expert's hidden activation from its input: ``spec`` contracts x with a matrix stored [.., F, H]."""
     if s.act == "relu2":
         return _relu2(jnp.einsum(spec, x, up))
-    return jax.nn.silu(jnp.einsum(spec, x, gate)) * jnp.einsum(spec, x, up)
+    return _gate(s)(jnp.einsum(spec, x, gate)) * jnp.einsum(spec, x, up)
 
 
 def route(w, x, c):
@@ -125,7 +134,7 @@ def route(w, x, c):
 
 def shared_expert(w, x, s: ExpertLayer):
     """The expert every token passes, of the routed experts' form; gated per token where published."""
-    h = _relu2(jnp.dot(x, w["shared_up"])) if s.act == "relu2" else jax.nn.silu(jnp.dot(x, w["shared_gate"])) * jnp.dot(x, w["shared_up"])
+    h = _relu2(jnp.dot(x, w["shared_up"])) if s.act == "relu2" else _gate(s)(jnp.dot(x, w["shared_gate"])) * jnp.dot(x, w["shared_up"])
     y = jnp.dot(h, w["shared_down"])
     if s.shared_gated:
         g = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w["shared_sg"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
@@ -265,8 +274,10 @@ def experts_grouped(stacked, layer, x, idx, wt, valid, c):
     return _grouped(stacked, layer, x, idx, wt, valid, c)[0]
 
 
-def moe_seq(w, xn, lengths, c, stacked=None):
-    """xn [B,T,H] -> ([B,T,H], counters): routed experts held here plus the shared expert.
+def moe_seq(w, xn, lengths, c, stacked=None, routing=None):
+    """xn [B,T,H] -> ([B,T,H], counters): routed experts held here plus the shared expert where
+    the layer has one. ``routing`` = (expert ids [B,T,k] int32, weights [B,T,k] f32) made elsewhere
+    takes the place of this layer's own router.
     ``stacked`` = (the expert layers' stacked weights, this layer's index): the serving path's
     grouped matmul, in slabs of ``SLAB_ROWS`` rows where the batch is larger; without it every
     held expert over every token, which has a backward pass. The counters, float32 [3], are of
@@ -275,7 +286,10 @@ def moe_seq(w, xn, lengths, c, stacked=None):
     s = c.expert_layer
     B, T, H = xn.shape
     N = B * T
-    idx, wt = scoped("moe.route", route)(w, xn.reshape(N, H), c)  # on the norm as it comes
+    if routing is None:
+        idx, wt = scoped("moe.route", route)(w, xn.reshape(N, H), c)  # on the norm as it comes
+    else:
+        idx, wt = (a.reshape(N, s.top_k) for a in routing)
     x = xn.reshape(N, H).astype(w["w_up"].dtype)
     valid = (jnp.arange(T)[None, :] < lengths[:, None]).reshape(-1)
     if stacked is None:
@@ -289,7 +303,9 @@ def moe_seq(w, xn, lengths, c, stacked=None):
         else:
             routed, sizes, rows = _grouped(*stacked, x, idx, wt, valid, c)
         counters = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes), rows]).astype(jnp.float32)
-    return (routed + scoped("moe.shared", shared_expert)(w, x, s)).reshape(B, T, H), counters
+    if s.shared:
+        routed = routed + scoped("moe.shared", shared_expert)(w, x, s)
+    return routed.reshape(B, T, H), counters
 
 
 def experts_step(stacked, layer, x, idx, wt, active, c):
@@ -330,16 +346,16 @@ def experts_step(stacked, layer, x, idx, wt, active, c):
         return out.astype(x.dtype), n_hit
 
 
-def moe_step(w, xn, active, c, stacked):
+def moe_step(w, xn, active, c, stacked, routing=None):
     """One token a lane: xn [B,H], active [B] bool, ``stacked`` = (the expert layers' stacked
     weights, this layer's index) -> (out [B,H], [held experts that got a token, pairs served
     here, most tokens at one expert, held experts whose weights the step read] over the active
-    lanes, float32)."""
+    lanes, float32). ``routing`` = (expert ids [B,k], weights [B,k]) made elsewhere, as in ``moe_seq``."""
     s = c.expert_layer
-    idx, wt = scoped("moe.route", route)(w, xn, c)  # on the norm as it comes
+    idx, wt = scoped("moe.route", route)(w, xn, c) if routing is None else routing  # on the norm as it comes
     xn = xn.astype(w["w_up"].dtype)
     hot = jax.nn.one_hot(idx - s.expert_start, s.held, dtype=jnp.float32) * active[:, None, None]
     load = jnp.sum(hot, axis=(0, 1))
     routed, read = experts_step(*stacked, xn, idx, wt, active, c)
     stats = jnp.stack([jnp.sum(load > 0).astype(jnp.float32), jnp.sum(load), jnp.max(load), read.astype(jnp.float32)])
-    return routed + scoped("moe.shared", shared_expert)(w, xn, s), stats
+    return (routed + scoped("moe.shared", shared_expert)(w, xn, s) if s.shared else routed), stats

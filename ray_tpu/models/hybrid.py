@@ -15,6 +15,14 @@ file states WHAT its layers are; this file walks them. A description
   ONE layer of that kind keeps, per position of a sequence (the slot cache's
   rows: ``k`` and ``v`` of an attention layer with heads, ``c_kv`` and ``k_r`` of
   a latent one) or once per sequence (the state cache);
+- ``ring_entries()``: name -> W for the per-position entries that keep a sequence's
+  LAST W positions only (a sliding-window layer's keys and values): such an entry is
+  a ring of W rows a slot, position p at row p mod W, beside the entries that span
+  ``max_seq_len``; none by default;
+- ``handed``: name -> (shape, dtype) of what one sub-block hands the NEXT for every
+  token (a routing decided before attention, for the expert layer after it); the
+  mixers that hand something on say so (``Mixer.hands``); nothing by default, and
+  the loops then carry nothing more than the stream;
 - ``norm(x, w)``: the pre-norm of every sub-block and the final norm;
 - ``stream_dtype``, ``init_params``, ``num_params()``.
 
@@ -64,18 +72,21 @@ class Mixer(NamedTuple):
     seq: Callable  # (w, xn [B,T,H], SeqCtx) -> (y [B,T,H], kept {name: one layer's entry})
     step: Callable  # (w, xn [B,H], LayerCache, StepCtx) -> (y [B,H], routing counters [4] or None)
     routes: bool = False  # its sequence form keeps ROUTING, its step form hands back counters
+    hands: bool = False  # both forms return one thing more, last: what the next sub-block finds as ``ctx.handed`` (``HybridDescription.handed``)
 
 
 class SeqCtx(NamedTuple):
     lengths: Any  # [B] int32: true lengths of the right-padded sequences
     mesh: Any  # the mesh a kernel has to be told about, or None
     stacked: Any  # the serving path: (the kind's stacked weights, this layer's index); else None
+    handed: Any = None  # {name: [B,T,*shape]}: what the last sub-block that hands something on handed (``HybridDescription.handed``)
 
 
 class StepCtx(NamedTuple):
     lengths: Any  # [B] int32: positions already held = the new token's position
     active: Any  # [B] bool: lanes bound to a live sequence
     stacked: Any  # (the kind's stacked weights, this layer's index), as in ``SeqCtx``
+    handed: Any = None  # {name: [B,*shape]}, as in ``SeqCtx``
 
 
 class LayerPlan(NamedTuple):
@@ -114,6 +125,17 @@ class HybridDescription:
         return {name: (self.count(kind), tuple(shape), dtype)
                 for kind, spec in self.cache_spec().items()
                 for name, (shape, dtype, per) in spec.items() if per == "position"}
+
+    def ring_entries(self) -> dict:
+        """name -> W of the per-position entries that are RINGS: the slot cache keeps a sequence's
+        last W positions of them, position p at row p mod W (``llm/kv_cache.py``), where every other
+        per-position entry spans ``max_seq_len``. None by default."""
+        return {}
+
+    @property
+    def handed(self) -> dict:
+        """name -> (shape, dtype) of what a sub-block hands the next for every token. Nothing by default."""
+        return {}
 
     @property
     def num_kv_layers(self) -> int:
@@ -264,10 +286,11 @@ class LayerCache:
     ``read`` would slice all S positions of every lane out). All act on the stacked arrays in
     place (a dynamic slice of, a scatter or an update into the donated carry); the arrays as
     they stand afterwards are ``arrays``. A position's write is scoped ``cache``; a per-sequence
-    entry's stays in its caller's scope (a recurrent state's in ``<kind>.state``)."""
+    entry's stays in its caller's scope (a recurrent state's in ``<kind>.state``). An entry of
+    ``rings`` holds as many rows as its window: position p goes to row p mod rows."""
 
-    def __init__(self, arrays: dict, per_position: frozenset, i, lanes, pos):
-        self.arrays, self._per_position, self._i, self._lanes, self._pos = dict(arrays), per_position, i, lanes, pos
+    def __init__(self, arrays: dict, per_position: frozenset, i, lanes, pos, rings: frozenset = frozenset()):
+        self.arrays, self._per_position, self._i, self._lanes, self._pos, self._rings = dict(arrays), per_position, i, lanes, pos, rings
 
     def read(self, name: str):
         return jax.lax.dynamic_index_in_dim(self.arrays[name], self._i, 0, keepdims=False)
@@ -281,7 +304,8 @@ class LayerCache:
         a = self.arrays[name]
         if name in self._per_position:
             with scope("cache"):
-                self.arrays[name] = a.at[self._i, self._lanes, self._pos].set(value.astype(a.dtype))
+                pos = self._pos % a.shape[2] if name in self._rings else self._pos
+                self.arrays[name] = a.at[self._i, self._lanes, pos].set(value.astype(a.dtype))
         else:
             self.arrays[name] = jax.lax.dynamic_update_index_in_dim(a, value.astype(a.dtype), self._i, 0)
 
@@ -302,12 +326,17 @@ def init_stacked(groups: dict, count, keys, dt) -> dict:
             for g, group in groups.items() if count(g)}
 
 
-def attend_slot(q, cache: LayerCache, ctx: StepCtx, num_kv_heads: int):
+def attend_slot(q, cache: LayerCache, ctx: StepCtx, num_kv_heads: int, entries: tuple = ("k", "v"), name: str = slot_attention.KERNEL):
     """One token a lane (its query q [B,nh,hd]) against the positions its lane holds in THIS
-    layer's keys and values, the new token's among them (written through ``cache`` before the
-    call): ``ops/slot_attention.attend`` on the stacked rows where they lie. -> [B, nh*hd] f32."""
-    (k, i), (v, _) = cache.stacked("k"), cache.stacked("v")
-    return slot_attention.attend(q, k, v, i, ctx.lengths, num_kv_heads, live=ctx.active)
+    layer's keys and values (``entries``: their names in the cache), the new token's among them
+    (written through ``cache`` before the call): ``ops/slot_attention.attend`` on the stacked rows
+    where they lie. Entries that are a ring of W rows hold the lane's last min(length + 1, W)
+    positions in its first that many rows while it is young and in all W once it has wrapped,
+    which is what the op reads of ANY stack of W rows for a lane at ``lengths``: keys are cached
+    after their rotation, so the order of the rows is immaterial to the softmax. ``name``: the
+    kernel's name in a trace, where it is not the op's own. -> [B, nh*hd] f32."""
+    (k, i), (v, _) = (cache.stacked(n) for n in entries)
+    return slot_attention.attend(q, k, v, i, ctx.lengths, num_kv_heads, live=ctx.active, name=name)
 
 
 # ------------------------------------------------------------- the stream's ends and its branches
@@ -349,12 +378,15 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
         if c.routing_layers:
             empty[ROUTING] = jnp.zeros((3,), jnp.float32)
 
-    def layer(kind, w, i, x):
-        ctx = SeqCtx(lengths, mesh, (params[kind], i) if collect else None)
-        y, kept = c.mixers[kind].seq(w, c.norm(x, w["norm"]), ctx)
-        return add_branch(x, y, c), kept if collect else {}
+    def layer(kind, w, i, riding):
+        # what sub-blocks hand on rides the loop beside the stream (nothing, for a description that hands nothing on: no array more)
+        x, handed = riding
+        ctx = SeqCtx(lengths, mesh, (params[kind], i) if collect else None, handed)
+        y, kept, *more = c.mixers[kind].seq(w, c.norm(x, w["norm"]), ctx)
+        return (add_branch(x, y, c), more[0] if c.mixers[kind].hands else handed), kept if collect else {}
 
-    x, out = scan_layers(c, params, x, layer, empty)
+    handed = {n: jnp.zeros((B, T) + tuple(shape), jnp.dtype(dt)) for n, (shape, dt) in c.handed.items()}
+    (x, _), out = scan_layers(c, params, (x, handed), layer, empty)
     with scope("head"):
         return before_head(x, params, c), out
 

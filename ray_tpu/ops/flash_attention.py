@@ -30,22 +30,25 @@ def _default_blocks(head_dim: int) -> tuple[int, int]:
 # ----------------------------------------------------------------------
 # reference / fallback implementation (XLA; used on CPU)
 # ----------------------------------------------------------------------
-def attention_xla(q, k, v, causal: bool = True, scale: float | None = None, segment_ids=None):
-    """Plain XLA attention, f32 softmax. q,k,v: [B, H, T, D]."""
+def attention_xla(q, k, v, causal: bool = True, scale: float | None = None, segment_ids=None, window: int | None = None):
+    """Plain XLA attention, f32 softmax. q,k,v: [B, H, T, D]. ``window``: a query at position i
+    reads the keys j with ``i - window < j <= i`` (its own among them) and no earlier one."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    logits = _apply_masks(logits, causal, segment_ids)
+    logits = _apply_masks(logits, causal, segment_ids, window)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def _apply_masks(logits, causal, segment_ids):
+def _apply_masks(logits, causal, segment_ids, window=None):
     B, H, Tq, Tk = logits.shape
     if causal:
         qi = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1)
         logits = jnp.where((ki <= qi)[None, None], logits, _NEG_INF)
+        if window is not None:
+            logits = jnp.where((ki > qi - window)[None, None], logits, _NEG_INF)
     if segment_ids is not None:
         same = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
         logits = jnp.where(same, logits, _NEG_INF)
@@ -55,7 +58,10 @@ def _apply_masks(logits, causal, segment_ids):
 # ----------------------------------------------------------------------
 # pallas forward kernel
 # ----------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k, window=None):
+    """``window`` (causal only): a query at position i reads keys ``i - window < j <= i``. A tile
+    wholly before the window of its first query is skipped as a tile above the diagonal is (and not
+    fetched: ``_fwd_pallas``'s index map holds the tile before it), the edge tiles are masked."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -77,9 +83,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, s
             q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+            if window is not None:
+                inside = k_pos > q_pos - window
+                s = jnp.where(inside, s, _NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if causal and window is not None:
+            # a row whose window starts after this tile has read no key yet: exp(_NEG_INF - _NEG_INF) is 1, not 0
+            p = jnp.where(inside, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
@@ -87,7 +99,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, s
         )
         m_scr[:] = m_new
 
-    if causal:
+    if causal and window is not None:
+        @pl.when((ki * block_k <= qi * block_q + block_q - 1) & (ki * block_k + block_k - 1 > qi * block_q - window))
+        def _():
+            _compute()
+    elif causal:
         @pl.when(ki * block_k <= qi * block_q + block_q - 1)
         def _():
             _compute()
@@ -101,8 +117,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, s
         lse_ref[0, 0] = (m_scr[:] + jnp.log(l))[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q", "block_k"))
-def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None):
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q", "block_k", "window"))
+def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -117,13 +133,23 @@ def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None):
     qs, ks, vs = (x.reshape(B * H, x.shape[2], D) for x in (q, k, v))
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+    keys = lambda b, i, j: (b, j, 0)  # noqa: E731
+    windowed = {}
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is causal: keys i - window < j <= i")  # tpulint: disable=ERR002 — a programmer's error at trace time
+        kernel = functools.partial(kernel, window=window)
+        # a tile the kernel skips is not fetched either: its index is that of the nearest tile the query tile reads,
+        # which the pipeline holds already; the name tells the windowed calls apart in a trace
+        keys = lambda b, i, j: (b, jnp.clip(j, jnp.maximum(i * block_q - window + 1, 0) // block_k, (i * block_q + block_q - 1) // block_k), 0)  # noqa: E731
+        windowed = {"name": "window_flash_attention"}
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, D), keys, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, D), keys, memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
@@ -144,6 +170,7 @@ def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None):
             bytes_accessed=(qs.size + ks.size + vs.size) * 2,
             transcendentals=B * H * T * Tk,
         ),
+        **windowed,
     )(qs, ks, vs)
     return o.reshape(B, H, T, D), lse.reshape(B, H, T)
 
@@ -311,17 +338,19 @@ def _bwd_pallas_with_delta(q, k, v, g, lse, delta, causal=True, scale=None, bloc
 # ----------------------------------------------------------------------
 # custom VJP
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal: bool = True, scale: float | None = None, impl: str = "auto"):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, causal: bool = True, scale: float | None = None, impl: str = "auto", window: int | None = None):
     """Flash attention with GQA support. q: [B,H,T,D]; k,v: [B,Hkv,T,D].
 
     impl: "auto" (pallas on TPU when head_dim tiles), "pallas", or "xla".
+    window: a query at position i reads the keys ``i - window < j <= i`` only (causal; forward
+    kernel and both XLA passes; the backward KERNELS have no window and refuse one).
     """
-    out, _ = _flash_fwd(q, k, v, causal, scale, impl)
+    out, _ = _flash_fwd(q, k, v, causal, scale, impl, window)
     return out
 
 
-def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto", scale: float | None = None):
+def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto", scale: float | None = None, window: int | None = None):
     """Causal flash_attention over [B, H, T, D] inside a GSPMD program.
     GSPMD cannot partition a Mosaic kernel on its own ("wrap the call in
     a shard_map"), so where the Pallas kernel is selected on a mesh of
@@ -331,12 +360,12 @@ def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto", scale: float | No
     None (or one device, or the XLA path) for the plain call."""
     if (mesh is None or mesh.size == 1 or not set(mesh.axis_names) <= {"dp", "fsdp", "tp"}
             or not _use_pallas(q, impl)):
-        return flash_attention(q, k, v, True, scale, impl)
+        return flash_attention(q, k, v, True, scale, impl, window)
     from jax.sharding import PartitionSpec as P
 
     batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
     spec = P(batch, "tp" if "tp" in mesh.axis_names else None, None, None)
-    attn = functools.partial(flash_attention, causal=True, scale=scale, impl=impl)
+    attn = functools.partial(flash_attention, causal=True, scale=scale, impl=impl, window=window)
     return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)(q, k, v)
 
 
@@ -349,32 +378,34 @@ def _broadcast_kv(q, k, v):
     return k, v
 
 
-def _flash_fwd(q, k, v, causal, scale, impl="auto"):
+def _flash_fwd(q, k, v, causal, scale, impl="auto", window=None):
     kb, vb = _broadcast_kv(q, k, v)
     if _use_pallas(q, impl):
-        o, lse = _fwd_pallas(q, kb, vb, causal=causal, scale=scale)
+        o, lse = _fwd_pallas(q, kb, vb, causal=causal, scale=scale, window=window)
     else:
-        o, lse = _fwd_xla_with_lse(q, kb, vb, causal, scale)
+        o, lse = _fwd_xla_with_lse(q, kb, vb, causal, scale, window)
     return o, (q, k, v, o, lse)
 
 
-def _fwd_xla_with_lse(q, k, v, causal, scale):
+def _fwd_xla_with_lse(q, k, v, causal, scale, window=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    logits = _apply_masks(logits, causal, None)
+    logits = _apply_masks(logits, causal, None, window)
     lse = jax.nn.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[..., None]).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v), lse
 
 
-def _flash_bwd(causal, scale, impl, residuals, g):
+def _flash_bwd(causal, scale, impl, window, residuals, g):
     q, k, v, o, lse = residuals
     kb, vb = _broadcast_kv(q, k, v)
     if _use_pallas(q, impl):
+        if window is not None:
+            raise NotImplementedError("the flash backward kernels have no window (ROADMAP B10): train a windowed layer with impl='xla'")  # tpulint: disable=ERR002 — a programmer's error at trace time
         dq, dk, dv = _bwd_pallas(q, kb, vb, o, lse, g, causal=causal, scale=scale)
     else:
-        dq, dk, dv = _bwd_xla(q, kb, vb, o, lse, g, causal, scale)
+        dq, dk, dv = _bwd_xla(q, kb, vb, o, lse, g, causal, scale, window)
     H, Hkv = q.shape[1], k.shape[1]
     if H != Hkv:
         rep = H // Hkv
@@ -383,11 +414,11 @@ def _flash_bwd(causal, scale, impl, residuals, g):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _bwd_xla(q, k, v, o, lse, g, causal, scale):
+def _bwd_xla(q, k, v, o, lse, g, causal, scale, window=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    logits = _apply_masks(logits, causal, None)
+    logits = _apply_masks(logits, causal, None, window)
     p = jnp.exp(logits - lse[..., None])
     g32 = g.astype(jnp.float32)
     dv = jnp.einsum("bhqk,bhqd->bhkd", p, g32)

@@ -115,6 +115,19 @@ def block_positions(S: int, num_kv_heads: int, head_dim: int, itemsize: int, blo
     return math.gcd(S, 1 << (cap.bit_length() - 1))
 
 
+KERNEL = "slot_decode_attention"  # the live-block kernel's name in a trace, where a caller gives it no other
+
+
+def padded_heads(num_heads: int, num_kv_heads: int) -> int:
+    """Query rows the kernel takes for ``num_heads`` heads: every key-value head's group grown by
+    rows of zeros until the rows are whole bfloat16 tiles of 16 (28 heads over 4: 7 a group go as
+    8, 32 rows). A padded row reads what its group reads and is cut off."""
+    rep = num_heads // num_kv_heads
+    while (rep * num_kv_heads) % 16:
+        rep += 1
+    return rep * num_kv_heads
+
+
 def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: int, *,
             quantized: bool = False, sharded: bool = False, value_dim: int | None = None) -> str | None:
     """Why the kernel does NOT serve this call (the XLA form then does), or None. Off the TPU the
@@ -150,8 +163,9 @@ def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: in
         return (f"{num_kv_heads} kv heads x head_dim {head_dim}: a position's heads lie in ({num_kv_heads}, 128) tiles, which "
                 "are [S * kv, hd] rows only after a copy of the whole cache (402 MB of temporaries at 3 x 16 x 4096 x 2 x 256, "
                 "compiled for a v5e in PR 35)")
-    if num_heads % 16 or num_heads > 32:
-        return f"{num_heads} query heads: compiled at 16 and 32 (whole bfloat16 tiles of 16 rows)"
+    if num_heads < 16 or padded_heads(num_heads, num_kv_heads) > 32:
+        return (f"{num_heads} query heads over {num_kv_heads} kv heads: compiled at 16 and 32 (whole bfloat16 tiles of 16 rows), "
+                "and at 28 over 4, whose groups of 7 go as 8")
     blk = block_positions(S, num_kv_heads, head_dim, dt.itemsize)
     if blk * num_kv_heads < 512:
         return f"{S} positions a slot: no block of at least {512 // num_kv_heads} positions divides it"
@@ -285,16 +299,23 @@ def _launch(kernel, name: str, layer, bound, queries, stacks, blk_rows: int, blk
     )(jnp.asarray(layer, jnp.int32).reshape(1), bound, src, last, *queries, *stacks)
 
 
-def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None, interpret: bool = False):
+def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None, interpret: bool = False,
+                  name: str = KERNEL):
     """The kernel form. q [B,nh,hd]; k/v_stack [L,B,S,kv,hd]; layer: int32 scalar (traced or not);
-    bound [B] int32: lane b attends positions 0 .. bound[b]-1 of layer ``layer`` (0: the lane is
-    bound to no sequence, reads nothing and gets zeros). -> [B, nh*hd] float32."""
+    bound [B] int32: lane b attends rows 0 .. bound[b]-1 of layer ``layer`` (0: the lane is
+    bound to no sequence, reads nothing and gets zeros). ``name``: the kernel's in a trace (a ring
+    layer's calls carry their own). -> [B, nh*hd] float32."""
     B, nh, hd = q.shape
     L, _, S, kv, _ = k_stack.shape
     blk = block or block_positions(S, kv, hd, k_stack.dtype.itemsize)
-    kernel = functools.partial(_kernel, blk=blk, kv=kv, rep=nh // kv, scale=1.0 / math.sqrt(hd))
-    out = _launch(kernel, "slot_decode_attention", layer, bound, [q],
+    rows = padded_heads(nh, kv)
+    if rows != nh:  # each group's rows of zeros at its end: head h stays row (h // rep) * rep' + h % rep
+        q = jnp.pad(q.reshape(B, kv, nh // kv, hd), ((0, 0), (0, 0), (0, (rows - nh) // kv), (0, 0))).reshape(B, rows, hd)
+    kernel = functools.partial(_kernel, blk=blk, kv=kv, rep=rows // kv, scale=1.0 / math.sqrt(hd))
+    out = _launch(kernel, name, layer, bound, [q],
                   [k_stack.reshape(L, B, S * kv, hd), v_stack.reshape(L, B, S * kv, hd)], blk * kv, blk, hd, interpret)
+    if rows != nh:
+        out = out.reshape(B, kv, rows // kv, hd)[:, :, :nh // kv]
     return out.reshape(B, nh * hd)
 
 
@@ -399,12 +420,15 @@ def refusal_blocks(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int
 
 # --------------------------------------------------------------------------- the op
 def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None, k_scale=None, v_scale=None,
-           sharded: bool = False):
+           sharded: bool = False, name: str = KERNEL):
     """One token a lane (its query q [B,nh,hd]) against layer ``layer`` of the stacked slot cache
     k/v_stack [L,B,S,kv,hd], in which the new token's key and value already sit at index
     lengths[b]. ``live`` [B] bool, where the caller knows it: lanes bound to a sequence (the kernel
     reads nothing for the others; the XLA form computes what nobody reads, as it always has).
     k/v_scale [L,B,kv,S]: an int8 cache's scales. ``sharded``: the caller is a shard_map body.
+    A stack of S rows that is a RING of a window of S positions (``llm/kv_cache.py``) is read the
+    same way: a lane at ``lengths`` holds min(lengths + 1, S) live rows, its first that many, which
+    is this op's bound for any stack; ``name`` is then the kernel's own name in a trace.
     -> [B, nh*hd] float32."""
     S = k_stack.shape[2]
     why = refusal(k_stack.dtype, q.shape[1], num_kv_heads, q.shape[2], S, quantized=k_scale is not None, sharded=sharded)
@@ -412,7 +436,7 @@ def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None,
         bound = jnp.minimum(lengths, S - 1) + 1
         # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
         return attend_kernel(q, k_stack, v_stack, layer, bound if live is None else jnp.where(live, bound, 0),
-                             interpret=jax.default_backend() != "tpu")
+                             interpret=jax.default_backend() != "tpu", name=name)
     k_rows, v_rows = layer_of(k_stack, layer), layer_of(v_stack, layer)
     if k_scale is not None:  # dequantize at the float32 the products already accumulate in
         k_rows = k_rows.astype(jnp.float32) * layer_of(k_scale, layer).transpose(0, 2, 1)[..., None]

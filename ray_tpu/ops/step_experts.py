@@ -74,7 +74,7 @@ def _kernel(layer_ref, ids_ref, n_ref, x_ref, comb_ref, *refs, act: str):
             a = rounded(jnp.square(jnp.maximum(up, 0.0)))
         else:
             gate = rounded(jax.lax.dot_general(x, mat_refs[0][...], nt, preferred_element_type=jnp.float32))
-            a = rounded(rounded(gate * jax.nn.sigmoid(gate)) * up)
+            a = rounded((jnp.maximum(gate, 0.0) if act == "reglu" else rounded(gate * jax.nn.sigmoid(gate))) * up)
         comb = comb_ref[...]  # [B, held]: this expert's column, picked without indexing a lane
         col = jnp.sum(jnp.where(jax.lax.broadcasted_iota(jnp.int32, comb.shape, 1) == ids_ref[j], comb, 0.0), axis=1, keepdims=True)
         o_ref[...] += jnp.dot((a * col).astype(x.dtype), mat_refs[-1][...], preferred_element_type=jnp.float32)

@@ -73,6 +73,10 @@ _OUTDATED = {
         "asserts that `latent_decode_roofline` is the LAST per-layer metric and that PR 36's cell is listed by the metrics of PR 36 "
         "and no others; PR 39 appended seven metrics at the end, four of which list the cell, as ISSUE 39 asked (PERF.md section 7, "
         "left by PR 39)",
+    "test_minicpm_sala_family.py::test_the_cell_is_listed_and_whatever_follows_it_was_appended":
+        "asserts, despite its name, that PR 45's cell and configuration are the LAST entries of BENCHMARK.json's lists and last in every "
+        "`workloads` list that names the cell; PR 49 appended its cell and configuration at the end, where the driver wants new entries, "
+        "and the file lies under the benchmark's paths, which a model_config PR may not edit (PERF.md section 7, left by PR 49)",
 }
 
 

@@ -310,7 +310,8 @@ CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widt
          "qwen3_next": ("qwen3-next-80b-a3b-ep4.json", {"remat": False}),  # 12 of 48 layers, 128 of 512 experts, 16 slots x 4096
          "glm": ("glm-4.7-flash-d8.json", {"remat": False}),  # 8 of 47 layers whole, 16 slots x 16,384
          "kimi": ("kimi-linear-48b-a3b-ep4.json", {"remat": False}),  # 9 of 27 layers, 64 of 256 experts, 16 slots x 4096
-         "sala": ("minicpm-sala-9b-d8.json", {"remat": False})}  # layers 9-16 of 32, the whole vocabulary, 16 slots x 12,288
+         "sala": ("minicpm-sala-9b-d8.json", {"remat": False}),  # layers 9-16 of 32, the whole vocabulary, 16 slots x 12,288
+         "smallthinker": ("smallthinker-21b-a3b-d8.json", {"remat": False})}  # layers 0-7 of 52, every expert, the whole vocabulary, 16 slots x 12,288
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -331,7 +332,7 @@ def _cell_at_its_size(one_chip, cell):
     # off the TPU "auto" picks the XLA attention; the chip runs the flash kernel
     cfg = common.load_family(c["family"]).program_config(c, S, attention_impl="pallas", **program_kw)
     params = _on(jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))), one_chip)
-    cache = _on(jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), slots, S)), one_chip)
+    cache = _on(jax.eval_shape(lambda: kvc.alloc_entries(cfg.position_entries(), slots, S, cfg.ring_entries())), one_chip)
     return cfg, params, cache, _on(jax.eval_shape(lambda: state_cache.alloc(cfg, slots)), one_chip)
 
 
@@ -794,3 +795,66 @@ def test_sala_prefill_of_the_12288_bucket_fits_beside_weights_and_caches_on_one_
     assert kernel and all("tpu_custom_call" in line and "sparse.attend" in line for line in kernel), "step 5 as one kernel, under its scope"
     assert mem.temp_size_in_bytes < most_gib * 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.58 * 2**30 < 15.0 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# PR 49: a sixth description, SmallThinker (models/smallthinker.py): the cell smallthinker-21b-d8.longdoc-12k.
+# ---------------------------------------------------------------------------
+def test_smallthinker_fused_step_fits_one_v5e_aliases_rows_and_rings_and_slices_no_layers_rows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 16 x 12,288 through the SAME ``hybrid_runner.fused_step`` and layer loop as
+    the five other descriptions (``attn moe swa moe swa moe swa moe`` twice, scanned, the routing
+    riding the loop beside the stream): 7.39 GiB of weights and 1.50 GiB of cache, half of it the two
+    global layers' rows for every position and half the six window layers' rings of 4,096 rows; all
+    of it aliased to the donated inputs; the live-block kernel under two names (28 query heads over
+    4 go as 32 rows), the experts' step kernel with the ReLU gate, and no slice of a layer's rows
+    (192 MiB of keys at 16 x 12,288, 64 MiB a ring) in the compiled text."""
+    import re
+
+    cfg, _, cache, state, compiled = fused_step_for_the_chip("smallthinker")
+    assert cfg.layer_plan == (("attn", "moe", "swa", "moe", "swa", "moe", "swa", "moe"), 2, (), ()) and state == {}
+    assert {n: a.shape for n, a in cache.items() if n != "length"} == {
+        "k": (2, 16, 12288, 4, 128), "v": (2, 16, 12288, 4, 128), "k_w": (6, 16, 4096, 4, 128), "v_w": (6, 16, 4096, 4, 128)}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert _kv_bytes(cache) == 1_610_612_736 == 2 * (2 * 16 * 12288 + 6 * 16 * 4096) * 1024
+    print("smallthinker fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+    assert 8.85 * 2**30 < mem.argument_size_in_bytes < 8.95 * 2**30 and mem.alias_size_in_bytes >= _kv_bytes(cache)
+    assert all(name in txt for name in ("slot_decode_attention", "window_decode_attention", "step_experts"))
+    assert not re.search(r"bf16\[(1,)?16,(12288|4096),4,128\]", txt)
+    assert mem.temp_size_in_bytes < 140 * 2**20  # 123 MiB: the window layers' query projections copied out of the stack inside the scan, as SALA's are (PERF.md section 7)
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 1.1), (4, 3.8)])
+def test_smallthinker_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 12,288-bucket prefill (the flash kernel with a window under ``swa``, without one under
+    ``attn``; 73,728 routed pairs a prompt through the grouped matmul) for one prompt and for the
+    largest group the cell warms, 4 x 12,288, beside 7.39 GiB of weights and 1.50 GiB of cache:
+    under 15.75 GiB. Eight prompts at once would not fit (6.6 GiB of temporaries and 1.5 GiB handed
+    to the cache): the engine's reckoning of the device's free memory halves such a wave."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "smallthinker")
+    tokens = jax.ShapeDtypeStruct((prompts, 12288), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("smallthinker prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    kernels = [line for line in txt.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert any("window_flash_attention" in line and "swa" in line for line in kernels), "the window layers' attention as the flash kernel, under its scope"
+    assert any("window_flash_attention" not in line and "/attn/" in line for line in kernels), "and the global layers' without a window"
+    assert mem.temp_size_in_bytes < most_gib * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 1.5 * 2**30 < 15.75 * 2**30
+
+
+def test_smallthinker_ring_insertion_updates_the_cache_in_place_and_gathers_only_the_windows_rows(one_chip):
+    """``insert_entries`` for one prefilled prompt of the 12,288 bucket: the global layers' rows
+    written whole, the window layers' LAST 4,096 positions gathered by the true length into their
+    ring: the donated cache aliased (1.50 GiB), and no temporary of a layer's rows or of a ring."""
+    from ray_tpu.llm import kv_cache as kvc
+
+    cfg, _, cache, _ = _cell_at_its_size(one_chip, "smallthinker")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    new = {n: s((a.shape[0], 12288) + a.shape[3:], a.dtype) for n, a in cache.items() if n != "length"}
+    insert = jax.jit(partial(kvc.insert_entries, rings=frozenset(cfg.ring_entries())), donate_argnums=(0,))
+    mem = insert.lower(cache, s((), jnp.int32), new, s((), jnp.int32)).compile().memory_analysis()
+    print("smallthinker insert:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+    assert mem.alias_size_in_bytes >= _kv_bytes(cache) and mem.temp_size_in_bytes < 8 * 2**20
