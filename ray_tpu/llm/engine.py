@@ -394,7 +394,7 @@ class LLMEngine:
 
         from ray_tpu.llm import kv_cache as kvc
         from ray_tpu.llm.model_runner import device_free_bytes, make_paged_runner_fns, make_runner_fns
-        from ray_tpu.llm.sampling import sample
+        from ray_tpu.llm.sampling import sample_first, seed_keys
         from ray_tpu.models.llama import init_params
         from ray_tpu.util.compile_cache import enable_compile_cache
 
@@ -453,7 +453,7 @@ class LLMEngine:
             buckets.append(self.max_seq_len)
             prefill_buckets = tuple(buckets)
         self.prefill_buckets = tuple(sorted(prefill_buckets))
-        self._sample = jax.jit(sample)
+        self._sample_first = jax.jit(sample_first)
 
         if kv_layout == "paged":
             from ray_tpu.llm import paged_kv as pkv
@@ -583,9 +583,6 @@ class LLMEngine:
         self._temps = np.zeros((B,), np.float32)
         self._top_k = np.zeros((B,), np.int32)
         self._top_p = np.ones((B,), np.float32)
-        self._keys = np.array(
-            jax.vmap(lambda s: jax.random.key_data(jax.random.PRNGKey(s)))(jnp.arange(B, dtype=jnp.uint32))
-        ).astype(np.uint32)
 
         self._slots: list[RequestState | None] = [None] * B
         self._waiting: deque[RequestState] = deque()
@@ -653,6 +650,12 @@ class LLMEngine:
         # in-flight fused step awaiting host readback:
         # (tokens [B] dev, logps [B] dev, [(RequestState, slot), ...])
         self._pending = None
+        # first tokens sampled on the device and not read yet, a group an entry: (tokens [G] dev,
+        # logps [G] dev, a hybrid prefill's routing counters or None, [(row, RequestState, slot), ...],
+        # the group's stamps or None, (tokens, padded tokens, shape counters) of a hybrid prefill or
+        # None); _read_first_tokens empties it, behind the step's dispatch. And the step's two counters
+        self._first_tokens: list = []
+        self._lanes_bound_device = self._first_token_syncs = 0
         # the shard_map hot path engages on a PURE tp mesh (other axes
         # would shard dims the per-shard programs assume replicated; a
         # mixed mesh falls back to the GSPMD compilation, fp collectives)
@@ -693,7 +696,7 @@ class LLMEngine:
                 config, mesh=tp_mesh, tp_collective=tp_collective, kv_quant=self.kv_quant,
                 partitioned=mesh is not None,
             )
-        self._set_lane, self._set_table, self._set_table_cell = make_delta_fns()
+        self._set_lanes, self._set_table, self._set_table_cell = make_delta_fns()
         if mesh is None:
             _put = jnp.asarray
         else:
@@ -704,7 +707,8 @@ class LLMEngine:
         # device-resident decode state; host arrays above stay as the
         # scheduler's shadow copies (never re-uploaded wholesale)
         self._dtokens = _put(np.zeros((B,), np.int32))  # each lane's input token for the next step
-        self._dkeys = _put(self._keys)
+        # each lane's key lives here alone: a seedless lane draws from it, a seeded admission overwrites it
+        self._dkeys = _put(np.asarray(seed_keys(jnp.arange(B, dtype=jnp.uint32))))
         self._dtemps = _put(self._temps)
         self._dtopk = _put(self._top_k)
         self._dtopp = _put(self._top_p)
@@ -1633,6 +1637,8 @@ class LLMEngine:
         if self._pending is not None:
             for entry in self._pending[-1]:  # lanes: (st, slot[, k_eff])
                 pending_k[id(entry[0])] = entry[2] if len(entry) > 2 else 0
+        for key in self._first_unread():  # bound this step: the first token is sampled and not read yet
+            pending_k[key] = 0
         for st in [s for s in self._slots if s is not None]:
             if st.slot < 0 or self._slots[st.slot] is not st:
                 continue  # preempted by an earlier iteration's _preempt_for
@@ -1828,7 +1834,7 @@ class LLMEngine:
                     break
             self._waiting.popleft()
             st.cached_pref = None  # admission consumes the cached resolution
-            self._slots[slot] = st  # reserve; _bind_slot fills the rest
+            self._slots[slot] = st  # reserve; _bind_group fills the rest
             wave.append((st, slot, pref, pages, prompt))
         for st in reversed(deferred):
             self._waiting.appendleft(st)  # original FIFO order restored
@@ -2107,8 +2113,11 @@ class LLMEngine:
         Plain prefills sharing a bucket run as ONE batched forward instead
         of B=1 dispatches; transferred-KV and prefix-hit requests scatter
         in without re-attending cached tokens; prefill-only requests
-        complete into handoff blocks inside _bind_slot. Returns the
-        admitted RequestStates."""
+        complete into handoff blocks inside _bind_group. Group after
+        group is enqueued (prefill, inserts, first-token sample, lane
+        write) and nothing is read: the first tokens stay on the device
+        until _stage_decode has dispatched the step that consumes them.
+        Returns the admitted RequestStates."""
         admitted: list[RequestState] = []
         if not wave:
             return admitted
@@ -2178,13 +2187,13 @@ class LLMEngine:
         inserted. This is how forward-only prefill reaches training-step
         MXU utilization instead of B=1 dispatch overhead.
 
-        Two stamped stages inside ``llm.step.prefill``, once a group:
-        ``llm.step.prefill.launch`` (the host until the prefill program
-        and every sequence's inserts are enqueued) and
-        ``llm.step.prefill.first_tokens`` (the host blocked reading the
-        first tokens back, one sequence after another), and the group's
-        three stamps on the step's row (``prefill_dispatch_t``: prefill
-        enqueued, inserts enqueued, first tokens read), against which a
+        One stamped stage inside ``llm.step.prefill``, once a group:
+        ``llm.step.prefill.launch`` (the host until the prefill program,
+        every sequence's inserts, the group's first-token sample and its
+        lane write are enqueued), and the group's three stamps on the
+        step's row (``prefill_dispatch_t``: prefill enqueued, all of it
+        enqueued, first tokens read: after the step's dispatch, or here
+        where the next dispatch needs them on the host), against which a
         trace's prefill executions are set (util/profiling.summarize)."""
         import jax.numpy as jnp
 
@@ -2237,25 +2246,24 @@ class LLMEngine:
                         # replaces whatever the slot's last sequence left: the reset of a recycled slot
                         with stage(tel, "llm.step.state_insert"):
                             self.state = self._state_insert(self.state, np.int32(slot), np.int32(i), kept)
-            t_launched = time.time() if tel is not None else 0.0
-        with stage(tel, "llm.step.prefill.first_tokens"):
-            # the first token of each: its sample waits for the prefill program's end
-            for i, (st, slot, _) in enumerate(group):
-                self._bind_slot(st, slot, logits[i : i + 1])
-        if tel is not None:
-            if tel.prefill_dispatch_t is None:
-                tel.prefill_dispatch_t = []
-            tel.prefill_dispatch_t.append([t_dispatch, t_launched, time.time()])
-        if self._hybrid and self._tel is not None:
-            # the step's row in the flight log: tokens prefilled, true and as padded, and the
-            # routing counters (zeros for a description that routes nothing), read AFTER the first
-            # tokens (whose readback the program's end already waited for): the copy of three floats
-            # waits for nothing
-            routing = np.asarray(kept["routing"]) if "routing" in kept else np.zeros((3,), np.float32)  # tpulint: disable=CCR002 — rides the first tokens' sync point: the prefill program has ended
-            seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
-            counted = self.config.prefill_counters(Bp, T, lengths=[len(p) for _, _, p in group])
-            self._prefill_stats = (seen[0] + int(sum(len(p) for _, _, p in group)), seen[1] + Bp * T,
-                                   seen[2] + 1, seen[3] + routing, {k: seen[4].get(k, 0) + v for k, v in counted.items()})
+            stamps = None
+            if tel is not None:
+                stamps = [t_dispatch, 0.0, 0.0]
+                if tel.prefill_dispatch_t is None:
+                    tel.prefill_dispatch_t = []
+                tel.prefill_dispatch_t.append(stamps)
+            stats = None
+            if self._hybrid and tel is not None:
+                # the step's row in the flight log: tokens prefilled, true and as padded, what the description
+                # counts of the shape; the routing counters ride the first tokens' readback
+                stats = (int(sum(len(p) for _, _, p in group)), Bp * T,
+                         self.config.prefill_counters(Bp, T, lengths=[len(p) for _, _, p in group]))
+            self._bind_group([(st, slot) for st, slot, _ in group], logits, stamps=stamps, stats=stats,
+                             routing=kept.get("routing") if stats is not None else None)
+            if stamps is not None:
+                stamps[1] = time.time()
+        if self._spec_cfg is not None:  # the drafter's history is built from the token: read it before the dispatch
+            self._read_first_tokens()
 
     def _admit_special_paged(self, st: RequestState, slot: int, pref, prompt):
         """Paged admission for transferred-KV / prefix-cache-hit requests
@@ -2323,7 +2331,9 @@ class LLMEngine:
         if st.resume is not None:
             self._bind_resume(st, slot)
         else:
-            self._bind_slot(st, slot, logits)
+            self._bind_group([(st, slot)], logits)
+            if self._spec_cfg is not None:  # the drafter's history is built from the token
+                self._read_first_tokens()
 
     def _admit_special_slots(self, st: RequestState, slot: int, pref, prompt):
         """Slot-layout admission for transferred-KV / prefix-cache-hit
@@ -2371,68 +2381,96 @@ class LLMEngine:
         if st.resume is not None:
             self._bind_resume(st, slot)
         else:
-            self._bind_slot(st, slot, logits)
+            self._bind_group([(st, slot)], logits)
+            if self._spec_cfg is not None:  # the drafter's history is built from the token
+                self._read_first_tokens()
 
-    def _bind_slot(self, st: RequestState, slot: int, logits):
-        import jax
-        import jax.numpy as jnp
-
+    def _bind(self, st: RequestState, slot: int):
+        """The host's side of a binding, which needs no device value: the slot, the admission order,
+        the request's stamps, the sampling parameters' shadows."""
         st.slot = slot
         st.admit_seq = self._admit_counter = getattr(self, "_admit_counter", 0) + 1
         self._slots[slot] = st
         if self._tel is not None:
             self._tel.on_bind(st, getattr(self, "_t_prefill_start", st.t_submit))
-        if st.prefill_only:
-            # prefill replica path: the block leaves, the slot recycles,
-            # decode never sees this request
-            self._complete_handoff(st, slot, logits)
-            return
         p = st.params
         self._temps[slot] = p.temperature
         self._top_k[slot] = p.top_k
         self._top_p[slot] = p.top_p
-        if p.seed is not None:
-            self._keys[slot] = np.asarray(jax.random.key_data(jax.random.PRNGKey(p.seed)))  # tpulint: disable=CCR002 — seeded lane key init: host PRNG material, one-time per admission
-        else:
-            # the lane's key lives on device (advanced by every fused
-            # step); pull its current value for the first-token sample.
-            # This blocks on the not-yet-drained in-flight step if one is
-            # pending, on seedless admissions only and bounded by one step
-            # per admission. Nothing asks for this parity any more: the key
-            # may stay on the device or be drawn on the host (ROADMAP.md A4
-            # (a) and A4a take the wait away, and are judged on the chip).
-            self._keys[slot] = np.asarray(self._dkeys[slot])  # tpulint: disable=CCR002 — documented first-sample key pull: bounded one pending step per seedless admission
-        tok, logp, key = self._sample(
-            logits,
-            jnp.asarray(self._keys[slot : slot + 1]),
-            jnp.asarray(self._temps[slot : slot + 1]),
-            jnp.asarray(self._top_k[slot : slot + 1]),
-            jnp.asarray(self._top_p[slot : slot + 1]),
-        )
-        self._keys[slot] = np.asarray(key[0])  # tpulint: disable=CCR002 — post-sample key readback rides the prefill's own sync point
-        token = int(tok[0])
-        self._push_lane(slot, token, p)  # first input token, advanced key, sampling params
-        spec_hist = (st.prompt_token_ids + st.token_ids + [token]) if self._spec_cfg is not None else None
-        self._emit(st, token, float(logp[0]))  # tpulint: disable=CCR002 — first-token emit: prefill output is already host-synced here
-        if spec_hist is not None:
-            self._spec_admit(st, slot, spec_hist)
 
-    def _push_lane(self, slot: int, token: int, p: SamplingParams):
-        """Lane delta: a slot's next input token, its key (``_keys[slot]``) and
-        sampling params into the device-resident decode state."""
-        self._dtokens, self._dkeys, self._dtemps, self._dtopk, self._dtopp = self._set_lane(
-            self._dtokens,
-            self._dkeys,
-            self._dtemps,
-            self._dtopk,
-            self._dtopp,
-            np.int32(slot),
-            np.int32(token),
-            self._keys[slot],
-            np.float32(p.temperature),
-            np.int32(p.top_k),
-            np.float32(p.top_p),
-        )
+    def _bind_group(self, lanes: list, logits, stamps=None, stats=None, routing=None):
+        """Bind a group's lanes (``lanes``: [(st, slot)], the first rows of ``logits`` [G, V]) and
+        enqueue its first tokens: ONE sample over the group's rows, the seedless rows' keys gathered
+        from the lanes on the device, and ONE lane write (first input token, advanced key, sampling
+        parameters) from the sample's device results. The host reads nothing here: the tokens wait in
+        ``_first_tokens`` for ``_read_first_tokens``. A prefill-only request's row is not sampled: its
+        block and logits leave at once (``_complete_handoff``), and decode never sees it."""
+        G, B = int(logits.shape[0]), self.max_num_seqs
+        slots = np.full((G,), B, np.int32)  # out of range: a row the lane write drops
+        seeds, seeded = np.zeros((G,), np.int32), np.zeros((G,), np.bool_)
+        temps, top_k, top_p = np.zeros((G,), np.float32), np.zeros((G,), np.int32), np.ones((G,), np.float32)
+        live = []
+        for i, (st, slot) in enumerate(lanes):
+            self._bind(st, slot)
+            if st.prefill_only:
+                self._complete_handoff(st, slot, logits[i : i + 1])
+                continue
+            p = st.params
+            slots[i], temps[i], top_k[i], top_p[i] = slot, p.temperature, p.top_k, p.top_p
+            if p.seed is not None:
+                # PRNGKey(seed) is made inside the program, from the seed as jax takes a Python int in
+                seeds[i], seeded[i] = np.int64(p.seed).astype(np.int32), True
+            live.append((i, st, slot))
+        if not live and stats is None:
+            return
+        tok = logp = None
+        if live:
+            if self._spec_cfg is None:
+                self._lanes_bound_device += len(live)  # no host round trip before the dispatch
+            tok, logp, keys = self._sample_first(logits, self._dkeys, slots, seeds, seeded, temps, top_k, top_p)
+            self._write_lanes(slots, tok, keys, temps, top_k, top_p)
+        self._first_tokens.append((tok, logp, routing, live, stamps, stats))
+
+    def _write_lanes(self, slots, tokens, keys, temps, top_k, top_p):
+        """Lane delta: the rows' next input tokens, keys and sampling params into the device-resident
+        decode state at ``slots``, from host or device values alike."""
+        self._dtokens, self._dkeys, self._dtemps, self._dtopk, self._dtopp = self._set_lanes(
+            self._dtokens, self._dkeys, self._dtemps, self._dtopk, self._dtopp, slots, tokens, keys, temps, top_k, top_p)
+
+    def _first_unread(self) -> set:
+        """ids of the requests bound this step whose first token the host has not read yet: they
+        hold one position more than ``token_ids`` says, as the lanes of a step in flight do."""
+        return {id(st) for entry in self._first_tokens for _, st, _ in entry[3]}
+
+    def _read_first_tokens(self):
+        """ONE blocking transfer for every group enqueued since the last call: first tokens and
+        log-probabilities (and a hybrid prefill's routing counters, which ride it), then the emits.
+        Called behind the step's dispatch, so the device runs that step while the host waits here;
+        a lane finished by its first token has then run one discarded trailing step, as a lane
+        finished by any later token has. A lane that lost its slot since (an abort, a preemption)
+        emits nothing."""
+        import jax
+
+        groups, self._first_tokens = self._first_tokens, []
+        if not groups:
+            return
+        host = jax.device_get([entry[:3] for entry in groups])  # tpulint: disable=CCR002 — the wave's one first-token readback, behind the dispatch of the step that runs meanwhile
+        self._first_token_syncs += 1
+        now = time.time()
+        for (tok, logp, routing), (_, _, _, live, stamps, stats) in zip(host, groups):
+            if stamps is not None:
+                stamps[2] = now
+            for i, st, slot in live:
+                if st.finished or st.slot != slot:
+                    continue
+                self._emit(st, int(tok[i]), float(logp[i]))  # tpulint: disable=CCR002 — reads the host arrays the one transfer above brought
+                if self._spec_cfg is not None:
+                    self._spec_admit(st, slot, st.prompt_token_ids + st.token_ids)
+            if stats is not None:
+                routing = np.zeros((3,), np.float32) if routing is None else routing
+                seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
+                self._prefill_stats = (seen[0] + stats[0], seen[1] + stats[1], seen[2] + 1, seen[3] + routing,
+                                       {k: seen[4].get(k, 0) + v for k, v in stats[2].items()})
 
     def _bind_resume(self, st: RequestState, slot: int):
         """Splice a restored live-state request into the decode loop
@@ -2443,23 +2481,17 @@ class LLMEngine:
         source's in-flight step, so the next client-visible token is
         minted by the first decode step here: the stream can neither
         repeat nor drop a token across the splice."""
-        st.slot = slot
-        st.admit_seq = self._admit_counter = getattr(self, "_admit_counter", 0) + 1
-        self._slots[slot] = st
-        if self._tel is not None:
-            self._tel.on_bind(st, getattr(self, "_t_prefill_start", st.t_submit))
+        self._bind(st, slot)
         rs = st.resume
         st.resume = None
         p = st.params
-        self._temps[slot] = p.temperature
-        self._top_k[slot] = p.top_k
-        self._top_p[slot] = p.top_p
         # the checkpointed key, NEVER re-derived from the seed: a seeded
         # lane's key advanced once per sample at the source, and the
         # oracle's post-splice draws continue that sequence
-        self._keys[slot] = np.asarray(rs["rng_key"], np.uint32)  # tpulint: disable=CCR002 — checkpoint splice: rs is host state from llm/migrate.py, not a device array
-        token = int(st.token_ids[-1])
-        self._push_lane(slot, token, p)
+        key = np.asarray(rs["rng_key"], np.uint32)  # tpulint: disable=CCR002 — checkpoint splice: rs is host state from llm/migrate.py, not a device array
+        # a group of one, from host values: the last emitted token is the next decode input
+        self._write_lanes(np.int32([slot]), np.int32(st.token_ids[-1:]), key[None],
+                          np.float32([p.temperature]), np.int32([p.top_k]), np.float32([p.top_p]))
         if self._spec_cfg is not None:
             spec = rs.get("spec") or {}
             self._controller.restore(st.request_id, spec.get("ema"), spec.get("k"))
@@ -2564,6 +2596,8 @@ class LLMEngine:
                 self._last_spec_drain = None
                 self._moe_stats = None
                 self._step_emitted = 0
+                self._first_tokens = []
+                self._lanes_bound_device = self._first_token_syncs = 0
                 with stage(tel, "llm.step.admission"):
                     wave = self._stage_admission()
                 with stage(tel, "llm.step.prefill"):
@@ -2593,9 +2627,15 @@ class LLMEngine:
         """DECODE stage: advance every occupied slot one tick: dispatch
         the fused (or speculative) step and drain the PREVIOUS one.
         Prefill-only requests never reach here — they finished (and freed
-        their slot) inside the prefill stage. Three telemetry stages:
+        their slot) inside the prefill stage. Four telemetry stages:
         dispatch (host time to enqueue), drain_wait (the host blocked on
-        the device's readback), emit (finish detection, queue puts)."""
+        the device's readback), emit (finish detection, queue puts), and
+        in an admitting step prefill.first_tokens: the wave's first
+        tokens read and emitted, while the device runs the step just
+        dispatched behind the wave's prefills. They come after the last
+        step's tokens (which the device had ready before the prefills
+        began) and before the new lanes' second tokens, which that step
+        is making."""
         spec = self._spec_cfg is not None
         with stage(tel, "llm.step.dispatch"):
             if self.kv_layout == "paged":
@@ -2612,6 +2652,9 @@ class LLMEngine:
             host = self._drain_wait(prev)
         with stage(tel, "llm.step.emit"):
             emitted = self._drain_spec(prev, host) if spec else self._drain(prev, host)
+        if self._first_tokens:
+            with stage(tel, "llm.step.prefill.first_tokens"):
+                self._read_first_tokens()
         self._step_emitted = len(emitted)
         return admitted + emitted
 
@@ -2625,8 +2668,9 @@ class LLMEngine:
     def _positions_held(self, active: list, prev) -> list:
         """Positions each active lane holds as the step about to be dispatched attends, its new
         token's among them. From host state alone: a lane holds its prompt and the tokens emitted
-        so far, and one more where the step still in flight (``prev``) ran it."""
-        in_flight = {id(st) for st, _ in prev[-1]} if prev is not None else ()
+        so far, and one more where the step still in flight (``prev``) ran it or its first token is
+        sampled and not read yet."""
+        in_flight = self._first_unread() | ({id(st) for st, _ in prev[-1]} if prev is not None else set())
         return [min(len(st.prompt_token_ids) + len(st.token_ids) + (id(st) in in_flight), self.max_seq_len) for st in active]
 
     def _count_step_attn_blocks(self, active: list, prev) -> tuple:

@@ -306,13 +306,13 @@ def _bucket_paged_fused_q(B=8, pages=64, page=16):
     ), {}
 
 
-def _bucket_set_lane(B=8):
+def _bucket_set_lanes(B=8, G=4):
     tokens, keys, temps, top_k, top_p = _sds_lanes(B)
-    scalars = (
-        _sds((), jnp.int32), _sds((), jnp.int32), _sds((2,), jnp.uint32),
-        _sds((), jnp.float32), _sds((), jnp.int32), _sds((), jnp.float32),
+    rows = (
+        _sds((G,), jnp.int32), _sds((G,), jnp.int32), _sds((G, 2), jnp.uint32),
+        _sds((G,), jnp.float32), _sds((G,), jnp.int32), _sds((G,), jnp.float32),
     )
-    return (tokens, keys, temps, top_k, top_p) + scalars, {}
+    return (tokens, keys, temps, top_k, top_p) + rows, {}
 
 
 def _qkv(xn, layer, cfg: LlamaConfig):
@@ -978,19 +978,18 @@ def make_fused_paged_fns(cfg: LlamaConfig, mesh=None, tp_collective: str = "fp",
 
 
 @jaxcheck.entry(
-    name="llm.delta_set_lane",
-    shapes={"b8": _bucket_set_lane},
+    name="llm.delta_set_lanes",
+    shapes={"b8_g4": _bucket_set_lanes},
     donate_bytes=0,
 )
-def set_lane(tokens, keys, temps, top_k, top_p, slot, token, key, temp, tk, tp):  # tpulint: disable=JXC001 — delta fns deliberately donate nothing: the engine may still hold every one of these buffers for an in-flight step's delayed readback when a scheduler delta lands
-    """O(1) jitted scatter for admission: write one slot's lane state."""
-    return (
-        tokens.at[slot].set(token),
-        keys.at[slot].set(key),
-        temps.at[slot].set(temp),
-        top_k.at[slot].set(tk),
-        top_p.at[slot].set(tp),
-    )
+def set_lanes(tokens, keys, temps, top_k, top_p, slots, token, key, temp, tk, tp):  # tpulint: disable=JXC001 — delta fns deliberately donate nothing: the engine may still hold every one of these buffers for an in-flight step's delayed readback when a scheduler delta lands
+    """Jitted scatter for admission: write a group's lane state, a row a slot (``slots`` [G]; the
+    rest [G] or [G, 2]), from values that may never have left the device. A row whose slot is out
+    of range (a group's padding) is dropped."""
+    def put(lane, rows):
+        return lane.at[slots].set(rows, mode="drop")
+
+    return put(tokens, token), put(keys, key), put(temps, temp), put(top_k, tk), put(top_p, tp)
 
 
 def set_table(tables, lengths, slot, row, length):
@@ -1003,11 +1002,12 @@ def set_table_cell(tables, slot, pg_ix, page):
 
 def make_delta_fns():
     """Jitted scatter updates for scheduler deltas on device-resident
-    decode state (admission / eviction / page growth). Each compiles once
-    (slot/index are traced scalars) and touches O(1) elements — the
-    replacement for re-uploading whole host arrays every step. Nothing is
-    donated (see set_lane's inline rationale)."""
-    return jax.jit(set_lane), jax.jit(set_table), jax.jit(set_table_cell)
+    decode state (admission / eviction / page growth). The table writes
+    compile once (slot/index are traced scalars), the lane write once a
+    group size, and each touches O(group) elements — the replacement for
+    re-uploading whole host arrays every step. Nothing is donated (see
+    set_lanes' inline rationale)."""
+    return jax.jit(set_lanes), jax.jit(set_table), jax.jit(set_table_cell)
 
 
 # ---------------------------------------------------------------------------

@@ -135,3 +135,20 @@ def sample(logits, key, temperature, top_k, top_p):
         logp = jax.nn.log_softmax(logits, axis=-1)
         chosen_logp = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
         return tokens, chosen_logp, new_keys
+
+
+def seed_keys(seeds):
+    """[N] integer seeds -> [N, 2] u32: ``PRNGKey(seed)``'s data a row, where a seeded lane's chain starts."""
+    return jax.vmap(lambda s: jax.random.key_data(jax.random.PRNGKey(s)))(seeds)
+
+
+def sample_first(logits, lane_keys, slots, seeds, seeded, temperature, top_k, top_p):
+    """A group's first tokens, a row a sequence, from keys that never leave the device.
+
+    logits: [G, V]; lane_keys: [B, 2] u32, every lane's key as the last step left it; slots: [G]
+    i32; seeds: [G] i32; seeded: [G] bool; temperature/top_k/top_p: [G], as sample() takes them.
+    A seeded row starts its chain at ``PRNGKey(seed)``, a seedless one draws from its lane's own
+    key. A padding row's slot is out of range: it reads some lane's key and samples garbage that
+    nothing reads. Returns sample()'s triple, the keys advanced once."""
+    keys = jnp.where(seeded[:, None], seed_keys(seeds), jnp.take(lane_keys, slots, axis=0, mode="clip"))
+    return sample(logits, keys, temperature, top_k, top_p)

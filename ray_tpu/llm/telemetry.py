@@ -79,25 +79,31 @@ DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read")
 
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
+    # the wave LAUNCHED, nothing read: it ends when the last group is enqueued
     "llm.step.prefill": "prefill_ms",
     # inside llm.step.prefill, once a group of the wave: the host from the group's start until its
-    # prefill program and its inserts are dispatched, then the host blocked reading the group's
-    # first tokens back (sums over the wave's groups)
+    # prefill program, its inserts, its first-token sample and its lane write are dispatched (sums
+    # over the wave's groups)
     "llm.step.prefill.launch": "prefill_launch_ms",
     # inside llm.step.prefill.launch: a hybrid model's recurrent state written into its slot
     "llm.step.state_insert": "state_insert_ms",
-    "llm.step.prefill.first_tokens": "first_token_wait_ms",
     "llm.step.dispatch": "dispatch_ms",
     "llm.step.drain_wait": "drain_wait_ms",
     "llm.step.emit": "emit_ms",
+    # an admitting step's one readback of the wave's first tokens, and their emits: BEHIND the
+    # dispatch, while the device runs the step that consumes them. (Where the next dispatch needs
+    # the tokens on the host, under speculation, the read stands inside llm.step.prefill and has
+    # no stage of its own: a group's third stamp says when it returned.)
+    "llm.step.prefill.first_tokens": "first_token_wait_ms",
     "llm.step.outputs": "outputs_ms",
     "llm.stepper.deliver": "stepper_deliver_ms",
     "llm.stepper.wait": "stepper_wait_ms",
 }
 _STAGE_IX = {name: i for i, name in enumerate(STAGES)}
 # stages timed INSIDE another: their time is in the outer stage's too
-INSIDE = {"llm.step.prefill.launch": "llm.step.prefill", "llm.step.prefill.first_tokens": "llm.step.prefill",
-          "llm.step.state_insert": "llm.step.prefill.launch"}
+INSIDE = {"llm.step.prefill.launch": "llm.step.prefill", "llm.step.state_insert": "llm.step.prefill.launch"}
+# the stages that tile a step's row, in the order they run: each ends where the next starts, the last at the row's ``t``
+TILED = [name for name in STAGES if name.startswith("llm.step.") and name not in INSIDE]
 
 # time.time() at which the serving ingress took the request now being
 # admitted on this thread/task (OpenAIServer.__call__ sets it, on_submit
@@ -381,9 +387,16 @@ class FlightRecorder:
         # the step's start and the instant its fused program was enqueued
         # (both time.time(); dispatch_t absent where none was); of an ADMITTING
         # step, for each group of its wave [its prefill program enqueued, its
-        # inserts enqueued too (launch's end), its first tokens read back];
-        # then the stage durations
-        "t0", "dispatch_t", "prefill_dispatch_t",
+        # inserts, first-token sample and lane write enqueued too (launch's
+        # end), its first tokens read back (after dispatch_t, but where the
+        # dispatch needed them on the host)]; and of an admitting step the
+        # lanes whose first token was sampled and bound by device programs
+        # alone, no host round trip before the dispatch (all that were
+        # admitted, but lanes resumed from a checkpoint, handed off after
+        # their prefill, or bound under speculation), and the blocking
+        # readbacks of first tokens (at most one a wave; one a group under
+        # speculation)
+        "t0", "dispatch_t", "prefill_dispatch_t", "lanes_bound_device", "first_token_syncs",
         # a hybrid model's drained decode step (llm/hybrid_runner.MOE_STATS): held experts that
         # got a token (mean over expert layers), (token, expert) pairs served here and asked
         # for in all, most tokens at one expert, held experts whose weights the step read (mean over
@@ -648,7 +661,7 @@ class EngineTelemetry:
         sentinel (called after the engine finished building them)."""
         eng = self.engine
         for name in ("_fused_step", "_fused_attn", "_fused_append",
-                     "_set_lane", "_set_table", "_set_table_cell",
+                     "_set_table", "_set_table_cell",
                      "_verify_step", "_verify_attn", "_verify_append"):
             self.recorder.register_entry(name.lstrip("_"), getattr(eng, name, None))
         if getattr(eng, "_tp_fused", False):
@@ -718,13 +731,16 @@ class EngineTelemetry:
             st.trace = (trace_id, uuid.uuid4().hex[:16], parent_id)  # (trace, root span, parent)
 
     def on_bind(self, st, t_prefill_start: float) -> None:
-        """Slot bound + prefill executed: close the admission and prefill
-        spans, observe queue wait. FIRST bind only — a recompute-preempted
-        request re-binds through here, but its queue wait was already
-        observed (re-measuring from t_submit would report the request's
-        whole lifetime) and a second admission/prefill span pair would
-        show the one request admitted twice; preemptions have their own
-        counter and flight-record field."""
+        """Slot bound + prefill LAUNCHED (the engine binds a group's lanes
+        as it enqueues the group; the device's work on the prompt falls
+        under ``llm.first_token``, which ends at the first emit): close
+        the admission and prefill spans, observe queue wait. FIRST bind
+        only — a recompute-preempted request re-binds through here, but
+        its queue wait was already observed (re-measuring from t_submit
+        would report the request's whole lifetime) and a second
+        admission/prefill span pair would show the one request admitted
+        twice; preemptions have their own counter and flight-record
+        field."""
         now = time.time()
         if st.t_admit != 0.0:
             return
@@ -779,9 +795,10 @@ class EngineTelemetry:
             "reason": reason,
             # the request path's boundary stamps, all time.time():
             # ingress (serving entry, before parse/encode/admission) ->
-            # submit (engine queue) -> admit (prefill done) -> first
-            # token (engine emit) -> first/last yield (the stream's
-            # generator; stamped by on_stream once the stream ends)
+            # submit (engine queue) -> admit (slot bound, prefill
+            # launched) -> first token (engine emit) -> first/last yield
+            # (the stream's generator; stamped by on_stream once the
+            # stream ends)
             "ingress_t": st.t_ingress,
             "first_yield_t": None,
             "last_yield_t": None,
@@ -968,7 +985,9 @@ class EngineTelemetry:
             eng._page_alloc.free_pages if paged else None,
             eng._pcfg.num_pages - 1 if paged else None,
             recompiled or None, sd[0], sd[1],
-            self._step_t0, dispatch_t, prefill_t, *moe, *[round(ms, 4) for ms in stages],
+            self._step_t0, dispatch_t, prefill_t,
+            *((eng._lanes_bound_device, eng._first_token_syncs) if n_admitted else (None, None)),
+            *moe, *[round(ms, 4) for ms in stages],
             round((_GC_HELD[0] - self._gc_seen) * 1e3, 3) or None,
         ))
         self._gc_seen = _GC_HELD[0]
@@ -1093,6 +1112,17 @@ def dispatch_stamps(steps: list) -> dict:
             "prefill": [g[0] for s in steps for g in s.get("prefill_dispatch_t") or ()]}
 
 
+def drain_stamps(steps: list) -> list:
+    """For every fused step ``dispatch_stamps`` lists, in its order: the host's time (time.time()) at
+    which that step's tokens were on the host: the end of the NEXT row's ``drain_wait`` (a row's
+    stages end at ``t``); None for the last row's. An execution has ended by then, which bounds the
+    device's clock from the side its start after the dispatch does not (``util/profiling._align``)."""
+    steps = sorted(steps, key=lambda s: s["t0"])
+    after = [STAGES[name] for name in TILED[TILED.index("llm.step.drain_wait") + 1:]]
+    return [nxt and nxt["t"] - sum(float(nxt.get(col) or 0.0) for col in after) * 1e-3
+            for s, nxt in zip(steps, steps[1:] + [None]) if s.get("dispatch_t")]
+
+
 IN_STEP = "in step, no stage"  # the engine's lock, on_step's own time
 
 
@@ -1102,10 +1132,11 @@ def timeline(steps: list) -> list[tuple]:
     holds its start (``t0``), the stamp at its end (``t``: on_step's first act) and its stages'
     durations; the stages run in STAGES' order and end at ``t``, so each one's edges follow by
     walking back from there, and what is left before the first is the wait for the engine's
-    lock. Inside ``prefill``, a group's launch and first-token wait lie where its stamps
-    (``prefill_dispatch_t``) say, the state inserts (whose sum alone is known) at the launches'
-    ends; what is neither is ``prefill``. Between two steps the stepper's wait ends at the next
-    step's start and its delivery stands before that. Labels: STAGES' names less their prefix."""
+    lock. Inside ``prefill``, a group's launch lies where its stamps (``prefill_dispatch_t``) say,
+    the state inserts (whose sum alone is known) at the launches' ends, and a group's first-token
+    read where its third stamp falls inside the stage (under speculation; otherwise the wave's one
+    read is the stage of that name, after ``emit``); what is neither is ``prefill``. Between two
+    steps the stepper's wait ends at the next step's start and its delivery stands before that. Labels: STAGES' names less their prefix."""
     def label(name):
         return name.split(".", 2)[2] if name.startswith("llm.step.") else name[len("llm."):]
 
@@ -1127,7 +1158,7 @@ def timeline(steps: list) -> list[tuple]:
             put("stepper.wait", t0 - wait, t0)
         t0 = t0 if last_end is None else max(t0, last_end)
         edges, at = {}, t
-        for name in reversed([n for n in STAGES if n.startswith("llm.step.") and n not in INSIDE]):
+        for name in reversed(TILED):
             edges[name] = (max(at - ms(name), t0), at)
             at = edges[name][0]
         put(IN_STEP, t0, at)
@@ -1136,18 +1167,20 @@ def timeline(steps: list) -> list[tuple]:
             if not groups:
                 put(label(name), a, b)
                 continue
-            # launch g = [start, launched], first tokens g = [launched, read], and the next group's launch starts there
-            later = sum(g[1] - h[2] for h, g in zip(groups, groups[1:]))
+            # launch g = [start, launched], and the next group's launch starts there, or where g's first
+            # tokens were read if that was inside this stage
+            ends = [read if launched <= read <= b else launched for _, launched, read in groups]
+            later = sum(g[1] - end for end, g in zip(ends, groups[1:]))
             at = min(max(groups[0][1] - max(ms("llm.step.prefill.launch") - later, 0.0), a), b)
             put("prefill", a, at)
             insert = ms("llm.step.state_insert") / len(groups)
-            for _, launched, read in groups:
-                launched, read = min(max(launched, at), b), min(max(read, at), b)
+            for (_, launched, _), end in zip(groups, ends):
+                launched, end = min(max(launched, at), b), min(max(end, at), b)
                 cut = max(launched - insert, at)
                 put("prefill.launch", at, cut)
                 put("state_insert", cut, launched)
-                put("prefill.first_tokens", launched, read)
-                at = max(read, launched)
+                put("prefill.first_tokens", launched, end)
+                at = end
             put("prefill", at, b)
         last_end = t
     return out

@@ -425,33 +425,71 @@ def _best_shift(starts: list, stamps: list) -> int | None:
     return int(np.argmin(diff.max(axis=1) - diff.min(axis=1)))
 
 
-def _align(runs: list, anchors: dict, idle_before: dict) -> dict:
+def _shifts(runs: list, anchors: dict) -> dict:
+    """{word: which of its stamps the word's first execution goes with}. The device runs its programs
+    in the order the host dispatched them, so the executions' order by family (fused, prefill, prefill,
+    fused, ...) is a stretch of the stamps' order by family, and where it occurs once that is the
+    alignment, whatever the times say: a step dispatched behind a wave's prefills starts long after
+    its own stamp and, the loop being bound by the device, a steady host round BEFORE the next one, so
+    times alone take the next. Where it occurs more than once (a run as regular as a clock), the
+    occurrence under which ``start - stamp`` varies least; where it does not occur (rows dropped from
+    the log), each family by that rule alone (``_best_shift``)."""
+    words = [w for w in anchors if any(w in name for name, _, _ in runs)]
+    execs = sorted((start, i) for name, start, _ in runs for i, w in enumerate(words) if w in name)
+    stamps = sorted((t * 1e9, i) for i, w in enumerate(words) for t in anchors[w])
+    order, stamped = ("".join(chr(65 + i) for _, i in seq) for seq in (execs, stamps))
+    found, k = [], stamped.find(order) if order else -1
+    while k >= 0:
+        found.append(k)
+        k = stamped.find(order, k + 1)
+    if not found:
+        return {w: _best_shift([s for name, s, _ in runs if w in name], [t * 1e9 for t in anchors[w]]) for w in words}
+
+    def spread(k):
+        d = [start - t for (start, _), (t, _) in zip(execs, stamps[k:])]
+        return max(d) - min(d)
+
+    k = min(found, key=spread)
+    return {w: stamped[:k].count(chr(65 + i)) for i, w in enumerate(words)}
+
+
+def _align(runs: list, anchors: dict, idle_before: dict, drained: list | None = None) -> dict:
     """The offset between the device's clock and the host's, from events both sides record.
     ``runs``: chip 0's executions (program name, start_ns, duration_ns) in order; ``anchors``:
     {"fused": [...], "prefill": [...]} host stamps (seconds) of every dispatch of the run, in
     order; ``idle_before``: execution start -> the ns no program had run before it. An execution
     that found the device idle started as soon as the host had dispatched it, so its start less
     its stamp IS the offset, to within a launch; one that found it busy only bounds the offset
-    from above. The offset is the MEDIAN over the launches onto an idle device (the tightest
-    bound over all pairs where there are fewer than three), not their minimum: a stamp is taken
-    after the dispatching call returns, and a thread that loses the interpreter in between stamps
-    late by milliseconds (one such pair in 750 moved a minimum by 2.5 to 7.5 ms, PERF.md PR 39).
+    from above. The offset is the MEDIAN over the launches onto an idle device, not their minimum:
+    a stamp is taken after the dispatching call returns, and a thread that loses the interpreter in
+    between stamps late by milliseconds (one such pair in 750 moved a minimum by 2.5 to 7.5 ms,
+    PERF.md PR 39). Where fewer than three launches found the device idle, the loop is bound by the
+    device and every start lies a queue's length above its stamp; then the other side bounds tightly:
+    ``drained`` (``llm/telemetry.drain_stamps``: for every fused stamp the host's time at which that
+    step's tokens had been read) says an execution had ENDED by then, and the host, blocked on that
+    read, returns from it a transfer after the end. The offset is then the largest ``end - drained``
+    (``clock_bounds_ms``: how far below the tightest bound from above it lies), and without
+    ``drained`` that bound from above.
     ``clock_residual_ms``: the spread (interquartile range) of those launches about the offset;
     ``clock_residual_max_ms`` the farthest of them; ``late_stamps`` how many pairs of all lie more
     than a millisecond BELOW the offset (a start before its stamp: the stamp was late) and
     ``latest_stamp_ms`` by how much at most."""
-    pairs = []
-    for word, stamps in anchors.items():
+    pairs, shifts = [], _shifts(runs, anchors)
+    for word, shift in shifts.items():
         starts = [s for name, s, _ in runs if word in name]
-        shift = _best_shift(starts, [t * 1e9 for t in stamps])
         if shift is not None:
-            pairs += [(s - stamps[shift + i] * 1e9, s) for i, s in enumerate(starts)]
+            pairs += [(s - anchors[word][shift + i] * 1e9, s) for i, s in enumerate(starts)]
     if not pairs:
         return {}
     waited = sorted(d for d, s in pairs if idle_before.get(s, 0) >= 100_000)
     lowest = min(d for d, _ in pairs)
-    offset = statistics.median(waited) if len(waited) >= 3 else lowest
-    out = {"offset_ns": offset, "anchors": len(pairs), "anchors_on_an_idle_device": len(waited),
+    offset, bounds_ms = statistics.median(waited) if len(waited) >= 3 else lowest, None
+    if len(waited) < 3 and drained and shifts.get("fused") is not None:
+        fused = [(s, d) for name, s, d in runs if "fused" in name]
+        below = [s + d - drained[k] * 1e9 for k, (s, d) in enumerate(fused, shifts["fused"]) if k < len(drained) and drained[k]]
+        if below:
+            offset, bounds_ms = min(max(below), lowest), (lowest - max(below)) * 1e-6
+    out = {"offset_ns": offset, "clock_bounds_ms": bounds_ms, "anchors": len(pairs), "anchors_on_an_idle_device": len(waited),
            "clock_residual_ms": None, "clock_residual_max_ms": None,
            "late_stamps": sum(1 for d, _ in pairs if d < offset - 1e6), "latest_stamp_ms": max(offset - lowest, 0.0) * 1e-6}
     if waited:
@@ -610,14 +648,14 @@ def summarize(logdir_or_xplane: str, flight: list | None = None, stretch_s: floa
     if flight is None:
         return out
 
-    from ray_tpu.llm.telemetry import dispatch_stamps, timeline
+    from ray_tpu.llm.telemetry import dispatch_stamps, drain_stamps, timeline
 
     idle_before, free_at = {}, None  # an execution's start -> how long no program had run before it
     for _, start, dur in sorted(runs, key=lambda r: r[1]):
         if free_at is not None:
             idle_before[start] = start - free_at
         free_at = max(free_at or 0, start + dur)
-    clock = _align(runs, dispatch_stamps(flight), idle_before)
+    clock = _align(runs, dispatch_stamps(flight), idle_before, drain_stamps(flight))
     ann = _annotation_offset(planes, flight)
     if ann is not None:
         clock["annotation_offset_ns"] = ann
@@ -646,7 +684,8 @@ def tables(summary: dict, programs: tuple = ("prefill", "fused")) -> list[str]:
         clock = summary.get("clock", {})
         total = summary["window_s"] - summary["busy_s"]
         lines.append(f"idle: {total:.4f} s of a {summary['window_s']:.4f} s window; clock from {clock.get('anchors', 0)} dispatches, "
-                     f"residual {clock.get('clock_residual_ms')} ms (at most {clock.get('clock_residual_max_ms')}), {clock.get('late_stamps')} late stamps (by {clock.get('latest_stamp_ms')} ms at most)")
+                     f"residual {clock.get('clock_residual_ms')} ms (at most {clock.get('clock_residual_max_ms')}), {clock.get('late_stamps')} late stamps (by {clock.get('latest_stamp_ms')} ms at most)"
+                     + (f"; from the reads of a loop the device bounds, {clock['clock_bounds_ms']:.3f} ms under the bound from above" if clock.get("clock_bounds_ms") is not None else ""))
         for label, piece in sorted(summary["idle"].items(), key=lambda kv: -kv[1]["s"]):
             lines.append(f"  {label:<22} {piece['s']:9.4f} s  {piece['gaps']:>6} gaps")
     return lines

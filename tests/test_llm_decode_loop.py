@@ -12,7 +12,15 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from plain_reference import assert_streams_are_the_references, drive, full_forward_sampled  # noqa: E402
+from plain_reference import (  # noqa: E402
+    _padded,
+    _padded_forward,
+    admitted as plain_admitted,
+    assert_streams_are_the_references,
+    drive,
+    full_forward_sampled,
+    reference_stream,
+)
 
 from ray_tpu.llm import LLMEngine, SamplingParams  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
@@ -120,3 +128,100 @@ def test_the_loop_is_no_option(params):
     gone = "device_" + "resident"  # in two halves: a grep of the tree for the name finds nothing
     with pytest.raises(TypeError, match=gone):
         LLMEngine(CFG, params=params, max_num_seqs=1, max_seq_len=64, **{gone: False})
+
+
+# ------------------------------------------------------------------------------------------------
+# A wave's first tokens stay on the device (PR 50): one sample and one lane write a group, the
+# step dispatched behind the wave's prefills, one readback after it
+# ------------------------------------------------------------------------------------------------
+def _wave(n):
+    """n prompts in two buckets (16 and 64), greedy, seeded and seedless lanes mixed."""
+    rng = np.random.default_rng(50 + n)
+    prompts = [[int(t) for t in rng.integers(1, CFG.vocab_size - 1, size=length)] for length in [5, 40, 9, 33, 12][:n]]
+    sps = [SamplingParams(max_tokens=6, logprobs=True),
+           SamplingParams(max_tokens=7, temperature=0.9, top_k=12, seed=31, logprobs=True),
+           SamplingParams(max_tokens=5, temperature=0.8, logprobs=True),  # seedless: it draws from its lane's own key
+           SamplingParams(max_tokens=6, temperature=0.7, top_p=0.9, logprobs=True),  # seedless
+           SamplingParams(max_tokens=4, temperature=1.0, seed=7, logprobs=True)][:n]
+    return prompts, sps
+
+
+# what the parent (13c6792: one sample, three readbacks and one lane write a SEQUENCE) served for the same waves
+PARENT_STREAMS = {
+    3: [[307, 443, 273, 331, 387, 396], [364, 135, 482, 385, 59, 296, 148], [415, 433, 123, 488, 380]],
+    5: [[37, 493, 501, 303, 501, 47], [451, 108, 334, 227, 124, 185, 463], [211, 363, 123, 474, 176], [27, 411, 181, 235, 148, 276],
+        [436, 312, 245, 122]],
+}
+PARENT_FIRST_LOGPS = {3: [-3.8761, -3.8542, -5.3135], 5: [-3.9255, -4.5176, -5.4045, -5.2191, -5.8256]}
+
+
+def _reference_logps(params, prompt, tokens):
+    """log-probability of each token of a stream under the whole-sequence forward, no cache."""
+    toks, out = list(prompt), []
+    for t in tokens:
+        logits = _padded_forward(CFG)(params, _padded(CFG, toks))[0, len(toks) - 1]
+        out.append(float(jax.nn.log_softmax(logits.astype("float32"))[t]))
+        toks.append(t)
+    return out
+
+
+@pytest.mark.parametrize("n", [3, 5])
+def test_a_waves_streams_are_the_references_and_the_parents(params, n):
+    """A wave of n prompts in two groups: every lane's stream, seedless ones too, is what the parent served
+    (the seedless lanes draw from the same device keys), and the greedy and seeded ones are the plain
+    reference's, tokens and log-probabilities."""
+    prompts, sps = _wave(n)
+    eng = LLMEngine(CFG, params=params, max_num_seqs=6, max_seq_len=128, prefill_buckets=(16, 64), enable_prefix_caching=False)
+    outs = eng.generate(prompts, sps)
+    assert [o.token_ids for o in outs] == PARENT_STREAMS[n]
+    assert [o.logprobs[0] for o in outs] == pytest.approx(PARENT_FIRST_LOGPS[n], abs=2e-4)
+    for o, prompt, sp in zip(outs, prompts, sps):
+        if sp.temperature == 0.0 or sp.seed is not None:
+            assert (o.token_ids, o.finish_reason) == reference_stream(CFG, params, prompt, sp)
+        assert o.logprobs == pytest.approx(_reference_logps(params, prompt, o.token_ids), abs=2e-4)
+    wave = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+    assert [(s["admitted"], len(s["prefill_dispatch_t"]), s["lanes_bound_device"], s["first_token_syncs"]) for s in wave] == [(n, 2, n, 1)]
+
+
+@pytest.mark.parametrize("ending", ["max_tokens", "stop"])
+def test_a_lane_that_ends_on_its_first_token_gives_its_slot_to_the_next(params, ending):
+    """Inside a group of two, one request's first token ends it (``max_tokens=1``; a stop token): the host
+    learns of it behind the dispatch, so the lane has run one discarded step, as a lane that ends on any
+    later token has. One token emitted, the slot recycled, and the stream admitted into it is the reference's."""
+    first = [9, 4, 33, 2]
+    t0 = reference_stream(CFG, params, first, SamplingParams(max_tokens=1))[0][0]
+    short = SamplingParams(max_tokens=1) if ending == "max_tokens" else SamplingParams(max_tokens=5, stop_token_ids=(t0,))
+    sched = {0: [(first, short), ([5, 6, 7, 8, 1], SamplingParams(max_tokens=7))],
+             1: [([3, 1, 4, 1, 5, 9, 2, 6], SamplingParams(max_tokens=6, temperature=0.9, seed=5))]}
+    eng = LLMEngine(CFG, params=params, max_num_seqs=2, max_seq_len=64, enable_prefix_caching=False)
+    taken = []
+    bind = eng._bind
+    eng._bind = lambda st, slot: (taken.append(slot), bind(st, slot))[1]
+    finals, reasons = drive(eng, sched)
+    assert finals[0] == [t0] and reasons[0] == ("length" if ending == "max_tokens" else "stop")
+    assert assert_streams_are_the_references(CFG, params, sched, finals, reasons) == {"length", "stop"} - ({"stop"} if ending == "max_tokens" else set())
+    assert taken == [0, 1, 0], "the third request took the slot the first one left"
+    assert eng._slots == [None, None] and not eng._first_tokens
+
+
+def test_an_abort_between_launch_and_readback_emits_nothing(params):
+    """A lane that loses its slot after its first token was sampled and bound on the device, and before the
+    host has read it (an abort lands there; a preemption for pages does the same), emits nothing: the request
+    ends with no token, its neighbours' streams are the references, and the slot serves the next request."""
+    sched = {0: [([9, 4, 33, 2], SamplingParams(max_tokens=6)), ([5, 6, 7, 8, 1], SamplingParams(max_tokens=7))],
+             1: [([3, 1, 4, 1, 5, 9, 2, 6], SamplingParams(max_tokens=6))]}
+    eng = LLMEngine(CFG, params=params, max_num_seqs=2, max_seq_len=64, enable_prefix_caching=False)
+    dispatch = eng._dispatch_fused
+
+    def abort_then_dispatch(prev=None):
+        if eng._first_tokens and len(eng._first_tokens[0][3]) == 2:  # the wave of two is launched, nothing is read
+            eng._finish(eng._first_tokens[0][3][0][1], "aborted")  # what abort_request does, under the lock the step holds
+        dispatch(prev)
+
+    eng._dispatch_fused = abort_then_dispatch
+    finals, reasons = drive(eng, sched)
+    assert finals[0] == [] and reasons[0] == "aborted"
+    for i in (1, 2):
+        prompt, sp = plain_admitted(sched)[i]
+        assert (finals[i], reasons[i]) == reference_stream(CFG, params, prompt, sp)
+    assert eng._slots == [None, None]

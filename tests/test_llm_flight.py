@@ -28,7 +28,7 @@ from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.serve.llm import LLMConfig, LLMServer, OpenAIServer  # noqa: E402
 
 CFG = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256)
-STEP_STAGES = [f for name, f in telemetry.STAGES.items() if name.startswith("llm.step.") and name not in telemetry.INSIDE]
+STEP_STAGES = [telemetry.STAGES[name] for name in telemetry.TILED]
 
 
 def _engine(**kw):
@@ -129,6 +129,114 @@ def test_stages_sum_to_the_step_and_drain_wait_is_the_device(monkeypatch):
     assert all(s["t0"] <= s["dispatch_t"] <= s["t"] - 0.015 for s in decode if s["batch"])
     admitting = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
     assert admitting and all(s["prefill_ms"] > 0 for s in admitting)
+
+
+def _count_reads(monkeypatch, events: list):
+    """Every way the engine's code can bring a device array to the host, counted: ``np.asarray`` /
+    ``np.array`` of one in ``llm/engine.py`` (on the CPU they read the buffer in place and pass every
+    guard jax has), ``jax.device_get``, and ``int()`` / ``float()`` / ``bool()`` of one. ``events`` takes
+    a line a read, between the lines the test's own hooks write."""
+    import numpy as np
+    from jax._src import array as jax_array
+
+    from ray_tpu.llm import engine as engine_mod
+
+    def counted(fn):
+        def call(a, *args, **kw):
+            if isinstance(a, jax.Array):
+                events.append("read:np")
+            return fn(a, *args, **kw)
+        return call
+
+    class Numpy:
+        asarray, array = staticmethod(counted(np.asarray)), staticmethod(counted(np.array))
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+    monkeypatch.setattr(engine_mod, "np", Numpy())
+    get, inside = jax.device_get, []
+
+    def device_get(tree):  # one blocking transfer, whatever it holds: its own reads of the leaves are not counted again
+        events.append("read:device_get")
+        inside.append(1)
+        try:
+            return get(tree)
+        finally:
+            inside.pop()
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    value = jax_array.ArrayImpl._value
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(lambda a: (inside or events.append("read:value"), value.fget(a))[1]))
+
+
+def test_a_wave_is_bound_on_the_device_and_read_once_behind_the_dispatch(monkeypatch):
+    """PR 50: between the launch of a wave's first group and the dispatch of the step that follows the
+    host reads nothing from the device: each group's first tokens are sampled and its lanes written by
+    programs that take and leave device arrays, and the wave's ONE readback stands behind the dispatch.
+    The step's row says so: ``lanes_bound_device == admitted``, ``first_token_syncs <= groups``."""
+    eng = _engine(max_num_seqs=6, prefill_buckets=(16, 64))
+    warm = [SamplingParams(max_tokens=2), SamplingParams(max_tokens=2, temperature=0.7, seed=3), SamplingParams(max_tokens=2, temperature=0.9)]
+    eng.generate([[1, 2, 3], list(range(1, 30)), [4, 5]], warm)  # compile: tracing reads constants
+    events: list = []
+    _count_reads(monkeypatch, events)
+    for name in ("_admit_prefill_batch", "_dispatch_fused"):
+        monkeypatch.setattr(eng, name, lambda *a, _f=getattr(eng, name), _n=name, **kw: (events.append(_n), _f(*a, **kw))[1])
+    before = eng.telemetry()["step_count"]
+    for prompt, sp in zip([[7, 8, 9], list(range(2, 40)), [3, 1], list(range(5, 25)), [6]], warm + warm[:2]):
+        eng.add_request(prompt, sp)  # greedy, seeded and seedless lanes, in two buckets
+    eng.step()
+    assert events == ["_admit_prefill_batch", "_admit_prefill_batch", "_dispatch_fused", "read:device_get"]
+    row = eng.telemetry()["steps"][before]
+    assert (row["admitted"], len(row["prefill_dispatch_t"]), row["lanes_bound_device"], row["first_token_syncs"]) == (5, 2, 5, 1)
+    assert all(launched <= row["dispatch_t"] <= read <= row["t"] for _, launched, read in row["prefill_dispatch_t"])
+    # the row's stages tile it in the new order: the wave launched inside ``prefill``, read after ``emit``
+    assert list(telemetry.STAGES).index("llm.step.prefill.first_tokens") > list(telemetry.STAGES).index("llm.step.emit")
+    assert "llm.step.prefill.first_tokens" not in telemetry.INSIDE and row["first_token_wait_ms"] > 0
+    assert sum(row[f] for f in STEP_STAGES) <= row["wall_ms"] and row["prefill_launch_ms"] <= row["prefill_ms"]
+    assert (row["dispatch_t"] - row["t0"]) * 1e3 - row["admission_ms"] - row["prefill_ms"] >= 0.0  # ``prefill_bubble_ms``' formula
+    labels = [sp[0] for sp in telemetry.timeline([row]) if sp[0] in ("prefill.launch", "dispatch", "emit", "prefill.first_tokens", "outputs")]
+    assert labels == ["prefill.launch", "prefill.launch", "dispatch", "emit", "prefill.first_tokens", "outputs"]
+    events.clear()
+    while eng.has_unfinished():
+        eng.step()
+    assert "_admit_prefill_batch" not in events and "read:device_get" not in events  # decode steps: the drain's reads alone,
+    assert "read:np" in events  # which the count does see
+    assert all("lanes_bound_device" not in s and "first_token_syncs" not in s for s in eng.telemetry()["steps"][before + 1:])
+
+
+@pytest.mark.parametrize("path", ["speculative", "prefill_only", "resume"])
+def test_the_paths_that_need_the_token_on_the_host_read_it_before_the_dispatch(path):
+    """Speculation builds the drafter's history from the first token, a prefill replica ships the logits, a
+    restored request samples nothing: their rows read ``lanes_bound_device == 0``, and under speculation one
+    readback a group, inside ``llm.step.prefill`` (the group's third stamp lies before ``dispatch_t``)."""
+    from ray_tpu.llm.spec import SpecConfig
+
+    eng = _engine(max_num_seqs=4, prefill_buckets=(16, 64), **({"speculative": SpecConfig(k=2)} if path == "speculative" else {}))
+    if path == "speculative":
+        outs = eng.generate([[1, 2, 3], list(range(1, 30)), [4, 5]], SamplingParams(max_tokens=5))
+        assert all(len(o.token_ids) == 5 for o in outs)
+        (row,) = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+        assert (row["admitted"], row["lanes_bound_device"], row["first_token_syncs"]) == (3, 0, 2)
+        assert all(launched <= read <= row["dispatch_t"] for _, launched, read in row["prefill_dispatch_t"])
+        assert row["first_token_wait_ms"] == 0.0 and "prefill.first_tokens" in [sp[0] for sp in telemetry.timeline([row])]
+    elif path == "prefill_only":
+        payload = eng.prefill_handoff([1, 2, 3, 4, 5])
+        assert payload["n"] == 5
+        (row,) = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+        assert (row["admitted"], row["lanes_bound_device"], row["first_token_syncs"]) == (1, 0, 0)
+    else:
+        rid = eng.add_request([1, 2, 3, 4, 5], SamplingParams(max_tokens=8))
+        for _ in range(3):
+            eng.step()
+        state = eng.checkpoint_request(rid)
+        eng.abort_request(rid)
+        peer = _engine(max_num_seqs=4)
+        peer.restore_request(state)
+        while peer.has_unfinished():
+            peer.step()
+        (row,) = [s for s in peer.telemetry()["steps"] if s.get("admitted")]
+        assert (row["admitted"], row["lanes_bound_device"], row["first_token_syncs"]) == (1, 0, 0)
 
 
 def test_a_hybrid_models_rows_carry_routing_counters_and_the_state_insert_stage():
@@ -298,7 +406,7 @@ def test_the_logs_bound_drops_the_oldest_and_says_how_many(session, monkeypatch)
 
 
 def test_the_logs_memory_stays_under_its_stated_bound():
-    """12,000 step rows and 2,000 requests of 150 tokens: the stated bound (21 MB since PR 39 added three fields to a step row; 21.5 since PR 46 added two, 16 bytes a row)."""
+    """12,000 step rows and 2,000 requests of 150 tokens: the stated bound (21 MB since PR 39 added three fields to a step row; 21.5 since PR 46 added two, 16 bytes a row; 21.7 since PR 50 added two)."""
     import tracemalloc
 
     rec = telemetry.FlightRecorder()
@@ -313,7 +421,7 @@ def test_the_logs_memory_stays_under_its_stated_bound():
     held, _ = tracemalloc.get_traced_memory()
     tracemalloc.stop()
     assert len(rec.log_steps) == 12_000 and len(rec.log_requests) == 2_000
-    assert held < 21.5e6, f"the flight log holds {held / 1e6:.1f} MB"
+    assert held < 21.7e6, f"the flight log holds {held / 1e6:.1f} MB"
 
 
 def test_load_flight_merges_two_processes_and_skips_a_torn_last_line(session):

@@ -76,6 +76,90 @@ def test_flight_recorder_ring_is_bounded():
     assert len(snap["requests"]) == 4 and snap["requests"][-1]["request_id"] == "r49"
 
 
+def _admitting_row(read_inside_prefill: bool) -> dict:
+    """One admitting step's row, built by hand from the host's true spans (seconds from t0 = 100): two groups
+    launched, and their first tokens read behind the dispatch (one read a wave), or inside ``prefill`` a group
+    (speculation, where the stage of that name stays empty)."""
+    from ray_tpu.llm import telemetry
+
+    spans, h, groups = [], 100.0, []
+
+    def stage(label, dur):
+        nonlocal h
+        spans.append((label, h, h + dur))
+        h += dur
+
+    stage(telemetry.IN_STEP, 0.05e-3)
+    stage("admission", 0.2e-3)
+    p0 = h
+    stage("prefill", 0.1e-3)
+    for _ in range(2):
+        stage("prefill.launch", 0.4e-3)
+        d = h
+        stage("prefill.launch", 0.6e-3)
+        stage("state_insert", 0.2e-3)
+        groups.append([d, h, 0.0])
+        if read_inside_prefill:
+            stage("prefill.first_tokens", 40e-3)
+            groups[-1][2] = h
+    stage("prefill", 0.05e-3)
+    prefill_ms = (h - p0) * 1e3
+    stage("dispatch", 0.5e-3)
+    dispatch_t = h
+    stage("drain_wait", 0.05e-3)
+    stage("emit", 0.3e-3)
+    if not read_inside_prefill:
+        stage("prefill.first_tokens", 80e-3)
+        for g in groups:
+            g[2] = h
+    stage("outputs", 0.2e-3)
+    row = {"step": 7, "t0": 100.0, "t": h, "wall_ms": (h - 100.0) * 1e3, "admitted": 5, "dispatch_t": dispatch_t, "prefill_dispatch_t": groups,
+           "admission_ms": 0.2, "prefill_ms": prefill_ms, "prefill_launch_ms": 2.4, "state_insert_ms": 0.4, "dispatch_ms": 0.5, "drain_wait_ms": 0.05,
+           "emit_ms": 0.3, "first_token_wait_ms": 0.0 if read_inside_prefill else 80.0, "outputs_ms": 0.2, "stepper_deliver_ms": 0.0, "stepper_wait_ms": 0.0}
+    return {"row": row, "spans": spans}
+
+
+@pytest.mark.parametrize("read_inside_prefill", [False, True], ids=["read_behind_the_dispatch", "read_before_it"])
+def test_timeline_follows_a_wave_through_the_new_stage_order(read_inside_prefill):
+    """PR 50: ``llm.step.prefill`` ends when the wave is launched, and the wait for its first tokens stands after
+    ``emit``, no longer inside ``prefill``: ``timeline`` gives back the host's true spans, which tile the row, and
+    ``prefill_bubble_ms``' formula (``dispatch_t - t0 - admission_ms - prefill_ms``) is not negative. Where the
+    dispatch needs the tokens on the host, a group's third stamp puts the read inside ``prefill``."""
+    from ray_tpu.llm import telemetry
+
+    built = _admitting_row(read_inside_prefill)
+    row, want = built["row"], built["spans"]
+    names = list(telemetry.STAGES)
+    assert names.index("llm.step.dispatch") < names.index("llm.step.emit") < names.index("llm.step.prefill.first_tokens") < names.index("llm.step.outputs")
+    assert telemetry.INSIDE == {"llm.step.prefill.launch": "llm.step.prefill", "llm.step.state_insert": "llm.step.prefill.launch"}
+    got = telemetry.timeline([row])
+    merged = []  # the true spans, a launch's two halves as one
+    for label, a, b in want:
+        if merged and merged[-1][0] == label and abs(merged[-1][2] - a) < 1e-12:
+            merged[-1] = (label, merged[-1][1], b)
+        else:
+            merged.append((label, a, b))
+    assert [sp[0] for sp in got] == [sp[0] for sp in merged]
+    assert all(a == pytest.approx(c, abs=2e-6) and b == pytest.approx(d, abs=2e-6) for (_, a, b), (_, c, d) in zip(got, merged))
+    assert got[0][1] == row["t0"] and got[-1][2] == row["t"] and all(x[2] == pytest.approx(y[1], abs=1e-9) for x, y in zip(got, got[1:]))
+    bubble = (row["dispatch_t"] - row["t0"]) * 1e3 - row["admission_ms"] - row["prefill_ms"]
+    assert 0.0 <= bubble == pytest.approx(0.05 + 0.5, abs=1e-3)  # the wait for the lock and the dispatch's own host time
+    assert sum(row[telemetry.STAGES[name]] for name in telemetry.TILED) == pytest.approx(row["wall_ms"] - 0.05, abs=1e-3)
+
+
+def test_drain_stamps_say_when_each_steps_tokens_were_on_the_host():
+    """The end of the NEXT row's ``drain_wait``, a row's stages ending at ``t``: what bounds the device's clock from
+    below where no launch finds the device idle (``util/profiling._align``)."""
+    from ray_tpu.llm import telemetry
+
+    first, second = _admitting_row(False)["row"], dict(_admitting_row(False)["row"], step=8)
+    shift = first["t"] + 1e-3 - second["t0"]
+    second.update(t0=second["t0"] + shift, t=second["t"] + shift, dispatch_t=second["dispatch_t"] + shift)
+    idle = dict(second, step=9, t0=second["t"] + 1e-3, t=second["t"] + 2e-3, dispatch_t=None, first_token_wait_ms=0.0, emit_ms=0.1, outputs_ms=0.1)
+    assert telemetry.drain_stamps([first, second, idle]) == [pytest.approx(second["t"] - (0.3 + 80.0 + 0.2) * 1e-3), pytest.approx(idle["t"] - 0.2e-3)]
+    assert telemetry.drain_stamps([first]) == [None]
+
+
 def test_recompile_sentinel_counts_cache_growth():
     """The sentinel's contract: first observed program per entry is the
     warm baseline; any growth after that is a recompile, counted per
