@@ -2210,6 +2210,7 @@ class LLMEngine:
             # a hybrid's prefill also hands back each recurrent layer's state at the prompt's true
             # length, and its routing layers' counters (hybrid_runner.PREFILL_STATS)
             ks = vs = rows = kept = None
+            rows_lens = lens  # on the host: every row's length as the program gets it, a padding row's 1 among them
             toks, lens = jnp.asarray(toks), jnp.asarray(lens)
             if self._hybrid:
                 # what each layer keeps per position and per sequence, by entry name (hybrid_runner.prefill)
@@ -2253,13 +2254,16 @@ class LLMEngine:
                     tel.prefill_dispatch_t = []
                 tel.prefill_dispatch_t.append(stamps)
             stats = None
-            if self._hybrid and tel is not None:
+            if tel is not None:
                 # the step's row in the flight log: tokens prefilled, true and as padded, what the description
-                # counts of the shape; the routing counters ride the first tokens' readback
-                stats = (int(sum(len(p) for _, _, p in group)), Bp * T,
-                         self.config.prefill_counters(Bp, T, lengths=[len(p) for _, _, p in group]))
+                # counts of the shape, the flash calls' query tiles; the routing counters ride the first tokens' readback
+                from ray_tpu.ops.flash_attention import query_tiles
+
+                true = [len(p) for _, _, p in group]
+                counted = self.config.prefill_counters(Bp, T, lengths=true) if self._hybrid else {}
+                stats = (sum(true), Bp * T, {**counted, **query_tiles(self.config.flash_calls(T), T, rows_lens)})
             self._bind_group([(st, slot) for st, slot, _ in group], logits, stamps=stamps, stats=stats,
-                             routing=kept.get("routing") if stats is not None else None)
+                             routing=kept.get("routing") if self._hybrid and stats is not None else None)
             if stamps is not None:
                 stamps[1] = time.time()
         if self._spec_cfg is not None:  # the drafter's history is built from the token: read it before the dispatch
@@ -2421,7 +2425,8 @@ class LLMEngine:
                 # PRNGKey(seed) is made inside the program, from the seed as jax takes a Python int in
                 seeds[i], seeded[i] = np.int64(p.seed).astype(np.int32), True
             live.append((i, st, slot))
-        if not live and stats is None:
+        if not live and routing is None:  # nothing to read back: the group's counts go onto the step's row at once
+            self._count_prefill(stats, None)
             return
         tok = logp = None
         if live:
@@ -2466,11 +2471,17 @@ class LLMEngine:
                 self._emit(st, int(tok[i]), float(logp[i]))  # tpulint: disable=CCR002 — reads the host arrays the one transfer above brought
                 if self._spec_cfg is not None:
                     self._spec_admit(st, slot, st.prompt_token_ids + st.token_ids)
-            if stats is not None:
-                routing = np.zeros((3,), np.float32) if routing is None else routing
-                seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
-                self._prefill_stats = (seen[0] + stats[0], seen[1] + stats[1], seen[2] + 1, seen[3] + routing,
-                                       {k: seen[4].get(k, 0) + v for k, v in stats[2].items()})
+            self._count_prefill(stats, routing)
+
+    def _count_prefill(self, stats, routing):
+        """One prefill program's counts (``_admit_prefill_batch``'s ``stats``; None without telemetry)
+        and its routing counters, on the host, onto the sums that the step's row takes."""
+        if stats is None:
+            return
+        routing = np.zeros((3,), np.float32) if routing is None else routing
+        seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
+        self._prefill_stats = (seen[0] + stats[0], seen[1] + stats[1], seen[2] + 1, seen[3] + routing,
+                               {k: seen[4].get(k, 0) + v for k, v in stats[2].items()})
 
     def _bind_resume(self, st: RequestState, slot: int):
         """Splice a restored live-state request into the decode loop

@@ -375,7 +375,7 @@ def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
             q, k, v = _qkv(xn, layer, cfg)
             qh = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
             kh = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
-            o = flash_attention_on_mesh(qh, kh, v.transpose(0, 2, 1, 3), mesh, cfg.attention_impl)
+            o = flash_attention_on_mesh(qh, kh, v.transpose(0, 2, 1, 3), mesh, cfg.attention_impl, lengths=length)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.num_heads * cfg.hd)
             x = x + jnp.dot(o, layer["wo"])
         x = _mlp(x, layer, cfg)

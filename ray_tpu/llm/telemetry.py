@@ -72,8 +72,10 @@ logger = logging.getLogger("ray_tpu.llm")
 # device (the delayed readback); everything else is the host's own work.
 # what a hybrid description may count of a prefill program from its shape alone
 # (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
-# takes on that step's row
-PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs")
+# takes on that step's row; then what the engine counts of any model's from its ``flash_calls``
+# (``ops/flash_attention.query_tiles``)
+PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs",
+                    "attn_q_tiles", "attn_q_tiles_live")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
 DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read")
 
@@ -403,9 +405,9 @@ class FlightRecorder:
         # expert layers: the experts hit, since the step loops over those); absent for a model
         # without routed experts
         "experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load", "experts_read",
-        # a hybrid model's ADMITTING step, of that step's prefills: tokens taken in, true and as
-        # padded to bucket and batch, then llm/hybrid_runner.PREFILL_STATS, each a mean over the
-        # routing layers: held experts that got a pair (mean over the step's prefill programs),
+        # an ADMITTING step, of that step's prefills: tokens taken in, true and as padded to bucket
+        # and batch, then llm/hybrid_runner.PREFILL_STATS (zeros for a model without routed experts),
+        # each a mean over the routing layers: held experts that got a pair (mean over the step's prefill programs),
         # (token, expert) pairs served here and rows of the grouped matmul's blocks in use (sums
         # over them). Under names of their own: the four above stay the drained DECODE step's
         "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed",
@@ -415,7 +417,9 @@ class FlightRecorder:
         # many of them the kernel ran; (query, block) pairs that the sparse layers read at the prompts' true
         # lengths; (query, key) pairs inside the window that the sliding-window layers' mathematics needs at
         # the prompts' true lengths (``swa_pairs``: min(i + 1, window) a position and layer); absent for a
-        # description that counts none
+        # description that counts none. Last, of any model: the query tiles that the programs' flash calls
+        # have by their shape (``attn_q_tiles``: calls x batch rows x tiles of the bucket) and those that start
+        # under a row's true length (``attn_q_tiles_live``): the kernel computes and fetches these alone
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)

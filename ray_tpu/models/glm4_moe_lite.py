@@ -77,6 +77,15 @@ class LatentAttention:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
+    def flash_width(self) -> int:
+        """Columns of ``mla_seq``'s queries, keys and values: the widest of them, padded up to a width the flash kernel takes."""
+        need = max(self.qk_head_dim, self.v_head_dim)
+        return next((wd for wd in (64, 128, 256) if wd >= need), need)
+
+    def flash_calls(self, length: int) -> dict:
+        return {self.flash_width: self.count("mla")}
+
+    @property
     def rope_row(self) -> int:
         """Columns of the cached shared key: qk_rope_head_dim rounded up to whole 128-lane tiles."""
         return -(-self.qk_rope_head_dim // 128) * 128
@@ -161,7 +170,7 @@ class Glm4MoeLiteConfig(LatentAttention, HybridDescription):
         dt = jnp.dtype(self.dtype)
 
         def attention_seq(w, xn, ctx):
-            y, c_kv, k_r = mla_seq(w, xn.astype(dt), self, ctx.mesh)
+            y, c_kv, k_r = mla_seq(w, xn.astype(dt), self, ctx.mesh, ctx.skippable)
             return y, {"c_kv": c_kv, "k_r": k_r}
 
         def attention_step(w, xn, cache, ctx):
@@ -304,13 +313,14 @@ def _queries(w, c_q, rope, c: LatentAttention):
     return q[..., :c.qk_nope_head_dim], q_rope
 
 
-def mla_seq(w, xn, c: LatentAttention, mesh=None):
+def mla_seq(w, xn, c: LatentAttention, mesh=None, lengths=None):
     """The EXPANDED form over a padded sequence, positions 0..T-1: xn [B,T,H] -> (out [B,T,H],
     c_kv [B,T,kv_lora_rank], k_r [B,T,rope_row]) with the latter two as the cache keeps them.
     The flash kernel takes queries, keys and values of ONE width, of 64, 128 or 256 columns: a
     description whose values are narrower than its keys, or whose keys are of another width (Kimi
     Linear: 128 + 64 against 128), has both padded with zeros up to the next of those, which
-    changes no score and adds zero columns to the output, cut off again."""
+    changes no score and adds zero columns to the output, cut off again. ``lengths`` [B]: the true
+    lengths, where the kernel may skip what lies past them (``SeqCtx.skippable``)."""
     B, T, _ = xn.shape
     nh = c.num_heads
     c_q, c_kv, k_r, rope = mla_down(w, xn, jnp.arange(T, dtype=jnp.int32), c)
@@ -318,13 +328,12 @@ def mla_seq(w, xn, c: LatentAttention, mesh=None):
         q_nope, q_rope = _queries(w, c_q, rope, c)
         k_nope = jnp.einsum("btr,rnd->bntd", c_kv, w["w_kb"].reshape(c.kv_lora_rank, nh, c.qk_nope_head_dim))
         v = jnp.einsum("btr,rnd->bntd", c_kv, w["w_vb"].reshape(c.kv_lora_rank, nh, c.v_head_dim))
-        need = max(c.qk_head_dim, c.v_head_dim)
-        width = next((wd for wd in (64, 128, 256) if wd >= need), need)
+        width = c.flash_width
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r[:, None, :, :c.qk_rope_head_dim], (B, nh, T, c.qk_rope_head_dim))], axis=-1)
         q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, width - a.shape[-1]))) for a in (q, k, v))
     with scope("mla.attn"):
-        o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl, scale=None if width == c.qk_head_dim else c.qk_head_dim ** -0.5)
+        o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl, scale=None if width == c.qk_head_dim else c.qk_head_dim ** -0.5, lengths=lengths)
     o = o[..., :c.v_head_dim].transpose(0, 2, 1, 3).reshape(B, T, nh * c.v_head_dim)
     return jnp.dot(o.astype(xn.dtype), w["wo"]), c_kv, k_r
 

@@ -81,6 +81,12 @@ class SeqCtx(NamedTuple):
     stacked: Any  # the serving path: (the kind's stacked weights, this layer's index); else None
     handed: Any = None  # {name: [B,T,*shape]}: what the last sub-block that hands something on handed (``HybridDescription.handed``)
 
+    @property
+    def skippable(self):
+        """``lengths`` for a kernel that skips what lies past them and has no backward pass
+        (``ops/flash_attention``): the serving path's; None where a backward pass may follow."""
+        return None if self.stacked is None else self.lengths
+
 
 class StepCtx(NamedTuple):
     lengths: Any  # [B] int32: positions already held = the new token's position
@@ -153,6 +159,12 @@ class HybridDescription:
         the true ``lengths`` runs that can be counted on the host from those alone, by a name of
         ``llm/telemetry.PREFILL_COUNTERS``: summed over an admitting step's programs onto that step's
         row of the flight log. None by default."""
+        return {}
+
+    def flash_calls(self, length: int) -> dict:
+        """{head width: calls} of ``ops/flash_attention`` in ONE prefill program over ``length``
+        padded positions: what ``flash_attention.query_tiles`` counts an admitting step's
+        ``attn_q_tiles`` and ``attn_q_tiles_live`` from. None by default."""
         return {}
 
     def decode_counters(self, positions) -> dict:

@@ -145,7 +145,7 @@ class KimiLinearConfig(LatentAttention, HybridDescription):
             return y, None
 
         def attention_seq(w, xn, ctx):
-            y, c_kv, k_r = mla_seq(w, xn.astype(dt), self, ctx.mesh)
+            y, c_kv, k_r = mla_seq(w, xn.astype(dt), self, ctx.mesh, ctx.skippable)
             return y, {"c_kv": c_kv, "k_r": k_r}
 
         def experts_seq(w, xn, ctx):

@@ -53,6 +53,10 @@ class LlamaConfig:
     def hd(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def flash_calls(self, length: int) -> dict:
+        """{head width: calls} of ``ops/flash_attention`` in one prefill program (``HybridDescription.flash_calls``)."""
+        return {self.hd: self.num_layers}
+
     @staticmethod
     def llama2_7b(**kw):
         return LlamaConfig(**{**dict(vocab_size=32000, hidden_size=4096, intermediate_size=11008, num_layers=32, num_heads=32, num_kv_heads=32), **kw})

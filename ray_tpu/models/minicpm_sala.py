@@ -160,7 +160,7 @@ class MiniCPMSALAConfig(HybridDescription):
         dt = jnp.dtype(self.dtype)
 
         def sparse_seq_(w, xn, ctx):
-            y, k, v, kc = sparse_seq(w, xn.astype(dt), ctx.lengths, self, ctx.mesh)
+            y, k, v, kc = sparse_seq(w, xn.astype(dt), ctx.lengths, self, ctx.mesh, ctx.skippable)
             return y, {"k": k, "v": v, "kc": kc}
 
         def lightning_seq_(w, xn, ctx):
@@ -186,6 +186,10 @@ class MiniCPMSALAConfig(HybridDescription):
     @property
     def hd(self) -> int:
         return self.head_dim
+
+    def flash_calls(self, length: int) -> dict:
+        """A bucket over ``dense_len`` goes through the selection, not the flash kernel (``sparse_seq``)."""
+        return {self.hd: self.count("sparse")} if length <= self.dense_len else {}
 
     @property
     def lightning_dim(self) -> int:
@@ -303,12 +307,13 @@ def param_logical_axes(config: MiniCPMSALAConfig):
 
 
 # ------------------------------------------------------------- sparse: InfLLM v2
-def sparse_seq(w, xn, lengths, c: MiniCPMSALAConfig, mesh=None):
+def sparse_seq(w, xn, lengths, c: MiniCPMSALAConfig, mesh=None, skippable=None):
     """xn [B,T,H], lengths [B] -> (out [B,T,H], k, v [B,T,kv,hd] as the cache keeps them, kc
     [B, max_seq_len // stride, kv, hd]: the compressed keys of the whole windows inside each true
     length, zeros after). A bucket of at most ``dense_len`` positions holds only sequences that
     attend densely: the flash kernel; a longer one goes through the selection, a few sequences at
-    a time (``qwen3_next.a_few_at_a_time``)."""
+    a time (``qwen3_next.a_few_at_a_time``). ``skippable`` [B]: the true lengths again, where the
+    flash kernel may skip what lies past them (``SeqCtx.skippable``: no backward pass follows)."""
     B, T, _ = xn.shape
     h, sp = c.sparse_heads, c.sparse
     width = c.num_heads * c.hd
@@ -317,7 +322,7 @@ def sparse_seq(w, xn, lengths, c: MiniCPMSALAConfig, mesh=None):
         q, gate, k, v = gated_attn_qkv(w, xn, jnp.arange(T, dtype=jnp.int32), h)
         if T <= sp.dense_len:
             o = flash_attention_on_mesh(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                                        mesh, c.attention_impl).transpose(0, 2, 1, 3)
+                                        mesh, c.attention_impl, lengths=skippable).transpose(0, 2, 1, 3)
             with scope("sparse.select"):
                 kc = sparse_attention.compress_keys(k, lengths, sp)
         else:

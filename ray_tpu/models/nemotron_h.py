@@ -131,7 +131,7 @@ class NemotronHConfig(HybridDescription):
             return y, {ROUTING: counters}
 
         def attention_seq(w, xn, ctx):
-            y, k, v = attn_seq(w, xn.astype(dt), self, ctx.mesh)
+            y, k, v = attn_seq(w, xn.astype(dt), self, ctx.mesh, ctx.skippable)
             return y, {"k": k, "v": v}
 
         def attention_step(w, xn, cache, ctx):
@@ -156,6 +156,9 @@ class NemotronHConfig(HybridDescription):
     @property
     def hd(self) -> int:
         return self.head_dim
+
+    def flash_calls(self, length: int) -> dict:
+        return {self.hd: self.count("attn")}
 
     @property
     def stream_dtype(self):
@@ -421,12 +424,13 @@ def qkv(w, xn, c: NemotronHConfig):
     return q, k, v
 
 
-def attn_seq(w, xn, c: NemotronHConfig, mesh=None):
-    """Causal grouped-query attention with NO position embedding. -> (out, k, v [B,T,kv,hd])."""
+def attn_seq(w, xn, c: NemotronHConfig, mesh=None, lengths=None):
+    """Causal grouped-query attention with NO position embedding. -> (out, k, v [B,T,kv,hd]).
+    ``lengths`` [B]: the true lengths, where the kernel may skip what lies past them (``SeqCtx.skippable``)."""
     B, T, _ = xn.shape
     q, k, v = qkv(w, xn, c)
     o = flash_attention_on_mesh(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                                mesh, c.attention_impl)
+                                mesh, c.attention_impl, lengths=lengths)
     return jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, c.num_heads * c.hd), w["wo"]), k, v
 
 

@@ -145,7 +145,7 @@ class Qwen3NextConfig(HybridDescription):
             return y, None
 
         def attention_seq(w, xn, ctx):
-            y, k, v = gated_attn_seq(w, xn.astype(dt), self, ctx.mesh)
+            y, k, v = gated_attn_seq(w, xn.astype(dt), self, ctx.mesh, ctx.skippable)
             return y, {"k": k, "v": v}
 
         def attention_step(w, xn, cache, ctx):
@@ -175,6 +175,9 @@ class Qwen3NextConfig(HybridDescription):
     @property
     def hd(self) -> int:
         return self.head_dim
+
+    def flash_calls(self, length: int) -> dict:
+        return {self.hd: self.count("attn")}
 
     @property
     def rot_dim(self) -> int:
@@ -585,11 +588,12 @@ def _gated_out(w, o, gate, dtype):
     return jnp.dot((o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype), w["wo"])
 
 
-def gated_attn_seq(w, xn, c: Qwen3NextConfig, mesh=None):
+def gated_attn_seq(w, xn, c: Qwen3NextConfig, mesh=None, lengths=None):
     """Causal grouped-query attention over a padded sequence, positions 0..T-1.
-    -> (out, k, v [B,T,kv,hd]) with k as the cache keeps it: normalised and rotated."""
+    -> (out, k, v [B,T,kv,hd]) with k as the cache keeps it: normalised and rotated. ``lengths`` [B]:
+    the true lengths, where the kernel may skip what lies past them (``SeqCtx.skippable``)."""
     B, T, _ = xn.shape
     q, gate, k, v = gated_attn_qkv(w, xn, jnp.arange(T, dtype=jnp.int32), c)
     o = flash_attention_on_mesh(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                                mesh, c.attention_impl)
+                                mesh, c.attention_impl, lengths=lengths)
     return _gated_out(w, o.transpose(0, 2, 1, 3).reshape(B, T, c.num_heads * c.hd), gate, xn.dtype), k, v

@@ -112,7 +112,7 @@ class SmallThinkerConfig(HybridDescription):
 
         def attention(kind):
             def seq(w, xn, ctx):
-                y, k, v = attn_seq(w, xn.astype(dt), self, kind == "swa", ctx.mesh)
+                y, k, v = attn_seq(w, xn.astype(dt), self, kind == "swa", ctx.mesh, ctx.skippable)
                 return y, dict(zip(ENTRIES[kind], (k, v))), routing(w, xn, self)
 
             def step(w, xn, cache, ctx):
@@ -140,6 +140,9 @@ class SmallThinkerConfig(HybridDescription):
     @property
     def hd(self) -> int:
         return self.head_dim
+
+    def flash_calls(self, length: int) -> dict:
+        return {self.hd: self.count("attn") + self.count("swa")}
 
     @property
     def stream_dtype(self):
@@ -249,13 +252,14 @@ def qkv(w, xn, positions, c: SmallThinkerConfig, rotates: bool):
     return q, k, v
 
 
-def attn_seq(w, xn, c: SmallThinkerConfig, window: bool, mesh=None):
+def attn_seq(w, xn, c: SmallThinkerConfig, window: bool, mesh=None, lengths=None):
     """Causal grouped-query attention over a padded sequence, positions 0..T-1; a window layer
     rotates and reads the last ``sliding_window_size`` keys. -> (out [B,T,H], k, v [B,T,kv,hd] as
-    the cache keeps them: k rotated)."""
+    the cache keeps them: k rotated). ``lengths`` [B]: the true lengths, where the kernel may skip
+    what lies past them (``SeqCtx.skippable``)."""
     B, T, _ = xn.shape
     q, k, v = qkv(w, xn, jnp.arange(T, dtype=jnp.int32), c, window)
-    o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl, window=c.sliding_window_size if window else None)
+    o = flash_attention_on_mesh(q, k, v, mesh, c.attention_impl, window=c.sliding_window_size if window else None, lengths=lengths)
     return jnp.dot(o.transpose(0, 2, 1, 3).reshape(B, T, c.num_heads * c.hd), w["wo"]), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 

@@ -58,12 +58,15 @@ def _apply_masks(logits, causal, segment_ids, window=None):
 # ----------------------------------------------------------------------
 # pallas forward kernel
 # ----------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k, window=None):
+def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None, lengths=False):
     """``window`` (causal only): a query at position i reads keys ``i - window < j <= i``. A tile
     wholly before the window of its first query is skipped as a tile above the diagonal is (and not
-    fetched: ``_fwd_pallas``'s index map holds the tile before it), the edge tiles are masked."""
+    fetched: ``_fwd_pallas``'s index map holds the tile before it), the edge tiles are masked.
+    ``lengths``: the first ref is the prefetched true length of each grid row, and a query tile that
+    starts at or past its row's is skipped whole: ``_init`` and ``_finalize`` make zeros of it."""
     from jax.experimental import pallas as pl
 
+    lens_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr) = (refs[0], refs[1:]) if lengths else (None, refs)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -99,16 +102,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, s
         )
         m_scr[:] = m_new
 
-    if causal and window is not None:
-        @pl.when((ki * block_k <= qi * block_q + block_q - 1) & (ki * block_k + block_k - 1 > qi * block_q - window))
-        def _():
-            _compute()
-    elif causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            _compute()
-    else:
+    runs = None  # where this (query tile, key tile) has anything to compute; None: everywhere
+    if causal:
+        runs = ki * block_k <= qi * block_q + block_q - 1
+        if window is not None:
+            runs = runs & (ki * block_k + block_k - 1 > qi * block_q - window)
+    if lengths:
+        runs = runs & (qi * block_q < lens_ref[pl.program_id(0)])
+    if runs is None:
         _compute()
+    else:
+        pl.when(runs)(_compute)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -118,7 +122,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, s
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q", "block_k", "window"))
-def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, window=None):
+def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, window=None, lengths=None):
+    """``lengths`` [B] int32 (causal only, forward only): row b's true length. A query tile that
+    starts at or past it is neither computed nor fetched, and comes out as zeros; the tile that
+    holds position ``length - 1`` and every tile before it are what they are without ``lengths``,
+    bit for bit. None is the call as it was: no prefetched scalar, the same specs, and so is a
+    call of ONE query tile, which has nothing to skip (``_skippable``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -126,6 +135,9 @@ def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, wi
     Tk = k.shape[2]
     if scale is None:
         scale = D**-0.5
+    if not causal and (window is not None or lengths is not None):
+        raise ValueError("a window and true lengths are causal: keys i - window < j <= i < length")  # tpulint: disable=ERR002 — a programmer's error at trace time
+    lengths = _skippable(lengths, T, D, block_q)
     dq, dk = _default_blocks(D)
     block_q = min(block_q or dq, T)
     block_k = min(block_k or dk, Tk)
@@ -133,36 +145,59 @@ def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, wi
     qs, ks, vs = (x.reshape(B * H, x.shape[2], D) for x in (q, k, v))
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k)
-    keys = lambda b, i, j: (b, j, 0)  # noqa: E731
-    windowed = {}
+    named = {}
     if window is not None:
-        if not causal:
-            raise ValueError("a window is causal: keys i - window < j <= i")  # tpulint: disable=ERR002 — a programmer's error at trace time
         kernel = functools.partial(kernel, window=window)
-        # a tile the kernel skips is not fetched either: its index is that of the nearest tile the query tile reads,
-        # which the pipeline holds already; the name tells the windowed calls apart in a trace
-        keys = lambda b, i, j: (b, jnp.clip(j, jnp.maximum(i * block_q - window + 1, 0) // block_k, (i * block_q + block_q - 1) // block_k), 0)  # noqa: E731
-        windowed = {"name": "window_flash_attention"}
-    o, lse = pl.pallas_call(
-        kernel,
+        named = {"name": "window_flash_attention"}  # the name tells the windowed calls apart in a trace
+
+    # a tile the kernel skips is not fetched either: its index is that of the nearest tile the query tile reads (a
+    # window's first, the diagonal's), which the pipeline holds already; past a row's true length (``lens``: the
+    # prefetched lengths, where the call has them) that of the last tile its last live query tile read
+    def last_live(b, lens):
+        return jnp.maximum(pl.cdiv(lens[b], block_q) - 1, 0)
+
+    def queries(b, i, j, *lens):
+        return (b, jnp.minimum(i, last_live(b, *lens)) if lens else i, 0)
+
+    def keys(b, i, j, *lens):
+        if lens or window is not None:
+            at = jnp.minimum(i, last_live(b, *lens)) if lens else i
+            first = 0 if window is None else jnp.maximum(at * block_q - window + 1, 0) // block_k
+            diagonal = (at * block_q + block_q - 1) // block_k
+            j = jnp.clip(j, first, diagonal)
+            if lens:
+                j = jnp.where(i > at, diagonal, j)
+        return (b, j, 0)
+
+    specs = dict(
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, D), queries, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, D), keys, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, D), keys, memory_space=pltpu.VMEM),
         ],
+        # every output tile is written, a skipped one as zeros: rows of memory nobody wrote could hold a NaN
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
+            pl.BlockSpec((1, block_q, D), lambda b, i, j, *_: (b, i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j, *_: (b, 0, i), memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
+        ],
+    )
+    prefetched = ()
+    if lengths is not None:
+        kernel = functools.partial(kernel, lengths=True)
+        specs = {"grid_spec": pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **specs)}
+        prefetched = (jnp.repeat(lengths.astype(jnp.int32), H),)  # the grid's first axis is B * H
+    o, lse = pl.pallas_call(
+        kernel,
+        **specs,
+        out_shape=[
+            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -170,8 +205,8 @@ def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, wi
             bytes_accessed=(qs.size + ks.size + vs.size) * 2,
             transcendentals=B * H * T * Tk,
         ),
-        **windowed,
-    )(qs, ks, vs)
+        **named,
+    )(*prefetched, qs, ks, vs)
     return o.reshape(B, H, T, D), lse.reshape(B, H, T)
 
 
@@ -338,35 +373,52 @@ def _bwd_pallas_with_delta(q, k, v, g, lse, delta, causal=True, scale=None, bloc
 # ----------------------------------------------------------------------
 # custom VJP
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = True, scale: float | None = None, impl: str = "auto", window: int | None = None):
+def flash_attention(q, k, v, causal: bool = True, scale: float | None = None, impl: str = "auto", window: int | None = None, lengths=None):
     """Flash attention with GQA support. q: [B,H,T,D]; k,v: [B,Hkv,T,D].
 
     impl: "auto" (pallas on TPU when head_dim tiles), "pallas", or "xla".
     window: a query at position i reads the keys ``i - window < j <= i`` only (causal; forward
     kernel and both XLA passes; the backward KERNELS have no window and refuse one).
+    lengths [B] int32: the true lengths of right-padded sequences (causal, forward only: no
+    backward pass knows them, and a differentiated call that takes them refuses). Rows below a
+    sequence's length are what they are without it; the query tiles (``query_tiles``) wholly at
+    or past it come out as zeros, and the kernel neither computes nor fetches them. A call of
+    ONE query tile does not take them (``_skippable``): it is the call without them, and so is
+    the program around it.
     """
-    out, _ = _flash_fwd(q, k, v, causal, scale, impl, window)
+    if lengths is not None and not causal:
+        raise ValueError("true lengths are causal: keys j <= i < length")  # tpulint: disable=ERR002 — a programmer's error at trace time
+    return _flash(q, k, v, causal, scale, impl, window, _skippable(lengths, q.shape[2], q.shape[-1]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, impl, window, lengths):
+    out, _ = _flash_fwd(q, k, v, causal, scale, impl, window, lengths)
     return out
 
 
-def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto", scale: float | None = None, window: int | None = None):
+def flash_attention_on_mesh(q, k, v, mesh, impl: str = "auto", scale: float | None = None, window: int | None = None, lengths=None):
     """Causal flash_attention over [B, H, T, D] inside a GSPMD program.
     GSPMD cannot partition a Mosaic kernel on its own ("wrap the call in
     a shard_map"), so where the Pallas kernel is selected on a mesh of
     several devices it runs under shard_map: batch over dp/fsdp, heads
     over tp — attention is independent across both, so each device runs
     the kernel on its own block and no collective is needed. ``mesh`` is
-    None (or one device, or the XLA path) for the plain call."""
+    None (or one device, or the XLA path) for the plain call. ``lengths``
+    [B] (``flash_attention``) are sharded as the batch is."""
     if (mesh is None or mesh.size == 1 or not set(mesh.axis_names) <= {"dp", "fsdp", "tp"}
             or not _use_pallas(q, impl)):
-        return flash_attention(q, k, v, True, scale, impl, window)
+        return flash_attention(q, k, v, True, scale, impl, window, lengths)
     from jax.sharding import PartitionSpec as P
 
     batch = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) or None
     spec = P(batch, "tp" if "tp" in mesh.axis_names else None, None, None)
     attn = functools.partial(flash_attention, causal=True, scale=scale, impl=impl, window=window)
-    return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)(q, k, v)
+    lengths = _skippable(lengths, q.shape[2], q.shape[-1])  # the sequence is whole on every device: the shape's rule is the call's
+    if lengths is None:
+        return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(lambda q, k, v, n: attn(q, k, v, lengths=n), mesh=mesh, in_specs=(spec, spec, spec, P(batch)),
+                         out_specs=spec, check_vma=False)(q, k, v, lengths)
 
 
 def _broadcast_kv(q, k, v):
@@ -378,13 +430,48 @@ def _broadcast_kv(q, k, v):
     return k, v
 
 
-def _flash_fwd(q, k, v, causal, scale, impl="auto", window=None):
+def _query_tile(head_dim: int, T: int, block_q: int | None = None) -> int:
+    """Positions of one query tile of a call over ``T`` positions (at the default blocks, unless ``block_q`` says)."""
+    return min(block_q or _default_blocks(head_dim)[0], T)
+
+
+def _skippable(lengths, T: int, head_dim: int, block_q: int | None = None):
+    """THE rule, the shape's alone: a call of ONE query tile (``T`` positions in a tile of at least
+    as many) has nothing to skip and never can, so it does not learn the lengths. It is then the
+    call without them on every backend, and the program around it never holds them (asked where
+    a call enters, before ``custom_vjp`` or ``shard_map`` would make an operand of them, and by
+    ``_fwd_pallas`` for who calls the kernel itself); any other call keeps them."""
+    return None if lengths is None or T <= _query_tile(head_dim, T, block_q) else lengths
+
+
+def query_tiles(calls: dict, T: int, lengths) -> dict:
+    """Host arithmetic for a step's row of the flight log: ``attn_q_tiles``, the query tiles that
+    ``calls`` ({head width: calls}, a description's ``flash_calls``) over ``T`` padded positions
+    have by their shape, and ``attn_q_tiles_live``, those that start under a true length of
+    ``lengths`` (the program's, a padding row's among them): the ones a call with ``lengths`` runs.
+    Summed over calls and batch rows; every head of a row has its row's. A call of one query
+    tile (``_skippable``) runs it whatever the length: it counts once in both."""
+    tiles = live = 0
+    for head_dim, n_calls in calls.items():
+        block = _query_tile(head_dim, T)
+        tiles += n_calls * len(lengths) * -(-T // block)
+        live += n_calls * (len(lengths) if T <= block else sum(-(-min(int(n), T) // block) for n in lengths))
+    return {"attn_q_tiles": tiles, "attn_q_tiles_live": live}
+
+
+def _flash_fwd(q, k, v, causal, scale, impl="auto", window=None, lengths=None):
     kb, vb = _broadcast_kv(q, k, v)
     if _use_pallas(q, impl):
-        o, lse = _fwd_pallas(q, kb, vb, causal=causal, scale=scale, window=window)
+        o, lse = _fwd_pallas(q, kb, vb, causal=causal, scale=scale, window=window, lengths=lengths)
     else:
         o, lse = _fwd_xla_with_lse(q, kb, vb, causal, scale, window)
-    return o, (q, k, v, o, lse)
+        if lengths is not None:
+            # the kernel's contract, so that a program is the same function of its input on every backend
+            T = q.shape[2]
+            block = _query_tile(q.shape[-1], T)
+            skipped = jnp.arange(T)[None, :] // block * block >= lengths[:, None]
+            o = jnp.where(skipped[:, None, :, None], 0, o)
+    return o, (q, k, v, o, lse, lengths)
 
 
 def _fwd_xla_with_lse(q, k, v, causal, scale, window=None):
@@ -398,7 +485,9 @@ def _fwd_xla_with_lse(q, k, v, causal, scale, window=None):
 
 
 def _flash_bwd(causal, scale, impl, window, residuals, g):
-    q, k, v, o, lse = residuals
+    q, k, v, o, lse, lengths = residuals
+    if lengths is not None:
+        raise NotImplementedError("no backward pass knows a sequence's true length: differentiate the call without lengths")  # tpulint: disable=ERR002 — a programmer's error at trace time
     kb, vb = _broadcast_kv(q, k, v)
     if _use_pallas(q, impl):
         if window is not None:
@@ -411,7 +500,7 @@ def _flash_bwd(causal, scale, impl, window, residuals, g):
         rep = H // Hkv
         dk = dk.reshape(dk.shape[0], Hkv, rep, *dk.shape[2:]).sum(axis=2).astype(k.dtype)
         dv = dv.reshape(dv.shape[0], Hkv, rep, *dv.shape[2:]).sum(axis=2).astype(v.dtype)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), None
 
 
 def _bwd_xla(q, k, v, o, lse, g, causal, scale, window=None):
@@ -430,7 +519,7 @@ def _bwd_xla(q, k, v, o, lse, g, causal, scale, window=None):
     return dq, dk, dv
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 # kept for callers/tests that used the older name
 _flash_fwd_pallas = _fwd_pallas

@@ -67,6 +67,45 @@ def test_flash_fwd_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < _B * _H * _T * _T
 
 
+@pytest.mark.parametrize("shape, window", [((2, 20, 16384, 256), None), ((2, 16, 12288, 128), 4096), ((4, 16, 4096, 128), None)])
+def test_flash_fwd_with_true_lengths_compiles_for_v5e(one_chip, shape, window):
+    """The serving prefill's form where a bucket has a tile to skip (PR 52): the lengths prefetched, index
+    maps that read them. At GLM's expanded MLA (20 heads x 256 over 16,384), a SmallThinker window layer
+    and InternLM2's longdoc bucket."""
+    from ray_tpu.ops.flash_attention import _fwd_pallas
+
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    n = jax.ShapeDtypeStruct(shape[:1], jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(lambda q, k, v, n: _fwd_pallas(q, k, v, True, None, window=window, lengths=n), q, q, q, n)
+    assert "tpu_custom_call" in txt and ("window_flash_attention" in txt) == (window is not None)
+    assert compiled.memory_analysis().temp_size_in_bytes < shape[0] * shape[1] * shape[2] * 4  # the repeated lengths, no scores
+
+
+@pytest.mark.parametrize("bucket, learns", [(1024, False), (2048, True)])
+def test_llama_prefill_learns_the_lengths_only_where_its_bucket_has_a_tile_to_skip(one_chip, monkeypatch, bucket, learns):
+    """The rule is the shape's (``flash_attention._skippable``): at InternLM2's widths (128 columns,
+    tiles of 1,024) the 1,024 bucket's prefill lowers for the chip to the SAME text whether the
+    kernel may learn the lengths or never does, so chat's one-tile programs are the parent's; the
+    2,048 bucket's takes them."""
+    from ray_tpu.llm.model_runner import _sds_params, prefill
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.ops import flash_attention as fa
+
+    cfg = LlamaConfig(vocab_size=92544, hidden_size=2048, intermediate_size=8192, num_layers=2, num_heads=16,
+                      num_kv_heads=8, max_seq_len=4096, remat=False, attention_impl="pallas")
+    args = _on((_sds_params(cfg), jax.ShapeDtypeStruct((4, bucket), jnp.int32), jax.ShapeDtypeStruct((4,), jnp.int32)), one_chip)
+
+    def lowered():
+        return jax.jit(partial(prefill, cfg=cfg)).lower(*args).as_text()
+
+    with_the_rule = lowered()
+    with monkeypatch.context() as m:
+        m.setattr(fa, "_skippable", lambda lengths, *shape: None)  # the parent: no call learns a length
+        never = lowered()
+    assert "tpu_custom_call" in with_the_rule
+    assert (with_the_rule != never) == learns
+
+
 def test_flash_bwd_compiles_for_v5e(one_chip):
     from ray_tpu.ops.flash_attention import _bwd_pallas
 
@@ -242,9 +281,12 @@ def _sharded(tree, mesh, specs):
     )
 
 
-def test_tp4_prefill_with_flash_kernel_compiles_for_v5e(topo):
+@pytest.mark.parametrize("bucket", [512, 2048])
+def test_tp4_prefill_with_flash_kernel_compiles_for_v5e(topo, bucket):
     """The engine's prefill, SPMD over a tp=4 mesh, with the Pallas flash
-    kernel selected as it is on a TPU (heads over tp under shard_map)."""
+    kernel selected as it is on a TPU (heads over tp under shard_map): a
+    bucket of one query tile, and one of two, whose call takes the rows' true
+    lengths into the shard_map beside q, k and v (replicated: no batch axis)."""
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.llm.model_runner import _param_pspecs, _sds_params, prefill
@@ -255,7 +297,7 @@ def test_tp4_prefill_with_flash_kernel_compiles_for_v5e(topo):
                       num_kv_heads=16, max_seq_len=2048, remat=False, attention_impl="pallas")
     mesh = create_mesh(tp=4, devices=topo.devices)
     params = _sharded(_sds_params(cfg), mesh, _param_pspecs(cfg, mesh))
-    toks, lens = _sharded((jax.ShapeDtypeStruct((4, 512), jnp.int32), jax.ShapeDtypeStruct((4,), jnp.int32)),
+    toks, lens = _sharded((jax.ShapeDtypeStruct((4, bucket), jnp.int32), jax.ShapeDtypeStruct((4,), jnp.int32)),
                           mesh, (P(), P()))
     _, txt = _compile(partial(prefill, cfg=cfg, mesh=mesh), params, toks, lens)
     assert "tpu_custom_call" in txt and "all-reduce" in txt

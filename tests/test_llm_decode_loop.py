@@ -225,3 +225,41 @@ def test_an_abort_between_launch_and_readback_emits_nothing(params):
         prompt, sp = plain_admitted(sched)[i]
         assert (finals[i], reasons[i]) == reference_stream(CFG, params, prompt, sp)
     assert eng._slots == [None, None]
+
+
+# ------------------------------------------------------------------------------------------------
+# The flash kernel knows a row's true length where its bucket has a tile to skip (PR 52): query tiles
+# past it are zeros, not attention among padding, and nothing a client reads moves
+# ------------------------------------------------------------------------------------------------
+ONE_BUCKET_LENGTHS = [1, 17, 40, 64]  # one bucket of 64 in tiles of 16: one, two, three and all four query tiles live
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("tile", [16, None])
+def test_prompts_that_leave_whole_query_tiles_empty_are_served_the_plain_references_streams(params, impl, tile, monkeypatch):
+    """Four prompts of mixed lengths in ONE bucket, greedy and seeded lanes, the XLA form and the kernel
+    interpreted: in tiles of 16 the group leaves six of its sixteen query tiles empty, at the default
+    tile the bucket is one tile and no call learns a length; either way every stream is the plain
+    reference's, token for token, and the admitting step's row counts the query tiles of its flash
+    calls and those under a true length."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops import flash_attention as fa
+
+    if tile is not None:
+        monkeypatch.setattr(fa, "_default_blocks", lambda head_dim: (tile, tile))
+    cfg = LlamaConfig.tiny(dtype="float32", remat=False, max_seq_len=256, attention_impl=impl)
+    rng = np.random.default_rng(52)
+    prompts = [[int(t) for t in rng.integers(1, cfg.vocab_size - 1, size=n)] for n in ONE_BUCKET_LENGTHS]
+    sps = [SamplingParams(max_tokens=5), SamplingParams(max_tokens=6, temperature=0.9, top_k=12, seed=31),
+           SamplingParams(max_tokens=5), SamplingParams(max_tokens=4, temperature=1.0, seed=7)]
+    eng = LLMEngine(cfg, params=params, max_num_seqs=4, max_seq_len=128, prefill_buckets=(64,), enable_prefix_caching=False)
+    with pltpu.force_tpu_interpret_mode():
+        outs = eng.generate(prompts, sps)
+    for o, prompt, sp in zip(outs, prompts, sps):
+        assert (o.token_ids, o.finish_reason) == reference_stream(CFG, params, prompt, sp)
+    (row,) = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+    assert (row["prefill_tokens"], row["prefill_tokens_padded"]) == (sum(ONE_BUCKET_LENGTHS), 4 * 64)
+    calls = cfg.num_layers
+    want = (calls * 4 * 4, calls * (1 + 2 + 3 + 4)) if tile else (calls * 4, calls * 4)
+    assert (row["attn_q_tiles"], row["attn_q_tiles_live"]) == want

@@ -257,12 +257,63 @@ def test_a_hybrid_models_rows_carry_routing_counters_and_the_state_insert_stage(
     steps = eng.telemetry()["steps"]
     admitting = [s for s in steps if s.get("admitted")]
     assert admitting and all(0 < s["state_insert_ms"] <= s["prefill_ms"] for s in admitting)
+    # its buckets are one query tile each: every flash call's tile is live whatever the length (PR 52)
+    assert all(s["attn_q_tiles"] == s["attn_q_tiles_live"] >= cfg.count("attn") > 0 for s in admitting)
     drained = [s for s in steps if "experts_hit" in s]
     assert len(drained) >= 4 and all(set(MOE_STATS) <= set(s) for s in drained)
     for s in drained:  # 2 lanes x 2 experts a token asked for; chip 0 of two holds 4 of the 8
         assert s["moe_pairs_total"] in (2.0, 4.0) and 0 <= s["moe_pairs_local"] <= s["moe_pairs_total"]
         assert s["experts_hit"] <= min(4, s["moe_pairs_local"]) and s["moe_max_load"] <= s["moe_pairs_total"] / 2
     assert eng._tel._state_bytes == eng.kv_cache_stats()["state_allocated_bytes"] > 0 and plain._tel._state_bytes == 0
+
+
+@pytest.mark.parametrize("tile", [None, 16])
+def test_a_hybrid_models_admitting_row_counts_its_flash_calls_query_tiles(tile, monkeypatch):
+    """PR 52: an admitting step's row carries ``attn_q_tiles`` (calls x rows x tiles of the bucket) and
+    ``attn_q_tiles_live`` (those that start under a row's true length: what the kernel computes and
+    fetches). At the default tile the bucket is ONE tile, no call learns a length and the two are equal;
+    in tiles of 16 a group of lengths 1, 17, 40 and 64 leaves six of its sixteen tiles empty, its rows
+    go on through the delta rule and the experts as zeros, and what is served is the family's plain
+    reference's and, token for token, what an engine whose kernel never learns a length serves."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference
+    from benchmark.families import qwen3_next as family
+    from ray_tpu.models import qwen3_next as qn
+    from ray_tpu.ops import flash_attention as fa
+
+    assert {"attn_q_tiles", "attn_q_tiles_live"} <= set(telemetry.PREFILL_COUNTERS) <= set(telemetry.FlightRecorder.STEP_FIELDS)
+    if tile is not None:
+        monkeypatch.setattr(fa, "_default_blocks", lambda head_dim: (tile, tile))
+    c = family.rehearsal({"linear_conv_kernel_dim": 4, "norm_topk_prob": True, "rms_norm_eps": 1e-6,
+                          "partial_rotary_factor": 0.25, "rope_theta": 1e7})
+    cfg = family.program_config(c, 128, remat=False)
+    weights = jax.jit(lambda k: qn.init_params(cfg, k))(jax.random.PRNGKey(7))
+    rs = np.random.RandomState(52)
+    lengths = [1, 17, 40, 64]
+    prompts = [[int(t) for t in rs.randint(1, cfg.vocab_size - 1, size=n)] for n in lengths]
+    sps = [SamplingParams(max_tokens=5, logprobs=True), SamplingParams(max_tokens=6, temperature=0.9, top_k=12, seed=31, logprobs=True),
+           SamplingParams(max_tokens=5, logprobs=True), SamplingParams(max_tokens=4, temperature=1.0, seed=7, logprobs=True)]
+
+    def serve():
+        eng = LLMEngine(cfg, params=weights, max_num_seqs=4, max_seq_len=128, prefill_buckets=(64,), enable_prefix_caching=False)
+        return eng, eng.generate(prompts, sps)
+
+    eng, outs = serve()
+    (row,) = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+    calls = cfg.count("attn")
+    assert (row["prefill_tokens"], row["prefill_tokens_padded"]) == (sum(lengths), 4 * 64)
+    assert (row["attn_q_tiles"], row["attn_q_tiles_live"]) == ((calls * 16, calls * 10) if tile else (calls * 4, calls * 4))
+    samples = [{"prompt": p, "tokens": o.token_ids, "logprobs": o.logprobs, "greedy": sp.temperature == 0.0}
+               for o, p, sp in zip(outs, prompts, sps)]
+    res = reference.check_served(family.reference_logprobs, weights, c, samples, 1e-3)
+    assert res["ok"] and res["tokens"] == 20 and res["max_abs_dlogprob"] < 1e-4, res
+    with monkeypatch.context() as m:
+        m.setattr(fa, "_skippable", lambda lengths, *shape: None)  # the parent: every query tile of the bucket computed
+        _, parents = serve()
+    assert [o.token_ids for o in outs] == [o.token_ids for o in parents]
+    assert jnp.allclose(jnp.asarray([o.logprobs for o in outs][0]), jnp.asarray([o.logprobs for o in parents][0]), atol=1e-5)
 
 
 def test_a_step_row_carries_the_time_the_process_spent_collecting_garbage():
@@ -406,7 +457,7 @@ def test_the_logs_bound_drops_the_oldest_and_says_how_many(session, monkeypatch)
 
 
 def test_the_logs_memory_stays_under_its_stated_bound():
-    """12,000 step rows and 2,000 requests of 150 tokens: the stated bound (21 MB since PR 39 added three fields to a step row; 21.5 since PR 46 added two, 16 bytes a row; 21.7 since PR 50 added two)."""
+    """12,000 step rows and 2,000 requests of 150 tokens: the stated bound (21 MB since PR 39 added three fields to a step row; 21.5 since PR 46 added two, 16 bytes a row; 21.7 since PR 50 added two; 21.9 since PR 52 added two)."""
     import tracemalloc
 
     rec = telemetry.FlightRecorder()
@@ -421,7 +472,7 @@ def test_the_logs_memory_stays_under_its_stated_bound():
     held, _ = tracemalloc.get_traced_memory()
     tracemalloc.stop()
     assert len(rec.log_steps) == 12_000 and len(rec.log_requests) == 2_000
-    assert held < 21.7e6, f"the flight log holds {held / 1e6:.1f} MB"
+    assert held < 21.9e6, f"the flight log holds {held / 1e6:.1f} MB"
 
 
 def test_load_flight_merges_two_processes_and_skips_a_torn_last_line(session):
