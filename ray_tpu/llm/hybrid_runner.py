@@ -100,7 +100,7 @@ def prefill(params, tokens, length, cfg, mesh=None):
     x, out = hybrid.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
     with scope("head"):
         x_last = jnp.take_along_axis(x, (length - 1)[:, None, None], axis=1)[:, 0]
-        logits = jnp.dot(x_last, params["unembed"], preferred_element_type=jnp.float32)
+        logits = hybrid.head(x_last, params)
     if ROUTING in out:
         out[ROUTING] = jnp.mean(out[ROUTING], axis=0)
     return logits, {name: out.pop(name) for name in cfg.position_entries()}, out
@@ -137,7 +137,7 @@ def decode_step(params, cache, state, tokens, active, cfg):
     handed = {n: jnp.zeros((B,) + tuple(shape), jnp.dtype(dt)) for n, (shape, dt) in cfg.handed.items()}
     (x, _), (arrays, stats) = hybrid.run_layers(cfg, params, (x, handed), (arrays, jnp.zeros((4,), jnp.float32)), layer)
     with scope("head"):
-        logits = jnp.dot(hybrid.before_head(x, params, cfg), params["unembed"], preferred_element_type=jnp.float32)
+        logits = hybrid.head(hybrid.before_head(x, params, cfg), params)
     n = max(cfg.routing_layers, 1)
     total = cfg.expert_layer.top_k * jnp.sum(active.astype(jnp.float32)) if cfg.routing_layers else jnp.zeros((), jnp.float32)
     moe = jnp.stack([stats[0] / n, stats[1] / n, total, stats[2], stats[3] / n])

@@ -75,9 +75,9 @@ logger = logging.getLogger("ray_tpu.llm")
 # takes on that step's row; then what the engine counts of any model's from its ``flash_calls``
 # (``ops/flash_attention.query_tiles``)
 PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs",
-                    "attn_q_tiles", "attn_q_tiles_live")
+                    "narrow_pairs", "attn_q_tiles", "attn_q_tiles_live")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
-DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read")
+DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read", "narrow_rows_read")
 
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
@@ -381,8 +381,9 @@ class FlightRecorder:
         # lanes hold (``HybridDescription.decode_counters``): blocks of 64 positions, a key-value head's
         # share each, that the sparse layers read for the blocks their queries chose, and would read
         # attending to everything; rows of a window layer's ring that the step's bound lanes read, over the
-        # window layers (``swa_rows_read``: min(position + 1, window) a lane and layer); absent for a
-        # description that counts none
+        # window layers (``swa_rows_read``: min(position + 1, window) a lane and layer); positions whose keys and
+        # values the attention layers with heads narrower than the 128 lanes read, over those layers
+        # (``narrow_rows_read``: position + 1 a lane and layer); absent for a description that counts none
         *DECODE_COUNTERS,
         "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
@@ -416,8 +417,9 @@ class FlightRecorder:
         # among them, over the layers of Kimi Delta Attention (``kda_``) or of Gated DeltaNet (``gdn_``), and how
         # many of them the kernel ran; (query, block) pairs that the sparse layers read at the prompts' true
         # lengths; (query, key) pairs inside the window that the sliding-window layers' mathematics needs at
-        # the prompts' true lengths (``swa_pairs``: min(i + 1, window) a position and layer); absent for a
-        # description that counts none. Last, of any model: the query tiles that the programs' flash calls
+        # the prompts' true lengths (``swa_pairs``: min(i + 1, window) a position and layer); causal (query, key)
+        # pairs of the attention layers with heads narrower than the 128 lanes at the prompts' true lengths
+        # (``narrow_pairs``: i + 1 a position and layer); absent for a description that counts none. Last, of any model: the query tiles that the programs' flash calls
         # have by their shape (``attn_q_tiles``: calls x batch rows x tiles of the bucket) and those that start
         # under a row's true length (``attn_q_tiles_live``): the kernel computes and fetches these alone
         *PREFILL_COUNTERS,

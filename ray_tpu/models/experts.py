@@ -86,6 +86,7 @@ class ExpertLayer:
     act: str = "swiglu"  # swiglu | reglu (w_gate, w_up, w_down) | relu2 (w_up, w_down)
     shared_gated: bool = False
     shared: bool = True  # False: routed experts alone, no expert that every token passes
+    norm_eps: float = 1e-20  # what the sum of the chosen scores is raised by where they are normalised (as published: 1e-20, or 1e-6)
 
     def __post_init__(self):
         if self.score not in ("softmax", "sigmoid") or self.act not in ("swiglu", "reglu", "relu2"):
@@ -128,7 +129,7 @@ def route(w, x, c):
     _, idx = jax.lax.top_k(scores + w["router_bias"] if s.bias else scores, s.top_k)
     wt = jnp.take_along_axis(scores, idx, axis=-1)
     if s.norm_topk:
-        wt = wt / (jnp.sum(wt, axis=-1, keepdims=True) + 1e-20)
+        wt = wt / (jnp.sum(wt, axis=-1, keepdims=True) + s.norm_eps)
     return idx.astype(jnp.int32), wt * s.scale if s.scale != 1.0 else wt
 
 

@@ -28,7 +28,8 @@ file states WHAT its layers are; this file walks them. A description
 
 Parameters are stacked by layer kind (``params[kind][name]``: [layers of that
 kind, ...]) so that the loops can index them; ``embed``, ``unembed`` and
-``final_norm`` stand beside the kinds.
+``final_norm`` stand beside the kinds (a model whose head is TIED to its embedding holds no
+``unembed``: ``head`` then multiplies by the table itself).
 
 Two loops over one description:
 
@@ -45,7 +46,11 @@ Two loops over one description:
 
 Neither loop, nor the step programs of ``llm/hybrid_runner.py`` that call them,
 names a model or a kind of layer. A uniform model is the special case of one kind
-and a period of one.
+and a period of one. Seven descriptions stand over these loops today
+(``nemotron_h``, ``qwen3_next``, ``glm4_moe_lite``, ``kimi_linear``, ``minicpm_sala``,
+``smallthinker``, ``lfm2``, each a file beside this one), and the benchmark has a
+family file for each and an eighth for ``llama``, which the runner still writes out
+itself (``benchmark/families/``).
 """
 
 from __future__ import annotations
@@ -365,6 +370,12 @@ def add_branch(x, y, c):
     return x + y.astype(x.dtype) if a == 1.0 else x + (y.astype(jnp.float32) * a).astype(x.dtype)
 
 
+def head(x, params):
+    """x [.., H] -> logits [.., vocab] float32: through ``unembed`` [H, V], or, where the weights hold
+    none, through the embedding table [V, H] itself (a tied head: contracted over its columns)."""
+    return jnp.dot(x, params["unembed"] if "unembed" in params else params["embed"].T, preferred_element_type=jnp.float32)
+
+
 def before_head(x, params, c):
     """The final norm, in the weights' dtype, times the description's constant if it has one: what the head multiplies."""
     x, scale = c.norm(x, params["final_norm"]), c.stream_scales[2]
@@ -408,7 +419,7 @@ def forward(params, tokens, config, mesh=None):
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _ = forward_hidden(params, tokens, lengths, config, mesh)
     with scope("head"):
-        return jnp.dot(x, params["unembed"], preferred_element_type=jnp.float32)
+        return head(x, params)
 
 
 def loss_fn(params, batch, config, mesh=None):

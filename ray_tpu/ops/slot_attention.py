@@ -26,6 +26,15 @@ with the usual running max and sum. The probabilities stay float32: they are spl
 bfloat16 terms (8 + 8 + 8 mantissa bits) that multiply the bfloat16 values exactly, so the second
 product accumulates what a float32 x float32 one would.
 
+Heads NARROWER than the 128 lanes (64 wide: ``models/lfm2.py``) lie ``128 // hd`` to a row: the
+cache keeps a position's ``(kv, hd)`` keys as ``(kv * hd / 128, 128)`` (``position_tile``), because a
+stack whose last dimension is 64 takes 128 lanes a row on the chip anyway (twice the bytes) and
+reaches the kernel only through a copy. The kernel is the same one: it sees rows of 128 as it would
+``kv * hd / 128`` heads of 128, each query placed in its own head's columns of its row with zeros
+in the others (so a row's product with it is its own head's score), the output's own columns cut
+out afterwards; the MXU multiplies the zeros (it idles in a decode step anyway) and the bytes
+streamed are the keys' and values' own (``slot_decode_attention_narrow`` in a trace).
+
 A LATENT layer (``attend_latent``; ``models/glm4_moe_lite.py``) keeps no heads: a position is one
 latent row ``c_kv`` [r] and one rotated key ``k_r`` [rope, in whole 128-lane tiles: the model file
 says why], two stacked arrays ``[L, slots, S, r]`` and ``[L, slots, S, rope]``, and every query head
@@ -116,6 +125,17 @@ def block_positions(S: int, num_kv_heads: int, head_dim: int, itemsize: int, blo
 
 
 KERNEL = "slot_decode_attention"  # the live-block kernel's name in a trace, where a caller gives it no other
+KERNEL_NARROW = "slot_decode_attention_narrow"  # and over rows that hold several heads narrower than the 128 lanes
+LANES = 128
+
+
+def position_tile(num_kv_heads: int, head_dim: int) -> tuple:
+    """The shape a position's keys (or values) take in the stacked cache: ``(kv, hd)``, or, for heads
+    narrower than the 128 lanes that fill whole rows of them, ``(kv * hd / 128, 128)``: ``128 // hd``
+    heads side by side a row, head g in row ``g // (128 // hd)``. The same bytes in the same order."""
+    if head_dim < LANES and LANES % head_dim == 0 and (num_kv_heads * head_dim) % LANES == 0:
+        return num_kv_heads * head_dim // LANES, LANES
+    return num_kv_heads, head_dim
 
 
 def padded_heads(num_heads: int, num_kv_heads: int) -> int:
@@ -155,8 +175,15 @@ def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: in
         if block_positions(S, 1, value_dim, dt.itemsize) < 512:
             return f"{S} positions a slot: no block of at least 512 positions divides it"
         return None
+    if head_dim == 64:
+        # two heads a row of 128 lanes (``position_tile``): the kernel sees kv / 2 heads of 128, whose every group of
+        # query rows is two key-value heads' groups, whole
+        if num_kv_heads % 2 or num_heads % num_kv_heads or num_heads % 16:
+            return (f"{num_heads} query heads over {num_kv_heads} kv heads x head_dim 64: compiled at 32 over 8 (pairs of "
+                    "key-value heads a 128-lane row, whole bfloat16 tiles of 16 query rows)")
+        return refusal(cache_dtype, num_heads, num_kv_heads // 2, 128, S)
     if head_dim % 128 or head_dim > 256:
-        return f"head_dim {head_dim}: compiled at 128 and 256 (a multiple of the 128 lanes)"
+        return f"head_dim {head_dim}: compiled at 64 (two heads a row), 128 and 256 (a multiple of the 128 lanes)"
     if num_kv_heads & (num_kv_heads - 1) or num_kv_heads > 8:
         return f"{num_kv_heads} kv heads: compiled at 2 and 8 (a power of two, at most 8)"
     if head_dim != 128 and num_kv_heads != 8:
@@ -300,23 +327,40 @@ def _launch(kernel, name: str, layer, bound, queries, stacks, blk_rows: int, blk
 
 
 def attend_kernel(q, k_stack, v_stack, layer, bound, *, block: int | None = None, interpret: bool = False,
-                  name: str = KERNEL):
+                  name: str = KERNEL, scale: float | None = None):
     """The kernel form. q [B,nh,hd]; k/v_stack [L,B,S,kv,hd]; layer: int32 scalar (traced or not);
     bound [B] int32: lane b attends rows 0 .. bound[b]-1 of layer ``layer`` (0: the lane is
     bound to no sequence, reads nothing and gets zeros). ``name``: the kernel's in a trace (a ring
-    layer's calls carry their own). -> [B, nh*hd] float32."""
+    layer's calls carry their own). ``scale``: on the scores, where it is not hd^-1/2.
+    -> [B, nh*hd] float32."""
     B, nh, hd = q.shape
     L, _, S, kv, _ = k_stack.shape
     blk = block or block_positions(S, kv, hd, k_stack.dtype.itemsize)
     rows = padded_heads(nh, kv)
     if rows != nh:  # each group's rows of zeros at its end: head h stays row (h // rep) * rep' + h % rep
         q = jnp.pad(q.reshape(B, kv, nh // kv, hd), ((0, 0), (0, 0), (0, (rows - nh) // kv), (0, 0))).reshape(B, rows, hd)
-    kernel = functools.partial(_kernel, blk=blk, kv=kv, rep=rows // kv, scale=1.0 / math.sqrt(hd))
+    kernel = functools.partial(_kernel, blk=blk, kv=kv, rep=rows // kv, scale=1.0 / math.sqrt(hd) if scale is None else scale)
     out = _launch(kernel, name, layer, bound, [q],
                   [k_stack.reshape(L, B, S * kv, hd), v_stack.reshape(L, B, S * kv, hd)], blk * kv, blk, hd, interpret)
     if rows != nh:
         out = out.reshape(B, kv, rows // kv, hd)[:, :, :nh // kv]
     return out.reshape(B, nh * hd)
+
+
+def attend_narrow_kernel(q, k_stack, v_stack, layer, bound, num_kv_heads: int, *, block: int | None = None, interpret: bool = False):
+    """``attend_kernel`` for heads narrower than the 128 lanes: q [B,nh,hd]; k/v_stack
+    [L,B,S,rows,128] with ``128 // hd`` key-value heads side by side a row (``position_tile``).
+    Query head h reads key-value head g = h // (nh / kv), which lies in row ``g // pack`` at columns
+    ``(g % pack) * hd``: the query goes to those columns of a 128-wide row of its own and zeros to
+    the rest, the kernel then takes the rows for heads of 128 (a row's score is the head's own,
+    scaled by hd^-1/2), and of its 128 output columns a head keeps its own. -> [B, nh*hd] float32."""
+    B, nh, hd = q.shape
+    pack = LANES // hd
+    at = (jnp.arange(nh) // (nh // num_kv_heads)) % pack  # [nh]: which of a row's heads is head h's
+    own = at[:, None] == jnp.arange(pack)[None, :]  # [nh, pack]
+    wide = jnp.where(own[None, :, :, None], q[:, :, None, :], jnp.zeros((), q.dtype)).reshape(B, nh, LANES)
+    out = attend_kernel(wide, k_stack, v_stack, layer, bound, block=block, interpret=interpret, name=KERNEL_NARROW, scale=hd ** -0.5)
+    return jnp.sum(jnp.where(own[None, :, :, None], out.reshape(B, nh, pack, hd), 0.0), axis=2).reshape(B, nh * hd)
 
 
 def attend_latent_kernel(q_lat, q_rope, c_stack, r_stack, layer, bound, scale: float, *, block: int | None = None,
@@ -429,15 +473,21 @@ def attend(q, k_stack, v_stack, layer, lengths, num_kv_heads: int, *, live=None,
     A stack of S rows that is a RING of a window of S positions (``llm/kv_cache.py``) is read the
     same way: a lane at ``lengths`` holds min(lengths + 1, S) live rows, its first that many, which
     is this op's bound for any stack; ``name`` is then the kernel's own name in a trace.
+    Heads narrower than the stack's rows (q's hd under the stack's 128): the stack holds several
+    heads a row (``position_tile``), and the kernel that reads it is ``KERNEL_NARROW`` in a trace.
     -> [B, nh*hd] float32."""
-    S = k_stack.shape[2]
+    S, narrow = k_stack.shape[2], k_stack.shape[-1] != q.shape[2]
     why = refusal(k_stack.dtype, q.shape[1], num_kv_heads, q.shape[2], S, quantized=k_scale is not None, sharded=sharded)
     if why is None:
         bound = jnp.minimum(lengths, S - 1) + 1
+        bound = bound if live is None else jnp.where(live, bound, 0)
         # off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
-        return attend_kernel(q, k_stack, v_stack, layer, bound if live is None else jnp.where(live, bound, 0),
-                             interpret=jax.default_backend() != "tpu", name=name)
+        if narrow:
+            return attend_narrow_kernel(q, k_stack, v_stack, layer, bound, num_kv_heads, interpret=jax.default_backend() != "tpu")
+        return attend_kernel(q, k_stack, v_stack, layer, bound, interpret=jax.default_backend() != "tpu", name=name)
     k_rows, v_rows = layer_of(k_stack, layer), layer_of(v_stack, layer)
+    if narrow:  # the rows as heads again: the same bytes
+        k_rows, v_rows = (a.reshape(a.shape[:2] + (num_kv_heads, q.shape[2])) for a in (k_rows, v_rows))
     if k_scale is not None:  # dequantize at the float32 the products already accumulate in
         k_rows = k_rows.astype(jnp.float32) * layer_of(k_scale, layer).transpose(0, 2, 1)[..., None]
         v_rows = v_rows.astype(jnp.float32) * layer_of(v_scale, layer).transpose(0, 2, 1)[..., None]

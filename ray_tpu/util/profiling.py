@@ -44,7 +44,7 @@ ANNOTATION_LEVEL = 1
 # The scope vocabulary: name -> role. A sub-scope is named ``<kind>.<part>`` and set INSIDE its
 # kind's scope (a path ``.../moe/moe.place/...``); an operation belongs to the DEEPEST table name
 # on its path. Roles: ``mixer`` whatever mixes along the sequence, ``ffn`` whatever acts on a
-# position alone, ``state`` a recurrent state's read-decay-write in a decode step, ``cache`` a
+# position alone, ``state`` a recurrent state's read-decay-write (or a convolution's window's read and write) in a decode step, ``cache`` a
 # write into the KV / latent / state caches, ``embed`` / ``head`` / ``sample`` the ends of a step.
 # ---------------------------------------------------------------------------
 SCOPES = {
@@ -55,7 +55,8 @@ SCOPES = {
     "mla": "mixer", "mla.down": "mixer", "mla.expand": "mixer", "mla.absorb": "mixer", "mla.attn": "mixer",
     "sparse": "mixer", "sparse.select": "mixer", "sparse.attend": "mixer",
     "lightning": "mixer", "lightning.chunk": "mixer",
-    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state", "lightning.state": "state",
+    "shortconv": "mixer", "shortconv.conv": "mixer",
+    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state", "lightning.state": "state", "shortconv.state": "state",
     "mlp": "ffn", "ffn": "ffn",
     "moe": "ffn", "moe.route": "ffn", "moe.place": "ffn", "moe.place.count": "ffn", "moe.place.into": "ffn", "moe.place.out": "ffn",
     "moe.blocks": "ffn", "moe.shared": "ffn",

@@ -353,7 +353,8 @@ CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widt
          "glm": ("glm-4.7-flash-d8.json", {"remat": False}),  # 8 of 47 layers whole, 16 slots x 16,384
          "kimi": ("kimi-linear-48b-a3b-ep4.json", {"remat": False}),  # 9 of 27 layers, 64 of 256 experts, 16 slots x 4096
          "sala": ("minicpm-sala-9b-d8.json", {"remat": False}),  # layers 9-16 of 32, the whole vocabulary, 16 slots x 12,288
-         "smallthinker": ("smallthinker-21b-a3b-d8.json", {"remat": False})}  # layers 0-7 of 52, every expert, the whole vocabulary, 16 slots x 12,288
+         "smallthinker": ("smallthinker-21b-a3b-d8.json", {"remat": False}),  # layers 0-7 of 52, every expert, the whole vocabulary, 16 slots x 12,288
+         "lfm2": ("lfm2-24b-a2b-d10.json", {"remat": False})}  # layers 0-9 of 40, every expert, the whole vocabulary, 16 slots x 12,288
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -900,3 +901,84 @@ def test_smallthinker_ring_insertion_updates_the_cache_in_place_and_gathers_only
     mem = insert.lower(cache, s((), jnp.int32), new, s((), jnp.int32)).compile().memory_analysis()
     print("smallthinker insert:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
     assert mem.alias_size_in_bytes >= _kv_bytes(cache) and mem.temp_size_in_bytes < 8 * 2**20
+
+
+# ---------------------------------------------------------------------------
+# PR 53: a seventh description, LFM2 with experts (models/lfm2.py): the cell lfm2-24b-d10.longdoc-12k.
+# ---------------------------------------------------------------------------
+def test_narrow_slot_attention_kernel_compiles_for_v5e_and_copies_nothing(one_chip, as_on_a_tpu):
+    """32 query heads over 8 key-value heads 64 wide at 16 x 12,288: the gate lets the tile through,
+    the cache keeps a position as 4 rows of 128 lanes (two heads a row), and the live-block kernel
+    reads them where they lie (seen as [L, slots, S*4, 128] by a bitcast) under a name of its own:
+    no temporary of any size to speak of. A cache of (8, 64) tiles would be twice the bytes."""
+    from ray_tpu.ops import slot_attention as sa
+
+    assert sa.refusal(jnp.bfloat16, 32, 8, 64, 12288) is None and sa.position_tile(8, 64) == (4, 128)
+    assert sa.position_tile(8, 128) == (8, 128) and sa.position_tile(2, 16) == (2, 16) and sa.position_tile(2, 64) == (1, 128)
+    for heads, kv in ((32, 1), (24, 8), (8, 8)):
+        assert f"{heads} query heads over {kv} kv heads x head_dim 64" in sa.refusal(jnp.bfloat16, heads, kv, 64, 12288)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    stack = sds((2, 16, 12288, 4, 128), jnp.bfloat16)
+    compiled, txt = _compile(partial(sa.attend_narrow_kernel, num_kv_heads=8), sds((16, 32, 64), jnp.bfloat16), stack, stack, sds((), jnp.int32), sds((16,), jnp.int32))
+    assert "tpu_custom_call" in txt and sa.KERNEL_NARROW in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_flash_fwd_at_heads_64_wide_compiles_for_v5e(one_chip):
+    """The flash call at 32 heads of 64 over 12,288 positions with the true lengths, the kernel's tiles
+    1,024 x 64: what ``models/lfm2.py`` does NOT run. Mosaic takes an operand whose rows are 64 wide
+    only in whole 128-lane tiles: the compiler copies q, k and v into that layout and the output out
+    of it (four arrays of 2 x 32 x 12,288 x 128 bfloat16, 0.75 GiB here), and on the chip those
+    copies stood the core idle 4 ms apiece (PERF.md section 6, PR 53). The model pads a head to 128
+    where it is made instead (``Lfm2Config.flash_width``): the prefill case below compiles that."""
+    from ray_tpu.ops.flash_attention import _fwd_pallas
+
+    q = jax.ShapeDtypeStruct((2, 32, 12288, 64), jnp.bfloat16, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(lambda q, k, v, n: _fwd_pallas(q, k, v, True, None, lengths=n), q, q, q, n)
+    assert "tpu_custom_call" in txt and "window_flash_attention" not in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.05 * 2 * 32 * 12288 * 128 * 2
+
+
+def test_lfm2_fused_step_fits_one_v5e_aliases_rows_and_windows_and_slices_no_layers_rows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 16 x 12,288 through the SAME ``hybrid_runner.fused_step`` and layer loop as
+    the six other descriptions (two dense layers unrolled, then ``attn moe shortconv moe shortconv
+    moe shortconv moe`` twice, scanned): 9.81 GiB of weights (no ``unembed``: the head is the table),
+    0.75 GiB of keys and values at 4,096 B a position and 1 MiB of convolution windows, all of it
+    aliased to the donated inputs; the live-block kernel at heads 64 wide by its own name, the
+    experts' step kernel, and no slice of a layer's rows (96 MiB of keys at 16 x 12,288) in the
+    compiled text; the temporaries under the bound the file holds for the others."""
+    import re
+
+    cfg, params, cache, state, compiled = fused_step_for_the_chip("lfm2")
+    assert cfg.layer_plan == (("attn", "moe", "shortconv", "moe", "shortconv", "moe", "shortconv", "moe"), 2, (), ("shortconv", "ffn", "shortconv", "ffn"))
+    assert "unembed" not in params and {n: a.shape for n, a in cache.items() if n != "length"} == {"k": (2, 16, 12288, 4, 128), "v": (2, 16, 12288, 4, 128)}
+    assert {n: (a.shape, str(a.dtype)) for n, a in state.items()} == {"conv": ((8, 16, 2, 2048), "bfloat16")}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert _kv_bytes(cache) == 805_306_368 == 16 * 12288 * 4096
+    print("lfm2 fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+    assert 10.5 * 2**30 < mem.argument_size_in_bytes < 10.65 * 2**30 and mem.alias_size_in_bytes >= _kv_bytes(cache) + _kv_bytes(state)
+    assert all(name in txt for name in ("slot_decode_attention_narrow", "step_experts", "shortconv.state", "shortconv.conv"))
+    assert not re.search(r"bf16\[(1,)?16,12288,(4,128|8,64)\]", txt)
+    assert mem.temp_size_in_bytes < 16 * 2**20  # 7.6 MiB
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 0.7), (4, 2.9)])
+def test_lfm2_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 12,288-bucket prefill (the short convolution a sequence at a time under ``shortconv``, the
+    flash kernel at heads 64 wide under ``attn``, an 11,776-wide SwiGLU in slabs, 49,152 routed
+    pairs a prompt through the grouped matmul) for one prompt and for the largest group the cell
+    warms, 4 x 12,288, beside 9.81 GiB of weights and 0.75 GiB of cache: under 15.75 GiB."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "lfm2")
+    tokens = jax.ShapeDtypeStruct((prompts, 12288), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("lfm2 prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    kernels = [line for line in txt.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert kernels and all("/attn/" in line for line in kernels), "the attention layers' flash kernel, under its scope"
+    assert "shortconv.conv" in txt
+    assert mem.temp_size_in_bytes < most_gib * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.75 * 2**30 < 15.75 * 2**30

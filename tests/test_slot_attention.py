@@ -85,7 +85,8 @@ def _on_a_tpu(monkeypatch):
     ((jnp.bfloat16, 16, 8, 128, 4096), {"sharded": True}, "shard_map"),
     ((jnp.int8, 16, 8, 128, 4096), {"quantized": True}, "int8"),
     ((jnp.float32, 16, 8, 128, 4096), {}, "float32"),
-    ((jnp.bfloat16, 16, 8, 64, 4096), {}, "head_dim 64"),
+    ((jnp.bfloat16, 24, 8, 64, 4096), {}, "head_dim 64"),  # 16 or 32 over 8 go through since PR 53: two heads a 128-lane row
+    ((jnp.bfloat16, 16, 8, 32, 4096), {}, "head_dim 32"),
     ((jnp.bfloat16, 16, 2, 256, 4096), {}, "copy of the whole cache"),
     ((jnp.bfloat16, 24, 3, 128, 4096), {}, "3 kv heads"),
     ((jnp.bfloat16, 8, 8, 128, 4096), {}, "8 query heads"),
