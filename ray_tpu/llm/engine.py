@@ -2256,12 +2256,18 @@ class LLMEngine:
             stats = None
             if tel is not None:
                 # the step's row in the flight log: tokens prefilled, true and as padded, what the description
-                # counts of the shape, the flash calls' query tiles; the routing counters ride the first tokens' readback
+                # counts of the shape, the flash calls' query tiles, the positions a position-wise sub-block runs; the
+                # routing counters ride the first tokens' readback
                 from ray_tpu.ops.flash_attention import query_tiles
+                from ray_tpu.ops.layers import live_rows
 
                 true = [len(p) for _, _, p in group]
                 counted = self.config.prefill_counters(Bp, T, lengths=true) if self._hybrid else {}
-                stats = (sum(true), Bp * T, {**counted, **query_tiles(self.config.flash_calls(T), T, rows_lens)})
+                if self._hybrid:  # the description knows which of its sub-blocks go through ``live_slabs``
+                    rows_live = self.config.prefill_rows_live(T, rows_lens)
+                else:  # ``model_runner.prefill``'s MLP does; under a mesh the plain form runs
+                    rows_live = live_rows(T, rows_lens) if self.mesh is None else Bp * T
+                stats = (sum(true), Bp * T, {**counted, **query_tiles(self.config.flash_calls(T), T, rows_lens), "prefill_rows_live": rows_live})
             self._bind_group([(st, slot) for st, slot, _ in group], logits, stamps=stamps, stats=stats,
                              routing=kept.get("routing") if self._hybrid and stats is not None else None)
             if stamps is not None:

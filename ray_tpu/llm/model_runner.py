@@ -38,7 +38,7 @@ from ray_tpu.lint import jaxcheck
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops import slot_attention
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
-from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
+from ray_tpu.ops.layers import apply_rope, live_slabs, rms_norm, rotary_embedding
 from ray_tpu.util.profiling import SCOPES, scope, scoped  # noqa: F401 - SCOPES: the table beside STEP_PROGRAM_NAMES, where a reader looks for it
 
 
@@ -324,12 +324,24 @@ def _qkv(xn, layer, cfg: LlamaConfig):
     return q, k, v
 
 
-def _mlp(x, layer, cfg: LlamaConfig, tpc: TpSpec | None = None):
+_MLP = ("mlp_norm", "w_gate", "w_up", "w_down")
+
+
+def _mlp(x, layer, cfg: LlamaConfig, tpc: TpSpec | None = None, live=None):
+    """``live`` = (the true lengths [B] of x [B,T,H]'s rows, the layers' stacked weights, this layer's
+    index), from a prefill that wants the positions under the lengths and no others
+    (``ops/layers.live_slabs``: norm and products a slab at a time, read where they lie in the stack)."""
+    def branch(x, w=layer):
+        xn = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
+        g = jnp.dot(xn, w["w_gate"])
+        u = jnp.dot(xn, w["w_up"])
+        return jnp.dot(jax.nn.silu(g) * u, w["w_down"])
+
     with scope("mlp"):
-        xn = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-        g = jnp.dot(xn, layer["w_gate"])
-        u = jnp.dot(xn, layer["w_up"])
-        return x + _tp_reduce(jnp.dot(jax.nn.silu(g) * u, layer["w_down"]), tpc)
+        if live is None:
+            return x + _tp_reduce(branch(x), tpc)
+        lengths, stacked, i = live
+        return x + live_slabs(branch, x, lengths, layer, ({n: stacked[n] for n in _MLP}, i))
 
 
 _layer_of = slot_attention.layer_of  # layer i of a stacked cache leaf; ``spec/verify.py`` takes it from here
@@ -369,7 +381,8 @@ def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
     with scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
 
-    def layer_fn(x, layer):
+    def layer_fn(x, layer_and_index):
+        layer, i = layer_and_index
         with scope("attn"):
             xn = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
             q, k, v = _qkv(xn, layer, cfg)
@@ -378,14 +391,14 @@ def prefill(params, tokens, length, cfg: LlamaConfig, mesh=None):
             o = flash_attention_on_mesh(qh, kh, v.transpose(0, 2, 1, 3), mesh, cfg.attention_impl, lengths=length)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.num_heads * cfg.hd)
             x = x + jnp.dot(o, layer["wo"])
-        x = _mlp(x, layer, cfg)
+        x = _mlp(x, layer, cfg, live=(length, params["layers"], i) if mesh is None else None)
         # cache stores rope'd keys (decode appends rope'd keys too)
         return x, (kh.transpose(0, 2, 1, 3), v)
 
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn, policy=getattr(jax.checkpoint_policies, cfg.remat_policy))
     with scope("cache"):  # the loop's own work: each layer's keys and values stacked as they leave it
-        x, (ks, vs) = jax.lax.scan(layer_fn, x, params["layers"])
+        x, (ks, vs) = jax.lax.scan(layer_fn, x, (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
 
     with scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)

@@ -73,9 +73,9 @@ logger = logging.getLogger("ray_tpu.llm")
 # what a hybrid description may count of a prefill program from its shape alone
 # (``HybridDescription.prefill_counters``), by the name its sum over an admitting step's programs
 # takes on that step's row; then what the engine counts of any model's from its ``flash_calls``
-# (``ops/flash_attention.query_tiles``)
+# (``ops/flash_attention.query_tiles``) and from the rows' lengths (``ops/layers.live_rows``, a description's ``prefill_rows_live``)
 PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs",
-                    "narrow_pairs", "attn_q_tiles", "attn_q_tiles_live")
+                    "narrow_pairs", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
 DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read", "narrow_rows_read")
 
@@ -421,7 +421,11 @@ class FlightRecorder:
         # pairs of the attention layers with heads narrower than the 128 lanes at the prompts' true lengths
         # (``narrow_pairs``: i + 1 a position and layer); absent for a description that counts none. Last, of any model: the query tiles that the programs' flash calls
         # have by their shape (``attn_q_tiles``: calls x batch rows x tiles of the bucket) and those that start
-        # under a row's true length (``attn_q_tiles_live``): the kernel computes and fetches these alone
+        # under a row's true length (``attn_q_tiles_live``): the kernel computes and fetches these alone;
+        # and the positions that a position-wise sub-block of the programs runs (``prefill_rows_live``: a dense
+        # FFN or a Llama MLP, through ``ops/layers.live_slabs``), whole slabs under each row's true length beside
+        # ``prefill_tokens_padded``, and equal to it where the plain form runs or the description has no such
+        # sub-block (``HybridDescription.prefill_rows_live``)
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)

@@ -57,7 +57,7 @@ from ray_tpu.models.hybrid import ROUTING, HybridDescription, Mixer, forward, in
 from ray_tpu.models.nemotron_h import _anchor_routing  # one orthogonal matrix for all expert layers' routers: the same scoring, the same reason
 from ray_tpu.ops import slot_attention
 from ray_tpu.ops.flash_attention import flash_attention_on_mesh
-from ray_tpu.ops.layers import apply_rope, rms_norm, rotary_embedding
+from ray_tpu.ops.layers import apply_rope, live_slabs, rms_norm, rotary_embedding
 from ray_tpu.util.profiling import scope
 
 # rows (batch x padded length) the dense layer takes at once: its two hidden activations are
@@ -181,7 +181,7 @@ class Glm4MoeLiteConfig(LatentAttention, HybridDescription):
             return y, {ROUTING: counters}
 
         return {"mla": Mixer("mla", attention_seq, attention_step),
-                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt)), {}),
+                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt), ctx.skippable, ctx.stacked), {}),
                              lambda w, xn, cache, ctx: (ffn(w, xn.astype(dt)), None)),
                 "moe": Mixer("moe", experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self, ctx.stacked), True)}
 
@@ -273,15 +273,23 @@ def param_logical_axes(config: Glm4MoeLiteConfig):
 
 
 # -------------------------------------------------------------- ffn: dense SwiGLU
-def ffn(w, x):
-    """``W_down (SiLU(W_gate x) * W_up x)`` on x [.., H], ``FFN_ROWS`` rows at a time."""
-    def some(x):
+def ffn(w, x, lengths=None, stacked=None):
+    """``W_down (SiLU(W_gate x) * W_up x)`` on x [.., H], ``FFN_ROWS`` rows at a time; with
+    ``lengths`` [B] (a serving prefill's x [B,T,H]: ``SeqCtx.skippable``) on the slabs of positions
+    under them and no others, where the bucket has more than one, the matrices read where they
+    lie in ``stacked`` = (the kind's stacked weights, this layer's index) (``ops/layers.live_slabs``)."""
+    def some(x, w):
         return jnp.dot(jax.nn.silu(jnp.dot(x, w["w_gate"])) * jnp.dot(x, w["w_up"]), w["w_down"])
 
-    rows = x.reshape(-1, x.shape[-1])
-    if rows.shape[0] <= FFN_ROWS or rows.shape[0] % FFN_ROWS:
-        return some(x)
-    return jax.lax.map(some, rows.reshape(-1, FFN_ROWS, x.shape[-1])).reshape(x.shape)
+    def rows_at_a_time(x, w):
+        rows = x.reshape(-1, x.shape[-1])
+        if rows.shape[0] <= FFN_ROWS or rows.shape[0] % FFN_ROWS:
+            return some(x, w)
+        return jax.lax.map(lambda x: some(x, w), rows.reshape(-1, FFN_ROWS, x.shape[-1])).reshape(x.shape)
+
+    # without lengths, or where the bucket has nothing to skip, ``rows_at_a_time`` of x whole; of a slab it is ``some``
+    stack = None if lengths is None else ({n: stacked[0][n] for n in ("w_gate", "w_up", "w_down")}, stacked[1])
+    return live_slabs(rows_at_a_time, x, lengths, w, stack)
 
 
 # ------------------------------------------------------- mla: latent attention

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import slot_attention
-from ray_tpu.ops.layers import cross_entropy_loss
+from ray_tpu.ops.layers import cross_entropy_loss, live_rows
 from ray_tpu.util.profiling import scope
 
 # what a routing layer's sequence form reports beside its output, as one more thing it "keeps":
@@ -88,8 +88,9 @@ class SeqCtx(NamedTuple):
 
     @property
     def skippable(self):
-        """``lengths`` for a kernel that skips what lies past them and has no backward pass
-        (``ops/flash_attention``): the serving path's; None where a backward pass may follow."""
+        """``lengths`` for a kernel or a loop that skips what lies past them and has no backward pass
+        (``ops/flash_attention``, ``ops/layers.live_slabs``): the serving path's, which has no mesh
+        either (``hybrid_runner.refuse``); None where a backward pass may follow."""
         return None if self.stacked is None else self.lengths
 
 
@@ -165,6 +166,14 @@ class HybridDescription:
         ``llm/telemetry.PREFILL_COUNTERS``: summed over an admitting step's programs onto that step's
         row of the flight log. None by default."""
         return {}
+
+    def prefill_rows_live(self, length: int, lengths) -> int:
+        """The positions that the position-wise sub-blocks run in ONE prefill program over ``length``
+        padded positions and rows of the true ``lengths`` (a padding row's among them), for an admitting
+        step's ``prefill_rows_live``: whole slabs under each row's length where the description has a
+        dense layer (the kind ``ffn``, which is ``glm4_moe_lite.ffn`` over ``ops/layers.live_slabs`` in
+        every description that has one), every position where it has none."""
+        return live_rows(length, lengths) if "ffn" in self.layer_kinds else len(lengths) * length
 
     def flash_calls(self, length: int) -> dict:
         """{head width: calls} of ``ops/flash_attention`` in ONE prefill program over ``length``
@@ -391,6 +400,7 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
     ``ROUTING`` [routing layers, 3]."""
     c = config
     B, T = tokens.shape
+    assert not collect or mesh is None, "the collecting pass is the serving path's, which takes no mesh (hybrid_runner.refuse)"
     with scope("embed"):
         x = embed_tokens(params, tokens, c)
     empty = {}

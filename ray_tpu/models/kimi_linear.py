@@ -154,7 +154,7 @@ class KimiLinearConfig(LatentAttention, HybridDescription):
 
         return {"kda": Mixer("kda", rule_seq, rule_step),
                 "mla": Mixer("mla", attention_seq, lambda w, xn, cache, ctx: (mla_step(w, xn.astype(dt), cache, ctx, self), None)),
-                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt)), {}),
+                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt), ctx.skippable, ctx.stacked), {}),
                              lambda w, xn, cache, ctx: (ffn(w, xn.astype(dt)), None)),
                 "moe": Mixer("moe", experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self, ctx.stacked), True)}
 

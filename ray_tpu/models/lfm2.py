@@ -149,7 +149,7 @@ class Lfm2Config(HybridDescription):
 
         return {"shortconv": Mixer("shortconv", conv_seq, conv_step),
                 "attn": Mixer("attn", attention_seq, lambda w, xn, cache, ctx: (attn_step(w, xn.astype(dt), cache, ctx, self), None)),
-                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt)), {}), lambda w, xn, cache, ctx: (ffn(w, xn.astype(dt)), None)),
+                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt), ctx.skippable, ctx.stacked), {}), lambda w, xn, cache, ctx: (ffn(w, xn.astype(dt)), None)),
                 "moe": Mixer("moe", experts_seq, lambda w, xn, cache, ctx: experts.moe_step(w, xn, ctx.active, self, ctx.stacked), True)}
 
     def norm(self, x, w):

@@ -177,7 +177,7 @@ class MiniCPMSALAConfig(HybridDescription):
 
         return {"sparse": Mixer("sparse", sparse_seq_, lambda w, xn, cache, ctx: (sparse_step(w, xn.astype(dt), cache, ctx, self), None)),
                 "lightning": Mixer("lightning", lightning_seq_, lightning_step_),
-                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt)), {}),
+                "ffn": Mixer("ffn", lambda w, xn, ctx: (ffn(w, xn.astype(dt), ctx.skippable, ctx.stacked), {}),
                              lambda w, xn, cache, ctx: (ffn(w, xn.astype(dt)), None))}
 
     def norm(self, x, w):

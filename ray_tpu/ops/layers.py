@@ -78,6 +78,62 @@ def swiglu(x, w_gate, w_up, w_down):
     return jnp.dot(jax.nn.silu(g) * u, w_down)
 
 
+# positions of one slab of ``live_slabs``. On a v5e 512 rows keep a product at the whole bucket's rate (2048 x 8192:
+# 89-96 us a slab against 91 for as many rows of the plain form; 256 rows lose a fifth of it) and round a prompt
+# of 2,500 up by 2% where 1,024 round it up by 23%: PERF.md section 6, PR 54, has what 256, 1,024 and 2,048 read
+LIVE_SLAB = 512
+
+
+def live_slabs(fn, x, lengths, w, stacked=None):
+    """``fn(x, w)`` for a ``fn`` that acts on a position alone ([.., H] -> [.., H'], ``w`` its weights),
+    on the rows of x [B,T,H] under their sequence's true length ``lengths`` [B] and no others: slab
+    after slab of ``LIVE_SLAB`` positions, over the slabs that START under their row's length, the
+    slabs of all rows in ONE loop whose length is data. Every other position comes out as zeros. A
+    serving prefill's, where nobody reads a padded position's output: a loop of data length has no
+    backward pass and no reduction over a mesh, so the caller hands ``lengths`` only where neither
+    follows (``SeqCtx.skippable``).
+    ``stacked`` = (arrays stacked over layers, this layer's index): a slab then reads ``w`` where it
+    lies, layer i of each of them; a layer sliced out first would be copied once a layer to become
+    the loop's operand (100 MB at 2048 x 8192 x 3: a fifth of a millisecond, an eighth of that MLP
+    over 2,500 positions).
+    The plain form, ``fn(x, w)``, where there is nothing to skip, by the shape alone: no lengths, a
+    bucket of one slab, or one that is no whole number of them."""
+    S = LIVE_SLAB
+    if lengths is None or x.shape[1] <= S or x.shape[1] % S:
+        return fn(x, w)
+    B, T, H = x.shape
+    slabs_of = (jnp.clip(lengths, 0, T).astype(jnp.int32) + S - 1) // S
+    last_slab = jnp.cumsum(slabs_of)  # one past each row's last slab
+    like = jax.eval_shape(fn, jax.ShapeDtypeStruct((S, H), x.dtype), w)
+
+    def one_slab(j, out):
+        b = jnp.sum(last_slab <= j).astype(jnp.int32)
+        at = (j - (last_slab[b] - slabs_of[b])) * S
+        if stacked is None:
+            mine = w
+        else:
+            # the layer's index behind a barrier with the trip's: what keeps the compiler from hoisting the slices
+            # out of the loop, where each would be a copy of the layer's matrix
+            arrays, i = stacked
+            i, _ = jax.lax.optimization_barrier((i, j))
+            mine = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), arrays)
+        y = fn(jax.lax.dynamic_slice(x, (b, at, 0), (1, S, H))[0], mine)
+        return jax.lax.dynamic_update_slice(out, y[None], (b, at, 0))
+
+    return jax.lax.fori_loop(0, last_slab[-1], one_slab, jnp.zeros((B, T) + like.shape[1:], like.dtype))
+
+
+def live_rows(T: int, lengths) -> int:
+    """Host arithmetic for a step's row of the flight log (``prefill_rows_live``): the positions
+    that ``live_slabs`` runs ``fn`` on in ONE prefill program over ``T`` padded positions and rows
+    of the true ``lengths`` (a padding row's among them); every position where the shape has the
+    plain form run."""
+    S = LIVE_SLAB
+    if T <= S or T % S:
+        return len(lengths) * T
+    return sum(-(-min(int(n), T) // S) * S for n in lengths)
+
+
 def cross_entropy_loss(logits, labels, mask=None, z_loss: float = 0.0):
     """Token cross entropy in f32; labels -100 or mask==0 are ignored."""
     logits = logits.astype(jnp.float32)

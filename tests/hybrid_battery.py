@@ -182,6 +182,27 @@ def test_a_seeded_lane_draws_one_stream_alone_and_beside_others_in_a_recycled_sl
     assert check(desc, params, served([beside], [p], [sp]))["ok"]
 
 
+def test_prompts_that_leave_whole_slabs_empty_are_served_what_the_reference_gives(desc, params, monkeypatch):
+    """PR 54: a serving prefill's dense FFN runs the slabs of positions under a row's true length and writes
+    zeros past them (``ops/layers.live_slabs``). In slabs of 16 three prompts and a padding row in ONE bucket
+    of 64 leave nine of their sixteen slabs empty; the zeros go on through every later layer's mixer, and what
+    is served is still the reference's. The admitting row counts the positions such a sub-block runs: every
+    padded position for a description that has no dense layer, whose programs compute them all."""
+    from ray_tpu.ops import layers
+
+    monkeypatch.setattr(layers, "LIVE_SLAB", 16)
+    lengths = [17, 40, 33]
+    ps = prompts(desc, 54, lengths)
+    sampling = [SamplingParams(max_tokens=4, logprobs=True), SamplingParams(max_tokens=5, temperature=0.9, top_k=12, seed=31, logprobs=True),
+                SamplingParams(max_tokens=4, logprobs=True)]
+    eng = engine(desc.cfg, params, prefill_buckets=(64,))
+    res = check(desc, params, served(eng.generate(ps, sampling), ps, sampling))
+    assert res["ok"] and res["tokens"] == 13 and res["max_abs_dlogprob"] < desc.agrees_to, res
+    (row,) = [r for r in eng.telemetry()["steps"] if r.get("admitted")]
+    slabbed = "ffn" in desc.cfg.layer_kinds
+    assert (row["prefill_tokens"], row["prefill_tokens_padded"], row["prefill_rows_live"]) == (sum(lengths), 4 * 64, 32 + 48 + 48 + 16 if slabbed else 4 * 64)
+
+
 def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(desc, eng):
     """``experts_read`` beside ``experts_hit`` in a step row (``hybrid_runner.MOE_STATS``): means
     over the expert layers of the held experts whose weights the step read and that got a token.

@@ -12,6 +12,7 @@ persistent compile cache is off around the compiles: an executable built
 for a described chip is written to it but cannot be read back here.
 """
 
+import dataclasses
 from functools import partial
 
 import jax
@@ -104,6 +105,29 @@ def test_llama_prefill_learns_the_lengths_only_where_its_bucket_has_a_tile_to_sk
         never = lowered()
     assert "tpu_custom_call" in with_the_rule
     assert (with_the_rule != never) == learns
+
+
+def _loops(txt: str, under: str) -> list:
+    """The compiled ``while`` operations whose name ends under the scope ``under``."""
+    import re
+
+    return [line for line in txt.splitlines() if " while(" in line and re.search(rf'op_name="[^"]*/{under}/while"', line)]
+
+
+def test_llama_prefill_runs_its_mlp_over_live_slabs_and_holds_a_slabs_hidden_rows(one_chip):
+    """PR 54: InternLM2-1.8B's 1 x 4,096 prefill, the shape that decides the long-document cell, compiles
+    for the chip with the loop over live slabs of 512 positions in its MLP (``ops/layers.live_slabs``), and
+    its temporaries are under ONE bucket's hidden activations (4,096 x 8,192 in bfloat16: 64 MiB; the plain
+    form's program took 64.5 MiB, this one 16.9): a slab's are 512 x 8,192, and the loop reads the layer's
+    matrices where they lie in the stack, so no layer's 96 MiB of them is copied to become its operand."""
+    from ray_tpu.llm.model_runner import _sds_params, prefill
+
+    cfg = dataclasses.replace(_internlm2_1_8b(), attention_impl="pallas")
+    args = _on((_sds_params(cfg), jax.ShapeDtypeStruct((1, 4096), jnp.int32), jax.ShapeDtypeStruct((1,), jnp.int32)), one_chip)
+    compiled, txt = _compile(partial(prefill, cfg=cfg), *args)
+    (loop,) = _loops(txt, "mlp")
+    assert "bf16[24,2048,8192]" in loop and "bf16[1,2048,8192]" not in loop and "tpu_custom_call" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 4096 * 8192 * 2
 
 
 def test_flash_bwd_compiles_for_v5e(one_chip):
@@ -834,6 +858,8 @@ def test_sala_prefill_of_the_12288_bucket_fits_beside_weights_and_caches_on_one_
     mem = compiled.memory_analysis()
     print("sala prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
     assert all(name in txt for name in ("sparse.select", "sparse.attend", "lightning.chunk"))
+    (loop,) = _loops(txt, "ffn")  # the dense SwiGLU over the slabs of 512 positions under a true length (PR 54): one loop for all layers and rows,
+    assert "bf16[8,4096,16384]" in loop and "bf16[1,4096,16384]" not in loop  # its matrices read where they lie in the stack
     kernel = [line for line in txt.splitlines() if "custom-call(" in line and "sparse_prefill_attention" in line]
     assert kernel and all("tpu_custom_call" in line and "sparse.attend" in line for line in kernel), "step 5 as one kernel, under its scope"
     assert mem.temp_size_in_bytes < most_gib * 2**30

@@ -263,3 +263,33 @@ def test_prompts_that_leave_whole_query_tiles_empty_are_served_the_plain_referen
     calls = cfg.num_layers
     want = (calls * 4 * 4, calls * (1 + 2 + 3 + 4)) if tile else (calls * 4, calls * 4)
     assert (row["attn_q_tiles"], row["attn_q_tiles_live"]) == want
+
+
+# ------------------------------------------------------------------------------------------------
+# A prefill's MLP runs the slabs of positions under a row's true length (PR 54, ``ops/layers.live_slabs``):
+# what lies past them is zeros, not a SwiGLU of padding, and nothing a client reads moves
+# ------------------------------------------------------------------------------------------------
+@pytest.mark.parametrize("slab", [16, None])
+def test_prompts_that_leave_whole_slabs_empty_are_served_the_plain_references_streams(params, slab, monkeypatch):
+    """Three prompts and a padding row in ONE bucket of 64: in slabs of 16 the group runs seven of its sixteen
+    slabs (one of them the padding row's, whose length is 1), at the chip's 512 the bucket is one slab and
+    the plain form runs; either way every stream is the plain reference's, token for token, and the
+    admitting step's row says how many positions the MLP ran: ``prefill_rows_live``, the host's arithmetic
+    for the group's lengths, and ``prefill_tokens_padded`` where the plain form ran."""
+    from ray_tpu.ops import layers
+
+    if slab is not None:
+        monkeypatch.setattr(layers, "LIVE_SLAB", slab)
+    rng = np.random.default_rng(54)
+    lengths = ONE_BUCKET_LENGTHS[:3]  # three prompts: the program's fourth row is padding, of length 1
+    prompts = [[int(t) for t in rng.integers(1, CFG.vocab_size - 1, size=n)] for n in lengths]
+    sps = [SamplingParams(max_tokens=5), SamplingParams(max_tokens=6, temperature=0.9, top_k=12, seed=31), SamplingParams(max_tokens=5)]
+    eng = LLMEngine(CFG, params=params, max_num_seqs=4, max_seq_len=128, prefill_buckets=(64,), enable_prefix_caching=False)
+    traced = str(jax.make_jaxpr(eng._prefill)(eng.params, np.zeros((4, 64), np.int32), np.ones((4,), np.int32)))
+    assert ("while[" in traced) == (slab is not None)
+    outs = eng.generate(prompts, sps)
+    for o, prompt, sp in zip(outs, prompts, sps):
+        assert (o.token_ids, o.finish_reason) == reference_stream(CFG, params, prompt, sp)
+    (row,) = [s for s in eng.telemetry()["steps"] if s.get("admitted")]
+    assert (row["prefill_tokens"], row["prefill_tokens_padded"]) == (sum(lengths), 4 * 64)
+    assert row["prefill_rows_live"] == layers.live_rows(64, lengths + [1]) == (16 + 32 + 48 + 16 if slab else 4 * 64)
