@@ -50,7 +50,7 @@ import numpy as np
 
 from ray_tpu.llm.kvplane.index import prefix_key, token_bytes
 from ray_tpu.llm.sampling import SamplingParams
-from ray_tpu.llm.telemetry import NO_STAGE, stage
+from ray_tpu.llm.telemetry import LOCK_WAIT, NO_STAGE, stage
 
 logger = logging.getLogger("ray_tpu.llm")
 
@@ -93,6 +93,7 @@ class RequestState:
     # telemetry lifecycle stamps (llm/telemetry.py; host wall clocks only)
     t_ingress: float | None = None  # serving entry (telemetry.INGRESS_T), before parse/encode/admission
     t_submit: float = 0.0
+    lock_wait: float | None = None  # seconds the admitting thread waited for the engine's lock
     t_admit: float = 0.0
     t_first: float = 0.0
     t_last: float = 0.0
@@ -982,7 +983,9 @@ class LLMEngine:
         backdates the telemetry clock to the true ingress arrival when a
         front-end queued the request before admitting it here."""
         params = params or SamplingParams()
+        asked = time.perf_counter()
         with self._lock:
+            held = time.perf_counter()
             if request_id is None:
                 request_id = f"req-{self._auto_id}"
                 self._auto_id += 1
@@ -1002,11 +1005,16 @@ class LLMEngine:
             st = RequestState(request_id, list(prompt_token_ids), params)
             if stream or out_queue is not None:
                 st.out_queue = out_queue if out_queue is not None else queue.SimpleQueue()
-            if self._tel is not None:
-                self._tel.on_submit(st, submitted_at)
-            self._requests[request_id] = st
-            self._waiting.append(st)
-            return request_id
+            return self._enqueue(st, held - asked, submitted_at)
+
+    def _enqueue(self, st: RequestState, lock_wait_s: float | None, submitted_at: float | None, parent_trace: tuple | None = None) -> str:  # holds-lock: _lock
+        """The tail of every admission: the request's submit stamp, with how long its thread waited
+        for the lock that the caller holds, then the registry and the queue. -> its id."""
+        if self._tel is not None:
+            self._tel.on_submit(st, submitted_at, parent_trace=parent_trace, lock_wait_s=lock_wait_s)
+        self._requests[st.request_id] = st
+        self._waiting.append(st)
+        return st.request_id
 
     def prefix_cache_stats(self) -> dict:
         """Prefix-reuse accounting. Flat keys are the LOCAL cache's
@@ -1040,7 +1048,9 @@ class LLMEngine:
         "handoff": its KV block is extracted into a contiguous buffer
         (fused extract program) and stashed for ``pop_handoff``, and the
         slot/pages recycle immediately. It never enters the decode stage."""
+        asked = time.perf_counter()
         with self._lock:
+            held = time.perf_counter()
             if request_id is None:
                 request_id = f"req-{self._auto_id}"
                 self._auto_id += 1
@@ -1056,11 +1066,7 @@ class LLMEngine:
                         f"{self._pcfg.num_pages - 1}; raise num_pages"
                     )
             st = RequestState(request_id, list(prompt_token_ids), SamplingParams(max_tokens=1), prefill_only=True)
-            if self._tel is not None:
-                self._tel.on_submit(st, submitted_at)
-            self._requests[request_id] = st
-            self._waiting.append(st)
-            return request_id
+            return self._enqueue(st, held - asked, submitted_at)
 
     def pop_handoff(self, request_id: str) -> dict | None:
         """Claim a finished prefill-only request's handoff payload
@@ -1118,7 +1124,9 @@ class LLMEngine:
         """Admit a sequence whose prefill ran on another engine; decoding
         starts from the transferred KV without touching the prompt again."""
         params = params or SamplingParams()
+        asked = time.perf_counter()
         with self._lock:
+            held = time.perf_counter()
             if request_id is None:
                 request_id = f"req-{self._auto_id}"
                 self._auto_id += 1
@@ -1131,19 +1139,12 @@ class LLMEngine:
             st = RequestState(request_id, prompt, params, prefilled=kv)
             if stream or out_queue is not None:
                 st.out_queue = out_queue if out_queue is not None else queue.SimpleQueue()
-            if self._tel is not None:
-                # a handoff payload carries the ORIGINAL submit stamp and
-                # trace context, so TTFT spans the whole pipeline and one
-                # trace id stitches prefill and decode replicas
-                tr = kv.get("trace")
-                self._tel.on_submit(
-                    st,
-                    kv.get("submitted_at"),
-                    parent_trace=(tr["trace_id"], tr.get("parent_id")) if isinstance(tr, dict) else None,
-                )
-            self._requests[request_id] = st
-            self._waiting.append(st)
-            return request_id
+            # a handoff payload carries the ORIGINAL submit stamp and
+            # trace context, so TTFT spans the whole pipeline and one
+            # trace id stitches prefill and decode replicas
+            tr = kv.get("trace")
+            return self._enqueue(st, held - asked, kv.get("submitted_at"),
+                                 (tr["trace_id"], tr.get("parent_id")) if isinstance(tr, dict) else None)
 
     def abort_request(self, request_id: str) -> bool:
         with self._lock:
@@ -1171,8 +1172,15 @@ class LLMEngine:
         scheduler shadow state, never a device array (the telemetry
         plane's zero-sync rule applies to the actuator too). Queued
         demand counts each waiting request's prompt + max_tokens: the
-        admission caps bound BACKLOG, not just live occupancy."""
+        admission caps bound BACKLOG, not just live occupancy. A request
+        on its way in waits for the engine's lock HERE first (a step holds
+        it from end to end): the wait goes to its record's ``lock_wait_s``
+        through ``telemetry.LOCK_WAIT``, where the ingress set one."""
+        waited = LOCK_WAIT.get()
+        asked = time.perf_counter()
         with self._lock:
+            if waited is not None:
+                waited[0] += time.perf_counter() - asked
             waiting = len(self._waiting)
             # max_tokens bounds TOTAL generated tokens, so a preempted
             # requeued request's footprint stays prompt + max_tokens
@@ -2212,6 +2220,7 @@ class LLMEngine:
             ks = vs = rows = kept = None
             rows_lens = lens  # on the host: every row's length as the program gets it, a padding row's 1 among them
             toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+            t_before = time.time() if tel is not None else 0.0
             if self._hybrid:
                 # what each layer keeps per position and per sequence, by entry name (hybrid_runner.prefill)
                 logits, rows, kept = self._prefill(self.params, toks, lens)
@@ -2251,8 +2260,9 @@ class LLMEngine:
             if tel is not None:
                 stamps = [t_dispatch, 0.0, 0.0]
                 if tel.prefill_dispatch_t is None:
-                    tel.prefill_dispatch_t = []
+                    tel.prefill_dispatch_t, tel.prefill_dispatch_t0 = [], []
                 tel.prefill_dispatch_t.append(stamps)
+                tel.prefill_dispatch_t0.append(t_before)
             stats = None
             if tel is not None:
                 # the step's row in the flight log: tokens prefilled, true and as padded, what the description
@@ -2465,7 +2475,13 @@ class LLMEngine:
         groups, self._first_tokens = self._first_tokens, []
         if not groups:
             return
-        host = jax.device_get([entry[:3] for entry in groups])  # tpulint: disable=CCR002 — the wave's one first-token readback, behind the dispatch of the step that runs meanwhile
+        waited = [entry[:3] for entry in groups]
+        tel = self._tel
+        if tel is not None:
+            tel.blocked_on = waited
+        host = jax.device_get(waited)  # tpulint: disable=CCR002 — the wave's one first-token readback, behind the dispatch of the step that runs meanwhile
+        if tel is not None:
+            tel.blocked_on = None
         self._first_token_syncs += 1
         now = time.time()
         for (tok, logp, routing), (_, _, _, live, stamps, stats) in zip(host, groups):
@@ -2659,12 +2675,13 @@ class LLMEngine:
                 self._paged_grow()
             prev = self._pending
             self._pending = None
+            t_before = time.time() if tel is not None else 0.0
             if spec:
                 self._dispatch_spec(prev)
             else:
                 self._dispatch_fused(prev)
             if tel is not None and self._pending is not None:
-                tel.dispatch_t = time.time()
+                tel.dispatch_t0, tel.dispatch_t = t_before, time.time()
         with stage(tel, "llm.step.drain_wait"):
             host = self._drain_wait(prev)
         with stage(tel, "llm.step.emit"):
@@ -2764,7 +2781,13 @@ class LLMEngine:
         arrays, in the pending tuple's order, for _drain/_drain_spec."""
         if pending is None:
             return ()
-        return tuple(np.asarray(a) for a in pending[:-1])  # tpulint: disable=CCR002 — sanctioned one-step-delayed drain readback (overlaps next step's compute)
+        tel = self._tel
+        if tel is not None:
+            tel.blocked_on = pending[:-1]
+        host = tuple(np.asarray(a) for a in pending[:-1])  # tpulint: disable=CCR002 — sanctioned one-step-delayed drain readback (overlaps next step's compute)
+        if tel is not None:
+            tel.blocked_on = None
+        return host
 
     def _drain(self, pending, host: tuple | None = None) -> list:
         """Emit the PREVIOUS step's tokens from their read-back arrays

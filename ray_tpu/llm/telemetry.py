@@ -29,7 +29,15 @@ Three pieces:
   ``load_flight()``. Nothing is written while requests are served. Each
   step record carries its STAGES (``stage()``: where the host's time in
   a step went, and the same spans as ``TraceAnnotation``s on the
-  profiler's clock). The recompile sentinel watches each registered
+  profiler's clock) and the stepping thread's CPU time beside its wall
+  time. Two more rings ride the snapshot: ``fetches``, the span of every
+  async remote prefix fetch (the operator's proof that a transfer over
+  the KV plane overlapped live steps: tests/test_llm_kv_tiering.py and
+  README "KV tiering" read it), and ``stalls``, the captures of the
+  STALL SENTINEL (``StallSentinel``: a thread beside a replica's stepper
+  that says, of a step that stands still, whether the device or the host
+  is late, and what every thread of the process was doing), which the
+  flight log keeps as a section of its own. The recompile sentinel watches each registered
   fixed-shape fused entry's jit cache: the serving hot path compiles
   ONCE per entry, so any growth after the first program is a bug
   (a varying static arg, a dtype drifting per step) and gets its own
@@ -53,11 +61,13 @@ import gc
 import json
 import logging
 import os
+import sys
 import threading
 import time
 import uuid
 from collections import deque
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from ray_tpu.util import tracing
@@ -112,6 +122,12 @@ TILED = [name for name in STAGES if name.startswith("llm.step.") and name not in
 # reads it): the request's first stamp, before parse/encode/admission
 INGRESS_T: contextvars.ContextVar = contextvars.ContextVar("rt_llm_ingress_t", default=None)
 
+# seconds the request now on its way in on this thread/task has waited for the ENGINE's lock before its
+# admission call: a one-item list that the serving ingress sets beside INGRESS_T (None elsewhere) and
+# ``LLMEngine.host_load`` (the admission check's read of the queue, under that lock) adds to; on_submit
+# puts it on the request's record together with the admission call's own wait (``lock_wait_s``)
+LOCK_WAIT: contextvars.ContextVar = contextvars.ContextVar("rt_llm_lock_wait", default=None)
+
 NO_STAGE = contextlib.nullcontext()
 
 # seconds the cyclic garbage collector has held this process (a collection stops every thread: the
@@ -131,22 +147,29 @@ class _Stage:
     """One stage, two clocks, one pair of stamps: a TraceAnnotation for
     the profiler (~0.4 us while no profile is active; inside one, a host
     span on the profiler's own clock, next to the device lines) and the
-    duration added to the step's flight record."""
+    duration added to the step's flight record. While it runs, its name
+    and start stand in ``EngineTelemetry.at`` for the sentinel to read; a
+    stage inside another hands the slot back to the outer one."""
 
-    __slots__ = ("_acc", "_ix", "_ann", "_t0")
+    __slots__ = ("_tel", "_name", "_ix", "_ann", "_t0", "_outer")
 
-    def __init__(self, acc: list, name: str):
-        self._acc = acc
+    def __init__(self, tel: "EngineTelemetry", name: str):
+        self._tel = tel
+        self._name = name
         self._ix = _STAGE_IX[name]
         self._ann = TraceAnnotation(name)
 
     def __enter__(self):
         self._ann.__enter__()
         self._t0 = time.perf_counter()
+        tel = self._tel
+        self._outer, tel.at = tel.at, (self._name, self._t0)
         return self
 
     def __exit__(self, *exc):
-        self._acc[self._ix] += (time.perf_counter() - self._t0) * 1e3
+        tel = self._tel
+        tel.at = self._outer
+        tel._stage_ms[self._ix] += (time.perf_counter() - self._t0) * 1e3
         self._ann.__exit__(*exc)
         return False
 
@@ -155,7 +178,7 @@ def stage(tel: "EngineTelemetry | None", name: str):
     """``with stage(tel, "llm.step.prefill"): ...`` — the ONE way a stage
     is stamped (name from STAGES). A no-op context for an engine built
     with telemetry=False. Reads no device value, adds no host callback."""
-    return _Stage(tel._stage_ms, name) if tel is not None else NO_STAGE
+    return _Stage(tel, name) if tel is not None else NO_STAGE
 
 # SLO histogram boundaries (seconds): decode steps are single-digit ms on
 # chip, prefill stalls are tens-to-hundreds of ms, a cold compile is
@@ -429,7 +452,18 @@ class FlightRecorder:
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)
-    ) + tuple(STAGES.values()) + ("gc_ms",)
+    ) + tuple(STAGES.values()) + (
+        "gc_ms",
+        # the stamps (time.time()) taken BEFORE the calls whose return ``dispatch_t`` and a group's first
+        # stamp of ``prefill_dispatch_t`` mark: an execution cannot start before the host began to enqueue
+        # it, whatever the thread lost afterwards (``util/profiling._align``'s bound from above)
+        "dispatch_t0", "prefill_dispatch_t0",
+        # the stepping thread's CPU time over the step (``time.thread_time()`` at its two ends): the host's own
+        # stages (``wall_ms`` less the two blocking reads) against it is how long the stepper was runnable and
+        # not running. With a sentinel beside the stepper (``StallSentinel``): the captures it took during the
+        # step (absent where none) and its worst lateness at a wake-up (ms; absent under one)
+        "cpu_ms", "captures", "tick_late_ms",
+    )
 
     # The flight log's bound: it holds a run whole — 10 minutes at 20
     # steps/s, 2,000 requests (about 8.5 MB of step rows and 11 MB of
@@ -437,6 +471,10 @@ class FlightRecorder:
     # Past it the oldest go, and the log's header says how many.
     LOG_STEPS = 12_000
     LOG_REQUESTS = 2_000
+    # the sentinel's captures (``StallSentinel``), newest kept: those of a stage that had stood a second or
+    # more in one ring, the younger ones (a long prompt's ordinary wait takes them by the hundred) in another
+    # that cannot push them out
+    STALLS = 256
 
     def __init__(self, max_steps: int = 512, max_requests: int = 256):
         self.steps: deque = deque(maxlen=max_steps)
@@ -450,6 +488,8 @@ class FlightRecorder:
         # a fetch record's [t0, t1] against step records' timestamps is
         # the item-3a overlap evidence the bench and tests read
         self.fetches: deque = deque(maxlen=max_requests)
+        self.stalls = (deque(maxlen=self.STALLS // 2), deque(maxlen=self.STALLS // 2))  # (stood under a second, a second or more)
+        self.stall_count = 0
         self._lock = threading.Lock()
         self._entries: dict[str, tuple] = {}  # name -> (fn, warm_size or None)
         self.recompiles: dict[str, int] = {}
@@ -516,15 +556,25 @@ class FlightRecorder:
         with self._lock:
             self.fetches.append(rec)
 
+    def record_stall(self, rec: dict) -> None:
+        """One capture of the sentinel's (its thread's call; ``age_s``: how long the stage had stood)."""
+        with self._lock:
+            self.stall_count += 1
+            self.stalls[1 if rec["age_s"] >= 1.0 else 0].append(rec)
+
+    def _stalls(self) -> list:  # holds-lock: _lock
+        return sorted((dict(r) for ring in self.stalls for r in ring), key=lambda r: r["t"])
+
     def snapshot(self) -> dict:
         with self._lock:
             rows = list(self.steps)
             reqs = [dict(r) for r in self.requests]
             fetches = [dict(r) for r in self.fetches]
+            stalls = self._stalls()
             count = self.step_count
             recs = dict(self.recompiles)
         return {"step_count": count, "steps": [self._step_dict(row) for row in rows], "requests": reqs,
-                "fetches": fetches, "recompiles": recs}
+                "fetches": fetches, "stalls": stalls, "recompiles": recs}
 
     def _step_dict(self, row: tuple) -> dict:
         # drop layout-/mode-inapplicable fields (None) for readability
@@ -532,15 +582,18 @@ class FlightRecorder:
 
     def dump_jsonl(self, path: str, header: dict | None = None) -> str:
         """Write the flight log as JSONL: one header line, then one line
-        per step record, then one per request record. The header counts
-        what the log's bound dropped (``dropped_steps``/``_requests``)."""
+        per step record, then one per request record, then one per capture
+        of the sentinel's (the ``stalls`` section). The header counts
+        what the log's bounds dropped (``dropped_steps``/``_requests``/``_stalls``)."""
         with self._lock:
             rows = list(self.log_steps)
             reqs = [dict(r) for r in self.log_requests]
+            stalls = self._stalls()
             head = {"kind": "flight_header", "ts": time.time(), "pid": os.getpid(),
                     "recompiles": dict(self.recompiles),
                     "steps": len(rows), "dropped_steps": self.step_count - len(rows),
-                    "requests": len(reqs), "dropped_requests": self.request_count - len(reqs)}
+                    "requests": len(reqs), "dropped_requests": self.request_count - len(reqs),
+                    "stalls": len(stalls), "dropped_stalls": self.stall_count - len(stalls)}
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
             f.write(json.dumps({**head, **(header or {})}) + "\n")
@@ -548,6 +601,8 @@ class FlightRecorder:
                 f.write(json.dumps({"kind": "step", **self._step_dict(row)}) + "\n")
             for rec in reqs:
                 f.write(json.dumps({"kind": "request", **rec}) + "\n")
+            for rec in stalls:
+                f.write(json.dumps({"kind": "stall", **rec}) + "\n")
         return path
 
 
@@ -639,8 +694,17 @@ class EngineTelemetry:
         # dispatch_t, None where a step dispatched nothing)
         self._stage_ms = [0.0] * len(STAGES)
         self._step_t0 = 0.0
+        self._step_cpu0 = 0.0  # the stepping thread's CPU clock at the step's start
         self.dispatch_t: float | None = None
+        self.dispatch_t0: float | None = None  # taken before the call whose return dispatch_t marks
         self.prefill_dispatch_t: list | None = None  # the engine appends a group's three stamps
+        self.prefill_dispatch_t0: list | None = None  # and the stamp before the group's prefill call
+        # where the stepper is, for the sentinel (another thread) to read: (name, perf_counter start) of the
+        # stage or step under way, None between two steps; and what a blocking read of the device is
+        # about to wait for (any tree of arrays), None once it has returned. One store each; no lock.
+        self.at: tuple | None = None
+        self.blocked_on = None
+        self.sentinel: "StallSentinel | None" = None  # set by its start(): a bare engine has none
         # per-step ICI wire bytes of the fused step's collectives: a
         # one-shot jaxpr accounting turned into a LIVE series (counter
         # advanced every dispatched step). 0 on tp=1 engines; computed
@@ -723,12 +787,18 @@ class EngineTelemetry:
         return bytes_per_step
 
     # -- request lifecycle ------------------------------------------------
-    def on_submit(self, st, submitted_at: float | None = None, parent_trace: tuple | None = None) -> None:
+    def on_submit(self, st, submitted_at: float | None = None, parent_trace: tuple | None = None,
+                  lock_wait_s: float | None = None) -> None:
         """Stamp admission-queue entry. ``parent_trace`` (trace_id,
         span_id) joins an existing trace — the disagg decode side passes
-        the context the handoff carried so ONE trace id spans replicas."""
+        the context the handoff carried so ONE trace id spans replicas.
+        ``lock_wait_s``: how long the admitting thread waited for the
+        engine's lock (which a step holds from end to end) in this call;
+        what it waited for it on its way here (``LOCK_WAIT``) is added."""
         st.t_submit = float(submitted_at) if submitted_at is not None else time.time()
         st.t_ingress = INGRESS_T.get()
+        earlier = LOCK_WAIT.get()
+        st.lock_wait = lock_wait_s if earlier is None or lock_wait_s is None else lock_wait_s + earlier[0]
         # latched HERE: the prefill stage consumes st.prefilled (sets it
         # None) before the slot binds, so on_bind can't tell a transferred
         # block from a local prefill anymore
@@ -818,6 +888,8 @@ class EngineTelemetry:
             "finish_t": now,
             "ttft_s": (st.t_first - st.t_submit) if st.t_first else None,
             "queue_wait_s": getattr(st, "queue_wait", None),
+            # inside ingress -> submit: the wait for the engine's lock alone
+            "lock_wait_s": st.lock_wait,
             "itl_s": list(st.itls),
             "tokens": len(st.token_ids),
             "prompt_tokens": len(st.prompt_token_ids),
@@ -937,6 +1009,8 @@ class EngineTelemetry:
         ``llm.step`` annotation (parent of the stage spans, carrying the
         step's number) for the step to run under."""
         self._step_t0 = time.time()
+        self._step_cpu0 = time.thread_time()
+        self.at = ("llm.step", time.perf_counter())
         return TraceAnnotation("llm.step", step=self.recorder.step_count + 1)
 
     def on_step(self, t0: float, n_admitted: int, n_emitted: int, spec_drained: tuple | None) -> None:
@@ -945,8 +1019,13 @@ class EngineTelemetry:
         eng = self.engine
         now = time.time()
         wall_ms = (time.perf_counter() - t0) * 1e3
+        cpu_ms = (time.thread_time() - self._step_cpu0) * 1e3
+        self.at = None
         stages, dispatch_t, prefill_t = self._stage_ms, self.dispatch_t, self.prefill_dispatch_t
+        dispatch_t0, prefill_t0 = self.dispatch_t0, self.prefill_dispatch_t0
         self._stage_ms, self.dispatch_t, self.prefill_dispatch_t = [0.0] * len(STAGES), None, None
+        self.dispatch_t0 = self.prefill_dispatch_t0 = None
+        captures, tick_late = self.sentinel.take_step() if self.sentinel is not None else (None, None)
         slots_in_use = sum(1 for s in eng._slots if s is not None)
         sampling_lanes = sum(1 for s in eng._slots if s is not None and s.params.temperature > 0.0)
         waiting = len(eng._waiting)
@@ -999,6 +1078,7 @@ class EngineTelemetry:
             *((eng._lanes_bound_device, eng._first_token_syncs) if n_admitted else (None, None)),
             *moe, *[round(ms, 4) for ms in stages],
             round((_GC_HELD[0] - self._gc_seen) * 1e3, 3) or None,
+            dispatch_t0, prefill_t0, round(cpu_ms, 4), captures, tick_late,
         ))
         self._gc_seen = _GC_HELD[0]
         if self._gap_n:
@@ -1078,16 +1158,232 @@ class EngineTelemetry:
         return snap
 
 
+# ----------------------------------------------------------------------
+# the stall sentinel
+# ----------------------------------------------------------------------
+def _proc_text(path: str) -> str:
+    with open(path) as f:
+        return f.read()
+
+
+def _task_stat(tid: str) -> list:
+    """[tid, comm, state, user + system CPU ticks] of one native thread (``/proc/self/task/<tid>/stat``;
+    the comm stands in brackets and may hold any character, so the fields are counted from its end)."""
+    text = _proc_text(f"/proc/self/task/{tid}/stat")
+    comm = text[text.index("(") + 1:text.rindex(")")]
+    rest = text[text.rindex(")") + 2:].split()  # rest[0] is the state, field 3; utime and stime are fields 14 and 15
+    return [int(tid), comm, rest[0], int(rest[11]) + int(rest[12])]
+
+
+def _python_threads(deep: int | None = None) -> list:
+    """Every Python thread: its name, native id, and innermost three frames (innermost first); twelve of the
+    thread whose ident is ``deep``: the stepper's, where three end inside jax and do not reach the engine's line."""
+    names = {t.ident: (t.name, t.native_id) for t in threading.enumerate()}
+    out = []
+    for ident, frame in sys._current_frames().items():
+        frames, depth = [], 12 if ident == deep else 3
+        while frame is not None and len(frames) < depth:
+            frames.append(f"{os.path.basename(frame.f_code.co_filename)}:{frame.f_lineno} {frame.f_code.co_name}")
+            frame = frame.f_back
+        name, tid = names.get(ident, (str(ident), None))
+        out.append({"name": name, "tid": tid, "frames": frames})
+    return out
+
+
+def _native_threads() -> list:
+    """Every native thread of the process that has ever run: the runtime's transfer and compile threads show
+    here and nowhere in Python. Two captures of one stall apart say which of them burned CPU between."""
+    out = []
+    for tid in os.listdir("/proc/self/task"):
+        try:
+            row = _task_stat(tid)
+        except (OSError, ValueError, IndexError):  # a thread that ended between the listing and the read
+            continue
+        if row[3]:
+            out.append(row)
+    return out
+
+
+def _process_counters() -> dict:
+    """The process's context switches (given up, taken away) and major faults."""
+    import resource
+
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return {"voluntary": ru.ru_nvcsw, "involuntary": ru.ru_nivcsw, "major_faults": ru.ru_majflt}
+
+
+def _thread_sched(tid: int) -> dict:
+    """One native thread's own switches, and the kernel's account of it: ns on a processor and ns runnable
+    and waiting for one (``schedstat``)."""
+    out = {}
+    for line in _proc_text(f"/proc/self/task/{tid}/status").splitlines():
+        key, _, value = line.partition(":")
+        if key in ("voluntary_ctxt_switches", "nonvoluntary_ctxt_switches"):
+            out["voluntary" if key[0] == "v" else "involuntary"] = int(value)
+    run_ns, wait_ns, _ = _proc_text(f"/proc/self/task/{tid}/schedstat").split()
+    return {**out, "run_ns": int(run_ns), "runnable_wait_ns": int(wait_ns)}
+
+
+def _pressure() -> dict:
+    """{"cpu" / "memory" / "io": {"some" / "full": [avg10, total us]}} where the kernel keeps them."""
+    out = {}
+    for kind in ("cpu", "memory", "io"):
+        try:
+            lines = _proc_text(f"/proc/pressure/{kind}").splitlines()
+        except OSError:
+            continue
+        out[kind] = {}
+        for line in lines:
+            share, *pairs = line.split()
+            fields = dict(p.split("=") for p in pairs)
+            out[kind][share] = [float(fields["avg10"]), int(fields["total"])]
+    return out
+
+
+_MEMORY_KEYS = ("bytes_in_use", "peak_bytes_in_use", "largest_free_block_bytes", "num_allocs", "bytes_limit")
+
+
+def _device_memory() -> list:
+    """Each local device's allocator figures, where the backend gives any (the CPU's gives none)."""
+    out = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
+        if stats:
+            out.append({"device": dev.id, **{k: int(stats[k]) for k in _MEMORY_KEYS if k in stats}})
+    return out
+
+
+class StallSentinel:
+    """A thread beside the stepper (``llm-sentinel``) that says why a step stands still. It sleeps ``TICK_S``
+    at a time and notes how late each wake-up was against the sleep it asked for (a process that was frozen,
+    or an interpreter lock that nobody gave up, makes it late as well). Where the stage the stepper published
+    (``EngineTelemetry.at``) is older than ``FIRST_S`` it takes a CAPTURE into the flight recorder's ``stalls``
+    ring, and again each time that age doubles (0.25, 0.5, 1, 2, 4, 8 s ...):
+
+    - ``t`` (time.time()), ``step`` (the step's number), ``stage`` and ``age_s``;
+    - ``ready``: ``is_ready()`` of every array the stepper said it was about to block on (``blocked_on``; no
+      transfer, no block). False: the device or the runtime has not produced the result. True: the host thread
+      is late picking it up. Absent where the stage is not a blocking read;
+    - ``tick_late_ms``: the sentinel's own lateness at its last wakes;
+    - ``threads``: every Python thread's name and innermost three frames (the stepper's twelve); ``native``: every native thread's
+      [tid, comm, state, CPU ticks]; ``stepper``: the stepping thread's own switches and scheduler account;
+    - ``process`` (switches, major faults), ``loadavg``, ``pressure``, ``memory`` (each device's allocator).
+
+    A source that a platform lacks is left out of the capture, never an error. At the capture at ``WARN_S`` of a
+    blocking read it logs ONE warning line: what an operator of a replica would have. A long prompt's ordinary
+    wait is captured like a stall (some milliseconds on a thread that is not the stepper); the reader tells them
+    apart (``benchmark/metrics/stall_excess_ms.py``), the sentinel does not try to. Started by ``LLMServer``
+    beside its stepper; a bare ``LLMEngine`` has none."""
+
+    TICK_S = 0.05
+    FIRST_S = 0.25
+    WARN_S = 2.0
+    IDLE = "llm.stepper.wait"  # the stepper asleep until a request arrives: not a stall
+
+    def __init__(self, tel: EngineTelemetry, stepper: threading.Thread | None = None):
+        self.tel = tel
+        self.stepper = stepper
+        self.captures = 0  # taken since the last step's row (take_step)
+        self.late_ms = 0.0  # worst lateness since then
+        self._lates: deque = deque(maxlen=8)
+        self._next: dict[tuple, float] = {}  # published stage -> the age at which its next capture is due
+        self._step = -1  # the recorder's count as the thresholds were last cleared
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._run, daemon=True, name="llm-sentinel")
+
+    def start(self) -> "StallSentinel":
+        self.tel.sentinel = self
+        self._thread.start()
+        return self
+
+    def stop(self) -> None:
+        self._stop.set()
+        if self._thread.is_alive() and self._thread is not threading.current_thread():
+            self._thread.join(timeout=5.0)
+
+    def is_alive(self) -> bool:
+        return self._thread.is_alive()
+
+    def take_step(self) -> tuple:
+        """on_step's: (captures during the step or None, worst lateness in ms or None under one), then zeroed.
+        Two threads and no lock: a count that lands between the read and the store goes to no row."""
+        captures, late = self.captures, self.late_ms
+        self.captures, self.late_ms = 0, 0.0
+        return captures or None, round(late, 3) if late >= 1.0 else None
+
+    def _run(self) -> None:
+        while True:
+            asked = time.perf_counter()
+            if self._stop.wait(self.TICK_S):
+                return
+            late = (time.perf_counter() - asked - self.TICK_S) * 1e3
+            self._lates.append(round(late, 3))
+            self.late_ms = max(self.late_ms, late)
+            try:
+                self.look()
+            except Exception:  # noqa: BLE001 — an observer never takes its replica down
+                logger.warning("stall sentinel: capture failed", exc_info=True)
+
+    def look(self, now: float | None = None) -> dict | None:
+        """One look at the published stage (at ``now``, perf_counter's): -> the capture taken, or None where none was due."""
+        at, step = self.tel.at, self.tel.recorder.step_count
+        if at is None or step != self._step:  # the thresholds are those of the step under way: the last one's stages are gone
+            self._next.clear()
+            self._step = step
+        if at is None:
+            return None
+        name, since = at
+        age = (time.perf_counter() if now is None else now) - since
+        due = self._next.get(at, self.FIRST_S)
+        if name == self.IDLE or age < due:
+            return None
+        warn = due <= self.WARN_S <= age  # the first capture of this stage at WARN_S or later
+        while due <= age:
+            due *= 2.0
+        self._next[at] = due
+        rec = self.capture(name, age)
+        self.captures += 1
+        self.tel.recorder.record_stall(rec)
+        if warn and "ready" in rec:
+            memory = "".join(f"; device {m['device']} holds {m.get('bytes_in_use')} of {m.get('bytes_limit')} bytes" for m in rec.get("memory", ()))
+            logger.warning(
+                "step %d has stood in %s for %.1f s: result %s; the sentinel's last wakes were late by %.0f ms at most%s; "
+                "the captures are in the flight log's stalls section", rec["step"], name, age,
+                "ready (the host is late picking it up)" if all(rec["ready"]) else "not ready (the device or the runtime is late)",
+                max(self._lates, default=0.0), memory)
+        return rec
+
+    def capture(self, stage: str, age_s: float) -> dict:
+        rec = {"t": time.time(), "step": self.tel.recorder.step_count + 1, "stage": stage, "age_s": round(age_s, 4),
+               "tick_late_ms": list(self._lates)}
+        waited = self.tel.blocked_on
+        if waited is not None:
+            rec["ready"] = [bool(a.is_ready()) for a in jax.tree_util.tree_leaves(waited) if hasattr(a, "is_ready")]
+        sources = {"threads": lambda: _python_threads(self.stepper and self.stepper.ident), "native": _native_threads, "process": _process_counters,
+                   "loadavg": lambda: list(os.getloadavg()), "pressure": _pressure, "memory": _device_memory}
+        if self.stepper is not None and self.stepper.native_id is not None:
+            sources["stepper"] = lambda: _thread_sched(self.stepper.native_id)
+        for key, read in sources.items():
+            try:
+                value = read()
+            except Exception:  # noqa: BLE001 — a platform without /proc, a backend without memory_stats: left out, never an error
+                logger.debug("stall sentinel: no %s here", key, exc_info=True)
+                continue
+            if value:
+                rec[key] = value
+        return rec
+
+
 def load_flight(pid: int | None = None) -> dict:
     """Merge every flight log of the session (each replica process
     writes its own under the shared session dir, like the span files
-    ``tracing.load_spans`` merges): -> {"headers", "steps", "requests"},
-    every step and request carrying the ``pid`` that wrote it. A torn
+    ``tracing.load_spans`` merges): -> {"headers", "steps", "requests", "stalls"},
+    every step, request and capture carrying the ``pid`` that wrote it. A torn
     last line (a process killed while writing) is skipped."""
     from ray_tpu.util.state import session_dir
 
     d = os.path.join(session_dir(pid), "llm_flight")
-    out: dict = {"headers": [], "steps": [], "requests": []}
+    out: dict = {"headers": [], "steps": [], "requests": [], "stalls": []}
     try:
         names = sorted(os.listdir(d))
     except OSError:
@@ -1105,7 +1401,7 @@ def load_flight(pid: int | None = None) -> dict:
                     if kind == "flight_header":
                         writer = rec.get("pid")
                         out["headers"].append(rec)
-                    elif kind in ("step", "request"):
+                    elif kind in ("step", "request", "stall"):
                         rec["pid"] = writer
                         out[kind + "s"].append(rec)
         except OSError:
@@ -1120,6 +1416,17 @@ def dispatch_stamps(steps: list) -> dict:
     steps = sorted(steps, key=lambda s: s["t0"])
     return {"fused": [s["dispatch_t"] for s in steps if s.get("dispatch_t")],
             "prefill": [g[0] for s in steps for g in s.get("prefill_dispatch_t") or ()]}
+
+
+def dispatch_stamps_before(steps: list) -> dict | None:
+    """The same dispatches by the stamps taken BEFORE each call (``dispatch_t0``, ``prefill_dispatch_t0``), one
+    for one with ``dispatch_stamps``; None for a log that has a dispatch without one (written before PR 55)."""
+    steps = sorted(steps, key=lambda s: s["t0"])
+    if any((s.get("dispatch_t") and not s.get("dispatch_t0"))
+           or len(s.get("prefill_dispatch_t") or ()) != len(s.get("prefill_dispatch_t0") or ()) for s in steps):
+        return None
+    return {"fused": [s["dispatch_t0"] for s in steps if s.get("dispatch_t")],
+            "prefill": [t for s in steps for t in s.get("prefill_dispatch_t0") or ()]}
 
 
 def drain_stamps(steps: list) -> list:

@@ -27,7 +27,7 @@ from dataclasses import dataclass, field
 
 from ray_tpu import chaos
 from ray_tpu.exceptions import GetTimeoutError
-from ray_tpu.llm.telemetry import INGRESS_T, stage
+from ray_tpu.llm.telemetry import INGRESS_T, LOCK_WAIT, StallSentinel, stage
 from ray_tpu.serve.overload import (
     AdmissionController,
     OverloadedError,  # noqa: F401 (re-export: the ingress's typed 429)
@@ -152,6 +152,9 @@ class LLMServer:
             self._prewarm()
         self._stepper = threading.Thread(target=self._step_loop, daemon=True, name="llm-stepper")
         self._stepper.start()
+        # beside it, the thread that says why a step stands still (llm/telemetry.StallSentinel)
+        tel = self.engine._tel
+        self._sentinel = StallSentinel(tel, self._stepper).start() if tel is not None else None
 
     def _prewarm(self):
         """Compile the replica's hot programs at construction (smallest
@@ -451,6 +454,8 @@ class LLMServer:
         engine's flight log (llm/telemetry.py: every step and request of
         this replica's life) is written to the session dir, once."""
         self._stop_stepper()
+        if getattr(self, "_sentinel", None) is not None:
+            self._sentinel.stop()
         with self._lock:
             pending = bool(self._events)
         if pending or self.engine.has_unfinished():
@@ -749,10 +754,12 @@ class OpenAIServer(LLMServer):
         # parsed, the prompt encoded and admission checked: the engine's
         # record takes it at on_submit (same thread, same context)
         ingress = INGRESS_T.set(time.time())
+        waited = LOCK_WAIT.set([0.0])  # what the admission check waits for the engine's lock goes onto the request's record too
         try:
             return self._route(request)
         finally:
             INGRESS_T.reset(ingress)
+            LOCK_WAIT.reset(waited)
 
     def _route(self, request):
         path = getattr(request, "path", "/")

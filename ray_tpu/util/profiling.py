@@ -454,45 +454,63 @@ def _shifts(runs: list, anchors: dict) -> dict:
     return {w: stamped[:k].count(chr(65 + i)) for i, w in enumerate(words)}
 
 
-def _align(runs: list, anchors: dict, idle_before: dict, drained: list | None = None) -> dict:
+def _align(runs: list, anchors: dict, idle_before: dict, drained: list | None = None, before: dict | None = None) -> dict:
     """The offset between the device's clock and the host's, from events both sides record.
     ``runs``: chip 0's executions (program name, start_ns, duration_ns) in order; ``anchors``:
     {"fused": [...], "prefill": [...]} host stamps (seconds) of every dispatch of the run, in
-    order; ``idle_before``: execution start -> the ns no program had run before it. An execution
-    that found the device idle started as soon as the host had dispatched it, so its start less
-    its stamp IS the offset, to within a launch; one that found it busy only bounds the offset
-    from above. The offset is the MEDIAN over the launches onto an idle device, not their minimum:
-    a stamp is taken after the dispatching call returns, and a thread that loses the interpreter in
-    between stamps late by milliseconds (one such pair in 750 moved a minimum by 2.5 to 7.5 ms,
-    PERF.md PR 39). Where fewer than three launches found the device idle, the loop is bound by the
-    device and every start lies a queue's length above its stamp; then the other side bounds tightly:
-    ``drained`` (``llm/telemetry.drain_stamps``: for every fused stamp the host's time at which that
-    step's tokens had been read) says an execution had ENDED by then, and the host, blocked on that
-    read, returns from it a transfer after the end. The offset is then the largest ``end - drained``
-    (``clock_bounds_ms``: how far below the tightest bound from above it lies), and without
-    ``drained`` that bound from above.
-    ``clock_residual_ms``: the spread (interquartile range) of those launches about the offset;
-    ``clock_residual_max_ms`` the farthest of them; ``late_stamps`` how many pairs of all lie more
+    order, each taken as its call returned; ``before``: the same dispatches' stamps taken BEFORE the
+    call (``llm/telemetry.dispatch_stamps_before``; None for a log without them); ``idle_before``:
+    execution start -> the ns no program had run before it; ``drained``
+    (``llm/telemetry.drain_stamps``): for every fused stamp the host's time at which that step's
+    tokens had been read.
+
+    The BRACKET (``clock_bracket_ns``, its width ``clock_bounds_ms``), where ``before`` is given: an
+    execution cannot start before the host began to enqueue it, so the least ``start - stamp before``
+    bounds the offset from above whatever a thread lost after its stamp; an execution had ENDED by the
+    time its tokens were on the host, so the largest ``end - drained`` bounds it from below (the host,
+    blocked on that read, returns from it a transfer after the end). Neither can be moved by a late stamp.
+
+    The offset inside it: an execution that found the device idle started as soon as the host had
+    dispatched it, so its start less its stamp IS the offset, to within a launch; the offset is the
+    MEDIAN over those launches, not their minimum: a stamp taken after the dispatching call returns is
+    late by milliseconds where the thread loses the interpreter in between (one such pair in 750 moved a
+    minimum by 2.5 to 7.5 ms, PERF.md PR 39). Where fewer than three launches found the device idle,
+    the loop is bound by the device and every start lies a queue's length above its stamp: the offset
+    is then the bound from below (without ``drained``, the one from above). A log without ``before``
+    is read as it was before PR 55: the least ``start - stamp`` stands in for the bound from above, and
+    the bound from below is taken (and ``clock_bounds_ms`` given) in the second regime alone.
+    ``clock_residual_ms``: the spread (interquartile range) of the launches onto an idle device about the
+    offset; ``clock_residual_max_ms`` the farthest of them; ``late_stamps`` how many pairs of all lie more
     than a millisecond BELOW the offset (a start before its stamp: the stamp was late) and
     ``latest_stamp_ms`` by how much at most."""
-    pairs, shifts = [], _shifts(runs, anchors)
+    pairs, early, shifts = [], [], _shifts(runs, before or anchors)  # a late stamp can stand behind the next family's: the order is the earlier stamps'
     for word, shift in shifts.items():
         starts = [s for name, s, _ in runs if word in name]
         if shift is not None:
             pairs += [(s - anchors[word][shift + i] * 1e9, s) for i, s in enumerate(starts)]
+            if before:
+                early += [s - before[word][shift + i] * 1e9 for i, s in enumerate(starts)]
     if not pairs:
         return {}
     waited = sorted(d for d, s in pairs if idle_before.get(s, 0) >= 100_000)
     lowest = min(d for d, _ in pairs)
-    offset, bounds_ms = statistics.median(waited) if len(waited) >= 3 else lowest, None
-    if len(waited) < 3 and drained and shifts.get("fused") is not None:
+    above, below = min(early) if early else lowest, None
+    if (early or len(waited) < 3) and drained and shifts.get("fused") is not None:
         fused = [(s, d) for name, s, d in runs if "fused" in name]
-        below = [s + d - drained[k] * 1e9 for k, (s, d) in enumerate(fused, shifts["fused"]) if k < len(drained) and drained[k]]
-        if below:
-            offset, bounds_ms = min(max(below), lowest), (lowest - max(below)) * 1e-6
-    out = {"offset_ns": offset, "clock_bounds_ms": bounds_ms, "anchors": len(pairs), "anchors_on_an_idle_device": len(waited),
+        ends = [s + d - drained[k] * 1e9 for k, (s, d) in enumerate(fused, shifts["fused"]) if k < len(drained) and drained[k]]
+        below = max(ends) if ends else None
+    if len(waited) >= 3:
+        offset = statistics.median(waited)
+        if early:
+            offset = min(offset if below is None else max(offset, below), above)
+    else:
+        offset = above if below is None else min(below, above)
+    out = {"offset_ns": offset, "clock_bounds_ms": (above - below) * 1e-6 if below is not None else None,
+           "anchors": len(pairs), "anchors_on_an_idle_device": len(waited),
            "clock_residual_ms": None, "clock_residual_max_ms": None,
            "late_stamps": sum(1 for d, _ in pairs if d < offset - 1e6), "latest_stamp_ms": max(offset - lowest, 0.0) * 1e-6}
+    if early:
+        out["clock_bracket_ns"] = [below, above]
     if waited:
         q = statistics.quantiles(waited, n=4) if len(waited) >= 4 else (waited[0], offset, waited[-1])
         out["clock_residual_ms"] = (q[2] - q[0]) * 1e-6
@@ -553,14 +571,31 @@ def _idle_by_stage(gaps: list, spans: list, offset_ns: float) -> dict:
     return idle
 
 
-def summarize(logdir_or_xplane: str, flight: list | None = None, stretch_s: float | None = None) -> dict:
+def _captures_on_the_device(stalls: list, runs: list, offset_ns: float, t_lo: int, t_hi: int) -> list:
+    """The sentinel's captures that fall inside the traced stretch, each with what the device was doing at
+    its instant: the program it was running (chip 0's), or ``idle``."""
+    runs = sorted(runs, key=lambda r: r[1])
+    starts = [r[1] for r in runs]
+    out = []
+    for cap in sorted(stalls, key=lambda c: c["t"]):
+        at = cap["t"] * 1e9 + offset_ns
+        if not t_lo <= at <= t_hi:
+            continue
+        i = bisect.bisect_right(starts, at) - 1
+        running = i >= 0 and at < runs[i][1] + runs[i][2]
+        out.append({**{k: cap.get(k) for k in ("t", "step", "stage", "age_s", "ready")}, "device": runs[i][0] if running else "idle"})
+    return out
+
+
+def summarize(logdir_or_xplane: str, flight: list | None = None, stretch_s: float | None = None, stalls: list | None = None) -> dict:
     """A traced stretch by the program's own names.
 
     -> {"window_s", "busy_s", "chips",
         "programs": {name: {"calls", "device_s", "leaf_s", "scopes": {scope: {"s", "calls", "flops", "bytes"}},
                             "ops": {instruction: {"s", "calls", "path", "scope", "flops", "bytes" (the last two a call)}}}},
         "roles": {name: {role: seconds}},
-        "clock": {...}, "idle": {stage: {"s", "gaps"}}}  (the last two with ``flight``)
+        "clock": {...}, "idle": {stage: {"s", "gaps"}},  (the last two with ``flight``)
+        "captures": [{"t", "step", "stage", "age_s", "ready", "device"}]}  (with ``flight`` and ``stalls``)
 
     ``programs`` is chip 0's (every chip of a mesh runs the same programs), by ``program_id`` and
     named as ``program_name`` names it, prefill buckets together: executions, their device
@@ -573,7 +608,9 @@ def summarize(logdir_or_xplane: str, flight: list | None = None, stretch_s: floa
     ``flight``: the step rows of the flight log (``llm/telemetry.load_flight()["steps"]``, one
     replica's). The two clocks are set against each other by what both record (``_align``), and
     every idle gap of the device goes, whole or cut at the boundaries, to the stage of the step
-    that holds it (``llm/telemetry.timeline``)."""
+    that holds it (``llm/telemetry.timeline``). ``stalls``: the log's captures
+    (``load_flight()["stalls"]``): each one inside the stretch is set on the device's clock by the same
+    offset and told what the device was running at that instant, or that it was idle."""
     path = find_xplane(logdir_or_xplane)
     if path is None:
         return {}
@@ -649,14 +686,14 @@ def summarize(logdir_or_xplane: str, flight: list | None = None, stretch_s: floa
     if flight is None:
         return out
 
-    from ray_tpu.llm.telemetry import dispatch_stamps, drain_stamps, timeline
+    from ray_tpu.llm.telemetry import dispatch_stamps, dispatch_stamps_before, drain_stamps, timeline
 
     idle_before, free_at = {}, None  # an execution's start -> how long no program had run before it
     for _, start, dur in sorted(runs, key=lambda r: r[1]):
         if free_at is not None:
             idle_before[start] = start - free_at
         free_at = max(free_at or 0, start + dur)
-    clock = _align(runs, dispatch_stamps(flight), idle_before, drain_stamps(flight))
+    clock = _align(runs, dispatch_stamps(flight), idle_before, drain_stamps(flight), dispatch_stamps_before(flight))
     ann = _annotation_offset(planes, flight)
     if ann is not None:
         clock["annotation_offset_ns"] = ann
@@ -667,6 +704,8 @@ def summarize(logdir_or_xplane: str, flight: list | None = None, stretch_s: floa
     else:
         idle = {"unattributed": {"s": sum(b - a for a, b in gaps) * 1e-9, "gaps": len(gaps)}}
     out["idle"] = idle
+    if stalls and "offset_ns" in clock:
+        out["captures"] = _captures_on_the_device(stalls, runs, clock["offset_ns"], t_lo, t_hi)
     return out
 
 
@@ -686,9 +725,14 @@ def tables(summary: dict, programs: tuple = ("prefill", "fused")) -> list[str]:
         total = summary["window_s"] - summary["busy_s"]
         lines.append(f"idle: {total:.4f} s of a {summary['window_s']:.4f} s window; clock from {clock.get('anchors', 0)} dispatches, "
                      f"residual {clock.get('clock_residual_ms')} ms (at most {clock.get('clock_residual_max_ms')}), {clock.get('late_stamps')} late stamps (by {clock.get('latest_stamp_ms')} ms at most)"
-                     + (f"; from the reads of a loop the device bounds, {clock['clock_bounds_ms']:.3f} ms under the bound from above" if clock.get("clock_bounds_ms") is not None else ""))
+                     + (f"; offset {(clock['offset_ns'] - clock['clock_bracket_ns'][0]) * 1e-6:.3f} ms above the drained reads' bound in a bracket of "
+                        f"{clock['clock_bounds_ms']:.3f} ms under the stamps before each dispatch" if clock.get("clock_bracket_ns", [None])[0] is not None
+                        else f"; from the reads of a loop the device bounds, {clock['clock_bounds_ms']:.3f} ms under the bound from above" if clock.get("clock_bounds_ms") is not None else ""))
         for label, piece in sorted(summary["idle"].items(), key=lambda kv: -kv[1]["s"]):
             lines.append(f"  {label:<22} {piece['s']:9.4f} s  {piece['gaps']:>6} gaps")
+        for cap in summary.get("captures", ()):
+            ready = "" if cap["ready"] is None else ", result ready" if all(cap["ready"]) else ", result not ready"
+            lines.append(f"  capture: step {cap['step']} {cap['age_s']:.2f} s into {cap['stage']}{ready}: device {cap['device']}")
     return lines
 
 
@@ -699,12 +743,13 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("logdir", help="a profiler's log directory, or an .xplane.pb file")
     ap.add_argument("--session", type=int, default=None, help="the driver's pid, whose session holds the replica's flight log; without it, no idle table")
     a = ap.parse_args(argv)
-    flight = None
+    flight = stalls = None
     if a.session is not None:
         from ray_tpu.llm.telemetry import load_flight
 
-        flight = load_flight(a.session)["steps"]
-    summary = summarize(a.logdir, flight)
+        log = load_flight(a.session)
+        flight, stalls = log["steps"], log["stalls"]
+    summary = summarize(a.logdir, flight, stalls=stalls)
     if not summary:
         print(f"no trace under {a.logdir}")
         return 1

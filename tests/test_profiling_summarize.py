@@ -331,6 +331,78 @@ def test_a_loop_bound_by_the_device_is_aligned_by_the_order_of_its_programs():
     drained = [next(e for s0, e in sorted(ends.items()) if s0 >= t) + 0.2e-3 for t in stamps["fused"]]
     clock = profiling._align(traced, stamps, {}, drained)
     assert OFFSET_NS - 0.3e6 <= clock["offset_ns"] <= OFFSET_NS and clock["clock_bounds_ms"] > 10.0
+    # PR 55: with a stamp before each call the same reads give the same offset, inside a bracket neither side of which a late stamp can move
+    before = {word: [t - 0.3e-3 for t in ts] for word, ts in stamps.items()}
+    bracket = profiling._align(traced, stamps, {}, drained, before)
+    assert bracket["offset_ns"] == clock["offset_ns"] == bracket["clock_bracket_ns"][0]
+    assert bracket["clock_bracket_ns"][1] > OFFSET_NS + 10e6 and bracket["clock_bounds_ms"] == pytest.approx(clock["clock_bounds_ms"] + 0.3, abs=1e-3)
+
+
+def _with_stamps_before(rows: list, late_every: int = 0) -> list:
+    """The rows as a replica of PR 55 writes them: a stamp before each dispatching call (the simulated host works 0.4 ms
+    through ``dispatch`` before it enqueues the step, 0.2 ms before a group's prefill); and every ``late_every``-th stamp
+    AFTER a call planted 5 ms late, as a thread that lost the interpreter on its way to the stamp takes it."""
+    out, n = [], 0
+    for r in rows:
+        r = dict(r)
+        if r.get("dispatch_t"):
+            n += 1
+            r["dispatch_t0"] = r["dispatch_t"] - 0.4e-3
+            r["dispatch_t"] += 5e-3 if late_every and n % late_every == 0 else 0.0
+        if r.get("prefill_dispatch_t"):
+            r["prefill_dispatch_t0"] = [g[0] - 0.2e-3 for g in r["prefill_dispatch_t"]]
+            groups = []
+            for g in r["prefill_dispatch_t"]:
+                n += 1
+                groups.append([g[0] + (5e-3 if late_every and n % late_every == 0 else 0.0), g[1], g[2]])
+            r["prefill_dispatch_t"] = groups
+        out.append(r)
+    return out
+
+
+@pytest.mark.parametrize("late_every", [0, 3])
+def test_the_stamps_before_each_dispatch_bracket_the_offset_whatever_the_later_stamps_lost(synthetic, late_every):
+    rows = _with_stamps_before(synthetic["rows"], late_every)
+    clock = summarize(synthetic["plain"], flight=rows)["clock"]
+    below, above = clock["clock_bracket_ns"]
+    assert below <= OFFSET_NS <= above and below <= clock["offset_ns"] <= above
+    # from above by a launch and the host's 0.2-0.4 ms between its stamp and the enqueue; from below by the 50 us a read returns after the step's end
+    assert clock["clock_bounds_ms"] == pytest.approx((above - below) * 1e-6) and 0.0 < clock["clock_bounds_ms"] < 0.6
+    assert clock["offset_ns"] == pytest.approx(OFFSET_NS, abs=0.1e6)
+    if late_every:
+        assert clock["late_stamps"] >= 5 and 4.0 < clock["latest_stamp_ms"] <= 5.1  # counted against an offset they did not move
+    else:
+        assert clock["late_stamps"] == 0
+    text = "\n".join(profiling.tables(summarize(synthetic["plain"], flight=rows)))
+    assert "in a bracket of" in text and "under the stamps before each dispatch" in text
+
+
+def test_a_log_without_the_stamps_before_is_read_as_it_was(synthetic):
+    """A flight log written by the parent: no bracket, the median of the launches onto an idle device, and a bound from the
+    reads only where the device bounds the loop (the three tests above hold the numbers)."""
+    clock = summarize(synthetic["plain"], flight=synthetic["rows"])["clock"]
+    assert "clock_bracket_ns" not in clock and clock["clock_bounds_ms"] is None
+    assert telemetry.dispatch_stamps_before(synthetic["rows"]) is None
+    some = _with_stamps_before(synthetic["rows"])
+    del some[30]["dispatch_t0"]  # a log in which ONE dispatch lacks its stamp is such a log too
+    assert summarize(synthetic["plain"], flight=some)["clock"] == clock
+    assert "in a bracket of" not in "\n".join(profiling.tables(summarize(synthetic["plain"], flight=synthetic["rows"])))
+
+
+def test_a_capture_is_told_what_the_device_was_running_at_its_instant(synthetic):
+    """The sentinel's captures carry the host's time; the offset sets each on the device's clock, inside an execution or between two."""
+    runs = synthetic["runs"]
+    inside = next(r for r in runs if r[0] == PREFILL and r[2] > 15e-3)
+    gap = next((a[1] + a[2], b[1]) for a, b in zip(runs, runs[1:]) if b[1] - (a[1] + a[2]) > 1e-3)
+    stalls = [{"t": inside[1] + 10e-3, "step": 15, "stage": "llm.step.prefill.first_tokens", "age_s": 0.26, "ready": [False, False]},
+              {"t": (gap[0] + gap[1]) / 2, "step": 16, "stage": "llm.step.emit", "age_s": 0.3},
+              {"t": runs[0][1] - 5.0, "step": 1, "stage": "llm.step.drain_wait", "age_s": 0.5, "ready": [True]}]  # before the traced stretch: left out
+    s = summarize(synthetic["plain"], flight=_with_stamps_before(synthetic["rows"]), stalls=stalls)
+    assert [(c["step"], c["device"]) for c in s["captures"]] == sorted([(15, "jit_llm_prefill"), (16, "idle")], key=lambda c: stalls[c[0] - 15]["t"])
+    text = "\n".join(profiling.tables(s))
+    assert "capture: step 15 0.26 s into llm.step.prefill.first_tokens, result not ready: device jit_llm_prefill" in text
+    assert "capture: step 16 0.30 s into llm.step.emit: device idle" in text
+    assert "captures" not in summarize(synthetic["plain"], flight=synthetic["rows"])
 
 
 def test_the_annotations_alone_give_the_same_offset(synthetic):
