@@ -2500,7 +2500,8 @@ class LLMEngine:
         and its routing counters, on the host, onto the sums that the step's row takes."""
         if stats is None:
             return
-        routing = np.zeros((3,), np.float32) if routing is None else routing
+        # hybrid_runner.PREFILL_STATS: the fourth comes only from a program whose blocks the kernel runs
+        routing = np.zeros((4,), np.float32) if routing is None else np.pad(routing, (0, 4 - len(routing)))
         seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
         self._prefill_stats = (seen[0] + stats[0], seen[1] + stats[1], seen[2] + 1, seen[3] + routing,
                                {k: seen[4].get(k, 0) + v for k, v in stats[2].items()})

@@ -45,8 +45,8 @@ from ray_tpu.util.profiling import scope, scoped
 
 # one step's expert-routing counters, in the order the fused step returns them
 MOE_STATS = ("experts_hit", "moe_pairs_local", "moe_pairs_total", "moe_max_load", "experts_read")
-# an admission's, in the order the prefill returns them (means over the routing layers)
-PREFILL_STATS = ("experts_hit", "moe_pairs_local", "moe_rows_computed")
+# an admission's, in the order the prefill returns them (means over the routing layers; the last only from a program whose blocks the kernel runs)
+PREFILL_STATS = ("experts_hit", "moe_pairs_local", "moe_rows_computed", "moe_rows_kernel")
 ROUTING = hybrid.ROUTING
 
 
@@ -95,7 +95,7 @@ def prefill(params, tokens, length, cfg, mesh=None):
     """tokens [B, T_pad] right-padded, length [B] -> (last-token logits [B, vocab] f32,
     rows {name: [layers that keep it, B, T_pad, *shape]}: the per-position entries (``k`` and ``v``
     [La, B, T_pad, kv, hd] of an attention layer with heads), state {name: [Lm, B, ...]} at each
-    prompt's true length, with PREFILL_STATS as float32 [3] beside the state under ``ROUTING``
+    prompt's true length, with PREFILL_STATS as float32 [3] or [4] beside the state under ``ROUTING``
     where the model routes)."""
     x, out = hybrid.forward_hidden(params, tokens, length, cfg, mesh, collect=True)
     with scope("head"):

@@ -432,9 +432,10 @@ class FlightRecorder:
         # an ADMITTING step, of that step's prefills: tokens taken in, true and as padded to bucket
         # and batch, then llm/hybrid_runner.PREFILL_STATS (zeros for a model without routed experts),
         # each a mean over the routing layers: held experts that got a pair (mean over the step's prefill programs),
-        # (token, expert) pairs served here and rows of the grouped matmul's blocks in use (sums
+        # (token, expert) pairs served here, rows of the grouped matmul's blocks in use and rows of the
+        # blocks that the kernel ran (``ops/grouped_experts.py``; 0 where the loop ran them) (sums
         # over them). Under names of their own: the four above stay the drained DECODE step's
-        "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed",
+        "prefill_tokens", "prefill_tokens_padded", "prefill_experts_hit", "prefill_moe_pairs_local", "moe_rows_computed", "moe_rows_kernel",
         # and what the description counts of those prefill programs from their shapes alone
         # (``HybridDescription.prefill_counters``): chunks of the delta rule that the programs ran, padding's
         # among them, over the layers of Kimi Delta Attention (``kda_``) or of Gated DeltaNet (``gdn_``), and how
@@ -610,7 +611,7 @@ class FlightRecorder:
 # engine-facing facade
 # ----------------------------------------------------------------------
 _NO_MOE = (None,) * 5  # a step row's routing counters for a model without routed experts
-_NO_PREFILL = (None,) * (5 + len(PREFILL_COUNTERS))  # and its prefill counters where the step admitted nothing through a hybrid's prefill
+_NO_PREFILL = (None,) * (6 + len(PREFILL_COUNTERS))  # and its prefill counters where the step admitted nothing through a hybrid's prefill
 
 
 class EngineTelemetry:
@@ -1064,8 +1065,8 @@ class EngineTelemetry:
         if pf is None:
             moe += _NO_PREFILL
         else:
-            tokens, padded, programs, (hit, pairs, rows), counted = pf
-            moe += (tokens, padded, round(float(hit) / programs, 3), round(float(pairs), 3), round(float(rows), 3),
+            tokens, padded, programs, (hit, pairs, rows, kernel), counted = pf
+            moe += (tokens, padded, round(float(hit) / programs, 3), round(float(pairs), 3), round(float(rows), 3), round(float(kernel), 3),
                     *(counted.get(name) for name in PREFILL_COUNTERS))
         self.recorder.record_step((
             now, phase, round(wall_ms, 4), n_admitted, n_emitted, slots_in_use, sampling_lanes, waiting,

@@ -16,10 +16,19 @@ no router. Everything else is one code path:
 - ``experts_dense``: every held expert over every row: the form that has a
   backward pass (training, the plain forward), and what the tests hold the two
   others to. Its cost is reading every held expert's weights.
-- ``experts_grouped``: a grouped matmul in plain XLA over the (row, expert)
-  pairs routed HERE, for prefill: what it places, gathers and multiplies follows
-  the pairs held here, not the pairs the router made; no pair is dropped
-  whatever one expert's load.
+- ``experts_grouped``: a grouped matmul over the (row, expert) pairs routed
+  HERE, for prefill: what it places, gathers and multiplies follows the pairs
+  held here, not the pairs the router made; no pair is dropped whatever one
+  expert's load. How its blocks run follows two things the call's shapes say
+  (``blocks_plan``). The rows an expert is EXPECTED to get, ``N k / num_experts``:
+  tall blocks of 256 rows where the call has ``TALL_FROM`` pairs and an expert
+  expects 256 rows or more, blocks of 128 where it expects fewer. And the
+  expert's SIZE: full blocks run at the MXU's pace in the plain XLA loop; where
+  an expert expects less than two blocks' rows a block's time is its expert's
+  weights coming in, the loop adds a fixed cost a product to that, and where
+  the expert is small enough for that cost to be the larger part (16 MiB) one
+  kernel runs the blocks on a TPU (``ops/grouped_experts.py``: the next block's
+  weights are fetched under this block's products); the loop everywhere else.
 - ``experts_step``: a decode step's form: the held experts that a BOUND lane
   chose, one after another, every lane against one expert's matrices read
   straight out of the stacked weights (a kernel on a TPU, ``ops/step_experts.py``;
@@ -56,10 +65,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import step_experts
+from ray_tpu.ops import grouped_experts, step_experts
 from ray_tpu.util.profiling import scope, scoped
 
-# rows of one block of the grouped matmul; pairs beyond this many take blocks twice as tall
+# rows of one block of the grouped matmul. A call takes blocks twice as tall where it has ``TALL_FROM`` pairs or more
+# AND an expert is expected to get a tall block's rows of them (``blocks_plan``): the pairs spread over every PUBLISHED
+# expert, so a chip that holds a quarter of them fills a tall block a quarter (Qwen3-Next's 4,096 rows x 10 choices
+# over 512 experts are 80 rows an expert; a 256-row block took 7.5 us a product where a 128-row block takes 4.9, and
+# the layer alone 4.89 -> 4.08 ms at 4,096 rows, Kimi's 3.95 -> 3.58: PERF.md section 6, PR 56). The rule only takes
+# tall blocks away: a call that was short stays short
 BLOCK, TALL_FROM = 128, 32768
 # the grouped matmul's buffers grow with the rows handed to it (a row of the residual width for
 # every pair that COULD be held here): beyond this many rows a sequence batch goes through in slabs
@@ -164,13 +178,28 @@ def _count_before(flags):
     return before.reshape(-1)[:n].astype(jnp.int32), jnp.sum(totals).astype(jnp.int32)
 
 
+def blocks_plan(s: ExpertLayer, N: int, mats) -> tuple:
+    """How a call of ``_grouped`` over N rows runs its blocks, from its shapes alone: (rows of a block,
+    whether the kernel runs them). An expert is expected to get ``N k / num_experts`` rows (the router's
+    width: with expert parallelism the pairs split over all published experts, and each expert held
+    here expects that many). Tall blocks only where the call has ``TALL_FROM`` pairs AND an expert
+    expects a tall block's rows; the kernel (``ops/grouped_experts.py``) where an expert expects less
+    than that and is small enough for the loop's fixed costs to be the larger part of a block's time
+    (its ``refusal`` reads both, and the dtype and the tiles); full blocks and large experts keep the loop."""
+    M = N * s.top_k
+    block = 2 * BLOCK if M >= max(TALL_FROM, 2 * BLOCK * s.num_experts) else BLOCK
+    F, H = mats[0].shape[2:]
+    return block, grouped_experts.refusal(mats[0].dtype, H, F, len(mats), M // s.num_experts, BLOCK) is None
+
+
 def _grouped(stacked, layer, x, idx, wt, valid, c):
     """``experts_grouped`` and what it did: -> (out [N,H], pairs at each held expert [El] int32,
     rows of the blocks in use, int32)."""
     s = c.expert_layer
     N, k = idx.shape
     M, El, H = N * k, s.held, x.shape[-1]
-    block = 2 * BLOCK if M >= TALL_FROM else BLOCK
+    mats = [stacked[n] for n in s.matrices]
+    block, kernel = blocks_plan(s, N, mats)
     slabs, tiles = -(-M // SLAB), -(-N // TILE)
     n_pairs = slabs * SLAB + ROWS  # every pair could be held here; a trip may hang over the end
     n_rows = -(-(M + El * ALIGN) // SLAB) * SLAB + block  # and every run start on a whole tile of the layout; a slab or a block may hang over
@@ -216,9 +245,17 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
         pair = jax.lax.dynamic_slice_in_dim(pair_at, j * SLAB, SLAB)
         return jax.lax.dynamic_update_slice_in_dim(rows, jnp.take(x, jnp.minimum(pair, M - 1) // k, axis=0), block + j * SLAB, 0)
 
+    def fill_slab_and_weights(j, filled):
+        # for the kernel: each row's weight beside it, in every lane of a row of its own (a block's come in by one DMA)
+        weight = scale[jnp.minimum(jax.lax.dynamic_slice_in_dim(pair_at, j * SLAB, SLAB), M - 1)]
+        return fill_slab(j, filled[0]), jax.lax.dynamic_update_slice_in_dim(filled[1], jnp.broadcast_to(weight[:, None], (SLAB, grouped_experts.LANES)), j * SLAB, 0)
+
     with scope("moe.place"), scope("moe.place.into"):  # the held pairs' rows of x, gathered into the layout a slab at a time
-        rows = jax.lax.fori_loop(0, ((start[-1] + tiles_of[-1]) * ALIGN + SLAB - 1) // SLAB, fill_slab, jnp.zeros((block + n_rows, H), x.dtype))
-    mats = [stacked[n] for n in s.matrices]
+        slabs_in_use, rows = ((start[-1] + tiles_of[-1]) * ALIGN + SLAB - 1) // SLAB, jnp.zeros((block + n_rows, H), x.dtype)
+        if kernel:
+            rows, row_scale = jax.lax.fori_loop(0, slabs_in_use, fill_slab_and_weights, (rows, jnp.zeros((n_rows, grouped_experts.LANES), jnp.float32)))
+        else:
+            rows = jax.lax.fori_loop(0, slabs_in_use, fill_slab, rows)
 
     def one_block(b, rows):
         # ONE array holds a row's input ``block`` rows behind where its output goes, and the blocks
@@ -235,7 +272,15 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
         return jax.lax.dynamic_update_slice_in_dim(rows, yb, at, 0)
 
     with scope("moe.blocks"):
-        rows = jax.lax.fori_loop(0, last_block[-1], one_block, rows)
+        if kernel:
+            # every block's expert and offset at once (``one_block``'s own lines): the kernel's grid has a step for
+            # every block the call COULD need; off the TPU only a test gets here (it swaps ``refusal``), and runs the same body interpreted
+            b = jnp.arange(-(-M // block) + El, dtype=i32)
+            e = jnp.minimum(jnp.sum(last_block[None, :] <= b[:, None], axis=1).astype(i32), El - 1)
+            at = start[e] + (b - (last_block[e] - blocks_of[e])) * (block // ALIGN)
+            rows = grouped_experts.blocks(mats, layer, rows, row_scale, e, at, last_block[-1], s.act, block, ALIGN, interpret=jax.default_backend() != "tpu")
+        else:
+            rows = jax.lax.fori_loop(0, last_block[-1], one_block, rows)
 
     def sum_rows(t, out):
         # ``ROWS`` of a tile's pairs: their rows gathered, and added to their tokens by a 0/1
@@ -255,7 +300,7 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
 
 
 def experts_grouped(stacked, layer, x, idx, wt, valid, c):
-    """A grouped matmul in plain XLA over the (row, expert) pairs routed HERE, whose number is
+    """A grouped matmul over the (row, expert) pairs routed HERE, whose number is
     data: every loop below turns as often as those pairs need, none as often as the router's
     N x k. The held pairs are compacted in token order by a running count (no sort), ``SLAB`` of
     them at a time get their rows (an expert's first row plus the pair's rank among that expert's
@@ -263,7 +308,9 @@ def experts_grouped(stacked, layer, x, idx, wt, valid, c):
     padding between the runs but to a multiple of ``ALIGN`` rows. The pairs' inputs are gathered
     into it a slab at a time; one loop over the blocks IN USE takes ``block`` rows at its run's
     offset against its expert's matrices, read straight from the stacked weights, and writes the
-    outputs into the SAME array ``block`` rows before the inputs (``one_block`` says why that is safe);
+    outputs into the SAME array ``block`` rows before the inputs (``one_block`` says why that is safe;
+    where a small expert expects less than two blocks' rows the kernel of ``ops/grouped_experts.py``
+    walks the same blocks in the same order with ``one_block``'s mathematics, ``blocks_plan``);
     then a tile of ``TILE`` tokens gathers its pairs' rows ``ROWS`` a trip and a 0/1 matrix sums
     them by token in float32. A pair's product is scaled by its weight in float32 and rounded
     once, a token's pairs are summed in float32 and rounded once; no pair is dropped, whatever the
@@ -275,6 +322,18 @@ def experts_grouped(stacked, layer, x, idx, wt, valid, c):
     return _grouped(stacked, layer, x, idx, wt, valid, c)[0]
 
 
+def _call_rows(N: int) -> int:
+    """Rows of one call of ``_grouped`` for a batch of N: a slab's, where the batch goes through in slabs."""
+    return SLAB_ROWS if N > SLAB_ROWS and N % SLAB_ROWS == 0 else N
+
+
+def seq_counters(c, group, N: int) -> int:
+    """How many counters ``moe_seq`` hands back for a batch of N rows through the stacked weights ``group``:
+    three, and a fourth in the programs whose blocks the kernel runs (the others keep the text they had)."""
+    s = c.expert_layer
+    return 3 + blocks_plan(s, _call_rows(N), [group[n] for n in s.matrices])[1]
+
+
 def moe_seq(w, xn, lengths, c, stacked=None, routing=None):
     """xn [B,T,H] -> ([B,T,H], counters): routed experts held here plus the shared expert where
     the layer has one. ``routing`` = (expert ids [B,T,k] int32, weights [B,T,k] f32) made elsewhere
@@ -283,7 +342,8 @@ def moe_seq(w, xn, lengths, c, stacked=None, routing=None):
     grouped matmul, in slabs of ``SLAB_ROWS`` rows where the batch is larger; without it every
     held expert over every token, which has a backward pass. The counters, float32 [3], are of
     the grouped matmul (zeros without it): held experts that got a pair, pairs served here, rows
-    of the blocks in use."""
+    of the blocks in use; and where the kernel runs the blocks a fourth, the rows of the blocks
+    it ran (``seq_counters`` says how many there are, from the shapes)."""
     s = c.expert_layer
     B, T, H = xn.shape
     N = B * T
@@ -297,13 +357,14 @@ def moe_seq(w, xn, lengths, c, stacked=None, routing=None):
         routed = scoped("moe.blocks", experts_dense)(w, x, idx, jnp.where(valid[:, None], wt, 0.0), c)
         counters = jnp.zeros((3,), jnp.float32)
     else:
-        if N > SLAB_ROWS and N % SLAB_ROWS == 0:
+        if _call_rows(N) < N:
             slabs = jax.tree.map(lambda a: a.reshape((N // SLAB_ROWS, SLAB_ROWS) + a.shape[1:]), (x, idx, wt, valid))
             routed, sizes, rows = jax.lax.map(lambda a: _grouped(*stacked, *a, c), slabs)
             routed, sizes, rows = routed.reshape(N, H), jnp.sum(sizes, axis=0), jnp.sum(rows)
         else:
             routed, sizes, rows = _grouped(*stacked, x, idx, wt, valid, c)
-        counters = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes), rows]).astype(jnp.float32)
+        # the blocks in use are the blocks the kernel ran, in the programs where it runs them
+        counters = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes), rows, rows][:seq_counters(c, stacked[0], N)]).astype(jnp.float32)
     if s.shared:
         routed = routed + scoped("moe.shared", shared_expert)(w, x, s)
     return routed.reshape(B, T, H), counters

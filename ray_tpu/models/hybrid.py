@@ -61,12 +61,13 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import experts
 from ray_tpu.ops import slot_attention
 from ray_tpu.ops.layers import cross_entropy_loss, live_rows
 from ray_tpu.util.profiling import scope
 
 # what a routing layer's sequence form reports beside its output, as one more thing it "keeps":
-# float32 [3], see ``models/experts.moe_seq``
+# float32 [3], or [4] in the programs whose blocks the kernel runs: see ``models/experts.moe_seq``
 ROUTING = "routing"
 
 
@@ -409,7 +410,8 @@ def forward_hidden(params, tokens, lengths, config, mesh=None, collect: bool = F
             for name, (shape, dtype, per) in spec.items():
                 empty[name] = jnp.zeros(((B, T) if per == "position" else (B,)) + tuple(shape), jnp.dtype(dtype))
         if c.routing_layers:
-            empty[ROUTING] = jnp.zeros((3,), jnp.float32)
+            group = next(params[kind] for kind, m in c.mixers.items() if m.routes)
+            empty[ROUTING] = jnp.zeros((experts.seq_counters(c, group, B * T),), jnp.float32)
 
     def layer(kind, w, i, riding):
         # what sub-blocks hand on rides the loop beside the stream (nothing, for a description that hands nothing on: no array more)

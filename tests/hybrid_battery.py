@@ -24,6 +24,7 @@ from benchmark import reference
 from ray_tpu.exceptions import HybridModelUnsupportedError
 from ray_tpu.llm import LLMEngine, SamplingParams
 from ray_tpu.models import experts, hybrid
+from ray_tpu.ops import grouped_experts
 
 ENGINE_KW = {"max_num_seqs": 4, "max_seq_len": 128, "prefill_buckets": (16, 32, 64)}
 
@@ -155,7 +156,7 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(desc, para
     assert s.held < s.num_experts or all(r["moe_pairs_local"] == r["moe_pairs_total"] for r in rows), "every expert is held here"
     for r in admitting:
         assert 0 < r["prefill_moe_pairs_local"] <= r["moe_rows_computed"] and r["prefill_moe_pairs_local"] <= s.top_k * r["prefill_tokens"]
-        assert 0 < r["prefill_experts_hit"] <= s.held
+        assert 0 < r["prefill_experts_hit"] <= s.held and r["moe_rows_kernel"] == 0  # off the TPU the loop runs the blocks
 
 
 def test_a_seeded_lane_draws_one_stream_alone_and_beside_others_in_a_recycled_slot(desc, params, eng):
@@ -201,6 +202,23 @@ def test_prompts_that_leave_whole_slabs_empty_are_served_what_the_reference_give
     (row,) = [r for r in eng.telemetry()["steps"] if r.get("admitted")]
     slabbed = "ffn" in desc.cfg.layer_kinds
     assert (row["prefill_tokens"], row["prefill_tokens_padded"], row["prefill_rows_live"]) == (sum(lengths), 4 * 64, 32 + 48 + 48 + 16 if slabbed else 4 * 64)
+
+
+def test_a_prefill_whose_blocks_the_kernel_runs_is_served_what_the_reference_gives_and_counts_their_rows(desc, params, monkeypatch):
+    """PR 57: where a small expert expects less than two blocks' rows of a prefill, a TPU runs the grouped matmul's
+    blocks as one kernel (``ops/grouped_experts.py``). The test answers for its ``refusal`` before a fresh engine
+    traces its programs, the same body runs interpreted, and what is served is the reference's; every admitting
+    row then carries ``moe_rows_kernel`` equal to ``moe_rows_computed``."""
+    if not desc.cfg.routing_layers:  # nothing is routed: no program of this description holds the layer
+        return
+    monkeypatch.setattr(grouped_experts, "refusal", lambda *a: None)
+    ps = prompts(desc, 56, (21, 38, 11, 27, 50))
+    sp = [SamplingParams(max_tokens=6, temperature=0.0, logprobs=True)] * len(ps)
+    eng = engine(desc.cfg, params)
+    res = check(desc, params, served(eng.generate(ps, sp), ps, sp))
+    assert res["ok"] and res["tokens"] == 30 and res["max_abs_dlogprob"] < desc.agrees_to, res
+    admitting = [r for r in eng.telemetry()["steps"] if r.get("admitted")]
+    assert admitting and all(r["moe_rows_kernel"] == r["moe_rows_computed"] > 0 for r in admitting)
 
 
 def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(desc, eng):
@@ -360,20 +378,26 @@ def test_the_chips_shares_add_up_to_the_uncut_expert_layer(desc):
     np.testing.assert_allclose(x + total, ref, atol=1e-4)
 
 
-PLACEMENTS = ("one_expert", "none_here", "a_block_and_one_more", "valid_ends_inside_a_block", "tall_blocks", "in_slabs", "many_small_trips")
+PLACEMENTS = ("one_expert", "none_here", "a_block_and_one_more", "valid_ends_inside_a_block", "tall_blocks", "few_rows_an_expert", "in_slabs", "many_small_trips")
 
 
+@pytest.mark.parametrize("runs", ["loop", "kernel"])
 @pytest.mark.parametrize("case", PLACEMENTS)
-def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(desc, params, case, monkeypatch):
+def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(desc, params, case, runs, monkeypatch):
     """The layout of ``experts._grouped`` follows the pairs held here, so its loops' lengths are
     data: every pair at ONE held expert (a run of many blocks); no pair held here (no trip, zeros
     out); an expert with exactly ``BLOCK`` pairs beside one with ``BLOCK + 1``; ``valid`` that ends
-    inside a block; the tall blocks; a batch in slabs; slabs, tiles and trips a few rows long, so
-    that every loop turns many times. Each against the pairs computed one by one, and the counters
-    against their definition. (Not in ``__all__``: for the descriptions that route experts.)"""
+    inside a block; the tall blocks, which a call takes where it has ``TALL_FROM`` pairs AND an expert
+    is expected to get two blocks' rows of them, and not where it expects fewer; a batch in slabs;
+    slabs, tiles and trips a few rows long, so that every loop turns many times. Each against the
+    pairs computed one by one, and the counters against their definition; with the blocks run by the
+    loop, and by the kernel (``ops/grouped_experts.py``: the test answers for its ``refusal`` and the
+    same body runs interpreted). (Not in ``__all__``: for the descriptions that route experts.)"""
     cfg, s = desc.cfg, desc.cfg.expert_layer
     w = jax.tree.map(lambda a: a[0], params["moe"])
     N, k, El, B = 300, s.top_k, s.held, experts.BLOCK
+    if runs == "kernel":
+        monkeypatch.setattr(grouped_experts, "refusal", lambda *a: None)
     x = jax.random.normal(jax.random.PRNGKey(21), (N, cfg.hidden_size))
     # a layer whose routing is made elsewhere holds no router: the first one that any kind holds serves
     router = w if "router" in w else {"router": next(g["router"][0] for g in params.values() if isinstance(g, dict) and "router" in g)}
@@ -393,9 +417,14 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
     elif case == "valid_ends_inside_a_block":
         idx = jnp.asarray(np.asarray(idx) % 2 + s.expert_start)  # two experts, runs of more than a block
         valid[173:] = False
-    elif case == "tall_blocks":
+    elif case == "tall_blocks":  # 600 pairs over 8 published experts: 75 rows an expert, more than two blocks of 32
         monkeypatch.setattr(experts, "TALL_FROM", 64)
-        B = 2 * B
+        monkeypatch.setattr(experts, "BLOCK", 32)
+        B = 2 * 32
+        assert N * k // s.num_experts >= B
+    elif case == "few_rows_an_expert":  # the pairs for tall blocks, and an expert expects less than one: the blocks stay short
+        monkeypatch.setattr(experts, "TALL_FROM", 64)
+        assert N * k // s.num_experts < 2 * B
     elif case == "in_slabs":
         monkeypatch.setattr(experts, "SLAB_ROWS", 100)
     elif case == "many_small_trips":
@@ -403,12 +432,15 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
         monkeypatch.setattr(experts, "TILE", 8)
         monkeypatch.setattr(experts, "ROWS", 4)
     want = one_by_one(w, x, idx, jnp.where(valid[:, None], wt, 0.0), cfg)
+    assert experts.blocks_plan(s, N, [params["moe"][n] for n in s.matrices]) == (B, runs == "kernel")
     if case == "in_slabs":  # the layer over three sequences of 100, the shared expert taken off again
         lengths = jnp.asarray([100, 100, 100])
         monkeypatch.setattr(experts, "route", lambda *_: (idx, wt))
         got, counters = experts.moe_seq(w, x.reshape(3, 100, -1), lengths, cfg, stacked=(params["moe"], 0))
         got = got.reshape(N, -1) - (experts.shared_expert(w, x, s) if s.shared else 0.0)
-        sizes, rows = None, None
+        sizes = None
+        # the fourth counter is there in the programs whose blocks the kernel runs, and is the rows of the blocks it ran: all in use
+        assert len(counters) == experts.seq_counters(cfg, params["moe"], N) == (4 if runs == "kernel" else 3) and counters[-1] == counters[2]
     else:
         got, sizes, rows = experts._grouped(params["moe"], 0, x, idx, wt, jnp.asarray(valid), cfg)
         counters = [int(jnp.sum(sizes > 0)), int(jnp.sum(sizes)), int(rows)]

@@ -470,12 +470,24 @@ def _no_row_for_every_pair(txt, cfg, positions):
     assert f"[{N * k},{H}]" not in txt and f"[{N},{k},{H}]" not in txt and f"[{k},{N},{H}]" not in txt
 
 
+def _blocks_by_the_kernel(txt, cfg, kernel: bool):
+    """PRs 56 and 57: where a small expert expects less than two blocks' rows of a call, the compiled prefill
+    holds the blocks' kernel by name under ``moe.blocks``, reads the experts' matrices where they lie (no copy
+    of a layer's experts is among its operands: the caller bounds the temporaries) and no block of it is 256
+    rows tall; where an expert expects two blocks' rows the loop's tall blocks stand as they stood."""
+    ran = [line for line in txt.splitlines() if "custom-call(" in line and "grouped_experts" in line]
+    assert bool(ran) == kernel and all("tpu_custom_call" in line and "moe.blocks" in line for line in ran)
+    assert (f"bf16[256,{cfg.hidden_size}]" in txt) != kernel
+
+
 @pytest.mark.parametrize("prompts, most_gib", [(1, 0.45), (8, 2.85)])
 def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
     """The 4096-bucket prefill (the delta rule with one gate a head as ONE kernel under ``gdn.chunk``,
     PR 46, a few sequences at a time; the flash kernel at heads 256 wide; the grouped matmul over 128
-    small experts in slabs) for one prompt (0.397 GiB of temporaries as compiled for PR 47, whose
-    expert layer lays out the pairs held here; 0.615 for PR 46; 0.65 for PR 34, with the XLA lines)
+    small experts in slabs, its blocks run by the kernel of ``ops/grouped_experts.py`` and none of them
+    256 rows tall, PR 57) for one prompt (0.443 GiB of temporaries as compiled for PRs 56 and 57, whose kernel
+    is handed every row's weight; 0.397 for PR 47, whose expert layer lays out the pairs held here;
+    0.615 for PR 46; 0.65 for PR 34, with the XLA lines)
     and for the largest group the cell warms, 8 x 4096 (2.73 GiB, where the rule's groups set the
     peak; 3.23 for PR 34; and 0.33 GiB of output), beside 10.10 GiB of weights and 0.66 GiB of
     caches: under 15.75 GiB. No ``[.., 64, 64]`` float32 square of a chunk's pairs is left among the
@@ -496,6 +508,7 @@ def test_qwen3_next_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on
     assert rule and all("tpu_custom_call" in line and "gdn.chunk" in line for line in rule), "the rule's kernel, under its scope"
     assert "gdn.scan" not in txt and not re.search(r"f32\[[0-9,]*64,64\]", txt)
     _no_row_for_every_pair(txt, cfg, prompts * 4096)
+    _blocks_by_the_kernel(txt, cfg, kernel=True)  # 80 rows an expert at 4,096 rows, 160 in a slab of 8,192
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (4.5 GiB), no layer's worth of them (0.375 GiB x 12)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.67 * 2**30 < 15.0 * 2**30
 
@@ -733,6 +746,7 @@ def test_kimi_prefill_of_the_4096_bucket_fits_beside_weights_and_caches_on_one_v
     assert rule and all("tpu_custom_call" in line and "kda.chunk" in line for line in rule), "the rule's kernel, under its scope"
     assert "kda.scan" not in txt and not re.search(r"f32\[[0-9,]*64,64\]", txt)
     _no_row_for_every_pair(txt, cfg, prompts * 4096)
+    _blocks_by_the_kernel(txt, cfg, kernel=prompts == 1)  # 128 rows an expert at 4,096 rows; 256 in a slab of 8,192: full tall blocks, the loop
     assert mem.temp_size_in_bytes < most_gib * 2**30  # no copy of the experts (3.4 GiB), no layer's worth of them (0.42 GiB x 8)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.39 * 2**30 < 15.0 * 2**30
 
@@ -912,6 +926,49 @@ def test_smallthinker_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_
     assert any("window_flash_attention" not in line and "/attn/" in line for line in kernels), "and the global layers' without a window"
     assert mem.temp_size_in_bytes < most_gib * 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 1.5 * 2**30 < 15.75 * 2**30
+
+
+def _prefill_text(cfg, params, one_chip, prompts, bucket):
+    """A prefill of ``prompts`` x ``bucket`` positions lowered for the chip, the Mosaic kernels' payloads cut
+    out (they hold the checkout's path and line numbers): what a warm-up program's compile-cache key follows."""
+    import re
+
+    from ray_tpu.llm import hybrid_runner as hr
+
+    shapes = (jax.ShapeDtypeStruct((prompts, bucket), jnp.int32, sharding=one_chip), jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip))
+    return re.sub(r'\\22body\\22: \\22[A-Za-z0-9+/=]+', "BODY", jax.jit(partial(hr.prefill, cfg=cfg)).lower(params, *shapes).as_text())
+
+
+def _the_rule_the_blocks_had(monkeypatch):
+    """``experts.blocks_plan`` as the blocks ran before PRs 56 and 57: tall from ``TALL_FROM`` pairs, the loop, three counters."""
+    from ray_tpu.models import experts
+
+    monkeypatch.setattr(experts, "blocks_plan", lambda s, N, mats: (2 * experts.BLOCK if N * s.top_k >= experts.TALL_FROM else experts.BLOCK, False))
+
+
+@pytest.mark.parametrize("cell, prompts, bucket", [("nemotron", 1, 2048), ("nemotron", 4, 512), ("glm", 1, 8192), ("glm", 1, 16384), ("smallthinker", 1, 12288),
+                                                   ("lfm2", 1, 12288), ("kimi", 2, 4096)])
+def test_a_prefill_that_the_kernel_does_not_serve_lowers_to_the_text_it_had(one_chip, as_on_a_tpu, monkeypatch, cell, prompts, bucket):
+    """PRs 56 and 57 changed how the blocks of ``experts._grouped`` run only where an expert of 16 MiB or less
+    expects less than two blocks' rows of a call. Nemotron's prefills (19 MiB an expert: the chat cell's 18
+    warm programs, of which PR 56 renewed 17 for a kernel that no metric of the cell reads), the prefills that
+    the traffic of the GLM, SmallThinker and LFM2 cells runs (8,192 rows and more, 512-1,152 rows an expert)
+    and Kimi's of 8,192 rows lower for the chip to the text they lower to under the rule the blocks had before,
+    without the kernel: no warm-up program of theirs is a new one (``ROADMAP.md`` A7; ``scripts/warm_texts.py``
+    makes the comparison against another tree, every warm shape of every cell)."""
+    cfg, params, _, _ = _cell_at_its_size(one_chip, cell)
+    now = _prefill_text(cfg, params, one_chip, prompts, bucket)
+    _the_rule_the_blocks_had(monkeypatch)
+    assert "tpu_custom_call" in now and "grouped_experts" not in now and now == _prefill_text(cfg, params, one_chip, prompts, bucket)
+
+
+def test_a_prefill_of_few_rows_an_expert_over_small_experts_does_not(one_chip, as_on_a_tpu, monkeypatch):
+    """The same comparison where the kernel does serve (SmallThinker's bucket of 2,048 rows, which its traffic
+    never admits: 192 rows an expert of 11.25 MiB): the text is a new one, so the comparison above can fail."""
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "smallthinker")
+    now = _prefill_text(cfg, params, one_chip, 1, 2048)
+    _the_rule_the_blocks_had(monkeypatch)
+    assert "grouped_experts" in now and "grouped_experts" not in _prefill_text(cfg, params, one_chip, 1, 2048)
 
 
 def test_smallthinker_ring_insertion_updates_the_cache_in_place_and_gathers_only_the_windows_rows(one_chip):

@@ -227,3 +227,62 @@ def test_the_step_form_reads_the_experts_its_bound_lanes_hit_and_equals_the_dens
         got2, read2 = step(garbage, idx2, wt2, active)
         np.testing.assert_array_equal(np.asarray(got2)[::2], np.asarray(got)[::2])
         assert int(read2) == int(read) > 0 and not np.asarray(got2)[1::2].any()
+
+
+# what ``experts.blocks_plan`` says of a call of N rows in each cell that routes experts, at the cell's own (k, E, El, H, F, matrices):
+# cell -> (configuration file, MiB an expert, {N: (rows of a block, the kernel runs them), ...})
+RULE_AT_THE_CELLS = {
+    # 10 choices over 512 experts, 128 held, 3 x 512 x 2,048: 20-160 rows an expert. The parent took tall blocks from 4,096 rows up (40,960 pairs)
+    "qwen3_next": ("qwen3-next-80b-a3b-ep4.json", 6.0, {1024: (128, True), 2048: (128, True), 4096: (128, True), 8192: (128, True)}),
+    # 8 over 256, 64 held, 3 x 1,024 x 2,304: 128 rows an expert at 4,096 (short now, tall at the parent), 256 at 8,192 (tall, the loop)
+    "kimi_linear": ("kimi-linear-48b-a3b-ep4.json", 13.5, {2048: (128, True), 4096: (128, True), 8192: (256, False)}),
+    # every expert held, 6 over 64, 3 x 768 x 2,560: 192 rows an expert at 2,048; the cell's traffic runs 12,288 and more
+    "smallthinker": ("smallthinker-21b-a3b-d8.json", 11.25, {1024: (128, True), 2048: (128, True), 4096: (128, False), 8192: (256, False), 12288: (256, False)}),
+    # 6 over 128, 64 held, 2 x 1,856 x 2,688: a chat prompt's buckets expect under 200 rows an expert up to 4,096 rows, and the expert is too large at any
+    "nemotron_h": ("nemotron-3-nano-30b-a3b-ep2.json", 19.03, {128: (128, False), 512: (128, False), 2048: (128, False), 4096: (128, False), 8192: (256, False)}),
+    # every expert held, 4 over 64, 3 x 1,536 x 2,048: too large at any number of rows
+    "glm4_moe_lite": ("glm-4.7-flash-d8.json", 18.0, {1024: (128, False), 2048: (128, False), 4096: (128, False), 8192: (256, False), 16384: (256, False)}),
+    "lfm2": ("lfm2-24b-a2b-d10.json", 18.0, {1024: (128, False), 2048: (128, False), 4096: (128, False), 8192: (256, False), 12288: (256, False)}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(RULE_AT_THE_CELLS))
+def test_the_blocks_height_and_the_kernel_follow_the_rows_an_expert_expects_and_its_size_at_each_cells_shapes(cell, monkeypatch):
+    """PRs 56 and 57: a call takes tall blocks where it has ``TALL_FROM`` pairs AND an expert expects two
+    blocks' rows of them (pairs over the router's width), never anything taller than before; on a TPU the
+    kernel runs the blocks where an expert expects less AND is of 16 MiB or less. At every cell's own
+    configuration: which calls are tall, which short, which take the kernel. Nemotron's, GLM's and LFM2's
+    experts (18-19 MiB) are turned away at every number of rows with their size as the reason, so every
+    program of theirs is the parent's; Qwen3-Next's, Kimi's and SmallThinker's are served under two blocks'
+    rows and turned away at and over them."""
+    import json
+    import os
+
+    from benchmark import common
+    from ray_tpu.ops import grouped_experts
+
+    config, mib, plan = RULE_AT_THE_CELLS[cell]
+    with open(os.path.join(common.HERE, "configs", config)) as f:
+        c = json.load(f)
+    cfg = common.load_family(c["family"]).program_config(c, c["serving"]["max_seq_len"])
+    s = cfg.expert_layer
+    group = next(g for g in jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0))).values() if isinstance(g, dict) and "w_down" in g and g["w_down"].ndim == 4)
+    mats = [group[n] for n in s.matrices]
+    F, H = mats[0].shape[2:]
+    assert mats[0].dtype == jnp.bfloat16 and mats[0].shape[1] == s.held and len(mats) * F * H * 2 / 2**20 == pytest.approx(mib, abs=0.01)
+    why = lambda N: grouped_experts.refusal(jnp.bfloat16, H, F, len(mats), N * s.top_k // s.num_experts, experts.BLOCK)  # noqa: E731
+    off_the_tpu = {N: experts.blocks_plan(s, N, mats) for N in plan}
+    assert off_the_tpu == {N: (block, False) for N, (block, _) in plan.items()} and all("backend 'cpu'" in why(N) for N in plan)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert {N: experts.blocks_plan(s, N, mats) for N in plan} == plan
+    for N, (block, kernel) in plan.items():
+        expects, parent = N * s.top_k // s.num_experts, 2 * experts.BLOCK if N * s.top_k >= experts.TALL_FROM else experts.BLOCK
+        assert block <= parent and (block == parent or expects < 2 * experts.BLOCK)
+        if mib > 16:  # the expert's size speaks first, whatever the rows
+            assert not kernel and f"an expert of {mib:.2f} MiB, over 16 MiB" in why(N)
+        else:
+            assert kernel == (expects < 2 * experts.BLOCK) and (kernel or "two blocks" in why(N))
+        assert experts.seq_counters(cfg, group, N) == 3 + kernel and experts.seq_counters(cfg, group, 2 * experts.SLAB_ROWS) == experts.seq_counters(cfg, group, experts.SLAB_ROWS)
+    # what the kernel turns down besides, by shape and dtype
+    assert "float32" in grouped_experts.refusal(jnp.float32, H, F, len(mats), 80, 128) and "128 lanes" in grouped_experts.refusal(jnp.bfloat16, H + 64, F, len(mats), 80, 128)
+    assert "over 16 MiB" in grouped_experts.refusal(jnp.bfloat16, H, 8 * F, len(mats), 80, 128)

@@ -177,6 +177,39 @@ def test_a_prefills_placement_stands_under_its_three_parts_by_name(lowered, desc
     assert placed == {"moe.place.count", "moe.place.into", "moe.place.out"}
 
 
+@pytest.mark.parametrize("runs", ["loop", "kernel"])
+@pytest.mark.parametrize("description", ["nemotron_h", "qwen3_next", "glm4_moe_lite", "kimi_linear"])
+def test_a_prefills_blocks_stand_under_moe_blocks_whichever_way_they_are_run(description, runs, monkeypatch):
+    """The experts' matmuls of a prefill stand under ``moe.blocks`` as the loop of ``experts._grouped`` and as
+    the kernel of ``ops/grouped_experts.py`` (PR 57; the test answers for its ``refusal`` and the body lowers as
+    the interpreter runs it), so ``moe_blocks_share`` and ``prefill_ffn_ms_per_ktok`` read either; the kernel's
+    program hands back a fourth routing counter, and nothing of it stands outside the table's scopes."""
+    from functools import partial
+
+    from jax._src.lib.mlir import ir
+
+    from ray_tpu.ops import grouped_experts
+
+    if runs == "kernel":
+        monkeypatch.setattr(grouped_experts, "refusal", lambda *a: None)
+    cfg = _config(description)
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    lowering = jax.jit(partial(hybrid_runner.prefill, cfg=cfg)).lower(params, jax.ShapeDtypeStruct((2, 32), "int32"), jax.ShapeDtypeStruct((2,), "int32"))
+    under = {}
+
+    def visit(op):
+        if "/moe.blocks/" in _name(op):
+            under.setdefault(op.operation.name, []).append(_name(op))
+        return ir.WalkResult.ADVANCE
+
+    lowering.compiler_ir().operation.walk(visit)
+    assert "stablehlo.dot_general" in under and all(scope_of(path) == "moe.blocks" for paths in under.values() for path in paths)
+    # the loop's blocks are a ``while`` of its own under the scope; the kernel's are the interpreter's walk of the grid
+    assert any("/moe.blocks/while/" in path for path in under["stablehlo.dot_general"]) == (runs == "loop")
+    assert lowering.out_info[2][hybrid.ROUTING].shape == (4 if runs == "kernel" else 3,)
+    assert unscoped_ops(lowering) == []
+
+
 @pytest.mark.parametrize("description", sorted(PROGRAMS))
 def test_the_engine_ran_no_step_program_the_cases_above_leave_out(lowered, description):
     assert set(lowered(description)) <= set(PROGRAMS[description]) | {"llm_prefill"}  # the paged engine prefills by the slot program
