@@ -85,9 +85,9 @@ logger = logging.getLogger("ray_tpu.llm")
 # takes on that step's row; then what the engine counts of any model's from its ``flash_calls``
 # (``ops/flash_attention.query_tiles``) and from the rows' lengths (``ops/layers.live_rows``, a description's ``prefill_rows_live``)
 PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs",
-                    "narrow_pairs", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live")
+                    "narrow_pairs", "pairs_scored", "pairs_chosen", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
-DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read", "narrow_rows_read")
+DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read", "narrow_rows_read", "rows_scored", "rows_chosen")
 
 STAGES = {  # annotation name -> the step record's column (milliseconds)
     "llm.step.admission": "admission_ms",
@@ -406,7 +406,9 @@ class FlightRecorder:
         # attending to everything; rows of a window layer's ring that the step's bound lanes read, over the
         # window layers (``swa_rows_read``: min(position + 1, window) a lane and layer); positions whose keys and
         # values the attention layers with heads narrower than the 128 lanes read, over those layers
-        # (``narrow_rows_read``: position + 1 a lane and layer); absent for a description that counts none
+        # (``narrow_rows_read``: position + 1 a lane and layer); rows of the indexer's keys that the layers under a learned index
+        # score (``rows_scored``: position + 1 a lane and layer) and rows of keys and values they then attend to (``rows_chosen``:
+        # min(position + 1, top-k)); absent for a description that counts none
         *DECODE_COUNTERS,
         "pages_free", "pages_total",
         "recompiled", "spec_k", "spec_accepted",
@@ -443,7 +445,9 @@ class FlightRecorder:
         # lengths; (query, key) pairs inside the window that the sliding-window layers' mathematics needs at
         # the prompts' true lengths (``swa_pairs``: min(i + 1, window) a position and layer); causal (query, key)
         # pairs of the attention layers with heads narrower than the 128 lanes at the prompts' true lengths
-        # (``narrow_pairs``: i + 1 a position and layer); absent for a description that counts none. Last, of any model: the query tiles that the programs' flash calls
+        # (``narrow_pairs``: i + 1 a position and layer); (query, position) pairs that the layers under a learned index score
+        # (``pairs_scored``: every causal pair of a bucket longer than the top-k) and attend to (``pairs_chosen``: min(i + 1, top-k) a
+        # position and layer); absent for a description that counts none. Last, of any model: the query tiles that the programs' flash calls
         # have by their shape (``attn_q_tiles``: calls x batch rows x tiles of the bucket) and those that start
         # under a row's true length (``attn_q_tiles_live``): the kernel computes and fetches these alone;
         # and the positions that a position-wise sub-block of the programs runs (``prefill_rows_live``: a dense

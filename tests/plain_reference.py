@@ -5,7 +5,9 @@ The reference shares nothing with what it checks: ``models/llama.forward`` over 
 for every token, no cache, no step program, no slot; of the serving code only the sampler
 (``llm/sampling.sample``), whose key chain it states. It imports nothing of ``llm/engine.py``,
 ``llm/model_runner.py``, ``llm/hybrid_runner.py`` or the caches. A description's reference is its
-family's (``hybrid_battery.check``)."""
+family's (``hybrid_battery.check``). ``indexed_attention_by_hand`` is attention under a learned
+index (``ops/indexed_attention.py``) one query at a time in numpy float64, a full stable sort a
+query: what the op's forms and kernels are held to."""
 
 from functools import lru_cache
 
@@ -70,6 +72,28 @@ def reference_stream(cfg, params, prompt, sp):
         toks = full_forward_sampled(cfg, params, prompt, sp)
     stops = [i for i, t in enumerate(toks) if t in sp.stop_token_ids]
     return (toks[: stops[0] + 1], "stop") if stops else (toks, "length")
+
+
+def indexed_attention_by_hand(q, k, v, qi, w, ki, topk):
+    """q [B,nh,T,hd], k, v [B,G,T,hd], qi [B,J,T,d], w [B,T,J], ki [B,T,d] -> o [B,nh,T,hd] float64: query t
+    attends to every s <= t while t + 1 <= topk, else to the topk positions s <= t of largest
+    ``sum_j w[t, j] relu(qi[t, j] . ki[s])``, ties to the earlier position; ONE choice for all heads."""
+    import numpy as np
+
+    q, k, v, qi, w, ki = (np.asarray(a, np.float64) for a in (q, k, v, qi, w, ki))
+    B, nh, T, hd = q.shape
+    G, J = k.shape[1], qi.shape[1]
+    out = np.zeros((B, nh, T, hd))
+    for b in range(B):
+        index = sum(w[b, :, j:j + 1] * np.maximum(qi[b, j] @ ki[b].T, 0.0) for j in range(J))
+        for t in range(T):
+            chosen = np.arange(t + 1) if t + 1 <= topk else np.argsort(-index[t, :t + 1], kind="stable")[:topk]
+            for h in range(nh):
+                g = h // (nh // G)
+                s = q[b, h, t] @ k[b, g, chosen].T / np.sqrt(hd)
+                p = np.exp(s - s.max())
+                out[b, h, t] = (p / p.sum()) @ v[b, g, chosen]
+    return out
 
 
 def drive(engine, schedule, aborts=None, max_steps=900):

@@ -378,7 +378,8 @@ CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widt
          "kimi": ("kimi-linear-48b-a3b-ep4.json", {"remat": False}),  # 9 of 27 layers, 64 of 256 experts, 16 slots x 4096
          "sala": ("minicpm-sala-9b-d8.json", {"remat": False}),  # layers 9-16 of 32, the whole vocabulary, 16 slots x 12,288
          "smallthinker": ("smallthinker-21b-a3b-d8.json", {"remat": False}),  # layers 0-7 of 52, every expert, the whole vocabulary, 16 slots x 12,288
-         "lfm2": ("lfm2-24b-a2b-d10.json", {"remat": False})}  # layers 0-9 of 40, every expert, the whole vocabulary, 16 slots x 12,288
+         "lfm2": ("lfm2-24b-a2b-d10.json", {"remat": False}),  # layers 0-9 of 40, every expert, the whole vocabulary, 16 slots x 12,288
+         "keye": ("keye-vl-2.0-30b-a3b-d6.json", {"remat": False})}  # layers 24-29 of 48, every expert, the whole vocabulary, 12 slots x 24,576
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -1065,3 +1066,66 @@ def test_lfm2_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_v
     assert "shortconv.conv" in txt
     assert mem.temp_size_in_bytes < most_gib * 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.75 * 2**30 < 15.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# PR 58: an eighth description, Keye-VL-2.0's language model (models/keye_vl.py): the cell keye-vl-2.0-d6.longdoc-24k.
+# ---------------------------------------------------------------------------
+def test_both_kernels_of_the_indexed_prefill_compile_for_v5e_at_24576_positions(one_chip, as_on_a_tpu):
+    """The thresholds' kernel (a tile of 256 queries' index keys against every earlier position in
+    25 MB of fast memory, then the two bisections) and the attention kernel that computes a tile's
+    keys again and masks by the thresholds, at 32 heads over 4 of 128 under 16 x 64 at 24,576
+    positions: the gate lets the shape through, both lower to Mosaic under their names, and neither
+    holds a [T, T] array (2.4 GB in float32) or a table a pair."""
+    from ray_tpu.ops import indexed_attention as ia
+
+    assert ia.refusal(jnp.bfloat16, 128, 64, 24576) is None and ia.refusal(jnp.bfloat16, 128, 64, 4096) is None
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    T = 24576
+    qi, w, ki, n = sds((1, 16, T, 64), jnp.bfloat16), sds((1, T, 16), jnp.float32), sds((1, T, 64), jnp.bfloat16), sds((1,), jnp.int32)
+    compiled, txt = _compile(lambda qi, w, ki, n: ia.thresholds_kernel(qi, w, ki, n, 2048), qi, w, ki, n)
+    assert "tpu_custom_call" in txt and "indexer_thresholds" in txt and compiled.memory_analysis().output_size_in_bytes < 2 * T * 128 * 4 + 4096
+    q, k, thr = sds((1, 32, T, 128), jnp.bfloat16), sds((1, 4, T, 128), jnp.bfloat16), sds((1, T, 128), jnp.int32)
+    compiled, txt = _compile(ia.attend_indexed_kernel, q, k, k, qi, w, ki, thr, thr, n)
+    assert "tpu_custom_call" in txt and "indexed_prefill_attention" in txt and compiled.memory_analysis().output_size_in_bytes < 32 * T * 128 * 2 + 4096
+
+
+def test_keye_fused_step_fits_one_v5e_aliases_its_three_entries_and_gathers_the_chosen_rows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 12 x 24,576 through the SAME ``hybrid_runner.fused_step`` and layer loop as
+    the seven other descriptions (``indexed moe`` six times, scanned): 8.15 GiB of weights, 3.59 GiB
+    of keys, values and the indexer's keys at 13,056 B a position, all of it aliased to the donated
+    inputs; no live-block kernel (the step gathers the rows its lanes chose), the experts' step
+    kernel, and no slice of a layer's keys or values (288 MiB at 12 x 24,576) in the compiled text:
+    the one layer's rows it reads whole are the indexer's keys, 36 MiB."""
+    import re
+
+    cfg, params, cache, state, compiled = fused_step_for_the_chip("keye")
+    assert cfg.layer_plan == (("indexed", "moe"), 6, (), ()) and state == {}
+    assert {n: a.shape for n, a in cache.items() if n != "length"} == {"k": (6, 12, 24576, 4, 128), "v": (6, 12, 24576, 4, 128), "k_idx": (6, 12, 24576, 64)}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert _kv_bytes(cache) == 3_850_371_072 == 12 * 24576 * 13056
+    print("keye fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+    assert 11.6 * 2**30 < mem.argument_size_in_bytes < 11.85 * 2**30 and mem.alias_size_in_bytes >= _kv_bytes(cache)
+    assert all(name in txt for name in ("step_experts", "indexed.score", "indexed.select", "indexed.attend")) and "slot_decode_attention" not in txt
+    assert not re.search(r"bf16\[(1,)?12,24576,4,128\]", txt)
+    assert mem.temp_size_in_bytes < 64 * 2**20  # 10.9 MiB
+
+
+def test_keye_prefill_of_the_24576_bucket_fits_beside_weights_and_cache_on_one_v5e(one_chip, as_on_a_tpu):
+    """The 24,576-bucket prefill of one prompt (the indexer's projections under ``indexed.score``, the
+    thresholds' kernel under ``indexed.select``, the attention kernel under ``indexed.attend``, 196,608
+    routed pairs through the grouped matmul in slabs) beside 8.15 GiB of weights and 3.59 GiB of cache:
+    under 15.75 GiB. (Two prompts take 2.74 GiB of temporaries and 0.6 of results: they fit too, and
+    the cell warms that shape; four do not, and the engine never asks for them.)"""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "keye")
+    tokens = jax.ShapeDtypeStruct((1, 24576), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("keye prefill:", mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    kernels = [line for line in txt.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert kernels and any("indexed.select" in line for line in kernels) and any("indexed.attend" in line for line in kernels)
+    assert mem.temp_size_in_bytes < 1.7 * 2**30  # 1.50 GiB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 3.59 * 2**30 < 15.75 * 2**30

@@ -150,7 +150,7 @@ def test_neither_the_runner_nor_the_engine_names_a_model_or_a_kind_of_layer():
     for module in (hybrid_runner, engine_module):
         with open(module.__file__) as f:
             text = f.read()
-        found = re.findall(r"nemotron|qwen|glm|mamba|gdn|deltanet|\"attn\"|\"moe\"|\"mla\"|'moe'|'attn'|c_kv|k_r\b", text, flags=re.IGNORECASE)
+        found = re.findall(r"nemotron|qwen|glm|keye[_v-]|mamba|gdn|deltanet|\"attn\"|\"moe\"|\"mla\"|\"indexed\"|'moe'|'attn'|'indexed'|c_kv|k_r\b|k_idx", text, flags=re.IGNORECASE)
         assert not found, (module.__name__, found)
 
 
@@ -243,6 +243,8 @@ RULE_AT_THE_CELLS = {
     # every expert held, 4 over 64, 3 x 1,536 x 2,048: too large at any number of rows
     "glm4_moe_lite": ("glm-4.7-flash-d8.json", 18.0, {1024: (128, False), 2048: (128, False), 4096: (128, False), 8192: (256, False), 16384: (256, False)}),
     "lfm2": ("lfm2-24b-a2b-d10.json", 18.0, {1024: (128, False), 2048: (128, False), 4096: (128, False), 8192: (256, False), 12288: (256, False)}),
+    # every expert held, 8 over 128, 3 x 768 x 2,048: 256 rows an expert at 4,096; the cell's 24,576-position prefill goes through in slabs of 8,192 rows (512 an expert: tall, the loop)
+    "keye_vl": ("keye-vl-2.0-30b-a3b-d6.json", 9.0, {1024: (128, True), 2048: (128, True), 4096: (256, False), 8192: (256, False)}),
 }
 
 

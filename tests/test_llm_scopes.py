@@ -1,5 +1,4 @@
-"""The scope vocabulary is whole (PR 39): for a toy configuration of each of the six
-descriptions, every step program an engine builds and runs, lowered on the CPU, has every
+"""The scope vocabulary is whole (PR 39): for a toy configuration of each of the descriptions below, every step program an engine builds and runs, lowered on the CPU, has every
 ``dot_general``, convolution, custom call, scatter, gather and ``dynamic_update_slice`` under a
 scope of ``util/profiling.SCOPES``; and a scope outside the table raises where it is traced."""
 
@@ -28,7 +27,8 @@ HYBRID = ["llm_hybrid_prefill", "llm_kv_insert", "llm_state_insert", "llm_hybrid
 PROGRAMS = {"llama": SLOTS, "llama_paged": PAGED, "nemotron_h": HYBRID, "qwen3_next": HYBRID,
             "glm4_moe_lite": [p for p in HYBRID if p != "llm_state_insert"],  # latent attention keeps nothing per sequence
             "kimi_linear": HYBRID,  # a state a sequence AND a latent a position
-            "minicpm_sala": HYBRID}  # keys and values a position, a state and the compressed keys a sequence
+            "minicpm_sala": HYBRID,  # keys and values a position, a state and the compressed keys a sequence
+            "keye_vl": [p for p in HYBRID if p != "llm_state_insert"]}  # keys, values and the indexer's key a position, nothing a sequence
 
 
 class Recording:
@@ -65,6 +65,10 @@ def _config(description):
         from ray_tpu.models.minicpm_sala import MiniCPMSALAConfig
 
         return MiniCPMSALAConfig.tiny()  # dense below 32: the prompt of 40 chooses its blocks, the one of 9 does not
+    if description == "keye_vl":
+        from ray_tpu.models.keye_vl import KeyeVLConfig
+
+        return KeyeVLConfig.tiny()  # top-k 16: the prompt of 40 goes through the index (its bucket holds 64), the one of 9 through the flash call
     from ray_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
 
     return Glm4MoeLiteConfig.tiny()
