@@ -2504,7 +2504,7 @@ class LLMEngine:
         routing = np.zeros((4,), np.float32) if routing is None else np.pad(routing, (0, 4 - len(routing)))
         seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
         self._prefill_stats = (seen[0] + stats[0], seen[1] + stats[1], seen[2] + 1, seen[3] + routing,
-                               {k: seen[4].get(k, 0) + v for k, v in stats[2].items()})
+                               {**seen[4], **{k: seen[4].get(k, 0) + v for k, v in stats[2].items()}})  # a program may count what another of the step does not
 
     def _bind_resume(self, st: RequestState, slot: int):
         """Splice a restored live-state request into the decode loop

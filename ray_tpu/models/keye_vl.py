@@ -153,11 +153,19 @@ class KeyeVLConfig(HybridDescription):
     def prefill_counters(self, batch: int, length: int, lengths=()) -> dict:
         """(query, position) pairs of the indexed layers for prompts of the TRUE ``lengths`` in a bucket
         of ``length``: the causal pairs the indexer scores (none in a bucket of at most ``index_topk``,
-        where no query chooses) and the pairs attention reads, min(t + 1, ``index_topk``) a query."""
+        where no query chooses) and the pairs attention reads, min(t + 1, ``index_topk``) a query. And,
+        where the two kernels run, ``choice_bytes``: the bytes of choice tables that ONE prefill program
+        of ``batch`` x ``length`` writes, a bit a pair a layer, from its SHAPE alone (a tile of padding
+        still has its zero words). Where the flash kernel runs (no query chooses) or the XLA form does
+        (``ops/indexed_attention.refusal``, asked as ``ops/delta_rule.counters`` asks its own) no table
+        is written and the row carries none, which its readers take for 0."""
         L, k = self.count("indexed"), self.index_topk
         causal = sum(int(n) * (int(n) + 1) // 2 for n in lengths)
         over = sum((int(n) - k) * (int(n) - k + 1) // 2 for n in lengths if n > k)  # what the queries past ``index_topk`` leave unread
-        return {"pairs_scored": L * causal if length > k else 0, "pairs_chosen": L * (causal - over)}
+        counted = {"pairs_scored": L * causal if length > k else 0, "pairs_chosen": L * (causal - over)}
+        if length > k and indexed_attention.refusal(self.dtype, self.hd, self.index_dim, length) is None:
+            counted["choice_bytes"] = L * batch * length * indexed_attention.choice_words(length) * 4
+        return counted
 
     def decode_counters(self, positions) -> dict:
         """Rows of ``k_idx`` a decode step's indexed layers score for lanes holding ``positions`` (the

@@ -17,12 +17,14 @@ projections are the model file's; what is here is shared by the sequence form an
    sorts), and, where several candidates hold exactly that key, the position up to which they are
    taken (a second bisection over the positions; skipped where no row of a tile has such a tie).
    ``threshold`` is the XLA form over keys that are held whole; ``thresholds_kernel`` computes a
-   tile of queries' keys into fast memory and bisects there, so no [T, T] scores ever exist;
+   tile of queries' keys into fast memory, bisects there, and hands on the CHOICE, a bit a pair
+   (``choice_words``: 75.5 MB a sequence at 24,576), so no [T, T] scores, keys or bytes ever exist
+   in HBM and a pair's index score is computed once;
 3. attention under the choice: ``attend_indexed_kernel`` is a flash pass over every causal tile of
-   keys that RECOMPUTES the tile's index keys and masks by the two thresholds (16 x 64-deep
-   products a tile beside attention's 64 x 128-deep ones; no table a (query, position): a byte a
-   pair is 604 MB a group at 24,576); ``indexed_attention_seq`` runs it, or, where ``refusal``
-   gives a reason, the same steps a tile of queries at a time in XLA with a mask.
+   keys that READS the tile's bits (a block of words a tile of queries and eight tiles of keys) and
+   does attention's own work alone: a masked score is ``-inf`` under a running maximum that starts
+   at a finite floor, so its weight is exactly 0; ``indexed_attention_seq`` runs it, or, where
+   ``refusal`` gives a reason, the same steps a tile of queries at a time in XLA with a mask.
 
 The decode step (``indexed_attention_step``) scores a lane's ``k_idx`` rows, takes the ``topk`` best
 (``jax.lax.top_k``: one row of at most ``max_seq_len`` keys a lane, ties to the lower index) and
@@ -48,6 +50,7 @@ INT_MIN = -(1 << 31)  # the key of a position that is no candidate; no finite sc
 _NEG = -1e30
 _TILE_Q, _TILE_K = 256, 512  # queries a grid step takes (with all their heads), and positions a tile of keys
 _LANES = 128
+_WORD = 32  # positions an int32 word of the choice table holds, a bit each
 
 
 # --------------------------------------------------------------------------- scores and keys
@@ -137,21 +140,30 @@ def refusal(dtype, head_dim: int, index_dim: int, positions: int, mesh=None) -> 
         return f"heads of {head_dim} under an index of {index_dim}: compiled at 128 under 64"
     if positions % min(_TILE_Q, positions) or positions % min(_TILE_K, positions) or positions % _LANES:
         return f"{positions} positions: not whole tiles of {_TILE_Q} queries and {_TILE_K} keys"
+    if positions % (_WORD * _LANES):
+        return f"{positions} positions: not whole groups of {_WORD * _LANES}, which the choice table packs (a bit a pair, {_WORD} chunks of {_LANES} lanes a word)"
     return None
 
 
-# --------------------------------------------------------------------------- kernel 1: the thresholds
-def _thresholds_kernel(len_ref, qi_ref, w_ref, ki_ref, thr_ref, cut_ref, keys_scr, *, topk: int, tq: int, tk: int, heads: int, bits: int):
-    """Grid step (sequence b, tile of queries i): the tile's index keys against every position at
-    or before its last query into ``keys_scr`` [tq, T], then the two bisections over them, a chunk
-    of ``tk`` positions at a time; -> thr, cut [tq, 128] (a row's value in every lane)."""
-    b, i = pl.program_id(0), pl.program_id(1)
-    chunks = ((i + 1) * tq + tk - 1) // tk  # the chunks of positions that hold a candidate of some query of the tile
+# --------------------------------------------------------------------------- kernel 1: the thresholds, and the choice a bit a pair
+def choice_words(positions: int) -> int:
+    """int32 words a query's row of the choice table has: ``_WORD`` positions a word, in whole groups of
+    ``_WORD * _LANES`` positions. Bit ``c`` of lane ``l`` of a group's ``_LANES`` words is the group's
+    position ``c * _LANES + l``: a group is packed from ``_WORD`` chunks of lanes as they lie, shifted,
+    and unpacked the same way, with no movement across lanes."""
+    return -(-positions // (_WORD * _LANES)) * _LANES
 
-    @pl.when(i * tq >= len_ref[b])  # a tile of padding: nothing is read of it
-    def _skip():
-        thr_ref[...] = jnp.full_like(thr_ref, INT_MIN)
-        cut_ref[...] = jnp.zeros_like(cut_ref)
+
+def _thresholds_kernel(len_ref, qi_ref, w_ref, ki_ref, table_ref, keys_scr, *, topk: int, tq: int, tk: int, heads: int, bits: int):
+    """Grid step (sequence b, tile of queries i): the tile's index keys against every position at
+    or before its last query into ``keys_scr`` [tq, groups of positions], the two bisections over
+    them, a chunk of ``tk`` positions at a time, then ``chosen`` of the keys and the thresholds,
+    packed (``choice_words``) -> table [tq, words]; zeros for a tile of padding, and after a query."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    group = _WORD * _LANES
+    chunks = ((i + 1) * tq + tk - 1) // tk  # the chunks of positions that hold a candidate of some query of the tile
+    groups = (chunks * tk + group - 1) // group  # and the groups of the table: the rest of the last one is filled with ``INT_MIN``
+    table_ref[...] = jnp.zeros_like(table_ref)  # a tile of padding, and the groups after the tile's last query: no word is undefined
 
     @pl.when(i * tq < len_ref[b])
     def _tile():
@@ -166,7 +178,12 @@ def _thresholds_kernel(len_ref, qi_ref, w_ref, ki_ref, thr_ref, cut_ref, keys_sc
             keys_scr[:, pl.ds(first, tk)] = jnp.where(col <= row, keys, INT_MIN)
             return 0
 
+        def blank(c, _):
+            keys_scr[:, pl.ds(pl.multiple_of(c * tk, tk), tk)] = jnp.full((tq, tk), INT_MIN, jnp.int32)
+            return 0
+
         jax.lax.fori_loop(0, chunks, fill, 0)
+        jax.lax.fori_loop(chunks, groups * (group // tk), blank, 0)
 
         def count(pred):
             """[tq, 1]: how many of a row's keys ``pred(keys [tq,128], first position)`` holds of."""
@@ -177,18 +194,34 @@ def _thresholds_kernel(len_ref, qi_ref, w_ref, ki_ref, thr_ref, cut_ref, keys_sc
                 return acc
             return jnp.sum(jax.lax.fori_loop(0, chunks, chunk, jnp.zeros((tq, _LANES), jnp.int32)), axis=-1, keepdims=True)
 
+        def pack(allowed):
+            """The table's live groups from ``allowed(keys [tq,128], first position)``: one pass over the keys."""
+            def one(g, _):
+                word = jnp.zeros((tq, _LANES), jnp.int32)
+                for c in range(_WORD):
+                    first = pl.multiple_of(g * group + c * _LANES, _LANES)
+                    word = word | jnp.where(allowed(keys_scr[:, pl.ds(first, _LANES)], first), jnp.int32(1 << c if c < 31 else INT_MIN), 0)
+                table_ref[:, pl.ds(pl.multiple_of(g * _LANES, _LANES), _LANES)] = word
+                return 0
+            jax.lax.fori_loop(0, groups, one, 0)
+
         rows = jnp.zeros((tq, 1), jnp.int32)
         ge = lambda c: count(lambda keys, _: keys >= c)  # noqa: E731
         thr = _kth_key(ge, topk, rows)
-        thr_ref[...] = jnp.broadcast_to(thr, thr_ref.shape)
-        cut_ref[...] = jnp.full_like(cut_ref, (1 << bits) - 1)  # no tie at the threshold: every position passes
+        ties = jnp.max(ge(thr)) > topk  # some row holds its threshold's key more than once (or has fewer candidates than topk)
 
-        @pl.when(jnp.max(ge(thr)) > topk)  # some row holds its threshold's key more than once (or has fewer candidates than topk)
+        @pl.when(jnp.logical_not(ties))
+        def _plain():
+            least = jnp.maximum(thr, INT_MIN + 1)  # a row with fewer candidates than topk takes them all, and no position that is none
+            pack(lambda keys, _: keys >= least)
+
+        @pl.when(ties)
         def _ties():
             lane = jax.lax.broadcasted_iota(jnp.int32, (tq, _LANES), 1)
             tied_before = lambda p: count(lambda keys, first: (keys == thr) & (first + lane < p))  # noqa: E731
             cut = _tie_cut(tied_before, topk - count(lambda keys, _: keys > thr), bits, rows)
-            cut_ref[...] = jnp.broadcast_to(cut, cut_ref.shape)
+            cut = jnp.where(thr > INT_MIN, cut, -1)  # where ``INT_MIN`` is the threshold it is the key of what is no candidate
+            pack(lambda keys, first: (keys > thr) | ((keys == thr) & (first + lane <= cut)))
 
 
 def _tiles(T: int) -> tuple:
@@ -196,37 +229,39 @@ def _tiles(T: int) -> tuple:
 
 
 def thresholds_kernel(qi, w, ki, lengths, topk: int, *, interpret: bool = False):
-    """qi [B,J,T,d], w [B,T,J] float32, ki [B,T,d], lengths [B] -> (thr, cut) int32 [B,T,128]: every
-    query's two thresholds (``threshold``) among the positions at or before it, a value in all 128
-    lanes of its row (as the attention kernel reads them); tiles of queries past a sequence's true
-    length are skipped."""
+    """qi [B,J,T,d], w [B,T,J] float32, ki [B,T,d], lengths [B] -> int32 [B,T,``choice_words(T)``]:
+    every query's choice among the positions at or before it (``chosen`` of its keys and its
+    ``threshold``), a bit a position; tiles of queries past a sequence's true length are all zeros."""
     B, J, T, d = qi.shape
     tq, tk = _tiles(T)
     bits = max(T - 1, 1).bit_length()
+    words = choice_words(T)
     rows = lambda b, i, *_: (b, i, 0)  # noqa: E731
-    out = jax.ShapeDtypeStruct((B, T, _LANES), jnp.int32)
     return pl.pallas_call(
         functools.partial(_thresholds_kernel, topk=topk, tq=tq, tk=tk, heads=J, bits=bits),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, T // tq),
             in_specs=[pl.BlockSpec((None, J, tq, d), lambda b, i, *_: (b, 0, i, 0)), pl.BlockSpec((None, tq, J), rows),
                       pl.BlockSpec((None, T, d), lambda b, i, *_: (b, 0, 0))],
-            out_specs=[pl.BlockSpec((None, tq, _LANES), rows), pl.BlockSpec((None, tq, _LANES), rows)],
-            scratch_shapes=[pltpu.VMEM((tq, T), jnp.int32)]),
-        out_shape=[out, out], interpret=interpret, name="indexer_thresholds",
+            out_specs=pl.BlockSpec((None, tq, words), rows),
+            scratch_shapes=[pltpu.VMEM((tq, words * _WORD), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((B, T, words), jnp.int32), interpret=interpret, name="indexer_thresholds",
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=100 << 20)}),
     )(lengths.astype(jnp.int32), qi, w, ki)
 
 
 # --------------------------------------------------------------------------- kernel 2: attention under the choice
-def _attend_kernel(len_ref, q_ref, k_ref, v_ref, qi_ref, w_ref, ki_ref, thr_ref, cut_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   scale: float, tq: int, tk: int, heads: int, group: int, index_heads: int):
-    """Grid step (sequence b, tile of queries i, tile of keys j): the tile's index keys again, the
-    choice as a mask from the queries' two thresholds, and the tile's keys folded into the running
+def _attend_kernel(len_ref, q_ref, k_ref, v_ref, table_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float, tq: int, tk: int, heads: int, group: int):
+    """Grid step (sequence b, tile of queries i, tile of keys j): the choice of the tile's pairs
+    from the words of the group that holds the keys, and the tile's keys folded into the running
     max, sum and weighted values of every query, one query head after another; the mask is the
-    same for all of them."""
+    same for all of them. A pair that is not chosen scores ``-inf`` under a maximum that is never
+    below ``_NEG``: its weight is ``exp(-inf)``, and a row that has met no chosen pair yet keeps
+    its zeros (``alpha`` is ``exp(0)``). A row's running max and sum are held in EVERY lane of
+    [tq, ``_LANES``]: a column of one lane a row costs the pass as much again (PERF.md section 6, PR 59)."""
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    hd = acc_scr.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -236,57 +271,51 @@ def _attend_kernel(len_ref, q_ref, k_ref, v_ref, qi_ref, w_ref, ki_ref, thr_ref,
 
     @pl.when((j * tk <= i * tq + tq - 1) & (i * tq < len_ref[b]))  # a tile of keys after every query of the tile, or a tile of padding: not fetched, not computed
     def _fold():
-        kt = ki_ref[...]
-        keys = index_keys(lambda n: _dot_nt(qi_ref[n], kt), w_ref[...], index_heads)
-        row = i * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-        col = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        thr, cut = thr_ref[:, :1], cut_ref[:, :1]
-        allowed = (col <= row) & ((keys > thr) | ((keys == thr) & (col <= cut)))
+        word = table_ref[...]
+        at = (j * tk) % (_WORD * _LANES) // _LANES  # the tile's first chunk of lanes in its group: the bit its positions have in a word
+        allowed = jnp.concatenate([(word & jnp.left_shift(jnp.int32(1), at + u)) != 0 for u in range(tk // _LANES)], axis=1)  # causal as it comes: no later position is chosen
         for h in range(heads):
             k, v = k_ref[h // group], v_ref[h // group]
             s = jax.lax.dot_general(q_ref[h], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-            s = jnp.where(allowed, s, _NEG)
+            s = jnp.where(allowed, s, -jnp.inf)
             m_prev = m_scr[h]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
+            p = jnp.exp(s - pltpu.repeat(m_new, tk // _LANES, axis=1))
             alpha = jnp.exp(m_prev - m_new)
             l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            acc_scr[h] = acc_scr[h] * alpha[:, :hd] + jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
             m_scr[h] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         for h in range(heads):
-            l = l_scr[h]
+            l = l_scr[h][:, :hd]
             o_ref[h] = (acc_scr[h] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
 
 
-def attend_indexed_kernel(q, k, v, qi, w, ki, thr, cut, lengths, *, interpret: bool = False):
-    """q [B,nh,T,hd], k, v [B,G,T,hd]; qi [B,J,T,d], w [B,T,J] float32, ki [B,T,d]; thr, cut
-    [B,T,128] (``thresholds_kernel``); lengths [B] -> o [B,nh,T,hd] in q's dtype: softmax attention
-    of every query over the positions its thresholds choose, zeros for the tiles of queries past a
-    sequence's true length."""
+def attend_indexed_kernel(q, k, v, table, lengths, *, interpret: bool = False):
+    """q [B,nh,T,hd], k, v [B,G,T,hd]; table [B,T,``choice_words(T)``] (``thresholds_kernel``);
+    lengths [B] -> o [B,nh,T,hd] in q's dtype: softmax attention of every query over the positions
+    its bits choose, zeros for the tiles of queries past a sequence's true length."""
     B, nh, T, hd = q.shape
-    G, J, d = k.shape[1], qi.shape[1], qi.shape[-1]
+    G = k.shape[1]
     tq, tk = _tiles(T)
     last = lambda i: (i * tq + tq - 1) // tk  # noqa: E731 - the last tile of keys a tile of queries reads
     queries = lambda b, i, j, *_: (b, 0, i, 0)  # noqa: E731
-    rows = lambda b, i, j, *_: (b, i, 0)  # noqa: E731
     keys = lambda b, i, j, *_: (b, 0, jnp.minimum(j, last(i)), 0)  # noqa: E731 - past it the index repeats: nothing is fetched
+    words = lambda b, i, j, *_: (b, i, jnp.minimum(j, last(i)) * tk // (_WORD * _LANES))  # noqa: E731 - one block of words serves a group's tiles of keys
     return pl.pallas_call(
-        functools.partial(_attend_kernel, scale=hd ** -0.5, tq=tq, tk=tk, heads=nh, group=nh // G, index_heads=J),
+        functools.partial(_attend_kernel, scale=hd ** -0.5, tq=tq, tk=tk, heads=nh, group=nh // G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, T // tq, T // tk),
             in_specs=[pl.BlockSpec((None, nh, tq, hd), queries), pl.BlockSpec((None, G, tk, hd), keys), pl.BlockSpec((None, G, tk, hd), keys),
-                      pl.BlockSpec((None, J, tq, d), queries), pl.BlockSpec((None, tq, J), rows),
-                      pl.BlockSpec((None, tk, d), lambda b, i, j, *_: (b, jnp.minimum(j, last(i)), 0)),
-                      pl.BlockSpec((None, tq, _LANES), rows), pl.BlockSpec((None, tq, _LANES), rows)],
+                      pl.BlockSpec((None, tq, _LANES), words)],
             out_specs=pl.BlockSpec((None, nh, tq, hd), queries),
-            scratch_shapes=[pltpu.VMEM((nh, tq, 1), jnp.float32), pltpu.VMEM((nh, tq, 1), jnp.float32), pltpu.VMEM((nh, tq, hd), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((nh, tq, _LANES), jnp.float32), pltpu.VMEM((nh, tq, _LANES), jnp.float32), pltpu.VMEM((nh, tq, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, nh, T, hd), q.dtype), interpret=interpret, name="indexed_prefill_attention",
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=100 << 20)}),
-    )(lengths.astype(jnp.int32), q, k, v, qi, w, ki, thr, cut)
+    )(lengths.astype(jnp.int32), q, k, v, table)
 
 
 # --------------------------------------------------------------------------- the sequence form
@@ -295,17 +324,17 @@ def indexed_attention_seq(q, k, v, qi, w, ki, lengths, topk: int, tile: int = 12
     [B,J,T,d], w [B,T,J] float32 and ki [B,T,d]; lengths [B]: true lengths of the right-padded
     sequences -> o [B,nh,T,hd]: every query's softmax attention over the ``topk`` positions at or
     before it that its index scores highest (all of them while it has at most ``topk``). Two
-    kernels (thresholds under ``indexed.select``, attention under ``indexed.attend``, each with
-    the tile's scores computed inside it) unless ``refusal`` gives a reason; then ``tile`` queries
+    kernels (thresholds and the choice a bit a pair under ``indexed.select``, attention under the
+    bits under ``indexed.attend``) unless ``refusal`` gives a reason; then ``tile`` queries
     at a time against ALL positions in XLA: keys, thresholds, a mask."""
     B, nh, T, hd = q.shape
     G, J = k.shape[1], qi.shape[1]
     if refusal(q.dtype, hd, qi.shape[-1], T, mesh) is None:
         interpret = jax.default_backend() != "tpu"  # off the TPU only a test gets here (it swaps ``refusal``), and runs the same bodies interpreted
         with scope("indexed.select"):
-            thr, cut = thresholds_kernel(qi, w, ki, lengths, topk, interpret=interpret)
+            table = thresholds_kernel(qi, w, ki, lengths, topk, interpret=interpret)
         with scope("indexed.attend"):
-            return attend_indexed_kernel(q, k, v, qi, w, ki, thr, cut, lengths, interpret=interpret)
+            return attend_indexed_kernel(q, k, v, table, lengths, interpret=interpret)
     Q = min(tile, T)
     pad = -T % Q
     if pad:  # what is padded lies after every real position and is cut off

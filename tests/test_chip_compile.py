@@ -1073,20 +1073,23 @@ def test_lfm2_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_v
 # ---------------------------------------------------------------------------
 def test_both_kernels_of_the_indexed_prefill_compile_for_v5e_at_24576_positions(one_chip, as_on_a_tpu):
     """The thresholds' kernel (a tile of 256 queries' index keys against every earlier position in
-    25 MB of fast memory, then the two bisections) and the attention kernel that computes a tile's
-    keys again and masks by the thresholds, at 32 heads over 4 of 128 under 16 x 64 at 24,576
-    positions: the gate lets the shape through, both lower to Mosaic under their names, and neither
-    holds a [T, T] array (2.4 GB in float32) or a table a pair."""
+    25 MB of fast memory, the two bisections, then the choice packed a bit a pair) and the attention
+    kernel that reads a tile's bits and does attention's work alone, at 32 heads over 4 of 128 under
+    16 x 64 at 24,576 positions: the gate lets the ladder's buckets through and no other, both lower
+    to Mosaic under their names, the table is T x T / 8 bytes a sequence (75.5 MB) and all the first
+    kernel hands on, and neither holds a [T, T] array of scores, keys or bytes."""
     from ray_tpu.ops import indexed_attention as ia
 
-    assert ia.refusal(jnp.bfloat16, 128, 64, 24576) is None and ia.refusal(jnp.bfloat16, 128, 64, 4096) is None
+    assert all(ia.refusal(jnp.bfloat16, 128, 64, T) is None for T in (4096, 8192, 16384, 24576))
+    assert "whole groups of 4096" in ia.refusal(jnp.bfloat16, 128, 64, 6144)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     T = 24576
     qi, w, ki, n = sds((1, 16, T, 64), jnp.bfloat16), sds((1, T, 16), jnp.float32), sds((1, T, 64), jnp.bfloat16), sds((1,), jnp.int32)
     compiled, txt = _compile(lambda qi, w, ki, n: ia.thresholds_kernel(qi, w, ki, n, 2048), qi, w, ki, n)
-    assert "tpu_custom_call" in txt and "indexer_thresholds" in txt and compiled.memory_analysis().output_size_in_bytes < 2 * T * 128 * 4 + 4096
-    q, k, thr = sds((1, 32, T, 128), jnp.bfloat16), sds((1, 4, T, 128), jnp.bfloat16), sds((1, T, 128), jnp.int32)
-    compiled, txt = _compile(ia.attend_indexed_kernel, q, k, k, qi, w, ki, thr, thr, n)
+    assert "tpu_custom_call" in txt and "indexer_thresholds" in txt
+    assert T * T // 8 == 75_497_472 <= compiled.memory_analysis().output_size_in_bytes < T * T // 8 + 4096
+    q, k, table = sds((1, 32, T, 128), jnp.bfloat16), sds((1, 4, T, 128), jnp.bfloat16), sds((1, T, ia.choice_words(T)), jnp.int32)
+    compiled, txt = _compile(ia.attend_indexed_kernel, q, k, k, table, n)
     assert "tpu_custom_call" in txt and "indexed_prefill_attention" in txt and compiled.memory_analysis().output_size_in_bytes < 32 * T * 128 * 2 + 4096
 
 
@@ -1116,7 +1119,10 @@ def test_keye_prefill_of_the_24576_bucket_fits_beside_weights_and_cache_on_one_v
     thresholds' kernel under ``indexed.select``, the attention kernel under ``indexed.attend``, 196,608
     routed pairs through the grouped matmul in slabs) beside 8.15 GiB of weights and 3.59 GiB of cache:
     under 15.75 GiB. (Two prompts take 2.74 GiB of temporaries and 0.6 of results: they fit too, and
-    the cell warms that shape; four do not, and the engine never asks for them.)"""
+    the cell warms that shape; four do not, and the engine never asks for them.) What passes from
+    the first kernel to the second is a layer's choice table, a bit a pair (72 MiB): the program holds
+    no array with both a query's and a position's axis (a byte a pair would be 576 MiB)."""
+    import re
     from ray_tpu.llm import hybrid_runner as hr
 
     cfg, params, _, _ = _cell_at_its_size(one_chip, "keye")
@@ -1127,5 +1133,8 @@ def test_keye_prefill_of_the_24576_bucket_fits_beside_weights_and_cache_on_one_v
     print("keye prefill:", mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
     kernels = [line for line in txt.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
     assert kernels and any("indexed.select" in line for line in kernels) and any("indexed.attend" in line for line in kernels)
+    assert "s32[1,24576,768]" in txt, "the choice table: 24,576 / 32 words a query"
+    pairs = sorted(set(re.findall(r"\b\w+\[(?:\d+,)*24576,(?:\d+,)*24576(?:,\d+)*\]", txt)))
+    assert not pairs, f"an array a (query, position) pair: {pairs}"
     assert mem.temp_size_in_bytes < 1.7 * 2**30  # 1.50 GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 3.59 * 2**30 < 15.75 * 2**30

@@ -94,7 +94,7 @@ def test_the_description_is_one_period_of_two_sub_blocks_and_keeps_three_entries
     # the counters, from lengths alone: every causal pair scored in a bucket over topk, min(t + 1, topk) read a query
     n, k = 20500, 2048
     chosen = n * (n + 1) // 2 - (n - k) * (n - k + 1) // 2
-    assert cut.prefill_counters(1, 24576, lengths=[n]) == {"pairs_scored": 6 * n * (n + 1) // 2, "pairs_chosen": 6 * chosen}
+    assert cut.prefill_counters(1, 24576, lengths=[n]) == {"pairs_scored": 6 * n * (n + 1) // 2, "pairs_chosen": 6 * chosen}  # off the TPU the XLA form runs: no ``choice_bytes``
     assert chosen == sum(min(t + 1, k) for t in range(n))
     assert cut.prefill_counters(2, 2048, lengths=[100, 2000]) == {"pairs_scored": 0, "pairs_chosen": 6 * (5050 + 2000 * 2001 // 2)}
     assert cut.decode_counters([20000, 2048, 17]) == {"rows_scored": 6 * 22065, "rows_chosen": 6 * (2048 + 2048 + 17)}
@@ -127,6 +127,12 @@ def _operands(B, nh, G, T, hd, J, d, seed, ties=False):
     return q, k, v, qi, w, ki
 
 
+def _small_tiles(monkeypatch):
+    """The kernels' tiles and lanes at 32: a word's 32 bits are 32 chunks of 32 positions, a group 1,024."""
+    for name in ("_TILE_Q", "_TILE_K", "_LANES"):
+        monkeypatch.setattr(ia, name, 32)
+
+
 @pytest.mark.parametrize("ties", [False, True])
 def test_the_threshold_chooses_what_a_stable_sort_chooses_with_ties_at_the_threshold(ties):
     """``threshold``'s two bisections (32 passes over the keys' bits, one a bit of a position) and
@@ -157,13 +163,101 @@ def test_both_forms_of_the_sequence_op_are_attention_under_the_choice_by_hand(ti
     want = indexed_attention_by_hand(*args, 16)
     np.testing.assert_allclose(ia.indexed_attention_seq(*args, lengths, 16, tile=32), want, atol=2e-5)
     monkeypatch.setattr(ia, "refusal", lambda *a, **kw: None)
-    monkeypatch.setattr(ia, "_TILE_Q", 32)
-    monkeypatch.setattr(ia, "_TILE_K", 32)
-    monkeypatch.setattr(ia, "_LANES", 32)
+    _small_tiles(monkeypatch)
     got = np.asarray(ia.indexed_attention_seq(*args, lengths, 16))
     np.testing.assert_allclose(got[0], want[0], atol=2e-5)
     np.testing.assert_allclose(got[1, :, :60], want[1, :, :60], atol=2e-5)
     assert not got[1, :, 64:].any(), "a tile of queries past the true length is skipped: zeros"
+
+
+def _unpacked(table):
+    """int32 [B,T,words] -> bool [B,T,T]: bit ``c`` of lane ``l`` of a group's words is the group's position
+    ``c * lanes + l`` (``ia.choice_words``); what a last group holds past T is zeros."""
+    B, T, _ = table.shape
+    words = np.asarray(table).view(np.uint32).reshape(B, T, -1, 1, ia._LANES)
+    bits = ((words >> np.arange(ia._WORD, dtype=np.uint32)[:, None]) & 1).reshape(B, T, -1)  # [B,T,groups x 32 chunks x lanes]
+    assert not bits[..., T:].any()
+    return bits[..., :T].astype(bool)
+
+
+def _packed(mask):
+    """bool [B,T,T] -> int32 [B,T,words]: ``_unpacked``'s inverse."""
+    B, T, _ = mask.shape
+    whole = np.zeros((B, T, ia.choice_words(T) * ia._WORD), np.uint32)
+    whole[..., :T] = mask
+    chunks = whole.reshape(B, T, -1, ia._WORD, ia._LANES) << np.arange(ia._WORD, dtype=np.uint32)[:, None]
+    return jnp.asarray(np.bitwise_or.reduce(chunks, axis=3).reshape(B, T, -1).view(np.int32))
+
+
+def _causal_keys(qi, w, ki):
+    """One sequence's index keys [T,T], ``INT_MIN`` after a query: what ``threshold`` and ``chosen`` take."""
+    at = jnp.arange(ki.shape[0])
+    return jnp.where(at[None, :] <= at[:, None], ia.index_keys(lambda j: ia._dot_nt(qi[j], ki), w, qi.shape[0]), ia.INT_MIN)
+
+
+@pytest.mark.parametrize("ties,topk", [(False, 16), (True, 16), (False, 40)])
+def test_the_thresholds_kernel_hands_on_the_choice_a_bit_a_pair(ties, topk, monkeypatch):
+    """The table of the thresholds' kernel (interpreted, tiles of 32), unpacked, IS ``chosen`` of the
+    keys and their ``threshold``, for two sequences of unequal length: rows with fewer candidates
+    than top-k (all of them, and nothing after the query), rows with ties at the threshold, a first
+    tile that holds no more positions than top-k (top-k 40: the pass without the ties' cut); zeros
+    in the second sequence's tile of padding and at every position after a query."""
+    _small_tiles(monkeypatch)
+    _, _, _, qi, w, ki = _operands(2, 4, 2, 96, 16, 2, 8, 9, ties)
+    lengths = (96, 60)
+    table = ia.thresholds_kernel(qi, w, ki, jnp.asarray(lengths, jnp.int32), topk, interpret=True)
+    assert table.shape == (2, 96, ia.choice_words(96)) == (2, 96, 32) and table.dtype == jnp.int32
+    got = _unpacked(table)
+    for b, n in enumerate(lengths):
+        keys = _causal_keys(qi[b], w[b], ki[b])
+        want = np.asarray(ia.chosen(keys, *ia.threshold(keys, topk)))
+        live = -(-n // 32) * 32
+        assert (got[b, :live] == want[:live]).all() and not got[b, live:].any()
+        assert (got[b, :live].sum(-1) == np.minimum(np.arange(live) + 1, topk)).all()
+    assert not np.triu(got, 1).any()
+
+
+def test_the_attention_kernel_under_a_handed_table_gives_no_weight_to_tiles_that_hold_no_chosen_position(monkeypatch):
+    """An index that grows with the position: every query's 16 best are the 16 LAST positions at or
+    before it, so a query of the last tile of 32 meets two or three tiles of keys in which nothing
+    is chosen before it meets a chosen position: its running maximum stays at the floor through
+    them, its sums stay zeros, and the values there (1e30, a NaN times zero away from the result)
+    leave no trace. Under a table packed by hand the kernel (interpreted) is the XLA form and the
+    sum by hand to float32's rounding, and the thresholds' kernel hands on that very table."""
+    _small_tiles(monkeypatch)
+    B, nh, G, T, hd, topk = 2, 4, 2, 128, 16, 16
+    q, k, v, _, _, _ = _operands(B, nh, G, T, hd, 1, 8, 11)
+    v = v.at[:, :, :64].set(1e30)
+    qi, w = jnp.ones((B, 1, T, 8)), jnp.ones((B, T, 1))
+    ki = jnp.broadcast_to((jnp.arange(T, dtype=jnp.float32) / T)[None, :, None], (B, T, 8))
+    lengths = jnp.asarray([T, T], jnp.int32)
+    keys = _causal_keys(qi[0], w[0], ki[0])  # both sequences' index is the same
+    mask = np.broadcast_to(np.asarray(ia.chosen(keys, *ia.threshold(keys, topk))), (B, T, T))
+    at = np.arange(T)
+    assert (mask[0] == ((at[None, :] <= at[:, None]) & (at[None, :] > at[:, None] - topk))).all() and not mask[:, 111:, :96].any()
+    got = np.asarray(ia.attend_indexed_kernel(q, k, v, _packed(mask), lengths, interpret=True))
+    assert np.isfinite(got).all() and np.abs(got[:, :, 96:]).max() < 10.0
+    want = indexed_attention_by_hand(q, k, v, qi, w, ki, topk)
+    np.testing.assert_allclose(got[:, :, 96:], want[:, :, 96:], atol=2e-6)
+    np.testing.assert_allclose(got[:, :, 96:], np.asarray(ia.indexed_attention_seq(q, k, v, qi, w, ki, lengths, topk, tile=32))[:, :, 96:], atol=2e-6)  # the gate refuses here: the XLA form
+    assert (np.asarray(ia.thresholds_kernel(qi, w, ki, lengths, topk, interpret=True)) == np.asarray(_packed(mask))).all()
+
+
+def test_the_choice_tables_bytes_are_counted_from_the_programs_shape(monkeypatch):
+    """``choice_bytes``: a bit a pair of the bucket, a layer and a row of the program, where the two
+    kernels run; none (0 to the step row's sum) for a bucket of at most ``index_topk`` (the flash
+    kernel), for one the packing does not take and off the TPU (the XLA form)."""
+    from ray_tpu.llm import telemetry
+
+    cut = dataclasses.replace(kv.KeyeVLConfig(), num_hidden_layers=6)
+    table_bytes = lambda *a, **kw: cut.prefill_counters(*a, **kw).get("choice_bytes", 0)  # noqa: E731
+    assert table_bytes(2, 24576, lengths=[20500, 17000]) == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert table_bytes(1, 24576, lengths=[20500]) == 6 * 24576 * 24576 // 8 == 452_984_832
+    assert table_bytes(2, 24576, lengths=[20500, 17000]) == 2 * 452_984_832  # by shape: the lengths do not enter
+    assert table_bytes(2, 4096, lengths=[3000, 2100]) == 6 * 2 * 4096 * 4096 // 8
+    assert table_bytes(4, 2048, lengths=[2048] * 4) == 0 and table_bytes(1, 6144, lengths=[6000]) == 0
+    assert "whole groups" in ia.refusal(jnp.bfloat16, 128, 64, 6144) and "choice_bytes" in telemetry.PREFILL_COUNTERS
 
 
 def test_the_decode_step_attends_to_the_chosen_rows_of_the_stacked_cache():
@@ -254,12 +348,15 @@ def test_padding_is_never_chosen(params):
 
 def test_both_kernels_interpreted_serve_what_the_xla_form_serves(params, monkeypatch):
     """Off the TPU the gate refuses; swapped open, the thresholds' kernel and the attention kernel run
-    interpreted through the engine's prefill (tiles of 32), a dense lane beside two that choose."""
+    interpreted through the engine's prefill (tiles of 32), a dense lane beside two that choose; the
+    admitting rows of the flight log carry the tables' bytes, a group of 1,024 positions a query,
+    layer and row of the two programs over top-k (buckets of 64 and 32) and none for the bucket of 16."""
     monkeypatch.setattr(ia, "refusal", lambda *a, **kw: None)
-    monkeypatch.setattr(ia, "_TILE_Q", 32)
-    monkeypatch.setattr(ia, "_TILE_K", 32)
-    monkeypatch.setattr(ia, "_LANES", 32)
+    _small_tiles(monkeypatch)
     ps = battery.prompts(DESC, 24, (50, 28, 9))
     sp = [SamplingParams(max_tokens=8, temperature=0.0, logprobs=True)] * 3
-    res = battery.check(DESC, params, battery.served(battery.engine(CFG, params).generate(ps, sp), ps, sp))
+    eng = battery.engine(CFG, params)
+    res = battery.check(DESC, params, battery.served(eng.generate(ps, sp), ps, sp))
     assert res["ok"] and res["tokens"] == 24 and res["max_abs_dlogprob"] < DESC.agrees_to, res
+    admitting = [r for r in battery.steps_after(eng, 0) if r.get("admitted")]
+    assert sum(r.get("choice_bytes", 0) for r in admitting) == 3 * (64 + 32) * ia.choice_words(64) * 4 == 3 * 96 * 128
