@@ -85,7 +85,7 @@ logger = logging.getLogger("ray_tpu.llm")
 # takes on that step's row; then what the engine counts of any model's from its ``flash_calls``
 # (``ops/flash_attention.query_tiles``) and from the rows' lengths (``ops/layers.live_rows``, a description's ``prefill_rows_live``)
 PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs",
-                    "narrow_pairs", "pairs_scored", "pairs_chosen", "choice_bytes", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live")
+                    "narrow_pairs", "pairs_scored", "pairs_chosen", "choice_bytes", "selscan_positions", "selscan_kernel_positions", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
 DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read", "narrow_rows_read", "rows_scored", "rows_chosen")
 
@@ -448,7 +448,9 @@ class FlightRecorder:
         # (``narrow_pairs``: i + 1 a position and layer); (query, position) pairs that the layers under a learned index score
         # (``pairs_scored``: every causal pair of a bucket longer than the top-k) and attend to (``pairs_chosen``: min(i + 1, top-k) a
         # position and layer), and the bytes of choice tables, a bit a pair, that the programs' thresholds' kernels wrote by their shape
-        # (``choice_bytes``: 0 where the XLA form or the flash kernel ran); absent for a description that counts none. Last, of any model: the query tiles that the programs' flash calls
+        # (``choice_bytes``: 0 where the XLA form or the flash kernel ran); positions, as padded, that the programs' Mamba-1 layers scan
+        # (``selscan_positions``: batch rows x bucket x layers) and how many of them the kernel ran (``selscan_kernel_positions``,
+        # ``ops/selective_scan.py``: all, or 0 where the XLA form ran); absent for a description that counts none. Last, of any model: the query tiles that the programs' flash calls
         # have by their shape (``attn_q_tiles``: calls x batch rows x tiles of the bucket) and those that start
         # under a row's true length (``attn_q_tiles_live``): the kernel computes and fetches these alone;
         # and the positions that a position-wise sub-block of the programs runs (``prefill_rows_live``: a dense

@@ -46,11 +46,12 @@ Two loops over one description:
 
 Neither loop, nor the step programs of ``llm/hybrid_runner.py`` that call them,
 names a model or a kind of layer. A uniform model is the special case of one kind
-and a period of one. Eight descriptions stand over these loops today
+and a period of one. Nine descriptions stand over these loops today
 (``nemotron_h``, ``qwen3_next``, ``glm4_moe_lite``, ``kimi_linear``, ``minicpm_sala``,
-``smallthinker``, ``lfm2``, ``keye_vl``, each a file beside this one; the eighth needed no line
-of ``llm/engine.py`` or ``llm/hybrid_runner.py``: its third per-position entry, the indexer's key,
-is one more row of ``cache_spec()``), and the benchmark has a family file for each and a ninth
+``smallthinker``, ``lfm2``, ``keye_vl``, ``jamba``, each a file beside this one; the eighth and the
+ninth needed no line of ``llm/engine.py`` or ``llm/hybrid_runner.py``: a third per-position entry,
+the indexer's key, and a state of another shape, ``[states, channels]`` with no heads, are each one
+more row of ``cache_spec()``), and the benchmark has a family file for each and a tenth
 for ``llama``, which the runner still writes out itself (``benchmark/families/``).
 """
 

@@ -57,7 +57,8 @@ SCOPES = {
     "lightning": "mixer", "lightning.chunk": "mixer",
     "shortconv": "mixer", "shortconv.conv": "mixer",
     "indexed": "mixer", "indexed.score": "mixer", "indexed.select": "mixer", "indexed.attend": "mixer",
-    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state", "lightning.state": "state", "shortconv.state": "state",
+    "mamba1": "mixer", "mamba1.conv": "mixer", "mamba1.scan": "mixer",
+    "gdn.state": "state", "kda.state": "state", "mamba2.state": "state", "lightning.state": "state", "shortconv.state": "state", "mamba1.state": "state",
     "mlp": "ffn", "ffn": "ffn",
     "moe": "ffn", "moe.route": "ffn", "moe.place": "ffn", "moe.place.count": "ffn", "moe.place.into": "ffn", "moe.place.out": "ffn",
     "moe.blocks": "ffn", "moe.shared": "ffn",

@@ -379,7 +379,8 @@ CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widt
          "sala": ("minicpm-sala-9b-d8.json", {"remat": False}),  # layers 9-16 of 32, the whole vocabulary, 16 slots x 12,288
          "smallthinker": ("smallthinker-21b-a3b-d8.json", {"remat": False}),  # layers 0-7 of 52, every expert, the whole vocabulary, 16 slots x 12,288
          "lfm2": ("lfm2-24b-a2b-d10.json", {"remat": False}),  # layers 0-9 of 40, every expert, the whole vocabulary, 16 slots x 12,288
-         "keye": ("keye-vl-2.0-30b-a3b-d6.json", {"remat": False})}  # layers 24-29 of 48, every expert, the whole vocabulary, 12 slots x 24,576
+         "keye": ("keye-vl-2.0-30b-a3b-d6.json", {"remat": False}),  # layers 24-29 of 48, every expert, the whole vocabulary, 12 slots x 24,576
+         "jamba": ("jamba2-3b.json", {"remat": False})}  # the published model whole: 28 layers, the whole vocabulary, 16 slots x 12,288
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -1138,3 +1139,75 @@ def test_keye_prefill_of_the_24576_bucket_fits_beside_weights_and_cache_on_one_v
     assert not pairs, f"an array a (query, position) pair: {pairs}"
     assert mem.temp_size_in_bytes < 1.7 * 2**30  # 1.50 GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 3.59 * 2**30 < 15.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# PR 60: a ninth description, AI21-Jamba2-3B (models/jamba.py): the cell jamba2-3b.longdoc-12k.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("prompts", [1, 2])
+def test_selective_scan_kernel_compiles_for_v5e_at_the_cells_bucket(one_chip, as_on_a_tpu, prompts):
+    """[12288, 5120] x 16 states in bfloat16: the gate lets it through, Mosaic takes the body (the state in VMEM
+    across a sequence's 96 blocks of 128 positions, ten runs of 512 channels a block, B and C broadcast over one
+    row of lanes), under its own name; what XLA makes beside it is the two broadcasts, 4 KB a position each."""
+    from ray_tpu.ops import selective_scan as ss
+
+    assert ss.refusal(jnp.bfloat16, 5120, 16) is None and ss.refusal(jnp.bfloat16, 5120 + 128, 16) is not None
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    bf, f32, T, W, N = jnp.bfloat16, jnp.float32, 12288, 5120, 16
+    args = (sds((prompts, T, W), bf), sds((prompts, T, W), bf), sds((W, N), f32), sds((prompts, T, N), bf), sds((prompts, T, N), bf), sds((W,), f32), sds((W,), f32), sds((prompts,), jnp.int32))
+    compiled, txt = _compile(ss.selective_scan, *args)
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in txt and ss.KERNEL in txt
+    assert mem.output_size_in_bytes < prompts * (T * W * 2 + N * W * 4) + 4096 and mem.temp_size_in_bytes <= 2 * prompts * T * N * 128 * 2 + (1 << 20)
+
+
+def test_slot_attention_kernel_at_20_heads_over_one_compiles_for_v5e_and_copies_nothing(one_chip, as_on_a_tpu):
+    """20 query heads over ONE key-value head 128 wide at 16 x 12,288 (32 rows of queries a lane, as padded): the gate
+    lets the tile through and Mosaic compiles it, the stack read where it lies."""
+    from ray_tpu.ops import slot_attention as sa
+
+    assert sa.refusal(jnp.bfloat16, 20, 1, 128, 12288) is None
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    stack = sds((2, 16, 12288, 1, 128), jnp.bfloat16)
+    compiled, txt = _compile(sa.attend_kernel, sds((16, 20, 128), jnp.bfloat16), stack, stack, sds((), jnp.int32), sds((16,), jnp.int32))
+    assert "tpu_custom_call" in txt and sa.KERNEL in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_jamba_fused_step_fits_one_v5e_aliases_rows_state_and_windows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 16 x 12,288 through the SAME ``hybrid_runner.fused_step`` and layer loop as the eight other
+    descriptions, its scan body 28 sub-blocks long (the longest period any description has had), twice: 5.64 GiB
+    of weights (no ``unembed``: the head is the table), 0.19 GiB of keys and values at 1,024 B a position and 0.14 GiB
+    of state (states x channels, float32: dense in the 128 lanes) and windows, all of it aliased to the donated inputs;
+    the live-block kernel in the two attention layers, the state's read, decay and write under ``mamba1.state``."""
+    cfg, params, cache, state, compiled = fused_step_for_the_chip("jamba")
+    assert len(cfg.layer_plan.period) == 28 and cfg.layer_plan.repeats == 2 and "unembed" not in params
+    assert {n: a.shape for n, a in cache.items() if n != "length"} == {"k": (2, 16, 12288, 1, 128), "v": (2, 16, 12288, 1, 128)}
+    assert {n: (a.shape, str(a.dtype)) for n, a in state.items()} == {"ssm": ((26, 16, 16, 5120), "float32"), "conv": ((26, 16, 3, 5120), "bfloat16")}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert _kv_bytes(cache) == 201_326_592 and _kv_bytes(state) == 16 * 9_318_400
+    print("jamba fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20, mem.generated_code_size_in_bytes / 2**20)
+    assert 5.9 * 2**30 < mem.argument_size_in_bytes < 6.1 * 2**30 and mem.alias_size_in_bytes >= _kv_bytes(cache) + _kv_bytes(state)
+    assert all(name in txt for name in ("slot_decode_attention", "mamba1.state", "mamba1.conv"))
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 1.0), (4, 3.2)])
+def test_jamba_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 12,288-bucket prefill (the selective scan's kernel a sequence at a time under ``mamba1.scan``, the flash kernel
+    at 20 heads over one under ``attn``, an 8,192-wide SwiGLU in slabs) for one prompt and for the largest group the
+    cell warms, 4 x 12,288, beside 5.64 GiB of weights and 0.33 GiB of caches: under 15.75 GiB."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "jamba")
+    tokens = jax.ShapeDtypeStruct((prompts, 12288), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("jamba prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    kernels = [line for line in txt.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert any("/attn/" in line for line in kernels) and any("mamba1.scan" in line and "selective_scan" in line for line in kernels)
+    assert all("/attn/" in line or "mamba1.scan" in line for line in kernels), "two kernels, each under its scope"
+    assert "mamba1.conv" in txt
+    assert mem.temp_size_in_bytes < most_gib * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.33 * 2**30 < 15.75 * 2**30

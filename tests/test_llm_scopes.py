@@ -28,7 +28,8 @@ PROGRAMS = {"llama": SLOTS, "llama_paged": PAGED, "nemotron_h": HYBRID, "qwen3_n
             "glm4_moe_lite": [p for p in HYBRID if p != "llm_state_insert"],  # latent attention keeps nothing per sequence
             "kimi_linear": HYBRID,  # a state a sequence AND a latent a position
             "minicpm_sala": HYBRID,  # keys and values a position, a state and the compressed keys a sequence
-            "keye_vl": [p for p in HYBRID if p != "llm_state_insert"]}  # keys, values and the indexer's key a position, nothing a sequence
+            "keye_vl": [p for p in HYBRID if p != "llm_state_insert"],  # keys, values and the indexer's key a position, nothing a sequence
+            "jamba": HYBRID}  # keys and values a position in two layers, a state and a window a sequence in the rest
 
 
 class Recording:
@@ -69,6 +70,10 @@ def _config(description):
         from ray_tpu.models.keye_vl import KeyeVLConfig
 
         return KeyeVLConfig.tiny()  # top-k 16: the prompt of 40 goes through the index (its bucket holds 64), the one of 9 through the flash call
+    if description == "jamba":
+        from ray_tpu.models.jamba import JambaConfig
+
+        return JambaConfig.tiny()
     from ray_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
 
     return Glm4MoeLiteConfig.tiny()
@@ -212,6 +217,45 @@ def test_a_prefills_blocks_stand_under_moe_blocks_whichever_way_they_are_run(des
     assert any("/moe.blocks/while/" in path for path in under["stablehlo.dot_general"]) == (runs == "loop")
     assert lowering.out_info[2][hybrid.ROUTING].shape == (4 if runs == "kernel" else 3,)
     assert unscoped_ops(lowering) == []
+
+
+@pytest.mark.parametrize("runs", ["xla", "kernel"])
+def test_a_mamba1_layers_parts_stand_under_their_four_scopes_whichever_way_the_scan_is_run(lowered, runs, monkeypatch):
+    """PR 60: in a prefill the convolution stands under ``mamba1.conv`` and the recurrence under ``mamba1.scan`` (as XLA's
+    ``while`` over positions, and as the kernel of ``ops/selective_scan.py``: the test answers for its ``refusal`` and the
+    body lowers as the interpreter runs it), both INSIDE ``mamba1``, whose own operations are the projections; in the
+    fused step the state's and the window's read, decay and write stand under ``mamba1.state``: what the three new
+    readers and ``decode_state_ms`` go by."""
+    from functools import partial
+
+    from jax._src.lib.mlir import ir
+
+    from ray_tpu.ops import selective_scan
+
+    def scopes_of(lowering):
+        found = {}
+
+        def visit(op):
+            found.setdefault(scope_of(_name(op)), set()).add(op.operation.name)
+            return ir.WalkResult.ADVANCE
+
+        lowering.compiler_ir().operation.walk(visit)
+        return found
+
+    if runs == "kernel":
+        monkeypatch.setattr(selective_scan, "refusal", lambda *a, **kw: None)
+    cfg = _config("jamba")
+    params = jax.eval_shape(lambda: cfg.init_params(jax.random.PRNGKey(0)))
+    prefill = jax.jit(partial(hybrid_runner.prefill, cfg=cfg)).lower(params, jax.ShapeDtypeStruct((2, 32), "int32"), jax.ShapeDtypeStruct((2,), "int32"))
+    found = scopes_of(prefill)
+    assert {"mamba1", "mamba1.conv", "mamba1.scan", "attn", "ffn"} <= set(found) and "mamba1.state" not in found
+    # XLA's form is the recurrence's own operations under the scope; the interpreter walks the kernel's grid in functions it calls from there
+    assert ("stablehlo.exponential" if runs == "xla" else "func.call") in found["mamba1.scan"] and "stablehlo.dot_general" in found["mamba1"] and "stablehlo.dot_general" not in found["mamba1.scan"] | found["mamba1.conv"]
+    assert unscoped_ops(prefill) == []
+    step = scopes_of(lowered("jamba")["llm_hybrid_fused_step"])
+    assert {"mamba1", "mamba1.conv", "mamba1.state"} <= set(step) and "mamba1.scan" not in step
+    assert {"stablehlo.exponential", "stablehlo.dynamic_update_slice"} <= step["mamba1.state"]
+    assert all(SCOPES[n] == role for n, role in (("mamba1", "mixer"), ("mamba1.conv", "mixer"), ("mamba1.scan", "mixer"), ("mamba1.state", "state")))
 
 
 @pytest.mark.parametrize("description", sorted(PROGRAMS))
