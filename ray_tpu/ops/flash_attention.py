@@ -21,10 +21,28 @@ _NEG_INF = -1e30
 
 
 def _default_blocks(head_dim: int) -> tuple[int, int]:
-    """Flash tile sizes: 1024x1024 measured fastest on v5e for hd<=128
+    """Flash tile sizes by head width: 1024x1024 measured fastest on v5e for hd<=128
     (0.595 vs 0.568 MFU at 512x512 on the bench model); larger head dims
-    fall back to 512 to stay inside VMEM."""
+    fall back to 512: the backward kernels need that to stay inside VMEM (four
+    float32 score tiles live at once), the forward kernel does not where a call
+    is long (``_fwd_blocks``)."""
     return (1024, 1024) if head_dim <= 128 else (512, 512)
+
+
+def _fwd_blocks(head_dim: int, T: int) -> tuple[int, int]:
+    """The forward kernel's tiles for a call over ``T`` query positions, the shape's alone:
+    ``_default_blocks``', and 1,024 x 1,024 at heads up to 256 wide too where the call holds
+    eight such query tiles or more. A tile twice as tall and as wide pays a grid step's fixed
+    cost a quarter as often and computes its diagonal tiles whole, and skips a row's padding
+    by 1,024 positions where it skipped by 512: 20 heads x 256 x 16,384 take 19.9 ms for 24.5
+    and 9.3 for 13.3 at a true length of 10,000, and a prompt of 2,500 in a bucket of 4,096
+    computes six tile pairs of 1,024 for fifteen of 512, 60% more (PERF.md section 6, PR 61:
+    a cell of such buckets lost half to one percent at the larger tile). Everything that
+    asks for a call's query tile asks ``_query_tile``, which asks here."""
+    bq, bk = _default_blocks(head_dim)
+    if head_dim <= 256 and T >= 8 * 1024:
+        bq, bk = max(bq, 1024), max(bk, 1024)
+    return bq, bk
 
 
 # ----------------------------------------------------------------------
@@ -63,7 +81,11 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, window=None, lengths=Fal
     wholly before the window of its first query is skipped as a tile above the diagonal is (and not
     fetched: ``_fwd_pallas``'s index map holds the tile before it), the edge tiles are masked.
     ``lengths``: the first ref is the prefetched true length of each grid row, and a query tile that
-    starts at or past its row's is skipped whole: ``_init`` and ``_finalize`` make zeros of it."""
+    starts at or past its row's is skipped whole: ``_init`` and ``_finalize`` make zeros of it.
+    A row's running max ``m_scr`` and sum ``l_scr`` are [block_q, 1] columns. Held in every lane
+    of a [block_q, 128] tile they were the same bits and 1-5% of this kernel at its tiles, one head
+    a grid step beside a score tile of 512 x 512 or more, and nothing a cell showed (PERF.md
+    section 6, PR 61): not kept."""
     from jax.experimental import pallas as pl
 
     lens_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr) = (refs[0], refs[1:]) if lengths else (None, refs)
@@ -138,7 +160,7 @@ def _fwd_pallas(q, k, v, causal=True, scale=None, block_q=None, block_k=None, wi
     if not causal and (window is not None or lengths is not None):
         raise ValueError("a window and true lengths are causal: keys i - window < j <= i < length")  # tpulint: disable=ERR002 — a programmer's error at trace time
     lengths = _skippable(lengths, T, D, block_q)
-    dq, dk = _default_blocks(D)
+    dq, dk = _fwd_blocks(D, T)
     block_q = min(block_q or dq, T)
     block_k = min(block_k or dk, Tk)
     grid = (B * H, pl.cdiv(T, block_q), pl.cdiv(Tk, block_k))
@@ -431,8 +453,8 @@ def _broadcast_kv(q, k, v):
 
 
 def _query_tile(head_dim: int, T: int, block_q: int | None = None) -> int:
-    """Positions of one query tile of a call over ``T`` positions (at the default blocks, unless ``block_q`` says)."""
-    return min(block_q or _default_blocks(head_dim)[0], T)
+    """Positions of one query tile of a call over ``T`` positions (at the forward kernel's blocks, unless ``block_q`` says)."""
+    return min(block_q or _fwd_blocks(head_dim, T)[0], T)
 
 
 def _skippable(lengths, T: int, head_dim: int, block_q: int | None = None):

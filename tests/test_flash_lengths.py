@@ -2,6 +2,8 @@
 interpreted on the CPU: a query tile that starts at or past a row's length is skipped whole and comes
 out as zeros, every tile before it is what it is without ``lengths``, bit for bit; the XLA form keeps
 the same contract; and a call of ONE query tile never learns the lengths: it is the call without them.
+The tile itself is the shape's too (``_fwd_blocks``, PR 61): heads up to 256 wide run 1,024 x 1,024 where
+a call holds eight such query tiles or more, and the counter, the rule and the XLA form's zeros follow it.
 Nothing here says anything of a time."""
 
 import jax
@@ -50,7 +52,17 @@ def test_query_tiles_past_a_true_length_are_zeros_and_the_rest_is_the_call_witho
     with pltpu.force_tpu_interpret_mode():
         plain, plain_lse = fa._fwd_pallas(q, k, v, **kw)
         out, lse = fa._fwd_pallas(q, k, v, lengths=jnp.asarray(LENGTHS, jnp.int32), **kw)
-    np.testing.assert_allclose(plain, fa.attention_xla(q, k, v, causal=True, window=window), atol=2e-3)
+    ref, ref_lse = fa._fwd_xla_with_lse(q, k, v, True, None, window)
+    np.testing.assert_allclose(plain, ref, atol=2e-3)
+    # ``lse`` is the [B, H, T] it is by contract, and every row's is its own keys': under a window the
+    # rows at a query tile's end meet their first computed key tile with every pair outside (a maximum of _NEG_INF over a
+    # state of _NEG_INF: exp(0) is 1 a pair), which would count 128 keys more in ``l``, log(2) or more in ``lse``
+    assert plain_lse.shape == q.shape[:3]
+    np.testing.assert_allclose(plain_lse, ref_lse, atol=2e-3)
+    if window is not None:
+        first_tile = (np.arange(T) // TILE * TILE - window + 1).clip(0) // TILE  # the first key tile a row's query tile computes
+        unread = (np.arange(T) - window + 1).clip(0) >= (first_tile + 1) * TILE  # rows whose own window starts past it
+        assert unread[T - 1] and unread.sum() >= T // TILE - 1, "the case is in the operands: the last row of every query tile but the first"
     plain, plain_lse, out, lse = (np.asarray(a) for a in (plain, plain_lse, out, lse))
     for b, n in enumerate(LENGTHS):
         live = _live(n)
@@ -159,9 +171,26 @@ def test_lengths_are_causal(impl):
     (128, 2048, 3 * 5 * 2, 3 * (1 + 1 + 1 + 2 + 2)),
     (256, 2048, 3 * 5 * 4, 3 * (1 + 1 + 2 + 3 + 4)),
     (256, 512, 3 * 5 * 1, 3 * 5 * 1),
+    (256, 4096, 3 * 5 * 8, 3 * (1 + 1 + 2 + 3 + 8)),  # under eight tiles of 1,024: heads 256 wide keep tiles of 512
+    (256, 8192, 3 * 5 * 8, 3 * (1 + 1 + 1 + 2 + 8)),  # eight of them: the long call runs tiles of 1,024 (``_fwd_blocks``)
+    (512, 8192, 3 * 5 * 16, 3 * (1 + 1 + 2 + 3 + 16)),  # wider heads keep 512 at any length
 ])
 def test_the_counter_is_the_shapes_arithmetic(head_dim, length, tiles, live):
     """``attn_q_tiles``: calls x rows x tiles of the bucket; ``attn_q_tiles_live``: the tiles that start under a length."""
     lengths = [1, 512, 513, 1025, length]
     assert fa.query_tiles({head_dim: 3}, length, lengths) == {"attn_q_tiles": tiles, "attn_q_tiles_live": live}
     assert fa.query_tiles({}, length, lengths) == {"attn_q_tiles": 0, "attn_q_tiles_live": 0}
+
+
+@pytest.mark.parametrize("head_dim, length, tile", [(128, 2048, 1024), (128, 16384, 1024), (256, 4096, 512), (256, 8192, 1024), (256, 16384, 1024), (512, 8192, 512)])
+def test_the_forward_tile_is_the_shapes(head_dim, length, tile):
+    """``_fwd_blocks``: the tile of a head width (``_default_blocks``), and 1,024 x 1,024 up to heads 256 wide where a call
+    holds eight such query tiles or more; the kernel's grid and scratch and the tile of the rule, the counter and the XLA
+    form's zeros (``_query_tile``) are the same, and ``_default_blocks``, which the backward kernels ask, is what it was."""
+    assert fa._fwd_blocks(head_dim, length) == (tile, tile) and fa._query_tile(head_dim, length) == tile
+    assert fa._default_blocks(head_dim) == ((1024, 1024) if head_dim <= 128 else (512, 512))
+    shape = jax.ShapeDtypeStruct((1, 2, length, head_dim), jnp.bfloat16)
+    n = jax.ShapeDtypeStruct((1,), jnp.int32)
+    (call,) = _pallas_calls(lambda q, k, v, n: fa._fwd_pallas(q, k, v, lengths=n), shape, shape, shape, n)
+    assert call["grid_mapping"].grid == (2, length // tile, length // tile)
+    assert [a.shape for a in call["grid_mapping"].scratch_avals] == [(tile, 1), (tile, 1), (tile, head_dim)]
