@@ -9,7 +9,18 @@ below are then collected there, against that description, beside what is truly t
 A fourth description costs a ``DESC``, not a file of copies. ``eng`` is ONE engine a module at the
 default arguments: for a test that leaves it as it found it, or plants its fault on the engine
 object through ``monkeypatch``; a test that patches a module before the step programs are traced,
-or needs other arguments, builds its own with ``engine(...)``."""
+or needs other arguments, builds its own with ``engine(...)``.
+
+An engine is what a test pays for (three prefill buckets and a fused step traced and compiled: 7-20 s
+for a model of a few layers), so a test builds the LEAST one that shows its point, and a file's cost
+is the count of its engines. The rule for a planted fault (``Fault``), cheapest first:
+(a) a fault that can be said in the WEIGHTS the engine serves, or on the engine object, is planted on
+the module's ``eng`` (``with_params``, ``slot_not_reset``, ``padded_length``): nothing is traced, the
+case costs its four prompts and the reference's run, about a second;
+(b) a fault in TRACED code or in the description (``patched``, ``bf16_state``, a window one wider) needs
+programs of its own: it builds ``least_engine``, ONE bucket that holds all four prompts, so one
+prefill program and one fused step. Write a new fault under (a) wherever its meaning allows.
+The clean round is served and checked once a module (``clean_round``), not once a fault."""
 
 import dataclasses
 import re
@@ -31,9 +42,12 @@ ENGINE_KW = {"max_num_seqs": 4, "max_seq_len": 128, "prefill_buckets": (16, 32, 
 
 class Fault(NamedTuple):
     """A mistake the comparison must catch. ``plant(desc, params, eng, monkeypatch)`` returns the
-    engine to serve with (``eng`` itself where the fault is planted on it); the served
-    log-probabilities are then off the reference's by more than ``over`` tolerances (``margin``:
-    or the reference's top-1 leads the served token by as much)."""
+    engine to serve with; the served log-probabilities are then off the reference's by more than
+    ``over`` tolerances (``margin``: or the reference's top-1 leads the served token by as much).
+    Two ways to plant one, cheapest first (the module's docstring): (a) on the module's ``eng``,
+    which ``plant`` then returns, for a fault in the weights served (``with_params``) or in what the
+    engine object does between its programs (``monkeypatch.setattr(eng, ...)``); (b) on a
+    ``least_engine`` of its own, only where the fault is in traced code or in the description."""
     plant: Callable
     over: float = 1.0
     margin: bool = False
@@ -62,6 +76,12 @@ def prompts(desc, seed, lengths):
 
 def engine(cfg, params, **kw):
     return LLMEngine(cfg, params, **{**ENGINE_KW, **kw})
+
+
+def least_engine(cfg, params):
+    """An engine for a fault that needs programs of its own: ONE bucket, which all four prompts of the
+    fault test pad to in one group, so one prefill program and one fused step are compiled, not three and one."""
+    return engine(cfg, params, prefill_buckets=(64,))
 
 
 def served(outs, ps, sampling):
@@ -208,13 +228,14 @@ def test_a_prefill_whose_blocks_the_kernel_runs_is_served_what_the_reference_giv
     """PR 57: where a small expert expects less than two blocks' rows of a prefill, a TPU runs the grouped matmul's
     blocks as one kernel (``ops/grouped_experts.py``). The test answers for its ``refusal`` before a fresh engine
     traces its programs, the same body runs interpreted, and what is served is the reference's; every admitting
-    row then carries ``moe_rows_kernel`` equal to ``moe_rows_computed``."""
+    row then carries ``moe_rows_kernel`` equal to ``moe_rows_computed``. The least engine that shows it: one bucket
+    and a slot a prompt, so the five prompts are ONE prefill program's rows and one fused step's lanes."""
     if not desc.cfg.routing_layers:  # nothing is routed: no program of this description holds the layer
         return
     monkeypatch.setattr(grouped_experts, "refusal", lambda *a: None)
     ps = prompts(desc, 56, (21, 38, 11, 27, 50))
     sp = [SamplingParams(max_tokens=6, temperature=0.0, logprobs=True)] * len(ps)
-    eng = engine(desc.cfg, params)
+    eng = engine(desc.cfg, params, prefill_buckets=(64,), max_num_seqs=8)
     res = check(desc, params, served(eng.generate(ps, sp), ps, sp))
     assert res["ok"] and res["tokens"] == 30 and res["max_abs_dlogprob"] < desc.agrees_to, res
     admitting = [r for r in eng.telemetry()["steps"] if r.get("admitted")]
@@ -237,30 +258,27 @@ def test_every_decode_row_of_the_flight_log_read_the_experts_it_hit(desc, eng):
 
 
 # ------------------------------------------------------------------------------ the planted faults
-def bf16_state(kind, entry):
-    """The recurrent state kept in bfloat16: the precision below the stated one."""
+def with_params(change):
+    """(a) A fault in the WEIGHTS the engine serves (the reference keeps the true ones): planted on the module's engine, no program is traced anew."""
     def plant(desc, params, eng, monkeypatch):
-        @dataclasses.dataclass(frozen=True)
-        class Bf16State(type(desc.cfg)):
-            def cache_spec(self):
-                spec = super().cache_spec()
-                shape, _, per = spec[kind][entry]
-                return {**spec, kind: {**spec[kind], entry: (shape, "bfloat16", per)}}
-
-        low = engine(Bf16State(**dataclasses.asdict(desc.cfg)), params)
-        assert low.state[entry].dtype == jnp.bfloat16
-        return low
+        monkeypatch.setattr(eng, "params", change(params))
+        return eng
     return plant
 
 
+def in_kind(params, kind, **new):
+    """``params`` with the entries ``new`` of one kind of layer replaced."""
+    return {**params, kind: {**params[kind], **new}}
+
+
 def slot_not_reset(desc, params, eng, monkeypatch):
-    """A recycled slot keeps the last sequence's state (the clean round before left one in every slot): no insert at admission."""
+    """(a) A recycled slot keeps the last sequence's state (``clean_round`` left one in every slot): no insert at admission."""
     monkeypatch.setattr(eng, "_state_insert", lambda state, slot, row, new: state)
     return eng
 
 
 def padded_length(desc, params, eng, monkeypatch):
-    """The recurrence run over the padding too: the state at the bucket's length, not the prompt's."""
+    """(a) The recurrence run over the padding too: the state at the bucket's length, not the prompt's."""
     real = eng._prefill
 
     def at_padded_length(params, toks, lens):
@@ -271,18 +289,46 @@ def padded_length(desc, params, eng, monkeypatch):
     return eng
 
 
-def patched(module, name, wrap):
-    """A fault in ``module.name``, planted before a fresh engine traces its step programs: ``wrap(real)`` is what stands there."""
+def bf16_state(kind, entry):
+    """(b) The recurrent state kept in bfloat16: the precision below the stated one."""
     def plant(desc, params, eng, monkeypatch):
-        monkeypatch.setattr(module, name, wrap(getattr(module, name)))
-        return engine(desc.cfg, params)
+        @dataclasses.dataclass(frozen=True)
+        class Bf16State(type(desc.cfg)):
+            def cache_spec(self):
+                spec = super().cache_spec()
+                shape, _, per = spec[kind][entry]
+                return {**spec, kind: {**spec[kind], entry: (shape, "bfloat16", per)}}
+
+        low = least_engine(Bf16State(**dataclasses.asdict(desc.cfg)), params)
+        assert low.state[entry].dtype == jnp.bfloat16
+        return low
     return plant
 
 
-def test_the_comparison_fails_each_planted_fault(desc, params, eng, fault, monkeypatch):
-    ps = prompts(desc, 4, (21, 38, 11, 27))  # none on a bucket (32, 64, 16, 32), none on a chunk
+def patched(module, name, wrap):
+    """(b) A fault in ``module.name``, planted before a fresh engine traces its step programs: ``wrap(real)`` is what stands there."""
+    def plant(desc, params, eng, monkeypatch):
+        monkeypatch.setattr(module, name, wrap(getattr(module, name)))
+        return least_engine(desc.cfg, params)
+    return plant
+
+
+@pytest.fixture(scope="module")
+def clean_round(desc, params, eng):
+    """The fault test's four prompts (none on a bucket: 32, 64, 16, 32, and all 64 in ``least_engine``; none on a
+    chunk), their sampling, and the round served CLEAN on the module's engine and checked against the
+    reference, once a module: what every planted fault is a departure from. It also leaves a finished
+    sequence's state in every one of ``eng``'s four slots, which is what ``slot_not_reset`` leans on
+    (a slot that was never held has zeros to keep, and keeping zeros is no fault): every fault case
+    asks for this fixture, so the round has run before any fault is planted, whatever ran before."""
+    ps = prompts(desc, 4, (21, 38, 11, 27))
     sp = [SamplingParams(max_tokens=24, temperature=0.0, logprobs=True)] * len(ps)
-    assert check(desc, params, served(eng.generate(ps, sp), ps, sp))["ok"]
+    return ps, sp, check(desc, params, served(eng.generate(ps, sp), ps, sp))
+
+
+def test_the_comparison_fails_each_planted_fault(desc, params, eng, clean_round, fault, monkeypatch):
+    ps, sp, clean = clean_round
+    assert clean["ok"] and clean["tokens"] == 4 * 24, clean
     res = check(desc, params, served(fault.plant(desc, params, eng, monkeypatch).generate(ps, sp), ps, sp))
     off = max(res["max_abs_dlogprob"], res["max_margin"]) if fault.margin else res["max_abs_dlogprob"]
     assert not res["ok"] and off > fault.over * desc.tol, res
@@ -372,18 +418,43 @@ def test_the_chips_shares_add_up_to_the_uncut_expert_layer(desc):
     for chip in range(chips):
         share = dataclasses.replace(whole, expert_start=each * chip, num_local_experts=each)
         w = {**layer, **{n: layer[n][each * chip:each * chip + each] for n in s.matrices}}
-        routed = experts.experts_grouped(jax.tree.map(lambda a: a[None], w), 0, xn, idx, wt, jnp.ones((40,), bool), share)
+        # traced whole, a program a share: run op by op the layout's loops compile one at a time, each anew
+        routed = jax.jit(lambda w, *a, share=share: experts.experts_grouped(w, 0, *a, share))(jax.tree.map(lambda a: a[None], w), xn, idx, wt, jnp.ones((40,), bool))
         assert np.abs(np.asarray(routed)).max() > 0
         total = total + routed
     np.testing.assert_allclose(x + total, ref, atol=1e-4)
 
 
 PLACEMENTS = ("one_expert", "none_here", "a_block_and_one_more", "valid_ends_inside_a_block", "tall_blocks", "few_rows_an_expert", "in_slabs", "many_small_trips")
+TRACE_READS = ("BLOCK", "TALL_FROM", "SLAB", "TILE", "ROWS", "SLAB_ROWS", "ALIGN")  # the constants of ``models/experts.py`` that a trace of ``_grouped`` reads
+
+
+@pytest.fixture(scope="module")
+def routed(desc, params):
+    """What the placement's sixteen cases share, made once a module: 300 tokens and the router's choice
+    for them, the first expert layer's weights (the one-by-one oracle's), and ``grouped``:
+    ``experts._grouped`` traced ONCE for each set of values its trace reads (who runs the blocks, and
+    ``TRACE_READS`` as the case has patched them), so the eight cases that patch no constant share two
+    programs and only a case that changes the traced program traces one."""
+    cfg, w = desc.cfg, jax.tree.map(lambda a: a[0], params["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(21), (300, cfg.hidden_size))
+    # a layer whose routing is made elsewhere holds no router: the first one that any kind holds serves
+    router = w if "router" in w else {"router": next(g["router"][0] for g in params.values() if isinstance(g, dict) and "router" in g)}
+    idx, wt = experts.route(router, x, cfg)
+    traced = {}
+
+    def grouped(runs, *operands):
+        reads = (runs,) + tuple(getattr(experts, name) for name in TRACE_READS)
+        if reads not in traced:  # a jit of its own: one keyed by ``_grouped`` would hand back the program of other constants
+            traced[reads] = jax.jit(lambda *a: experts._grouped(params["moe"], 0, *a, cfg))
+        return traced[reads](*operands)
+
+    return w, x, idx, wt, grouped
 
 
 @pytest.mark.parametrize("runs", ["loop", "kernel"])
 @pytest.mark.parametrize("case", PLACEMENTS)
-def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(desc, params, case, runs, monkeypatch):
+def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(desc, params, routed, case, runs, monkeypatch):
     """The layout of ``experts._grouped`` follows the pairs held here, so its loops' lengths are
     data: every pair at ONE held expert (a run of many blocks); no pair held here (no trip, zeros
     out); an expert with exactly ``BLOCK`` pairs beside one with ``BLOCK + 1``; ``valid`` that ends
@@ -394,14 +465,10 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
     loop, and by the kernel (``ops/grouped_experts.py``: the test answers for its ``refusal`` and the
     same body runs interpreted). (Not in ``__all__``: for the descriptions that route experts.)"""
     cfg, s = desc.cfg, desc.cfg.expert_layer
-    w = jax.tree.map(lambda a: a[0], params["moe"])
+    w, x, idx, wt, grouped = routed
     N, k, El, B = 300, s.top_k, s.held, experts.BLOCK
     if runs == "kernel":
         monkeypatch.setattr(grouped_experts, "refusal", lambda *a: None)
-    x = jax.random.normal(jax.random.PRNGKey(21), (N, cfg.hidden_size))
-    # a layer whose routing is made elsewhere holds no router: the first one that any kind holds serves
-    router = w if "router" in w else {"router": next(g["router"][0] for g in params.values() if isinstance(g, dict) and "router" in g)}
-    idx, wt = experts.route(router, x, cfg)
     valid = np.ones((N,), bool)
     elsewhere = [e for e in range(s.num_experts) if not s.expert_start <= e < s.expert_start + El]
     if case == "one_expert":
@@ -431,7 +498,7 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
         monkeypatch.setattr(experts, "SLAB", 16)
         monkeypatch.setattr(experts, "TILE", 8)
         monkeypatch.setattr(experts, "ROWS", 4)
-    want = one_by_one(w, x, idx, jnp.where(valid[:, None], wt, 0.0), cfg)
+    want = one_by_one(w, x, idx, np.where(valid[:, None], wt, 0.0), cfg)
     assert experts.blocks_plan(s, N, [params["moe"][n] for n in s.matrices]) == (B, runs == "kernel")
     if case == "in_slabs":  # the layer over three sequences of 100, the shared expert taken off again
         lengths = jnp.asarray([100, 100, 100])
@@ -442,15 +509,16 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
         # the fourth counter is there in the programs whose blocks the kernel runs, and is the rows of the blocks it ran: all in use
         assert len(counters) == experts.seq_counters(cfg, params["moe"], N) == (4 if runs == "kernel" else 3) and counters[-1] == counters[2]
     else:
-        got, sizes, rows = experts._grouped(params["moe"], 0, x, idx, wt, jnp.asarray(valid), cfg)
-        counters = [int(jnp.sum(sizes > 0)), int(jnp.sum(sizes)), int(rows)]
+        got, sizes, rows = grouped(runs, x, idx, wt, jnp.asarray(valid))
+        sizes = np.asarray(sizes)
+        counters = [(sizes > 0).sum(), sizes.sum(), int(rows)]
     here = (np.asarray(idx) - s.expert_start)[valid]
     pairs = np.bincount(here[(here >= 0) & (here < El)], minlength=El)
     assert (np.abs(want).max() > 0) == (pairs.sum() > 0)
     np.testing.assert_allclose(got, want, atol=2e-4)
     assert counters[0] == (pairs > 0).sum() and counters[1] == pairs.sum()
     if sizes is not None:
-        assert (np.asarray(sizes) == pairs).all() and counters[2] == (-(-pairs // B)).sum() * B
+        assert (sizes == pairs).all() and counters[2] == (-(-pairs // B)).sum() * B
     if case == "none_here":
         assert counters[2] == 0 and not np.asarray(got).any()
     if case == "a_block_and_one_more":
@@ -458,15 +526,21 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
 
 
 def one_by_one(w, x, idx, wt, cfg):
-    """Each (token, chosen expert) pair computed alone: what no dispatch may lose."""
-    out, s = np.zeros(x.shape, np.float32), cfg.expert_layer
+    """Each (token, chosen expert) pair computed alone, on the host: what no dispatch may lose."""
+    s = cfg.expert_layer
+    w, x, idx, wt = jax.tree.map(np.asarray, w), np.asarray(x), np.asarray(idx), np.asarray(wt)
+    out = np.zeros(x.shape, np.float32)
     for n in range(x.shape[0]):
-        for e, g in zip(np.asarray(idx[n]), np.asarray(wt[n])):
+        for e, g in zip(idx[n], wt[n]):
             e = int(e) - s.expert_start
             if 0 <= e < s.held:
                 up = x[n] @ w["w_up"][e].T
-                h = jnp.square(jax.nn.relu(up)) if s.act == "relu2" else (jax.nn.relu if s.act == "reglu" else jax.nn.silu)(x[n] @ w["w_gate"][e].T) * up
-                out[n] += g * np.asarray(h @ w["w_down"][e])
+                if s.act == "relu2":
+                    h = np.square(np.maximum(up, 0.0))
+                else:
+                    gate = x[n] @ w["w_gate"][e].T
+                    h = (np.maximum(gate, 0.0) if s.act == "reglu" else gate / (1.0 + np.exp(-gate))) * up
+                out[n] += g * (h @ w["w_down"][e])
     return out
 
 
@@ -480,4 +554,4 @@ def jiggled(params):
 
 
 # what ``import *`` hands a description's file: the fixtures, the hook that parametrizes ``fault``, and every test but the share's
-__all__ = ["desc", "eng", "fault", "pytest_generate_tests"] + [n for n in dir() if n.startswith("test_") and n not in ("test_the_chips_shares_add_up_to_the_uncut_expert_layer", "test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number")]
+__all__ = ["desc", "eng", "clean_round", "routed", "fault", "pytest_generate_tests"] + [n for n in dir() if n.startswith("test_") and n not in ("test_the_chips_shares_add_up_to_the_uncut_expert_layer", "test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number")]

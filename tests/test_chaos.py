@@ -123,7 +123,9 @@ def test_transfer_failure_falls_back_to_reconstruction(rt, tmp_path):
         return int(x[0]), x.nbytes
 
     ref = produce.remote()
-    ray_tpu.wait([ref], timeout=60)
+    # produced before the chaos starts. Not ``ray_tpu.wait``: on a result that lives in shared memory and that
+    # nothing has fetched yet it reports nothing ready and sits out its whole timeout (ROADMAP.md C11)
+    assert ray_tpu.get(ref, timeout=60)[0] == 7
     # enough hits to exhaust one full pull-retry budget and then some:
     # the consumer must go through mark-lost -> reconstruction
     rpc_chaos.inject("transfer_chunk", drop_prob=1.0, max_hits=4)

@@ -31,26 +31,14 @@ C = family.rehearsal(PUBLISHED)  # the configuration file's side of the toy mode
 CFG = family.program_config(C, 128, remat=False)
 
 
-def _with_params(change):
-    """A fault in the WEIGHTS the engine serves (the reference keeps the true ones): planted on the module's engine, no program is traced anew."""
-    def plant(desc, params, eng, monkeypatch):
-        monkeypatch.setattr(eng, "params", change(params))
-        return eng
-    return plant
-
-
-def _in(params, kind, **new):
-    return {**params, kind: {**params[kind], **new}}
-
-
 def _zero(name):
-    return _with_params(lambda p: _in(p, "mamba1", **{name: jnp.zeros_like(p["mamba1"][name])}))
+    return battery.with_params(lambda p: battery.in_kind(p, "mamba1", **{name: jnp.zeros_like(p["mamba1"][name])}))
 
 
 def _one_decay_a_channel(params):
     """``A`` averaged over its 16 states: every state of a channel decays alike, Mamba-2's scalar form."""
     A = jnp.exp(params["mamba1"]["A_log"])
-    return _in(params, "mamba1", A_log=jnp.log(jnp.broadcast_to(A.mean(-1, keepdims=True), A.shape)))
+    return battery.in_kind(params, "mamba1", A_log=jnp.log(jnp.broadcast_to(A.mean(-1, keepdims=True), A.shape)))
 
 
 class _NoStepNorm:
@@ -91,7 +79,7 @@ DESC = battery.Description(
     poison={"k": jnp.nan, "v": 1e4},
     faults={"state_and_window_at_the_padded_length": battery.Fault(battery.padded_length),
             "slot_not_reset": battery.Fault(battery.slot_not_reset),
-            "one_decay_a_channel": battery.Fault(_with_params(_one_decay_a_channel)),
+            "one_decay_a_channel": battery.Fault(battery.with_params(_one_decay_a_channel)),
             "step_bias_left_out": battery.Fault(_zero("dt_bias")),
             "skip_left_out": battery.Fault(_zero("D")),
             "convolution_bias_left_out": battery.Fault(_zero("conv_b")),
@@ -154,11 +142,11 @@ def test_a_siblings_config_fails_loudly_and_the_two_bias_keys_are_honoured():
     params = jax.jit(lambda k: jamba.init_params(cfg, k))(jax.random.PRNGKey(3))
     assert "conv_b" not in params["mamba1"] and params["mamba1"]["in_bias"].shape == (6, 256) and params["mamba1"]["out_bias"].shape == (6, 64)
     assert cfg.num_params() == CFG.num_params() + 6 * (256 + 64 - 128) == sum(a.size for a in jax.tree.leaves(params)) == family.parameters_held(c)
-    params = _in(params, "mamba1", in_bias=0.3 * jax.random.normal(jax.random.PRNGKey(4), (6, 256)), out_bias=0.3 * jax.random.normal(jax.random.PRNGKey(5), (6, 64)))
+    params = battery.in_kind(params, "mamba1", in_bias=0.3 * jax.random.normal(jax.random.PRNGKey(4), (6, 256)), out_bias=0.3 * jax.random.normal(jax.random.PRNGKey(5), (6, 64)))
     toks = np.asarray(battery.prompts(DESC, 2, (23,)), np.int32)
     got = jax.nn.log_softmax(hybrid.forward(params, jnp.asarray(toks), cfg)[0], -1)
     np.testing.assert_allclose(got, family.reference_logprobs(params, toks[0], c, 0, 23), atol=1e-5)
-    without = jax.nn.log_softmax(hybrid.forward(_in(params, "mamba1", in_bias=jnp.zeros((6, 256))), jnp.asarray(toks), cfg)[0], -1)
+    without = jax.nn.log_softmax(hybrid.forward(battery.in_kind(params, "mamba1", in_bias=jnp.zeros((6, 256))), jnp.asarray(toks), cfg)[0], -1)
     assert float(jnp.abs(got - without).max()) > 1e-2, "the bias is there to be honoured"
 
 

@@ -35,11 +35,16 @@ CFG = family.program_config(C, 128, remat=False)
 
 def _config(**changed):
     """A fault in the description: an engine of the same weights under another ``KeyeVLConfig``."""
-    return lambda desc, params, eng, monkeypatch: battery.engine(dataclasses.replace(desc.cfg, **changed), params)
+    return lambda desc, params, eng, monkeypatch: battery.least_engine(dataclasses.replace(desc.cfg, **changed), params)
 
 
 def _keys(change):
     return battery.patched(ia, "index_keys", lambda real: lambda dots, w, heads: change(real, dots, w, heads))
+
+
+def _one_indexer_head(params):
+    """The index from the indexer's FIRST head alone: the other heads' weights are products with zero columns of ``w_idx``, in prefill and in the step alike."""
+    return battery.in_kind(params, "indexed", w_idx=params["indexed"]["w_idx"].at[..., 1:].set(0.0))
 
 
 def _no_relu(real, dots, w, heads):
@@ -60,7 +65,7 @@ DESC = battery.Description(
     poison={"k": jnp.nan, "v": 1e4, "k_idx": 1e4},
     faults={"no_selection": battery.Fault(_config(index_topk=1 << 20)),  # dense above topk
             "topk_halved": battery.Fault(_config(index_topk=8)),
-            "score_from_one_indexer_head": battery.Fault(_keys(lambda real, dots, w, heads: real(dots, w, 1))),
+            "score_from_one_indexer_head": battery.Fault(battery.with_params(_one_indexer_head)),
             "relu_dropped": battery.Fault(_keys(_no_relu)),
             "rows_past_the_length": battery.Fault(battery.patched(ia, "indexed_attention_step", _rows_past_the_length))},
     refusal_says=("its attention layers keep k_idx per position, not keys and values by head",),

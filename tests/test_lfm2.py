@@ -33,22 +33,10 @@ C = {**family.rehearsal(PUBLISHED), "init_router_bias_range": 0.5}
 CFG = family.program_config(C, 128, remat=False)
 
 
-def _with_params(change):
-    """A fault in the WEIGHTS the engine serves (the reference keeps the true ones): planted on the module's engine, no program is traced anew."""
-    def plant(desc, params, eng, monkeypatch):
-        monkeypatch.setattr(eng, "params", change(params))
-        return eng
-    return plant
-
-
-def _in(params, kind, **new):
-    return {**params, kind: {**params[kind], **new}}
-
-
 def _gates_swapped(params):
     """``[C, B, u]`` for ``[B, C, u]``: the output gate and the convolution's first factor change places."""
     b, c, u = jnp.split(params["shortconv"]["in_proj"], 3, axis=-1)
-    return _in(params, "shortconv", in_proj=jnp.concatenate([c, b, u], axis=-1))
+    return battery.in_kind(params, "shortconv", in_proj=jnp.concatenate([c, b, u], axis=-1))
 
 
 # float32 program against float32 reference: the same mathematics summed in another order (tiles of
@@ -61,10 +49,10 @@ DESC = battery.Description(
     poison={"k": jnp.nan, "v": 1e4},
     faults={"window_at_the_padded_length": battery.Fault(battery.padded_length),
             "slot_not_reset": battery.Fault(battery.slot_not_reset),
-            "bias_left_out": battery.Fault(_with_params(lambda p: _in(p, "moe", router_bias=jnp.zeros_like(p["moe"]["router_bias"])))),
-            "taps_reversed": battery.Fault(_with_params(lambda p: _in(p, "shortconv", conv_w=p["shortconv"]["conv_w"][:, ::-1]))),
-            "gates_swapped": battery.Fault(_with_params(_gates_swapped)),
-            "head_norms_weights_left_off": battery.Fault(_with_params(lambda p: _in(p, "attn", q_norm=jnp.ones_like(p["attn"]["q_norm"]), k_norm=jnp.ones_like(p["attn"]["k_norm"]))))},
+            "bias_left_out": battery.Fault(battery.with_params(lambda p: battery.in_kind(p, "moe", router_bias=jnp.zeros_like(p["moe"]["router_bias"])))),
+            "taps_reversed": battery.Fault(battery.with_params(lambda p: battery.in_kind(p, "shortconv", conv_w=p["shortconv"]["conv_w"][:, ::-1]))),
+            "gates_swapped": battery.Fault(battery.with_params(_gates_swapped)),
+            "head_norms_weights_left_off": battery.Fault(battery.with_params(lambda p: battery.in_kind(p, "attn", q_norm=jnp.ones_like(p["attn"]["q_norm"]), k_norm=jnp.ones_like(p["attn"]["k_norm"]))))},
     refusal_says=("its recurrent layers keep a state per sequence (conv)",),
     refusal_says_not=("c_kv", "ring"))
 

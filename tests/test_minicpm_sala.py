@@ -48,13 +48,13 @@ def _property(cls, name, change):
     def plant(desc, params, eng, monkeypatch):
         real = getattr(cls, name)
         monkeypatch.setattr(cls, name, property(lambda self: change(self, real.fget(self))))
-        return battery.engine(desc.cfg, params)
+        return battery.least_engine(desc.cfg, params)
     return plant
 
 
-def _reversed_slopes(desc, params, eng, monkeypatch):
-    """The heads' decays in the opposite order: the slowest head forgets fastest."""
-    return battery.engine(desc.cfg, {**params, "lightning": {**params["lightning"], "slope": params["lightning"]["slope"][:, ::-1]}})
+def _reversed_slopes(params):
+    """The heads' decays in the opposite order: the slowest head forgets fastest. The slopes are an operand of the programs, not a constant in them."""
+    return battery.in_kind(params, "lightning", slope=params["lightning"]["slope"][:, ::-1])
 
 
 def _rows_past_the_length(real):
@@ -76,7 +76,7 @@ DESC = battery.Description(
             "topk_short_by_one": battery.Fault(battery.patched(spa, "choose_blocks", _choose(lambda sp: sp._replace(topk=sp.topk - 1)))),
             # a group's blocks chosen by ONE of its heads' scores, not by the sum over its heads: a selection a head, as far as a shared table can hold one
             "selection_by_one_head": battery.Fault(battery.patched(spa, "block_scores", _scores(lambda real, q, kc, t, sp: real(q[:, :, :, :1], kc, t, sp)))),
-            "slopes_reversed": battery.Fault(_reversed_slopes),
+            "slopes_reversed": battery.Fault(battery.with_params(_reversed_slopes)),
             "rotated_sparse_layer": battery.Fault(_property(ms.MiniCPMSALAConfig, "sparse_heads", lambda c, h: h._replace(rot_dim=c.head_dim))),
             "a_from_the_held_depth": battery.Fault(_property(ms.MiniCPMSALAConfig, "stream_scales",
                                                              lambda c, s: (s[0], c.scale_depth / c.num_hidden_layers ** 0.5, s[2]))),
@@ -209,7 +209,8 @@ def test_both_kernels_interpreted_serve_what_the_xla_forms_serve(params, monkeyp
     monkeypatch.setattr(sa, "refusal_blocks", lambda *a, **kw: None)
     ps = battery.prompts(DESC, 24, (50, 28, 9))
     sp = [SamplingParams(max_tokens=8, temperature=0.0, logprobs=True)] * 3
-    res = battery.check(DESC, params, battery.served(battery.engine(CFG, params).generate(ps, sp), ps, sp))
+    eng = battery.engine(CFG, params, prefill_buckets=(64,))  # the three prompts as ONE prefill program's rows: the kernel interpreted is traced once
+    res = battery.check(DESC, params, battery.served(eng.generate(ps, sp), ps, sp))
     assert res["ok"] and res["tokens"] == 24 and res["max_abs_dlogprob"] < DESC.agrees_to, res
 
 

@@ -36,7 +36,7 @@ W = CFG.sliding_window_size
 
 def _another_window(by):
     """The window one key wider or narrower than the reference's, in prefill, in the ring and in the decode step alike."""
-    return lambda desc, params, eng, monkeypatch: battery.engine(dataclasses.replace(desc.cfg, sliding_window_size=W + by), params)
+    return lambda desc, params, eng, monkeypatch: battery.least_engine(dataclasses.replace(desc.cfg, sliding_window_size=W + by), params)
 
 
 def _rotation(always):
@@ -54,13 +54,13 @@ def _routed_after_attention(desc, params, eng, monkeypatch):
     seq, step = experts.moe_seq, experts.moe_step
     monkeypatch.setattr(experts, "moe_seq", lambda w, xn, lengths, c, stacked=None, routing=None: seq(w, xn, lengths, c, stacked))
     monkeypatch.setattr(experts, "moe_step", lambda w, xn, active, c, stacked, routing=None: step(w, xn, active, c, stacked))
-    return battery.engine(desc.cfg, {**params, "moe": {**params["moe"], "router": routers}})
+    return battery.least_engine(desc.cfg, battery.in_kind(params, "moe", router=routers))
 
 
 def _silu_gate(desc, params, eng, monkeypatch):
     real = st.SmallThinkerConfig.expert_layer
     monkeypatch.setattr(st.SmallThinkerConfig, "expert_layer", property(lambda self: dataclasses.replace(real.fget(self), act="swiglu")))
-    return battery.engine(desc.cfg, params)
+    return battery.least_engine(desc.cfg, params)
 
 
 def _row_at_pos(real):
@@ -68,9 +68,12 @@ def _row_at_pos(real):
     return lambda arrays, per_position, i, lanes, pos, rings=frozenset(): real(arrays, per_position, i, lanes, pos)
 
 
-def _first_rows(real):
-    """A prompt longer than the window inserted from its FIRST W positions, not its last."""
-    return lambda cache, slot, new, length, rings=frozenset(): real(cache, slot, {n: a[:, :cache[n].shape[2]] for n, a in new.items()}, length)
+def _first_rows(desc, params, eng, monkeypatch):
+    """A prompt longer than the window inserted from its FIRST W positions, not its last: the engine's insertion is a program
+    of its own between the prefill and the step (``eng._insert``), so the fault stands there, on the module's engine."""
+    monkeypatch.setattr(eng, "_insert", jax.jit(lambda cache, slot, new, length: kvc.insert_entries(
+        cache, slot, {n: a[:, :cache[n].shape[2]] for n, a in new.items()}, length)))
+    return eng
 
 
 def _ring_unmasked(real):
@@ -94,7 +97,7 @@ DESC = battery.Description(
             "routed_after_attention": battery.Fault(_routed_after_attention),
             "silu_for_relu": battery.Fault(_silu_gate),
             "ring_row_at_pos": battery.Fault(battery.patched(hybrid, "LayerCache", _row_at_pos)),
-            "ring_from_the_first_rows": battery.Fault(battery.patched(kvc, "insert_entries", _first_rows)),
+            "ring_from_the_first_rows": battery.Fault(_first_rows),
             "young_ring_not_masked": battery.Fault(battery.patched(sa, "attend", _ring_unmasked))},
     refusal_says=("its window layers keep k_w and v_w in a ring of the last 16 positions",),
     refusal_says_not=("recurrent", "c_kv"))
@@ -258,7 +261,8 @@ def test_the_decode_kernels_interpreted_serve_what_the_xla_forms_serve(params, m
     monkeypatch.setattr(sa, "_launch", lambda kernel, name, *a: names.append(name) or launch(kernel, name, *a))
     ps = battery.prompts(DESC, 24, (50, 13, 9))
     sp = [SamplingParams(max_tokens=8, temperature=0.0, logprobs=True)] * 3
-    res = battery.check(DESC, params, battery.served(battery.engine(CFG, params).generate(ps, sp), ps, sp))
+    eng = battery.engine(CFG, params, prefill_buckets=(64,))  # the kernels are the step's: one prefill program serves the three prompts
+    res = battery.check(DESC, params, battery.served(eng.generate(ps, sp), ps, sp))
     assert res["ok"] and res["tokens"] == 24 and res["max_abs_dlogprob"] < DESC.agrees_to, res
     assert set(names) == {"slot_decode_attention", st.DECODE_KERNEL["swa"]}
 
