@@ -2502,9 +2502,11 @@ class LLMEngine:
             return
         # hybrid_runner.PREFILL_STATS: the fourth comes only from a program whose blocks the kernel runs
         routing = np.zeros((4,), np.float32) if routing is None else np.pad(routing, (0, 4 - len(routing)))
+        # what the description reads off the program's routing counters and its shape (``stats[1]``: its rows as padded), host arithmetic alone
+        counted = {**stats[2], **self.config.routed_counters(stats[1], routing)} if self._hybrid else stats[2]
         seen = self._prefill_stats or (0, 0, 0, np.zeros_like(routing), {})
         self._prefill_stats = (seen[0] + stats[0], seen[1] + stats[1], seen[2] + 1, seen[3] + routing,
-                               {**seen[4], **{k: seen[4].get(k, 0) + v for k, v in stats[2].items()}})  # a program may count what another of the step does not
+                               {**seen[4], **{k: seen[4].get(k, 0) + v for k, v in counted.items()}})  # a program may count what another of the step does not
 
     def _bind_resume(self, st: RequestState, slot: int):
         """Splice a restored live-state request into the decode loop

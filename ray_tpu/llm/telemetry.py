@@ -85,7 +85,9 @@ logger = logging.getLogger("ray_tpu.llm")
 # takes on that step's row; then what the engine counts of any model's from its ``flash_calls``
 # (``ops/flash_attention.query_tiles``) and from the rows' lengths (``ops/layers.live_rows``, a description's ``prefill_rows_live``)
 PREFILL_COUNTERS = ("kda_chunks", "kda_kernel_chunks", "prefill_sparse_pairs", "gdn_chunks", "gdn_kernel_chunks", "swa_pairs",
-                    "narrow_pairs", "pairs_scored", "pairs_chosen", "choice_bytes", "selscan_positions", "selscan_kernel_positions", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live")
+                    "narrow_pairs", "pairs_scored", "pairs_chosen", "choice_bytes", "selscan_positions", "selscan_kernel_positions", "attn_q_tiles", "attn_q_tiles_live", "prefill_rows_live",
+                    # and what a description reads off a program's routing counters on the host (``HybridDescription.routed_counters``)
+                    "moe_expert_fetches")
 # and of a decode step from the positions its lanes hold (``HybridDescription.decode_counters``), on that step's row
 DECODE_COUNTERS = ("sparse_blocks_read", "sparse_blocks_live", "swa_rows_read", "narrow_rows_read", "rows_scored", "rows_chosen")
 
@@ -456,7 +458,10 @@ class FlightRecorder:
         # and the positions that a position-wise sub-block of the programs runs (``prefill_rows_live``: a dense
         # FFN or a Llama MLP, through ``ops/layers.live_slabs``), whole slabs under each row's true length beside
         # ``prefill_tokens_padded``, and equal to it where the plain form runs or the description has no such
-        # sub-block (``HybridDescription.prefill_rows_live``)
+        # sub-block (``HybridDescription.prefill_rows_live``); and how many times a held expert's matrices were brought in by the
+        # step's prefills (``moe_expert_fetches``, a mean over the expert layers, summed over the programs: a block in the grouped
+        # matmul's loop, a run of an expert's blocks in its kernel; ``HybridDescription.routed_counters`` reckons it on the host from
+        # the counters above and the program's shape; absent for a description that does not)
         *PREFILL_COUNTERS,
         # then the stage durations, and the milliseconds of the step that the process spent inside
         # the garbage collector (every thread held; absent where there were none)

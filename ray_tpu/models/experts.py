@@ -31,7 +31,11 @@ no router. Everything else is one code path:
   products are the floor, and the loop is at half of it), and where it expects
   less than two short blocks' rows and is of 16 MiB or less (LOW fill: the
   bytes are the floor, and the loop adds a fixed cost a product to them); the
-  loop everywhere else.
+  loop everywhere else: tall blocks of which an expert expects one and a few
+  rows of a second, and a LARGE expert's low-fill calls (an expert of 54 MiB
+  that expects 164-192 rows of a 12,288-row call, ``models/afmoe.py``'s: the
+  loop fetches its matrices once a block, 2.3 times a call by the cell's
+  ``moe_fetches_per_expert``, where the experts' bytes once are the floor).
 - ``experts_step``: a decode step's form: the held experts that a BOUND lane
   chose, one after another, every lane against one expert's matrices read
   straight out of the stacked weights (a kernel on a TPU, ``ops/step_experts.py``;

@@ -46,13 +46,16 @@ Two loops over one description:
 
 Neither loop, nor the step programs of ``llm/hybrid_runner.py`` that call them,
 names a model or a kind of layer. A uniform model is the special case of one kind
-and a period of one. Nine descriptions stand over these loops today
+and a period of one. Ten descriptions stand over these loops today
 (``nemotron_h``, ``qwen3_next``, ``glm4_moe_lite``, ``kimi_linear``, ``minicpm_sala``,
-``smallthinker``, ``lfm2``, ``keye_vl``, ``jamba``, each a file beside this one; the eighth and the
-ninth needed no line of ``llm/engine.py`` or ``llm/hybrid_runner.py``: a third per-position entry,
-the indexer's key, and a state of another shape, ``[states, channels]`` with no heads, are each one
-more row of ``cache_spec()``), and the benchmark has a family file for each and a tenth
-for ``llama``, which the runner still writes out itself (``benchmark/families/``).
+``smallthinker``, ``lfm2``, ``keye_vl``, ``jamba``, ``afmoe``, each a file beside this one; the eighth
+and the ninth needed no line of ``llm/engine.py`` or ``llm/hybrid_runner.py``: a third per-position
+entry, the indexer's key, and a state of another shape, ``[states, channels]`` with no heads, are
+each one more row of ``cache_spec()``; the tenth, whose every sub-block norms its OUTPUT too, keeps
+that second norm inside its mixers, so the loops' ``x + mixer(norm(x))`` stands, and needed one
+host line of the engine: ``routed_counters``, what a description reads off a prefill program's
+routing counters once they are back), and the benchmark has a family file for each and an
+eleventh for ``llama``, which the runner still writes out itself (``benchmark/families/``).
 """
 
 from __future__ import annotations
@@ -168,6 +171,13 @@ class HybridDescription:
         the true ``lengths`` runs that can be counted on the host from those alone, by a name of
         ``llm/telemetry.PREFILL_COUNTERS``: summed over an admitting step's programs onto that step's
         row of the flight log. None by default."""
+        return {}
+
+    def routed_counters(self, rows: int, routing) -> dict:
+        """What ONE prefill program over ``rows`` positions (as padded) did that follows, on the host,
+        from its routing counters as they came back (``routing``: ``llm/hybrid_runner.PREFILL_STATS``,
+        means over the routing layers) and its shapes, by a name of ``llm/telemetry.PREFILL_COUNTERS``:
+        no value more leaves the device program for it. None by default."""
         return {}
 
     def prefill_rows_live(self, length: int, lengths) -> int:

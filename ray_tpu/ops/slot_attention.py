@@ -190,8 +190,8 @@ def refusal(cache_dtype, num_heads: int, num_kv_heads: int, head_dim: int, S: in
         return (f"{num_kv_heads} kv heads x head_dim {head_dim}: a position's heads lie in ({num_kv_heads}, 128) tiles, which "
                 "are [S * kv, hd] rows only after a copy of the whole cache (402 MB of temporaries at 3 x 16 x 4096 x 2 x 256, "
                 "compiled for a v5e in PR 35)")
-    if num_heads < 16 or padded_heads(num_heads, num_kv_heads) > 32:
-        return (f"{num_heads} query heads over {num_kv_heads} kv heads: compiled at 16 and 32 (whole bfloat16 tiles of 16 rows), "
+    if num_heads < 16 or padded_heads(num_heads, num_kv_heads) > 48:
+        return (f"{num_heads} query heads over {num_kv_heads} kv heads: compiled at 16, 32 and 48 (whole bfloat16 tiles of 16 rows), "
                 "and at 28 over 4, whose groups of 7 go as 8")
     blk = block_positions(S, num_kv_heads, head_dim, dt.itemsize)
     if blk * num_kv_heads < 512:

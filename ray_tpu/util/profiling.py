@@ -50,6 +50,7 @@ ANNOTATION_LEVEL = 1
 SCOPES = {
     "embed": "embed", "head": "head", "sample": "sample", "cache": "cache",
     "attn": "mixer", "gated_attn": "mixer", "mamba2": "mixer", "swa": "mixer",
+    "attn.gate": "mixer", "swa.gate": "mixer",
     "gdn": "mixer", "gdn.chunk": "mixer", "gdn.scan": "mixer",
     "kda": "mixer", "kda.chunk": "mixer", "kda.scan": "mixer",
     "mla": "mixer", "mla.down": "mixer", "mla.expand": "mixer", "mla.absorb": "mixer", "mla.attn": "mixer",

@@ -1,7 +1,7 @@
 """Which warm-up programs a change renews, counted without a chip (ROADMAP.md A7).
 
 ``python scripts/warm_texts.py <tree> <out.json>`` lowers every warm prefill shape of every cell the hybrid
-runner serves, seven that route experts and two that do not, and of the llama family's two serving cells
+runner serves, eight that route experts and two that do not, and of the llama family's two serving cells
 (the server's own first request in the smallest bucket, then the cell's warm plan) for the described v5e, from
 the tree it is given (a checkout, or ``git archive`` of the parent unpacked somewhere), and writes
 cell -> shape -> sha1 of the lowered text with the Mosaic kernels' payloads cut out (they hold the checkout's
@@ -60,7 +60,7 @@ def prefill_text(cfg, params, B, T):
 
 # ``tests/test_chip_compile.CELLS``' name of a cell of the hybrid runner -> its traffic mix; a tree that lacks one (an older parent) leaves it out
 CELLS = {"qwen3_next": "longdoc", "kimi": "longdoc", "nemotron": "chat", "glm": "longdoc-16k", "smallthinker": "longdoc-12k", "lfm2": "longdoc-12k", "keye": "longdoc-24k",
-         "sala": "longdoc-12k", "jamba": "longdoc-12k"}  # the last two route nothing: the hybrid runner's other cells, which a change to it must leave alone
+         "trinity": "longdoc-12k", "sala": "longdoc-12k", "jamba": "longdoc-12k"}  # the last two route nothing: the hybrid runner's other cells, which a change to it must leave alone
 # the llama family's two serving cells, which ``llm/model_runner.prefill`` serves (``mistral-7b-d6.sft-2k`` trains: it warms no prefill)
 LLAMA = {"internlm2.chat": "chat", "internlm2.longdoc": "longdoc"}
 texts = {}

@@ -220,7 +220,7 @@ def test_prompts_that_leave_whole_slabs_empty_are_served_what_the_reference_give
     res = check(desc, params, served(eng.generate(ps, sampling), ps, sampling))
     assert res["ok"] and res["tokens"] == 13 and res["max_abs_dlogprob"] < desc.agrees_to, res
     (row,) = [r for r in eng.telemetry()["steps"] if r.get("admitted")]
-    slabbed = "ffn" in desc.cfg.layer_kinds
+    slabbed = bool({"ffn", "mlp"} & set(desc.cfg.layer_kinds))  # the dense SwiGLU over ``live_slabs``, under the kind's name in the description
     assert (row["prefill_tokens"], row["prefill_tokens_padded"], row["prefill_rows_live"]) == (sum(lengths), 4 * 64, 32 + 48 + 48 + 16 if slabbed else 4 * 64)
 
 

@@ -380,7 +380,8 @@ CELLS = {"nemotron": ("nemotron-3-nano-30b-a3b-ep2.json", {}),  # published widt
          "smallthinker": ("smallthinker-21b-a3b-d8.json", {"remat": False}),  # layers 0-7 of 52, every expert, the whole vocabulary, 16 slots x 12,288
          "lfm2": ("lfm2-24b-a2b-d10.json", {"remat": False}),  # layers 0-9 of 40, every expert, the whole vocabulary, 16 slots x 12,288
          "keye": ("keye-vl-2.0-30b-a3b-d6.json", {"remat": False}),  # layers 24-29 of 48, every expert, the whole vocabulary, 12 slots x 24,576
-         "jamba": ("jamba2-3b.json", {"remat": False})}  # the published model whole: 28 layers, the whole vocabulary, 16 slots x 12,288
+         "jamba": ("jamba2-3b.json", {"remat": False}),  # the published model whole: 28 layers, the whole vocabulary, 16 slots x 12,288
+         "trinity": ("trinity-large-preview-ep8-d5.json", {"remat": False})}  # a dense layer and one period of four, 32 of 256 experts, an eighth of the vocabulary, 16 slots x 12,288
 
 
 def _cell_at_its_size(one_chip, cell):
@@ -1216,3 +1217,85 @@ def test_jamba_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_
     assert "mamba1.conv" in txt
     assert mem.temp_size_in_bytes < most_gib * 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.33 * 2**30 < 15.75 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# PR 64: a tenth description, Arcee Trinity-Large-Preview (models/afmoe.py): the cell trinity-large-ep8-d5.longdoc-12k.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("layers, S", [(1, 12288), (4, 4096)])
+def test_slot_attention_kernel_at_48_heads_over_8_compiles_for_v5e_and_copies_nothing(one_chip, as_on_a_tpu, layers, S):
+    """48 query heads over 8 key-value heads 128 wide are THREE bfloat16 tiles of 16 query rows a lane, where every cell before
+    had at most two: the gate lets the tile through (PR 64; it refuses four) and Mosaic compiles the kernel's body as it stands,
+    over the full layer's rows of 16 x 12,288 and over the window layers' rings of 16 x 4,096, each stack read where it lies."""
+    from ray_tpu.ops import slot_attention as sa
+
+    assert sa.refusal(jnp.bfloat16, 48, 8, 128, S) is None and sa.padded_heads(48, 8) == 48 and "56 query heads over 8" in sa.refusal(jnp.bfloat16, 56, 8, 128, S)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    stack = sds((layers, 16, S, 8, 128), jnp.bfloat16)
+    compiled, txt = _compile(partial(sa.attend_kernel, name="window_decode_attention"), sds((16, 48, 128), jnp.bfloat16), stack, stack, sds((), jnp.int32), sds((16,), jnp.int32))
+    assert "tpu_custom_call" in txt and "window_decode_attention" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_trinitys_calls_take_blocks_of_128_through_the_loop(as_on_a_tpu):
+    """Which branch ``experts.blocks_plan`` takes at Trinity's shapes, asked as on the chip: a 12,288-row call makes 49,152 pairs,
+    under ``2 * BLOCK * num_experts`` = 65,536, so blocks are 128 rows; an expert of 54 MiB expects 192 rows: under two blocks'
+    (not FULL) and over ``ops/grouped_experts``'s 16 MiB (not LOW fill): the XLA loop, a fetch of the expert's matrices a block.
+    The same for the 8,192-row slabs of a two-prompt wave (128 rows an expert). A later change of the rule shows here."""
+    from ray_tpu.models import experts
+    from ray_tpu.models.afmoe import AfmoeConfig
+    from ray_tpu.ops import grouped_experts
+
+    s = dataclasses.replace(AfmoeConfig(), num_local_experts=32).expert_layer
+    mats = [jax.ShapeDtypeStruct((4, 32, 3072, 3072), jnp.bfloat16)] * 3
+    assert experts.blocks_plan(s, 12288, mats) == (128, False) and experts.blocks_plan(s, 8192, mats) == (128, False)
+    assert experts._call_rows(12288) == 12288 and experts._call_rows(2 * 12288) == 8192
+    assert "an expert of 54.00 MiB, over 16 MiB, expects 192 rows, under two blocks of 128" in grouped_experts.refusal(jnp.bfloat16, 3072, 3072, 3, 192, 128, False)
+    # the same expert at FULL blocks would go to the kernel: the refusal is of the fill, not of the shape
+    assert grouped_experts.refusal(jnp.bfloat16, 3072, 3072, 3, 256, 128, False) is None
+
+
+def test_trinity_fused_step_fits_one_v5e_aliases_rows_and_rings_and_slices_no_layers_rows(fused_step_for_the_chip, as_on_a_tpu):
+    """The fused step at 16 x 12,288 through the SAME ``hybrid_runner.fused_step`` and layer loop as the nine other descriptions
+    (``swa mlp`` unrolled, ``swa moe`` scanned three times, ``attn moe`` unrolled): 8.05 GiB of weights and 1.75 GiB of cache, 0.75 of
+    it the full layer's rows for every position and 1.0 the four window layers' rings of 4,096 rows; all of it aliased to the
+    donated inputs; the live-block kernel under two names at three tiles of query rows, the experts' step kernel, the gate under
+    its own scope, and no slice of a layer's rows (384 MiB of keys at 16 x 12,288, 128 MiB a ring) in the compiled text."""
+    import re
+
+    cfg, _, cache, state, compiled = fused_step_for_the_chip("trinity")
+    assert cfg.layer_plan == (("swa", "moe"), 3, ("attn", "moe"), ("swa", "mlp")) and state == {}
+    assert {n: a.shape for n, a in cache.items() if n != "length"} == {
+        "k": (1, 16, 12288, 8, 128), "v": (1, 16, 12288, 8, 128), "k_w": (4, 16, 4096, 8, 128), "v_w": (4, 16, 4096, 8, 128)}
+    mem, txt = compiled.memory_analysis(), compiled.as_text()
+    assert _kv_bytes(cache) == 1_879_048_192 == 2 * (1 * 16 * 12288 + 4 * 16 * 4096) * 2048
+    print("trinity fused step:", mem.argument_size_in_bytes / 2**30, mem.alias_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**20)
+    assert 9.75 * 2**30 < mem.argument_size_in_bytes < 9.85 * 2**30 and mem.alias_size_in_bytes >= _kv_bytes(cache)
+    assert all(name in txt for name in ("slot_decode_attention", "window_decode_attention", "step_experts", "swa.gate", "attn.gate"))
+    # the full layer's stack is ONE layer deep: [1,16,12288,8,128] is the entry itself and [16,12288,8,128] a bitcast of it (the
+    # new row's scatter is in place); a copy of it would be 384 MiB of temporaries, and the step's are 39
+    assert not re.search(r"bf16\[(1,)?16,4096,8,128\]", txt)
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("prompts, most_gib", [(1, 1.4), (2, 2.9)])
+def test_trinity_prefill_of_the_12288_bucket_fits_beside_weights_and_cache_on_one_v5e(one_chip, as_on_a_tpu, prompts, most_gib):
+    """The 12,288-bucket prefill (the flash kernel with a window under ``swa``, without one under ``attn``, at 48 heads over 8; the
+    gate's projection as wide as the queries; 49,152 routed pairs a prompt through the grouped matmul's LOOP: no ``grouped_experts``
+    kernel in the text) for one prompt and for two, beside 8.05 GiB of weights and 1.75 GiB of cache: under 15.75 GiB. Four prompts
+    at once do not fit (5.4 GiB of temporaries and 0.94 GiB handed to the cache: 16.1 GiB in all): the engine's reckoning of the
+    device's free memory halves such a wave, so the cell's ``warm_batch_max`` of 4 warms groups of 1 and 2."""
+    from ray_tpu.llm import hybrid_runner as hr
+
+    cfg, params, _, _ = _cell_at_its_size(one_chip, "trinity")
+    tokens = jax.ShapeDtypeStruct((prompts, 12288), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((prompts,), jnp.int32, sharding=one_chip)
+    compiled, txt = _compile(partial(hr.prefill, cfg=cfg), params, tokens, lengths)
+    mem = compiled.memory_analysis()
+    print("trinity prefill:", prompts, mem.argument_size_in_bytes / 2**30, mem.temp_size_in_bytes / 2**30, mem.output_size_in_bytes / 2**30)
+    kernels = [line for line in txt.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert any("window_flash_attention" in line and "/swa/" in line for line in kernels), "the window layers' attention as the flash kernel, under its scope"
+    assert any("window_flash_attention" not in line and "/attn/" in line for line in kernels), "and the full layer's without a window"
+    assert not any("grouped_experts" in line for line in kernels) and "swa.gate" in txt and "attn.gate" in txt
+    assert mem.temp_size_in_bytes < most_gib * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 1.75 * 2**30 < 15.75 * 2**30

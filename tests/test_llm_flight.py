@@ -466,7 +466,7 @@ def test_the_logs_bound_drops_the_oldest_and_says_how_many(session, monkeypatch)
 
 
 def test_the_logs_memory_stays_under_its_stated_bound():
-    """12,000 step rows and 2,000 requests of 150 tokens: the stated bound (21 MB since PR 39 added three fields to a step row; 21.5 since PR 46 added two, 16 bytes a row; 21.7 since PR 50 added two; 21.9 since PR 52 added two; 22.1 since PR 54 added one; 23.3 since PR 55 added five, two of them floats on every row; 23.7 since PR 58 added four counts, 8 bytes a row each; 23.9 since PR 60 added two)."""
+    """12,000 step rows and 2,000 requests of 150 tokens: the stated bound (21 MB since PR 39 added three fields to a step row; 21.5 since PR 46 added two, 16 bytes a row; 21.7 since PR 50 added two; 21.9 since PR 52 added two; 22.1 since PR 54 added one; 23.3 since PR 55 added five, two of them floats on every row; 23.7 since PR 58 added four counts, 8 bytes a row each; 23.9 since PR 60 added two; 24.0 since PR 64 added one)."""
     import tracemalloc
 
     rec = telemetry.FlightRecorder()
@@ -481,7 +481,7 @@ def test_the_logs_memory_stays_under_its_stated_bound():
     held, _ = tracemalloc.get_traced_memory()
     tracemalloc.stop()
     assert len(rec.log_steps) == 12_000 and len(rec.log_requests) == 2_000
-    assert held < 23.9e6, f"the flight log holds {held / 1e6:.1f} MB"
+    assert held < 24.0e6, f"the flight log holds {held / 1e6:.1f} MB"
 
 
 def test_load_flight_merges_two_processes_and_skips_a_torn_last_line(session):

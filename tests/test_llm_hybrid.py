@@ -150,7 +150,7 @@ def test_neither_the_runner_nor_the_engine_names_a_model_or_a_kind_of_layer():
     for module in (hybrid_runner, engine_module):
         with open(module.__file__) as f:
             text = f.read()
-        found = re.findall(r"nemotron|qwen|glm|keye[_v-]|jamba|mamba|selscan|gdn|deltanet|\"attn\"|\"moe\"|\"mla\"|\"indexed\"|'moe'|'attn'|'indexed'|c_kv|k_r\b|k_idx", text, flags=re.IGNORECASE)
+        found = re.findall(r"nemotron|qwen|glm|keye[_v-]|jamba|afmoe|trinity|mamba|selscan|gdn|deltanet|\"attn\"|\"moe\"|\"mla\"|\"indexed\"|'moe'|'attn'|'indexed'|c_kv|k_r\b|k_idx", text, flags=re.IGNORECASE)
         assert not found, (module.__name__, found)
 
 

@@ -29,7 +29,8 @@ PROGRAMS = {"llama": SLOTS, "llama_paged": PAGED, "nemotron_h": HYBRID, "qwen3_n
             "kimi_linear": HYBRID,  # a state a sequence AND a latent a position
             "minicpm_sala": HYBRID,  # keys and values a position, a state and the compressed keys a sequence
             "keye_vl": [p for p in HYBRID if p != "llm_state_insert"],  # keys, values and the indexer's key a position, nothing a sequence
-            "jamba": HYBRID}  # keys and values a position in two layers, a state and a window a sequence in the rest
+            "jamba": HYBRID,  # keys and values a position in two layers, a state and a window a sequence in the rest
+            "afmoe": [p for p in HYBRID if p != "llm_state_insert"]}  # keys and values a position, in rows and in rings; nothing a sequence
 
 
 class Recording:
@@ -74,6 +75,10 @@ def _config(description):
         from ray_tpu.models.jamba import JambaConfig
 
         return JambaConfig.tiny()
+    if description == "afmoe":
+        from ray_tpu.models.afmoe import AfmoeConfig
+
+        return AfmoeConfig.tiny(num_local_experts=4)  # a dense layer, three window layers and a full one; the prompt of 40 is over two windows of 16
     from ray_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
 
     return Glm4MoeLiteConfig.tiny()
@@ -219,6 +224,20 @@ def test_a_prefills_blocks_stand_under_moe_blocks_whichever_way_they_are_run(des
     assert unscoped_ops(lowering) == []
 
 
+def scopes_of(lowering) -> dict:
+    """scope -> the names of the operations that stand under it (the deepest name of the table on their path) in a lowered program."""
+    from jax._src.lib.mlir import ir
+
+    found = {}
+
+    def visit(op):
+        found.setdefault(scope_of(_name(op)), set()).add(op.operation.name)
+        return ir.WalkResult.ADVANCE
+
+    lowering.compiler_ir().operation.walk(visit)
+    return found
+
+
 @pytest.mark.parametrize("runs", ["xla", "kernel"])
 def test_a_mamba1_layers_parts_stand_under_their_four_scopes_whichever_way_the_scan_is_run(lowered, runs, monkeypatch):
     """PR 60: in a prefill the convolution stands under ``mamba1.conv`` and the recurrence under ``mamba1.scan`` (as XLA's
@@ -228,19 +247,7 @@ def test_a_mamba1_layers_parts_stand_under_their_four_scopes_whichever_way_the_s
     readers and ``decode_state_ms`` go by."""
     from functools import partial
 
-    from jax._src.lib.mlir import ir
-
     from ray_tpu.ops import selective_scan
-
-    def scopes_of(lowering):
-        found = {}
-
-        def visit(op):
-            found.setdefault(scope_of(_name(op)), set()).add(op.operation.name)
-            return ir.WalkResult.ADVANCE
-
-        lowering.compiler_ir().operation.walk(visit)
-        return found
 
     if runs == "kernel":
         monkeypatch.setattr(selective_scan, "refusal", lambda *a, **kw: None)
@@ -256,6 +263,26 @@ def test_a_mamba1_layers_parts_stand_under_their_four_scopes_whichever_way_the_s
     assert {"mamba1", "mamba1.conv", "mamba1.state"} <= set(step) and "mamba1.scan" not in step
     assert {"stablehlo.exponential", "stablehlo.dynamic_update_slice"} <= step["mamba1.state"]
     assert all(SCOPES[n] == role for n, role in (("mamba1", "mixer"), ("mamba1.conv", "mixer"), ("mamba1.scan", "mixer"), ("mamba1.state", "state")))
+
+
+def test_an_afmoe_layers_gate_stands_under_a_scope_of_its_own_and_its_second_norm_under_its_sub_blocks(lowered):
+    """PR 64: in the prefill and in the fused step alike, the output gate's projection and its product with the heads'
+    output stand under ``swa.gate`` in a window layer and ``attn.gate`` in a full one, INSIDE the layer's scope (what
+    ``prefill_gate_ms_per_ktok`` goes by: a trace says what the gate costs), the other projections under the layer's own;
+    the sandwich's second norm is the sub-block's own operation (a ``rsqrt`` under ``mlp`` and under ``moe``, which hold no
+    other norm: the loop's pre-norm stands there too, so under each at least two)."""
+    programs = lowered("afmoe")
+    for name in ("llm_hybrid_prefill", "llm_hybrid_fused_step"):
+        found = scopes_of(programs[name])
+        assert {"swa", "swa.gate", "attn", "attn.gate", "mlp", "moe", "moe.route", "moe.blocks", "moe.shared"} <= set(found), name
+        for kind in ("swa", "attn"):
+            assert {"stablehlo.dot_general", "stablehlo.exponential", "stablehlo.multiply"} <= found[kind + ".gate"] and "stablehlo.rsqrt" not in found[kind + ".gate"]
+            assert {"stablehlo.dot_general", "stablehlo.rsqrt"} <= found[kind]
+        assert "stablehlo.rsqrt" in found["mlp"] and "stablehlo.rsqrt" in found["moe"]
+        assert unscoped_ops(programs[name]) == []
+    text = programs["llm_hybrid_prefill"].as_text()
+    assert text.count("stablehlo.rsqrt") >= 2 * 4 + 2 * 2  # a branch a kind: two stream norms in each of the four, two head norms in each attention kind
+    assert all(SCOPES[n] == "mixer" for n in ("swa", "swa.gate", "attn", "attn.gate")) and SCOPES["mlp"] == SCOPES["moe"] == "ffn"
 
 
 @pytest.mark.parametrize("description", sorted(PROGRAMS))

@@ -272,5 +272,6 @@ def test_the_gate_lets_28_heads_over_4_through_and_says_why_not_by_name(monkeypa
     assert sa.refusal(jnp.bfloat16, 28, 4, 128, 12288) is None and sa.refusal(jnp.bfloat16, 28, 4, 128, 4096) is None
     assert sa.refusal(jnp.bfloat16, 16, 8, 128, 4096) is None and sa.refusal(jnp.bfloat16, 32, 2, 128, 12288) is None
     assert sa.refusal(jnp.bfloat16, 20, 4, 128, 4096) is None  # groups of 5 go as 8: the 32 rows over 4 that 28 take
-    for heads, kv in ((8, 8), (12, 4), (40, 8)):  # under one tile of 16 rows (padding is for a group's rows, not for a small head count); over two
+    assert sa.refusal(jnp.bfloat16, 48, 8, 128, 12288) is None and sa.refusal(jnp.bfloat16, 40, 8, 128, 4096) is None  # three tiles (PR 64): groups of 6, and of 5 as 6
+    for heads, kv in ((8, 8), (12, 4), (56, 8)):  # under one tile of 16 rows (padding is for a group's rows, not for a small head count); over three
         assert f"{heads} query heads over {kv} kv heads" in sa.refusal(jnp.bfloat16, heads, kv, 128, 4096)
