@@ -86,9 +86,12 @@ BLOCK, TALL_FROM = 128, 32768
 # every pair that COULD be held here): beyond this many rows a sequence batch goes through in slabs
 SLAB_ROWS = 8192
 # the placement walks the pairs HELD here: ``SLAB`` of them at a time where it ranks them and gathers
-# their inputs, ``ROWS`` of a tile of ``TILE`` tokens at a time where it gathers and sums their outputs
-# (on a v5e a trip costs about 8 us and a row 25 ns: PERF.md section 6, PR 47, has what other sizes read)
-SLAB, TILE, ROWS = 1024, 128, 512
+# their inputs, and a tile of ``TILE`` tokens at a time where it gathers and sums their outputs, in
+# trips whose rows ``out_plan`` reads off the layer's description
+SLAB, TILE = 1024, 128
+# the most rows a trip of the gather out takes (``out_plan``): on a v5e a trip, the loop's own turn counted, is 9 us and 24 ns an entry at rows
+# 2,048 wide (16 and 37 at 2,560, 26 and 34 at 3,072), filled or not, up to 896 entries, and one of 1,024 reads 8-9 us over that line at all three
+TRIP_MOST = 768
 # an expert's run starts at a multiple of this many rows of the layout, a whole tile of a bfloat16
 # array: a block's matmuls read and write 20% slower at an offset the compiler cannot see aligned
 ALIGN = 16
@@ -206,6 +209,32 @@ def blocks_plan(s: ExpertLayer, N: int, mats) -> tuple:
     return block, grouped_experts.refusal(mats[0].dtype, H, F, len(mats), M // s.num_experts, block, tall) is None
 
 
+def out_plan(s: ExpertLayer) -> int:
+    """Rows of one trip of the gather out of the blocks (``moe.place.out``), from the layer's description alone:
+    the pairs that a tile of ``TILE`` tokens is EXPECTED to hold here, so that a tile is one trip and a trip has
+    no entry to spare. Where every published expert is held a tile holds ``TILE k`` exactly; where a share is
+    held, that share of it and a third more (the pairs are the router's to spread: a tile that holds more takes
+    a second trip, the trips' count is data). More than ``TRIP_MOST`` go in equal trips. In whole ``TILE``s up to
+    one, in whole pairs of them above. One layer alone on a v5e (``scripts/experts_layer_bench.py --trips``;
+    PERF.md section 6, PR 65, has the table): an entry of a trip costs what a row costs whether a pair fills it
+    or not (24-37 ns), a trip costs 9-26 us beside its entries, more the wider the rows, and trips of 384, 640
+    and 896 entries read 1.4-3.9 us over the line through 128, 256, 512 and 768. So at six choices a token one
+    trip of 768 and not two of 512, the second half empty (SmallThinker: ``moe.place.out`` -33%, the layer
+    -14%); at eight, two of 512 as before (Keye: one of 1,024 reads the layer the same to half a percent); 128
+    and not 512 where a chip holds an eighth of the experts at four choices (Trinity: -36%, the layer -11%);
+    and the 512 they had for GLM and LFM2 (512 exactly), Qwen3-Next (320 expected), Nemotron (384) and Kimi
+    (256: trips of 256 / 384 / 512 read the layer within 1.5% of each other there). The outputs are the same
+    bit for bit at every trip size: a token's k products are added in one float32 pass either way."""
+    pairs = TILE * s.top_k
+    if s.held < s.num_experts:
+        expected = pairs * s.held // s.num_experts
+        pairs = min(pairs, expected + expected // 3)
+    trips = -(-pairs // TRIP_MOST)
+    rows = -(-pairs // trips)
+    unit = TILE if rows <= TILE else 2 * TILE
+    return -(-rows // unit) * unit
+
+
 def _grouped(stacked, layer, x, idx, wt, valid, c):
     """``experts_grouped`` and what it did: -> (out [N,H], pairs at each held expert [El] int32,
     rows of the blocks in use, int32)."""
@@ -214,8 +243,9 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
     M, El, H = N * k, s.held, x.shape[-1]
     mats = [stacked[n] for n in s.matrices]
     block, kernel = blocks_plan(s, N, mats)
+    trip = out_plan(s)
     slabs, tiles = -(-M // SLAB), -(-N // TILE)
-    n_pairs = slabs * SLAB + ROWS  # every pair could be held here; a trip may hang over the end
+    n_pairs = slabs * SLAB + trip  # every pair could be held here; a trip may hang over the end
     n_rows = -(-(M + El * ALIGN) // SLAB) * SLAB + block  # and every run start on a whole tile of the layout; a slab or a block may hang over
     i32 = jnp.int32
     with scope("moe.place"), scope("moe.place.count"):
@@ -250,9 +280,9 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
 
         _, row_of, pair_at = jax.lax.fori_loop(0, (held + SLAB - 1) // SLAB, place_slab, (
             jnp.zeros((El,), jnp.float32), jnp.zeros((n_pairs,), i32), jnp.full((n_rows,), M, i32)))
-        # a tile of tokens owns a stretch of ``by_token``; it is summed ``ROWS`` pairs a trip
+        # a tile of tokens owns a stretch of ``by_token``; it is summed ``trip`` pairs a trip
         edges = jnp.append(before, held)[np.minimum(np.arange(tiles + 1) * TILE * k, M)]
-        trips_of = (edges[1:] - edges[:-1] + ROWS - 1) // ROWS
+        trips_of = (edges[1:] - edges[:-1] + trip - 1) // trip
         last_trip = jnp.cumsum(trips_of)
 
     def fill_slab(j, rows):
@@ -260,7 +290,8 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
         return jax.lax.dynamic_update_slice_in_dim(rows, jnp.take(x, jnp.minimum(pair, M - 1) // k, axis=0), block + j * SLAB, 0)
 
     def fill_slab_and_weights(j, filled):
-        # for the kernel: each row's weight beside it, in every lane of a row of its own (a block's come in by one DMA)
+        # for the kernel: each row's weight beside it, in every lane of a row of its own (a block's come in by one DMA). What this costs
+        # over ``fill_slab`` is the gather of the weights, 8 ns a scalar, and not the 512 bytes a row (PERF.md section 6, PR 65)
         weight = scale[jnp.minimum(jax.lax.dynamic_slice_in_dim(pair_at, j * SLAB, SLAB), M - 1)]
         return fill_slab(j, filled[0]), jax.lax.dynamic_update_slice_in_dim(filled[1], jnp.broadcast_to(weight[:, None], (SLAB, grouped_experts.LANES)), j * SLAB, 0)
 
@@ -297,13 +328,13 @@ def _grouped(stacked, layer, x, idx, wt, valid, c):
             rows = jax.lax.fori_loop(0, last_block[-1], one_block, rows)
 
     def sum_rows(t, out):
-        # ``ROWS`` of a tile's pairs: their rows gathered, and added to their tokens by a 0/1
-        # [TILE, ROWS] matrix on the MXU, which sums in float32 as a reduction over k would
+        # ``trip`` of a tile's pairs (all of them, where the tile holds what ``out_plan`` expected): their rows gathered, and
+        # added to their tokens by a 0/1 [TILE, trip] matrix on the MXU, which sums in float32 as a reduction over k would
         i = jnp.sum(last_trip <= t).astype(i32)
-        q = edges[i] + (t - (last_trip[i] - trips_of[i])) * ROWS
-        ok = jnp.arange(ROWS, dtype=i32) < edges[i + 1] - q
-        yb = jnp.take(rows, jnp.where(ok, jax.lax.dynamic_slice_in_dim(row_of, q, ROWS), 0), axis=0)
-        token = jax.lax.dynamic_slice_in_dim(by_token, q, ROWS) // k - i * TILE
+        q = edges[i] + (t - (last_trip[i] - trips_of[i])) * trip
+        ok = jnp.arange(trip, dtype=i32) < edges[i + 1] - q
+        yb = jnp.take(rows, jnp.where(ok, jax.lax.dynamic_slice_in_dim(row_of, q, trip), 0), axis=0)
+        token = jax.lax.dynamic_slice_in_dim(by_token, q, trip) // k - i * TILE
         pick = ok[None, :] & (token[None, :] == jnp.arange(TILE, dtype=i32)[:, None])
         add = jnp.dot(pick.astype(yb.dtype), jnp.where(ok[:, None], yb, 0), preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
         return jax.lax.dynamic_update_slice_in_dim(out, jax.lax.dynamic_slice_in_dim(out, i * TILE, TILE) + add, i * TILE, 0)
@@ -326,7 +357,8 @@ def experts_grouped(stacked, layer, x, idx, wt, valid, c):
     where an expert expects two blocks' rows or more, or a small expert less than two short blocks', the
     kernel of ``ops/grouped_experts.py`` walks the same blocks in the same order with ``one_block``'s
     mathematics, ``blocks_plan``);
-    then a tile of ``TILE`` tokens gathers its pairs' rows ``ROWS`` a trip and a 0/1 matrix sums
+    then a tile of ``TILE`` tokens gathers its pairs' rows, in the trips ``out_plan`` sizes (one,
+    where the tile holds the pairs expected of it and they are 768 or fewer), and a 0/1 matrix sums
     them by token in float32. A pair's product is scaled by its weight in float32 and rounded
     once, a token's pairs are summed in float32 and rounded once; no pair is dropped, whatever the
     load on one expert. ``valid`` [N] keeps padding out of every group. The loops' lengths are

@@ -1,15 +1,17 @@
 """The routed-expert layer alone (``models/experts._grouped``), one layer at the cells' shapes: the blocks run by the
 plain XLA loop beside the kernel of ``ops/grouped_experts.py`` at several block heights, in one process on one chip.
 
-    python3 scripts/experts_layer_bench.py [--only lg] [--heights 128,256,512] [--alt <another grouped_experts.py>] [--out chiprun_out/<name>.json]
+    python3 scripts/experts_layer_bench.py [--only lg] [--heights 128,256,512] [--trips 512] [--alt <another grouped_experts.py>] [--out chiprun_out/<name>.json]
 
 A row a shape, a call of it (a prefill's slabs differ in how many of their rows are true) and a form: ``loop<h>`` the loop
 and ``kernel<h>`` the kernel at blocks of h rows (``blocks_plan`` answered for), ``alt<h>`` the kernel of the file ``--alt``
-names; the form that ``experts.blocks_plan`` picks for the call on a TPU comes first and is marked ``the_trees``. Milliseconds a call on the host clock
+names; the form that ``experts.blocks_plan`` and ``experts.out_plan`` pick for the call on a TPU comes first and is marked
+``the_trees``. ``--trips`` adds the tree's form with the gather out at other rows a trip than ``out_plan``'s (``.trip<rows>``;
+512 is what every call had until PR 65). Milliseconds a call on the host clock
 (the median of 5 means of 10 calls), the first call's seconds (trace, lower, compile, one run), device milliseconds of
-the whole call and of ``moe.blocks`` and ``moe.place.*`` from one traced stretch read by scope, the blocks' TFLOP/s over
+the whole call, of ``moe.blocks`` and of ``moe.place.count`` / ``.into`` / ``.out`` (and their sum) from one traced stretch read by scope, the blocks' TFLOP/s over
 the pairs' own operations (rows of padding inside a block count for nothing), the rows of the blocks in use, and whether
-the output is the first form's bit for bit. The shapes are ISSUE 63's: rows a call, experts published and held, top k,
+the output is the first form's bit for bit. The shapes are ISSUE 63's and ISSUE 65's: rows a call, experts published and held, top k,
 F, H, form, and ``valid`` cut to the cells' true lengths. ``--compile`` runs nothing: it compiles every form for a
 described v5e, which is what the chip's compiler would refuse. It times a chip and says so where there is none;
 ``tests/hybrid_battery.py`` has the interpreted kernel."""
@@ -36,8 +38,12 @@ SHAPES = [
     ("s smallthinker 12288 of which 10500", 12288, (64, 64), 6, 768, 2560, "reglu", [(1, (12288, 10500))]),
     ("k keye 24576 of which 20500, slabs of 8192", 8192, (128, 128), 8, 768, 2048, "swiglu", [(2, (8192, 8192)), (1, (8192, 4116))]),
     ("n nemotron 4 x 2048 of which 1500 each", 8192, (128, 64), 6, 1856, 2688, "relu2", [(1, (2048, 1500))]),
+    ("t trinity 12288 of which 10500, an eighth of the experts", 12288, (256, 32), 4, 3072, 3072, "swiglu", [(1, (12288, 10500))]),
+    ("i kimi 4096 of which 2500, a quarter of the experts", 4096, (256, 64), 8, 1024, 2304, "swiglu", [(1, (4096, 2500))]),
+    ("j kimi 2 x 4096 of which 2500 each", 8192, (256, 64), 8, 1024, 2304, "swiglu", [(1, (4096, 2500))]),
+    ("q qwen3-next 4096 of which 2500, a quarter of the experts", 4096, (512, 128), 10, 512, 2048, "swiglu", [(1, (4096, 2500))]),
 ]
-TINY = [("t tiny", 512, (8, 8), 2, 64, 128, "swiglu", [(1, (512, 400))]), ("u tiny relu2", 512, (16, 8), 2, 64, 128, "relu2", [(2, (256, 200))])]
+TINY = [("t tiny", 2048, (8, 8), 2, 64, 128, "swiglu", [(1, (2048, 1900))]), ("u tiny relu2", 512, (16, 8), 2, 64, 128, "relu2", [(2, (256, 200))])]
 
 
 def load(path: str, name: str):
@@ -54,7 +60,8 @@ def load(path: str, name: str):
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--only", default=None, help="shapes whose name starts with one of these letters, e.g. lg")
-    ap.add_argument("--heights", default="128,256,512", help="rows of a block under the kernel")
+    ap.add_argument("--heights", default="", help="rows of a block under the kernel, besides the tree's own, e.g. 128,256,512")
+    ap.add_argument("--trips", default="", help="rows of a trip of the gather out, besides the tree's own, e.g. 512")
     ap.add_argument("--loops", default="", help="rows of a block under the loop, besides the tree's own")
     ap.add_argument("--alt", default=None, help="another tree's ray_tpu/ops/grouped_experts.py: its kernel as alt<h>")
     ap.add_argument("--out", default=None, help="where the rows go as JSON beside the printed lines")
@@ -82,7 +89,7 @@ def main() -> int:
         print(f"[experts_layer_bench] {device.platform}: no TPU, and a time from anything else is no device's", file=sys.stderr)
         return 2
     jax.config.update("jax_enable_compilation_cache", False)  # a first call is timed: it compiles, whatever an earlier run left
-    plan_of_the_tree = experts.blocks_plan
+    plan_of_the_tree, trip_of_the_tree = experts.blocks_plan, experts.out_plan
     heights = [int(h) for h in args.heights.split(",") if h]
     forms = {f"loop{h}": (int(h), False, grouped_experts) for h in args.loops.split(",") if h}
     forms.update({f"kernel{h}": (h, True, grouped_experts) for h in heights})
@@ -109,16 +116,18 @@ def main() -> int:
         backend, jax.default_backend = jax.default_backend, lambda: "tpu"
         picked, jax.default_backend = plan_of_the_tree(s, N, [stacked[n] for n in s.matrices]), backend
         the_trees = ("kernel" if picked[1] else "loop") + str(picked[0])
-        these = {the_trees: (*picked, grouped_experts), **{f: p for f, p in forms.items() if f != the_trees}}
+        # name -> rows a block, whether the kernel runs them, its module, the rows of a trip (None: ``out_plan``'s own)
+        these = {the_trees: (*picked, grouped_experts, None), **{f: (*p, None) for f, p in forms.items() if f != the_trees}}
+        these.update({f"{the_trees}.trip{rows}": (*picked, grouped_experts, int(rows)) for rows in args.trips.split(",") if rows and int(rows) != trip_of_the_tree(s)})
         for often, (period, true) in calls:
             valid = (np.arange(N) % period) < true
             data = (stacked, x, jnp.asarray(idx), jnp.asarray(wt), jnp.asarray(valid))
             if args.compile:
                 data = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), data)
             first, fns = None, {}
-            for form, (block, kernel, module) in these.items():
+            for form, (block, kernel, module, trip) in these.items():
                 row = {"shape": name, "calls_a_prefill": often, "true_rows": int(valid.sum()), "form": form, "the_trees": form == the_trees, "block": block, "kernel": kernel,
-                       "device": "described v5e" if args.compile else device.device_kind}
+                       "trip": trip or trip_of_the_tree(s), "device": "described v5e" if args.compile else device.device_kind}
                 rows.append(row)
 
                 def run(stacked, x, idx, wt, valid):
@@ -127,6 +136,7 @@ def main() -> int:
                 run.__name__ = f"layer_{name[0]}{true}_{form}"
                 fn = jax.jit(run)
                 experts.blocks_plan, experts.grouped_experts = (lambda *_a, plan=(block, kernel): plan), module
+                experts.out_plan = (lambda *_a, trip=trip: trip) if trip else trip_of_the_tree
                 try:
                     t0 = time.perf_counter()
                     if args.compile:
@@ -137,7 +147,7 @@ def main() -> int:
                 except Exception as e:  # noqa: BLE001 - a height the compiler refuses is a row's finding, not the run's end
                     row["error"] = f"{type(e).__name__}: {str(e)[:400]}"
                 finally:
-                    experts.blocks_plan, experts.grouped_experts = plan_of_the_tree, grouped_experts
+                    experts.blocks_plan, experts.grouped_experts, experts.out_plan = plan_of_the_tree, grouped_experts, trip_of_the_tree
                 if "first_call_s" not in row:
                     continue
                 means = []
@@ -169,6 +179,7 @@ def main() -> int:
                         row["device_ms"] = round(traced["device_s"] / n * 1e3, 4)
                         row["blocks_ms"] = round(scopes.get("moe.blocks", 0.0), 4)
                         row["place_ms"] = round(sum(v for sc, v in scopes.items() if sc.startswith("moe.place")), 4)
+                        row.update({part + "_ms": round(scopes.get("moe.place." + part, 0.0), 4) for part in ("count", "into", "out")})
                         if row["blocks_ms"]:  # over the pairs' own operations, and over the rows of the blocks in use (padding counted as work)
                             row["blocks_tflops"] = round(row["pairs"] * len(s.matrices) * 2 * F * H / row["blocks_ms"] / 1e9, 2)
                             row["blocks_tflops_padded"] = round(row["rows_in_blocks"] * len(s.matrices) * 2 * F * H / row["blocks_ms"] / 1e9, 2)

@@ -425,17 +425,18 @@ def test_the_chips_shares_add_up_to_the_uncut_expert_layer(desc):
     np.testing.assert_allclose(x + total, ref, atol=1e-4)
 
 
-PLACEMENTS = ("one_expert", "none_here", "a_block_and_one_more", "valid_ends_inside_a_block", "tall_blocks", "few_rows_an_expert", "in_slabs", "many_small_trips")
-TRACE_READS = ("BLOCK", "TALL_FROM", "SLAB", "TILE", "ROWS", "SLAB_ROWS", "ALIGN")  # the constants of ``models/experts.py`` that a trace of ``_grouped`` reads
+PLACEMENTS = ("one_expert", "none_here", "a_block_and_one_more", "valid_ends_inside_a_block", "tall_blocks", "few_rows_an_expert", "in_slabs", "many_small_trips",
+              "a_second_trip", "an_empty_tile", "full_blocks")
+TRACE_READS = ("BLOCK", "TALL_FROM", "SLAB", "TILE", "SLAB_ROWS", "ALIGN")  # the constants of ``models/experts.py`` that a trace of ``_grouped`` reads, beside what ``out_plan`` answers
 
 
 @pytest.fixture(scope="module")
 def routed(desc, params):
-    """What the placement's sixteen cases share, made once a module: 300 tokens and the router's choice
+    """What the placement's twenty-two cases share, made once a module: 300 tokens and the router's choice
     for them, the first expert layer's weights (the one-by-one oracle's), and ``grouped``:
-    ``experts._grouped`` traced ONCE for each set of values its trace reads (who runs the blocks, and
-    ``TRACE_READS`` as the case has patched them), so the eight cases that patch no constant share two
-    programs and only a case that changes the traced program traces one."""
+    ``experts._grouped`` traced ONCE for each set of values its trace reads (who runs the blocks,
+    ``TRACE_READS`` and the rows of a trip as the case has patched them), so the ten cases that patch
+    nothing share two programs and only a case that changes the traced program traces one."""
     cfg, w = desc.cfg, jax.tree.map(lambda a: a[0], params["moe"])
     x = jax.random.normal(jax.random.PRNGKey(21), (300, cfg.hidden_size))
     # a layer whose routing is made elsewhere holds no router: the first one that any kind holds serves
@@ -444,7 +445,7 @@ def routed(desc, params):
     traced = {}
 
     def grouped(runs, *operands):
-        reads = (runs,) + tuple(getattr(experts, name) for name in TRACE_READS)
+        reads = (runs, experts.out_plan(cfg.expert_layer)) + tuple(getattr(experts, name) for name in TRACE_READS)
         if reads not in traced:  # a jit of its own: one keyed by ``_grouped`` would hand back the program of other constants
             traced[reads] = jax.jit(lambda *a: experts._grouped(params["moe"], 0, *a, cfg))
         return traced[reads](*operands)
@@ -460,7 +461,11 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
     out); an expert with exactly ``BLOCK`` pairs beside one with ``BLOCK + 1``; ``valid`` that ends
     inside a block; the tall blocks, which a call takes where it has ``TALL_FROM`` pairs AND an expert
     is expected to get two blocks' rows of them, and not where it expects fewer; a batch in slabs;
-    slabs, tiles and trips a few rows long, so that every loop turns many times. Each against the
+    slabs, tiles and trips a few rows long, so that every loop turns many times; tiles that hold
+    more pairs than ``out_plan`` sized a trip for, as under expert parallelism a tile whose tokens all
+    chose experts held here does (every pair held here: a second trip and more for every tile); a tile
+    with no pair at all between two that have them (no trip); every expert a run of two blocks and
+    more (the regime in which the kernel serves an expert of any size). Each against the
     pairs computed one by one, and the counters against their definition; with the blocks run by the
     loop, and by the kernel (``ops/grouped_experts.py``: the test answers for its ``refusal`` and the
     same body runs interpreted). (Not in ``__all__``: for the descriptions that route experts.)"""
@@ -494,10 +499,18 @@ def test_the_grouped_matmul_places_the_pairs_held_here_whatever_their_number(des
         assert N * k // s.num_experts < 2 * B
     elif case == "in_slabs":
         monkeypatch.setattr(experts, "SLAB_ROWS", 100)
-    elif case == "many_small_trips":
+    elif case in ("many_small_trips", "a_second_trip"):
         monkeypatch.setattr(experts, "SLAB", 16)
         monkeypatch.setattr(experts, "TILE", 8)
-        monkeypatch.setattr(experts, "ROWS", 4)
+        monkeypatch.setattr(experts, "out_plan", lambda *_: 4)
+        if case == "a_second_trip":  # the same program: every tile holds all its 8 k pairs, 2 k trips of it
+            idx = jnp.asarray(np.asarray(idx) % El + s.expert_start)
+    elif case == "an_empty_tile":
+        valid[experts.TILE:2 * experts.TILE] = False
+    elif case == "full_blocks":  # 300 x k pairs over the published experts are two blocks of 16 an expert and more
+        monkeypatch.setattr(experts, "BLOCK", 16)
+        B = 16
+        assert N * k // s.num_experts >= 2 * B
     want = one_by_one(w, x, idx, np.where(valid[:, None], wt, 0.0), cfg)
     assert experts.blocks_plan(s, N, [params["moe"][n] for n in s.matrices]) == (B, runs == "kernel")
     if case == "in_slabs":  # the layer over three sequences of 100, the shared expert taken off again

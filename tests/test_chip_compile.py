@@ -949,6 +949,29 @@ def _the_rule_the_blocks_had(monkeypatch):
     monkeypatch.setattr(experts, "blocks_plan", lambda s, N, mats: (2 * experts.BLOCK if N * s.top_k >= experts.TALL_FROM else experts.BLOCK, False))
 
 
+def _the_trips_the_gather_out_had(monkeypatch):
+    """``experts.out_plan`` as the gather out walked a tile's pairs before PR 65: 512 a trip, whatever the call."""
+    from ray_tpu.models import experts
+
+    monkeypatch.setattr(experts, "out_plan", lambda *_: 512)
+
+
+@pytest.mark.parametrize("cell, pairs_a_tile, trip", [("glm", 512, 512), ("lfm2", 512, 512), ("qwen3_next", 320, 512), ("nemotron", 384, 512), ("kimi", 256, 512),
+                                                      ("smallthinker", 768, 768), ("keye", 1024, 512), ("trinity", 64, 128)])
+def test_the_gather_out_takes_a_tiles_pairs_in_trips_sized_by_the_layer(one_chip, cell, pairs_a_tile, trip):
+    """PR 65: ``experts.out_plan`` at the eight cells that route experts, from each cell's own configuration file. Where
+    every published expert is held a tile of 128 tokens holds 128 k pairs and ONE trip takes them all at 4 and 6
+    choices (512 and 768 rows; two trips of 512 until PR 65 at 6, the second half empty), two equal trips at 8 (Keye:
+    a trip of 1,024 entries costs more than two of 512); where a share is held, what a tile is expected to hold and a
+    third more, in whole pairs of 128s above one (Qwen3-Next 320, Nemotron 384 and Kimi 256 expected: the 512 they had;
+    Trinity 64: 128, where 512 rows were gathered for 64)."""
+    from ray_tpu.models import experts
+
+    cfg, _, _, _ = _cell_at_its_size(one_chip, cell)
+    s = cfg.expert_layer
+    assert experts.TILE * s.top_k * s.held // s.num_experts == pairs_a_tile and experts.out_plan(s) == trip
+
+
 @pytest.mark.parametrize("cell, prompts, bucket", [("nemotron", 1, 2048), ("nemotron", 4, 512), ("nemotron", 4, 2048), ("glm", 1, 64), ("lfm2", 1, 64), ("kimi", 2, 4096), ("kimi", 8, 1024)])
 def test_a_prefill_that_the_kernel_does_not_serve_lowers_to_the_text_it_had(one_chip, as_on_a_tpu, monkeypatch, cell, prompts, bucket):
     """PRs 56, 57 and 63 changed how the blocks of ``experts._grouped`` run only where an expert of 16 MiB or less
@@ -958,10 +981,12 @@ def test_a_prefill_that_the_kernel_does_not_serve_lowers_to_the_text_it_had(one_
     first request of the GLM and LFM2 cells (64 rows against experts of 18 MiB) and Kimi's calls of 8,192 rows (256 an
     expert, one tall block) lower for the chip to the text they lower to under the rule the blocks had before, without
     the kernel: no warm-up program of theirs is a new one (``ROADMAP.md`` A7; ``scripts/warm_texts.py`` makes the
-    comparison against another tree, every warm shape of every cell)."""
+    comparison against another tree, every warm shape of every cell). PR 65 sized the gather out's trips by the layer:
+    these cells' rule still says the 512 rows a trip they had, so the text is held with the trips put back too."""
     cfg, params, _, _ = _cell_at_its_size(one_chip, cell)
     now = _prefill_text(cfg, params, one_chip, prompts, bucket)
     _the_rule_the_blocks_had(monkeypatch)
+    _the_trips_the_gather_out_had(monkeypatch)
     assert "tpu_custom_call" in now and "grouped_experts" not in now and now == _prefill_text(cfg, params, one_chip, prompts, bucket)
 
 
@@ -971,9 +996,17 @@ def test_a_prefill_that_the_kernel_serves_does_not(one_chip, as_on_a_tpu, monkey
     small experts (SmallThinker's bucket of 2,048 rows, which its traffic never admits: 192 rows an expert of
     11.25 MiB; PR 57), and FULL blocks whatever the expert's size (PR 63): the prefills that the traffic of the GLM,
     SmallThinker, LFM2 and Keye cells runs, 8,192 rows a call and more, 512-1,152 rows an expert. Their text is a
-    new one and holds the kernel by name."""
+    new one and holds the kernel by name. And the trips of the gather out (PR 65), where the rule says another size
+    than the 512 rows they had (SmallThinker's six choices a token: one trip of 768): with the blocks' rule as it is
+    and the trips put back, the text is another, so the comparison above can fail on the trips too."""
+    from ray_tpu.models import experts
+
     cfg, params, _, _ = _cell_at_its_size(one_chip, cell)
     now = _prefill_text(cfg, params, one_chip, prompts, bucket)
+    if experts.out_plan(cfg.expert_layer) != 512:
+        with monkeypatch.context() as trips:
+            _the_trips_the_gather_out_had(trips)
+            assert cell == "smallthinker" and now != _prefill_text(cfg, params, one_chip, prompts, bucket)
     _the_rule_the_blocks_had(monkeypatch)
     assert "grouped_experts" in now and "grouped_experts" not in _prefill_text(cfg, params, one_chip, prompts, bucket)
 
